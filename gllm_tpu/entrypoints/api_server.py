@@ -389,6 +389,9 @@ class Handler(BaseHTTPRequestHandler):
                     },
                 },
                 "attention_impl": st.llm.runner.attn_impl,
+                # the device as jax reports it, so a jax-free parent
+                # (chip_smoke.py) can say what the server ran on
+                "device": _device_info(),
                 "waiting": len(st.llm.scheduler.waiting),
                 "running": len(st.llm.scheduler.running),
             })
@@ -1044,6 +1047,35 @@ def build_engine_config(args) -> EngineConfig:
     )
 
 
+def _sigterm_as_interrupt() -> None:
+    """SIGTERM (what a supervisor sends) takes the same graceful-drain
+    exit as Ctrl-C, and the process then exits 0."""
+    import signal
+    import threading
+    if threading.current_thread() is not threading.main_thread():
+        return      # embedded in a thread: the host application owns signals
+
+    def _raise(signum, frame):
+        raise KeyboardInterrupt
+
+    signal.signal(signal.SIGTERM, _raise)
+
+
+def _device_info() -> dict:
+    """The devices as jax reports them, with each local device's memory
+    (limit / in use / peak; None where the backend reports none)."""
+    import jax
+    devices = jax.devices()
+    keys = ("bytes_limit", "bytes_in_use", "peak_bytes_in_use")
+    memory = []
+    for d in jax.local_devices():
+        stats = d.memory_stats()
+        memory.append({k: stats.get(k) for k in keys} if stats else None)
+    return {"platform": devices[0].platform,
+            "kind": devices[0].device_kind, "count": len(devices),
+            "memory": memory}
+
+
 def make_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(
         description="gllm-tpu OpenAI-compatible API server")
@@ -1426,6 +1458,7 @@ def main(argv=None):
                       tool_parser=args.tool_call_parser,
                       replica_id=args.replica_id)
     logger.info("serving %s on %s:%d", args.model, args.host, args.port)
+    _sigterm_as_interrupt()
     try:
         httpd.serve_forever()
     except KeyboardInterrupt:

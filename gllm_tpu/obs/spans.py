@@ -221,10 +221,14 @@ PEAK_TFLOPS = (("v5 lite", 197.0), ("v5e", 197.0), ("v6", 918.0),
                ("v4", 275.0), ("v3", 123.0))
 
 
-def peak_flops(device_kind: str = "") -> float:
-    """Peak dense bf16 FLOP/s for a device kind string, or 0.0 when
-    unknown (CPU). ``GLLM_TPU_PEAK_TFLOPS`` overrides — also the lever
-    that makes the MFU plumbing testable on CPU."""
+def peak_flops(device) -> float:
+    """Peak dense bf16 FLOP/s of a jax device (``.platform`` and
+    ``.device_kind`` are read). Off the TPU there is no spec sheet and
+    the answer is 0.0 (every MFU field then reads null). A TPU whose kind
+    is not in ``PEAK_TFLOPS`` is an error, not a peak of 0.0 that would
+    silently null the chip's utilization figures.
+    ``GLLM_TPU_PEAK_TFLOPS`` overrides — also the lever that makes the
+    MFU plumbing testable on CPU."""
     ov = os.environ.get("GLLM_TPU_PEAK_TFLOPS")
     if ov:
         try:
@@ -235,10 +239,15 @@ def peak_flops(device_kind: str = "") -> float:
             # override is honored
             logger.warning("ignoring malformed GLLM_TPU_PEAK_TFLOPS=%r",
                            ov)
-    kind = (device_kind or "").lower()
+    kind = device.device_kind.lower()
     for tag, tf in PEAK_TFLOPS:
         if tag in kind:
             return tf * 1e12
+    if device.platform == "tpu":
+        raise ValueError(
+            f"no peak FLOP/s on file for TPU device_kind "
+            f"{device.device_kind!r}: add it to PEAK_TFLOPS "
+            "(gllm_tpu/obs/spans.py) with its source")
     return 0.0
 
 

@@ -82,6 +82,11 @@ def recurrent_gated_delta_step(
     return out, state
 
 
+def gdn_pallas_ok(dk: int, dv: int) -> bool:
+    """Can Mosaic compile the chunk-scan kernel for these head dims?"""
+    return dk % 128 == 0 and dv % 128 == 0
+
+
 @functools.partial(jax.jit, static_argnames=("chunk_size", "impl"))
 def chunk_gated_delta_rule(
     q: jnp.ndarray,          # [S, T, H, Dk]
@@ -152,22 +157,25 @@ def chunk_gated_delta_rule(
               else initial_state.astype(jnp.float32))
 
     if impl == "pallas":
-        backend = jax.default_backend()
-        interpret = backend == "cpu"
-        if interpret or (Dk % 128 == 0 and Dv % 128 == 0):
-            from gllm_tpu.ops.pallas.gdn_scan import gdn_chunk_scan
-            B = S * H
-            out_p, final_p = gdn_chunk_scan(
-                qc.reshape(B, N, C, Dk), kc.reshape(B, N, C, Dk),
-                v2.reshape(B, N, C, Dv), k_cumdecay.reshape(B, N, C, Dk),
-                attn_local.reshape(B, N, C, C),
-                gcum.reshape(B, N, C, 1),
-                state0.reshape(B, Dk, Dv), interpret=interpret)
-            out = out_p.reshape(S, H, N, C, Dv)
-            out = out.transpose(0, 2, 3, 1, 4).reshape(
-                S, T + pad, H, Dv)[:, :T]
-            return out, final_p.reshape(S, H, Dk, Dv)
-        # fall through to XLA when lane alignment rules out Mosaic
+        interpret = jax.default_backend() == "cpu"
+        if not (interpret or gdn_pallas_ok(Dk, Dv)):
+            # the runners resolve `auto` to XLA (with a warning) for such
+            # a model; reaching here means pallas was asked for by name
+            raise NotImplementedError(
+                f"GDN Pallas scan needs 128-lane-aligned head dims, got "
+                f"Dk={Dk} Dv={Dv}; use impl='xla'")
+        from gllm_tpu.ops.pallas.gdn_scan import gdn_chunk_scan
+        B = S * H
+        out_p, final_p = gdn_chunk_scan(
+            qc.reshape(B, N, C, Dk), kc.reshape(B, N, C, Dk),
+            v2.reshape(B, N, C, Dv), k_cumdecay.reshape(B, N, C, Dk),
+            attn_local.reshape(B, N, C, C),
+            gcum.reshape(B, N, C, 1),
+            state0.reshape(B, Dk, Dv), interpret=interpret)
+        out = out_p.reshape(S, H, N, C, Dv)
+        out = out.transpose(0, 2, 3, 1, 4).reshape(
+            S, T + pad, H, Dv)[:, :T]
+        return out, final_p.reshape(S, H, Dk, Dv)
 
     def chunk_step(state, inputs):
         q_i, k_i, v_i, kcd_i, attn_i, g_i = inputs

@@ -42,9 +42,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from gllm_tpu.ops.pallas.paged_kv import (CompilerParams, attend_block,
-                                          kv_stream_specs, make_fetch_fns,
-                                          unpack_refs)
+from gllm_tpu.ops.pallas.paged_kv import (attend_block, kv_stream_specs,
+                                          make_fetch_fns, unpack_refs)
 
 DEFAULT_KV_BLOCK = 256
 
@@ -283,9 +282,9 @@ def paged_decode_attention(
                                        q.dtype),
         # Sequences/groups are independent → let Mosaic split the grid
         # across Megacore TensorCores.
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)) if interpret else
-        CompilerParams(dimension_semantics=("parallel",)),
+        pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(*inputs)
     return out[:S] if s_pad != S else out

@@ -31,8 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from gllm_tpu.ops.pallas.paged_kv import CompilerParams
-
 
 def _kernel(q_ref, k_ref, v2_ref, kcd_ref, attn_ref, g_ref, init_ref,
             out_ref, final_ref, state, *, chunk: int):
@@ -94,7 +92,7 @@ def gdn_chunk_scan(
         scratch_shapes=[pltpu.VMEM((Dk, Dv), jnp.float32)],
         # chunk axis is a sequential scan over the VMEM-resident state;
         # the batch axis is embarrassingly parallel
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(qc, kc, v2, kcd, attn, gcum, init_state)

@@ -19,11 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax<0.5 ships pltpu.TPUCompilerParams; newer jax renamed it to
-# CompilerParams — alias so the kernels run on both
-CompilerParams = getattr(pltpu, "CompilerParams",
-                         getattr(pltpu, "TPUCompilerParams", None))
-
 
 def unpack_refs(refs, shared_kv: bool, quant: bool):
     """Split a kernel's ``*refs`` into its named parts.

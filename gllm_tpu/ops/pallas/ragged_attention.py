@@ -55,9 +55,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from gllm_tpu.ops.pallas.paged_kv import (CompilerParams, block_kv,
-                                          kv_stream_specs, make_fetch_fns,
-                                          unpack_refs)
+from gllm_tpu.ops.pallas.paged_kv import (block_kv, kv_stream_specs,
+                                          make_fetch_fns, unpack_refs)
 
 DEFAULT_KV_BLOCK = 256
 DEFAULT_Q_BLOCK = 128
@@ -187,9 +186,8 @@ def _kernel(cu_ref, kv_lens_ref, pt_ref, first_ref, last_ref,
         shared_kv, ks_hbm=ks_hbm, vs_hbm=vs_hbm, ks_buf=ks_buf,
         vs_buf=vs_buf)
 
-    q_raw = q_ref[...].astype(jnp.float32) * eff_scale    # [BQ, Hq, D]
-
     def _ragged_body():
+        q_raw = q_ref[...].astype(jnp.float32) * eff_scale  # [BQ, Hq, D]
         _ragged_block(q_raw, cu_ref, kv_lens_ref, o_ref, start_fetch,
                       wait_fetch, k_buf, v_buf, ks_buf, vs_buf,
                       t_start=t_start, s0=s0, s1=s1, bk=bk, rows=rows,
@@ -209,11 +207,12 @@ def _kernel(cu_ref, kv_lens_ref, pt_ref, first_ref, last_ref,
     # chunks, the straddling boundary block, tail padding).
     @pl.when(cls_ref[b] == 1)
     def _():
-        _decode_block(q_raw, kv_lens_ref, o_ref, start_fetch, wait_fetch,
+        _decode_block(q_ref, kv_lens_ref, o_ref, start_fetch, wait_fetch,
                       k_buf, v_buf, ks_buf, vs_buf, t_start=t_start,
                       bk=bk, num_kv_heads=num_kv_heads, group=group,
                       head_dim=head_dim, v_dim=v_dim, q_blk=q_blk,
-                      gsz=gsz, shared_kv=shared_kv, mqa=mqa, amla=amla)
+                      gsz=gsz, shared_kv=shared_kv, mqa=mqa, amla=amla,
+                      eff_scale=eff_scale)
 
     @pl.when(cls_ref[b] == 0)
     def _():
@@ -314,11 +313,11 @@ def _ragged_block(q, cu_ref, kv_lens_ref, o_ref, start_fetch, wait_fetch,
     o_ref[...] = out.astype(o_ref.dtype)
 
 
-def _decode_block(q, kv_lens_ref, o_ref, start_fetch, wait_fetch, k_buf,
+def _decode_block(q_ref, kv_lens_ref, o_ref, start_fetch, wait_fetch, k_buf,
                   v_buf, ks_buf, vs_buf, *, t_start, bk: int,
                   num_kv_heads: int, group: int, head_dim: int,
                   v_dim: int, q_blk: int, gsz: int, shared_kv: bool,
-                  mqa: bool, amla: bool):
+                  mqa: bool, amla: bool, eff_scale: float):
     """Decode-class block body: every row r of this q block is its own
     single-token sequence ``t_start + r`` (the guarantee the per-block
     class flag encodes), so the masked ragged dots would waste a BQ×
@@ -326,23 +325,29 @@ def _decode_block(q, kv_lens_ref, o_ref, start_fetch, wait_fetch, k_buf,
     chain per sequence. Instead, process rows in groups of ``gsz`` with
     the grouped decode kernel's round-robin discipline: one buffer slot
     per in-group sequence, up to ``gsz`` page DMAs in flight, each
-    sequence's online-softmax state carried across kv rounds."""
-    for g0 in range(0, q_blk, gsz):
-        gn = min(gsz, q_blk - g0)
-        rows_g = list(range(g0, g0 + gn))
-        seq_ids = [t_start + r for r in rows_g]
-        kv_lens = [kv_lens_ref[t_start + r] for r in rows_g]
+    sequence's online-softmax state carried across kv rounds.
+
+    The groups run as a ROLLED loop (rows are read from ``q_ref`` and
+    written to ``o_ref`` at a traced index): unrolled in Python, the body
+    was emitted ``q_blk`` times and Mosaic took a minute and a half per
+    shape bucket at the default 128-row block."""
+    lead = (num_kv_heads * group,) if mqa else (num_kv_heads, group)
+    kv_axis = 1 if mqa else 2
+
+    def run_group(g0, gn: int):
+        """Rows [g0, g0 + gn) of the block; ``g0`` static or traced."""
+        seq_ids = [t_start + g0 + g for g in range(gn)]
+        kv_lens = [kv_lens_ref[sid] for sid in seq_ids]
         n_blocks = [pl.cdiv(kv_len, bk) for kv_len in kv_lens]
         for g in range(gn):
             @pl.when(n_blocks[g] > 0)
             def _(g=g):
                 start_fetch(g, seq_ids[g], 0)
 
-        lead = (num_kv_heads * group,) if mqa else (num_kv_heads, group)
-        kv_axis = 1 if mqa else 2
         qs = []
         for g in range(gn):
-            qg = q[rows_g[g]]                              # [Hq, D]
+            qg = (q_ref[pl.ds(g0 + g, 1)].astype(jnp.float32)
+                  * eff_scale).reshape(num_kv_heads * group, head_dim)
             qs.append(qg if mqa
                       else qg.reshape(num_kv_heads, group, head_dim))
 
@@ -350,8 +355,7 @@ def _decode_block(q, kv_lens_ref, o_ref, start_fetch, wait_fetch, k_buf,
         for g in range(1, gn):
             max_nb = jnp.maximum(max_nb, n_blocks[g])
 
-        def body(r, carry, *, gn=gn, seq_ids=seq_ids, kv_lens=kv_lens,
-                 n_blocks=n_blocks, qs=qs):
+        def body(r, carry):
             out = list(carry)
             for g in range(gn):
                 m, l, acc = out[3 * g], out[3 * g + 1], out[3 * g + 2]
@@ -403,8 +407,18 @@ def _decode_block(q, kv_lens_ref, o_ref, start_fetch, wait_fetch, k_buf,
         for g in range(gn):
             l, acc = final[3 * g + 1], final[3 * g + 2]
             out = acc / jnp.maximum(l, 1e-30)
-            o_ref[rows_g[g]] = out.reshape(
-                num_kv_heads * group, v_dim).astype(o_ref.dtype)
+            o_ref[pl.ds(g0 + g, 1)] = out.reshape(
+                1, num_kv_heads * group, v_dim).astype(o_ref.dtype)
+
+    n_full, tail = divmod(q_blk, gsz)
+
+    def full_group(gi, carry):
+        run_group(gi * gsz, gsz)
+        return carry
+
+    jax.lax.fori_loop(0, n_full, full_group, 0)
+    if tail:
+        run_group(n_full * gsz, tail)
 
 
 def _decode_prefix_len(cu_q_lens, S: int):
@@ -540,9 +554,9 @@ def ragged_paged_attention(
         out_shape=jax.ShapeDtypeStruct((t_pad, num_q_heads, v_dim),
                                        q.dtype),
         # q blocks are independent → Megacore may split the grid.
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)) if interpret else
-        CompilerParams(dimension_semantics=("parallel",)),
+        pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(*inputs)
     return out[:T]

@@ -10,7 +10,7 @@ Resolution order, most specific wins:
 1. a JSON table named by ``GLLM_TPU_TUNE_TABLE`` (operator override),
 2. the committed ``tables.json`` next to this module (written by
    ``benchmarks/kernel_tune.py --write`` after an on-chip sweep),
-3. the BUILTIN defaults (the empirically safe 128/256 from rounds 1-3).
+3. the BUILTIN defaults (128/256: untuned, known to compile).
 
 Table shape: {device_tag: {kernel: {param: value}}}; ``default`` applies
 to every device. device_tag is ``jax.devices()[0].device_kind`` lowercased
@@ -70,11 +70,7 @@ def _table() -> dict:
 @functools.lru_cache()
 def device_tag() -> str:
     import jax
-    try:
-        kind = jax.devices()[0].device_kind
-    except Exception:
-        return "default"
-    return "_".join(kind.lower().split())
+    return "_".join(jax.devices()[0].device_kind.lower().split())
 
 
 def get(kernel: str) -> dict:

@@ -40,44 +40,9 @@ def make_mesh(dp: int = 1, tp: int = 1, sp: int = 1,
 
 
 def active_mesh():
-    """The mesh bound by the innermost ``mesh_context`` (or None).
-
-    Version shim: newer jax exposes ``jax.sharding.get_abstract_mesh``;
-    0.4.x tracks the ``with mesh:`` context in thread_resources. Both
-    returns carry ``.shape_tuple``."""
-    get_am = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_am is not None:
-        return get_am()
-    from jax._src import mesh as _mesh_lib
-    m = _mesh_lib.thread_resources.env.physical_mesh
-    if m is None or m.empty:
-        return None
-    return m
-
-
-def compat_shard_map(f, *, mesh, in_specs, out_specs, check_vma=True,
-                     axis_names=None):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax: pass through (``mesh=None`` + ``axis_names`` binds the
-    context abstract mesh with only those axes manual). 0.4.x (this
-    image): translate onto ``jax.experimental.shard_map`` — ``check_vma``
-    → ``check_rep``, partial-manual via ``auto`` = the mesh axes NOT in
-    ``axis_names``, and ``mesh=None`` resolves to the context mesh."""
-    try:
-        from jax import shard_map as sm
-        kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return sm(f, **kw)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-        m = mesh if mesh is not None else active_mesh()
-        auto = (frozenset(m.axis_names) - frozenset(axis_names)
-                if axis_names is not None else frozenset())
-        return sm(f, mesh=m, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma, auto=auto)
+    """The abstract mesh bound by the innermost ``mesh_context`` (an
+    empty mesh — falsy ``shape_tuple`` — when none is)."""
+    return jax.sharding.get_abstract_mesh()
 
 
 @contextlib.contextmanager
@@ -85,15 +50,8 @@ def mesh_context(mesh: Optional[Mesh]):
     if mesh is None:
         yield
         return
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    if set_mesh is not None:
-        with set_mesh(mesh):
-            yield
-    else:
-        # jax 0.4.x: Mesh itself is the context manager binding the
-        # active mesh that bare-PartitionSpec sharding constraints read
-        with mesh:
-            yield
+    with jax.sharding.set_mesh(mesh):
+        yield
 
 
 def shard_hint(x, *spec):
@@ -106,7 +64,7 @@ def shard_hint(x, *spec):
       tp=8 stay replicated instead of forcing reshard collectives)
     """
     mesh = active_mesh()
-    if mesh is None or not mesh.shape_tuple:
+    if not mesh.shape_tuple:
         return x
     sizes = dict(mesh.shape_tuple)
 

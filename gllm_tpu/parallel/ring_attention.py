@@ -83,10 +83,8 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     C, Hq, D = q.shape
     if scale is None:
         scale = D ** -0.5
-    if axis_size is not None:                  # static size from the mesh
-        n = axis_size
-    else:                                      # jax >= 0.6 only
-        n = jax.lax.axis_size(axis_name)
+    n = axis_size if axis_size is not None \
+        else jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
 
     pos_q = my * C + jnp.arange(C)
@@ -97,12 +95,9 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     m = jnp.full((C, Hq), -1e30, jnp.float32)
     l = jnp.zeros((C, Hq), jnp.float32)
     # mark the device-constant init values as varying over the ring axis so
-    # the fori_loop carry type matches the per-shard results (pcast is the
-    # vma-era API — 0.4.x shard_map has no vma tracking, nothing to mark)
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is not None:
-        acc, m, l = (pcast(x, (axis_name,), to="varying")
-                     for x in (acc, m, l))
+    # the fori_loop carry type matches the per-shard results
+    acc, m, l = (jax.lax.pcast(x, (axis_name,), to="varying")
+                 for x in (acc, m, l))
 
     def hop(i, carry):
         acc, m, l, k_cur, v_cur = carry
@@ -136,14 +131,15 @@ def ring_attention_sharded(q, k, v, mesh: Optional[Mesh] = None,
     other mesh axes stay GSPMD-auto); a concrete mesh is bound fully
     (standalone / unit-test use). ``kv_valid``: optional replicated scalar
     masking padded keys (see ring_attention)."""
-    from gllm_tpu.parallel.mesh import (active_mesh,
-                                        compat_shard_map as shard_map)
+    from jax import shard_map
+
+    from gllm_tpu.parallel.mesh import active_mesh
 
     spec = P(axis_name, None, None)
     kw = (dict(mesh=None, axis_names={axis_name}) if mesh is None
           else dict(mesh=mesh))
     m = mesh if mesh is not None else active_mesh()
-    sizes = dict(getattr(m, "shape_tuple", None) or m.shape)
+    sizes = dict(m.shape_tuple)
     part = functools.partial(ring_attention, axis_name=axis_name,
                              scale=scale, axis_size=sizes[axis_name])
     if kv_valid is None:

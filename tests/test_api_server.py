@@ -332,6 +332,10 @@ def test_server_info_advertises_topology_and_fast_path(server):
     assert set(par["fast_path"]) == {"overlap_scheduling",
                                      "pipelined_loop", "unified_step",
                                      "spec_fused"}
+    # the device as jax reports it (chip_smoke.py's jax-free parent
+    # reads its verdict's device block from here)
+    assert info["device"] == {"platform": "cpu", "kind": "cpu",
+                              "count": 8, "memory": [None] * 8}
 
 
 @pytest.mark.slow   # builds a real pp=2 engine behind a live HTTP server

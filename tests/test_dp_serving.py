@@ -135,14 +135,6 @@ def test_dp2_tp2_pallas_matches_dp1_xla(ckpt):
     """dp=2 × tp=2 with Pallas attention: the dp axis is manual
     (shard_map), tp stays auto inside and the attention dispatch nests
     its tp shard_map over the context mesh."""
-    import jax
-    if not hasattr(jax, "shard_map"):
-        # jax 0.4.x cannot nest the partial-manual tp shard_map inside
-        # the dp-manual region (the runner raises NotImplementedError,
-        # runner.py _pick_attn_impl) — a version gap, not a regression:
-        # tier-1 must report it as a skip, not a failure, on old-jax
-        # images
-        pytest.skip("dp>1 x tp>1 pallas needs jax.shard_map (jax >= 0.5)")
     rng = np.random.default_rng(9)
     prompts = [[int(x) for x in rng.integers(2, 120, size=int(n))]
                for n in rng.integers(2, 30, size=4)]

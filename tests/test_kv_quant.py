@@ -219,6 +219,8 @@ def test_int8_page_capacity_at_least_1_8x(monkeypatch):
         def memory_stats(self):
             return {"bytes_limit": 1 << 30, "bytes_in_use": 64 << 20}
 
+    # the pool is sized from the device only on a TPU: steer the test
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(jax, "local_devices", lambda: [FakeDev()])
     pages_bf16 = bf16.determine_num_pages()
     pages_int8 = q8.determine_num_pages()

@@ -98,11 +98,14 @@ def test_flops_model_and_peak():
     f4 = fm.block_flops([10], 4)
     singles = sum(fm.step_flops([(1, 10 + j, True)]) for j in range(4))
     assert f4 == pytest.approx(singles)
-    assert peak_flops("TPU v5e") == pytest.approx(197e12)
-    assert peak_flops("weird accelerator") == 0.0
+    from types import SimpleNamespace as Dev
+    assert peak_flops(Dev(platform="tpu", device_kind="TPU v5e")) \
+        == pytest.approx(197e12)
+    assert peak_flops(Dev(platform="cpu", device_kind="cpu")) == 0.0
     os.environ["GLLM_TPU_PEAK_TFLOPS"] = "2.5"
     try:
-        assert peak_flops("anything") == pytest.approx(2.5e12)
+        assert peak_flops(Dev(platform="cpu", device_kind="anything")) \
+            == pytest.approx(2.5e12)
     finally:
         del os.environ["GLLM_TPU_PEAK_TFLOPS"]
 
