@@ -709,16 +709,14 @@ def main():
     # supervisor kills in the sampled pass / report / teardown keeps its
     # WHY, not just its number — the supervisor merges this line into
     # the salvaged JSON.
-    # NOTE window_mfu (the steptrace-window estimator) is deliberately
-    # NOT named "mfu": the result JSON's mfu is the workload-level
-    # model_flops/dt/peak, and a salvage merge must never swap one
-    # definition for the other under the same key mid-trajectory.
+    # (the result JSON's mfu is the workload-level model_flops/dt/peak;
+    # the steptrace-window estimator that rode here as window_mfu went
+    # with the engine loop's per-step FLOPs walk, PR 24)
     print("ATTRIBUTION " + json.dumps({
         "host_ms_by_phase": step_summary.get("host_ms_by_phase"),
         "device_ms_by_kind": step_summary.get("device_ms_by_kind"),
         "overlap_efficiency": step_summary.get("overlap_efficiency"),
         "bubble_frac": step_summary.get("bubble_frac"),
-        "window_mfu": step_summary.get("mfu"),
         # pipelined loop (ISSUE 11): the sustained run-ahead depth and
         # why the loop failed to run further ahead — a salvaged run
         # keeps the bubble story, not just the bubble number
@@ -805,7 +803,6 @@ def main():
                 "overlap_efficiency":
                     step_summary.get("overlap_efficiency"),
                 "bubble_frac": b_on,
-                "window_mfu": step_summary.get("mfu"),
                 "mean_inflight_depth":
                     step_summary.get("mean_inflight_depth"),
                 "loop_stalls": step_summary.get("loop_stalls_by_reason"),

@@ -16,9 +16,9 @@ token ids, finish reason, and usage.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import logging
 import os
-import sys
 import time
 from typing import Callable, List, Optional, Sequence as Seq, Union
 
@@ -27,7 +27,8 @@ from gllm_tpu.config import EngineConfig
 from gllm_tpu.memory_manager import make_memory_manager
 from gllm_tpu.models.config import ModelConfig, from_hf_config
 from gllm_tpu.obs import metrics as obs
-from gllm_tpu.obs.spans import SpanTrace, StepFlopsModel, peak_flops
+from gllm_tpu.obs import spans
+from gllm_tpu.obs.spans import SpanTrace
 from gllm_tpu.obs.steptrace import TRACE
 from gllm_tpu.sampling_params import SamplingParams
 from gllm_tpu.scheduler import Scheduler, SeqOutput
@@ -114,21 +115,12 @@ _M_SPEC_FUSED = obs.counter(
     "gllm_spec_fused_tokens_total",
     "tokens through fused speculation blocks by kind "
     "(accepted|rejected|correction)", ("kind",))
-# Performance attribution (docs/observability.md#tracing): per-step MFU
-# from the obs/spans.py FLOPs model against the device wall, the share
-# of that device wall hidden under host work (1 = never blocked), and
-# the estimated HBM read bandwidth (weights + KV stream / device wall).
-_M_MFU = obs.gauge(
-    "gllm_step_mfu",
-    "model FLOPs utilization of the latest step's device wall "
-    "(0 when the chip peak is unknown)")
+# Performance attribution (docs/observability.md#tracing): the share of
+# the latest step's device wall hidden under host work (1 = never
+# blocked).
 _M_OVERLAP = obs.gauge(
     "gllm_overlap_efficiency",
     "share of the latest step's device wall hidden under host work")
-_M_HBM = obs.gauge(
-    "gllm_step_hbm_gbps",
-    "estimated HBM read bandwidth of the latest step (weights + KV "
-    "stream over the device wall; per-device)")
 # Pipelined loop (config.pipelined_loop,
 # docs/overlap_scheduling.md#pipelined-loop): dispatched-but-uncollected
 # entries after the latest fill pass — the run-ahead depth the loop
@@ -351,15 +343,6 @@ class LLM:
                 "--unified-step is inert for hybrid (GDN) models: "
                 "legacy dispatch and step kinds retained")
         self.futures = FutureMap()
-        # GLLM_TPU_STEP_TIMING=1: generate() records per-iteration collect
-        # latency / batch kind / committed tokens and prints one JSON
-        # summary line to stderr (where the serving wall-clock goes:
-        # dispatch-bound drain tails vs steady-state blocks). Armed only
-        # inside generate(): a serving engine drives step() directly and
-        # must not accumulate unbounded rows nobody will ever print.
-        self._step_timer = None
-        self._step_timing_enabled = (
-            os.environ.get("GLLM_TPU_STEP_TIMING", "0") not in ("", "0"))
         # Encoder disaggregation (gllm_tpu/disagg/): set by init_disagg on
         # LM nodes; monolith engines leave it None.
         self.disagg_coordinator = None
@@ -367,18 +350,11 @@ class LLM:
         # docs/observability.md#tracing): request-scoped spans are gated
         # per ENGINE by config.tracing and recorded on a PER-ENGINE ring
         # — seq_ids restart at 0 per LLM, so a process-global ring would
-        # merge co-resident engines' trees. The step FLOPs model + chip
-        # peak feed the per-step MFU/HBM estimates on steptrace events.
+        # merge co-resident engines' trees.
         self.tracing = bool(getattr(config, "tracing", True))
         self.spans = SpanTrace()
         for s in self.schedulers:
             s.spans = self.spans      # admission opens the span tree
-        try:
-            self._flops_model = StepFlopsModel.from_model_config(
-                model_cfg)
-        except Exception:       # exotic configs: attribution, not audit
-            self._flops_model = None
-        self._peak_flops = peak_flops(jax.devices()[0])
         # monotonic timestamp of the last collect's completion — the
         # lower bound of the next step's device-busy window (device
         # wall = ready - max(dispatched, prev_ready))
@@ -743,8 +719,9 @@ class LLM:
         while len(self._in_flight) < depth:
             # engine-loop phase attribution: everything from here to the
             # runner call is "schedule" wall for the entry this pass
-            # produces (obs/spans.py, docs/observability.md#tracing)
-            t_enter = time.monotonic()
+            # produces (obs/spans.py, docs/observability.md#tracing);
+            # _launch closes it, or the pass's end where nothing launched
+            sched_ph = spans.phase("schedule").start()
             if overlap and self._in_flight:
                 # chain the next decode step(s) off the chain's newest
                 # on-device tokens (overlap scheduling). Slot mode tracks
@@ -772,7 +749,7 @@ class LLM:
                     if isinstance(prev_batch, list):
                         prev_batch = prev_batch[-1]
                     if self._dispatch_reform(prev_batch, prev_handle,
-                                             t_enter, multi, slot_mode,
+                                             sched_ph, multi, slot_mode,
                                              False, mixed=True):
                         continue
                     # re-forming needs host-committed state — fall
@@ -825,41 +802,33 @@ class LLM:
                         # the device fed; the sync path only takes over
                         # when re-forming needs host-committed state.
                         if pipelined and self._dispatch_reform(
-                                prev_batch, prev_handle, t_enter, multi,
+                                prev_batch, prev_handle, sched_ph, multi,
                                 slot_mode, pressure, mixed=unified):
                             continue
                         self._chain_tip = None
                         self._chained_under_pressure = 0
                         ran_dry = True
+                        sched_ph.stop()
                         break
                     if pressure:
                         self._chained_under_pressure += len(chain)
                     self._yield_noted = False
-                    t_sched = time.monotonic()
                     if getattr(chain[0], "spec_block", False):
                         # fused on-device speculation: even a 1-link
                         # chain runs the draft+verify block driver (it
                         # emits up to spec_k+1 tokens per dispatch)
-                        entry = InFlight(
-                            chain, self.runner.step_spec_multi(
-                                chain, prev_handle),
-                            time.monotonic(),
-                            self._entry_phases(t_enter, t_sched),
-                            chained=True)
+                        entry = self._launch(
+                            sched_ph, chain, self.runner.step_spec_multi,
+                            chain, prev_handle, chained=True)
                     elif len(chain) > 1:
-                        entry = InFlight(
-                            chain, self.runner.step_multi(chain,
-                                                          prev_handle),
-                            time.monotonic(),
-                            self._entry_phases(t_enter, t_sched),
-                            chained=True)
+                        entry = self._launch(
+                            sched_ph, chain, self.runner.step_multi,
+                            chain, prev_handle, chained=True)
                     else:
-                        entry = InFlight(
-                            chain[0], self.runner.step_async_chained(
-                                chain[0], prev_handle),
-                            time.monotonic(),
-                            self._entry_phases(t_enter, t_sched),
-                            chained=True)
+                        entry = self._launch(
+                            sched_ph, chain[0],
+                            self.runner.step_async_chained,
+                            chain[0], prev_handle, chained=True)
                     self._in_flight.append(entry)
                     if slot_mode:
                         self._chain_tip = entry.tip
@@ -873,6 +842,7 @@ class LLM:
                     # loop must block on readback before it can proceed
                     self._note_stall("readback")
                 ran_dry = True
+                sched_ph.stop()
                 break
             if (overlap and multi > 1
                     and not self.scheduler.waiting
@@ -904,25 +874,19 @@ class LLM:
                                 [min(d + 1, k) for d in au]
                                 if au is not None else None))
                     chain = [first] + links
-                    t_sched = time.monotonic()
-                    entry = InFlight(chain,
-                                     self.runner.step_spec_multi(chain)
-                                     if spec_chain
-                                     else self.runner.step_multi(chain),
-                                     time.monotonic(),
-                                     self._entry_phases(t_enter, t_sched),
-                                     roots=True)
+                    entry = self._launch(
+                        sched_ph, chain,
+                        self.runner.step_spec_multi if spec_chain
+                        else self.runner.step_multi, chain, roots=True)
                     self._in_flight.append(entry)
                     self._yield_noted = False
                     if slot_mode:
                         self._chain_tip = entry.tip
                     continue
-            t_sched = time.monotonic()
-            entry = InFlight(batch, self.runner.step_async(batch),
-                             time.monotonic(),
-                             self._entry_phases(t_enter, t_sched),
-                             roots=(batch.num_decode == batch.num_seqs
-                                    and not batch.has_drafts))
+            entry = self._launch(
+                sched_ph, batch, self.runner.step_async, batch,
+                roots=(batch.num_decode == batch.num_seqs
+                       and not batch.has_drafts))
             self._in_flight.append(entry)
             if entry.roots:
                 self._yield_noted = False
@@ -969,13 +933,24 @@ class LLM:
             return []
         t0 = time.monotonic()
         tokens, aux = self.runner.collect(handle)
-        extra = None
-        if isinstance(batch, list) and aux.get("finish") is not None:
-            extra = self._ondevice_block_stats(
-                aux["finish"][0][:batch[0].num_seqs])
-        if isinstance(batch, list) and aux.get("spec_counts") is not None:
-            extra = self._spec_block_stats(batch, aux)
-        self._record_step(batch, t0, t_dispatch, extra, phases)
+        # ``output``: everything between the collect and the return —
+        # the step's own record keeping first (it takes the phases
+        # measured up to here, so this span rides with the NEXT event)
+        with spans.phase("output"):
+            extra = None
+            if isinstance(batch, list) and aux.get("finish") is not None:
+                extra = self._ondevice_block_stats(
+                    aux["finish"][0][:batch[0].num_seqs])
+            if isinstance(batch, list) \
+                    and aux.get("spec_counts") is not None:
+                extra = self._spec_block_stats(batch, aux)
+            self._record_step(batch, t0, t_dispatch, extra, phases)
+            return self._commit_step(batch, tokens, aux, extra)
+
+    def _commit_step(self, batch, tokens, aux, extra) -> List[SeqOutput]:
+        """Advance scheduler state by one collected single-runner entry
+        (the ``output`` phase): logprobs, speculation accept runs,
+        process_output, then the shared commit tail."""
         if isinstance(batch, list):
             if aux.get("spec_counts") is not None:
                 # fused speculation block: variable per-sub-step commits
@@ -1039,7 +1014,7 @@ class LLM:
         self._observe_outputs(outs)
         return outs
 
-    def _dispatch_reform(self, prev_batch, prev_handle, t_enter: float,
+    def _dispatch_reform(self, prev_batch, prev_handle, sched_ph,
                          multi: int, slot_mode: bool,
                          pressure: bool, mixed: bool = False) -> bool:
         """Speculatively re-form and dispatch the next batch off
@@ -1082,7 +1057,6 @@ class LLM:
                           for it in batch.items)
         links = (self._schedule_multi_links(batch, multi - 1)
                  if multi > 1 and decode_only else [])
-        t_sched = time.monotonic()
         if links:
             au = links[0].active_until
             k = 1 + len(links)
@@ -1090,18 +1064,14 @@ class LLM:
                 batch, active_until=([min(d + 1, k) for d in au]
                                      if au is not None else None))
             chain = [first] + links
-            entry = InFlight(chain,
-                             self.runner.step_multi(chain, prev_handle),
-                             time.monotonic(),
-                             self._entry_phases(t_enter, t_sched),
-                             chained=True, promises=promises)
+            entry = self._launch(sched_ph, chain, self.runner.step_multi,
+                                 chain, prev_handle, chained=True,
+                                 promises=promises)
         else:
-            entry = InFlight(batch,
-                             self.runner.step_async_chained(batch,
-                                                            prev_handle),
-                             time.monotonic(),
-                             self._entry_phases(t_enter, t_sched),
-                             chained=True, promises=promises)
+            entry = self._launch(sched_ph, batch,
+                                 self.runner.step_async_chained, batch,
+                                 prev_handle, chained=True,
+                                 promises=promises)
         self._in_flight.append(entry)
         self._yield_noted = False
         if pressure:
@@ -1234,41 +1204,17 @@ class LLM:
                        and out.new_token_id in self.eos_token_ids)
                 _M_ONDEV_FINISH.inc(kind="eos" if eos else "stop")
 
-    def _entry_phases(self, t_enter: float, t_sched_end: float) -> dict:
-        """Host-phase walls for one in-flight entry at dispatch time:
-        schedule (engine loop → batch/chain formed) plus the runner's
-        build/dispatch split and its per-dispatch KV-read estimate
-        (``ModelRunner.last_phases``). Seconds; converted to ms when
-        the collect lands (:meth:`_record_step`)."""
-        ph = {"t_enter": t_enter, "schedule": t_sched_end - t_enter}
-        rp = getattr(self.runner, "last_phases", None)
-        if rp:
-            ph.update(rp)
-        return ph
-
-    def _step_flops(self, batch, extra: Optional[dict] = None) -> float:
-        """Matmul-path FLOPs of one collected step (obs/spans.py model;
-        host arithmetic on scheduler counts). Fused blocks count the
-        sub-steps that actually EXECUTED (k_exec under on-device
-        finish) over their live rows."""
-        from gllm_tpu.sequence import HOLE_SEQ_ID
-        fm = self._flops_model
-        if fm is None:
-            return 0.0
-        if isinstance(batch, list):
-            k = (extra or {}).get("k_exec") or len(batch)
-            ctxs = [it.computed_before for it in batch[0].items
-                    if it.seq.seq_id != HOLE_SEQ_ID]
-            f = fm.block_flops(ctxs, k)
-            if getattr(batch[0], "spec_block", False):
-                # fused speculation: each sub-step feeds up to
-                # spec_k+1 verify rows instead of one decode token
-                # (upper bound — garbage draft rows still compute)
-                f *= self.spec_mult
-            return f
-        return fm.step_flops(
-            (it.num_new_tokens, it.computed_before, it.samples)
-            for it in batch.items if it.seq.seq_id != HOLE_SEQ_ID)
+    def _launch(self, sched_ph, batch, dispatch, *args, **flags) -> InFlight:
+        """The one dispatch tail of every fill pass: close the pass's
+        ``schedule`` phase, run the runner call (it times ``build`` and
+        ``dispatch`` itself), and wrap the handle in an in-flight entry
+        that takes the thread's open phase dict with it — everything the
+        loop did since the previous take (obs/spans.take_phases)."""
+        sched_ph.stop()
+        handle = dispatch(*args)
+        phases = spans.take_phases()
+        phases["t_enter"] = sched_ph.t0
+        return InFlight(batch, handle, time.monotonic(), phases, **flags)
 
     def _record_spans(self, batch, t_dispatch: float, now: float,
                       extra: Optional[dict] = None) -> None:
@@ -1306,12 +1252,10 @@ class LLM:
     def _record_step(self, batch, t0: float, t_dispatch: float,
                      extra: Optional[dict] = None,
                      phases: Optional[dict] = None) -> None:
-        """Step-kind attribution for one collected engine iteration:
-        latency/RTT histograms, per-kind counters, one steptrace event
-        — extended with the engine-loop phase breakdown, the device
-        wall attributed back to this step, and the MFU/HBM estimates
-        (docs/observability.md#tracing). Host wall clock only — the
-        handle was already collected."""
+        """Step-kind attribution for one collected single-runner
+        iteration: what kind of step it was and how many tokens it
+        carried; :meth:`_emit_step` does the rest. Host wall clock only
+        — the handle was already collected."""
         now = time.monotonic()
         fused = isinstance(batch, list)
         b = batch[-1] if fused else batch
@@ -1341,19 +1285,8 @@ class LLM:
             kind = ("decode" if b.num_decode == b.num_seqs
                     else "prefill")
             tokens = b.total_tokens
-        wall = now - t0
-        _M_STEP_LAT.observe(wall, kind=kind)
-        _M_RTT.observe(now - t_dispatch, kind=kind)
-        _M_STEPS.inc(kind=kind)
-        _M_STEP_TOKENS.inc(tokens, kind=kind)
-        if kind == "decode" or (kind == "unified_step"
-                                and mix == "decode"):
-            _M_DECODE_STEPS.inc(fused="false")
-        elif fused:
-            _M_DECODE_STEPS.inc(len(batch), fused="true")
+        decode_only = kind == "decode" or mix == "decode"
         ev = dict(num_seqs=b.num_seqs, tokens=tokens,
-                  wall_ms=round(wall * 1e3, 3),
-                  rtt_ms=round((now - t_dispatch) * 1e3, 3),
                   # entries still in flight AFTER this collect — the
                   # run-ahead depth the loop sustained (summarize() →
                   # mean_inflight_depth; bench promotes it)
@@ -1364,66 +1297,79 @@ class LLM:
             ev["mix"] = mix
         if extra:
             ev.update(extra)
+        self._emit_step(kind, ev, [batch], phases, t0, t_dispatch, now,
+                        decode_steps=(len(batch) if fused
+                                      else int(decode_only)),
+                        fused=fused, span_extra=extra)
+
+    def _record_step_dp(self, live, t0: float, t_dispatch: float,
+                        phases: Optional[dict],
+                        inflight: Optional[int] = None) -> None:
+        """One step event for the stacked dp program (all replicas run
+        in it): the sync dp loop and the dp super-step loop record the
+        same fields through the same tail as the single runner."""
+        now = time.monotonic()
+        decode_only = all(b.num_decode == b.num_seqs for b in live)
+        kind = ("unified_step" if self.unified
+                else "decode" if decode_only else "prefill")
+        ev = dict(num_seqs=sum(b.num_seqs for b in live),
+                  tokens=sum(b.total_tokens for b in live),
+                  dp=len(live))
+        if inflight is not None:
+            ev["inflight"] = inflight
+        if self.unified:
+            ev["mix"] = "decode" if decode_only else "mixed"
+        self._emit_step(kind, ev, live, phases, t0, t_dispatch, now,
+                        decode_steps=int(decode_only))
+
+    def _emit_step(self, kind: str, ev: dict, batches, phases, t0: float,
+                   t_dispatch: float, now: float, decode_steps: int = 0,
+                   fused: bool = False,
+                   span_extra: Optional[dict] = None) -> None:
+        """The tail every step path shares (single runner, sync dp, dp
+        super-step — one implementation so they cannot drift): latency /
+        RTT histograms, per-kind counters, the steptrace event with the
+        engine-loop phase breakdown and the device wall attributed back
+        to this step (docs/observability.md#tracing), request spans."""
+        wall = now - t0
+        _M_STEP_LAT.observe(wall, kind=kind)
+        _M_RTT.observe(now - t_dispatch, kind=kind)
+        _M_STEPS.inc(kind=kind)
+        _M_STEP_TOKENS.inc(ev["tokens"], kind=kind)
+        if decode_steps:
+            _M_DECODE_STEPS.inc(decode_steps,
+                                fused="true" if fused else "false")
+        ev["wall_ms"] = round(wall * 1e3, 3)
+        ev["rtt_ms"] = round((now - t_dispatch) * 1e3, 3)
         if phases is not None:
-            # sub-steps that actually EXECUTED: on-device early exit
-            # (k_exec < k) shrinks both the weight re-reads and the KV
-            # stream — the HBM estimate must shrink with them or it
-            # contradicts the k_exec-based MFU on the same step
-            k_sched = len(batch) if fused else 1
-            k_exec = ((extra or {}).get("k_exec") or k_sched) if fused \
-                else 1
-            rd = (phases.get("kv_bytes", 0) * k_exec / k_sched
-                  + getattr(self.runner, "param_bytes", 0) * k_exec)
-            flops = (self._step_flops(batch, extra)
-                     if self._peak_flops else 0.0)
-            self._attach_attribution(ev, phases, wall, now, t_dispatch,
-                                     flops, rd)
+            self._attach_attribution(ev, phases, wall, now, t_dispatch)
         else:
             self._last_ready = now
         TRACE.record(kind, **ev)
         if self.tracing:
-            self._record_spans(batch, t_dispatch, now, extra)
-        timer = self._step_timer
-        if timer is not None:
-            timer.append((wall,
-                          f"decode_block{len(batch)}" if fused
-                          else "decode" if (kind == "decode"
-                                            or mix == "decode")
-                          else "prefill_mixed", tokens))
+            for b in batches:
+                self._record_spans(b, t_dispatch, now, span_extra)
 
     def _attach_attribution(self, ev: dict, phases: dict, wall: float,
-                            now: float, t_dispatch: float,
-                            flops: float, rd_bytes: float) -> None:
-        """Shared attribution tail for a collected step event (single
-        runner AND dp paths — one implementation so they cannot drift):
-        host phase walls, the device wall attributed back to this step
+                            now: float, t_dispatch: float) -> None:
+        """Attribution fields of a collected step event: the host phase
+        walls (the entry's own dict from dispatch time plus what the
+        thread measured since — the collect's ``wait`` / ``readback``)
+        and the device wall attributed back to this step
         (block-until-ready delta at collect, floored by the previous
         collect's completion — before that the device was busy with the
-        OLDER step; no profiler, no extra device round trips), and the
-        MFU / HBM-bandwidth estimates + gauges."""
+        OLDER step; no profiler, no extra device round trips)."""
         dev = max(0.0, now - max(t_dispatch, self._last_ready))
         self._last_ready = now
-        ev["ph"] = {
-            "schedule": round(phases.get("schedule", 0.0) * 1e3, 3),
-            "build": round(phases.get("build", 0.0) * 1e3, 3),
-            "dispatch": round(phases.get("dispatch", 0.0) * 1e3, 3),
-            "collect": round(wall * 1e3, 3),
-        }
+        merged = dict(phases)
+        for name, sec in spans.take_phases().items():
+            merged[name] = merged.get(name, 0.0) + sec
+        ev.update(spans.step_phases(merged))
         ev["step_wall_ms"] = round(
             (now - phases.get("t_enter", t_dispatch)) * 1e3, 3)
         ev["dev_ms"] = round(dev * 1e3, 3)
-        if dev <= 0:
-            return
-        _M_OVERLAP.set(round(max(0.0, dev - wall) / dev, 4))
-        if flops and self._peak_flops:
-            # 6 digits, matching summarize()'s window rounding: a
-            # compile-absorbed step's true MFU sits below 1e-4 and
-            # must not floor to 0
-            ev["mfu"] = round(flops / dev / self._peak_flops, 6)
-            _M_MFU.set(ev["mfu"])
-        if rd_bytes:
-            ev["hbm_gbps"] = round(rd_bytes / dev / 1e9, 2)
-            _M_HBM.set(ev["hbm_gbps"])
+        if dev > 0:
+            _M_OVERLAP.set(round(max(0.0, dev - wall) / dev, 4))
 
     def _observe_outputs(self, outs) -> None:
         """Per-request latency bookkeeping over one iteration's outputs
@@ -1510,52 +1456,26 @@ class LLM:
     def _step_dp(self) -> List[SeqOutput]:
         """One synchronous step over all DP replicas (single jit program;
         idle replicas run dummy batches inside it)."""
-        t_enter = time.monotonic()
+        sched_ph = spans.phase("schedule").start()
         batches = [s.schedule_once() for s in self.schedulers]
+        sched_ph.stop()
         if all(b is None for b in batches):
             return []
         faults.FAULTS.maybe_stall("dispatch_stall")
         faults.FAULTS.maybe_raise("step_exception")
-        t_sched = t_dispatch = time.monotonic()
+        t_dispatch = time.monotonic()
         handle = self.runner.step_async_dp(batches)
+        phases = spans.take_phases()
+        phases["t_enter"] = sched_ph.t0
         t0 = time.monotonic()
         rows, auxes = self.runner.collect_dp(handle)
         live = [b for b in batches if b is not None]
-        # one step event for the stacked program (all replicas run in it)
-        now = time.monotonic()
-        decode_only = all(b.num_decode == b.num_seqs for b in live)
-        kind = ("unified_step" if self.unified
-                else "decode" if decode_only else "prefill")
-        tokens = sum(b.total_tokens for b in live)
-        _M_STEP_LAT.observe(now - t0, kind=kind)
-        _M_RTT.observe(now - t_dispatch, kind=kind)
-        _M_STEPS.inc(kind=kind)
-        _M_STEP_TOKENS.inc(tokens, kind=kind)
-        if decode_only:
-            _M_DECODE_STEPS.inc(fused="false")
-        # same attribution fields as the single-runner path — the
-        # shared helper keeps the two call sites from drifting (the dp
-        # step is synchronous: device wall ≈ collect block)
-        ph = self._entry_phases(t_enter, t_sched)
-        ev = dict(num_seqs=sum(b.num_seqs for b in live),
-                  tokens=tokens, wall_ms=round((now - t0) * 1e3, 3),
-                  rtt_ms=round((now - t_dispatch) * 1e3, 3),
-                  dp=len(live))
-        if self.unified:
-            ev["mix"] = "decode" if decode_only else "mixed"
-        flops = (sum(self._step_flops(b) for b in live)
-                 if self._peak_flops else 0.0)
-        rd = (ph.get("kv_bytes", 0)
-              + getattr(self.runner, "param_bytes", 0))
-        self._attach_attribution(ev, ph, now - t0, now, t_dispatch,
-                                 flops, rd)
-        TRACE.record(kind, **ev)
-        if self.tracing:
-            for b in live:
-                self._record_spans(b, t_dispatch, now)
-        outs = self._dp_process_outputs(batches, rows, auxes)
-        self._check_stop_strings(outs)
-        self._observe_outputs(outs)
+        with spans.phase("output"):
+            # the dp step is synchronous: device wall ≈ collect block
+            self._record_step_dp(live, t0, t_dispatch, phases)
+            outs = self._dp_process_outputs(batches, rows, auxes)
+            self._check_stop_strings(outs)
+            self._observe_outputs(outs)
         return outs
 
     def _dp_process_outputs(self, batches, rows, auxes) -> List[SeqOutput]:
@@ -1609,7 +1529,7 @@ class LLM:
         unified = self.unified
         ran_dry = False
         while len(self._in_flight) < depth:
-            t_enter = time.monotonic()
+            sched_ph = spans.phase("schedule").start()
             tip = self._in_flight[-1] if self._in_flight else None
             if tip is not None and tip.invalid:
                 # an invalidated super-step can never be a tip — the
@@ -1648,14 +1568,13 @@ class LLM:
                             self.schedulers[r].discard_batch(b)
                     self._note_stall(stall or "readback")
                     ran_dry = True
+                    sched_ph.stop()
                     break
-                t_sched = time.monotonic()
-                entry = InFlight(DPBatches(nxt),
-                                 self.runner.step_async_dp(
-                                     nxt, prev_handle=tip.handle),
-                                 time.monotonic(),
-                                 self._entry_phases(t_enter, t_sched),
-                                 chained=True, promises=promises)
+                entry = self._launch(
+                    sched_ph, DPBatches(nxt),
+                    functools.partial(self.runner.step_async_dp,
+                                      prev_handle=tip.handle), nxt,
+                    chained=True, promises=promises)
                 self._in_flight.append(entry)
                 continue
             batches = [s.schedule_once() for s in self.schedulers]
@@ -1665,13 +1584,11 @@ class LLM:
                                 for s in self.schedulers)):
                     self._note_stall("readback")
                 ran_dry = True
+                sched_ph.stop()
                 break
-            t_sched = time.monotonic()
-            entry = InFlight(DPBatches(batches),
-                             self.runner.step_async_dp(batches),
-                             time.monotonic(),
-                             self._entry_phases(t_enter, t_sched),
-                             roots=True)
+            entry = self._launch(sched_ph, DPBatches(batches),
+                                 self.runner.step_async_dp, batches,
+                                 roots=True)
             self._in_flight.append(entry)
         _M_INFLIGHT.set(len(self._in_flight))
         if not ran_dry and len(self._in_flight) >= depth:
@@ -1694,36 +1611,11 @@ class LLM:
         t0 = time.monotonic()
         rows, auxes = self.runner.collect_dp(entry.handle)
         live = [b for b in batches if b is not None]
-        now = time.monotonic()
-        decode_only = all(b.num_decode == b.num_seqs for b in live)
-        kind = ("unified_step" if self.unified
-                else "decode" if decode_only else "prefill")
-        tokens = sum(b.total_tokens for b in live)
-        _M_STEP_LAT.observe(now - t0, kind=kind)
-        _M_RTT.observe(now - entry.t_dispatch, kind=kind)
-        _M_STEPS.inc(kind=kind)
-        _M_STEP_TOKENS.inc(tokens, kind=kind)
-        if decode_only:
-            _M_DECODE_STEPS.inc(fused="false")
-        ph = entry.phases or {}
-        ev = dict(num_seqs=sum(b.num_seqs for b in live), tokens=tokens,
-                  wall_ms=round((now - t0) * 1e3, 3),
-                  rtt_ms=round((now - entry.t_dispatch) * 1e3, 3),
-                  dp=len(live), inflight=len(self._in_flight) + 1)
-        if self.unified:
-            ev["mix"] = "decode" if decode_only else "mixed"
-        flops = (sum(self._step_flops(b) for b in live)
-                 if self._peak_flops else 0.0)
-        rd = (ph.get("kv_bytes", 0)
-              + getattr(self.runner, "param_bytes", 0))
-        self._attach_attribution(ev, ph, now - t0, now,
-                                 entry.t_dispatch, flops, rd)
-        TRACE.record(kind, **ev)
-        if self.tracing:
-            for b in live:
-                self._record_spans(b, entry.t_dispatch, now)
-        outs = self._dp_process_outputs(batches, rows, auxes)
-        return self._commit_outputs(outs)
+        with spans.phase("output"):
+            self._record_step_dp(live, t0, entry.t_dispatch, entry.phases,
+                                 inflight=len(self._in_flight) + 1)
+            outs = self._dp_process_outputs(batches, rows, auxes)
+            return self._commit_outputs(outs)
 
     def _record_logprobs(self, batch, aux) -> None:
         """Attach per-token logprobs from the step's aux arrays to their
@@ -1930,44 +1822,15 @@ class LLM:
         for s in seqs:
             self.add_seq(s)
 
-        if self._step_timing_enabled:
-            self._step_timer = []
-            t_gen = time.monotonic()
-        try:
-            while self.has_unfinished:
-                for out in self.step():
-                    if out.new_token_id is not None \
-                            and self.tokenizer is not None:
-                        self._stream_detokenize(out.seq)
-                    if stream_cb is not None and out.new_token_id is not None:
-                        stream_cb(out)
-            if self._step_timer is not None:
-                self._print_step_timing(time.monotonic() - t_gen)
-        finally:
-            self._step_timer = None
+        while self.has_unfinished:
+            for out in self.step():
+                if out.new_token_id is not None \
+                        and self.tokenizer is not None:
+                    self._stream_detokenize(out.seq)
+                if stream_cb is not None and out.new_token_id is not None:
+                    stream_cb(out)
 
         return [self._finalize(s) for s in seqs]
-
-    def _print_step_timing(self, wall_s: float) -> None:
-        import json as _json
-        rows = self._step_timer
-        by_kind: dict = {}
-        for dt, kind, toks in rows:
-            e = by_kind.setdefault(kind, [0, 0.0, 0])
-            e[0] += 1
-            e[1] += dt
-            e[2] += toks
-        summary = {
-            "wall_s": round(wall_s, 2),
-            "iters": len(rows),
-            "collect_s": round(sum(r[0] for r in rows), 2),
-            "kinds": {k: {"iters": v[0], "collect_s": round(v[1], 2),
-                          "tokens": v[2],
-                          "ms_per_iter": round(v[1] / v[0] * 1e3, 1)}
-                      for k, v in sorted(by_kind.items())},
-        }
-        print("[step timing] " + _json.dumps(summary), file=sys.stderr,
-              flush=True)
 
     def chat(self, messages: List[dict],
              sampling_params: Optional[SamplingParams] = None,

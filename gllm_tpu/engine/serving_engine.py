@@ -59,6 +59,7 @@ from typing import List, Optional
 from gllm_tpu import faults
 from gllm_tpu.engine.llm import LLM
 from gllm_tpu.obs import metrics as obs
+from gllm_tpu.obs.spans import phase
 from gllm_tpu.obs.steptrace import TRACE
 from gllm_tpu.sampling_params import SamplingParams
 
@@ -78,6 +79,16 @@ _M_REJECTED = obs.counter(
 _M_DEADLINE = obs.counter(
     "gllm_request_deadline_exceeded_total",
     "requests aborted because their wall-clock deadline/TTL expired")
+# The HTTP front's own time (docs/observability.md): what a request
+# waits for OUTSIDE the engine's steps, on the interpreter the handler
+# threads share with the engine thread. Observed where it happens, in
+# the intake drain (its twin, the emit lag of a token, is observed by
+# the handler thread: api_server._sse).
+_M_ADMIT_LAG = obs.histogram(
+    "gllm_http_admit_lag_seconds",
+    "request body read to llm.add_seq returning in the engine loop: "
+    "parse, validation, tokenisation, the intake queue",
+    buckets=obs.FAST_LATENCY_BUCKETS)
 _M_STEP_FAIL = obs.counter(
     "gllm_engine_step_failures_total",
     "engine iterations that raised (each quarantines its batch)")
@@ -135,6 +146,10 @@ class StreamChunk:
     # transient (a request dropped as not-replay-safe during a
     # supervised recovery): the client may resubmit after this long
     retry_after: Optional[float] = None
+    # time.monotonic() of the engine step that queued this chunk, on one
+    # token in EMIT_LAG_EVERY (0.0 on the others): the handler thread
+    # observes gllm_http_emit_lag_seconds against it
+    t_deliver: float = 0.0
 
 
 class RequestHandle:
@@ -178,10 +193,18 @@ class RequestHandle:
                 return
 
 
+# A stream's first token and every EMIT_LAG_EVERY-th after it carry the
+# stamp: a sample is all a percentile needs, and 32 handler threads each
+# observing every token is Python work on the interpreter the engine
+# thread shares with them.
+EMIT_LAG_EVERY = 8
+
+
 def deliver_output(llm: LLM, out, handle: RequestHandle,
-                   emitted: dict) -> None:
+                   emitted: dict, now: float = 0.0) -> None:
     """Turn one SeqOutput into a StreamChunk on the request's queue
-    (shared by the single-host and multi-host serving engines)."""
+    (shared by the single-host and multi-host serving engines). ``now``:
+    the step's time.monotonic(), for the emit-lag stamp."""
     text = ""
     final_text = None
     if llm.tokenizer is not None:
@@ -208,7 +231,9 @@ def deliver_output(llm: LLM, out, handle: RequestHandle,
             logprob=lp,
             prompt_logprobs=(out.seq.prompt_logprobs
                              if out.finish_reason else None),
-            final_text=final_text))
+            final_text=final_text,
+            t_deliver=(now if out.seq.num_output_tokens
+                       % EMIT_LAG_EVERY == 1 else 0.0)))
     if out.finish_reason is not None:
         emitted.pop(out.seq.seq_id, None)
 
@@ -444,7 +469,10 @@ class ServingEngine:
                mm_input: Optional[dict] = None,
                disagg_items: Optional[list] = None,
                target_dp: Optional[int] = None,
-               deadline_s: Optional[float] = None) -> RequestHandle:
+               deadline_s: Optional[float] = None,
+               received_t: Optional[float] = None) -> RequestHandle:
+        """``received_t``: time.monotonic() at which the front end had
+        read the request's body (gllm_http_admit_lag_seconds)."""
         sampling_params.validate()
         self._admit()
         mm_state = None
@@ -462,6 +490,7 @@ class ServingEngine:
         with self._lock:
             seq = self.llm._allocate_seq(token_ids, sampling_params)
             seq.mm = mm_state
+            seq.received_t = received_t
             if target_dp is not None:
                 # per-DP-endpoint pinning (reference --endpoint-per-dp,
                 # llm_engine.py:121-133 + sequence.py:79-83): the endpoint
@@ -517,7 +546,8 @@ class ServingEngine:
                             committed_ids: List[int],
                             sampling_params: SamplingParams,
                             deadline_s: Optional[float] = None,
-                            target_dp: Optional[int] = None
+                            target_dp: Optional[int] = None,
+                            received_t: Optional[float] = None
                             ) -> RequestHandle:
         """Cross-replica failover continuation (docs/robustness.md#fleet
         -topology--failover): resume a retry-safe stream another replica
@@ -545,6 +575,7 @@ class ServingEngine:
                                         committed_ids, sampling_params)
             if target_dp is not None:
                 seq.target_dp = target_dp
+            seq.received_t = received_t
             handle = RequestHandle(seq.seq_id, len(prompt_ids),
                                    engine=self)
             self._handles[seq.seq_id] = handle
@@ -694,37 +725,15 @@ class ServingEngine:
             # runner/driver fault would — exercises the supervised
             # rebuild, not the batch quarantine
             faults.FAULTS.maybe_raise("engine_hard_crash")
-            drained = False
-            while True:
-                try:
-                    seq = self._intake.get_nowait()
-                except queue.Empty:
-                    break
-                if self._seqs.get(seq.seq_id) is not seq:
-                    # a recovery partition cleared/re-keyed this request
-                    # while its submit raced the trigger (the put landed
-                    # after the partition's intake drain): the journal
-                    # replay owns it now — admitting the stale
-                    # old-engine Sequence would compute it twice, and
-                    # its old seq id can collide with a rebuilt-engine
-                    # id (identity check, not membership: a replayed
-                    # request may hold the same id on a NEW Sequence)
-                    continue
-                try:
-                    items = getattr(seq, "_disagg_items", None)
-                    if items is not None:
-                        llm.submit_disagg(seq, items)
-                    else:
-                        llm.add_seq(seq)
-                except ValueError as e:
-                    self._deliver_error(seq.seq_id, "error", str(e))
-                drained = True
-            self._drain_push_work(llm)
-            self._expire_deadlines()
+            with phase("intake"):
+                drained = self._drain_intake(llm)
+                self._drain_push_work(llm)
+                self._expire_deadlines()
             if not llm.has_unfinished:
                 if not drained:
-                    self._wake.wait(timeout=0.05)
-                    self._wake.clear()
+                    with phase("idle"):
+                        self._wake.wait(timeout=0.05)
+                        self._wake.clear()
                 continue
             try:
                 outputs = llm.step()
@@ -740,29 +749,68 @@ class ServingEngine:
                 # handles; delivering now would corrupt their streams
                 return
             self._failed_steps = 0
-            for out in outputs:
-                handle = self._handles.get(out.seq.seq_id)
-                if handle is None:
-                    continue
-                deliver_output(llm, out, handle, self._emitted)
-                if self._journal is not None:
-                    if out.new_token_id is not None:
-                        # DELIVERED = committed: replay continues from
-                        # exactly what the client's stream already holds
-                        self._journal.commit(out.seq.seq_id,
-                                             out.new_token_id)
-                    if out.finish_reason is not None:
-                        self._journal.pop(out.seq.seq_id)
+            with phase("deliver"):
+                self._deliver(llm, outputs)
+                # aborted sequences never produce a SeqOutput → close
+                # their streams here
+                self._reap_aborted()
+
+    def _drain_intake(self, llm) -> bool:
+        """Admit everything the front end has queued (the ``intake``
+        phase). True if anything was taken off the queue."""
+        drained = False
+        while True:
+            try:
+                seq = self._intake.get_nowait()
+            except queue.Empty:
+                return drained
+            if self._seqs.get(seq.seq_id) is not seq:
+                # a recovery partition cleared/re-keyed this request
+                # while its submit raced the trigger (the put landed
+                # after the partition's intake drain): the journal
+                # replay owns it now — admitting the stale
+                # old-engine Sequence would compute it twice, and
+                # its old seq id can collide with a rebuilt-engine
+                # id (identity check, not membership: a replayed
+                # request may hold the same id on a NEW Sequence)
+                continue
+            try:
+                items = getattr(seq, "_disagg_items", None)
+                if items is not None:
+                    llm.submit_disagg(seq, items)
+                else:
+                    llm.add_seq(seq)
+            except ValueError as e:
+                self._deliver_error(seq.seq_id, "error", str(e))
+            received_t = getattr(seq, "received_t", None)
+            if received_t is not None:
+                _M_ADMIT_LAG.observe(time.monotonic() - received_t)
+            drained = True
+
+    def _deliver(self, llm, outputs) -> None:
+        """One step's outputs to their handles and the journal (the
+        ``deliver`` phase)."""
+        now = time.monotonic()
+        for out in outputs:
+            handle = self._handles.get(out.seq.seq_id)
+            if handle is None:
+                continue
+            deliver_output(llm, out, handle, self._emitted, now)
+            if self._journal is not None:
+                if out.new_token_id is not None:
+                    # DELIVERED = committed: replay continues from
+                    # exactly what the client's stream already holds
+                    self._journal.commit(out.seq.seq_id,
+                                         out.new_token_id)
                 if out.finish_reason is not None:
-                    with self._lock:
-                        self._handles.pop(out.seq.seq_id, None)
-                        self._seqs.pop(out.seq.seq_id, None)
-                        self._deadlines.pop(out.seq.seq_id, None)
-                        _M_ACTIVE.set(len(self._handles))
-                    self._emitted.pop(out.seq.seq_id, None)
-            # aborted sequences never produce a SeqOutput → close their
-            # streams here
-            self._reap_aborted()
+                    self._journal.pop(out.seq.seq_id)
+            if out.finish_reason is not None:
+                with self._lock:
+                    self._handles.pop(out.seq.seq_id, None)
+                    self._seqs.pop(out.seq.seq_id, None)
+                    self._deadlines.pop(out.seq.seq_id, None)
+                    _M_ACTIVE.set(len(self._handles))
+                self._emitted.pop(out.seq.seq_id, None)
 
     # ---- fault isolation ---------------------------------------------------
 

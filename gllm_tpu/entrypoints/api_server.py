@@ -33,8 +33,22 @@ from gllm_tpu.config import (CacheConfig, EngineConfig, ParallelConfig,
 from gllm_tpu.engine.llm import LLM
 from gllm_tpu.engine.serving_engine import RequestRejected, ServingEngine
 from gllm_tpu.entrypoints import protocol as proto
+from gllm_tpu.obs import metrics as obs_metrics
 
 logger = logging.getLogger(__name__)
+
+# How long a committed token waits for the socket: deliver_output's stamp
+# on the chunk (engine thread; a stream's first token and every eighth
+# after it) to this thread's flush of the SSE event that carries it. The
+# handler threads share one interpreter with the engine thread; this is
+# their share of the gap between tokens.
+_M_EMIT_LAG = obs_metrics.histogram(
+    "gllm_http_emit_lag_seconds",
+    "deliver_output's stamp of a token to the handler thread's flush of "
+    "the SSE chunk that carries it (a stream's first token and every "
+    "eighth after it)",
+    buckets=(5e-5, 1e-4, 2e-4, 5e-4, 1e-3, 2e-3, 5e-3, 1e-2, 2e-2, 5e-2,
+             0.1))
 
 
 class ServerState:
@@ -205,6 +219,9 @@ class Handler(BaseHTTPRequestHandler):
     def _read_json(self) -> dict:
         length = int(self.headers.get("Content-Length", 0))
         raw = self.rfile.read(length) if length else b"{}"
+        # the instant the request's body was in hand: admit lag is
+        # measured from here (gllm_http_admit_lag_seconds)
+        self._t_body = time.monotonic()
         try:
             d = json.loads(raw)
         except json.JSONDecodeError as e:
@@ -220,9 +237,14 @@ class Handler(BaseHTTPRequestHandler):
         self.send_header("Connection", "close")
         self.end_headers()
 
-    def _sse(self, obj) -> None:
+    def _sse(self, obj, chunk=None) -> None:
+        """One SSE event, flushed. ``chunk``: the StreamChunk it carries;
+        its ``t_deliver`` stamp (set by deliver_output on the engine
+        thread) to this flush is the emit lag of the token."""
         self.wfile.write(b"data: " + json.dumps(obj).encode() + b"\n\n")
         self.wfile.flush()
+        if chunk is not None and chunk.t_deliver:
+            _M_EMIT_LAG.observe(time.monotonic() - chunk.t_deliver)
 
     # ---- routes -----------------------------------------------------------
 
@@ -269,7 +291,6 @@ class Handler(BaseHTTPRequestHandler):
             # request-latency histograms (TTFT/TPOT/ITL/e2e/queue),
             # per-step-kind counters, scheduler/KV gauges. Pure host
             # state — scraping never touches the device.
-            from gllm_tpu.obs import metrics as obs_metrics
             self._text(obs_metrics.render(),
                        "text/plain; version=0.0.4; charset=utf-8")
         elif self.path.split("?", 1)[0] == "/steptrace":
@@ -289,9 +310,13 @@ class Handler(BaseHTTPRequestHandler):
             kinds = [k for part in q.get("kind", [])
                      for k in part.split(",") if k]
             events = TRACE.events(since=since, kinds=kinds or None)
+            # ``t0``: the ring's epoch on time.monotonic() — an event's
+            # ``t`` plus it is an instant on the clock /start_profile and
+            # /stop_profile answer with (one machine, one clock)
             self._json({"events": events,
                         "dropped": TRACE.dropped,
                         "next_since": TRACE.mark(),
+                        "t0": TRACE.t0,
                         "summary": summarize(events)})
         elif self.path.split("?", 1)[0] == "/trace":
             # Chrome trace-event JSON (Perfetto / chrome://tracing
@@ -454,7 +479,8 @@ class Handler(BaseHTTPRequestHandler):
                 handles.append(st.engine.submit(list(ids), sp,
                                                 mm_input=mm_input,
                                                 disagg_items=disagg_items,
-                                                target_dp=st.pin_dp))
+                                                target_dp=st.pin_dp,
+                                                received_t=self._t_body))
         except Exception:
             # partial submit must not leak running sequences: abort the
             # choices already admitted before re-raising
@@ -510,7 +536,7 @@ class Handler(BaseHTTPRequestHandler):
                     done += 1
                     first_err = first_err or c[1]
                     continue
-                self._sse(make_chunk(c.text or "", c.finish_reason, i))
+                self._sse(make_chunk(c.text or "", c.finish_reason, i), c)
                 if c.finish_reason in ("error", "abort", "deadline") \
                         and (c.error or c.retry_after is not None):
                     self._sse(proto.stream_error_event(
@@ -667,12 +693,13 @@ class Handler(BaseHTTPRequestHandler):
         if cont is not None:
             handle = st.engine.submit_continuation(
                 ids, cont.get("committed_token_ids", []), req.sampling,
-                target_dp=st.pin_dp)
+                target_dp=st.pin_dp, received_t=self._t_body)
         else:
             handle = st.engine.submit(list(ids), req.sampling,
                                       mm_input=mm_input,
                                       disagg_items=disagg_items,
-                                      target_dp=st.pin_dp)
+                                      target_dp=st.pin_dp,
+                                      received_t=self._t_body)
         if req.stream and parse_tools:
             # Incremental tool streaming (reference streams tool deltas):
             # text deltas flow through live; only potential-markup suffixes
@@ -779,10 +806,12 @@ class Handler(BaseHTTPRequestHandler):
             if cont is not None:
                 handle = st.engine.submit_continuation(
                     ids, cont.get("committed_token_ids", []),
-                    req.sampling, target_dp=st.pin_dp)
+                    req.sampling, target_dp=st.pin_dp,
+                    received_t=self._t_body)
             else:
                 handle = st.engine.submit(ids, req.sampling,
-                                          target_dp=st.pin_dp)
+                                          target_dp=st.pin_dp,
+                                          received_t=self._t_body)
             preamble = []
             if router is not None:
                 preamble.append(self._router_preamble(
@@ -876,7 +905,7 @@ class Handler(BaseHTTPRequestHandler):
                         pushed_pages = self.state.engine.push_prefix(
                             prompt_ids or [], push_to)
                         ev["gllm"]["pushed_pages"] = int(pushed_pages)
-                self._sse(ev)
+                self._sse(ev, chunk)
                 if chunk.finish_reason in ("error", "abort", "deadline") \
                         and (chunk.error
                              or chunk.retry_after is not None):
@@ -892,18 +921,53 @@ class Handler(BaseHTTPRequestHandler):
 
     # ---- profiler (reference profiler_mixin.py:12-117) --------------------
 
-    def _profile(self, start: bool):
+    def _start_capture(self):
+        """Start the profiler the way this server always does: into
+        ``GLLM_PROFILE_DIR``, with the Python tracer OFF (jax's default
+        traces every Python call of the engine thread and of every
+        handler thread: it inflates the host time the capture is taken
+        to measure, and the read-back at the stop) and the host tracer
+        at level 1, the lowest that records TraceAnnotations; then turn
+        the engine-loop phases into ``gllm:*`` spans (obs/spans.py).
+        Returns (trace_dir, time.monotonic() at the start). Caller holds
+        ``_profile_mu``."""
         import jax
+        from gllm_tpu.obs import spans
+        trace_dir = os.environ.get("GLLM_PROFILE_DIR",
+                                   "/tmp/gllm_tpu_profile")
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 1
+        jax.profiler.start_trace(trace_dir, profiler_options=opts)
+        spans.set_capture(True)
+        return trace_dir, time.monotonic()
+
+    @staticmethod
+    def _stop_capture() -> float:
+        """Spans off first, so that no phase opens an annotation the
+        stopped profiler would never close. Returns time.monotonic() at
+        the stop (before the trace is written). Caller holds
+        ``_profile_mu``."""
+        import jax
+        from gllm_tpu.obs import spans
+        spans.set_capture(False)
+        t_stop = time.monotonic()
+        jax.profiler.stop_trace()
+        return t_stop
+
+    def _profile(self, start: bool):
         st = self.state
         with st._profile_mu:
             if start and not st._profiling:
-                import os
-                trace_dir = os.environ.get("GLLM_PROFILE_DIR",
-                                           "/tmp/gllm_tpu_profile")
-                jax.profiler.start_trace(trace_dir)
+                trace_dir, t_start = self._start_capture()
                 st._profiling = True
+                # ``t_monotonic``: the server's time.monotonic() at the
+                # start (at the stop below) — a caller on the same
+                # machine places the slice on its own clock by it, not
+                # by the request's round trip
                 self._json({"status": "profiling started",
-                            "trace_dir": trace_dir})
+                            "trace_dir": trace_dir,
+                            "t_monotonic": t_start})
             elif not start and st._profiling_oneshot:
                 # a POST /profile capture owns the profiler right now —
                 # stopping it here would truncate that capture and make
@@ -912,9 +976,12 @@ class Handler(BaseHTTPRequestHandler):
                     "a one-shot /profile capture is in progress", 409),
                     code=409)
             elif not start and st._profiling:
-                jax.profiler.stop_trace()
-                st._profiling = False
-                self._json({"status": "profiling stopped"})
+                try:
+                    t_stop = self._stop_capture()
+                finally:
+                    st._profiling = False
+                self._json({"status": "profiling stopped",
+                            "t_monotonic": t_stop})
             else:
                 self._json({"status": "noop"})
 
@@ -924,10 +991,7 @@ class Handler(BaseHTTPRequestHandler):
         untouched), stop, return the artifact directory. The
         start/stop pair above remains for manual bracketing; this is
         the capture-and-return call a bench/ops script wants."""
-        import os
-        import time as _time
         from urllib.parse import parse_qs, urlparse
-        import jax
         st = self.state
         q = parse_qs(urlparse(self.path).query)
         try:
@@ -945,8 +1009,6 @@ class Handler(BaseHTTPRequestHandler):
                 "a profile capture is already running", 409), code=409)
             return
         try:
-            trace_dir = os.environ.get("GLLM_PROFILE_DIR",
-                                       "/tmp/gllm_tpu_profile")
             # check + start atomically vs /start_profile (_profile_mu):
             # a racing manual start must not double-start the profiler
             with st._profile_mu:
@@ -957,18 +1019,19 @@ class Handler(BaseHTTPRequestHandler):
                     return
                 st._profiling = True
                 st._profiling_oneshot = True
-                jax.profiler.start_trace(trace_dir)
+                trace_dir, t_start = self._start_capture()
             try:
-                _time.sleep(seconds)
+                time.sleep(seconds)
             finally:
                 with st._profile_mu:
                     try:
-                        jax.profiler.stop_trace()
+                        t_stop = self._stop_capture()
                     finally:
                         st._profiling = False
                         st._profiling_oneshot = False
             self._json({"status": "ok", "seconds": seconds,
-                        "trace_dir": trace_dir})
+                        "trace_dir": trace_dir,
+                        "t_monotonic": [t_start, t_stop]})
         finally:
             st._profile_lock.release()
 

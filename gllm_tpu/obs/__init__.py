@@ -12,8 +12,9 @@ no effect on jit cache keys):
   bench.py's metrics snapshot. ``python -m gllm_tpu.obs.dump
   trace.jsonl`` pretty-prints a saved trace.
 - ``gllm_tpu.obs.spans``: the performance-attribution layer — per-request
-  span trees, the step FLOPs model behind ``gllm_step_mfu``, and the
-  Chrome trace-event converter behind ``GET /trace`` and ``obs.dump
+  span trees, the engine-loop phase clock (``phase``: steptrace ``ph``
+  on the host clock, ``gllm:*`` TraceAnnotations while a capture runs),
+  and the Chrome trace-event converter behind ``GET /trace`` and ``obs.dump
   --format chrome`` (docs/observability.md#tracing--attribution).
 
 Every round-5 finding (unfused decode steps at 8x the fused latency, the

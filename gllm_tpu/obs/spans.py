@@ -1,5 +1,6 @@
-"""Performance-attribution layer: request-scoped spans, a step FLOPs
-model, peak-FLOPs tables, and the Chrome-trace converter (stdlib only).
+"""Performance-attribution layer: request-scoped spans, the engine-loop
+phase clock, a step FLOPs model, peak-FLOPs tables, and the Chrome-trace
+converter (stdlib only).
 
 ROADMAP item 1 says decode is host-loop-bound (MFU 0.086, BENCH_r05) —
 but the steptrace ring only said how long each engine iteration's
@@ -11,18 +12,21 @@ of the attribution stack:
 - :class:`SpanTrace` — one span tree per request
   (queued → prefill chunks → decode chains → detokenize → finish),
   completed trees held in a bounded ring like the steptrace;
-- :class:`StepFlopsModel` — matmul-path FLOPs per engine step from the
-  model config (the per-step half of bench.py's workload MFU), feeding
-  the ``gllm_step_mfu`` gauge and the per-window MFU in
-  ``steptrace.summarize``;
-- :func:`peak_flops` — dense-peak bf16 FLOP/s by TPU generation
-  (single source of truth; bench.py's ``chip_peak_flops`` wraps it);
+- :class:`phase` — the one timing primitive of the engine loop: a
+  context manager that adds its wall time to the open step's phase dict
+  (the steptrace ``ph`` field) and, only while a profiler capture runs,
+  is also a ``jax.profiler.TraceAnnotation("gllm:<name>")`` so the same
+  span sits on the device trace's clock;
+- :class:`StepFlopsModel`, :func:`peak_flops` — matmul-path FLOPs per
+  step and dense-peak bf16 FLOP/s by TPU generation. Plain functions
+  kept for bench.py alone; the engine loop no longer estimates per step;
 - :func:`chrome_trace` — steptrace step events + request spans →
   Chrome trace-event JSON (Perfetto/chrome://tracing loadable): one
   track per engine phase, one per request. Shared by ``GET /trace``
   and ``python -m gllm_tpu.obs.dump --format chrome``.
 
-Same design constraints as the rest of ``gllm_tpu/obs``: no jax import,
+Same design constraints as the rest of ``gllm_tpu/obs``: no jax import
+(the annotation class is imported at the first capture, never before),
 no device work, no new jit static arguments; every recorded number is
 host arithmetic the engine already had. Span recording is gated by
 ``EngineConfig.tracing`` (default ON — the acceptance bar is <2%
@@ -34,13 +38,16 @@ from __future__ import annotations
 import logging
 import os
 import threading
+import time
 from collections import deque
 from typing import Dict, Iterable, List, Optional
 
 logger = logging.getLogger(__name__)
 
 __all__ = ["SpanTrace", "SPANS", "StepFlopsModel", "peak_flops",
-           "chrome_trace", "SPAN_PHASES", "ENGINE_PHASES"]
+           "chrome_trace", "SPAN_PHASES", "ENGINE_PHASES", "HOST_PHASES",
+           "phase", "take_phases", "step_phases", "set_capture",
+           "capturing"]
 
 # Span phase taxonomy (docs/observability.md span-phase catalog): the
 # child spans a request tree may carry. ``queued`` = arrival → first
@@ -53,15 +60,142 @@ __all__ = ["SpanTrace", "SPANS", "StepFlopsModel", "peak_flops",
 SPAN_PHASES = ("queued", "prefill_chunk", "decode_step", "decode_chain",
                "detokenize")
 
-# Engine-loop host phases recorded on every step event (``ph`` field):
-# schedule (scheduler passes forming the batch/chain), build (runner
-# host work up to the jit call: drains, batch build), dispatch (jit
-# enqueue + async host-copy start), collect (host blocked on the
-# handle). ``wait`` is derived — the slack between dispatch end and
-# collect start while the handle rode the pipeline (device work hides
-# here). ``device`` is the block-until-ready delta attributed back to
-# the launching step.
-ENGINE_PHASES = ("schedule", "build", "dispatch", "collect")
+# Engine-loop phases (docs/observability.md phase catalog): the closed
+# vocabulary of what the engine thread does with its time, each opened
+# where the work is done. One pass of the loop, in order:
+#   intake    ServingEngine._run_loop: intake drain (llm.add_seq),
+#             _drain_push_work, _expire_deadlines
+#   schedule  LLM.step fill pass: scheduler passes forming the batch/chain
+#   build     runner: host work up to the jit call (drains, batch build)
+#   dispatch  runner: the jit call and _start_host_copy; the FIRST use of
+#             a step signature nests a ``first_use`` span inside it
+#             (trace + lower + compile or cache read)
+#   wait      runner.collect: blocked until the step's tokens are on the
+#             host (the program, then the copy started at dispatch) — the
+#             only phase in which an idle device is not the host's doing
+#   readback  runner.collect: the step's other outputs (logprobs, finish
+#             steps, speculation counts) to numpy, ready with the tokens
+#   output    LLM.step after the collect: process_output, logprobs, stop
+#             strings, _observe_outputs
+#   deliver   ServingEngine._run_loop: deliver_output (detokenise, the
+#             handles' queues), the journal, _reap_aborted
+#   idle      ServingEngine._run_loop: _wake.wait, nothing to do
+ENGINE_PHASES = ("intake", "schedule", "build", "dispatch", "wait",
+                 "readback", "output", "deliver", "idle")
+# What a step event's ``ph`` holds: the phases in which the HOST works,
+# plus ``collect`` = wait + readback (the name the field has always had
+# for the time blocked in runner.collect). ``wait`` / ``readback`` /
+# ``idle`` ride beside it as ``wait_ms`` / ``readback_ms`` / ``idle_ms``:
+# a reader that sums ``ph`` without ``collect`` keeps reading host time.
+HOST_PHASES = ("intake", "schedule", "build", "dispatch", "output",
+               "deliver")
+
+# ---- the phase clock -------------------------------------------------------
+
+_capturing = False      # a profiler capture is running in this process
+_annotation = None      # jax.profiler.TraceAnnotation, from the first capture
+_tls = threading.local()
+
+
+def set_capture(on: bool) -> None:
+    """Process-wide switch, set by whoever starts and stops the profiler
+    (api_server ``_profile`` / ``_profile_oneshot``): while on, every
+    :class:`phase` is also a TraceAnnotation. jax is imported here, at
+    the first capture, and nowhere else in ``gllm_tpu/obs``."""
+    global _capturing, _annotation
+    if on and _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    _capturing = bool(on)
+
+
+def capturing() -> bool:
+    return _capturing
+
+
+def _open_phases() -> dict:
+    try:
+        return _tls.ph
+    except AttributeError:
+        _tls.ph = {}
+        return _tls.ph
+
+
+def take_phases() -> dict:
+    """The calling thread's open phase dict ({name: seconds}), replaced
+    by an empty one. The engine takes it when a step is dispatched and
+    again when it is collected, so every second a phase measured lands in
+    exactly one step event: what ran since the previous take — the
+    previous step's ``output`` / ``deliver``, this pass's ``intake`` —
+    rides with the step dispatched next."""
+    ph = _open_phases()
+    _tls.ph = {}
+    return ph
+
+
+class phase:
+    """``with phase("build", step=n):`` — time one engine-loop phase.
+
+    Always: wall seconds added to the thread's open phase dict under
+    ``name`` (``add=False`` for a span nested in another phase, which
+    would count twice) and kept on ``.seconds``. While a capture runs:
+    also a ``TraceAnnotation("gllm:<name>", **args)``. With none running
+    a phase is two clock reads and one dict add, and constructs nothing
+    of jax. ``start()`` / ``stop()`` open and close it by hand where the
+    phase ends in several places of a loop body; ``stop`` is idempotent.
+    """
+
+    __slots__ = ("name", "args", "add", "t0", "seconds", "_ann", "_open")
+
+    def __init__(self, name: str, add: bool = True, **args):
+        self.name = name
+        self.args = args
+        self.add = add
+        self.t0 = None
+        self.seconds = 0.0
+        self._ann = None
+        self._open = False
+
+    def __enter__(self):
+        if _capturing:
+            self._ann = _annotation("gllm:" + self.name, **self.args)
+            self._ann.__enter__()
+        self._open = True
+        self.t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc):
+        self.stop()
+        return False
+
+    start = __enter__
+
+    def stop(self) -> None:
+        if not self._open:
+            return
+        self._open = False
+        self.seconds = time.monotonic() - self.t0
+        if self.add:
+            ph = _open_phases()
+            ph[self.name] = ph.get(self.name, 0.0) + self.seconds
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+
+
+def step_phases(phases: dict) -> dict:
+    """Step-event fields from a step's phase dict (seconds): ``ph`` (ms,
+    :data:`HOST_PHASES` that occurred plus ``collect`` = wait + readback)
+    and ``wait_ms`` / ``readback_ms`` / ``idle_ms`` beside it."""
+    ms = {k: round(v * 1e3, 3) for k, v in phases.items()
+          if k in ENGINE_PHASES}
+    out = {"ph": {k: ms[k] for k in HOST_PHASES if k in ms}}
+    out["ph"]["collect"] = round(ms.get("wait", 0.0)
+                                 + ms.get("readback", 0.0), 3)
+    for k in ("wait", "readback", "idle"):
+        if k in ms:
+            out[k + "_ms"] = ms[k]
+    return out
 
 
 class SpanTrace:
@@ -213,8 +347,9 @@ SPANS = SpanTrace()
 # ---- FLOPs / peak models ---------------------------------------------------
 
 # Dense-peak bf16 TFLOP/s by TPU generation (public spec sheets) — the
-# MFU denominator. Single source of truth: bench.py's chip_peak_flops
-# wraps peak_flops() below. Matched by substring against
+# MFU denominator of bench.py, whose chip_peak_flops wraps peak_flops()
+# below; nothing in the engine or the runner reads it (the benchmark's
+# peaks are perfbench/peaks.json). Matched by substring against
 # ``jax.Device.device_kind`` (lowercased).
 PEAK_TFLOPS = (("v5 lite", 197.0), ("v5e", 197.0), ("v6", 918.0),
                ("trillium", 918.0), ("v5p", 459.0), ("v5", 459.0),
@@ -320,7 +455,8 @@ class StepFlopsModel:
 # Track (tid) layout of the engine process row in the exported trace;
 # ``wait`` and ``device`` are derived tracks (see chrome_trace).
 _ENGINE_TIDS = {"schedule": 1, "build": 2, "dispatch": 3, "wait": 4,
-                "collect": 5, "device": 6}
+                "collect": 5, "device": 6, "output": 7, "deliver": 8,
+                "intake": 9}
 _PID_ENGINE = 1
 _PID_REQUESTS = 2
 
@@ -382,6 +518,13 @@ def chrome_trace(step_events: Iterable[dict], spans: Iterable[dict] = (),
         if "k" in e:
             args["k"] = e["k"]
         t = end - wall
+        for name in ("intake", "deliver", "output"):
+            dur = float(ph.get(name, 0.0)) / 1e3
+            if dur > 0:
+                t -= dur
+                events.append(_x(f"{e.get('kind', 'step')}:{name}", t,
+                                 dur, _PID_ENGINE, _ENGINE_TIDS[name]))
+        t = end - wall
         for name, dur in (("schedule", sched), ("build", build),
                           ("dispatch", disp), ("wait", wait),
                           ("collect", coll)):
@@ -392,12 +535,9 @@ def chrome_trace(step_events: Iterable[dict], spans: Iterable[dict] = (),
             t += dur
         dev = float(e.get("dev_ms", 0.0)) / 1e3
         if dev > 0:
-            dargs = dict(args)
-            if e.get("mfu") is not None:
-                dargs["mfu"] = e["mfu"]
             events.append(_x(f"{e.get('kind', 'step')}:device",
                              end - dev, dev, _PID_ENGINE,
-                             _ENGINE_TIDS["device"], dargs))
+                             _ENGINE_TIDS["device"], args))
 
     for rec in spans:
         sid = int(rec.get("seq_id", 0))

@@ -82,11 +82,14 @@ __all__ = ["StepTrace", "TRACE", "summarize"]
 #
 # Step events (prefill/decode/fused_block) additionally carry the
 # performance-attribution fields (docs/observability.md#tracing):
-# ``ph`` = host wall by engine phase {schedule, build, dispatch,
-# collect} in ms, ``step_wall_ms`` = schedule-start → collect-end,
+# ``ph`` = host wall by engine phase in ms (obs/spans.py HOST_PHASES:
+# intake, schedule, build, dispatch, output, deliver — plus ``collect``
+# = the time blocked in runner.collect), ``wait_ms`` / ``readback_ms``
+# = the split of ``collect`` (``idle_ms`` where the loop slept before
+# the step), ``step_wall_ms`` = schedule-start → collect-end, and
 # ``dev_ms`` = device wall attributed back to the launching step
-# (block-until-ready delta at collect), and optional ``mfu`` /
-# ``hbm_gbps`` estimates from the step FLOPs model (obs/spans.py).
+# (block-until-ready delta at collect). ``compile`` events carry
+# ``first_use_ms`` and ``source`` (compiled | cache).
 STEP_KINDS = ("prefill", "decode", "unified_step", "fused_block",
               "pp_stage", "compile", "chain_break", "fault",
               "quarantine", "prefix", "loop_stall", "recovery")
@@ -223,10 +226,10 @@ def summarize(events: List[dict]) -> dict:
     # engine-loop phase breakdown + device-wall attribution (events
     # carrying ``ph``/``dev_ms`` — docs/observability.md#tracing)
     host_phase: Dict[str, float] = {}
+    blocked: Dict[str, float] = {}   # wait / readback / idle (not host work)
     dev_by_kind: Dict[str, float] = {}
     dev_total = hidden_total = 0.0
-    mfu_dev = hbm_dev = 0.0          # Σ(estimate · dev_ms) numerators
-    mfu_seen = hbm_seen = False
+    first_use_ms = 0.0
     t_first_start = t_last_end = None
     for e in events:
         k = e["kind"]
@@ -239,6 +242,7 @@ def summarize(events: List[dict]) -> dict:
             continue
         if k == "compile":
             compiles += 1
+            first_use_ms += float(e.get("first_use_ms", 0.0))
             continue
         if k == "chain_break":
             chain_breaks += 1
@@ -294,12 +298,10 @@ def summarize(events: List[dict]) -> dict:
             dev_total += dev
             coll = float(ph.get("collect", wall))
             hidden_total += max(0.0, dev - coll)
-            if e.get("mfu") is not None:
-                mfu_seen = True
-                mfu_dev += float(e["mfu"]) * dev
-            if e.get("hbm_gbps") is not None:
-                hbm_seen = True
-                hbm_dev += float(e["hbm_gbps"]) * dev
+            for name in ("wait", "readback", "idle"):
+                if e.get(name + "_ms") is not None:
+                    blocked[name] = (blocked.get(name, 0.0)
+                                     + float(e[name + "_ms"]))
             start = float(e["t"]) - float(
                 e.get("step_wall_ms", wall)) / 1e3
             if t_first_start is None or start < t_first_start:
@@ -373,6 +375,11 @@ def summarize(events: List[dict]) -> dict:
         "host_ms_by_phase": ({k: round(v, 2)
                               for k, v in host_phase.items()}
                              if host_phase else None),
+        # the engine thread's time NOT spent working: the split of
+        # ``collect`` into wait / readback, and idle (nothing to do)
+        "blocked_ms_by_phase": ({k: round(v, 2)
+                                 for k, v in blocked.items()}
+                                if blocked else None),
         # device wall (block-until-ready deltas) attributed by step kind
         "device_ms_by_kind": ({k: round(v, 2)
                                for k, v in dev_by_kind.items()}
@@ -385,20 +392,10 @@ def summarize(events: List[dict]) -> dict:
         # gLLM bubble ratio, reproduced from engine-side attribution
         "bubble_frac": (round(max(0.0, 1.0 - dev_total / elapsed_ms), 4)
                         if elapsed_ms > 0 and dev_total > 0 else None),
-        # window MFU against the wall clock (Σ step-FLOPs / peak /
-        # elapsed) and against device-busy time only; None when the
-        # peak is unknown (CPU without GLLM_TPU_PEAK_TFLOPS). 6 digits:
-        # a tiny-model window with compile gaps sits at 1e-6 and must
-        # not quantize to a fake hard zero
-        "mfu": (round(mfu_dev / elapsed_ms, 6)
-                if mfu_seen and elapsed_ms > 0 else None),
-        "device_mfu": (round(mfu_dev / dev_total, 6)
-                       if mfu_seen and dev_total > 0 else None),
-        # estimated HBM read bandwidth over device-busy time (weights +
-        # KV stream per step; per-device)
-        "hbm_gbps": (round(hbm_dev / dev_total, 2)
-                     if hbm_seen and dev_total > 0 else None),
+        # first uses of a step signature in the window: how many, and the
+        # wall they took (trace + lower + compile or cache read)
         "compiles": compiles,
+        "first_use_ms": round(first_use_ms, 3),
         "chain_breaks": chain_breaks,
         "chain_breaks_by_reason": break_reasons,
         # pipelined loop (docs/overlap_scheduling.md#pipelined-loop):

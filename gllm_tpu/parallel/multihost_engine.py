@@ -743,10 +743,13 @@ class MultihostServingEngine:
         self._thread.start()
 
     def submit(self, token_ids, sampling_params, mm_input=None,
-               disagg_items=None, target_dp=None):
+               disagg_items=None, target_dp=None, received_t=None):
         # target_dp (per-DP-endpoint pinning) is accepted for interface
         # parity with ServingEngine but ignored: the multihost plane runs
-        # dp=1 per host group (replica routing happens in the engine loop)
+        # dp=1 per host group (replica routing happens in the engine loop).
+        # received_t likewise: gllm_http_admit_lag_seconds is observed in
+        # ServingEngine's intake drain; here intake rides the tick
+        # broadcast and has no such point
         if disagg_items:
             # coordinator runs on host 0; the admit reaches every host as
             # a tick event (gate-B flips ride the blob channel)

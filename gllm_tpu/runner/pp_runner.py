@@ -47,6 +47,7 @@ import numpy as np
 from gllm_tpu.config import EngineConfig
 from gllm_tpu.models import ModelConfig, get_model_def
 from gllm_tpu.obs import metrics as obs
+from gllm_tpu.obs.spans import phase
 from gllm_tpu.obs.steptrace import TRACE
 from gllm_tpu.ops.sampling import sample
 from gllm_tpu.runner.runner import (ModelRunner, _DTYPES, build_in_place,
@@ -182,9 +183,6 @@ class PPModelRunner(ModelRunner):
         self.rng_key = jax.random.key(config.seed)
         self._step_count = 0
         self._seen_sigs = set()          # see ModelRunner._note_dispatch
-        self.last_phases = {}            # see ModelRunner.last_phases
-        self._last_kv_read = 0
-        self.param_bytes = 0             # summed over stages below
         self._mb_inflight = 0            # feeds gllm_pp_stage_inflight
 
         if model_cfg.use_hybrid:
@@ -266,13 +264,6 @@ class PPModelRunner(ModelRunner):
             # calls differ only in arg placement → per-sharding compiles
             # dedupe through the jit cache)
             staged.append((scfg, sparams, self._make_stage_fn(scfg)))
-            try:
-                from gllm_tpu.ops.quant import param_bytes as _pbytes
-                # whole-pipeline weight bytes (HBM-bandwidth estimate);
-                # every stage's weights stream once per microbatch
-                self.param_bytes += int(_pbytes(sparams))
-            except Exception:
-                pass
             logger.info("[startup] phase=weight_load stage=%d seconds=%.2f",
                         i, _time.monotonic() - _t_load)
             _t_load = _time.monotonic()
@@ -478,19 +469,17 @@ class PPModelRunner(ModelRunner):
         and page tables are host-known from promised counts), so the
         splice rewrites only the stage-0 placed batch — the previous
         tokens hop last-stage → stage-0 device first."""
-        import time as _time
         from gllm_tpu.parallel.mesh import mesh_context
-        from gllm_tpu.runner.runner import _spec_sampled
-        t_enter = _time.monotonic()
+        from gllm_tpu.runner.runner import _spec_sampled, first_use
+        build = phase("build").start()
         batch, max_q, presence = self.builder.build(sched_batch, step_key,
                                                     device=False)
         lp_k, want_plp = self._lp_flags(sched_batch)
         spec_sampled = _spec_sampled(sched_batch.items)
         from gllm_tpu.runner.runner import _all_greedy as _ag
-        self._note_dispatch("pp", batch,
-                            (max_q, lp_k, want_plp, spec_sampled,
-                             _ag(sched_batch.items)),
-                            _ag(sched_batch.items))
+        new_sig = self._note_dispatch(
+            "pp", batch, (max_q, lp_k, want_plp, spec_sampled,
+                          _ag(sched_batch.items)), _ag(sched_batch.items))
         _M_MICROBATCH.inc()
         self._note_kv_read(sched_batch.items)
         # one pp_stage event PER STAGE, carrying the dispatch family the
@@ -509,50 +498,53 @@ class PPModelRunner(ModelRunner):
         self._mb_inflight += 1
         for i in range(len(stages)):
             _M_STAGE_INFLIGHT.set(self._mb_inflight, stage=str(i))
-        t_build = _time.monotonic()
-        hidden = residual = None
-        out = None
-        # one batched host→device transfer fans the step batch out to
-        # every stage (and presence to the last) — one dispatch call
-        # instead of per-stage puts
-        last = stages[-1]
-        targets = [batch] * len(stages)
-        devices = [s.device for s in stages]
-        if presence is not None:
-            targets.append(presence)
-            devices.append(last.device)
-        placed = jax.device_put(targets, devices)
-        sbs = list(placed[:len(stages)])
-        presence = placed[len(stages)] if presence is not None else None
-        if prev_handle is not None:
-            prev_tokens = prev_handle[0]
-            if getattr(prev_tokens, "ndim", 1) == 2:
-                prev_tokens = prev_tokens[-1]
-            prev_tokens = jax.device_put(prev_tokens, stages[0].device)
-            sbs[0] = self._splice_prev(sbs[0], sched_batch, prev_tokens)
-        for stage, sb in zip(stages, sbs):
-            if hidden is not None:
-                hidden = jax.device_put(hidden, stage.device)
-                residual = jax.device_put(residual, stage.device)
-            pm = presence if stage.cfg.is_last_stage else None
-            # lp flags are static jit args — only the last stage reads
-            # them, so earlier stages keep their (-1, False) cache entry
-            # for every logprobs pattern (no pipeline-wide recompiles)
-            from gllm_tpu.runner.runner import _all_greedy
-            lp_kw = (dict(logprobs_k=lp_k, prompt_lp=want_plp,
-                          spec_sampled=spec_sampled,
-                          all_greedy=_all_greedy(sched_batch.items))
-                     if stage.cfg.is_last_stage else {})
-            with mesh_context(stage.mesh):
-                out, stage.kv = stage.fn(stage.params, stage.kv, sb,
-                                         stage.cos_sin, hidden, residual,
-                                         pm, max_q_len=max_q, **lp_kw)
-            if not stage.cfg.is_last_stage:
-                hidden, residual = out
+        build.stop()
+        # one ``dispatch`` phase for the whole stage chain: the placement
+        # of the step batch and every stage's jit call; a first use of
+        # the signature covers all of them
+        with phase("dispatch", **self._span_args(
+                sched_batch.num_seqs, sched_batch.total_tokens)), \
+                first_use(new_sig):
+            hidden = residual = None
+            out = None
+            # one batched host→device transfer fans the step batch out to
+            # every stage (and presence to the last) — one dispatch call
+            # instead of per-stage puts
+            last = stages[-1]
+            targets = [batch] * len(stages)
+            devices = [s.device for s in stages]
+            if presence is not None:
+                targets.append(presence)
+                devices.append(last.device)
+            placed = jax.device_put(targets, devices)
+            sbs = list(placed[:len(stages)])
+            presence = placed[len(stages)] if presence is not None else None
+            if prev_handle is not None:
+                prev_tokens = prev_handle[0]
+                if getattr(prev_tokens, "ndim", 1) == 2:
+                    prev_tokens = prev_tokens[-1]
+                prev_tokens = jax.device_put(prev_tokens, stages[0].device)
+                sbs[0] = self._splice_prev(sbs[0], sched_batch, prev_tokens)
+            for stage, sb in zip(stages, sbs):
+                if hidden is not None:
+                    hidden = jax.device_put(hidden, stage.device)
+                    residual = jax.device_put(residual, stage.device)
+                pm = presence if stage.cfg.is_last_stage else None
+                # lp flags are static jit args — only the last stage reads
+                # them, so earlier stages keep their (-1, False) cache entry
+                # for every logprobs pattern (no pipeline-wide recompiles)
+                from gllm_tpu.runner.runner import _all_greedy
+                lp_kw = (dict(logprobs_k=lp_k, prompt_lp=want_plp,
+                              spec_sampled=spec_sampled,
+                              all_greedy=_all_greedy(sched_batch.items))
+                         if stage.cfg.is_last_stage else {})
+                with mesh_context(stage.mesh):
+                    out, stage.kv = stage.fn(stage.params, stage.kv, sb,
+                                             stage.cos_sin, hidden, residual,
+                                             pm, max_q_len=max_q, **lp_kw)
+                if not stage.cfg.is_last_stage:
+                    hidden, residual = out
         tokens, aux = out
-        self.last_phases = {"build": t_build - t_enter,
-                            "dispatch": _time.monotonic() - t_build,
-                            "kv_bytes": self._last_kv_read}
         return tokens, aux, sched_batch.num_seqs
 
     def _apply_scale_resets(self) -> None:
@@ -578,12 +570,15 @@ class PPModelRunner(ModelRunner):
 
     def collect(self, handle):
         tokens, aux, n = handle
-        if aux:
-            aux = jax.tree.map(np.asarray, aux)
+        with phase("wait"):         # see ModelRunner.collect
+            host = np.asarray(tokens)[:n]
+        with phase("readback"):
+            if aux:
+                aux = jax.tree.map(np.asarray, aux)
         self._mb_inflight = max(0, self._mb_inflight - 1)
         for i in range(len(self.stages)):
             _M_STAGE_INFLIGHT.set(self._mb_inflight, stage=str(i))
-        return np.asarray(tokens)[:n], aux
+        return host, aux
 
     def step(self, sched_batch) -> np.ndarray:
         return self.collect(self.step_async(sched_batch))[0]
@@ -622,8 +617,10 @@ class PPModelRunner(ModelRunner):
                 continue
             tokens, aux, n = h
             self._mb_inflight = max(0, self._mb_inflight - 1)
-            rows.append(np.asarray(tokens)[:n])
-            auxes.append(jax.tree.map(np.asarray, aux) if aux else {})
+            with phase("wait"):
+                rows.append(np.asarray(tokens)[:n])
+            with phase("readback"):
+                auxes.append(jax.tree.map(np.asarray, aux) if aux else {})
         for i in range(len(self.stages)):
             _M_STAGE_INFLIGHT.set(self._mb_inflight, stage=str(i))
         return rows, auxes

@@ -32,6 +32,7 @@ from gllm_tpu.batching import StepBatch
 from gllm_tpu.config import EngineConfig
 from gllm_tpu.models import ModelConfig, get_model_def
 from gllm_tpu.obs import metrics as obs
+from gllm_tpu.obs.spans import phase
 from gllm_tpu.obs.steptrace import TRACE
 from gllm_tpu.ops.sampling import sample
 from gllm_tpu.runner.prepare import BatchBuilder
@@ -77,6 +78,17 @@ _M_XLA_SECONDS = obs.counter(
     "gllm_xla_compile_seconds_total",
     "seconds spent obtaining executables from XLA (compiling, or reading "
     "the persistent cache), by source", ("source",))
+# The first use of a step signature (a program this process has not
+# dispatched yet) stalls every stream for its trace + lower + compile or
+# cache read, seconds even where the persistent cache has the program:
+# gllm_xla_programs_total reads 0 compiled in a run that lost most of its
+# throughput this way (PERF.md, PR 23). The steptrace ``compile`` event
+# carries the same wall as ``first_use_ms``.
+_M_FIRST_USE = obs.counter(
+    "gllm_step_first_use_seconds_total",
+    "wall seconds of the jit calls that first used a step signature "
+    "(trace + lower + compile, or the persistent cache read), by source",
+    ("source",))
 _xla_cache_hit = threading.local()
 
 
@@ -90,8 +102,44 @@ def _on_xla_duration(event: str, seconds: float, **_kw) -> None:
         source = ("cache" if getattr(_xla_cache_hit, "flag", False)
                   else "compiled")
         _xla_cache_hit.flag = False
+        if source == "compiled":
+            _xla_cache_hit.compiled = True      # read by first_use
         _M_XLA_PROGRAMS.inc(source=source)
         _M_XLA_SECONDS.inc(seconds, source=source)
+
+
+class first_use:
+    """Times the jit call that first uses a step signature: a
+    ``first_use`` span nested in ``dispatch`` (not added to the step's
+    phases a second time), the ``compile`` steptrace event with
+    ``first_use_ms`` and ``source`` — ``compiled`` if XLA compiled a
+    program during the call, else ``cache`` — and the counter. A no-op
+    where ``fields`` (from ``_note_dispatch``) is None: the signature
+    has been used before."""
+
+    __slots__ = ("fields", "span")
+
+    def __init__(self, fields: Optional[dict]):
+        self.fields = fields
+
+    def __enter__(self):
+        if self.fields is not None:
+            _xla_cache_hit.compiled = False
+            self.span = phase("first_use", add=False).start()
+        return self
+
+    def __exit__(self, exc_type, *_):
+        if self.fields is None:
+            return False
+        self.span.stop()
+        if exc_type is None:
+            source = ("compiled" if getattr(_xla_cache_hit, "compiled",
+                                            False) else "cache")
+            _M_FIRST_USE.inc(self.span.seconds, source=source)
+            TRACE.record("compile", source=source,
+                         first_use_ms=round(self.span.seconds * 1e3, 3),
+                         **self.fields)
+        return False
 
 
 jax.monitoring.register_event_listener(_on_xla_event)
@@ -464,13 +512,6 @@ class ModelRunner:
         # (shape-bucket, static-flag) signatures already dispatched —
         # first sightings count as compile events (obs layer)
         self._seen_sigs = set()
-        # Dispatch-phase attribution (docs/observability.md#tracing):
-        # every step_async* records its host build/dispatch split here
-        # (seconds) plus the step's KV-read estimate; the engine copies
-        # it into the in-flight entry it is building. Overwritten per
-        # dispatch — the engine reads it synchronously after the call.
-        self.last_phases = {}
-        self._last_kv_read = 0
 
         ep_loaded = False
         _t_load = time.monotonic()
@@ -578,13 +619,6 @@ class ModelRunner:
         # Host-RAM KV tier (gllm_tpu/kvswap) — attached by the engine
         # when configured; drained at dispatch time on every step path.
         self.swap_manager = None
-        # Total parameter bytes on device — the per-dispatch weight-read
-        # term of the HBM-bandwidth estimate (gllm_step_hbm_gbps).
-        try:
-            from gllm_tpu.ops.quant import param_bytes
-            self.param_bytes = int(param_bytes(self.params))
-        except Exception:
-            self.param_bytes = 0
         logger.info("KV cache: %d pages × %d tokens (%s)", self.num_pages,
                     config.cache.page_size, self._kv_dtype().__name__)
         _M_KV_DTYPE.set(1, dtype=jnp.dtype(self._kv_dtype()).name)
@@ -1028,35 +1062,43 @@ class ModelRunner:
         attention: each row reads its whole context (kv_len after this
         step's writes); a K-step fused block re-reads the growing
         context every sub-step. Pure host arithmetic on scheduler state
-        — never touches the device. The per-dispatch value is stashed
-        for the engine's HBM-bandwidth attribution (last_phases)."""
+        — never touches the device (gllm_kv_bytes_read_total; bench.py
+        derives kv_bytes_per_step from its growth)."""
         tok_bytes = getattr(self, "_kv_rd_tok_bytes", 0)
-        self._last_kv_read = 0
         if not tok_bytes:
             return
         ctx = sum(it.computed_before + it.num_new_tokens for it in items)
         grow = len(items) * steps * (steps - 1) // 2
-        self._last_kv_read = int((ctx * steps + grow) * tok_bytes)
-        _M_KV_READ.inc(self._last_kv_read)
+        _M_KV_READ.inc(int((ctx * steps + grow) * tok_bytes))
 
     def _note_dispatch(self, kind: str, batch, static_flags: tuple,
-                       all_greedy: bool) -> None:
-        """Host-side dispatch bookkeeping: sampler-variant counter + a
-        compile event on the first sighting of a (padded-shape,
-        static-flag) signature. Reads only shapes of already-built host
-        arrays — never forces a device sync."""
+                       all_greedy: bool) -> Optional[dict]:
+        """Host-side dispatch bookkeeping: sampler-variant counter and,
+        on the first sighting of a (padded-shape, static-flag) signature,
+        the fields of its ``compile`` event — the caller wraps the jit
+        call that follows in :class:`first_use` with them, which records
+        the event once the call's wall is known. None for a signature
+        seen before. Reads only shapes of already-built host arrays —
+        never forces a device sync."""
         self.num_dispatches += 1
         _M_SAMPLER.inc(program="greedy" if all_greedy else "sampled")
         key = (kind, batch.token_ids.shape,
                batch.attn.page_table.shape) + static_flags
-        if key not in self._seen_sigs:
-            self._seen_sigs.add(key)
-            _M_NEW_SHAPE.inc()
-            TRACE.record("compile", dispatch=kind,
-                         tokens_pad=int(batch.token_ids.shape[-1]),
-                         seqs_pad=int(batch.attn.page_table.shape[-2]),
-                         pages_pad=int(batch.attn.page_table.shape[-1]),
-                         flags=repr(static_flags))
+        if key in self._seen_sigs:
+            return None
+        self._seen_sigs.add(key)
+        _M_NEW_SHAPE.inc()
+        return dict(dispatch=kind,
+                    tokens_pad=int(batch.token_ids.shape[-1]),
+                    seqs_pad=int(batch.attn.page_table.shape[-2]),
+                    pages_pad=int(batch.attn.page_table.shape[-1]),
+                    flags=repr(static_flags))
+
+    def _span_args(self, rows: int, tokens: int) -> dict:
+        """What a ``build`` / ``dispatch`` span says of its step in the
+        profiler's trace: the dispatch's ordinal and its size."""
+        return {"step": self.num_dispatches, "rows": rows,
+                "tokens": tokens}
 
     @staticmethod
     def _lp_flags(sched_batch: ScheduledBatch):
@@ -1094,7 +1136,7 @@ class ModelRunner:
         """
         from jax.sharding import NamedSharding, PartitionSpec as P
         assert len(sched_batches) == self.dp
-        t_enter = time.monotonic()
+        build = phase("build").start()
         self._apply_ssm_intents()
         self._apply_swap_intents()   # no-op under dp>1 (tier is gated)
         self._step_count += 1
@@ -1172,22 +1214,22 @@ class ModelRunner:
         all_greedy_dp = all(_all_greedy(b.items) for b in live)
         spec_sampled_dp = any(_spec_sampled(b.items) for b in live)
         self._note_kv_read([it for b in live for it in b.items])
-        self._note_dispatch("dp_step", stacked,
-                            (max_q, lp_k, want_plp, spec_sampled_dp,
-                             all_greedy_dp),
-                            all_greedy_dp)
-        t_build = time.monotonic()
+        new_sig = self._note_dispatch("dp_step", stacked,
+                                      (max_q, lp_k, want_plp,
+                                       spec_sampled_dp, all_greedy_dp),
+                                      all_greedy_dp)
+        build.stop()
         from gllm_tpu.parallel.mesh import mesh_context
-        with mesh_context(self.mesh):
-            tokens, self.kv, aux = self._step_fn_dp(
-                self.params, self.kv, stacked, self.cos_sin, token_counts,
-                max_q_len=max_q, logprobs_k=lp_k, prompt_lp=want_plp,
-                spec_sampled=spec_sampled_dp,
-                all_greedy=all_greedy_dp)
-        _start_host_copy((tokens, aux))
-        self.last_phases = {"build": t_build - t_enter,
-                            "dispatch": time.monotonic() - t_build,
-                            "kv_bytes": self._last_kv_read}
+        with phase("dispatch", **self._span_args(
+                sum(b.num_seqs for b in live),
+                sum(b.total_tokens for b in live))):
+            with mesh_context(self.mesh), first_use(new_sig):
+                tokens, self.kv, aux = self._step_fn_dp(
+                    self.params, self.kv, stacked, self.cos_sin,
+                    token_counts, max_q_len=max_q, logprobs_k=lp_k,
+                    prompt_lp=want_plp, spec_sampled=spec_sampled_dp,
+                    all_greedy=all_greedy_dp)
+            _start_host_copy((tokens, aux))
         return tokens, aux, [b.num_seqs if b is not None else 0
                              for b in sched_batches]
 
@@ -1195,11 +1237,13 @@ class ModelRunner:
         """Per-replica sampled-token rows + per-replica aux slices:
         (List[np [n_r]], List[aux dict])."""
         tokens, aux, ns = handle
-        host = np.asarray(tokens)
-        aux_host = jax.tree.map(np.asarray, aux)
-        auxes = [jax.tree.map(lambda a: a[r], aux_host)
-                 for r in range(len(ns))]
-        return [host[r, :n] for r, n in enumerate(ns)], auxes
+        with phase("wait"):         # see collect()
+            host = np.asarray(tokens)
+        with phase("readback"):
+            aux_host = jax.tree.map(np.asarray, aux)
+            auxes = [jax.tree.map(lambda a: a[r], aux_host)
+                     for r in range(len(ns))]
+            return [host[r, :n] for r, n in enumerate(ns)], auxes
 
     def step_async(self, sched_batch: ScheduledBatch, prev_handle=None):
         """Launch one step; returns an opaque handle whose tokens are an
@@ -1213,7 +1257,7 @@ class ModelRunner:
         rows ride next to prefill chunks (whose tokens are host-known)
         in one dispatch — the chain absorbing a prefill chunk instead
         of breaking (docs/overlap_scheduling.md#unified-step)."""
-        t_enter = time.monotonic()
+        build = phase("build").start()
         if self.model_cfg.use_mm:
             self._prepare_mm(sched_batch)
         self._apply_ssm_intents()
@@ -1230,22 +1274,20 @@ class ModelRunner:
         spec_sampled = _spec_sampled(sched_batch.items)
         all_greedy = _all_greedy(sched_batch.items)
         self._note_kv_read(sched_batch.items)
-        self._note_dispatch("step", batch,
-                            (max_q, lp_k, want_plp, ring, spec_sampled,
-                             all_greedy), all_greedy)
-        t_build = time.monotonic()
+        new_sig = self._note_dispatch(
+            "step", batch, (max_q, lp_k, want_plp, ring, spec_sampled,
+                            all_greedy), all_greedy)
+        build.stop()
         from gllm_tpu.parallel.mesh import mesh_context
-        with mesh_context(self.mesh):
-            tokens, self.kv, aux = self._step_fn(
-                self.params, self.kv, batch, self.cos_sin, token_counts,
-                max_q_len=max_q, logprobs_k=lp_k, prompt_lp=want_plp,
-                ring=ring,
-                spec_sampled=spec_sampled,
-                all_greedy=all_greedy)
-        _start_host_copy((tokens, aux))
-        self.last_phases = {"build": t_build - t_enter,
-                            "dispatch": time.monotonic() - t_build,
-                            "kv_bytes": self._last_kv_read}
+        with phase("dispatch", **self._span_args(
+                sched_batch.num_seqs, sched_batch.total_tokens)):
+            with mesh_context(self.mesh), first_use(new_sig):
+                tokens, self.kv, aux = self._step_fn(
+                    self.params, self.kv, batch, self.cos_sin,
+                    token_counts, max_q_len=max_q, logprobs_k=lp_k,
+                    prompt_lp=want_plp, ring=ring,
+                    spec_sampled=spec_sampled, all_greedy=all_greedy)
+            _start_host_copy((tokens, aux))
         return tokens, aux, sched_batch.num_seqs
 
     def _use_ring(self, sched_batch: ScheduledBatch, t_pad: int) -> bool:
@@ -1389,7 +1431,7 @@ class ModelRunner:
         Returns a handle whose collect() yields tokens [K, n]; chainable
         (the last step's on-device tokens feed the next block)."""
         K = len(chain)
-        t_enter = time.monotonic()
+        build = phase("build").start()
         # chain scheduling may have minted prefix-cached pages (spill
         # intents) — drain before the block overwrites them
         self._apply_swap_intents()
@@ -1439,20 +1481,20 @@ class ModelRunner:
         self._note_kv_read(chain[0].items, steps=K)
         # e_bucket is part of the compile signature: stop-set presence
         # changes the pytree structure and its pow2 width E the shapes
-        self._note_dispatch("multi_step", batch,
-                            (K, all_greedy, odf, e_bucket), all_greedy)
-        t_build = time.monotonic()
+        new_sig = self._note_dispatch(
+            "multi_step", batch, (K, all_greedy, odf, e_bucket),
+            all_greedy)
+        build.stop()
         from gllm_tpu.parallel.mesh import mesh_context
-        with mesh_context(self.mesh):
-            tokens, finish_step, self.kv = self._multi_step_fn(
-                self.params, self.kv, batch, self.cos_sin, keys,
-                jnp.asarray(au_np), num_steps=K,
-                all_greedy=all_greedy, ondevice_finish=odf)
-        aux = {"finish": (finish_step,)} if finish_step is not None else {}
-        _start_host_copy((tokens, aux))
-        self.last_phases = {"build": t_build - t_enter,
-                            "dispatch": time.monotonic() - t_build,
-                            "kv_bytes": self._last_kv_read}
+        with phase("dispatch", **self._span_args(n, n * K)):
+            with mesh_context(self.mesh), first_use(new_sig):
+                tokens, finish_step, self.kv = self._multi_step_fn(
+                    self.params, self.kv, batch, self.cos_sin, keys,
+                    jnp.asarray(au_np), num_steps=K,
+                    all_greedy=all_greedy, ondevice_finish=odf)
+            aux = ({"finish": (finish_step,)}
+                   if finish_step is not None else {})
+            _start_host_copy((tokens, aux))
         return tokens, aux, chain[0].num_seqs
 
     def _build_multi_step_fn(self):
@@ -1758,7 +1800,7 @@ class ModelRunner:
         seeds from (actual frontiers; the host's scheduled bounds are
         upper bounds only)."""
         K = len(chain)
-        t_enter = time.monotonic()
+        build = phase("build").start()
         self._apply_swap_intents()
         keys = _fold_in_range(self.rng_key, self._step_count + 1, k=K)
         self._step_count += K
@@ -1786,24 +1828,22 @@ class ModelRunner:
                                       prev_handle)
         all_greedy = _all_greedy(chain[0].items)
         self._note_kv_read(chain[0].items, steps=K)
-        self._note_dispatch("spec_block", batch,
-                            (K, k_draft, all_greedy, e_bucket),
-                            all_greedy)
-        t_build = time.monotonic()
+        new_sig = self._note_dispatch(
+            "spec_block", batch, (K, k_draft, all_greedy, e_bucket),
+            all_greedy)
+        build.stop()
         from gllm_tpu.parallel.mesh import mesh_context
-        with mesh_context(self.mesh):
-            tokens, counts, totals, kcur, state_out, self.kv = \
-                self._spec_multi_fn(self.params, self.kv, batch,
-                                    self.cos_sin, keys, state,
-                                    num_steps=K, k_draft=k_draft,
-                                    all_greedy=all_greedy)
-        aux = {"spec_counts": (counts,), "spec_totals": totals,
-               "spec_kcur": (kcur,), "_spec_state": state_out}
-        _start_host_copy((tokens, {k: v for k, v in aux.items()
-                                   if not k.startswith("_")}))
-        self.last_phases = {"build": t_build - t_enter,
-                            "dispatch": time.monotonic() - t_build,
-                            "kv_bytes": self._last_kv_read}
+        with phase("dispatch", **self._span_args(n, n * K)):
+            with mesh_context(self.mesh), first_use(new_sig):
+                tokens, counts, totals, kcur, state_out, self.kv = \
+                    self._spec_multi_fn(self.params, self.kv, batch,
+                                        self.cos_sin, keys, state,
+                                        num_steps=K, k_draft=k_draft,
+                                        all_greedy=all_greedy)
+            aux = {"spec_counts": (counts,), "spec_totals": totals,
+                   "spec_kcur": (kcur,), "_spec_state": state_out}
+            _start_host_copy((tokens, {k: v for k, v in aux.items()
+                                       if not k.startswith("_")}))
         return tokens, aux, n
 
     def _spec_seed_state(self, batch: StepBatch, sched0, au_np,
@@ -1923,10 +1963,22 @@ class ModelRunner:
         chained dispatch consumes them directly."""
         tokens, aux, n = handle
         out_aux = {}
-        if aux:
-            out_aux = {k: tuple(_to_host(a) for a in v)
-                       for k, v in aux.items() if not k.startswith("_")}
-        host = _to_host(tokens)
+        # ``wait`` is the one phase in which an idle device is not the
+        # host's doing: blocked until the step's tokens are on the host
+        # (the program, then the copy that was started at dispatch,
+        # _start_host_copy). Blocking on the program alone first
+        # (block_until_ready) and fetching afterwards would separate the
+        # copy from the wait, at the price of a second wake-up: 0.4 ms a
+        # step on a v5e, 1 % of this cell's throughput (PERF.md, PR 24).
+        # ``readback`` is what is left: the step's other outputs, ready
+        # with the tokens.
+        with phase("wait"):
+            host = _to_host(tokens)
+        with phase("readback"):
+            if aux:
+                out_aux = {k: tuple(_to_host(a) for a in v)
+                           for k, v in aux.items()
+                           if not k.startswith("_")}
         if host.ndim == 3:              # spec block: [K, S, k+1]
             return host[:, :n, :], out_aux
         return (host[..., :n] if host.ndim == 2 else host[:n]), out_aux

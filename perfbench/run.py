@@ -2,7 +2,7 @@
 """The benchmark: one cell, one run, one result line.
 
     python perfbench/run.py --workload <cell> --seed <n> --seconds <s>
-                            --trace <0|1> [--cpu-rehearsal] [--control]
+                            --trace <0|1|2> [--cpu-rehearsal] [--control]
 
 This parent uses the standard library only and never imports jax: it is the
 load generator and must not hold the chip. It starts one
@@ -11,6 +11,13 @@ configuration's flags (and, beside it, a CPU-only child that computes the
 plain reference), checks the served answers against the reference, warms up
 the cell's shapes, offers the cell's traffic for ``--seconds`` seconds,
 stops the child and prints one JSON object as its last line.
+
+``--trace 0`` measures (the end-to-end metrics), ``--trace 1`` traces a
+run of its own (the per-layer metrics), and ``--trace 2`` does both in one
+process: exactly what ``--trace 0`` does until the measured window has
+closed and its numbers are frozen, then the same traffic goes on for a
+short tail in which the counters are read and the profiler runs
+(``Tail``), and the last line holds both kinds of metric.
 
 Everything that belongs to one configuration, traffic mix, cell, per-layer
 metric, kernel or reference family is a file of its own, found by name:
@@ -27,6 +34,7 @@ to come out ``"correct": false``.
 """
 
 import argparse
+import array
 import importlib.util
 import json
 import os
@@ -45,8 +53,26 @@ sys.path.insert(0, HERE)
 
 from lib import compare, stats                         # noqa: E402
 from lib.loadgen import Load, Req, completion          # noqa: E402
+from lib import serving                                # noqa: E402
 from lib.serving import (BenchFailure, Server, check,   # noqa: E402
-                         get_json, get_text, post_json, prom_samples)
+                         prom_samples)
+
+# every control request this process makes, with its instant: a --trace 2
+# run has to make, between the load's start and the window's end, exactly
+# those a --trace 0 run makes (the ``[requests]`` line says which)
+REQUESTS = []
+
+
+def _noted(method, call):
+    def noted(port, path, *args, **kw):
+        REQUESTS.append((time.monotonic(), f"{method} {path}"))
+        return call(port, path, *args, **kw)
+    return noted
+
+
+get_json = _noted("GET", serving.get_json)
+get_text = _noted("GET", serving.get_text)
+post_json = _noted("POST", serving.post_json)
 
 
 def log(msg):
@@ -440,16 +466,34 @@ class Session:
         by_event = getattr(self.generator, "OPENS_WHEN_READY", False)
         if before is not None:
             before()
-        reqs = self.generator.plan(traffic, cell,
-                                   self.seed if seed is None else seed,
-                                   seconds, self.vocab)
+        seed = self.seed if seed is None else seed
+        reqs = self.generator.plan(traffic, cell, seed, seconds,
+                                   self.vocab)
         mark = get_json(self.port, "/steptrace?kind=none")["next_since"]
         load = self.load = Load(self.port)
-        window = Window(self.port, seconds, trace, traffic, self.out_dir)
-        t0 = time.monotonic()
+        send_until = seconds        # the generator's last instant to send
+        if trace == 2:
+            # the same traffic goes on through the tail, to the instant
+            # the load is stopped: a closed loop's callers just keep
+            # sending (they have requests for minutes); an open loop's
+            # tail is a second plan from a derived seed, so that the
+            # window's own plan does not change with it
+            window = Tail(self.port, seconds, traffic, self.out_dir, mark)
+            send_until = seconds + window.plan_s
+            if not by_event:
+                more = self.generator.plan(
+                    dict(traffic, ramp_s=0.0), cell, seed ^ 0x7A11,
+                    window.plan_s, self.vocab)
+                for r in more:
+                    r.idx, r.due = len(reqs), r.due + seconds
+                    reqs.append(r)
+        else:
+            window = Window(self.port, seconds, trace, traffic,
+                            self.out_dir)
+        t0 = self.t_load = time.monotonic()
         if by_event:
             load.clock.zero = t0                        # provisional
-            ready = self.generator.start(load, reqs, seconds, traffic)
+            ready = self.generator.start(load, reqs, send_until, traffic)
             window.watch(load)
             while not ready.wait(0.2):
                 check(time.monotonic() - t0 < 900, "the fill did not end")
@@ -464,7 +508,7 @@ class Session:
         else:
             load.clock.zero = t0 + traffic["ramp_s"] + 0.05
             load.opened.set()
-            self.generator.start(load, reqs, seconds, traffic)
+            self.generator.start(load, reqs, send_until, traffic)
             window.watch(load)
         load.clock.sleep_until(0.0)
         stage_done("window open")
@@ -476,12 +520,21 @@ class Session:
                 for r in list(load.records)):
             time.sleep(0.05)
         stage_done("window shut")
+        self.t_shut = time.monotonic()
+        frozen = None
+        if trace == 2:
+            # the instant --trace 0 stops the load: what had happened by
+            # now is the measured window's, whatever the tail adds
+            frozen = freeze(load)
+            window.run(load)
+            stage_done("tail over")
         load.stop()
         window.join()
         self.new_shapes = self.shapes_since(mark)
         log(f"[window] step shapes (tokens, rows, pages) first used after "
             f"the load began, ramp included: {self.new_shapes}")
-        records = [r for r in load.records if r.due is not None]
+        window.records = [r for r in load.records if r.due is not None]
+        records = window.records if frozen is None else frozen
         return records, window, load.clock.zero
 
     def shapes_since(self, mark):
@@ -547,8 +600,12 @@ def run(args):
     def in_cell(m):
         return "workloads" not in m or args.workload in m["workloads"]
 
+    between = [what for t, what in REQUESTS
+               if session.t_load <= t < session.t_shut]
+    log(f"[requests] control requests between the load's start and the "
+        f"window's end: {json.dumps(between)}")
     metrics = {}
-    if not args.trace:
+    if args.trace != 1:
         for m in manifest["end_to_end"]:
             if in_cell(m) and e2e.get(m["name"]) is not None:
                 metrics[m["name"]] = {"value": e2e[m["name"]],
@@ -565,10 +622,18 @@ def run(args):
                                 args.cpu_rehearsal, args.keep_trace)
         run_data = dict(
             cell=session.cell, config=session.config, model=session.model,
-            traffic=session.traffic, seconds=seconds, records=records,
+            traffic=session.traffic, seconds=seconds,
+            records=window.records,
             prom0=window.prom0, prom1=window.prom1, steps=window.steps,
             kv_util=window.kv_util, trace=reduced, slice=window.slice,
             peaks=session.peaks, info=info_after, load_module=load_module)
+        if args.trace == 2:
+            # beside the keys the readers have always had: the trace
+            # itself, its idle time by host span (host_gaps.py), and the
+            # steptrace events of the measured window
+            run_data.update(trace_dir=window.trace_dir,
+                            host_gaps=window.gaps,
+                            window_steps=window.window_steps)
         for m in manifest["per_layer"]:
             if not in_cell(m):
                 continue
@@ -579,6 +644,8 @@ def run(args):
             device["busy_s"] = reduced["busy_s"]
             device["window_s"] = reduced["window_s"]
             breakdown = reduced["breakdown"]
+        if args.trace == 2:
+            breakdown = window.finish(breakdown, args.keep_trace)
     with open(os.path.join(session.out_dir, f"records.seed{args.seed}."
                            f"trace{args.trace}.json"), "w") as f:
         json.dump({"e2e": e2e, "metrics": metrics, "verdict": verdict,
@@ -600,7 +667,7 @@ def main():
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
-    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--trace", type=int, choices=(0, 1, 2), default=0)
     ap.add_argument("--cpu-rehearsal", action="store_true")
     ap.add_argument("--control", action="store_true")
     ap.add_argument("--keep-trace", action="store_true",
@@ -631,7 +698,7 @@ class Window:
         self.error = None
 
     def watch(self, load):
-        if self.trace:
+        if self.trace == 1:
             self.thread = threading.Thread(target=self._watch, args=(load,),
                                            daemon=True)
             self.thread.start()
@@ -682,6 +749,8 @@ class Window:
             if self.error is not None:
                 raise BenchFailure(f"reading the window: {self.error}")
 
+    keep_trace_dir = False      # Tail: host_gaps.py reads it as well
+
     def reduce(self, config, env, rehearsal, keep):
         """The trace, reduced by a CPU-only child after the server has
         gone. None where there is no device plane (a CPU rehearsal)."""
@@ -701,11 +770,151 @@ class Window:
                 return None
             raise BenchFailure("trace reduction failed: "
                                + r.stderr.strip()[-600:])
-        if not keep:
+        if not keep and not self.keep_trace_dir:
             shutil.rmtree(os.path.join(self.out_dir, "trace"),
                           ignore_errors=True)
         with open(out_path) as f:
             return json.load(f)
+
+
+def freeze(load):
+    """A copy of the load's records as they stand at this instant, for
+    the end-to-end numbers of a --trace 2 run: what --trace 0 would hold
+    after stopping the load here (an open stream counts as cut now)."""
+    with load.lock:
+        now, out = load.clock.now(), []
+        for r in list(load.records):
+            if r.due is None:
+                continue
+            c = Req(r.idx, r.prompt, r.max_tokens, due=r.due,
+                    client=r.client)
+            c.sent, c.times = r.sent, array.array("d", r.times)
+            c.status = "cut" if r.status == "planned" else r.status
+            c.ended = now if r.ended is None else r.ended
+            out.append(c)
+    return out
+
+
+def host_ms_per_decode_step(events):
+    """Mean host time (the phases other than ``collect``) of the decode
+    steps among steptrace events, and how many there were."""
+    host = [sum(ms for name, ms in e["ph"].items() if name != "collect")
+            for e in events
+            if e.get("kind") == "decode" and isinstance(e.get("ph"), dict)]
+    return (sum(host) / len(host) if host else None), len(host)
+
+
+class Tail(Window):
+    """What a --trace 2 run does AFTER its measured window has closed and
+    its numbers are frozen, while the same traffic goes on: the profiler
+    is started and stopped once and that trace thrown away (the first
+    start of a process costs what no later one does: it falls into no
+    number), then /metrics and the steptrace at both ends of the tail,
+    the KV gauge once a second, and a capture of ``trace_s`` seconds in
+    its middle, a margin from each end. The readers get these under the
+    keys the window's sources have in a --trace 1 run."""
+
+    keep_trace_dir = True
+    margin_s = 1.0
+
+    def __init__(self, port, seconds, traffic, out_dir, mark):
+        super().__init__(port, seconds, 0, traffic, out_dir)
+        self.mark = mark            # the steptrace before the load began
+        self.trace_dir = os.path.join(out_dir, "trace")
+        # how long the traffic is planned to go on: the throwaway start
+        # and stop, the two margins, the capture, and the seconds the
+        # profiler takes to hand a capture over at its stop (measured on
+        # the v5e: PERF.md), with as much again to spare
+        self.plan_s = 2 * self.margin_s + self.trace_s + 120.0
+        self.window_steps, self.gaps = [], None
+
+    def run(self, load):
+        port, zero = self.port, load.clock.zero
+        post_json(port, "/start_profile")
+        post_json(port, "/stop_profile", timeout=300)
+        shutil.rmtree(self.trace_dir, ignore_errors=True)
+        self.prom0 = get_text(port, "/metrics")
+        t_lo = time.monotonic()
+        time.sleep(self.margin_s)
+        started = post_json(port, "/start_profile")
+        t_stop = time.monotonic() + self.trace_s
+        while time.monotonic() < t_stop:
+            util = prom_samples(get_text(port, "/metrics"),
+                                "gllm_sched_kv_util")
+            if util:
+                self.kv_util.append(max(util.values()))
+            time.sleep(max(0.0, min(1.0, t_stop - time.monotonic())))
+        stopped = post_json(port, "/stop_profile", timeout=300)
+        self.stop_s = time.monotonic() - t_stop
+        # the server's own clock at the start and the stop: one machine,
+        # one CLOCK_MONOTONIC, so the slice lies on the load's clock
+        # without the requests' round trips
+        self.slice = (started["t_monotonic"] - zero,
+                      stopped["t_monotonic"] - zero)
+        time.sleep(self.margin_s)
+        self.prom1 = get_text(port, "/metrics")
+        t_hi = time.monotonic()
+        ring = get_json(port, f"/steptrace?since={self.mark}", timeout=300)
+        at = [(ring["t0"] + e["t"], e) for e in ring["events"]]
+        self.steps = [e for t, e in at if t_lo <= t <= t_hi]
+        self.window_steps = [e for t, e in at
+                             if zero <= t < zero + self.seconds]
+        traced = [e for t, e in at if self.slice[0] <= t - zero
+                  <= self.slice[1]]
+        on, n_on = host_ms_per_decode_step(traced)
+        off, n_off = host_ms_per_decode_step(self.window_steps)
+        if on is not None and off:
+            log(f"[trace] host phases per decode step with the capture "
+                f"running: {on:.3f} ms over {n_on} steps of the traced "
+                f"slice; without, in the measured window: {off:.3f} ms "
+                f"over {n_off} steps; inflation "
+                f"{100.0 * (on / off - 1.0):+.2f} %")
+        log(f"[trace] /stop_profile answered after {self.stop_s:.2f} s; "
+            f"slice {self.slice[0]:.2f}-{self.slice[1]:.2f} s on the "
+            f"load's clock")
+
+    def reduce(self, config, env, rehearsal, keep):
+        """Both reductions of the one trace, side by side."""
+        out_path = os.path.join(self.out_dir, "host_gaps.json")
+        gaps = subprocess.Popen(
+            [sys.executable, os.path.join(HERE, "host_gaps.py"),
+             "--trace-dir", self.trace_dir, "--patterns",
+             json.dumps(config.get("trace_patterns", {})),
+             "--out", out_path],
+            env=dict(env, JAX_PLATFORMS="cpu"), text=True,
+            stdout=subprocess.PIPE, stderr=subprocess.PIPE)
+        try:
+            reduced = super().reduce(config, env, rehearsal, keep)
+        finally:
+            _, err = gaps.communicate(timeout=600)
+        if gaps.returncode == 0:
+            with open(out_path) as f:
+                self.gaps = json.load(f)
+        elif rehearsal or reduced is None:
+            log("[trace] no idle time by host span: " + err.strip()[-200:])
+        else:
+            raise BenchFailure("host_gaps.py failed: "
+                               + err.strip()[-600:])
+        return reduced
+
+    def finish(self, breakdown, keep):
+        """The idle gaps named by the host span that covers them, the
+        sum the span metrics have to meet, and the trace deleted."""
+        g = self.gaps
+        if g is not None:
+            if breakdown is not None:
+                breakdown = dict(breakdown, idle_gaps=g["idle_gaps"],
+                                 idle_gaps_named_by=g["idle_gaps_named_by"])
+            total = sum(g["idle_pct_by_phase"].values())
+            log(f"[trace] device idle {g['idle_pct']:.3f} % of the slice "
+                f"= " + " + ".join(f"{k} {v:.3f}" for k, v in
+                                   g["idle_pct_by_phase"].items())
+                + f" = {total:.3f} (identity error "
+                f"{g['identity_error_pct']:.4f} %); clock check: "
+                f"{json.dumps(g['clock'])}")
+        if not keep:
+            shutil.rmtree(self.trace_dir, ignore_errors=True)
+        return breakdown
 
 
 if __name__ == "__main__":

@@ -22,7 +22,10 @@ def reporting(metric):
 
 def test_top_level_keys_are_exactly_the_contracts():
     assert set(M) == {"command", "paths", "run_seconds", "configs",
-                      "workloads", "end_to_end", "per_layer"}
+                      "workloads", "end_to_end", "per_layer",
+                      "trace_in_run"}
+    # one run measures first and traces afterwards (run.py --trace 2)
+    assert M["trace_in_run"] is True
     assert M["command"] == ["python3", "perfbench/run.py"]
     assert M["paths"] == ["perfbench", "tests/perfbench"]
     assert isinstance(M["run_seconds"], int) and 1 <= M["run_seconds"] <= 51

@@ -8,6 +8,7 @@ the test (monkeypatch), never through a program option.
 import json
 import os
 import pathlib
+import re
 import subprocess
 import sys
 import types
@@ -127,14 +128,34 @@ def test_peak_flops_raises_on_an_unlisted_tpu():
     assert peak_flops(_dev("cpu", "cpu")) == 0.0
 
 
-def test_engine_start_fails_on_an_unlisted_tpu(monkeypatch):
-    """Not a peak of 0.0 behind a bare except: the engine refuses to
-    start on a TPU it has no peak for."""
+def test_engine_and_runner_read_no_peak_and_estimate_no_flops(monkeypatch):
+    """The engine loop no longer estimates per step (PR 24): the peak
+    table and the FLOPs model are bench.py's alone. An engine starts
+    without consulting either, its step events carry phases and no
+    estimate, and nothing under engine/ or runner/ names them."""
     from gllm_tpu.engine.llm import LLM
-    monkeypatch.setattr(jax, "devices",
-                        lambda *a: [_dev("tpu", "TPU v9 mystery")])
-    with pytest.raises(ValueError, match="no peak FLOP/s on file"):
-        LLM(config=_config(), model_cfg=ModelConfig(**TINY))
+    from gllm_tpu.obs import spans
+    from gllm_tpu.obs.steptrace import TRACE
+    from gllm_tpu.sampling_params import SamplingParams
+
+    def refuse(*a, **k):
+        raise AssertionError("the engine consulted the peak table")
+    monkeypatch.setattr(spans, "peak_flops", refuse)
+    monkeypatch.setattr(spans.StepFlopsModel, "from_model_config", refuse)
+    llm = LLM(config=_config(), model_cfg=ModelConfig(**TINY))
+    mark = TRACE.mark()
+    llm.generate(prompt_token_ids=[[3, 5, 7]],
+                 sampling_params=SamplingParams(max_tokens=3,
+                                                temperature=0.0,
+                                                ignore_eos=True))
+    steps = [e for e in TRACE.events(since=mark) if "ph" in e]
+    assert steps and all("wait_ms" in e for e in steps)
+    assert not any({"mfu", "hbm_gbps"} & set(e) for e in steps)
+    hits = [str(f.relative_to(REPO))
+            for sub in ("engine", "runner")
+            for f in (REPO / "gllm_tpu" / sub).rglob("*.py")
+            if re.search(r"StepFlopsModel|peak_flops", f.read_text())]
+    assert not hits
 
 
 # ---- KV pool sizing --------------------------------------------------------

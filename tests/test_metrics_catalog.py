@@ -158,3 +158,31 @@ def test_every_span_phase_is_documented_and_vice_versa():
     assert not ghosts, (
         "span-phase-catalog rows no SPANS.event call site emits "
         f"(fix the doc): {ghosts}")
+
+
+# ---- engine-loop phases (ISSUE 24) -----------------------------------------
+#
+# The closed vocabulary of obs/spans.phase: every ``phase("<name>"``
+# call site in gllm_tpu/ uses a name of spans.ENGINE_PHASES (or the
+# nested ``first_use``), every name of the vocabulary is opened
+# somewhere, and the doc's engine-phase catalog has exactly these rows.
+
+_PHASE_RE = re.compile(r"\bphase\(\s*\n?\s*['\"]([a-z_]+)['\"]")
+
+
+def test_every_engine_phase_is_opened_documented_and_vice_versa():
+    from gllm_tpu.obs.spans import ENGINE_PHASES, HOST_PHASES
+    opened = _scan(_PHASE_RE)
+    vocabulary = set(ENGINE_PHASES) | {"first_use"}
+    stray = sorted(set(opened) - vocabulary)
+    assert not stray, (
+        "phase() call sites outside spans.ENGINE_PHASES (extend the "
+        "vocabulary and the doc): "
+        + ", ".join(f"{n} ({os.path.relpath(opened[n], REPO)})"
+                    for n in stray))
+    unopened = sorted(vocabulary - set(opened))
+    assert not unopened, f"phases no call site opens: {unopened}"
+    documented = _catalog("engine-phase-catalog")
+    assert documented == vocabulary, (
+        sorted(documented ^ vocabulary))
+    assert set(HOST_PHASES) < set(ENGINE_PHASES)

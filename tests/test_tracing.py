@@ -2,8 +2,12 @@
 
 - SpanTrace lifecycle units: begin/event/finish, ring bound/eviction,
   open-bound untracking, phase-cap rollup, idempotent close;
-- summarize() attribution math (host_ms_by_phase, overlap_efficiency,
-  bubble_frac, MFU) on synthetic events;
+- summarize() attribution math (host_ms_by_phase, blocked_ms_by_phase,
+  overlap_efficiency, bubble_frac, first-use wall) on synthetic events;
+- the phase clock (obs/spans.phase): the closed vocabulary in ``ph`` on
+  every step path, no annotation object and no jax import while no
+  capture runs, the capture flag over two captures, first_use_ms against
+  its counter, the two front-end histograms;
 - chrome_trace JSON schema (engine-phase tracks + request tracks,
   phase slices reconstruct the step wall);
 - engine e2e on a dummy-weight CPU model: step events carry the phase
@@ -31,8 +35,11 @@ import time
 import pytest
 
 from gllm_tpu.config import CacheConfig, EngineConfig, SchedulerConfig
-from gllm_tpu.obs.spans import (SPANS, SpanTrace, StepFlopsModel,
-                                chrome_trace, peak_flops)
+from gllm_tpu.obs import spans as obs_spans
+from gllm_tpu.obs.spans import (ENGINE_PHASES, HOST_PHASES, SPANS,
+                                SpanTrace, StepFlopsModel, chrome_trace,
+                                peak_flops, phase, step_phases,
+                                take_phases)
 from gllm_tpu.obs.steptrace import StepTrace, summarize
 from gllm_tpu.sampling_params import SamplingParams
 
@@ -113,12 +120,14 @@ def test_flops_model_and_peak():
 # ---- summarize() attribution math ------------------------------------------
 
 def _step_event(tr, kind, t, sched, build, disp, coll, wall, dev,
-                mfu=None, **extra):
+                more=None, **extra):
+    """``more``: seconds by phase beyond the four named ones (intake,
+    output, deliver, idle, and the wait / readback split of collect)."""
+    fields = step_phases(dict(
+        {"schedule": sched / 1e3, "build": build / 1e3,
+         "dispatch": disp / 1e3, "wait": coll / 1e3}, **(more or {})))
     tr.record(kind, num_seqs=2, tokens=2, wall_ms=coll, rtt_ms=wall,
-              ph={"schedule": sched, "build": build, "dispatch": disp,
-                  "collect": coll},
-              step_wall_ms=wall, dev_ms=dev,
-              **({"mfu": mfu} if mfu is not None else {}), **extra)
+              step_wall_ms=wall, dev_ms=dev, **fields, **extra)
     # pin the event's t for deterministic window math
     tr._buf[(tr._next_seq - 1) % tr.capacity]["t"] = t
 
@@ -126,21 +135,34 @@ def _step_event(tr, kind, t, sched, build, disp, coll, wall, dev,
 def test_summarize_attribution_window():
     tr = StepTrace(capacity=64)
     # two decode steps: 10ms wall each, device 8ms, collect 2ms
-    _step_event(tr, "decode", 0.010, 1.0, 2.0, 1.0, 2.0,
-                wall=10.0, dev=8.0, mfu=0.5)
-    _step_event(tr, "decode", 0.020, 1.0, 2.0, 1.0, 2.0,
-                wall=10.0, dev=8.0, mfu=0.5)
+    # ... of which 1.5 waiting for the device and 0.5 reading back; the
+    # second step also carries the first one's output and deliver and
+    # its own pass's intake, and the loop slept 3 ms before it
+    _step_event(tr, "decode", 0.010, 1.0, 2.0, 1.0, 1.5,
+                wall=10.0, dev=8.0, more={"readback": 0.0005})
+    _step_event(tr, "decode", 0.020, 1.0, 2.0, 1.0, 1.5,
+                wall=10.0, dev=8.0,
+                more={"readback": 0.0005, "output": 0.0007,
+                      "deliver": 0.0003, "intake": 0.0001,
+                      "idle": 0.003})
+    tr.record("compile", dispatch="step", source="cache",
+              first_use_ms=1500.0)
     s = summarize(tr.events())
     assert s["host_ms_by_phase"] == {"schedule": 2.0, "build": 4.0,
-                                     "dispatch": 2.0, "collect": 4.0}
+                                     "dispatch": 2.0, "collect": 4.0,
+                                     "output": 0.7, "deliver": 0.3,
+                                     "intake": 0.1}
+    # what the engine thread did NOT spend working rides beside it
+    assert s["blocked_ms_by_phase"] == {"wait": 3.0, "readback": 1.0,
+                                        "idle": 3.0}
+    assert s["compiles"] == 1 and s["first_use_ms"] == 1500.0
     assert s["device_ms_by_kind"] == {"decode": 16.0}
     # hidden = (8-2)*2 of 16 device ms
     assert s["overlap_efficiency"] == pytest.approx(12 / 16)
     # window: first start 0.000 → last end 0.020 = 20ms; 16ms device
     assert s["bubble_frac"] == pytest.approx(1 - 16 / 20, abs=1e-4)
-    # wall mfu: Σ(mfu·dev)/elapsed = 0.5*16/20; device mfu = 0.5
-    assert s["mfu"] == pytest.approx(0.4, abs=1e-4)
-    assert s["device_mfu"] == pytest.approx(0.5, abs=1e-4)
+    # the per-step estimates are gone from the loop and from the summary
+    assert not {"mfu", "device_mfu", "hbm_gbps"} & set(s)
 
 
 def test_summarize_without_attribution_fields_is_none():
@@ -149,7 +171,8 @@ def test_summarize_without_attribution_fields_is_none():
     s = summarize(tr.events())
     assert s["host_ms_by_phase"] is None
     assert s["overlap_efficiency"] is None
-    assert s["bubble_frac"] is None and s["mfu"] is None
+    assert s["bubble_frac"] is None
+    assert s["blocked_ms_by_phase"] is None and s["first_use_ms"] == 0.0
 
 
 # ---- chrome_trace schema ---------------------------------------------------
@@ -157,7 +180,9 @@ def test_summarize_without_attribution_fields_is_none():
 def test_chrome_trace_schema_and_phase_reconstruction():
     tr = StepTrace(capacity=16)
     _step_event(tr, "prefill", 0.050, 2.0, 3.0, 1.0, 4.0,
-                wall=12.0, dev=5.0)
+                wall=12.0, dev=5.0,
+                more={"output": 0.0015, "deliver": 0.0005,
+                      "intake": 0.0002})
     spans = [{"seq_id": 7, "t0": 100.0, "t1": 100.2, "reason": "stop",
               "prompt_tokens": 5, "output_tokens": 3,
               "phases": [{"ph": "queued", "t": 100.0, "dur_ms": 10.0},
@@ -187,12 +212,112 @@ def test_chrome_trace_schema_and_phase_reconstruction():
     assert span_us == pytest.approx(12.0 * 1e3, rel=0.10)
     assert last["ts"] + last["dur"] == pytest.approx(0.050 * 1e6, abs=2)
     assert "prefill:device" in by_name
+    # what the event carries from before its schedule began lies before
+    # it, in the order the loop ran it: output, deliver, intake
+    before = [by_name[f"prefill:{n}"] for n in ("output", "deliver",
+                                                  "intake")]
+    assert before[-1]["ts"] + before[-1]["dur"] \
+        == pytest.approx(first["ts"], abs=2)
+    assert [b["ts"] for b in before] == sorted(b["ts"] for b in before)
     # request track: root slice + children on tid 7
     assert all(e["tid"] == 7 for e in req)
     names = {e["name"] for e in req}
     assert "queued" in names and "decode_chain" in names
     assert any(n.startswith("request 7") for n in names)
     json.dumps(doc)                                   # serializable
+
+
+# ---- the phase clock -------------------------------------------------------
+
+class _CountingAnnotation:
+    made = []
+
+    def __init__(self, name, **args):
+        _CountingAnnotation.made.append((name, args))
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def test_phase_adds_to_the_open_dict_and_makes_no_annotation(monkeypatch):
+    """No capture running: a phase is two clock reads and a dict add. It
+    constructs no annotation object; take_phases hands the dict over and
+    opens an empty one; add=False times a nested span without counting
+    it twice; stop() is idempotent."""
+    monkeypatch.setattr(obs_spans, "_annotation", _CountingAnnotation)
+    _CountingAnnotation.made.clear()
+    assert not obs_spans.capturing()
+    take_phases()
+    with phase("build", step=3):
+        time.sleep(0.002)
+    with phase("build"):
+        pass
+    with phase("dispatch") as outer:
+        with phase("first_use", add=False) as inner:
+            time.sleep(0.001)
+    sched = phase("schedule").start()
+    sched.stop()
+    first = sched.seconds
+    sched.stop()
+    assert sched.seconds == first
+    ph = take_phases()
+    assert set(ph) == {"build", "dispatch", "schedule"}
+    assert ph["build"] >= 0.002 and ph["dispatch"] >= inner.seconds > 0
+    assert outer.seconds == ph["dispatch"]
+    assert take_phases() == {}
+    assert _CountingAnnotation.made == []
+    # ... and with one running, every phase is also an annotation
+    monkeypatch.setattr(obs_spans, "_capturing", True)
+    with phase("wait"):
+        pass
+    with phase("dispatch", step=7, rows=2, tokens=2):
+        pass
+    assert _CountingAnnotation.made == [
+        ("gllm:wait", {}),
+        ("gllm:dispatch", {"step": 7, "rows": 2, "tokens": 2})]
+
+
+def test_obs_imports_no_jax_until_the_first_capture():
+    """``gllm_tpu/obs`` stays importable without jax: the annotation
+    class is imported by set_capture(True), nowhere else."""
+    code = (
+        "import importlib.util, sys\n"
+        "spec = importlib.util.spec_from_file_location('spans', "
+        f"{os.path.join(REPO, 'gllm_tpu', 'obs', 'spans.py')!r})\n"
+        "m = importlib.util.module_from_spec(spec)\n"
+        "sys.modules['spans'] = m\n"
+        "spec.loader.exec_module(m)\n"
+        "with m.phase('schedule'):\n"
+        "    pass\n"
+        "assert 'schedule' in m.take_phases()\n"
+        "assert 'jax' not in sys.modules, 'phase() imported jax'\n"
+        "m.set_capture(True)\n"
+        "assert 'jax' in sys.modules and m.capturing()\n"
+        "m.set_capture(False)\n"
+        "assert not m.capturing()\n")
+    proc = subprocess.run([sys.executable, "-c", code], text=True,
+                          env=dict(os.environ, JAX_PLATFORMS="cpu"),
+                          capture_output=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+
+
+def test_step_phases_fields():
+    f = step_phases({"t_enter": 12.5, "intake": 0.0001, "schedule": 0.001,
+                     "build": 0.002, "dispatch": 0.003, "wait": 0.020,
+                     "readback": 0.0005, "output": 0.0007,
+                     "deliver": 0.0003, "idle": 0.05})
+    assert f["ph"] == {"intake": 0.1, "schedule": 1.0, "build": 2.0,
+                       "dispatch": 3.0, "output": 0.7, "deliver": 0.3,
+                       "collect": 20.5}
+    assert (f["wait_ms"], f["readback_ms"], f["idle_ms"]) \
+        == (20.0, 0.5, 50.0)
+    # a reader that sums ``ph`` without ``collect`` reads host work only
+    assert set(f["ph"]) - {"collect"} == set(HOST_PHASES)
+    assert set(HOST_PHASES) | {"wait", "readback", "idle"} \
+        == set(ENGINE_PHASES)
 
 
 # ---- engine e2e (dummy weights, CPU) ---------------------------------------
@@ -211,8 +336,11 @@ def make_llm(**over):
                        scheduler=SchedulerConfig(max_prefill_tokens=64,
                                                  max_decode_seqs=8),
                        cache=CacheConfig(page_size=4, num_pages=128))
+    mutate = over.pop("_mutate", None)
     for k, v in over.items():
         setattr(cfg, k, v)
+    if mutate is not None:
+        mutate(cfg)
     cfg.validate()
     return LLM(config=cfg, model_cfg=ModelConfig(**TINY_MODEL))
 
@@ -232,20 +360,32 @@ def test_sync_engine_phase_breakdown_and_spans():
     steps = [e for e in TRACE.events(since=mark)
              if e["kind"] in ("prefill", "decode", "fused_block")]
     assert steps, "no step events recorded"
-    tot_ph = tot_wall = 0.0
+    tot_ph = tot_wall = tot_all = 0.0
     for e in steps:
-        assert set(e["ph"]) == {"schedule", "build", "dispatch",
-                                "collect"}
+        assert set(e["ph"]) <= set(HOST_PHASES) | {"collect"}
+        assert {"schedule", "build", "dispatch", "collect"} <= set(e["ph"])
+        assert e["ph"]["collect"] == pytest.approx(
+            e["wait_ms"] + e["readback_ms"], abs=2e-3)
         assert e["dev_ms"] >= 0 and e["step_wall_ms"] > 0
-        ph_sum = sum(e["ph"].values())
-        # phases never exceed the step wall (small scheduling jitter
-        # allowed); the aggregate invariant below is the 10% criterion
+        # the phases of the step itself (schedule-start → collect-end)
+        ph_sum = sum(e["ph"][k] for k in ("schedule", "build",
+                                          "dispatch", "collect"))
+        # never exceed the step wall (small scheduling jitter allowed);
+        # the aggregate invariant below is the 10% criterion
         assert ph_sum <= e["step_wall_ms"] * 1.10 + 0.5
         tot_ph += ph_sum
         tot_wall += e["step_wall_ms"]
+        tot_all += sum(e["ph"].values())
     # synchronous engine (no overlap): phase sums reconstruct the
     # measured step wall within 10%
     assert tot_ph == pytest.approx(tot_wall, rel=0.10)
+    # every millisecond has a name: all phases of all events add up to
+    # the loop's wall from the first step's start to the last one's end
+    # (the last step's own output rides with no later event)
+    loop_ms = (steps[-1]["t"] - steps[0]["t"]) * 1e3 \
+        + steps[0]["step_wall_ms"]
+    assert tot_all == pytest.approx(loop_ms, rel=0.10)
+    assert any("output" in e["ph"] for e in steps[1:])
     s = summarize(steps)
     assert s["host_ms_by_phase"] is not None
     assert set(s["device_ms_by_kind"]) <= {"prefill", "decode",
@@ -275,6 +415,93 @@ def test_fused_engine_records_decode_chain_spans():
     chains = [p for p in rec["phases"] if p["ph"] == "decode_chain"]
     assert chains and all(c["k"] >= 1 for c in chains)
     assert llm.spans.open_count == 0
+
+
+def _dp2(cfg):
+    from gllm_tpu.config import ParallelConfig
+    cfg.parallel = ParallelConfig(dp=2)
+
+
+def _dp2_pipelined(cfg):
+    _dp2(cfg)
+    cfg.overlap_scheduling = cfg.pipelined_loop = True
+
+
+STEP_PATHS = {
+    "sync": {},
+    "fused": dict(overlap_scheduling=True, multi_step_decode=4),
+    "pipelined": dict(overlap_scheduling=True, pipelined_loop=True),
+    "dp": dict(_mutate=_dp2),
+    "dp_pipelined": dict(_mutate=_dp2_pipelined),   # dp super-steps
+}
+
+
+@pytest.mark.parametrize("path", sorted(STEP_PATHS))
+def test_every_phase_of_the_vocabulary_on_every_step_path(path):
+    """Served through the engine loop, every step path (sync, fused
+    blocks, the pipelined loop, the stacked dp program) names all of its
+    time: each step event has the split of its collect, and over a
+    request the events carry every phase of the closed vocabulary."""
+    from gllm_tpu.engine.serving_engine import ServingEngine
+    from gllm_tpu.obs.steptrace import TRACE
+    over = dict(STEP_PATHS[path])
+    mutate = over.pop("_mutate", None)
+    llm = make_llm(**over) if mutate is None else make_llm(_mutate=mutate)
+    eng = ServingEngine(llm)
+    try:
+        time.sleep(0.12)                # the loop idles: nothing to do
+        mark = TRACE.mark()
+        handles = [eng.submit([3, 5, 7, 9 + i],
+                              SamplingParams(max_tokens=10, **GREEDY))
+                   for i in range(3)]
+        for h in handles:
+            assert [c for c in h][-1].finish_reason == "length"
+    finally:
+        eng.shutdown()
+    steps = [e for e in TRACE.events(since=mark)
+             if isinstance(e.get("ph"), dict)]
+    assert steps
+    if path == "fused":
+        assert any(e["kind"] == "fused_block" for e in steps)
+    if path.startswith("dp"):
+        assert all(e.get("dp") for e in steps)
+    seen = set()
+    for e in steps:
+        assert set(e["ph"]) <= set(HOST_PHASES) | {"collect"}
+        assert e["ph"]["collect"] == pytest.approx(
+            e["wait_ms"] + e.get("readback_ms", 0.0), abs=2e-3)
+        seen |= set(e["ph"]) - {"collect"}
+        seen |= {k[:-3] for k in ("wait_ms", "readback_ms", "idle_ms")
+                 if k in e}
+    assert seen == set(ENGINE_PHASES), sorted(set(ENGINE_PHASES) - seen)
+    assert "idle_ms" in steps[0] and steps[0]["idle_ms"] >= 50
+
+
+def test_first_use_ms_on_the_compile_event_agrees_with_the_counter():
+    from gllm_tpu.obs import metrics as obs_metrics
+    from gllm_tpu.obs.steptrace import TRACE
+    llm = make_llm()
+    ctr = obs_metrics.REGISTRY.get("gllm_step_first_use_seconds_total")
+    before = sum(v for _, _, v in ctr.samples())
+    mark = TRACE.mark()
+    llm.generate(prompt_token_ids=[[3, 5, 7, 9]],
+                 sampling_params=SamplingParams(max_tokens=4, **GREEDY))
+    compiles = TRACE.events(since=mark, kinds=["compile"])
+    assert len(compiles) >= 2           # a prefill and a decode signature
+    for e in compiles:
+        assert e["first_use_ms"] > 0
+        assert e["source"] in ("compiled", "cache")
+        assert {"dispatch", "tokens_pad", "seqs_pad", "pages_pad"} <= set(e)
+    grown = sum(v for _, _, v in ctr.samples()) - before
+    assert grown == pytest.approx(
+        sum(e["first_use_ms"] for e in compiles) / 1e3, abs=1e-3)
+    # the same signatures again: no first use, no event, no growth
+    mark = TRACE.mark()
+    llm.generate(prompt_token_ids=[[3, 5, 7, 11]],
+                 sampling_params=SamplingParams(max_tokens=4, **GREEDY))
+    assert TRACE.events(since=mark, kinds=["compile"]) == []
+    assert sum(v for _, _, v in ctr.samples()) - before \
+        == pytest.approx(grown, abs=1e-9)
 
 
 def test_tracing_off_is_byte_identical_and_records_nothing():
@@ -406,12 +633,93 @@ def test_steptrace_kind_filter(trace_server):
     assert kinds <= {"prefill", "decode"}
 
 
+def _hist_count(name):
+    from gllm_tpu.obs import metrics as obs_metrics
+    return obs_metrics.REGISTRY.get(name).snapshot()[2]
+
+
+def test_front_end_histograms_observe_per_request_and_per_chunk(
+        trace_server):
+    """gllm_http_admit_lag_seconds: once per request, whatever its form;
+    gllm_http_emit_lag_seconds: once per stamped SSE chunk — a stream's
+    first token and every eighth after it (a non-streamed reply writes
+    no chunk)."""
+    admit0 = _hist_count("gllm_http_admit_lag_seconds")
+    emit0 = _hist_count("gllm_http_emit_lag_seconds")
+    _drive_completion(trace_server)             # not streamed
+    assert _hist_count("gllm_http_admit_lag_seconds") == admit0 + 1
+    assert _hist_count("gllm_http_emit_lag_seconds") == emit0
+    conn = http.client.HTTPConnection("127.0.0.1", trace_server,
+                                      timeout=120)
+    conn.request("POST", "/v1/completions", body=json.dumps({
+        "prompt": [5, 6, 7, 8], "max_tokens": 18, "temperature": 0,
+        "ignore_eos": True, "stream": True}),
+        headers={"Content-Type": "application/json"})
+    r = conn.getresponse()
+    assert r.status == 200
+    chunks = r.read().count(b'"choices"')
+    conn.close()
+    assert chunks == 18
+    assert _hist_count("gllm_http_admit_lag_seconds") == admit0 + 2
+    # tokens 1, 9 and 17 of the 18 carry the stamp
+    assert _hist_count("gllm_http_emit_lag_seconds") == emit0 + 3
+    status, body = _req(trace_server, "GET", "/metrics")
+    assert b'gllm_http_emit_lag_seconds_bucket{le="5e-05"}' in body
+    assert b'gllm_http_emit_lag_seconds_bucket{le="0.1"}' in body
+
+
+def test_two_captures_leave_the_flag_clear_and_spans_in_the_trace(
+        trace_server, tmp_path, monkeypatch):
+    """/start_profile ... /stop_profile twice in one process: each
+    answers with the server's time.monotonic(), the capture flag is set
+    only in between, the phases are TraceAnnotations in the profiler's
+    trace (Python tracer off), and /steptrace gives the ring's t0 on the
+    same clock."""
+    import glob
+    import jax
+    monkeypatch.setenv("GLLM_PROFILE_DIR", str(tmp_path))
+    for _ in range(2):
+        assert not obs_spans.capturing()
+        t_before = time.monotonic()
+        status, body = _req(trace_server, "POST", "/start_profile")
+        assert status == 200, body
+        started = json.loads(body)
+        assert started["trace_dir"] == str(tmp_path)
+        assert obs_spans.capturing()
+        _drive_completion(trace_server)
+        status, body = _req(trace_server, "POST", "/stop_profile")
+        assert status == 200, body
+        stopped = json.loads(body)
+        assert not obs_spans.capturing()
+        assert t_before <= started["t_monotonic"] \
+            <= stopped["t_monotonic"] <= time.monotonic()
+    status, body = _req(trace_server, "POST", "/stop_profile")
+    assert json.loads(body)["status"] == "noop"
+    newest = sorted(glob.glob(os.path.join(str(tmp_path), "**",
+                                           "*.xplane.pb"),
+                              recursive=True), key=os.path.getmtime)[-1]
+    names = set()
+    for plane in jax.profiler.ProfileData.from_file(newest).planes:
+        for line in plane.lines:
+            names |= {e.name for e in line.events}
+    assert {"gllm:schedule", "gllm:build", "gllm:dispatch", "gllm:wait",
+            "gllm:readback", "gllm:output", "gllm:deliver",
+            "gllm:intake"} <= names, sorted(n for n in names
+                                            if n.startswith("gllm"))
+    # python_tracer_level 0: no Python call of any thread is traced
+    assert not any(n.startswith("$") for n in names)
+    d = json.loads(_req(trace_server, "GET", "/steptrace?kind=none")[1])
+    assert 0 < d["t0"] <= time.monotonic()
+
+
 def test_profile_oneshot_endpoint(trace_server, tmp_path, monkeypatch):
     monkeypatch.setenv("GLLM_PROFILE_DIR", str(tmp_path))
     status, body = _req(trace_server, "POST", "/profile?seconds=0.1")
     assert status == 200, body
     d = json.loads(body)
     assert d["status"] == "ok" and d["trace_dir"] == str(tmp_path)
+    t_start, t_stop = d["t_monotonic"]
+    assert 0.1 <= t_stop - t_start < 5 and not obs_spans.capturing()
     assert os.path.isdir(str(tmp_path))
     # artifact landed (jax profiler writes plugins/profile/<run>/)
     assert any(os.scandir(str(tmp_path)))
@@ -491,16 +799,18 @@ def test_bench_tiny_attribution_smoke(tmp_path):
     for blob in (result, attr):
         hp = blob["host_ms_by_phase"]
         assert hp and sum(hp.values()) > 0
-        assert set(hp) == {"schedule", "build", "dispatch", "collect"}
+        # offline generate() has no serving loop: no intake, no deliver
+        assert set(hp) == {"schedule", "build", "dispatch", "collect",
+                           "output"}
         dm = blob["device_ms_by_kind"]
         assert dm and sum(dm.values()) > 0
         assert blob["overlap_efficiency"] is not None
         assert 0.0 <= blob["overlap_efficiency"] <= 1.0
-    # --tiny declares a nominal CPU peak so both MFU estimators are
-    # exercised; the salvage line keeps the window estimator under its
-    # OWN key (never swapped for the workload-level result mfu)
+    # --tiny declares a nominal CPU peak so the workload-level MFU is
+    # exercised; the per-step window estimator is gone with the engine
+    # loop's FLOPs walk, and the split of collect rides in its place
     assert result["mfu"] is not None and result["mfu"] > 0
-    assert attr["window_mfu"] is not None and attr["window_mfu"] > 0
+    assert "window_mfu" not in attr
     assert result["bubble_frac"] is None \
         or 0.0 <= result["bubble_frac"] <= 1.0
     # chrome artifact loads and has engine + request tracks
