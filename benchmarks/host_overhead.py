@@ -107,11 +107,10 @@ def main():
     bb = BatchBuilder(cfg, cfg.cache.page_size, vocab_size=32000,
                       hidden_size=1024)
     batch = sched.schedule_once()
-    import jax
-    step_key = jax.random.key(0)
+    from gllm_tpu.batching import pack
 
     def build():
-        bb.build(batch, step_key, device=False)
+        pack(bb.build(batch)[0], (1,))
 
     build()
     prepare_us = _time_us(build, args.iters)

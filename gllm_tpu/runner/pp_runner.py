@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from gllm_tpu.batching import pack, unpack
 from gllm_tpu.config import EngineConfig
 from gllm_tpu.models import ModelConfig, get_model_def
 from gllm_tpu.obs import metrics as obs
@@ -114,6 +115,9 @@ class _Stage:
     cos_sin: object = None  # rope table pre-placed on this stage's devices
                             # (re-transferring it every call costs a
                             # host→device copy per stage per step)
+    rng_key: object = None  # the LAST stage samples: the runner's key,
+                            # pre-placed there (the step's key is folded
+                            # from it inside the stage program)
 
 
 class PPModelRunner(ModelRunner):
@@ -327,6 +331,9 @@ class PPModelRunner(ModelRunner):
         for stages in self.replicas:
             for stage in stages:
                 stage.cos_sin = jax.device_put(self.cos_sin, stage.device)
+                if stage.cfg.is_last_stage:
+                    stage.rng_key = jax.device_put(self.rng_key,
+                                                   stage.device)
         if model_cfg.use_mm:
             # the inherited _prepare_mm embeds on stage 0 (visual tower)
             self.params = self.stages[0].params
@@ -392,15 +399,17 @@ class PPModelRunner(ModelRunner):
         attn_impl = getattr(self, "fwd_attn_impl", self.attn_impl)
 
         @functools.partial(jax.jit,
-                           static_argnames=("max_q_len", "logprobs_k",
-                                            "prompt_lp", "spec_sampled",
-                                            "all_greedy"),
+                           static_argnames=("layout", "max_q_len",
+                                            "logprobs_k", "prompt_lp",
+                                            "spec_sampled", "all_greedy"),
                            compiler_options=tpu_compiler_options(),
                            donate_argnums=(1,))
-        def stage(params, kv, batch, cos_sin, hidden, residual,
-                  token_counts, *, max_q_len: int, logprobs_k: int = -1,
-                  prompt_lp: bool = False, spec_sampled: bool = False,
-                  all_greedy: bool = False):
+        def stage(params, kv, packed, cos_sin, hidden, residual,
+                  token_counts, rng_key, *, layout, max_q_len: int,
+                  logprobs_k: int = -1, prompt_lp: bool = False,
+                  spec_sampled: bool = False, all_greedy: bool = False):
+            # rng_key is None on every stage but the last: only it samples
+            batch, _ = unpack(packed, layout, rng_key)
             hidden, residual, kv = fwd(params, kv, batch, scfg,
                                        cos_sin=cos_sin,
                                        attn_impl=attn_impl,
@@ -457,10 +466,14 @@ class PPModelRunner(ModelRunner):
                                        s_src, s_dst, z, r_src, r_dst)
                 stage.kv = stage.kv._replace(conv=conv, rec=rec)
 
-    def _run_pipeline(self, stages, sched_batch, step_key,
+    def _run_pipeline(self, stages, sched_batch, step,
                       prev_handle=None):
         """Launch one microbatch through one replica's stage chain; all
         dispatch is async — returns (tokens_future, aux, num_seqs).
+
+        ``step``: the integers the microbatch's PRNG key is folded from
+        in the last stage's program (the dispatch's ordinal, then the dp
+        replica where there is one).
 
         ``prev_handle``: chain this microbatch off a previous entry's
         on-device sampled tokens (the pipelined loop under pp,
@@ -472,13 +485,13 @@ class PPModelRunner(ModelRunner):
         from gllm_tpu.parallel.mesh import mesh_context
         from gllm_tpu.runner.runner import _spec_sampled, first_use
         build = phase("build").start()
-        batch, max_q, presence = self.builder.build(sched_batch, step_key,
-                                                    device=False)
+        host, max_q, presence = self.builder.build(sched_batch)
+        batch, layout = pack(host, step)
         lp_k, want_plp = self._lp_flags(sched_batch)
         spec_sampled = _spec_sampled(sched_batch.items)
         from gllm_tpu.runner.runner import _all_greedy as _ag
         new_sig = self._note_dispatch(
-            "pp", batch, (max_q, lp_k, want_plp, spec_sampled,
+            "pp", host, (max_q, lp_k, want_plp, spec_sampled,
                           _ag(sched_batch.items)), _ag(sched_batch.items))
         _M_MICROBATCH.inc()
         self._note_kv_read(sched_batch.items)
@@ -507,24 +520,26 @@ class PPModelRunner(ModelRunner):
                 first_use(new_sig):
             hidden = residual = None
             out = None
-            # one batched host→device transfer fans the step batch out to
-            # every stage (and presence to the last) — one dispatch call
-            # instead of per-stage puts
+            # one call fans the packed batch out to every stage (and
+            # presence to the last): the buffer and the tokens once per
+            # stage, where handing over the StepBatch was 13 transfers
+            # per stage
             last = stages[-1]
             targets = [batch] * len(stages)
             devices = [s.device for s in stages]
-            if presence is not None:
-                targets.append(presence)
-                devices.append(last.device)
-            placed = jax.device_put(targets, devices)
-            sbs = list(placed[:len(stages)])
-            presence = placed[len(stages)] if presence is not None else None
             if prev_handle is not None:
                 prev_tokens = prev_handle[0]
                 if getattr(prev_tokens, "ndim", 1) == 2:
                     prev_tokens = prev_tokens[-1]
                 prev_tokens = jax.device_put(prev_tokens, stages[0].device)
-                sbs[0] = self._splice_prev(sbs[0], sched_batch, prev_tokens)
+                targets[0] = self._splice_prev(batch, sched_batch,
+                                               prev_tokens)
+            if presence is not None:
+                targets.append(presence)
+                devices.append(last.device)
+            placed = self._put(targets, devices)
+            sbs = placed[:len(stages)]
+            presence = placed[len(stages)] if presence is not None else None
             for stage, sb in zip(stages, sbs):
                 if hidden is not None:
                     hidden = jax.device_put(hidden, stage.device)
@@ -541,7 +556,9 @@ class PPModelRunner(ModelRunner):
                 with mesh_context(stage.mesh):
                     out, stage.kv = stage.fn(stage.params, stage.kv, sb,
                                              stage.cos_sin, hidden, residual,
-                                             pm, max_q_len=max_q, **lp_kw)
+                                             pm, stage.rng_key,
+                                             layout=layout, max_q_len=max_q,
+                                             **lp_kw)
                 if not stage.cfg.is_last_stage:
                     hidden, residual = out
         tokens, aux = out
@@ -564,8 +581,8 @@ class PPModelRunner(ModelRunner):
             self._prepare_mm(sched_batch)
         self._apply_ssm_intents()
         self._apply_scale_resets()
-        step_key = jax.random.fold_in(self.rng_key, self._step_count)
-        return self._run_pipeline(self.stages, sched_batch, step_key,
+        return self._run_pipeline(self.stages, sched_batch,
+                                  (self._step_count,),
                                   prev_handle=prev_handle)
 
     def collect(self, handle):
@@ -598,14 +615,13 @@ class PPModelRunner(ModelRunner):
                     self._prepare_mm(b)
         self._apply_ssm_intents()
         self._apply_scale_resets()
-        base_key = jax.random.fold_in(self.rng_key, self._step_count)
         handles = []
         for r, b in enumerate(sched_batches):
             if b is None:
                 handles.append(None)
                 continue
-            key = jax.random.fold_in(base_key, r)
-            handles.append(self._run_pipeline(self.replicas[r], b, key))
+            handles.append(self._run_pipeline(self.replicas[r], b,
+                                              (self._step_count, r)))
         return handles
 
     def collect_dp(self, handles):

@@ -1,4 +1,4 @@
-"""Host-side batch building: ScheduledBatch → StepBatch device arrays.
+"""Host-side batch building: ScheduledBatch → StepBatch of host arrays.
 
 Mirrors the reference InputData.cal_input path
 (/root/reference/gllm/input_data.py:338-533): flat token/position/slot
@@ -6,14 +6,17 @@ buffers, query-start offsets, per-seq kv lens and page tables, all padded to
 *bucketed* static shapes so the jit cache stays small (the reference's
 power-of-two CUDA-graph buckets → our compile-cache buckets).
 
-Staging happens in numpy and ships to device as ONE batched
-``jax.device_put`` of the whole StepBatch pytree — a dozen separate
-per-array transfers each paid the dispatch (and, on a remote-attached
-TPU, the network) round trip. The base fill is vectorized (flat scatters
-over ragged rows — the reference's vectorized-fill war story,
-input_data.py:436-476); only rare per-item features (seeds, mm splicing,
-prompt-logprob targets) loop, and only over the items that use them.
-~2 ms at a 256-seq decode bucket, amortized further by the fused
+Staging happens in numpy and stays there: ``build`` returns host arrays
+and touches no jax. The runner then packs them (``batching.pack``: every
+field but ``token_ids`` and ``mm_embeds`` into ONE int32 buffer with a
+static layout) and places the buffer and the tokens, two transfers a
+dispatch. Handing jax the StepBatch pytree as it is was one call but a
+transfer per leaf (13 for a plain decode batch, 2.1 ms of a 7 ms
+``gllm:build`` on a v5e host: PERF.md, PR 25). The base fill is vectorized
+(flat scatters over ragged rows — the reference's vectorized-fill war
+story, input_data.py:436-476); only rare per-item features (seeds, mm
+splicing, prompt-logprob targets) loop, and only over the items that use
+them. ~0.7 ms at the 32-seq decode bucket, amortized further by the fused
 multi-step decode.
 """
 
@@ -21,9 +24,7 @@ from __future__ import annotations
 
 from typing import List, Tuple
 
-import jax
 import numpy as np
-import jax.numpy as jnp
 
 from gllm_tpu.batching import StepBatch
 from gllm_tpu.config import EngineConfig
@@ -112,7 +113,7 @@ class BatchBuilder:
         p = bucket_size(max_pages, 4, self.max_pages_per_seq)
         return t, s, q, p
 
-    def empty(self, signature, step_key, force_extras=frozenset(),
+    def empty(self, signature, force_extras=frozenset(),
               force_bias_len=None):
         """An all-padding StepBatch of the given signature (idle DP
         replicas run these so every replica contributes the same jit
@@ -136,7 +137,7 @@ class BatchBuilder:
                 top_p=np.ones(s_pad, np.float32),
                 top_k=np.full((s_pad,), -1, np.int32),
                 repetition_penalty=np.ones(s_pad, np.float32),
-                step_key=step_key,
+                step_key=None,       # made in the program (unpack)
                 presence_penalty=(np.zeros(s_pad, np.float32)
                                   if "penalties" in force_extras else None),
                 frequency_penalty=(np.zeros(s_pad, np.float32)
@@ -279,20 +280,19 @@ class BatchBuilder:
                 extras.add("spec")
         return frozenset(extras)
 
-    def build(self, batch: ScheduledBatch, step_key,
+    def build(self, batch: ScheduledBatch,
               force_signature=None, force_extras=frozenset(),
-              force_penalty_len=None, force_bias_len=None, device=True):
-        """Returns (StepBatch, max_q_len, token_counts_or_None).
+              force_penalty_len=None, force_bias_len=None):
+        """Returns (StepBatch, max_q_len, token_counts_or_None), all of
+        host numpy leaves: the caller packs and places them
+        (``batching.pack``, ``ModelRunner._put``), alone, stacked over dp
+        replicas or once per pipeline stage. ``sampling.step_key`` is
+        None here: the step program folds it from the dispatch's ordinal
+        (``batching.unpack``).
 
         ``force_signature`` overrides the computed shape buckets and
         ``force_extras`` forces optional fields to exist (DP replicas must
-        agree on one signature + structure per step).
-
-        ``device``: place the whole StepBatch with ONE batched
-        ``jax.device_put`` (a dozen separate small `jnp.asarray` transfers
-        per step would each pay the dispatch round trip). Callers that re-place the batch
-        themselves (dp stacking with shardings, PP per-stage fan-out) pass
-        ``device=False`` and receive host numpy leaves."""
+        agree on one signature + structure per step)."""
         t_pad, s_pad, max_q, p_pad = (force_signature
                                       or self.shape_signature(batch))
         page = self.page_size
@@ -559,7 +559,7 @@ class BatchBuilder:
                 top_p=top_p,
                 top_k=top_k,
                 repetition_penalty=rep_penalty,
-                step_key=step_key,
+                step_key=None,
                 presence_penalty=pres,
                 frequency_penalty=freq,
                 # None keeps the fused single-draw gumbel path (the common
@@ -580,10 +580,4 @@ class BatchBuilder:
             spec_rows=spec_rows_arr,
             spec_drafts=spec_drafts_arr,
         )
-        if device:
-            # one batched transfer for the whole step batch (token_counts
-            # rides separately: its bucketed L changes more often)
-            step_batch = jax.device_put(step_batch)
-            if token_counts is not None:
-                token_counts = jax.device_put(token_counts)
         return step_batch, max_q, token_counts

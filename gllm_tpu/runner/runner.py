@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from gllm_tpu.batching import StepBatch
+from gllm_tpu.batching import PackedBatch, StepBatch, pack, unpack
 from gllm_tpu.config import EngineConfig
 from gllm_tpu.models import ModelConfig, get_model_def
 from gllm_tpu.obs import metrics as obs
@@ -53,6 +53,12 @@ _M_NEW_SHAPE = obs.counter(
     "gllm_jit_new_shape_signatures_total",
     "first dispatch of a (shape-bucket, static-flag) signature this "
     "process — an XLA compile unless the persistent cache held it")
+_M_H2D = obs.counter(
+    "gllm_step_h2d_arrays_total",
+    "host arrays the runner placed on the device for step dispatches "
+    "(counted where they are placed; a transfer each). Over "
+    "gllm_sampler_program_total: arrays per dispatch, 2 on the default "
+    "path (the packed batch and the tokens)")
 # KV-cache dtype observability (docs/observability.md): an info gauge
 # naming the active storage dtype, and a host-side ESTIMATE of KV bytes
 # the attention kernels stream per step (context tokens × per-token
@@ -237,13 +243,25 @@ def reset_page_scales_replica(k_scale, v_scale, r, pages):
 @functools.partial(jax.jit, static_argnames=("k",))
 def _fold_in_range(key, start, *, k: int):
     """[k] per-sub-step keys for a fused decode block:
-    fold_in(key, start + i) for i in range(k), as ONE device program. The
-    host-loop ``jnp.stack([fold_in(...) for i])`` form this replaces paid
-    K eager dispatches per block; the vmapped fold_in is bit-identical
-    (fold_in folds the integer in as data, traced or not) and keeps
-    working as chain lengths grow."""
+    fold_in(key, start + i) for i in range(k). The fused blocks call it
+    inside their programs, on the ``step`` their packed batch carries
+    (batching.unpack); the vmapped fold_in is bit-identical to k
+    fold_in calls from the host (fold_in folds the integer in as data,
+    traced or not) and keeps working as chain lengths grow."""
     steps = start + jnp.arange(k, dtype=jnp.uint32)
     return jax.vmap(lambda i: jax.random.fold_in(key, i))(steps)
+
+
+@jax.jit
+def _scatter_prev(tokens, prev, ir):
+    """``tokens[..., ir[-2]] = prev[..., ir[-1]]``: the promised rows of a
+    re-formed batch take the previous entry's on-device sampled tokens.
+    ``ir`` is ONE host-built index array, [2, n] (flat offset, source
+    row) or, over dp-stacked tokens, [3, n] (replica first). Neither
+    argument is donated: the previous entry's collect still reads
+    ``prev`` (its async host copy may be in flight)."""
+    lead = tuple(ir[:-2])
+    return tokens.at[lead + (ir[-2],)].set(prev[lead + (ir[-1],)])
 
 
 def device_free_bytes(device, memory_util: float) -> float:
@@ -407,6 +425,42 @@ def spec_aux(params, hidden, residual, batch, cfg, token_counts,
         aux["spec_lp"] = tuple(x.reshape((Sk, K1k) + x.shape[1:])
                                for x in slp)
     return aux
+
+
+def _spec_carry(seeds, prev_state, prev_tokens):
+    """A spec block's carry state (ring, ring_len, last_tok, pos, alive,
+    out_step, k_cur), inside its program: the host's seeds
+    (``ModelRunner._spec_seed_state``, out of the packed buffer) merged
+    with what the block chains off. Rows chaining off a previous SPEC
+    block carry its device state wholesale, except joins, which re-seed
+    from the host's arrays, and rows the host has since finished, which
+    are forced dead; rows chaining off a sync single step shift its
+    on-device sampled token into the ring tail (shift-in count 0 =
+    identity for the rows the host knows); a chain root is all seeds."""
+    from gllm_tpu.ops.sampling import ring_shift_in
+    ring, rlen, last, pos, alive, kcur = (
+        seeds[f"spec_{n}"]
+        for n in ("ring", "rlen", "last", "pos", "alive", "kcur"))
+    ostep = seeds.get("spec_ostep")
+    if prev_state is not None:
+        ring_c, rlen_c, last_c, pos_c, alive_c, ostep_c, kcur_c = prev_state
+        rs, dd = seeds["spec_reseed"], seeds["spec_dead"]
+        ring = jnp.where(rs[:, None], ring, ring_c)
+        rlen = jnp.where(rs, rlen, rlen_c)
+        last = jnp.where(rs, last, last_c)
+        pos = jnp.where(rs, pos, pos_c)
+        alive = jnp.where(dd, 0, jnp.where(rs, alive, alive_c))
+        kcur = jnp.where(rs, kcur, kcur_c)
+        if ostep is not None and ostep_c is not None:
+            ostep = jnp.where(rs, ostep, ostep_c)
+    elif prev_tokens is not None:
+        pt = prev_tokens[-1] if prev_tokens.ndim == 2 else prev_tokens
+        pt = pt.astype(jnp.int32)
+        hk = seeds["spec_host_known"]
+        ring, rlen = ring_shift_in(ring, rlen, pt[:, None],
+                                   jnp.where(hk, 0, 1).astype(jnp.int32))
+        last = jnp.where(hk, last, pt)
+    return ring, rlen, last, pos, alive, ostep, kcur
 
 
 def _spec_sampled(items) -> bool:
@@ -803,15 +857,17 @@ class ModelRunner:
             return aux
 
         @functools.partial(jax.jit,
-                           static_argnames=("max_q_len", "logprobs_k",
-                                            "prompt_lp", "ring",
-                                            "spec_sampled", "all_greedy"),
+                           static_argnames=("layout", "max_q_len",
+                                            "logprobs_k", "prompt_lp",
+                                            "ring", "spec_sampled",
+                                            "all_greedy"),
                            donate_argnums=(1,),
                            compiler_options=tpu_compiler_options())
-        def step(params, kv, batch: StepBatch, cos_sin, token_counts,
-                 *, max_q_len: int, logprobs_k: int = -1,
+        def step(params, kv, packed: PackedBatch, cos_sin, token_counts,
+                 rng_key, *, layout, max_q_len: int, logprobs_k: int = -1,
                  prompt_lp: bool = False, ring: bool = False,
                  spec_sampled: bool = False, all_greedy: bool = False):
+            batch, _ = unpack(packed, layout, rng_key)
             hidden, residual, kv = fwd(params, kv, batch, cfg,
                                        cos_sin=cos_sin,
                                        attn_impl=("ring" if ring
@@ -835,9 +891,12 @@ class ModelRunner:
             from jax.sharding import PartitionSpec as P
             from gllm_tpu.parallel.mesh import AXIS_DP
 
-            def one(kv_r, batch_r, counts_r, params, cos_sin, *,
-                    max_q_len, logprobs_k, prompt_lp,
+            def one(kv_r, packed_r, counts_r, params, cos_sin, rng_key, *,
+                    layout, max_q_len, logprobs_k, prompt_lp,
                     spec_sampled=False, all_greedy=False):
+                # each replica unpacks its own buffer: its key is
+                # fold_in(fold_in(rng_key, step), replica)
+                batch_r, _ = unpack(packed_r, layout, rng_key)
                 hidden, residual, kv_r = fwd(params, kv_r, batch_r,
                                              cfg_dp, cos_sin=cos_sin,
                                              attn_impl=attn_impl,
@@ -858,30 +917,32 @@ class ModelRunner:
                 return tokens, kv_r, aux
 
             @functools.partial(jax.jit,
-                               static_argnames=("max_q_len", "logprobs_k",
-                                                "prompt_lp",
+                               static_argnames=("layout", "max_q_len",
+                                                "logprobs_k", "prompt_lp",
                                                 "spec_sampled",
                                                 "all_greedy"),
                                donate_argnums=(1,),
                                compiler_options=tpu_compiler_options())
-            def step_dp(params, kv, batch, cos_sin, token_counts, *,
+            def step_dp(params, kv, batch: PackedBatch, cos_sin,
+                        token_counts, rng_key, *, layout,
                         max_q_len: int, logprobs_k: int = -1,
                         prompt_lp: bool = False,
                         spec_sampled: bool = False,
                         all_greedy: bool = False):
-                kw = dict(max_q_len=max_q_len, logprobs_k=logprobs_k,
-                          prompt_lp=prompt_lp, spec_sampled=spec_sampled,
-                          all_greedy=all_greedy)
+                kw = dict(layout=layout, max_q_len=max_q_len,
+                          logprobs_k=logprobs_k, prompt_lp=prompt_lp,
+                          spec_sampled=spec_sampled, all_greedy=all_greedy)
                 if attn_impl not in ("pallas", "unified") or mesh is None:
                     # XLA attention: plain vmap over stacked replicas —
                     # GSPMD partitions the batched program over the
                     # dp-sharded leading axis on its own.
                     if token_counts is None:
                         return jax.vmap(lambda k, b: one(
-                            k, b, None, params, cos_sin, **kw))(kv, batch)
+                            k, b, None, params, cos_sin, rng_key,
+                            **kw))(kv, batch)
                     return jax.vmap(lambda k, b, c: one(
-                        k, b, c, params, cos_sin, **kw))(kv, batch,
-                                                         token_counts)
+                        k, b, c, params, cos_sin, rng_key,
+                        **kw))(kv, batch, token_counts)
 
                 # Pallas attention: GSPMD cannot partition a custom call
                 # over the dp axis, so the replica loop runs MANUAL over
@@ -900,37 +961,38 @@ class ModelRunner:
                     aux_spec["lp"] = (P(AXIS_DP),) * 3
                 if prompt_lp:
                     aux_spec["plp"] = (P(AXIS_DP),) * 3
-                if batch.spec_rows is not None:
+                if layout.has("spec_rows"):
                     aux_spec["spec"] = (P(AXIS_DP),) * 2
                     if logprobs_k >= 0:
                         aux_spec["spec_lp"] = (P(AXIS_DP),) * 3
 
-                def body(kv_s, batch_s, counts_s, params_s, cos_s):
+                def body(kv_s, batch_s, counts_s, params_s, cos_s, key_s):
                     sq = lambda t: jax.tree.map(lambda x: x[0], t)
                     tokens, kv_r, aux = one(
                         sq(kv_s), sq(batch_s),
                         None if counts_s is None else sq(counts_s),
-                        params_s, cos_s, **kw)
+                        params_s, cos_s, key_s, **kw)
                     ex = lambda t: jax.tree.map(lambda x: x[None], t)
                     return ex(tokens), ex(kv_r), ex(aux)
 
                 out_specs = (P(AXIS_DP), dp_s(kv), aux_spec)
                 if token_counts is None:
                     fn = shard_map(
-                        lambda k, b, p, c: body(k, b, None, p, c),
+                        lambda k, b, p, c, r: body(k, b, None, p, c, r),
                         mesh=mesh,
                         in_specs=(dp_s(kv), dp_s(batch), rep(params),
-                                  rep(cos_sin)),
+                                  rep(cos_sin), P()),
                         out_specs=out_specs,
                         axis_names={AXIS_DP}, check_vma=False)
-                    return fn(kv, batch, params, cos_sin)
+                    return fn(kv, batch, params, cos_sin, rng_key)
                 fn = shard_map(
                     body, mesh=mesh,
                     in_specs=(dp_s(kv), dp_s(batch), dp_s(token_counts),
-                              rep(params), rep(cos_sin)),
+                              rep(params), rep(cos_sin), P()),
                     out_specs=out_specs,
                     axis_names={AXIS_DP}, check_vma=False)
-                return fn(kv, batch, token_counts, params, cos_sin)
+                return fn(kv, batch, token_counts, params, cos_sin,
+                          rng_key)
 
             self._step_fn_dp = step_dp
         return step
@@ -974,8 +1036,8 @@ class ModelRunner:
 
         def pad_pairs(pairs, n):
             pairs = pairs + [(0, 0)] * (n - len(pairs))
-            return (jnp.asarray([p[0] for p in pairs], jnp.int32),
-                    jnp.asarray([p[1] for p in pairs], jnp.int32))
+            return (np.asarray([p[0] for p in pairs], np.int32),
+                    np.asarray([p[1] for p in pairs], np.int32))
 
         for r, mm in enumerate(mms):
             if mm is None or not getattr(mm, "use_ssm", False):
@@ -988,8 +1050,8 @@ class ModelRunner:
             rest = [(a, b) for k, a, b in intents if k == "restore"]
             # pow2 padding keeps the jit-shape count logarithmic
             s_src, s_dst = pad_pairs(snap, next_pow2(len(snap), 1))
-            z = jnp.asarray(zero + [0] * (next_pow2(len(zero), 1)
-                                          - len(zero)), jnp.int32)
+            z = np.asarray(zero + [0] * (next_pow2(len(zero), 1)
+                                         - len(zero)), np.int32)
             r_src, r_dst = pad_pairs(rest, next_pow2(len(rest), 1))
             yield r, (s_src, s_dst, z, r_src, r_dst)
 
@@ -1042,7 +1104,7 @@ class ModelRunner:
             if pages:
                 idx = np.zeros(next_pow2(len(pages), 1), np.int32)
                 idx[:len(pages)] = pages     # pad → dummy page 0
-                yield r, jnp.asarray(idx)
+                yield r, idx
 
     def _apply_scale_resets(self) -> None:
         """int8 KV cache: zero the scales of pages minted since the last
@@ -1101,6 +1163,16 @@ class ModelRunner:
                 "tokens": tokens}
 
     @staticmethod
+    def _put(tree, sharding=None):
+        """Place the host (numpy) leaves of ``tree`` for a step dispatch:
+        one jax call, one transfer per leaf, each counted
+        (gllm_step_h2d_arrays_total). Leaves that are on the device
+        already (spliced-in tokens of the previous step) pass through."""
+        _M_H2D.inc(sum(isinstance(x, np.ndarray)
+                       for x in jax.tree.leaves(tree)))
+        return jax.device_put(tree, sharding)
+
+    @staticmethod
     def _lp_flags(sched_batch: ScheduledBatch):
         """(logprobs_k, prompt_lp) static flags for this batch."""
         k = -1
@@ -1140,7 +1212,6 @@ class ModelRunner:
         self._apply_ssm_intents()
         self._apply_swap_intents()   # no-op under dp>1 (tier is gated)
         self._step_count += 1
-        base_key = jax.random.fold_in(self.rng_key, self._step_count)
 
         live = [b for b in sched_batches if b is not None]
         assert live, "step_async_dp needs at least one non-empty batch"
@@ -1170,38 +1241,37 @@ class ModelRunner:
                  for b in live for it in b.items
                  if it.seq.sampling_params.logit_bias])
 
-        parts = []
+        # per replica: build, pack (its key folds the step, then its
+        # index r); the packed batches stack on a leading axis that is
+        # placed over the mesh's dp axis
+        parts, layouts = [], set()
         counts_any = False
         for r, b in enumerate(sched_batches):
-            key = jax.random.fold_in(base_key, r)
             if b is None:
-                parts.append((self.builder.empty(
-                    sig, key, extras, force_bias_len=bias_len), None))
+                host, counts = self.builder.empty(
+                    sig, extras, force_bias_len=bias_len), None
             else:
-                batch, _, counts = self.builder.build(
-                    b, key, force_signature=sig, force_extras=extras,
-                    force_penalty_len=pen_len, force_bias_len=bias_len,
-                    device=False)   # stacked + sharded below
+                host, _, counts = self.builder.build(
+                    b, force_signature=sig, force_extras=extras,
+                    force_penalty_len=pen_len, force_bias_len=bias_len)
                 counts_any = counts_any or counts is not None
-                parts.append((batch, counts))
+            packed, layout = pack(host, (self._step_count, r))
+            layouts.add(layout)
+            parts.append((packed, counts))
+        assert len(layouts) == 1, "dp replicas disagree on a batch layout"
         token_counts = None
         if counts_any:
             from gllm_tpu.ops.sampling import PenaltyTokens
             blank = PenaltyTokens(np.zeros((sig[1], pen_len), np.int32),
                                   np.zeros((sig[1], pen_len), bool))
             token_counts = jax.tree.map(
-                lambda *xs: jnp.stack(xs),
+                lambda *xs: np.stack(xs),
                 *[c if c is not None else blank for _, c in parts])
-        stacked = jax.tree.map(lambda *xs: jnp.stack(xs),
+        stacked = jax.tree.map(lambda *xs: np.stack(xs),
                                *[p[0] for p in parts])
-        if self.mesh is not None:
-            def put(x):
-                spec = P("dp", *([None] * (x.ndim - 1)))
-                return jax.device_put(x, NamedSharding(self.mesh, spec))
-            stacked = jax.tree.map(put, stacked)
-            if token_counts is not None:
-                token_counts = jax.device_put(
-                    token_counts, NamedSharding(self.mesh, P("dp")))
+        over_dp = (NamedSharding(self.mesh, P("dp"))
+                   if self.mesh is not None else None)
+        stacked, token_counts = self._put((stacked, token_counts), over_dp)
         if prev_handle is not None:
             stacked = self._splice_prev_dp(stacked, sched_batches,
                                            prev_handle[0])
@@ -1214,7 +1284,7 @@ class ModelRunner:
         all_greedy_dp = all(_all_greedy(b.items) for b in live)
         spec_sampled_dp = any(_spec_sampled(b.items) for b in live)
         self._note_kv_read([it for b in live for it in b.items])
-        new_sig = self._note_dispatch("dp_step", stacked,
+        new_sig = self._note_dispatch("dp_step", host,
                                       (max_q, lp_k, want_plp,
                                        spec_sampled_dp, all_greedy_dp),
                                       all_greedy_dp)
@@ -1226,7 +1296,8 @@ class ModelRunner:
             with mesh_context(self.mesh), first_use(new_sig):
                 tokens, self.kv, aux = self._step_fn_dp(
                     self.params, self.kv, stacked, self.cos_sin,
-                    token_counts, max_q_len=max_q, logprobs_k=lp_k,
+                    token_counts, self.rng_key, layout=layout,
+                    max_q_len=max_q, logprobs_k=lp_k,
                     prompt_lp=want_plp, spec_sampled=spec_sampled_dp,
                     all_greedy=all_greedy_dp)
             _start_host_copy((tokens, aux))
@@ -1263,20 +1334,20 @@ class ModelRunner:
         self._apply_ssm_intents()
         self._apply_swap_intents()
         self._step_count += 1
-        step_key = jax.random.fold_in(self.rng_key, self._step_count)
-        batch, max_q, token_counts = self.builder.build(sched_batch,
-                                                        step_key)
+        host, max_q, token_counts = self.builder.build(sched_batch)
+        batch, layout = pack(host, (self._step_count,))
         if prev_handle is not None:
             batch = self._splice_prev(batch, sched_batch, prev_handle[0])
+        batch, token_counts = self._put((batch, token_counts))
         lp_k, want_plp = self._lp_flags(sched_batch)
         ring = (prev_handle is None
-                and self._use_ring(sched_batch, batch.token_ids.shape[0]))
+                and self._use_ring(sched_batch, host.token_ids.shape[0]))
         spec_sampled = _spec_sampled(sched_batch.items)
         all_greedy = _all_greedy(sched_batch.items)
         self._note_kv_read(sched_batch.items)
         new_sig = self._note_dispatch(
-            "step", batch, (max_q, lp_k, want_plp, ring, spec_sampled,
-                            all_greedy), all_greedy)
+            "step", host, (max_q, lp_k, want_plp, ring, spec_sampled,
+                           all_greedy), all_greedy)
         build.stop()
         from gllm_tpu.parallel.mesh import mesh_context
         with phase("dispatch", **self._span_args(
@@ -1284,7 +1355,8 @@ class ModelRunner:
             with mesh_context(self.mesh), first_use(new_sig):
                 tokens, self.kv, aux = self._step_fn(
                     self.params, self.kv, batch, self.cos_sin,
-                    token_counts, max_q_len=max_q, logprobs_k=lp_k,
+                    token_counts, self.rng_key, layout=layout,
+                    max_q_len=max_q, logprobs_k=lp_k,
                     prompt_lp=want_plp, ring=ring,
                     spec_sampled=spec_sampled, all_greedy=all_greedy)
             _start_host_copy((tokens, aux))
@@ -1310,27 +1382,31 @@ class ModelRunner:
                 and it.num_new_tokens >= self.config.sp_ring_threshold
                 and t_pad % sp == 0)
 
-    def _splice_chain_tokens(self, batch: StepBatch, prev_tokens,
+    def _splice_chain_tokens(self, batch: PackedBatch, prev_tokens,
                              host_rows):
         """Input tokens for a chained step: the previous step's on-device
         sampled tokens, except rows JOINING the chain through a vacant
         slot this boundary (ScheduledBatch.host_rows) — their last token
         is host-known and the device array has no row for them, so those
         rows keep the host-built value. One tiny [S] select on device;
-        no new jit-step variant."""
+        no new jit-step variant. ``batch`` comes with its host-built
+        ``token_ids`` not yet placed: an identity chain never places
+        them, a join places them and the mask (the dispatch's third
+        array)."""
         if prev_tokens.ndim == 2:
             prev_tokens = prev_tokens[-1]   # preceding multi-step block
         assert prev_tokens.shape[0] == batch.token_ids.shape[0], \
             (prev_tokens.shape, batch.token_ids.shape)
         if host_rows:
-            from_host = self.builder.host_row_mask(
-                host_rows, batch.token_ids.shape[0])
-            return batch._replace(token_ids=jnp.where(
-                jnp.asarray(from_host), jnp.asarray(batch.token_ids),
-                prev_tokens))
+            from_host, tokens = self._put(
+                (self.builder.host_row_mask(host_rows,
+                                            batch.token_ids.shape[0]),
+                 batch.token_ids))
+            return batch._replace(token_ids=jnp.where(from_host, tokens,
+                                                      prev_tokens))
         return batch._replace(token_ids=prev_tokens)
 
-    def _splice_mapped_tokens(self, batch: StepBatch, prev_tokens,
+    def _splice_mapped_tokens(self, batch: PackedBatch, prev_tokens,
                               sched_batch: ScheduledBatch):
         """Input tokens for a speculatively RE-FORMED batch (pipelined
         loop): item j takes the previous decode entry's on-device
@@ -1340,27 +1416,33 @@ class ModelRunner:
         committed). Unlike :meth:`_splice_chain_tokens` the two sides'
         row buckets may differ (membership changed) and the batch may
         be MIXED, so the splice is a tiny scatter into the flat token
-        axis at each promised item's row offset; no new jit-step
-        variant. NOTE prev_tokens is NOT donated into the new step: the
-        previous entry's collect still reads it (its async host copy
-        may be in flight)."""
+        axis at each promised item's row offset (``_scatter_prev``; its
+        offsets and source rows are the dispatch's third array); no new
+        jit-step variant. NOTE prev_tokens is NOT donated into the new
+        step: the previous entry's collect still reads it (its async
+        host copy may be in flight)."""
         if prev_tokens.ndim == 2:
             prev_tokens = prev_tokens[-1]   # preceding multi-step block
-        idx, rows = [], []
-        off = 0
+        ir = self._promised_rows(sched_batch)
+        if not ir:
+            return batch
+        tokens, ir = self._put((batch.token_ids,
+                                np.asarray(ir, np.int32).T))
+        return batch._replace(
+            token_ids=_scatter_prev(tokens, prev_tokens, ir))
+
+    @staticmethod
+    def _promised_rows(sched_batch: ScheduledBatch):
+        """[(flat token offset, row of the previous entry's tokens)] of a
+        re-formed batch's promised items. A promised row is always a
+        single decode token at the item's flat offset (prefill chunks
+        and joins carry src -1)."""
+        out, off = [], 0
         for it, src in zip(sched_batch.items, sched_batch.src_rows):
             if src >= 0:
-                # a promised row is always a single decode token at the
-                # item's flat offset (prefill chunks carry src -1)
-                idx.append(off)
-                rows.append(src)
+                out.append((off, src))
             off += it.num_new_tokens + len(it.draft_tokens)
-        if not idx:
-            return batch
-        vals = jnp.asarray(prev_tokens)[jnp.asarray(np.asarray(
-            rows, np.int32))]
-        return batch._replace(token_ids=jnp.asarray(batch.token_ids).at[
-            jnp.asarray(np.asarray(idx, np.int32))].set(vals))
+        return out
 
     def _splice_prev_dp(self, stacked, sched_batches, prev_tokens):
         """Dispatch-time input-token splice for a chained dp SUPER-STEP:
@@ -1370,33 +1452,28 @@ class ModelRunner:
         stacked token_ids at each promised item's flat offset — the
         per-replica analogue of :meth:`_splice_mapped_tokens`. Replicas
         scheduled from committed state (src_rows None, including idle
-        dummies) keep their host-built tokens. prev_tokens is NOT
-        donated: the previous entry's collect still reads it."""
-        tok = jnp.asarray(stacked.token_ids)
-        prev = jnp.asarray(prev_tokens)
-        for r, b in enumerate(sched_batches):
-            if b is None or b.src_rows is None:
-                continue
-            idx, rows = [], []
-            off = 0
-            for it, src in zip(b.items, b.src_rows):
-                if src >= 0:
-                    idx.append(off)
-                    rows.append(src)
-                off += it.num_new_tokens + len(it.draft_tokens)
-            if not idx:
-                continue
-            vals = prev[r][jnp.asarray(np.asarray(rows, np.int32))]
-            tok = tok.at[r, jnp.asarray(np.asarray(idx, np.int32))
-                         ].set(vals)
-        return stacked._replace(token_ids=tok)
+        dummies) keep their host-built tokens. ``stacked`` is placed
+        already (its tokens lie over the dp axis); every replica's
+        (replica, offset, source row) triples go over as one array, into
+        one scatter. prev_tokens is NOT donated: the previous entry's
+        collect still reads it."""
+        ir = [(r, off, src) for r, b in enumerate(sched_batches)
+              if b is not None and b.src_rows is not None
+              for off, src in self._promised_rows(b)]
+        if not ir:
+            return stacked
+        return stacked._replace(token_ids=_scatter_prev(
+            stacked.token_ids, prev_tokens,
+            self._put(np.asarray(ir, np.int32).T)))
 
-    def _splice_prev(self, batch: StepBatch, sched_batch: ScheduledBatch,
+    def _splice_prev(self, batch: PackedBatch, sched_batch: ScheduledBatch,
                      prev_tokens):
         """Dispatch-time input-token splice for a batch that chains off
         on-device sampled tokens: the mapped re-form splice when the
         scheduler set ``src_rows`` (membership changed), else the
-        identity chain splice (+ host_rows joins)."""
+        identity chain splice (+ host_rows joins). Takes the packed
+        batch before it is placed and returns it with its tokens on the
+        device."""
         if sched_batch.src_rows is not None:
             return self._splice_mapped_tokens(batch, prev_tokens,
                                               sched_batch)
@@ -1437,27 +1514,25 @@ class ModelRunner:
         self._apply_swap_intents()
         # per-sub-step keys matching the single-step schedule exactly
         # (fold_in of consecutive step counts) → byte-identical sampling
-        # across multi/single scheduling modes; one vmapped program, not
-        # K eager fold_in dispatches
-        keys = _fold_in_range(self.rng_key, self._step_count + 1, k=K)
+        # across multi/single scheduling modes; the block folds them
+        # inside its program from the first sub-step's ordinal
+        step0 = self._step_count + 1
         self._step_count += K
         # pages allocated by the chained schedules must fit the page
         # bucket → size the signature from the LAST step's state
         sig = self.builder.shape_signature(chain[-1])
-        batch, max_q, token_counts = self.builder.build(
-            chain[0], keys[0], force_signature=sig)
+        host, max_q, token_counts = self.builder.build(
+            chain[0], force_signature=sig)
         # chains are all-decode by construction; under the unified
         # signature max_q rides the token bucket (== seq bucket here)
         # instead of pinning to 1
         assert token_counts is None
         assert all(it.num_new_tokens == 1 for it in chain[0].items)
-        if prev_handle is not None:
-            batch = self._splice_prev(batch, chain[0], prev_handle[0])
         # Per-row alive-link count: rows whose seq dies (length cap)
         # inside the block freeze their position and write KV to the
         # dummy page from their death step on; bucket-padding rows are
         # dead for the whole block. None → every real row runs all K.
-        s_bucket = batch.token_ids.shape[0]
+        s_bucket = host.token_ids.shape[0]
         au_np = np.zeros(s_bucket, np.int32)
         n = chain[0].num_seqs
         if chain[0].active_until is not None:
@@ -1474,23 +1549,27 @@ class ModelRunner:
                 chain[0].items, s_bucket, self.eos_token_ids)
             if stop_ids is not None:
                 e_bucket = stop_ids.shape[1]
-                batch = batch._replace(sampling=batch.sampling._replace(
-                    stop_ids=jnp.asarray(stop_ids),
-                    stop_from=jnp.asarray(stop_from)))
+                host = host._replace(sampling=host.sampling._replace(
+                    stop_ids=stop_ids, stop_from=stop_from))
+        # the stop sets and the alive counts ride the packed buffer
+        batch, layout = pack(host, (step0,), active_until=au_np)
+        if prev_handle is not None:
+            batch = self._splice_prev(batch, chain[0], prev_handle[0])
+        batch = self._put(batch)
         all_greedy = _all_greedy(chain[0].items)
         self._note_kv_read(chain[0].items, steps=K)
         # e_bucket is part of the compile signature: stop-set presence
-        # changes the pytree structure and its pow2 width E the shapes
+        # changes the batch layout and its pow2 width E the shapes
         new_sig = self._note_dispatch(
-            "multi_step", batch, (K, all_greedy, odf, e_bucket),
+            "multi_step", host, (K, all_greedy, odf, e_bucket),
             all_greedy)
         build.stop()
         from gllm_tpu.parallel.mesh import mesh_context
         with phase("dispatch", **self._span_args(n, n * K)):
             with mesh_context(self.mesh), first_use(new_sig):
                 tokens, finish_step, self.kv = self._multi_step_fn(
-                    self.params, self.kv, batch, self.cos_sin, keys,
-                    jnp.asarray(au_np), num_steps=K,
+                    self.params, self.kv, batch, self.cos_sin,
+                    self.rng_key, layout=layout, num_steps=K,
                     all_greedy=all_greedy, ondevice_finish=odf)
             aux = ({"finish": (finish_step,)}
                    if finish_step is not None else {})
@@ -1504,15 +1583,20 @@ class ModelRunner:
         attn_impl = self.fwd_attn_impl
         page = self.config.cache.page_size
 
-        @functools.partial(jax.jit, static_argnames=("num_steps",
+        @functools.partial(jax.jit, static_argnames=("layout", "num_steps",
                                                      "all_greedy",
                                                      "ondevice_finish"),
                            compiler_options=tpu_compiler_options(),
                            donate_argnums=(1,))
-        def step_multi(params, kv, batch: StepBatch, cos_sin, keys,
-                       active_until, *, num_steps: int,
+        def step_multi(params, kv, packed: PackedBatch, cos_sin, rng_key,
+                       *, layout, num_steps: int,
                        all_greedy: bool = False,
                        ondevice_finish: bool = False):
+            batch, extra = unpack(packed, layout)
+            active_until = extra["active_until"]
+            # sub-step k samples with fold_in(rng_key, step + k)
+            keys = _fold_in_range(rng_key, extra["step"][0], k=num_steps)
+
             def substep(kv, tokens, alive_n, k, key):
                 # rows whose seq died earlier in the block (length cap
                 # via active_until; EOS/stop via the carried alive count
@@ -1641,14 +1725,17 @@ class ModelRunner:
                                            spec_verify)
 
         @functools.partial(jax.jit,
-                           static_argnames=("num_steps", "k_draft",
-                                            "all_greedy"),
+                           static_argnames=("layout", "num_steps",
+                                            "k_draft", "all_greedy"),
                            compiler_options=tpu_compiler_options(),
                            donate_argnums=(1,))
-        def step_spec(params, kv, batch: StepBatch, cos_sin, keys, state,
-                      *, num_steps: int, k_draft: int,
-                      all_greedy: bool = False):
-            ring0, rlen0, last0, pos0, alive0, ostep0, kcur0 = state
+        def step_spec(params, kv, packed: PackedBatch, cos_sin, rng_key,
+                      prev_state, prev_tokens, *, layout, num_steps: int,
+                      k_draft: int, all_greedy: bool = False):
+            batch, extra = unpack(packed, layout)
+            keys = _fold_in_range(rng_key, extra["step"][0], k=num_steps)
+            ring0, rlen0, last0, pos0, alive0, ostep0, kcur0 = \
+                _spec_carry(extra, prev_state, prev_tokens)
             S = ring0.shape[0]
             K1 = k_draft + 1
             iota = jnp.arange(K1, dtype=jnp.int32)[None, :]   # [1, K1]
@@ -1802,15 +1889,15 @@ class ModelRunner:
         K = len(chain)
         build = phase("build").start()
         self._apply_swap_intents()
-        keys = _fold_in_range(self.rng_key, self._step_count + 1, k=K)
+        step0 = self._step_count + 1
         self._step_count += K
         sig = self.builder.shape_signature(chain[-1])
-        batch, _, token_counts = self.builder.build(chain[0], keys[0],
-                                                    force_signature=sig)
+        host, _, token_counts = self.builder.build(chain[0],
+                                                   force_signature=sig)
         assert token_counts is None, "penalties never reach spec chains"
         assert all(it.num_new_tokens == 1 for it in chain[0].items)
         k_draft = self.config.spec_k
-        s_bucket = batch.attn.page_table.shape[0]
+        s_bucket = host.attn.page_table.shape[0]
         n = chain[0].num_seqs
         au_np = np.zeros(s_bucket, np.int32)
         au_np[:n] = chain[0].active_until    # token budgets (spec chain)
@@ -1821,15 +1908,22 @@ class ModelRunner:
                 absolute=True)
             if stop_ids is not None:
                 e_bucket = stop_ids.shape[1]
-                batch = batch._replace(sampling=batch.sampling._replace(
-                    stop_ids=jnp.asarray(stop_ids),
-                    stop_from=jnp.asarray(stop_from)))
-        state = self._spec_seed_state(batch, chain[0], au_np,
-                                      prev_handle)
+                host = host._replace(sampling=host.sampling._replace(
+                    stop_ids=stop_ids, stop_from=stop_from))
+        # the host's seeds of the carry ride the packed buffer; what the
+        # block chains off stays on the device and is merged with them
+        # inside the program (_spec_carry)
+        seeds, prev_state, prev_tokens = self._spec_seed_state(
+            host.sampling.out_step is not None, chain[0], au_np,
+            prev_handle)
+        batch, layout = pack(host, (step0,), **seeds)
+        # the block feeds itself from the carry: its token_ids are read
+        # by nothing, so nothing is spliced into them
+        batch = self._put(batch)
         all_greedy = _all_greedy(chain[0].items)
         self._note_kv_read(chain[0].items, steps=K)
         new_sig = self._note_dispatch(
-            "spec_block", batch, (K, k_draft, all_greedy, e_bucket),
+            "spec_block", host, (K, k_draft, all_greedy, e_bucket),
             all_greedy)
         build.stop()
         from gllm_tpu.parallel.mesh import mesh_context
@@ -1837,8 +1931,10 @@ class ModelRunner:
             with mesh_context(self.mesh), first_use(new_sig):
                 tokens, counts, totals, kcur, state_out, self.kv = \
                     self._spec_multi_fn(self.params, self.kv, batch,
-                                        self.cos_sin, keys, state,
-                                        num_steps=K, k_draft=k_draft,
+                                        self.cos_sin, self.rng_key,
+                                        prev_state, prev_tokens,
+                                        layout=layout, num_steps=K,
+                                        k_draft=k_draft,
                                         all_greedy=all_greedy)
             aux = {"spec_counts": (counts,), "spec_totals": totals,
                    "spec_kcur": (kcur,), "_spec_state": state_out}
@@ -1846,10 +1942,12 @@ class ModelRunner:
                                        if not k.startswith("_")}))
         return tokens, aux, n
 
-    def _spec_seed_state(self, batch: StepBatch, sched0, au_np,
-                         prev_handle):
-        """Carry state for a spec block: (ring, ring_len, last_tok, pos,
-        alive, out_step, k_cur), each [S_bucket].
+    def _spec_seed_state(self, seeded: bool, sched0, au_np, prev_handle):
+        """The host's share of a spec block's carry state (ring,
+        ring_len, last_tok, pos, alive, out_step, k_cur, each
+        [S_bucket]) as numpy arrays for the packed buffer, and what the
+        block chains off, left on the device: (seeds, prev_state,
+        prev_tokens). :func:`_spec_carry` merges them in the program.
 
         Seeding discipline (docs/speculative_decoding.md#fused): rows
         whose link-0 token is HOST-known (chain roots, slot joins) seed
@@ -1868,7 +1966,6 @@ class ModelRunner:
         rlen = np.zeros(s_bucket, np.int32)
         last = np.zeros(s_bucket, np.int32)
         pos = np.zeros(s_bucket, np.int32)
-        seeded = batch.sampling.out_step is not None
         ostep = np.zeros(s_bucket, np.int32) if seeded else None
         kcur = np.ones(s_bucket, np.int32)
         host_known = np.ones(s_bucket, bool)
@@ -1910,51 +2007,28 @@ class ModelRunner:
             if prev_state is None:
                 prev_tokens = prev_handle[0]
 
-        from gllm_tpu.ops.sampling import ring_shift_in
-        ring = jnp.asarray(ring)
-        rlen = jnp.asarray(rlen)
-        last = jnp.asarray(last)
-        pos = jnp.asarray(pos)
-        alive = jnp.asarray(alive)
-        ostep_j = jnp.asarray(ostep) if seeded else None
-        kcur = jnp.asarray(kcur)
+        seeds = dict(spec_ring=ring, spec_rlen=rlen, spec_last=last,
+                     spec_pos=pos, spec_alive=alive, spec_ostep=ostep,
+                     spec_kcur=kcur)
         if prev_state is not None:
             # chained off a previous spec block: carry its device state;
             # joins/holes re-seed from the host arrays built above
-            (ring_c, rlen_c, last_c, pos_c, alive_c, ostep_c,
-             kcur_c) = prev_state
-            assert ring_c.shape[0] == s_bucket, \
-                (ring_c.shape, s_bucket)    # identity membership
+            assert prev_state[0].shape[0] == s_bucket, \
+                (prev_state[0].shape, s_bucket)    # identity membership
             reseed = np.zeros(s_bucket, bool)
-            for i in sorted(join_rows):
-                reseed[i] = True
-            rs = jnp.asarray(reseed)
-            rs2 = rs[:, None]
-            dd = jnp.asarray(dead)
-            ring = jnp.where(rs2, ring, ring_c)
-            rlen = jnp.where(rs, rlen, rlen_c)
-            last = jnp.where(rs, last, last_c)
-            pos = jnp.where(rs, pos, pos_c)
-            alive = jnp.where(dd, 0, jnp.where(rs, alive, alive_c))
-            kcur = jnp.where(rs, kcur, kcur_c)
-            if seeded:
-                ostep_j = (jnp.where(rs, ostep_j, ostep_c)
-                           if ostep_c is not None else ostep_j)
+            reseed[sorted(join_rows)] = True
+            seeds.update(spec_reseed=reseed, spec_dead=dead)
         elif prev_tokens is not None:
-            # chained off a sync single step: splice its on-device
-            # sampled token as the ring tail + link-0 input for every
-            # row the host doesn't know (shift-in count 0 = identity)
-            pt = prev_tokens[-1] if prev_tokens.ndim == 2 else prev_tokens
-            pt = jnp.asarray(pt).astype(jnp.int32)
-            assert pt.shape[0] == s_bucket, (pt.shape, s_bucket)
-            hk = jnp.asarray(host_known)
-            cnt = jnp.where(hk, 0, 1).astype(jnp.int32)
-            ring, rlen = ring_shift_in(ring, rlen, pt[:, None], cnt)
-            last = jnp.where(hk, last, pt)
+            # chained off a sync single step: its on-device sampled
+            # token becomes the ring tail + link-0 input of every row
+            # the host doesn't know
+            assert prev_tokens.shape[-1] == s_bucket, \
+                (prev_tokens.shape, s_bucket)
+            seeds.update(spec_host_known=host_known)
         else:
             assert host_known[:n].all(), \
                 "spec chain root with device-only tokens but no handle"
-        return (ring, rlen, last, pos, alive, ostep_j, kcur)
+        return seeds, prev_state, prev_tokens
 
     def collect(self, handle):
         """(sampled tokens [n] / [K, n] / [K, n, k+1], aux dict of host

@@ -154,13 +154,12 @@ def test_pt_cache_invalidated_on_preempt_and_rollback():
     seq = Sequence(0, [1, 2, 3, 4, 5, 6, 7], SamplingParams(max_tokens=4))
     seq.page_table = [3, 4]
     seq.num_computed_tokens = 0
-    key = jax.random.key(0)
     sb = ScheduledBatch([ScheduledSeq(seq, 7, 0)])
-    batch, _, _ = b.build(sb, key)
+    batch, _, _ = b.build(sb)
     assert list(np.asarray(batch.attn.page_table)[0][:2]) == [3, 4]
 
     seq.preempt()
     seq.page_table = [9, 10]          # same length, different pages
     seq.num_computed_tokens = 0
-    batch, _, _ = b.build(ScheduledBatch([ScheduledSeq(seq, 7, 0)]), key)
+    batch, _, _ = b.build(ScheduledBatch([ScheduledSeq(seq, 7, 0)]))
     assert list(np.asarray(batch.attn.page_table)[0][:2]) == [9, 10]

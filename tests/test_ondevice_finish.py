@@ -301,7 +301,7 @@ def test_multi_step_body_closes_over_no_buffers(organic):
     import jax.numpy as jnp
     from test_kernel_tuning import _big_consts
 
-    from gllm_tpu.runner.runner import _fold_in_range
+    from gllm_tpu.batching import pack
     from gllm_tpu.scheduler import ScheduledBatch, ScheduledSeq
     from gllm_tpu.sequence import Sequence
 
@@ -312,23 +312,24 @@ def test_multi_step_body_closes_over_no_buffers(organic):
     seq.page_table = [1, 2]
     seq.num_computed_tokens = 3
     items = [ScheduledSeq(seq, 1, 3)]
-    keys = _fold_in_range(runner.rng_key, 1, k=4)
-    batch, max_q, tc = runner.builder.build(ScheduledBatch(items), keys[0])
+    batch, max_q, tc = runner.builder.build(ScheduledBatch(items))
     assert max_q == 1 and tc is None
     s_bucket = batch.token_ids.shape[0]
     stop_ids, stop_from = runner.builder.stop_sets(
         items, s_bucket, runner.eos_token_ids)
     batch = batch._replace(sampling=batch.sampling._replace(
-        stop_ids=jnp.asarray(stop_ids), stop_from=jnp.asarray(stop_from)))
-    au = jnp.full((s_bucket,), 4, jnp.int32)
+        stop_ids=stop_ids, stop_from=stop_from))
+    packed, layout = pack(batch, (1,),
+                          active_until=np.full(s_bucket, 4, np.int32))
 
-    def fn(params, kv, b, cos_sin, ks, au_):
-        return runner._multi_step_fn(params, kv, b, cos_sin, ks, au_,
-                                     num_steps=4, all_greedy=True,
-                                     ondevice_finish=True)
+    def fn(params, kv, b, cos_sin, key):
+        return runner._multi_step_fn(params, kv, b, cos_sin, key,
+                                     layout=layout, num_steps=4,
+                                     all_greedy=True, ondevice_finish=True)
 
-    big = _big_consts(fn, runner.params, runner.kv, batch,
-                      runner.cos_sin, keys, au)
+    big = _big_consts(fn, runner.params, runner.kv,
+                      jax.tree.map(jnp.asarray, packed), runner.cos_sin,
+                      runner.rng_key)
     assert not big, (
         f"multi-step ondevice-finish body closes over buffer-sized "
         f"constants (shape, dtype, nbytes): {big}")
