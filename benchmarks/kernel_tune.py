@@ -32,6 +32,8 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
+from benchmarks.decode_attn_ablation import HBM_BYTES_PER_S  # noqa: E402
+
 CONFIG_TIMEOUT_S = 150
 BLOCKS = (64, 128, 256, 512)
 
@@ -285,63 +287,81 @@ def time_ragged(q_block, kv_block, iters=12, kv_dtype="auto"):
     return _time_reps(run, q, iters, *args, reps=reps)
 
 
-def build_decode(kv_block, gsz=1, S=128, ctx=2048, kv_dtype="auto"):
+# The decode sweep's inputs: the two benchmark cells' geometries first
+# (32 rows, contexts drawn as the ``reason`` mix holds them mid-window, the
+# kernel chained over a step's layers inside one program so that a 0.2 ms
+# call is not timed by its dispatch; these decide the winner), then the
+# old input (128 rows, every row 2048 tokens) for the record.
+DECODE_SHAPES = (
+    dict(name="dense_cell", S=32, Hkv=8, pool=2800, ctx=None, layers=36),
+    dict(name="hybrid_cell", S=32, Hkv=32, pool=4320, ctx=None, layers=36),
+    dict(name="s128_ctx2048", S=128, Hkv=8, pool=None, ctx=2048, layers=1),
+)
+DECODE_BLOCKS = (128, 256, 512, 1024)
+DECODE_GROUPS = (1, 2, 4, 8)
+
+
+def build_decode(kv_block, gsz=1, S=128, ctx=2048, kv_dtype="auto", Hkv=8,
+                 pool=None, layers=1, seed=28):
     """Jitted decode-sweep body + its buffers (caches as args, not
-    closure constants — see build_ragged)."""
+    closure constants — see build_ragged) + the KV bytes one call must
+    read. ``ctx`` None draws the rows' contexts (128-2048, no two alike)
+    and scatters their pages over ``pool``."""
     import jax
     import jax.numpy as jnp
-    from gllm_tpu.ops.pallas.decode_attention import paged_decode_attention
-    Hq, Hkv, D, page = 32, 8, 128, 16
-    P = S * (ctx // page) + 1
-    key = jax.random.key(0)
-    q = jax.random.normal(key, (S, Hq, D), jnp.bfloat16)
-    kl = jnp.full((S,), ctx, jnp.int32)
-    pt = (jnp.arange(S * (ctx // page), dtype=jnp.int32)
-          .reshape(S, ctx // page) + 1)
-    from gllm_tpu.utils import tpu_compiler_options
-
-    interp = _interp()
-
+    import numpy as np
+    from benchmarks.decode_attn_ablation import (build_inputs, chained,
+                                                 reason_contexts)
+    Hq, D, page = 32, 128, 16
+    rng = np.random.default_rng(seed)
+    lens = (reason_contexts(rng, S) if ctx is None
+            else np.full((S,), ctx, np.int32))
+    pool = pool or int(-(-lens // page).sum()) + 1
+    q, kc, vc, kl, pt = build_inputs(rng, Hq, Hkv, pool, S, lens, page, D,
+                                     jnp.bfloat16)
+    kv_bytes = 2 * Hkv * D * int(lens.sum()) * (1 if kv_dtype == "int8"
+                                               else 2)
+    args = (q, kc, vc, kl, pt)
     if kv_dtype == "int8":
-        kc, ks = _quant_caches(key, (P, page, Hkv, D))
-        vc, vs = _quant_caches(jax.random.fold_in(key, 1),
-                               (P, page, Hkv, D))
-
-        @functools.partial(jax.jit,
-                           compiler_options=tpu_compiler_options())
-        def run(qq, kc, vc, ks, vs):
-            return paged_decode_attention(
-                qq, kc, vc, kl, pt, scale=D ** -0.5, kv_block=kv_block,
-                interpret=interp, group_size=gsz, k_scale=ks, v_scale=vs)
-
-        return run, (q, kc, vc, ks, vs)
-
-    kc = jax.random.normal(key, (P, page, Hkv, D), jnp.bfloat16)
-    vc = jax.random.normal(key, (P, page, Hkv, D), jnp.bfloat16)
-
-    @functools.partial(jax.jit, compiler_options=tpu_compiler_options())
-    def run(qq, kc, vc):
-        return paged_decode_attention(qq, kc, vc, kl, pt, scale=D ** -0.5,
-                                      kv_block=kv_block, interpret=interp,
-                                      group_size=gsz)
-
-    return run, (q, kc, vc)
+        key = jax.random.key(seed)
+        kc, ks = _quant_caches(key, kc.shape)
+        vc, vs = _quant_caches(jax.random.fold_in(key, 1), vc.shape)
+        args = (q, kc, vc, kl, pt, ks, vs)
+    return chained(layers, kv_block, gsz, _interp(), D), args, kv_bytes
 
 
 def time_decode(kv_block, gsz=1, iters=25, kv_dtype="auto"):
-    # On the CPU smoke path a silicon-shaped workload (S=128, ctx=2048,
-    # 75 timed interpret-mode calls) runs for hours. Shrink the
-    # interpret workload and announce the geometry up front so a
-    # timeout names where it died instead of leaving a bare TIMEOUT.
+    """Per-call ms summed over the cells' shapes (the ranking), each
+    shape's line printed with the time its KV bytes take at the HBM's
+    peak beside it."""
+    # On the CPU smoke path a silicon-shaped workload runs for hours.
+    # Shrink the interpret workload and announce the geometry up front
+    # so a timeout names where it died instead of leaving a bare TIMEOUT.
+    shapes = DECODE_SHAPES
+    reps = 3
     if _interp():
-        S, ctx, iters, reps = 8, 256, 1, 2
-    else:
-        S, ctx, reps = 128, 2048, 3
+        shapes = (dict(name="cpu_smoke", S=8, Hkv=8, pool=None, ctx=256,
+                       layers=1),)
+        iters, reps = 1, 2
     print(f"EFFECTIVE decode:{kv_block}:{gsz}:{kv_dtype} "
-          f"S={S} ctx={ctx} iters={iters}", flush=True)
-    run, (q, *args) = build_decode(kv_block, gsz, S=S, ctx=ctx,
-                                   kv_dtype=kv_dtype)
-    return _time_reps(run, q, iters, *args, reps=reps)
+          f"shapes={[s['name'] for s in shapes]} iters={iters}", flush=True)
+    ranked = 0.0
+    for shape in shapes:
+        shape = dict(shape)
+        name, layers = shape.pop("name"), shape["layers"]
+        run, (q, *args), kv_bytes = build_decode(kv_block, gsz,
+                                                 kv_dtype=kv_dtype, **shape)
+        n = iters if layers == 1 else max(5, iters // layers)
+        ms = _time_reps(run, q, n, *args, reps=reps) / layers
+        floor_ms = kv_bytes / HBM_BYTES_PER_S * 1e3
+        if not _interp():       # an interpreter's time is no device time
+            print(f"DECODE {name} kv={kv_block} group={gsz}: {ms:.4f} ms "
+                  f"a call; {kv_bytes} KV bytes = {floor_ms:.4f} ms at 819 "
+                  f"GB/s ({100 * floor_ms / ms:.1f} % of the floor)",
+                  flush=True)
+        if name.endswith("_cell") or len(shapes) == 1:
+            ranked += ms
+    return ranked
 
 
 VMEM_PROBE_CONFIGS = ((128, 256), (256, 256), (256, 512), (512, 512),
@@ -514,7 +534,8 @@ def main():
             # (tuning.get() strips the field before kernel kwargs)
             entry["comment"] = (
                 f"benchmarks/kernel_tune.py sweep on {tag} "
-                f"({time.strftime('%Y-%m-%d')})")
+                f"({time.strftime('%Y-%m-%d')}), log in "
+                "chiprun_out/kernel_tune.log")
         with open(_TABLES_PATH, "w") as f:
             json.dump(table, f, indent=1, sort_keys=True)
         print(f"[tune] wrote {_TABLES_PATH} for {tag}",
@@ -550,16 +571,25 @@ def main():
                   flush=True)
         return
 
+    log_path = os.path.join(REPO, "chiprun_out", "kernel_tune.log")
+    os.makedirs(os.path.dirname(log_path), exist_ok=True)
+
+    def say(line):
+        print(line, file=sys.stderr, flush=True)
+        with open(log_path, "a") as f:
+            f.write(line + "\n")
+
     def report(kind, cfg, ms, out):
-        print(f"[tune] {kind} {cfg}: {'%.2f ms' % ms if ms else 'FAIL'}",
-              file=sys.stderr, flush=True)
+        say(f"[tune] {kind} {cfg}: {'%.4f ms' % ms if ms else 'FAIL'}")
+        for ln in out.splitlines():
+            if ln.startswith("DECODE "):     # per-shape times and floors
+                say("[tune]   " + ln)
         if ms is None:
             # a FAIL without its error is undiagnosable after the
             # single-tenant session ends (r5: the decode sweep failed at
             # all block sizes and left no evidence)
-            print("\n".join("[tune]   | " + ln
-                            for ln in out[-1200:].splitlines()[-12:]),
-                  file=sys.stderr, flush=True)
+            say("\n".join("[tune]   | " + ln
+                          for ln in out[-1200:].splitlines()[-12:]))
 
     results = {"ragged": {}, "decode": {}, "unified": {}}
     best = {}
@@ -584,13 +614,14 @@ def main():
             best["ragged"] = {"q_block": int(qb), "kv_block": int(kb)}
             write_best({"ragged": best["ragged"]})
     if args.kernel in (None, "decode"):
-        # group sweep: gsz seqs per program, one in-flight DMA each —
-        # the decode kernel's cost is a chain of DMA latencies, so the
-        # group dimension matters more than the block size
-        for kb, gsz in itertools.product(BLOCKS, (1, 2, 4, 8, 16)):
+        # group sweep: gsz seqs per program, one block in flight each.
+        # A config is ranked by its time over the two cells' shapes
+        # (DECODE_SHAPES); a config whose buffers overflow VMEM at 32 KV
+        # heads fails and is left out.
+        for kb, gsz in itertools.product(DECODE_BLOCKS, DECODE_GROUPS):
             ms, out = run_inner(f"decode:{kb}:{gsz}:{args.kv_dtype}")
             results["decode"][f"{kb}g{gsz}"] = ms
-            report("decode", f"kv={kb} group={gsz}", ms, out)
+            report("decode", f"kv={kb} group={gsz} (cells' sum)", ms, out)
         ok_d = {k: v for k, v in results["decode"].items() if v}
         if ok_d:
             kb, gsz = min(ok_d, key=ok_d.get).split("g")
@@ -615,6 +646,7 @@ def main():
             best["unified"] = {"q_block": int(qb), "kv_block": int(kb),
                                "group": int(gsz)}
             write_best({"unified": best["unified"]})
+    say(json.dumps({"results": results, "best": best}))
     print(json.dumps({"results": results, "best": best}))
 
 

@@ -3,10 +3,10 @@
 NOTE (unified step, docs/overlap_scheduling.md#unified-step): under
 ``--unified-step`` every paged step — pure decode included — routes
 through the unified ragged kernel (ops/pallas/ragged_attention.py,
-``unified=True``), whose decode-class blocks reproduce this kernel's
-grouped round-robin fetch discipline inside the one program. This module
-is kept as the legacy dispatch path (flag off) and as the PARITY ORACLE
-the unified kernel's decode-class path is tested against
+``unified=True``), whose decode-class blocks fetch round-robin with one
+slot a sequence and keep a block update of their own (ROADMAP A3). This
+module is the dispatch path with the flag off (the default) and the
+PARITY ORACLE the unified kernel's decode-class path is tested against
 (tests/test_unified_step.py).
 
 The decode half of the reference's core attention kernel
@@ -16,26 +16,46 @@ triton_decode_attention.py). One query row per sequence attends over that
 sequence's paged KV context.
 
 Design (TPU-first, not a Triton translation):
-- grid = (S,): one program per sequence; each program streams its own page
-  list — HBM traffic is the sequence's *actual* context, independent of the
-  padded page-table bucket (the XLA gather fallback pays the padded extent).
-- KV pages stay in HBM (`pl.ANY`); the kernel double-buffers page blocks
-  into VMEM with async DMA, overlapping fetch with the flash-attention
-  accumulation (online softmax in f32 carried through the kv-block loop).
-- GQA is computed as a kv-head-batched dot: q reshaped to [Hkv, G, D] so
-  every kv head's group hits the MXU together.
+- grid = (S / gsz,): ``gsz`` sequences per program, each streaming its
+  own page list through two buffer slots — HBM traffic is the
+  sequence's *actual* context to the page, independent of the padded
+  page-table bucket (the XLA gather fallback pays the padded extent)
+  and of the block's edge (a context's last block stops at its last
+  page).
+- KV pages stay in HBM (`pl.ANY`) and are read as the pool stores them,
+  token-major with the kv heads folded into the rows of a page
+  ([page * Hkv, D]: a view, no data moves). A block is consumed in that
+  layout and in the cache's dtype: ONE MXU product scores every query
+  head against every row ([Hq, BK * Hkv], float32 accumulation), a mask
+  keeps each query head the rows of its own kv head, and ``p @ V`` over
+  the same rows is the per-head sum (``paged_kv.attend_block``). The
+  MXU does Hkv times the products GQA needs and has them to spare; what
+  it saves is every per-element pass over K and V.
 - The kv-block loop bound is dynamic (ceil(kv_len / block)): padded
   sequences (kv_len 0) skip the loop entirely.
+- The grid runs in order: a sequence's slots go to the next program's
+  sequence as soon as its last block is read, so no program opens on a
+  cold DMA.
 - MLA absorbed mode: ``v_cache=None`` + ``v_dim`` reads values as the
   leading ``v_dim`` lanes of each key block (the latent prefix) — one DMA
   stream instead of two (reference MLA shares the latent cache the same
   way, layers/attention.py:272-293).
+
+What binds (PERF.md section 6, PR 28; the kernel alone on a v5e at 32
+rows of 320-1909 tokens, bf16, 8 and 32 kv heads of 128): the update
+this kernel had until then upcast every block to float32, transposed it
+[BK, Hkv, D] -> [Hkv, BK, D] and fed float32 operands to the MXU with
+the K or V tile stationary, and that, not the DMAs, was 90 % of its
+time: computing on resident blocks alone took 270 us of the 301 us a
+call, fetching alone 233, against 170 us of HBM time for the bytes.
+``benchmarks/decode_attn_ablation.py`` takes the three readings again.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
+import logging
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -43,26 +63,54 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from gllm_tpu.ops.pallas.paged_kv import (attend_block, kv_stream_specs,
-                                          make_fetch_fns, unpack_refs)
+                                          make_fetch_fns, mxu_operand,
+                                          own_head_tokens, unpack_refs)
+
+logger = logging.getLogger(__name__)
 
 DEFAULT_KV_BLOCK = 256
 
 
-def _kernel_grouped(kv_lens_ref, pt_ref,    # scalar prefetch
-                    *refs,
-                    page_size: int, pages_per_block: int, scale: float,
-                    num_kv_heads: int, group: int, head_dim: int,
-                    v_dim: int, shared_kv: bool, mqa: bool, gsz: int,
-                    quant: bool):
-    """``gsz`` sequences per grid program, ONE buffer slot each, fetched
-    round-robin so up to ``gsz`` page DMAs are in flight at once.
+class BlockUpdate(NamedTuple):
+    """The form ``paged_decode_attention`` gives its per-block update,
+    chosen from what it sees of a call and from nothing else."""
+    operand: str        # dtype K, V and q enter the MXU in
+    p_parts: int        # 2: p as a rounded part plus the remainder
+    folded_heads: int   # kv heads in a block's rows (1: no own-head mask)
+    group: int          # query heads a kv head
 
-    Rationale (r5 on-chip): decode compute per kv block is ~0 — the MXU
-    dots are microscopic — so the per-seq double buffer of ``_kernel``
-    degenerates into a chain of bare DMA *latencies* (~44 µs/seq
-    measured; × S/2 programs per core × num_layers ≈ the whole decode
-    step). Interleaving ``gsz`` sequences divides that latency chain by
-    ``gsz`` without paying any padded-extent HBM traffic."""
+
+def block_update(q_dtype, kv_dtype, num_q_heads: int, num_kv_heads: int,
+                 quant: bool = False) -> BlockUpdate:
+    operand = mxu_operand(jnp.dtype(q_dtype), jnp.dtype(kv_dtype), quant)
+    return BlockUpdate(operand.name, 2 if operand.itemsize == 2 else 1,
+                       num_kv_heads, num_q_heads // num_kv_heads)
+
+
+@functools.lru_cache(maxsize=None)
+def _announce(form: BlockUpdate, kv_block: int, gsz: int) -> None:
+    """Once per process and form, as the first program that holds the
+    kernel is traced (the start-up's warm-up, on a server)."""
+    logger.info(
+        "[startup] paged_decode_attention: K, V and q enter the MXU as %s, "
+        "p in %d part(s); %d kv head(s) folded into a block's rows, %d "
+        "query head(s) each; blocks of %d tokens, %d sequence(s) a program",
+        *form, kv_block, gsz)
+
+
+def _kernel(kv_lens_ref, pt_ref,            # scalar prefetch
+            *refs,
+            page_size: int, pages_per_block: int, scale: float,
+            num_kv_heads: int, v_dim: int, shared_kv: bool, gsz: int,
+            quant: bool):
+    """``gsz`` sequences per grid program, TWO buffer slots each: in
+    round ``r`` every sequence that still has a block ``r`` starts the
+    fetch of its block ``r + 1``, waits for block ``r`` and attends it,
+    so ``gsz`` to ``2 gsz`` blocks are in flight while one is attended,
+    and a sequence that outlives its group keeps its own double buffer.
+    The flash state lives in VMEM scratch and only live sequences touch
+    it (see the module docstring for what binds)."""
+    *refs, m_ref, l_ref, acc_ref = refs
     (q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf,
      vs_buf, sems) = unpack_refs(refs, shared_kv, quant)
     gi = pl.program_id(0)
@@ -72,113 +120,67 @@ def _kernel_grouped(kv_lens_ref, pt_ref,    # scalar prefetch
         shared_kv, ks_hbm=ks_hbm, vs_hbm=vs_hbm, ks_buf=ks_buf,
         vs_buf=vs_buf)
 
+    def fetch(fn, s, slot, blk):
+        """Start or wait for block ``blk`` of sequence ``s``, if it has
+        one: a whole block unrolled, a context's last block in a loop to
+        its last page and not to the block's edge (~12 % of the bytes
+        at 256-token blocks)."""
+        left = kv_lens_ref[s] - blk * bk
+
+        @pl.when(left >= bk)
+        def _():
+            fn(slot, s, blk)
+
+        @pl.when((left > 0) & (left < bk))
+        def _():
+            fn(slot, s, blk, pl.cdiv(left, page_size))
+
+    # Every program but the first finds its first blocks on their way
+    # (the grid runs in order): a sequence's pair of slots goes to the
+    # next program's sequence in the round after its last block, so the
+    # HBM stays busy while a group's longest context runs out alone, and
+    # no program opens with a DMA's latency.
+    def start_first(program, g):
+        fetch(start_fetch, program * gsz + g, 2 * g, 0)
+
+    for g in range(gsz):
+        pl.when(gi == 0)(functools.partial(start_first, 0, g))
+
+    def hand_over(g):
+        pl.when(gi + 1 < pl.num_programs(0))(
+            functools.partial(start_first, gi + 1, g))
+
     seq_ids = [gi * gsz + g for g in range(gsz)]
     kv_lens = [kv_lens_ref[s] for s in seq_ids]
     n_blocks = [pl.cdiv(kv_len, bk) for kv_len in kv_lens]
-    for g in range(gsz):
-        @pl.when(n_blocks[g] > 0)
-        def _(g=g):
-            start_fetch(g, seq_ids[g], 0)
-
-    lead = (num_kv_heads * group,) if mqa else (num_kv_heads, group)
-    qs = []
-    for g in range(gsz):
-        q = q_ref[g].astype(jnp.float32) * scale          # [Hq, D]
-        qs.append(q if mqa else q.reshape(num_kv_heads, group, head_dim))
-
+    m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+    own_tokens = own_head_tokens(q_ref.shape[1], num_kv_heads, bk)
     max_nb = n_blocks[0]
     for g in range(1, gsz):
         max_nb = jnp.maximum(max_nb, n_blocks[g])
 
-    def body(r, carry):
-        out = list(carry)
+    def body(r, _):
+        parity = jax.lax.rem(r, 2)
         for g in range(gsz):
-            m, l, acc = out[3 * g], out[3 * g + 1], out[3 * g + 2]
-            live = r < n_blocks[g]
-
-            @pl.when(live)
+            @pl.when(r < n_blocks[g])
             def _(g=g):
-                wait_fetch(g, seq_ids[g], r)
+                fetch(start_fetch, seq_ids[g], 2 * g + 1 - parity, r + 1)
+                fetch(wait_fetch, seq_ids[g], 2 * g + parity, r)
+                m_ref[g], l_ref[g], acc_ref[g] = attend_block(
+                    q_ref[g], k_buf, v_buf, 2 * g + parity, own_tokens,
+                    kv_lens[g] - r * bk, scale, v_dim, shared_kv,
+                    m_ref[g], l_ref[g], acc_ref[g], ks_buf=ks_buf,
+                    vs_buf=vs_buf)
 
-            # NOTE: the next-block re-issue for this slot happens inside
-            # pl.when below, between the (buffered) loads attend_block
-            # performs and the rest of the round-robin — program order
-            # keeps the loads ahead of the re-issued DMA.
-            m_new, l_new, acc_new = attend_block(
-                qs[g], k_buf, v_buf, g, bk, num_kv_heads, head_dim,
-                v_dim, shared_kv, mqa, kv_lens[g], r, m, l, acc,
-                ks_buf=ks_buf, vs_buf=vs_buf)
+            pl.when(r == n_blocks[g])(functools.partial(hand_over, g))
 
-            @pl.when(live & (r + 1 < n_blocks[g]))
-            def _(g=g):
-                start_fetch(g, seq_ids[g], r + 1)
-
-            out[3 * g] = jnp.where(live, m_new, m)
-            out[3 * g + 1] = jnp.where(live, l_new, l)
-            out[3 * g + 2] = jnp.where(live, acc_new, acc)
-        return tuple(out)
-
-    init = []
-    for _ in range(gsz):
-        init += [jnp.full((*lead, 1), -jnp.inf, jnp.float32),
-                 jnp.zeros((*lead, 1), jnp.float32),
-                 jnp.zeros((*lead, v_dim), jnp.float32)]
-    final = jax.lax.fori_loop(0, max_nb, body, tuple(init))
+    jax.lax.fori_loop(0, max_nb, body, None)
     for g in range(gsz):
-        l, acc = final[3 * g + 1], final[3 * g + 2]
-        out = acc / jnp.maximum(l, 1e-30)                # padded seqs → 0
-        o_ref[g] = out.reshape(num_kv_heads * group,
-                               v_dim).astype(o_ref.dtype)
-
-
-def _kernel(kv_lens_ref, pt_ref,            # scalar prefetch
-            *refs,
-            page_size: int, pages_per_block: int, scale: float,
-            num_kv_heads: int, group: int, head_dim: int, v_dim: int,
-            shared_kv: bool, mqa: bool, quant: bool):
-    (q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf,
-     vs_buf, sems) = unpack_refs(refs, shared_kv, quant)
-    s = pl.program_id(0)
-    kv_len = kv_lens_ref[s]
-    bk = pages_per_block * page_size
-    n_blocks = pl.cdiv(kv_len, bk)
-
-    start_fetch, wait_fetch = make_fetch_fns(
-        pt_ref, k_hbm, v_hbm, k_buf, v_buf, sems, pages_per_block,
-        shared_kv, ks_hbm=ks_hbm, vs_hbm=vs_hbm, ks_buf=ks_buf,
-        vs_buf=vs_buf)
-
-    @pl.when(n_blocks > 0)
-    def _():
-        start_fetch(0, s, 0)
-
-    q = q_ref[0].astype(jnp.float32) * scale          # [Hq, D]
-    # MQA (Hkv == 1): keep everything 2-D — scores [Hq, BK] from one
-    # q @ kᵀ MXU dot; the caches arrive 3-D with the head axis squeezed.
-    qh = q if mqa else q.reshape(num_kv_heads, group, head_dim)
-
-    def body(i, carry):
-        m, l, acc = carry
-        slot = jax.lax.rem(i, 2)
-
-        @pl.when(i + 1 < n_blocks)
-        def _():
-            start_fetch(1 - slot, s, i + 1)
-
-        wait_fetch(slot, s, i)
-        return attend_block(qh, k_buf, v_buf, slot, bk, num_kv_heads,
-                            head_dim, v_dim, shared_kv, mqa, kv_len, i,
-                            m, l, acc, ks_buf=ks_buf, vs_buf=vs_buf)
-
-    lead = (num_kv_heads * group,) if mqa else (num_kv_heads, group)
-    m0 = jnp.full((*lead, 1), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((*lead, 1), jnp.float32)
-    acc0 = jnp.zeros((*lead, v_dim), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, n_blocks, body, (m0, l0, acc0))
-
-    out = acc / jnp.maximum(l, 1e-30)                   # padded seqs → 0
-    o_ref[0] = out.reshape(num_kv_heads * group,
-                           v_dim).astype(o_ref.dtype)
+        pl.when(n_blocks[g] == max_nb)(functools.partial(hand_over, g))
+    o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                  ).astype(o_ref.dtype)                 # padded seqs → 0
 
 
 @functools.partial(jax.jit,
@@ -195,14 +197,13 @@ def paged_decode_attention(
     kv_block: int = DEFAULT_KV_BLOCK,
     interpret: bool = False,
     v_dim: Optional[int] = None,
-    group_size: int = 1,       # seqs per grid program (see _kernel_grouped)
+    group_size: int = 1,       # seqs per grid program (see _kernel)
     k_scale: Optional[jnp.ndarray] = None,   # [num_pages, Hkv] f32 (int8)
     v_scale: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     S, num_q_heads, head_dim = q.shape
     num_pages, page_size, num_kv_heads, _ = k_cache.shape
     max_pages = page_table.shape[1]
-    group = num_q_heads // num_kv_heads
     shared_kv = v_cache is None
     quant = k_scale is not None
     if shared_kv:
@@ -211,19 +212,22 @@ def paged_decode_attention(
     else:
         v_dim = v_cache.shape[-1]
 
-    # MQA (MLA's latent cache): squeeze the singleton head axis — Mosaic's
-    # sublane tiling rejects slicing a size-1 second-minor dim — and run
-    # the kernel's 2-D path.
-    mqa = num_kv_heads == 1
-    if quant and (mqa or shared_kv):
+    if quant and (num_kv_heads == 1 or shared_kv):
         raise NotImplementedError(
             "int8 KV cache unsupported for MQA/MLA decode kernels")
-    if mqa:
-        k_cache = k_cache.reshape(num_pages, page_size, head_dim)
-        if v_cache is not None:
-            v_cache = v_cache.reshape(num_pages, page_size, v_dim)
+    # The kernels read a page as [page * Hkv, D]: the token-major pool
+    # with the kv heads folded into the rows, which is the pool's own
+    # order (no data moves), and for MQA the squeeze of the singleton
+    # head axis that Mosaic's sublane tiling asks for anyway.
+    k_cache = k_cache.reshape(num_pages, page_size * num_kv_heads, head_dim)
+    if v_cache is not None:
+        v_cache = v_cache.reshape(num_pages, page_size * num_kv_heads,
+                                  v_dim)
 
     pages_per_block = max(1, min(kv_block // page_size, max_pages))
+    _announce(block_update(q.dtype, k_cache.dtype, num_q_heads,
+                           num_kv_heads, quant),
+              pages_per_block * page_size, max(1, group_size))
     # page_table must cover whole blocks; pad with dummy page 0.
     rem = max_pages % pages_per_block
     if rem:
@@ -231,46 +235,36 @@ def paged_decode_attention(
                              ((0, 0), (0, pages_per_block - rem)))
         max_pages += pages_per_block - rem
 
+    # pad the seq axis to a whole number of groups; padded rows have
+    # kv_len 0 (skip every round) and dummy page-table rows
     gsz = max(1, group_size)
-    if gsz > 1:
-        # pad the seq axis to a whole number of groups; padded rows have
-        # kv_len 0 (skip every round) and dummy page-table rows
-        s_pad = -(-S // gsz) * gsz
-        if s_pad != S:
-            q = jnp.pad(q, ((0, s_pad - S), (0, 0), (0, 0)))
-            kv_lens = jnp.pad(kv_lens, (0, s_pad - S))
-            page_table = jnp.pad(page_table, ((0, s_pad - S), (0, 0)))
-        kernel = functools.partial(
-            _kernel_grouped, page_size=page_size,
-            pages_per_block=pages_per_block, scale=scale,
-            num_kv_heads=num_kv_heads, group=group, head_dim=head_dim,
-            v_dim=v_dim, shared_kv=shared_kv, mqa=mqa, gsz=gsz,
-            quant=quant)
-        slots, n_prog, blk = gsz, s_pad // gsz, gsz
-    else:
-        kernel = functools.partial(
-            _kernel, page_size=page_size, pages_per_block=pages_per_block,
-            scale=scale, num_kv_heads=num_kv_heads, group=group,
-            head_dim=head_dim, v_dim=v_dim, shared_kv=shared_kv, mqa=mqa,
-            quant=quant)
-        slots, n_prog, blk = 2, S, 1
-        s_pad = S
+    s_pad = -(-S // gsz) * gsz
+    if s_pad != S:
+        q = jnp.pad(q, ((0, s_pad - S), (0, 0), (0, 0)))
+        kv_lens = jnp.pad(kv_lens, (0, s_pad - S))
+        page_table = jnp.pad(page_table, ((0, s_pad - S), (0, 0)))
+    kernel = functools.partial(
+        _kernel, page_size=page_size, pages_per_block=pages_per_block,
+        scale=scale, num_kv_heads=num_kv_heads, v_dim=v_dim,
+        shared_kv=shared_kv, gsz=gsz, quant=quant)
 
     kv_specs, scratch_shapes, kv_inputs = kv_stream_specs(
-        k_cache, v_cache, pages_per_block, page_size, num_kv_heads,
-        head_dim, v_dim, mqa=mqa, slots=slots, k_scale=k_scale,
+        k_cache, v_cache, pages_per_block, slots=2 * gsz, k_scale=k_scale,
         v_scale=v_scale)
+    scratch_shapes += [pltpu.VMEM((gsz, num_q_heads, 1), jnp.float32),
+                       pltpu.VMEM((gsz, num_q_heads, 1), jnp.float32),
+                       pltpu.VMEM((gsz, num_q_heads, v_dim), jnp.float32)]
     in_specs = [
-        pl.BlockSpec((blk, num_q_heads, head_dim), lambda s, *_: (s, 0, 0),
+        pl.BlockSpec((gsz, num_q_heads, head_dim), lambda s, *_: (s, 0, 0),
                      memory_space=pltpu.VMEM),
     ] + kv_specs
     inputs = [kv_lens, page_table, q] + kv_inputs
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(n_prog,),
+        grid=(s_pad // gsz,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((blk, num_q_heads, v_dim),
+        out_specs=pl.BlockSpec((gsz, num_q_heads, v_dim),
                                lambda s, *_: (s, 0, 0),
                                memory_space=pltpu.VMEM),
         scratch_shapes=scratch_shapes,
@@ -280,11 +274,9 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_pad, num_q_heads, v_dim),
                                        q.dtype),
-        # Sequences/groups are independent → let Mosaic split the grid
-        # across Megacore TensorCores.
+        # in order: a program starts its successor's first fetches
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)) if interpret else
-        pltpu.CompilerParams(dimension_semantics=("parallel",)),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*inputs)
     return out[:S] if s_pad != S else out

@@ -15,6 +15,7 @@ the decode read path moves half the bytes.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -49,7 +50,8 @@ def unpack_refs(refs, shared_kv: bool, quant: bool):
 def make_fetch_fns(pt_ref, k_hbm, v_hbm, k_buf, v_buf, sems,
                    pages_per_block: int, shared_kv: bool,
                    ks_hbm=None, vs_hbm=None, ks_buf=None, vs_buf=None):
-    """(start_fetch, wait_fetch), each taking (slot, seq, kv_block_idx).
+    """(start_fetch, wait_fetch), each taking (slot, seq, kv_block_idx)
+    and, optionally, ``pages``.
 
     Copies ``pages_per_block`` whole pages per block. Semaphore layout is
     [slot, k_or_v]: ONE DMA semaphore per slot per stream — every page
@@ -59,42 +61,62 @@ def make_fetch_fns(pt_ref, k_hbm, v_hbm, k_buf, v_buf, sems,
     must match 1:1 — the callers' buffer loops guarantee it. Quantized
     caches ride each page's scale row on the same per-stream semaphore
     (one extra tiny copy per page, same 1:1 accounting).
+
+    ``pages`` (a traced count; the same in the start and in its wait)
+    fetches only the block's first ``pages`` pages, in a loop of that
+    many rounds — a context's last block, whose other pages hold nothing
+    of it — and clears the value rows of the rest: their keys are masked
+    by position, but a probability of exactly 0 times whatever VMEM held
+    is not 0 if that is a NaN.
     """
     quant = ks_hbm is not None
+    val_buf, val_scale = (k_buf, ks_buf) if shared_kv else (v_buf, vs_buf)
 
-    def start_fetch(slot, s, blk):
-        for j in range(pages_per_block):
-            page_idx = pt_ref[s, blk * pages_per_block + j]
-            pltpu.make_async_copy(k_hbm.at[page_idx], k_buf.at[slot, j],
-                                  sems.at[slot, 0]).start()
+    def page_copies(slot, s, blk, j):
+        page_idx = pt_ref[s, blk * pages_per_block + j]
+        copies = [pltpu.make_async_copy(k_hbm.at[page_idx],
+                                        k_buf.at[slot, j], sems.at[slot, 0])]
+        if quant:
+            copies.append(pltpu.make_async_copy(
+                ks_hbm.at[page_idx], ks_buf.at[slot, j], sems.at[slot, 0]))
+        if not shared_kv:
+            copies.append(pltpu.make_async_copy(
+                v_hbm.at[page_idx], v_buf.at[slot, j], sems.at[slot, 1]))
             if quant:
-                pltpu.make_async_copy(ks_hbm.at[page_idx],
-                                      ks_buf.at[slot, j],
-                                      sems.at[slot, 0]).start()
-            if not shared_kv:
-                pltpu.make_async_copy(v_hbm.at[page_idx], v_buf.at[slot, j],
-                                      sems.at[slot, 1]).start()
-                if quant:
-                    pltpu.make_async_copy(vs_hbm.at[page_idx],
-                                          vs_buf.at[slot, j],
-                                          sems.at[slot, 1]).start()
+                copies.append(pltpu.make_async_copy(
+                    vs_hbm.at[page_idx], vs_buf.at[slot, j],
+                    sems.at[slot, 1]))
+        return copies
 
-    def wait_fetch(slot, s, blk):
-        for j in range(pages_per_block):
-            page_idx = pt_ref[s, blk * pages_per_block + j]
-            pltpu.make_async_copy(k_hbm.at[page_idx], k_buf.at[slot, j],
-                                  sems.at[slot, 0]).wait()
+    def over_pages(lo, hi, fn):
+        """``fn(j)`` for the block's pages lo <= j < hi: unrolled where
+        both ends are static, a loop where one is traced."""
+        if isinstance(lo, int) and isinstance(hi, int):
+            for j in range(lo, hi):
+                fn(j)
+        else:
+            jax.lax.fori_loop(lo, hi, lambda j, _: fn(j), None)
+
+    def start_fetch(slot, s, blk, pages=pages_per_block):
+        def start(j):
+            for c in page_copies(slot, s, blk, j):
+                c.start()
+
+        def clear(j):
+            val_buf[slot, j] = jnp.zeros(val_buf.shape[2:], val_buf.dtype)
             if quant:
-                pltpu.make_async_copy(ks_hbm.at[page_idx],
-                                      ks_buf.at[slot, j],
-                                      sems.at[slot, 0]).wait()
-            if not shared_kv:
-                pltpu.make_async_copy(v_hbm.at[page_idx], v_buf.at[slot, j],
-                                      sems.at[slot, 1]).wait()
-                if quant:
-                    pltpu.make_async_copy(vs_hbm.at[page_idx],
-                                          vs_buf.at[slot, j],
-                                          sems.at[slot, 1]).wait()
+                val_scale[slot, j] = jnp.zeros(val_scale.shape[2:],
+                                               val_scale.dtype)
+
+        over_pages(0, pages, start)
+        over_pages(pages, pages_per_block, clear)
+
+    def wait_fetch(slot, s, blk, pages=pages_per_block):
+        def wait(j):
+            for c in page_copies(slot, s, blk, j):
+                c.wait()
+
+        over_pages(0, pages, wait)
 
     return start_fetch, wait_fetch
 
@@ -131,85 +153,116 @@ def block_kv(k_buf, v_buf, slot, bk: int, num_kv_heads: int,
     return k, v
 
 
-def attend_block(qh, k_buf, v_buf, slot, bk: int, num_kv_heads: int,
-                 head_dim: int, v_dim: int, shared_kv: bool, mqa: bool,
-                 kv_len, blk_idx, m, l, acc, ks_buf=None, vs_buf=None):
-    """One kv-block online-softmax update, shared by the decode kernels.
+def own_head_tokens(num_q_heads: int, num_kv_heads: int, bk: int):
+    """[Hq, BK * Hkv] int32 for a block of folded rows (row = token *
+    Hkv + kv head): the token a row holds where the row's kv head is the
+    query head's own, and a token no context reaches where it is another
+    head's. ``attend_block`` compares it with the tokens left."""
+    shape = (num_q_heads, bk * num_kv_heads)
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    if num_kv_heads == 1:
+        return row
+    own = row % num_kv_heads == jax.lax.broadcasted_iota(
+        jnp.int32, shape, 0) // (num_q_heads // num_kv_heads)
+    return jnp.where(own, row // num_kv_heads, jnp.iinfo(jnp.int32).max)
 
-    ``qh`` is the pre-scaled query ([Hq, D] in mqa mode, else
-    [Hkv, G, D]); (m, l, acc) is the running flash-attention state.
-    Returns the updated (m, l, acc). Keys past ``kv_len`` are masked."""
-    import jax
-    import jax.numpy as jnp
-    kv_axis = 1 if mqa else 2
-    k, v = block_kv(k_buf, v_buf, slot, bk, num_kv_heads, head_dim,
-                    v_dim, shared_kv, mqa=mqa, ks_buf=ks_buf,
-                    vs_buf=vs_buf)
-    if mqa:
-        kt = k.astype(jnp.float32)                      # [BK, D]
-        vt = v.astype(jnp.float32)                      # [BK, Dv]
-        scores = jax.lax.dot_general(                   # [Hq, BK]
-            qh, kt, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+
+def mxu_operand(q_dtype, kv_dtype, quant: bool):
+    """The dtype K, V and q enter the MXU in. A 16-bit cache is used as
+    stored where q has its dtype: the products are exact in the float32
+    accumulator, and p, the one float32 operand left, then goes in as a
+    rounded part plus the rounding's remainder, so nothing is lost
+    against float32 operands. Anything else (float32 caches, int8 blocks
+    dequantized in VMEM, a q of another dtype) keeps float32 operands."""
+    if (not quant and q_dtype == kv_dtype
+            and jnp.dtype(kv_dtype).itemsize == 2):
+        return jnp.dtype(kv_dtype)
+    return jnp.dtype(jnp.float32)
+
+
+def attend_block(q, k_buf, v_buf, slot, own_tokens, tokens_left, scale,
+                 v_dim: int, shared_kv: bool, m, l, acc, ks_buf=None,
+                 vs_buf=None):
+    """One kv-block online-softmax update of the decode kernel.
+
+    The block is consumed as the DMA left it: ``k_buf[slot]`` is
+    [ppb, page * Hkv, D], every page's tokens with their kv heads folded
+    into the rows, and is never widened or re-laid-out. One MXU product
+    scores all query heads against all rows ([Hq, R], R = BK * Hkv);
+    ``own_tokens`` (``own_head_tokens``) keeps, per query head, the rows
+    of its own kv head that hold one of the ``tokens_left`` tokens of
+    the context, and the rest leave the softmax as exact zeros, so
+    ``p @ V`` over the same folded rows is the per-head sum. ``q`` is
+    [Hq, D] as it arrived; ``scale`` multiplies the float32 scores.
+    (m, l, acc) is the running flash-attention state ([Hq, 1], [Hq, 1],
+    [Hq, Dv] float32); returns it updated."""
+    quant = ks_buf is not None
+    k = k_buf[slot]                                  # [ppb, page*Hkv, D]
+    ppb, page_rows, head_dim = k.shape
+    rows = ppb * page_rows
+
+    def dequant(x, s_buf):
+        s = s_buf[slot]                              # [ppb, Hkv]
+        per_row = jnp.tile(s, (1, page_rows // s.shape[1]))
+        return x.astype(jnp.float32) * per_row[..., None]
+
+    if quant:
+        k = dequant(k, ks_buf)
+    k = k.reshape(rows, head_dim)
+    if shared_kv:
+        v = k[:, :v_dim]
     else:
-        kt = k.astype(jnp.float32).transpose(1, 0, 2)   # [Hkv, BK, D]
-        vt = v.astype(jnp.float32).transpose(1, 0, 2)   # [Hkv, BK, Dv]
-        scores = jax.lax.dot_general(                   # [Hkv, G, BK]
-            qh, kt, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-    kv_pos = blk_idx * bk + jax.lax.broadcasted_iota(
-        jnp.int32, scores.shape, kv_axis)
-    scores = jnp.where(kv_pos < kv_len, scores, -jnp.inf)
+        v = v_buf[slot]
+        if quant:
+            v = dequant(v, vs_buf)
+        v = v.reshape(rows, v_dim)
+    operand = mxu_operand(q.dtype, k.dtype, quant)
+    scores = jax.lax.dot_general(                    # [Hq, R]
+        q.astype(operand), k.astype(operand), (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale
+    scores = jnp.where(own_tokens < tokens_left, scores, -jnp.inf)
 
-    m_blk = jnp.max(scores, axis=kv_axis, keepdims=True)
-    m_new = jnp.maximum(m, m_blk)
+    m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
     alpha = jnp.exp(m - m_new)
     p = jnp.exp(scores - m_new)
-    l_new = l * alpha + jnp.sum(p, axis=kv_axis, keepdims=True)
-    if mqa:
-        pv = jax.lax.dot_general(                       # [Hq, Dv]
-            p, vt, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+    if operand.itemsize == 2:                        # p in two parts
+        hi = p.astype(operand)
+        lo = (p - hi.astype(jnp.float32)).astype(operand)
+        pv = jax.lax.dot_general(                    # [2 Hq, Dv]
+            jnp.concatenate([hi, lo], axis=0), v,
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        pv = pv[:p.shape[0]] + pv[p.shape[0]:]
     else:
-        pv = jax.lax.dot_general(                       # [Hkv, G, Dv]
-            p, vt, (((2,), (1,)), ((0,), (0,))),
+        pv = jax.lax.dot_general(                    # [Hq, Dv]
+            p, v.astype(operand), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
     return m_new, l_new, acc * alpha + pv
 
 
-def kv_stream_specs(k_cache, v_cache, pages_per_block: int, page_size: int,
-                    num_kv_heads: int, head_dim: int, v_dim: int,
-                    mqa: bool = False, slots: int = 2,
+def kv_stream_specs(k_cache, v_cache, pages_per_block: int, slots: int = 2,
                     k_scale=None, v_scale=None):
     """(in_specs_tail, scratch_shapes, inputs_tail) for the KV streams.
 
-    Appends the v stream only when a distinct v cache exists; the DMA
-    semaphore array always comes last in scratch. ``mqa`` expects 3-D
-    caches [P, page, D] (head axis squeezed by the caller). ``slots`` is
-    the buffer-slot count: 2 for the double-buffer kernels, the seq
-    group size for the grouped decode kernel (one slot per sequence).
+    The caches arrive as their kernel reads a page — [P, page, Hkv, D],
+    [P, page, D] with the singleton head axis squeezed (MQA), or
+    [P, page * Hkv, D] with the heads folded into the rows (the decode
+    kernels) — and a block's scratch is ``pages_per_block`` such pages
+    per slot. Appends the v stream only when a distinct v cache exists;
+    the DMA semaphore array always comes last in scratch. ``slots`` is
+    the buffer-slot count: 2 for the double-buffer kernels, two per
+    sequence of a group for the decode kernel.
     int8 caches (k_scale/v_scale [num_pages, Hkv] f32) append one
     scale stream per cache stream, in (k, v, k_scale, v_scale) order —
     ``unpack_refs`` mirrors this layout.
     """
-    shared_kv = v_cache is None
-    head_shape = () if mqa else (num_kv_heads,)
-    in_specs = [pl.BlockSpec(memory_space=pl.ANY)]
-    scratch = [pltpu.VMEM((slots, pages_per_block, page_size, *head_shape,
-                           head_dim), k_cache.dtype)]
-    inputs = [k_cache]
-    if not shared_kv:
-        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
-        scratch.append(pltpu.VMEM((slots, pages_per_block, page_size,
-                                   *head_shape, v_dim), v_cache.dtype))
-        inputs.append(v_cache)
+    streams = [k_cache] + ([] if v_cache is None else [v_cache])
     if k_scale is not None:
-        assert not mqa and not shared_kv, \
-            "int8 KV cache unsupported for MQA/shared-KV kernels"
-        for s in (k_scale, v_scale):
-            in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
-            scratch.append(pltpu.VMEM((slots, pages_per_block,
-                                       num_kv_heads), jnp.float32))
-            inputs.append(s)
+        assert v_cache is not None, \
+            "int8 KV cache unsupported for shared-KV kernels"
+        streams += [k_scale, v_scale]
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY) for _ in streams]
+    scratch = [pltpu.VMEM((slots, pages_per_block, *s.shape[1:]), s.dtype)
+               for s in streams]
     scratch.append(pltpu.SemaphoreType.DMA((slots, 2)))
-    return in_specs, scratch, inputs
+    return in_specs, scratch, streams

@@ -388,7 +388,7 @@ def _decode_block(q_ref, kv_lens_ref, o_ref, start_fetch, wait_fetch, k_buf,
 
                 # re-issue this slot's next block AFTER the buffered
                 # loads above — program order keeps the loads ahead of
-                # the DMA (same discipline as decode _kernel_grouped)
+                # the DMA
                 @pl.when(live & (r + 1 < n_blocks[g]))
                 def _(g=g):
                     start_fetch(g, seq_ids[g], r + 1)
@@ -528,9 +528,8 @@ def ragged_paged_attention(
     # decode-class blocks hold one buffer slot per in-group sequence;
     # the ragged path keeps using slots 0/1 of the same scratch
     kv_specs, scratch_shapes, kv_inputs = kv_stream_specs(
-        k_cache, v_cache, pages_per_block, page_size, num_kv_heads,
-        head_dim, v_dim, mqa=mqa, slots=max(2, gsz), k_scale=k_scale,
-        v_scale=v_scale)
+        k_cache, v_cache, pages_per_block, slots=max(2, gsz),
+        k_scale=k_scale, v_scale=v_scale)
     in_specs = [
         pl.BlockSpec((bq, num_q_heads, head_dim),
                      lambda b, *_: (b, 0, 0),

@@ -192,7 +192,7 @@ def test_sweep_bodies_close_over_no_buffers():
     workload — capture is a structural property, not a size one."""
     kt = _load_kernel_tune()
     run_r, args_r = kt.build_ragged(64, 64, T=128, S=4, ctx=256)
-    run_d, args_d = kt.build_decode(64, gsz=2, S=8, ctx=256)
+    run_d, args_d, _ = kt.build_decode(64, gsz=2, S=8, ctx=256)
     run_u, args_u = kt.build_unified(64, 64, gsz=2, mix="balanced",
                                      shrink=True)
     for name, run, args in (("ragged", run_r, args_r),
@@ -205,8 +205,9 @@ def test_sweep_bodies_close_over_no_buffers():
             nd, nv, chunks = 8, 4, (32, 32)   # shrink "balanced"
             assert args[0].shape[0] == (nd + nv * kt.VERIFY_Q
                                         + sum(chunks)), args[0].shape
-        # the caches must be in the argument list...
-        assert len(args) == 3, name
+        # the caches must be in the argument list (the decode body also
+        # takes its lengths and page table there)...
+        assert len(args) == (5 if name == "decode" else 3), name
         # ...and nothing buffer-sized may ride the jaxpr as a constant
         big = _big_consts(run, *args)
         assert not big, (

@@ -5,7 +5,9 @@ import pytest
 
 import jax.numpy as jnp
 
-from gllm_tpu.ops.pallas.decode_attention import paged_decode_attention
+from gllm_tpu.ops.pallas.decode_attention import (BlockUpdate,
+                                                  block_update,
+                                                  paged_decode_attention)
 
 
 def build_case(rng, shapes, Hq, Hkv, D, page, num_pages):
@@ -28,6 +30,7 @@ def build_case(rng, shapes, Hq, Hkv, D, page, num_pages):
 
 
 def dense_decode_ref(q, k_cache, v_cache, kv_lens, pt, page, scale):
+    """In the inputs' dtype (float64 inputs give a float64 reference)."""
     S, Hq, D = q.shape
     Hkv = k_cache.shape[2]
     group = Hq // Hkv
@@ -170,3 +173,139 @@ def test_grouped_mqa_shared_kv(gsz):
             p_ /= p_.sum()
             want[s, h] = p_ @ v
     np.testing.assert_allclose(np.asarray(got), want, rtol=2e-4, atol=2e-4)
+
+
+# ---- the benchmark cells' geometries, in the dtype they are served in ------
+
+CELL_BLOCK = 64     # tokens a kv block; the lengths below sit on its edges
+
+
+def _decode_groups():
+    """group_size 1 (the double-buffer kernel), 2, and the tuning
+    table's entry for the v5e."""
+    import json
+    import os
+    from gllm_tpu.ops.pallas import tuning
+    with open(os.path.join(os.path.dirname(tuning.__file__),
+                           "tables.json")) as f:
+        tuned = json.load(f)["tpu_v5_lite"]["decode"]["group"]
+    return sorted({1, 2, int(tuned)})
+
+
+def _as_f64(x):
+    return np.asarray(jnp.asarray(x, jnp.float32), np.float64)
+
+
+@pytest.mark.parametrize("gsz", _decode_groups())
+@pytest.mark.parametrize("Hkv", [8, 32], ids=["dense_hkv8", "hybrid_hkv32"])
+def test_cell_geometries_bf16_match_float64_reference(Hkv, gsz):
+    """(Hq 32, Hkv 8) and (Hq 32, Hkv 32) at head_dim 128, pages of 16,
+    bf16 q and cache: lengths 0, 1, one short of, on and one past a block
+    edge, and several blocks. The reference is float64 arithmetic on the
+    same bf16 inputs. Tolerance: K and V enter the MXU as stored and q as
+    it arrives, so every product is exact in the float32 accumulator; p
+    goes in as two bf16 parts (2**-17 relative); sums of at most 200
+    float32 terms add ~1e-6; what is left is the one rounding of the
+    result to bf16, half an ulp = 2**-9 relative. 2**-8 leaves a factor
+    of two."""
+    rng = np.random.default_rng(28)
+    shapes = [0, 1, CELL_BLOCK - 1, CELL_BLOCK, CELL_BLOCK + 1,
+              3 * CELL_BLOCK + 7]
+    q, kc, vc, kv_lens, pt = build_case(rng, shapes, 32, Hkv, 128, 16, 40)
+    q, kc, vc = (jnp.asarray(x, jnp.bfloat16) for x in (q, kc, vc))
+    scale = 128 ** -0.5
+    got = paged_decode_attention(
+        q, kc, vc, jnp.asarray(kv_lens), jnp.asarray(pt), scale=scale,
+        kv_block=CELL_BLOCK, interpret=True, group_size=gsz)
+    assert got.dtype == jnp.bfloat16
+    want = dense_decode_ref(_as_f64(q), _as_f64(kc), _as_f64(vc), kv_lens,
+                            pt, 16, scale)
+    np.testing.assert_allclose(_as_f64(got), want, rtol=2 ** -8, atol=1e-5)
+    assert not np.asarray(got[0]).any()            # the empty row reads 0
+
+
+@pytest.mark.parametrize("name,dtype,Hq,Hkv,want_form", [
+    # one kv head a query head (the hybrid cell's full-attention layers)
+    ("g1", "bfloat16", 8, 8, BlockUpdate("bfloat16", 2, 8, 1)),
+    # grouped queries (the dense cell)
+    ("g4", "bfloat16", 8, 2, BlockUpdate("bfloat16", 2, 2, 4)),
+    # a float32 cache keeps float32 operands
+    ("f32", "float32", 8, 2, BlockUpdate("float32", 1, 2, 4)),
+    # one latent head, values in its leading lanes: no own-head mask
+    ("mqa", "bfloat16", 8, 1, BlockUpdate("bfloat16", 2, 1, 8)),
+    # int8 blocks are dequantized in VMEM: float32 operands
+    ("quant", "bfloat16", 8, 2, BlockUpdate("float32", 1, 2, 4)),
+])
+def test_block_update_form_is_chosen_from_the_call(name, dtype, Hq, Hkv,
+                                                   want_form):
+    """The one entry point gives the block update its form from the
+    head counts, the dtypes and the quantization it sees, and every form
+    agrees with float64 arithmetic on the same inputs."""
+    rng = np.random.default_rng(5)
+    D, Dv, page = 128, 128, 8
+    shapes = [21, 0, 40, 8]
+    q, kc, vc, kv_lens, pt = build_case(rng, shapes, Hq, Hkv, D, page, 16)
+    q = jnp.asarray(q, dtype)
+    kw = dict(v_dim=None)
+    if name == "quant":
+        ks = rng.uniform(0.01, 0.02, (16, Hkv)).astype(np.float32)
+        vs = rng.uniform(0.01, 0.02, (16, Hkv)).astype(np.float32)
+        kc = rng.integers(-127, 128, kc.shape).astype(np.int8)
+        vc = rng.integers(-127, 128, vc.shape).astype(np.int8)
+        kw.update(k_scale=jnp.asarray(ks), v_scale=jnp.asarray(vs))
+        kc_j, vc_j = jnp.asarray(kc), jnp.asarray(vc)
+        k_ref = kc.astype(np.float64) * ks[:, None, :, None]
+        v_ref = vc.astype(np.float64) * vs[:, None, :, None]
+    elif name == "mqa":
+        Dv = 64
+        kc_j, vc_j = jnp.asarray(kc, dtype), None
+        kw.update(v_dim=Dv)
+        k_ref = _as_f64(kc_j)
+        v_ref = k_ref[..., :Dv]
+    else:
+        kc_j, vc_j = jnp.asarray(kc, dtype), jnp.asarray(vc, dtype)
+        k_ref, v_ref = _as_f64(kc_j), _as_f64(vc_j)
+    assert block_update(q.dtype, kc_j.dtype, Hq, Hkv,
+                        quant=name == "quant") == want_form
+    got = paged_decode_attention(
+        q, kc_j, vc_j, jnp.asarray(kv_lens), jnp.asarray(pt),
+        scale=D ** -0.5, kv_block=16, interpret=True, group_size=2, **kw)
+    q64 = _as_f64(q)
+    want = np.zeros((len(shapes), Hq, Dv))
+    for s, kv in enumerate(shapes):
+        if kv:
+            k = np.concatenate([k_ref[p] for p in pt[s]])[:kv]
+            v = np.concatenate([v_ref[p] for p in pt[s]])[:kv]
+            for h in range(Hq):
+                sc = (q64[s, h] @ k[:, h // (Hq // Hkv)].T) * D ** -0.5
+                p_ = np.exp(sc - sc.max())
+                want[s, h] = (p_ / p_.sum()) @ v[:, h // (Hq // Hkv)]
+    # the result is rounded once to q's dtype (half a bf16 ulp: 2**-9)
+    rtol = 2 ** -8 if dtype == "bfloat16" else 2e-4
+    np.testing.assert_allclose(_as_f64(got), want, rtol=rtol, atol=1e-5)
+
+
+@pytest.mark.parametrize("shared_kv", [False, True], ids=["kv", "latent"])
+def test_pages_left_unfetched_never_reach_the_result(shared_kv):
+    """A context's last block is fetched to its last page only. What the
+    rest of the buffer holds is masked out of the scores, but its value
+    rows would meet probabilities of exactly 0 in the MXU, and 0 x NaN
+    is NaN: with VMEM that starts as NaNs (the TPU interpreter's
+    ``uninitialized_memory``) the result must stay finite and right."""
+    from jax.experimental.pallas import tpu as pltpu
+    rng = np.random.default_rng(1)
+    shapes = [5, 70, 0, 33]          # blocks of 32: every last block partial
+    Hkv, Dv = (1, 64) if shared_kv else (2, 128)
+    q, kc, vc, kv_lens, pt = build_case(rng, shapes, 8, Hkv, 128, 8, 40)
+    q, kc, vc = (jnp.asarray(x, jnp.bfloat16) for x in (q, kc, vc))
+    got = paged_decode_attention(
+        q, kc, None if shared_kv else vc, jnp.asarray(kv_lens),
+        jnp.asarray(pt), scale=128 ** -0.5, kv_block=32, group_size=2,
+        v_dim=Dv if shared_kv else None,
+        interpret=pltpu.InterpretParams(uninitialized_memory="nan"))
+    k64 = _as_f64(kc)
+    want = dense_decode_ref(_as_f64(q), k64,
+                            k64 if shared_kv else _as_f64(vc), kv_lens,
+                            pt, 8, 128 ** -0.5)[..., :Dv]
+    assert np.isfinite(_as_f64(got)).all()
+    np.testing.assert_allclose(_as_f64(got), want, rtol=2 ** -8, atol=1e-5)

@@ -230,17 +230,34 @@ def _kernel_args(topo, *, S, T, Hq=32, Hkv=8, D=64, pack=2, P=8192,
         sds((S, pages), jnp.int32)
 
 
-def test_decode_kernel_compiles_for_v5e(topo, on_tpu):
+@pytest.mark.parametrize("geometry", [
+    # the smoke model: head_dim 64 packed in pairs, 4 cache heads of 128
+    dict(S=256, T=256),
+    # the benchmark's decode-bound cells: 32 rows, head_dim 128 unpacked
+    dict(S=32, T=32, Hkv=8, D=128, pack=1, P=2800),       # qwen3-4b
+    dict(S=32, T=32, Hkv=32, D=128, pack=1, P=4320),      # olmo-hybrid-7b
+], ids=["smoke_packed", "dense_cell_hkv8", "hybrid_cell_hkv32"])
+def test_decode_kernel_compiles_for_v5e(topo, on_tpu, geometry):
+    """Mosaic accepts the decode kernel's block update at every geometry
+    served on the chip, with the block and group the table gives, under
+    the step programs' own compiler options; and the view of the pool
+    the kernel reads (heads folded into a page's rows) costs no copy of
+    the cache."""
     from gllm_tpu.ops.pallas.decode_attention import paged_decode_attention
     from gllm_tpu.ops.pallas.tuning import get as tuned
+    from gllm_tpu.utils import tpu_compiler_options
     cfg = tuned("decode")
-    assert cfg["kv_block"] == 512, "expected the tpu_v5_lite table entry"
-    q, kc, vc, _cu, kv_lens, pt = _kernel_args(topo, S=256, T=256)
+    assert "group" in cfg, "expected the tpu_v5_lite table entry"
+    q, kc, vc, _cu, kv_lens, pt = _kernel_args(topo, **geometry)
     fn = jax.jit(lambda q, k, v, kl, pt: paged_decode_attention(
         q, k, v, kl, pt, scale=0.125, kv_block=cfg["kv_block"],
-        group_size=int(cfg.get("group", 1))))
+        group_size=int(cfg["group"])),
+        compiler_options=tpu_compiler_options())
     compiled = fn.lower(q, kc, vc, kv_lens, pt).compile()
     assert has_kernel(compiled)
+    copies = [ln for ln in compiled.as_text().splitlines()
+              if " copy(" in ln and f"[{kc.shape[0]}," in ln]
+    assert not copies, copies
 
 
 def _ragged(topo, *, unified: bool, S: int, T: int, **kw):
