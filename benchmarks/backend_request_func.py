@@ -96,7 +96,7 @@ def run_requests(host: str, port: int, payloads: List[dict],
     Poisson arrivals; returns (results, wall_s). Payloads and the arrival
     schedule are fully materialized BEFORE any thread starts, so seeded
     runs reproduce exactly (a shared RNG touched from worker threads
-    would not be thread-safe). Shared by serve_bench and latency_bench."""
+    would not be thread-safe)."""
     import random
     import threading
 

@@ -7,9 +7,9 @@ gives every harness the reference's eval ergonomics
 the server): a thread pool with per-thread persistent connections,
 bounded retries with backoff, and order-preserving results.
 
-``serve_bench.py`` remains the source of TTFT/TPOT latency claims; this
-is about saturating the server during accuracy runs so a 1k-question
-eval doesn't serialize on round-trips.
+This is about saturating the server during accuracy runs so a
+1k-question eval doesn't serialize on round-trips; a speed is measured by
+``perfbench/run.py`` and nothing else.
 """
 
 from __future__ import annotations

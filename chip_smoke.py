@@ -51,8 +51,8 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 OUT = os.path.join(HERE, "chiprun_out", "chip_smoke")
 SEED = 0
 
-# Llama-3.2-1B widths (bench.py flagship_model_cfg) at full depth. A smoke
-# model, not a benchmark cell (ROADMAP keeps the family out of cells).
+# Llama-3.2-1B widths at full depth. A smoke model, not a benchmark cell
+# (ROADMAP keeps the family out of cells).
 MODEL_1CHIP = {
     "architectures": ["LlamaForCausalLM"], "vocab_size": 128256,
     "hidden_size": 2048, "num_hidden_layers": 16,
@@ -60,9 +60,9 @@ MODEL_1CHIP = {
     "intermediate_size": 8192, "max_position_embeddings": 4096,
     "rope_theta": 500000.0, "rms_norm_eps": 1e-5,
     "tie_word_embeddings": True, "eos_token_id": 128001}
-# Qwen3-8B widths at full depth (BASELINE.json config 2): 16 GB of bf16
-# weights do not fit one 16 GB chip, and head_dim 128 lets the Pallas
-# kernels run under the tp shard_map.
+# Qwen3-8B widths at full depth: 16 GB of bf16 weights do not fit one
+# 16 GB chip, and head_dim 128 lets the Pallas kernels run under the tp
+# shard_map.
 MODEL_4CHIP = {
     "architectures": ["Qwen3ForCausalLM"], "vocab_size": 151936,
     "hidden_size": 4096, "num_hidden_layers": 36,

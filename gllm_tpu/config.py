@@ -274,11 +274,10 @@ class EngineConfig:
     attention_impl: str = "auto"          # auto | pallas | xla
     # Performance-attribution tracing (docs/observability.md#tracing):
     # request-scoped span trees (gllm_tpu/obs/spans.py) + the per-step
-    # phase/device/MFU fields on steptrace events, exported via
-    # GET /trace and ``obs.dump --format chrome``. Default ON — pure
-    # host dict work off the device path (the bench --tiny gate holds
-    # the overhead under 2%); ``--no-tracing`` disables the span layer
-    # for this engine (token streams are byte-identical either way).
+    # phase fields on steptrace events, exported via GET /trace and
+    # ``obs.dump --format chrome``. Default ON — pure host dict work off
+    # the device path; ``--no-tracing`` disables the span layer for this
+    # engine (token streams are byte-identical either way).
     tracing: bool = True
     # ---- request-lifecycle robustness (docs/robustness.md) ----
     # Admission control: cap the serving engine's intake queue and the

@@ -47,10 +47,6 @@ _M_STEP_LAT = obs.histogram(
     "gllm_step_latency_seconds",
     "engine-iteration collect latency (host blocked on device tokens)",
     ("kind",), buckets=obs.FAST_LATENCY_BUCKETS)
-_M_RTT = obs.histogram(
-    "gllm_dispatch_rtt_seconds",
-    "dispatch-to-collect round trip per engine iteration",
-    ("kind",), buckets=obs.FAST_LATENCY_BUCKETS)
 _M_STEPS = obs.counter("gllm_steps_total",
                        "engine iterations by step kind", ("kind",))
 _M_STEP_TOKENS = obs.counter("gllm_step_tokens_total",
@@ -115,12 +111,6 @@ _M_SPEC_FUSED = obs.counter(
     "gllm_spec_fused_tokens_total",
     "tokens through fused speculation blocks by kind "
     "(accepted|rejected|correction)", ("kind",))
-# Performance attribution (docs/observability.md#tracing): the share of
-# the latest step's device wall hidden under host work (1 = never
-# blocked).
-_M_OVERLAP = obs.gauge(
-    "gllm_overlap_efficiency",
-    "share of the latest step's device wall hidden under host work")
 # Pipelined loop (config.pipelined_loop,
 # docs/overlap_scheduling.md#pipelined-loop): dispatched-but-uncollected
 # entries after the latest fill pass — the run-ahead depth the loop
@@ -355,10 +345,6 @@ class LLM:
         self.spans = SpanTrace()
         for s in self.schedulers:
             s.spans = self.spans      # admission opens the span tree
-        # monotonic timestamp of the last collect's completion — the
-        # lower bound of the next step's device-busy window (device
-        # wall = ready - max(dispatched, prev_ready))
-        self._last_ready = 0.0
 
     @property
     def eos_token_ids(self) -> frozenset:
@@ -1106,7 +1092,7 @@ class LLM:
         to the latest-finishing row, possibly < the scheduled K — early
         exit) and dead sub-steps (row frozen but the block still ran).
         Feeds the gllm_dead_substep_frac gauge and the fused_block
-        steptrace event bench.py aggregates."""
+        steptrace event."""
         k_exec = int(finish_step.max()) if finish_step.size else 0
         dead = int((k_exec - finish_step).sum())
         if k_exec and finish_step.size:
@@ -1250,8 +1236,7 @@ class LLM:
             self.spans.event_many(decode_rows, "decode_step", t_dispatch, dur)
 
     def _record_step(self, batch, t0: float, t_dispatch: float,
-                     extra: Optional[dict] = None,
-                     phases: Optional[dict] = None) -> None:
+                     extra: Optional[dict], phases: dict) -> None:
         """Step-kind attribution for one collected single-runner
         iteration: what kind of step it was and how many tokens it
         carried; :meth:`_emit_step` does the rest. Host wall clock only
@@ -1289,7 +1274,7 @@ class LLM:
         ev = dict(num_seqs=b.num_seqs, tokens=tokens,
                   # entries still in flight AFTER this collect — the
                   # run-ahead depth the loop sustained (summarize() →
-                  # mean_inflight_depth; bench promotes it)
+                  # mean_inflight_depth)
                   inflight=len(self._in_flight))
         if fused:
             ev["k"] = len(batch)
@@ -1303,8 +1288,7 @@ class LLM:
                         fused=fused, span_extra=extra)
 
     def _record_step_dp(self, live, t0: float, t_dispatch: float,
-                        phases: Optional[dict],
-                        inflight: Optional[int] = None) -> None:
+                        phases: dict, inflight: Optional[int] = None) -> None:
         """One step event for the stacked dp program (all replicas run
         in it): the sync dp loop and the dp super-step loop record the
         same fields through the same tail as the single runner."""
@@ -1327,49 +1311,29 @@ class LLM:
                    fused: bool = False,
                    span_extra: Optional[dict] = None) -> None:
         """The tail every step path shares (single runner, sync dp, dp
-        super-step — one implementation so they cannot drift): latency /
-        RTT histograms, per-kind counters, the steptrace event with the
-        engine-loop phase breakdown and the device wall attributed back
-        to this step (docs/observability.md#tracing), request spans."""
+        super-step — one implementation so they cannot drift): the
+        latency histogram, per-kind counters, the steptrace event with
+        the engine-loop phase breakdown (the entry's own dict from
+        dispatch time plus what the thread measured since — the
+        collect's ``wait`` / ``readback``; docs/observability.md#tracing),
+        request spans."""
         wall = now - t0
         _M_STEP_LAT.observe(wall, kind=kind)
-        _M_RTT.observe(now - t_dispatch, kind=kind)
         _M_STEPS.inc(kind=kind)
         _M_STEP_TOKENS.inc(ev["tokens"], kind=kind)
         if decode_steps:
             _M_DECODE_STEPS.inc(decode_steps,
                                 fused="true" if fused else "false")
         ev["wall_ms"] = round(wall * 1e3, 3)
-        ev["rtt_ms"] = round((now - t_dispatch) * 1e3, 3)
-        if phases is not None:
-            self._attach_attribution(ev, phases, wall, now, t_dispatch)
-        else:
-            self._last_ready = now
-        TRACE.record(kind, **ev)
-        if self.tracing:
-            for b in batches:
-                self._record_spans(b, t_dispatch, now, span_extra)
-
-    def _attach_attribution(self, ev: dict, phases: dict, wall: float,
-                            now: float, t_dispatch: float) -> None:
-        """Attribution fields of a collected step event: the host phase
-        walls (the entry's own dict from dispatch time plus what the
-        thread measured since — the collect's ``wait`` / ``readback``)
-        and the device wall attributed back to this step
-        (block-until-ready delta at collect, floored by the previous
-        collect's completion — before that the device was busy with the
-        OLDER step; no profiler, no extra device round trips)."""
-        dev = max(0.0, now - max(t_dispatch, self._last_ready))
-        self._last_ready = now
         merged = dict(phases)
         for name, sec in spans.take_phases().items():
             merged[name] = merged.get(name, 0.0) + sec
         ev.update(spans.step_phases(merged))
-        ev["step_wall_ms"] = round(
-            (now - phases.get("t_enter", t_dispatch)) * 1e3, 3)
-        ev["dev_ms"] = round(dev * 1e3, 3)
-        if dev > 0:
-            _M_OVERLAP.set(round(max(0.0, dev - wall) / dev, 4))
+        ev["step_wall_ms"] = round((now - phases["t_enter"]) * 1e3, 3)
+        TRACE.record(kind, **ev)
+        if self.tracing:
+            for b in batches:
+                self._record_spans(b, t_dispatch, now, span_extra)
 
     def _observe_outputs(self, outs) -> None:
         """Per-request latency bookkeeping over one iteration's outputs

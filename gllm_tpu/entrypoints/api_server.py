@@ -320,10 +320,10 @@ class Handler(BaseHTTPRequestHandler):
                         "summary": summarize(events)})
         elif self.path.split("?", 1)[0] == "/trace":
             # Chrome trace-event JSON (Perfetto / chrome://tracing
-            # loadable): one track per engine phase + the device track,
-            # one track per request (this engine's span ring — spans
-            # are per-LLM; seq_ids restart per engine). ?since=N limits
-            # the step events like /steptrace.
+            # loadable): one track per engine phase, one track per
+            # request (this engine's span ring — spans are per-LLM;
+            # seq_ids restart per engine). ?since=N limits the step
+            # events like /steptrace.
             from urllib.parse import parse_qs, urlparse
             from gllm_tpu.obs.spans import SPANS, chrome_trace
             from gllm_tpu.obs.steptrace import TRACE

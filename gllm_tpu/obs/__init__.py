@@ -7,9 +7,8 @@ no effect on jit cache keys):
   Histogram with fixed buckets, thread-safe, text-exposition renderer)
   served by the api_server's ``GET /metrics``.
 - ``gllm_tpu.obs.steptrace``: a ring buffer of per-step records (kind,
-  batch size, token counts, wall ms, and the engine-loop phase/device
-  attribution fields) dumped by ``GET /steptrace`` and summarized into
-  bench.py's metrics snapshot. ``python -m gllm_tpu.obs.dump
+  batch size, token counts, wall ms, and the engine-loop phase
+  fields) dumped by ``GET /steptrace``. ``python -m gllm_tpu.obs.dump
   trace.jsonl`` pretty-prints a saved trace.
 - ``gllm_tpu.obs.spans``: the performance-attribution layer — per-request
   span trees, the engine-loop phase clock (``phase``: steptrace ``ph``

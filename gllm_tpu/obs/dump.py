@@ -1,4 +1,4 @@
-"""Pretty-print or convert a steptrace JSONL for bench post-mortems.
+"""Pretty-print or convert a steptrace JSONL for post-mortems.
 
 Usage:
   python -m gllm_tpu.obs.dump trace.jsonl            # event table + summary

@@ -204,7 +204,7 @@ class Histogram(_Metric):
 
     def snapshot(self, **labels):
         """(bucket_counts, sum, count) copy — diff two snapshots to get
-        the observations of a bounded window (bench measured pass)."""
+        the observations of a bounded window."""
         key = self._key(labels)
         with self._lock:
             cell = self._values.get(key)
@@ -298,7 +298,7 @@ class Registry:
 
     def reset(self) -> None:
         """Zero every metric's samples (registrations survive) — test
-        isolation and bench window bracketing."""
+        isolation."""
         for m in self.metrics():
             m.clear()
 

@@ -1,13 +1,12 @@
 """Performance-attribution layer: request-scoped spans, the engine-loop
-phase clock, a step FLOPs model, peak-FLOPs tables, and the Chrome-trace
-converter (stdlib only).
+phase clock, and the Chrome-trace converter (stdlib only).
 
-ROADMAP item 1 says decode is host-loop-bound (MFU 0.086, BENCH_r05) —
-but the steptrace ring only said how long each engine iteration's
-*collect* took, not where the wall clock went (schedule vs batch-build
-vs dispatch vs device vs collect) nor how much of the overlap
-scheduling actually overlapped. This module holds the pure-host pieces
-of the attribution stack:
+The steptrace ring alone says how long each engine iteration's *collect*
+took, not where the host's wall clock went (schedule vs batch-build vs
+dispatch vs collect). This module holds the pure-host pieces of the
+attribution stack; what the DEVICE did in the same milliseconds is read
+from a profiler capture, on whose clock the phases below also sit
+(perfbench/host_gaps.py):
 
 - :class:`SpanTrace` — one span tree per request
   (queued → prefill chunks → decode chains → detokenize → finish),
@@ -17,9 +16,6 @@ of the attribution stack:
   (the steptrace ``ph`` field) and, only while a profiler capture runs,
   is also a ``jax.profiler.TraceAnnotation("gllm:<name>")`` so the same
   span sits on the device trace's clock;
-- :class:`StepFlopsModel`, :func:`peak_flops` — matmul-path FLOPs per
-  step and dense-peak bf16 FLOP/s by TPU generation. Plain functions
-  kept for bench.py alone; the engine loop no longer estimates per step;
 - :func:`chrome_trace` — steptrace step events + request spans →
   Chrome trace-event JSON (Perfetto/chrome://tracing loadable): one
   track per engine phase, one per request. Shared by ``GET /trace``
@@ -29,25 +25,21 @@ Same design constraints as the rest of ``gllm_tpu/obs``: no jax import
 (the annotation class is imported at the first capture, never before),
 no device work, no new jit static arguments; every recorded number is
 host arithmetic the engine already had. Span recording is gated by
-``EngineConfig.tracing`` (default ON — the acceptance bar is <2%
-``--tiny`` throughput overhead and byte-identical token streams).
+``EngineConfig.tracing`` (default ON; token streams are byte-identical
+either way, tests/test_tracing.py).
 """
 
 from __future__ import annotations
 
-import logging
 import os
 import threading
 import time
 from collections import deque
 from typing import Dict, Iterable, List, Optional
 
-logger = logging.getLogger(__name__)
-
-__all__ = ["SpanTrace", "SPANS", "StepFlopsModel", "peak_flops",
-           "chrome_trace", "SPAN_PHASES", "ENGINE_PHASES", "HOST_PHASES",
-           "phase", "take_phases", "step_phases", "set_capture",
-           "capturing"]
+__all__ = ["SpanTrace", "SPANS", "chrome_trace", "SPAN_PHASES",
+           "ENGINE_PHASES", "HOST_PHASES", "phase", "take_phases",
+           "step_phases", "set_capture", "capturing"]
 
 # Span phase taxonomy (docs/observability.md span-phase catalog): the
 # child spans a request tree may carry. ``queued`` = arrival → first
@@ -344,119 +336,12 @@ class SpanTrace:
 SPANS = SpanTrace()
 
 
-# ---- FLOPs / peak models ---------------------------------------------------
-
-# Dense-peak bf16 TFLOP/s by TPU generation (public spec sheets) — the
-# MFU denominator of bench.py, whose chip_peak_flops wraps peak_flops()
-# below; nothing in the engine or the runner reads it (the benchmark's
-# peaks are perfbench/peaks.json). Matched by substring against
-# ``jax.Device.device_kind`` (lowercased).
-PEAK_TFLOPS = (("v5 lite", 197.0), ("v5e", 197.0), ("v6", 918.0),
-               ("trillium", 918.0), ("v5p", 459.0), ("v5", 459.0),
-               ("v4", 275.0), ("v3", 123.0))
-
-
-def peak_flops(device) -> float:
-    """Peak dense bf16 FLOP/s of a jax device (``.platform`` and
-    ``.device_kind`` are read). Off the TPU there is no spec sheet and
-    the answer is 0.0 (every MFU field then reads null). A TPU whose kind
-    is not in ``PEAK_TFLOPS`` is an error, not a peak of 0.0 that would
-    silently null the chip's utilization figures.
-    ``GLLM_TPU_PEAK_TFLOPS`` overrides — also the lever that makes the
-    MFU plumbing testable on CPU."""
-    ov = os.environ.get("GLLM_TPU_PEAK_TFLOPS")
-    if ov:
-        try:
-            return float(ov) * 1e12
-        except ValueError:
-            # fall through to the table — but SAY so, or every MFU
-            # field silently nulls while the operator believes the
-            # override is honored
-            logger.warning("ignoring malformed GLLM_TPU_PEAK_TFLOPS=%r",
-                           ov)
-    kind = device.device_kind.lower()
-    for tag, tf in PEAK_TFLOPS:
-        if tag in kind:
-            return tf * 1e12
-    if device.platform == "tpu":
-        raise ValueError(
-            f"no peak FLOP/s on file for TPU device_kind "
-            f"{device.device_kind!r}: add it to PEAK_TFLOPS "
-            "(gllm_tpu/obs/spans.py) with its source")
-    return 0.0
-
-
-class StepFlopsModel:
-    """Matmul-path FLOPs per engine step from the model config.
-
-    The per-step counterpart of bench.py's workload-level
-    ``model_flops`` — same decomposition (2×params on the matmul body
-    per processed token, one lm_head row per sampling sequence,
-    causal token×context attention at 4·Hq·D·L FLOPs per key), so a
-    measured pass's per-step sum reconciles with the workload total.
-    MoE configs count only the activated expert width (an estimator,
-    not an audit). Pure integer arithmetic on counts the scheduler
-    already tracks — never touches the device.
-    """
-
-    def __init__(self, num_layers: int, hidden_size: int, num_heads: int,
-                 num_kv_heads: int, head_dim: int,
-                 intermediate_size: int, vocab_size: int):
-        qkv = hidden_size * (num_heads + 2 * num_kv_heads) * head_dim
-        o_proj = num_heads * head_dim * hidden_size
-        mlp = 3 * hidden_size * intermediate_size
-        self.body_per_token = 2 * num_layers * (qkv + o_proj + mlp)
-        self.lm_head_per_row = 2 * vocab_size * hidden_size
-        # FLOPs per (query token × context token): QK^T + PV
-        self.attn_coeff = 4 * num_layers * num_heads * head_dim
-
-    @classmethod
-    def from_model_config(cls, mc) -> "StepFlopsModel":
-        inter = mc.intermediate_size
-        experts = getattr(mc, "num_experts_per_tok", 0) or 0
-        moe_inter = getattr(mc, "moe_intermediate_size", 0) or 0
-        if experts and moe_inter:
-            inter = experts * moe_inter       # activated width only
-        return cls(mc.num_layers, mc.hidden_size, mc.num_heads,
-                   mc.num_kv_heads, mc.head_dim or 0, inter,
-                   mc.vocab_size)
-
-    def step_flops(self, rows: Iterable[tuple]) -> float:
-        """One dispatch of mixed prefill/decode rows.
-
-        ``rows``: (new_tokens, ctx_before, samples) per scheduled item
-        — token j of a chunk attends ctx_before + j + 1 keys; a
-        sampling row pays one lm_head projection (the runner gathers
-        last-token rows before the vocab GEMM).
-        """
-        f = 0.0
-        for n, ctx, samples in rows:
-            f += n * self.body_per_token
-            if samples:
-                f += self.lm_head_per_row
-            f += self.attn_coeff * (n * ctx + n * (n + 1) / 2.0)
-        return f
-
-    def block_flops(self, ctx_before: Iterable[int], k: int) -> float:
-        """One fused decode block: ``k`` executed sub-steps over live
-        rows whose contexts start at ``ctx_before`` and grow by one
-        per sub-step. Dead/hole rows should not be passed (their
-        forward work is real but their attention reads the dummy page
-        — close enough for an estimator to skip)."""
-        f = 0.0
-        for ctx in ctx_before:
-            f += k * (self.body_per_token + self.lm_head_per_row)
-            f += self.attn_coeff * (k * ctx + k * (k + 1) / 2.0)
-        return f
-
-
 # ---- Chrome trace-event export ---------------------------------------------
 
 # Track (tid) layout of the engine process row in the exported trace;
-# ``wait`` and ``device`` are derived tracks (see chrome_trace).
+# ``wait`` is a derived track (see chrome_trace).
 _ENGINE_TIDS = {"schedule": 1, "build": 2, "dispatch": 3, "wait": 4,
-                "collect": 5, "device": 6, "output": 7, "deliver": 8,
-                "intake": 9}
+                "collect": 5, "output": 7, "deliver": 8, "intake": 9}
 _PID_ENGINE = 1
 _PID_REQUESTS = 2
 
@@ -489,7 +374,7 @@ def chrome_trace(step_events: Iterable[dict], spans: Iterable[dict] = (),
     collect-end timestamp ``t`` using the recorded phase walls:
     ``[t - step_wall, t]`` holds schedule → build → dispatch → wait →
     collect in order (wait = the pipelined slack between dispatch end
-    and collect start), and the device track shows ``[t - dev_ms, t]``.
+    and collect start).
     Request spans use absolute monotonic times; ``span_t0`` (the
     steptrace ring's epoch) rebases them onto the same axis.
     """
@@ -533,11 +418,6 @@ def chrome_trace(step_events: Iterable[dict], spans: Iterable[dict] = (),
                                  dur, _PID_ENGINE, _ENGINE_TIDS[name],
                                  args if name == "collect" else None))
             t += dur
-        dev = float(e.get("dev_ms", 0.0)) / 1e3
-        if dev > 0:
-            events.append(_x(f"{e.get('kind', 'step')}:device",
-                             end - dev, dev, _PID_ENGINE,
-                             _ENGINE_TIDS["device"], args))
 
     for rec in spans:
         sid = int(rec.get("seq_id", 0))

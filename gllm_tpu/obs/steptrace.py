@@ -3,13 +3,10 @@
 Every engine iteration appends one small dict (kind, batch size, token
 counts, wall ms, ...) to a fixed-capacity ring; compile events, chain
 breaks, and pp stage dispatches ride the same ring. The api_server dumps
-it as JSON (``GET /steptrace``), bench.py summarizes the measured-pass
-window into its metrics snapshot, and ``python -m gllm_tpu.obs.dump``
-pretty-prints a saved JSONL for post-mortems.
-
-The round-5 "18/59 decode steps running unfused at 90.8 ms vs 11.2 ms"
-finding took an afternoon of grepping ``docs/onchip_r05/*.out``; with
-this ring it is ``summarize(TRACE.events())`` — one call.
+it as JSON (``GET /steptrace``; the benchmark reads a window of it,
+perfbench/run.py), and ``python -m gllm_tpu.obs.dump`` pretty-prints a
+saved JSONL for post-mortems. "How many decode steps of the window ran
+unfused, and at what wall" is ``summarize(TRACE.events())`` — one call.
 
 Overhead: one dict + one list slot assignment per ENGINE iteration (not
 per token, not per layer), behind a lock only the host ever takes. No jax
@@ -86,10 +83,9 @@ __all__ = ["StepTrace", "TRACE", "summarize"]
 # intake, schedule, build, dispatch, output, deliver — plus ``collect``
 # = the time blocked in runner.collect), ``wait_ms`` / ``readback_ms``
 # = the split of ``collect`` (``idle_ms`` where the loop slept before
-# the step), ``step_wall_ms`` = schedule-start → collect-end, and
-# ``dev_ms`` = device wall attributed back to the launching step
-# (block-until-ready delta at collect). ``compile`` events carry
-# ``first_use_ms`` and ``source`` (compiled | cache).
+# the step) and ``step_wall_ms`` = schedule-start → collect-end.
+# ``compile`` events carry ``first_use_ms`` and ``source`` (compiled |
+# cache).
 STEP_KINDS = ("prefill", "decode", "unified_step", "fused_block",
               "pp_stage", "compile", "chain_break", "fault",
               "quarantine", "prefix", "loop_stall", "recovery")
@@ -187,8 +183,8 @@ def summarize(events: List[dict]) -> dict:
 
     Returns a machine-readable blob answering "where did the measured
     pass go": per-kind {steps, wall_ms, tokens, ms_per_step}, fused
-    decode sub-step totals, the unfused share of decode wall time (the
-    round-5 18/59 class of finding), and compile/chain-break counts.
+    decode sub-step totals, the unfused share of decode wall time, and
+    compile/chain-break counts.
     """
     kinds: Dict[str, dict] = {}
     fused_steps = unfused_steps = 0
@@ -223,14 +219,11 @@ def summarize(events: List[dict]) -> dict:
     # prefix-cache attribution: per-window hit rate + tier split
     pfx_queries = pfx_query_tokens = pfx_hit_tokens = 0
     pfx_pages: Dict[str, int] = {}
-    # engine-loop phase breakdown + device-wall attribution (events
-    # carrying ``ph``/``dev_ms`` — docs/observability.md#tracing)
+    # engine-loop phase breakdown (events carrying ``ph`` —
+    # docs/observability.md#tracing)
     host_phase: Dict[str, float] = {}
     blocked: Dict[str, float] = {}   # wait / readback / idle (not host work)
-    dev_by_kind: Dict[str, float] = {}
-    dev_total = hidden_total = 0.0
     first_use_ms = 0.0
-    t_first_start = t_last_end = None
     for e in events:
         k = e["kind"]
         if k == "prefix":
@@ -293,21 +286,10 @@ def summarize(events: List[dict]) -> dict:
         if isinstance(ph, dict):
             for name, ms in ph.items():
                 host_phase[name] = host_phase.get(name, 0.0) + float(ms)
-            dev = float(e.get("dev_ms", 0.0))
-            dev_by_kind[k] = dev_by_kind.get(k, 0.0) + dev
-            dev_total += dev
-            coll = float(ph.get("collect", wall))
-            hidden_total += max(0.0, dev - coll)
             for name in ("wait", "readback", "idle"):
                 if e.get(name + "_ms") is not None:
                     blocked[name] = (blocked.get(name, 0.0)
                                      + float(e[name + "_ms"]))
-            start = float(e["t"]) - float(
-                e.get("step_wall_ms", wall)) / 1e3
-            if t_first_start is None or start < t_first_start:
-                t_first_start = start
-            if t_last_end is None or float(e["t"]) > t_last_end:
-                t_last_end = float(e["t"])
         step_events += 1
         if k == "decode" or (k == "unified_step"
                              and e.get("mix") == "decode"):
@@ -326,18 +308,13 @@ def summarize(events: List[dict]) -> dict:
         row["wall_ms"] = round(row["wall_ms"], 2)
         row["ms_per_step"] = round(row["wall_ms"] / row["steps"], 2)
     decode_ms = fused_ms + unfused_ms
-    # window wall: first step's schedule-start → last step's collect-end
-    elapsed_ms = ((t_last_end - t_first_start) * 1e3
-                  if t_first_start is not None
-                  and t_last_end > t_first_start else 0.0)
     return {
         "by_kind": kinds,
         "decode_steps_unfused": unfused_steps,
         "decode_substeps_fused": fused_steps,
         "unfused_decode_wall_frac": (round(unfused_ms / decode_ms, 4)
                                      if decode_ms else None),
-        # unfused share of the WHOLE window's wall (prefill included) —
-        # the regression class bench.py promotes to its result JSON
+        # unfused share of the WHOLE window's wall (prefill included)
         "unfused_frac": (round(unfused_ms / total_ms, 4)
                          if total_ms else None),
         # wasted (dead-row) sub-step share of executed fused-block work;
@@ -380,18 +357,6 @@ def summarize(events: List[dict]) -> dict:
         "blocked_ms_by_phase": ({k: round(v, 2)
                                  for k, v in blocked.items()}
                                 if blocked else None),
-        # device wall (block-until-ready deltas) attributed by step kind
-        "device_ms_by_kind": ({k: round(v, 2)
-                               for k, v in dev_by_kind.items()}
-                              if dev_by_kind else None),
-        # share of device wall hidden under host work (1 = the host
-        # never blocked on the device; 0 = fully synchronous)
-        "overlap_efficiency": (round(hidden_total / dev_total, 4)
-                               if dev_total > 0 else None),
-        # share of the window's wall clock with the device idle — the
-        # gLLM bubble ratio, reproduced from engine-side attribution
-        "bubble_frac": (round(max(0.0, 1.0 - dev_total / elapsed_ms), 4)
-                        if elapsed_ms > 0 and dev_total > 0 else None),
         # first uses of a step signature in the window: how many, and the
         # wall they took (trace + lower + compile or cache read)
         "compiles": compiles,

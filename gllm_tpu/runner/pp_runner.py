@@ -340,11 +340,6 @@ class PPModelRunner(ModelRunner):
         self.memory_manager = None     # attached by the engine
         from gllm_tpu.runner.runner import _M_KV_DTYPE
         _M_KV_DTYPE.set(1, dtype=jnp.dtype(kv_dtype).name)
-        # gllm_kv_bytes_read_total estimate: per-context-token cache
-        # bytes across the WHOLE layer stack (self.model_cfg is the full
-        # model, so the base per-page pricing already sums every stage)
-        self._kv_rd_tok_bytes = (self._kv_bytes_per_page()
-                                 / config.cache.page_size)
         logger.info("pipeline: dp=%d × %d stages %s × tp=%d, "
                     "%d KV pages/stage", dp, pp, bounds, tp,
                     self.num_pages)
@@ -483,7 +478,6 @@ class PPModelRunner(ModelRunner):
             "pp", host, (max_q, lp_k, want_plp, spec_sampled,
                           _ag(sched_batch.items)), _ag(sched_batch.items))
         _M_MICROBATCH.inc()
-        self._note_kv_read(sched_batch.items)
         # one pp_stage event PER STAGE, carrying the dispatch family the
         # stage ran (family) — under --unified-step + token throttling
         # every stage must show "unified_step" (the acceptance probe the

@@ -60,16 +60,10 @@ _M_H2D = obs.counter(
     "gllm_sampler_program_total: arrays per dispatch, 2 on the default "
     "path (the packed batch and the tokens)")
 # KV-cache dtype observability (docs/observability.md): an info gauge
-# naming the active storage dtype, and a host-side ESTIMATE of KV bytes
-# the attention kernels stream per step (context tokens × per-token
-# cache bytes on device 0) — the decode bandwidth-floor denominator.
+# naming the active storage dtype.
 _M_KV_DTYPE = obs.gauge(
     "gllm_kv_cache_dtype",
     "info gauge: 1 for the active paged-KV storage dtype", ("dtype",))
-_M_KV_READ = obs.counter(
-    "gllm_kv_bytes_read_total",
-    "estimated KV cache bytes read by attention (context tokens x "
-    "per-token cache bytes incl. int8 scales; per-device estimate)")
 
 # What set-up costs (docs/observability.md): every executable this process
 # obtained from XLA, split by whether it was compiled here or read back
@@ -499,7 +493,7 @@ def resolve_kv_quant(config: EngineConfig, model_cfg: ModelConfig):
 class ModelRunner:
     # Total runner dispatches (every step path notes exactly one per
     # device program launched via _note_dispatch) — the denominator-free
-    # half of the dispatches-per-token acceptance metric (bench/tests).
+    # half of the dispatches-per-token acceptance metric (tests).
     # Class default so subclasses sharing _note_dispatch (PPModelRunner)
     # count too; first increment creates the instance attribute.
     num_dispatches = 0
@@ -695,11 +689,6 @@ class ModelRunner:
             if model_cfg.use_hybrid else 0, self._ssm_pool_bytes(),
             self._gdn_chunk_temp_bytes())
         _M_KV_DTYPE.set(1, dtype=jnp.dtype(self._kv_dtype()).name)
-        # per-context-token cache bytes (per device 0) for the
-        # gllm_kv_bytes_read_total estimate — amortizes scales and the
-        # layer stack through the same sizing arithmetic
-        self._kv_rd_tok_bytes = (self._kv_bytes_per_page()
-                                 / config.cache.page_size)
         # Fused on-device speculation (config.spec_fused,
         # docs/speculative_decoding.md#fused): draft+verify inside the
         # multi-step block driver. Gated off hybrid (cumulative SSM
@@ -1178,20 +1167,6 @@ class ModelRunner:
                                            self.kv.v_scale, idx)
             self.kv = self.kv._replace(k_scale=ks, v_scale=vs)
 
-    def _note_kv_read(self, items, steps: int = 1) -> None:
-        """Estimate of the KV bytes this dispatch streams through
-        attention: each row reads its whole context (kv_len after this
-        step's writes); a K-step fused block re-reads the growing
-        context every sub-step. Pure host arithmetic on scheduler state
-        — never touches the device (gllm_kv_bytes_read_total; bench.py
-        derives kv_bytes_per_step from its growth)."""
-        tok_bytes = getattr(self, "_kv_rd_tok_bytes", 0)
-        if not tok_bytes:
-            return
-        ctx = sum(it.computed_before + it.num_new_tokens for it in items)
-        grow = len(items) * steps * (steps - 1) // 2
-        _M_KV_READ.inc(int((ctx * steps + grow) * tok_bytes))
-
     def _note_dispatch(self, kind: str, batch, static_flags: tuple,
                        all_greedy: bool) -> Optional[dict]:
         """Host-side dispatch bookkeeping: sampler-variant counter and,
@@ -1342,7 +1317,6 @@ class ModelRunner:
 
         all_greedy_dp = all(_all_greedy(b.items) for b in live)
         spec_sampled_dp = any(_spec_sampled(b.items) for b in live)
-        self._note_kv_read([it for b in live for it in b.items])
         new_sig = self._note_dispatch("dp_step", host,
                                       (max_q, lp_k, want_plp,
                                        spec_sampled_dp, all_greedy_dp),
@@ -1403,7 +1377,6 @@ class ModelRunner:
                 and self._use_ring(sched_batch, host.token_ids.shape[0]))
         spec_sampled = _spec_sampled(sched_batch.items)
         all_greedy = _all_greedy(sched_batch.items)
-        self._note_kv_read(sched_batch.items)
         new_sig = self._note_dispatch(
             "step", host, (max_q, lp_k, want_plp, ring, spec_sampled,
                            all_greedy), all_greedy)
@@ -1616,7 +1589,6 @@ class ModelRunner:
             batch = self._splice_prev(batch, chain[0], prev_handle[0])
         batch = self._put(batch)
         all_greedy = _all_greedy(chain[0].items)
-        self._note_kv_read(chain[0].items, steps=K)
         # e_bucket is part of the compile signature: stop-set presence
         # changes the batch layout and its pow2 width E the shapes
         new_sig = self._note_dispatch(
@@ -1980,7 +1952,6 @@ class ModelRunner:
         # by nothing, so nothing is spliced into them
         batch = self._put(batch)
         all_greedy = _all_greedy(chain[0].items)
-        self._note_kv_read(chain[0].items, steps=K)
         new_sig = self._note_dispatch(
             "spec_block", host, (K, k_draft, all_greedy, e_bucket),
             all_greedy)
@@ -2223,6 +2194,6 @@ class ModelRunner:
     def num_shape_signatures(self) -> int:
         """Distinct (kind, shape-bucket, static-flag) dispatch signatures
         seen so far — the shape-bucket population this runner warmed or
-        compiled at first sight (bench.py promotes it: the unified step
-        must shrink it, docs/overlap_scheduling.md#unified-step)."""
+        compiled at first sight (the unified step must shrink it,
+        docs/overlap_scheduling.md#unified-step)."""
         return len(self._seen_sigs)

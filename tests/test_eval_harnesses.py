@@ -254,30 +254,6 @@ def test_serve_bench_summary_and_poisson(tmp_path, capsys, monkeypatch):
     assert d["itl_ms"]["mean"] > 0, d["itl_ms"]
 
 
-def test_latency_bench_tiny_cpu():
-    """latency_bench CLI end-to-end on CPU: in-process server + Poisson
-    client threads → one JSON line with TTFT/TPOT/ITL percentiles and
-    vs_baseline against the 500 ms TTFT target."""
-    import json as _json
-    import os
-    import subprocess
-    import sys
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               PYTHONPATH=root)
-    proc = subprocess.run(
-        [sys.executable, os.path.join(root, "benchmarks",
-                                      "latency_bench.py"), "--tiny"],
-        env=env, cwd=root, timeout=420, stdout=subprocess.PIPE,
-        stderr=subprocess.DEVNULL, text=True)
-    assert proc.returncode == 0
-    d = _json.loads(proc.stdout.strip().splitlines()[-1])
-    assert d["metric"] == "ttft_p50_ms" and d["value"] > 0
-    det = d["detail"]
-    assert det["failed"] == 0 and det["completed"] == 8
-    assert det["itl_ms"]["mean"] > 0
-
-
 def test_bfcl_native_mode_qwen35_xml_chain():
     """BFCL native mode over the Qwen3.5 XML markup: model output →
     Qwen3XmlToolParser (schema coercion) → OpenAI message shape →
@@ -304,27 +280,3 @@ def test_bfcl_native_mode_qwen35_xml_chain():
         [{"name": "get_weather",
           "args": {"city": ["Paris"], "days": [3]},
           "required": ["city", "days"]}], False) is True
-
-
-def test_host_overhead_bench_cpu():
-    """Control-plane microbenchmark runs and reports all four host-path
-    costs (pure host code, no device work)."""
-    import json as _json
-    import os
-    import subprocess
-    import sys
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=root)
-    proc = subprocess.run(
-        [sys.executable, os.path.join(root, "benchmarks",
-                                      "host_overhead.py"),
-         "--seqs", "16", "--iters", "10"],
-        env=env, cwd=root, timeout=240, stdout=subprocess.PIPE,
-        stderr=subprocess.DEVNULL, text=True)
-    assert proc.returncode == 0
-    d = _json.loads(proc.stdout.strip().splitlines()[-1])
-    assert d["metric"] == "host_step_overhead_us" and d["value"] > 0
-    det = d["detail"]
-    for k in ("schedule_us", "prepare_us", "prefix_match_us",
-              "dp_route_probe_us"):
-        assert det[k] > 0, (k, det)

@@ -8,10 +8,8 @@ the test (monkeypatch), never through a program option.
 import json
 import os
 import pathlib
-import re
 import subprocess
 import sys
-import types
 
 import jax
 import pytest
@@ -19,7 +17,6 @@ import pytest
 REPO = pathlib.Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO))
 
-import bench  # noqa: E402
 from gllm_tpu.config import (CacheConfig, EngineConfig,  # noqa: E402
                              SchedulerConfig)
 from gllm_tpu.models.config import ModelConfig  # noqa: E402
@@ -103,58 +100,13 @@ def test_retired_knobs_are_gone():
     assembled here so that a search of the tree finds nothing)."""
     knobs = ["GLLM_TPU_" + tail for tail in ("XLA_CACHE", "HBM_BYTES")]
     hits = []
-    for root in ("gllm_tpu", "benchmarks", "bench.py", "chip_smoke.py"):
+    for root in ("gllm_tpu", "benchmarks", "chip_smoke.py"):
         path = REPO / root
         files = [path] if path.is_file() else sorted(path.rglob("*.py"))
         for f in files:
             text = f.read_text()
             hits += [(str(f.relative_to(REPO)), knob)
                      for knob in knobs if knob in text]
-    assert not hits
-
-
-# ---- peak FLOP/s -----------------------------------------------------------
-
-def _dev(platform, kind):
-    return types.SimpleNamespace(platform=platform, device_kind=kind)
-
-
-def test_peak_flops_raises_on_an_unlisted_tpu():
-    from gllm_tpu.obs.spans import peak_flops
-    assert peak_flops(_dev("tpu", "TPU v5 lite")) == pytest.approx(197e12)
-    with pytest.raises(ValueError, match="TPU v9 mystery"):
-        peak_flops(_dev("tpu", "TPU v9 mystery"))
-    # CPU behaviour stays: no spec sheet, peak 0.0, MFU fields read null
-    assert peak_flops(_dev("cpu", "cpu")) == 0.0
-
-
-def test_engine_and_runner_read_no_peak_and_estimate_no_flops(monkeypatch):
-    """The engine loop no longer estimates per step (PR 24): the peak
-    table and the FLOPs model are bench.py's alone. An engine starts
-    without consulting either, its step events carry phases and no
-    estimate, and nothing under engine/ or runner/ names them."""
-    from gllm_tpu.engine.llm import LLM
-    from gllm_tpu.obs import spans
-    from gllm_tpu.obs.steptrace import TRACE
-    from gllm_tpu.sampling_params import SamplingParams
-
-    def refuse(*a, **k):
-        raise AssertionError("the engine consulted the peak table")
-    monkeypatch.setattr(spans, "peak_flops", refuse)
-    monkeypatch.setattr(spans.StepFlopsModel, "from_model_config", refuse)
-    llm = LLM(config=_config(), model_cfg=ModelConfig(**TINY))
-    mark = TRACE.mark()
-    llm.generate(prompt_token_ids=[[3, 5, 7]],
-                 sampling_params=SamplingParams(max_tokens=3,
-                                                temperature=0.0,
-                                                ignore_eos=True))
-    steps = [e for e in TRACE.events(since=mark) if "ph" in e]
-    assert steps and all("wait_ms" in e for e in steps)
-    assert not any({"mfu", "hbm_gbps"} & set(e) for e in steps)
-    hits = [str(f.relative_to(REPO))
-            for sub in ("engine", "runner")
-            for f in (REPO / "gllm_tpu" / sub).rglob("*.py")
-            if re.search(r"StepFlopsModel|peak_flops", f.read_text())]
     assert not hits
 
 
@@ -346,58 +298,7 @@ def test_tuning_device_tag_does_not_hide_an_unreadable_device(monkeypatch):
         tuning.device_tag.cache_clear()
 
 
-# ---- bench.py --------------------------------------------------------------
-
-def _supervise(monkeypatch, capsys, rc, stdout=""):
-    calls = []
-
-    def fake_run(cmd, **kw):
-        calls.append(cmd)
-        return subprocess.CompletedProcess(cmd, rc, stdout=stdout)
-
-    monkeypatch.setattr(bench.subprocess, "run", fake_run)
-    monkeypatch.setattr(bench.time, "sleep", lambda s: None)
-    code = bench.supervise(types.SimpleNamespace(tiny=False), [])
-    line = capsys.readouterr().out.strip().splitlines()[-1]
-    return code, json.loads(line), calls
-
-
-def test_bench_supervisor_exits_nonzero_without_a_number(monkeypatch,
-                                                         capsys):
-    code, out, calls = _supervise(monkeypatch, capsys, rc=1,
-                                  stdout="[bench phase] engine_build\n")
-    assert code != 0
-    assert out["failed"] is True and out["value"] == 0.0
-    assert out["phase"] == "engine_build"
-    # every rung was tried (twice: one retry each), none measured
-    assert len(calls) == 2 * len(bench.PROFILES)
-
-
-def test_bench_supervisor_stops_when_there_is_no_tpu(monkeypatch, capsys):
-    code, out, calls = _supervise(monkeypatch, capsys, rc=bench.NO_TPU_RC)
-    assert code != 0 and out["failed"] is True
-    assert len(calls) == 1, "no rung can measure without a TPU"
-
-
-def test_bench_supervisor_still_returns_zero_with_a_number(monkeypatch,
-                                                           capsys):
-    result = json.dumps({"metric": bench.METRIC, "value": 123.0,
-                         "unit": "tok/s"})
-    code, out, _ = _supervise(monkeypatch, capsys, rc=0, stdout=result)
-    assert code == 0 and out["value"] == 123.0
-
-
-def test_bench_refuses_to_measure_off_the_tpu():
-    """Without --tiny the measurement fails on a CPU backend; it never
-    carries on there."""
-    out = subprocess.run(
-        [sys.executable, str(REPO / "bench.py"), "--inner"],
-        env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True,
-        text=True, timeout=300)
-    assert out.returncode == bench.NO_TPU_RC
-    assert "measures on a TPU" in out.stderr
-    assert "RESULT" not in out.stdout
-
+# ---- chip_smoke.py ---------------------------------------------------------
 
 def test_chip_smoke_fails_without_a_tpu():
     out = subprocess.run(

@@ -16,6 +16,8 @@ covers modules that only load under flags/topologies CI never runs
 import os
 import re
 
+import pytest
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PKG = os.path.join(REPO, "gllm_tpu")
 DOC = os.path.join(REPO, "docs", "observability.md")
@@ -186,3 +188,60 @@ def test_every_engine_phase_is_opened_documented_and_vice_versa():
     assert documented == vocabulary, (
         sorted(documented ^ vocabulary))
     assert set(HOST_PHASES) < set(ENGINE_PHASES)
+
+
+# ---- retired names (ISSUE 29) ----------------------------------------------
+#
+# The engine loop's host-clock guesses at device numbers are gone: what
+# the device did is read from a profiler capture (perfbench/host_gaps.py).
+# None of their names may come back as a metric, a step-event field, a
+# summary field or a line of the doc.
+
+RETIRED = ("gllm_kv_bytes_read_total", "gllm_overlap_efficiency",
+           "gllm_dispatch_rtt_seconds", "dev_ms", "rtt_ms", "bubble_frac",
+           "overlap_efficiency", "device_ms_by_kind")
+
+
+@pytest.fixture(scope="module")
+def served():
+    """What a short CPU run of the default engine leaves behind: the
+    registry's exposition, the keys of its step events, and the keys of
+    their summary."""
+    from gllm_tpu.config import CacheConfig, EngineConfig, SchedulerConfig
+    from gllm_tpu.engine.llm import LLM
+    from gllm_tpu.models.config import ModelConfig
+    from gllm_tpu.obs import metrics
+    from gllm_tpu.obs.steptrace import TRACE, summarize
+    from gllm_tpu.sampling_params import SamplingParams
+    llm = LLM(
+        config=EngineConfig(
+            load_format="dummy", dtype="float32", max_model_len=64,
+            max_num_seqs=4,
+            scheduler=SchedulerConfig(max_prefill_tokens=32,
+                                      max_decode_seqs=4),
+            cache=CacheConfig(page_size=4, num_pages=32)),
+        model_cfg=ModelConfig(
+            architecture="LlamaForCausalLM", vocab_size=128,
+            hidden_size=32, num_layers=2, num_heads=4, num_kv_heads=2,
+            head_dim=8, intermediate_size=64, max_position=128))
+    mark = TRACE.mark()
+    llm.generate(prompt_token_ids=[[3, 5, 7], [2, 4]],
+                 sampling_params=SamplingParams(max_tokens=4,
+                                                temperature=0.0,
+                                                ignore_eos=True))
+    steps = [e for e in TRACE.events(since=mark) if "ph" in e]
+    assert steps, "the run recorded no step event"
+    keys = set().union(*steps)
+    assert {"ph", "step_wall_ms", "wall_ms", "tokens", "inflight"} <= keys
+    exposition = metrics.render()
+    assert "gllm_steps_total" in exposition
+    return {"exposition": exposition,
+            "event keys": " ".join(sorted(keys)),
+            "summary keys": " ".join(sorted(summarize(steps))),
+            "docs/observability.md": open(DOC).read()}
+
+
+@pytest.mark.parametrize("name", RETIRED)
+def test_retired_names_stay_gone(served, name):
+    hits = [where for where, text in served.items() if name in text]
+    assert not hits, f"{name} is back in: {hits}"

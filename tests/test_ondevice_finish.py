@@ -9,7 +9,7 @@ finish path in every mode: the device only stops computing tokens the
 host would have discarded anyway.
 
 All engines here run dummy weights (seeded init → deterministic logits)
-on the CPU backend, like bench.py --tiny.
+on the CPU backend.
 """
 
 import dataclasses
@@ -158,7 +158,7 @@ def test_early_exit_when_all_rows_die(organic):
     assert evs, "no fused blocks formed"
     assert all("k_exec" in e for e in evs)
     assert any(e["k_exec"] < e["k"] for e in evs), evs
-    # the summarizer aggregates the dead-substep share for bench.py
+    # the summarizer aggregates the dead-substep share
     assert summarize(evs)["dead_substep_frac"] is not None
 
 

@@ -61,8 +61,8 @@ def on_tpu(monkeypatch):
     compilation_cache.reset_cache()
 
 
-# The chip smoke's one-chip model (chip_smoke.py SMOKE_MODEL): the
-# Llama-3.2-1B widths of bench.py flagship_model_cfg at full depth.
+# The chip smoke's one-chip model (chip_smoke.py MODEL_1CHIP): the
+# Llama-3.2-1B widths at full depth.
 def smoke_model_cfg() -> ModelConfig:
     return ModelConfig(
         architecture="LlamaForCausalLM", vocab_size=128256,

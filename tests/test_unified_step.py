@@ -463,8 +463,7 @@ def test_dispatch_shape_acceptance(model_cfg):
     their token ladder), runs no more unfused decode steps, and retires
     the 'waiting' break class — all deterministic counts, not wall
     fractions (the wall-based unfused_frac is already ≈0 in both arms
-    since the pipelined loop landed; bench.py's unified_ab reports
-    both)."""
+    since the pipelined loop landed)."""
     def arm(unified):
         llm = make_llm(model_cfg, unified=unified, multi_step_decode=4,
                        decode_slot_batching=True, ondevice_finish=True,
