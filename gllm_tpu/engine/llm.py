@@ -633,8 +633,17 @@ class LLM:
 
     # ---- main loops -------------------------------------------------------
 
-    def step(self) -> List[SeqOutput]:
+    def step(self, after_dispatch: Optional[Callable[[], None]] = None
+             ) -> List[SeqOutput]:
         """One engine iteration.
+
+        ``after_dispatch``, if given, is called once in a pass that has
+        work on the device: after the fill pass has returned from its
+        last dispatch and before the blocking collect. It is the seam at
+        which a caller does work that should run under the device's step
+        and not between two of them (``ServingEngine._run_loop`` hands
+        the previous step's chunks to the handler threads there). A pass
+        that returns without anything in flight never calls it.
 
         Keeps up to ``pp`` microbatches in flight (the pipeline depth —
         reference scheduler.py:358-364 keeps pp_size batches running), then
@@ -670,8 +679,8 @@ class LLM:
             # loop's reform machinery; overlap alone keeps the legacy
             # sync dp loop.
             if self.pipelined and self.config.overlap_scheduling:
-                return self._step_dp_overlap()
-            return self._step_dp()
+                return self._step_dp_overlap(after_dispatch)
+            return self._step_dp(after_dispatch)
         pp = self.config.parallel.pp
         depth = max(1, self.config.pp_pipeline_depth or pp)
         overlap = self.config.overlap_scheduling
@@ -891,6 +900,8 @@ class LLM:
                 # gate-B-blocked seqs park in waiting; don't spin hot
                 time.sleep(0.002)
             return []
+        if after_dispatch is not None:
+            after_dispatch()
         # Fault points (gllm_tpu/faults.py, docs/robustness.md): fired
         # BEFORE the in-flight pop so quarantine_step_failure still sees
         # the batch it must attribute the failure to; the stall mimics a
@@ -1417,9 +1428,10 @@ class LLM:
                                              include_prev=True,
                                              spec_mult=self.spec_mult)
 
-    def _step_dp(self) -> List[SeqOutput]:
+    def _step_dp(self, after_dispatch=None) -> List[SeqOutput]:
         """One synchronous step over all DP replicas (single jit program;
-        idle replicas run dummy batches inside it)."""
+        idle replicas run dummy batches inside it). ``after_dispatch``:
+        as in :meth:`step`."""
         sched_ph = spans.phase("schedule").start()
         batches = [s.schedule_once() for s in self.schedulers]
         sched_ph.stop()
@@ -1431,6 +1443,8 @@ class LLM:
         handle = self.runner.step_async_dp(batches)
         phases = spans.take_phases()
         phases["t_enter"] = sched_ph.t0
+        if after_dispatch is not None:
+            after_dispatch()
         t0 = time.monotonic()
         rows, auxes = self.runner.collect_dp(handle)
         live = [b for b in batches if b is not None]
@@ -1474,7 +1488,7 @@ class LLM:
                                                  self.eos_token_ids))
         return outs
 
-    def _step_dp_overlap(self) -> List[SeqOutput]:
+    def _step_dp_overlap(self, after_dispatch=None) -> List[SeqOutput]:
         """dp fast path (docs/overlap_scheduling.md#topology-matrix):
         the stacked replica program forces lockstep (it donates the
         stacked KV), so the pipelined loop runs ahead in dp-wide
@@ -1559,6 +1573,8 @@ class LLM:
             self._note_stall("depth")
         if not self._in_flight:
             return []
+        if after_dispatch is not None:
+            after_dispatch()
         faults.FAULTS.maybe_stall("dispatch_stall")
         faults.FAULTS.maybe_raise("step_exception")
         entry = self._in_flight.popleft()

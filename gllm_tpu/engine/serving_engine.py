@@ -89,6 +89,12 @@ _M_ADMIT_LAG = obs.histogram(
     "request body read to llm.add_seq returning in the engine loop: "
     "parse, validation, tokenisation, the intake queue",
     buckets=obs.FAST_LATENCY_BUCKETS)
+_M_DELIVER = obs.counter(
+    "gllm_deliver_total",
+    "collected steps whose outputs were handed to the handler threads, by "
+    "when: after_dispatch (the next step was already on the device) or "
+    "flush (nothing to launch: idle, a step that dispatched nothing, a "
+    "failed step, a deadline, shutdown)", ("when",))
 _M_STEP_FAIL = obs.counter(
     "gllm_engine_step_failures_total",
     "engine iterations that raised (each quarantines its batch)")
@@ -108,6 +114,13 @@ _M_UNHEALTHY_REASON = obs.gauge(
     "while healthy", ("reason",))
 _UNHEALTHY_REASON_CLASSES = ("step_failures", "stall", "loop_death",
                              "crash_loop")
+
+
+class _HandOverFailed(Exception):
+    """Carries an exception of the hand-over out through ``LLM.step``,
+    inside which it runs, so that the loop does not take it for a failed
+    step and quarantine the batch in flight (``__cause__`` is the
+    exception)."""
 
 
 class RequestRejected(Exception):
@@ -717,7 +730,54 @@ class ServingEngine:
                 self._close_open_handles("abort", "engine stopped")
 
     def _run_loop(self, gen: int) -> None:
+        """The continuous-batching loop. One pass: ``intake``, then
+        ``llm.step`` — the fill pass (``schedule``, ``build``,
+        ``dispatch``), the hand-over of the PREVIOUS step's outputs
+        (``deliver``), the collect (``wait``, ``readback``, ``output``).
+
+        A collected step's outputs are held as ``pending`` and handed
+        to the handler threads only once the next step is on the device
+        (``LLM.step``'s ``after_dispatch`` seam): 32 woken handlers then
+        send their chunks while this thread is blocked in ``wait`` with
+        the interpreter released, and not while it builds the next step
+        with the device idle. Wherever the loop will not launch — nothing
+        left to run, a step that dispatched nothing, a failed step, a
+        deadline about to close a stream, the loop's exit — the pending
+        outputs are flushed at once; a superseded generation drops them
+        (they were never committed to the journal, so replay recomputes
+        them)."""
         llm = self.llm
+        pending: list = []      # collected, committed in the scheduler,
+                                # not yet delivered
+        handed = False          # this pass has reached the hand-over
+
+        def hand_over(when: str = "after_dispatch") -> None:
+            nonlocal pending, handed
+            handed = True
+            if self._gen != gen:
+                # a hard-stall recovery abandoned this thread while it
+                # was blocked in the fill pass — the rebuilt engine owns
+                # the handles; delivering now would corrupt their streams
+                pending = []
+                return
+            outputs, pending = pending, []
+            try:
+                with phase("deliver"):
+                    if outputs:
+                        _M_DELIVER.inc(when=when)
+                        self._deliver(llm, outputs)
+                    # aborted sequences never produce a SeqOutput →
+                    # close their streams here; only ever right after
+                    # the pending outputs have gone out, so a sequence
+                    # that FINISHED in them has left _seqs and is not
+                    # taken for an abort
+                    self._reap_aborted()
+            except Exception as e:
+                if when != "after_dispatch":
+                    raise
+                # inside llm.step: not a failure of the step
+                raise _HandOverFailed() from e
+
         while not self._stop and self._gen == gen:
             self._heartbeat = time.monotonic()
             # chaos point (docs/robustness.md#recovery-lifecycle): dies
@@ -728,18 +788,36 @@ class ServingEngine:
             with phase("intake"):
                 drained = self._drain_intake(llm)
                 self._drain_push_work(llm)
-                self._expire_deadlines()
+                expired = self._expired_deadlines()
+            if expired:
+                # the budget ran out between a step's collect and the
+                # hand-over of its tokens: those go first (a final chunk
+                # closes the stream with its own finish_reason, a middle
+                # token precedes the ``deadline`` chunk)
+                hand_over("flush")
+                with phase("intake"):
+                    self._expire_deadlines(expired)
             if not llm.has_unfinished:
+                if pending:
+                    hand_over("flush")
                 if not drained:
                     with phase("idle"):
                         self._wake.wait(timeout=0.05)
                         self._wake.clear()
                 continue
+            handed = False
             try:
-                outputs = llm.step()
+                outputs = llm.step(after_dispatch=hand_over)
+            except _HandOverFailed as e:
+                raise e.__cause__     # the loop dies of it, as it always has
             except Exception as e:
                 if self._gen != gen:
                     return        # superseded while blocked in step
+                if not handed:
+                    # step N's tokens are real (committed in the
+                    # scheduler) whatever became of step N+1: out before
+                    # the quarantine
+                    hand_over("flush")
                 logger.exception("engine step failed")
                 self._on_step_failure(e)
                 continue
@@ -749,11 +827,14 @@ class ServingEngine:
                 # handles; delivering now would corrupt their streams
                 return
             self._failed_steps = 0
-            with phase("deliver"):
-                self._deliver(llm, outputs)
-                # aborted sequences never produce a SeqOutput → close
-                # their streams here
-                self._reap_aborted()
+            if not handed:
+                # the pass never reached the seam: it launched nothing
+                # (``[]`` paths), so there is nothing to hide the
+                # hand-over behind
+                hand_over("flush")
+            pending = outputs
+        if self._gen == gen:
+            hand_over("flush")      # drain / shutdown: the loop's exit
 
     def _drain_intake(self, llm) -> bool:
         """Admit everything the front end has queued (the ``intake``
@@ -1090,17 +1171,22 @@ class ServingEngine:
         self._wake.set()
         return replayed, dropped
 
-    def _expire_deadlines(self) -> None:
-        """Abort requests past their wall-clock budget — including ones
-        still sitting unscheduled in the waiting queue, which the
-        per-step output path would never touch."""
+    def _expired_deadlines(self) -> list:
+        """Requests past their wall-clock budget — including ones still
+        sitting unscheduled in the waiting queue, which the per-step
+        output path would never touch."""
         if not self._deadlines:
-            return
+            return []
         now = time.monotonic()
         with self._lock:
-            expired = [sid for sid, t in self._deadlines.items()
-                       if now >= t]
+            return [sid for sid, t in self._deadlines.items() if now >= t]
+
+    def _expire_deadlines(self, expired: list) -> None:
+        """Abort them. A stream that closed since the list was made (its
+        final chunk was pending and has gone out) is left alone."""
         for sid in expired:
+            if sid not in self._deadlines:
+                continue
             self.llm.abort(sid)
             _M_DEADLINE.inc()
             self._deliver_error(sid, "deadline")

@@ -37,6 +37,8 @@ import time
 from collections import deque
 from typing import Dict, Iterable, List, Optional
 
+from gllm_tpu.obs import metrics as _metrics
+
 __all__ = ["SpanTrace", "SPANS", "chrome_trace", "SPAN_PHASES",
            "ENGINE_PHASES", "HOST_PHASES", "phase", "take_phases",
            "step_phases", "set_capture", "capturing"]
@@ -54,7 +56,7 @@ SPAN_PHASES = ("queued", "prefill_chunk", "decode_step", "decode_chain",
 
 # Engine-loop phases (docs/observability.md phase catalog): the closed
 # vocabulary of what the engine thread does with its time, each opened
-# where the work is done. One pass of the loop, in order:
+# where the work is done. One pass of the serving loop, in order:
 #   intake    ServingEngine._run_loop: intake drain (llm.add_seq),
 #             _drain_push_work, _expire_deadlines
 #   schedule  LLM.step fill pass: scheduler passes forming the batch/chain
@@ -62,16 +64,20 @@ SPAN_PHASES = ("queued", "prefill_chunk", "decode_step", "decode_chain",
 #   dispatch  runner: the jit call and _start_host_copy; the FIRST use of
 #             a step signature nests a ``first_use`` span inside it
 #             (trace + lower + compile or cache read)
+#   deliver   ServingEngine._run_loop at LLM.step's after_dispatch seam:
+#             the PREVIOUS step's outputs — deliver_output (detokenise,
+#             the handles' queues), the journal, _reap_aborted — so the
+#             handler threads send them under this step's ``wait``; at
+#             once (a flush) wherever the pass launches nothing
 #   wait      runner.collect: blocked until the step's tokens are on the
 #             host (the program, then the copy started at dispatch) — the
 #             only phase in which an idle device is not the host's doing
 #   readback  runner.collect: the step's other outputs (logprobs, finish
 #             steps, speculation counts) to numpy, ready with the tokens
 #   output    LLM.step after the collect: process_output, logprobs, stop
-#             strings, _observe_outputs
-#   deliver   ServingEngine._run_loop: deliver_output (detokenise, the
-#             handles' queues), the journal, _reap_aborted
+#             strings, _observe_outputs (the next schedule needs it)
 #   idle      ServingEngine._run_loop: _wake.wait, nothing to do
+# (the tuple keeps the order its readers have always had)
 ENGINE_PHASES = ("intake", "schedule", "build", "dispatch", "wait",
                  "readback", "output", "deliver", "idle")
 # What a step event's ``ph`` holds: the phases in which the HOST works,
@@ -83,6 +89,32 @@ HOST_PHASES = ("intake", "schedule", "build", "dispatch", "output",
                "deliver")
 
 # ---- the phase clock -------------------------------------------------------
+
+# Where the engine thread's time went, by phase, on two clocks. The wall
+# counter minus the CPU counter over the HOST_PHASES is time the thread
+# spent in a phase without running: queued for the interpreter behind the
+# handler threads, or descheduled (docs/observability.md#tracing). Over
+# wait / readback / idle the difference is the blocking those phases are
+# for.
+_M_PHASE_WALL = _metrics.counter(
+    "gllm_engine_phase_wall_seconds_total",
+    "wall seconds the engine thread spent in each engine-loop phase",
+    ("phase",))
+_M_PHASE_CPU = _metrics.counter(
+    "gllm_engine_phase_cpu_seconds_total",
+    "CPU seconds of the engine thread itself (time.thread_time) inside "
+    "each engine-loop phase", ("phase",))
+_phase_children: Dict[str, tuple] = {}
+
+
+def _phase_counters(name: str) -> tuple:
+    """The (wall, cpu) counter children of one phase, bound once."""
+    pair = _phase_children.get(name)
+    if pair is None:
+        pair = _phase_children[name] = (_M_PHASE_WALL.labels(phase=name),
+                                        _M_PHASE_CPU.labels(phase=name))
+    return pair
+
 
 _capturing = False      # a profiler capture is running in this process
 _annotation = None      # jax.profiler.TraceAnnotation, from the first capture
@@ -118,8 +150,9 @@ def take_phases() -> dict:
     by an empty one. The engine takes it when a step is dispatched and
     again when it is collected, so every second a phase measured lands in
     exactly one step event: what ran since the previous take — the
-    previous step's ``output`` / ``deliver``, this pass's ``intake`` —
-    rides with the step dispatched next."""
+    previous step's ``output``, this pass's ``intake`` — rides with the
+    step dispatched next, and the previous step's ``deliver`` (run at
+    the seam, after the dispatch's take) with the same step's collect."""
     ph = _open_phases()
     _tls.ph = {}
     return ph
@@ -130,21 +163,27 @@ class phase:
 
     Always: wall seconds added to the thread's open phase dict under
     ``name`` (``add=False`` for a span nested in another phase, which
-    would count twice) and kept on ``.seconds``. While a capture runs:
-    also a ``TraceAnnotation("gllm:<name>", **args)``. With none running
-    a phase is two clock reads and one dict add, and constructs nothing
-    of jax. ``start()`` / ``stop()`` open and close it by hand where the
-    phase ends in several places of a loop body; ``stop`` is idempotent.
+    would count twice) and kept on ``.seconds``; the same wall seconds,
+    and the thread's own CPU seconds beside them (``.cpu_seconds``,
+    ``time.thread_time()``), added to the two phase counters. While a
+    capture runs: also a ``TraceAnnotation("gllm:<name>", **args)``.
+    With none running a phase is four clock reads, one dict add and two
+    counter adds, and constructs nothing of jax. ``start()`` / ``stop()``
+    open and close it by hand where the phase ends in several places of
+    a loop body; ``stop`` is idempotent.
     """
 
-    __slots__ = ("name", "args", "add", "t0", "seconds", "_ann", "_open")
+    __slots__ = ("name", "args", "add", "t0", "c0", "seconds",
+                 "cpu_seconds", "_ann", "_open")
 
     def __init__(self, name: str, add: bool = True, **args):
         self.name = name
         self.args = args
         self.add = add
         self.t0 = None
+        self.c0 = 0.0
         self.seconds = 0.0
+        self.cpu_seconds = 0.0
         self._ann = None
         self._open = False
 
@@ -153,7 +192,9 @@ class phase:
             self._ann = _annotation("gllm:" + self.name, **self.args)
             self._ann.__enter__()
         self._open = True
+        # the wall reading encloses the CPU reading, so wall - CPU >= 0
         self.t0 = time.monotonic()
+        self.c0 = time.thread_time()
         return self
 
     def __exit__(self, *exc):
@@ -166,10 +207,14 @@ class phase:
         if not self._open:
             return
         self._open = False
+        self.cpu_seconds = time.thread_time() - self.c0
         self.seconds = time.monotonic() - self.t0
         if self.add:
             ph = _open_phases()
             ph[self.name] = ph.get(self.name, 0.0) + self.seconds
+            wall, cpu = _phase_counters(self.name)
+            wall.inc(self.seconds)
+            cpu.inc(self.cpu_seconds)
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
             self._ann = None
@@ -372,9 +417,13 @@ def chrome_trace(step_events: Iterable[dict], spans: Iterable[dict] = (),
 
     Engine phases are reconstructed backwards from each step event's
     collect-end timestamp ``t`` using the recorded phase walls:
-    ``[t - step_wall, t]`` holds schedule → build → dispatch → wait →
-    collect in order (wait = the pipelined slack between dispatch end
-    and collect start).
+    ``[t - step_wall, t]`` holds schedule → build → dispatch → deliver →
+    wait → collect in order (deliver = the previous step's outputs
+    handed over at the seam, where a serving loop recorded one; a
+    flushed hand-over, which ran before the schedule, is drawn there
+    too; wait = the pipelined slack between dispatch end and collect
+    start); the previous step's output and this pass's intake lie
+    before it.
     Request spans use absolute monotonic times; ``span_t0`` (the
     steptrace ring's epoch) rebases them onto the same axis.
     """
@@ -394,16 +443,18 @@ def chrome_trace(step_events: Iterable[dict], spans: Iterable[dict] = (),
         build = float(ph.get("build", 0.0)) / 1e3
         disp = float(ph.get("dispatch", 0.0)) / 1e3
         coll = float(ph.get("collect", e.get("wall_ms", 0.0))) / 1e3
+        deliver = float(ph.get("deliver", 0.0)) / 1e3
         wall = float(e.get("step_wall_ms",
-                           (sched + build + disp + coll) * 1e3)) / 1e3
-        wait = max(0.0, wall - (sched + build + disp + coll))
+                           (sched + build + disp + deliver + coll)
+                           * 1e3)) / 1e3
+        wait = max(0.0, wall - (sched + build + disp + deliver + coll))
         args = {"kind": e.get("kind"), "seq": e.get("seq"),
                 "num_seqs": e.get("num_seqs"),
                 "tokens": e.get("tokens")}
         if "k" in e:
             args["k"] = e["k"]
         t = end - wall
-        for name in ("intake", "deliver", "output"):
+        for name in ("intake", "output"):
             dur = float(ph.get(name, 0.0)) / 1e3
             if dur > 0:
                 t -= dur
@@ -411,8 +462,8 @@ def chrome_trace(step_events: Iterable[dict], spans: Iterable[dict] = (),
                                  dur, _PID_ENGINE, _ENGINE_TIDS[name]))
         t = end - wall
         for name, dur in (("schedule", sched), ("build", build),
-                          ("dispatch", disp), ("wait", wait),
-                          ("collect", coll)):
+                          ("dispatch", disp), ("deliver", deliver),
+                          ("wait", wait), ("collect", coll)):
             if dur > 0:
                 events.append(_x(f"{e.get('kind', 'step')}:{name}", t,
                                  dur, _PID_ENGINE, _ENGINE_TIDS[name],

@@ -166,7 +166,7 @@ def test_chrome_trace_schema_and_phase_reconstruction():
     # exactly step_wall, ending at the event's t
     by_name = {e["name"]: e for e in eng}
     order = ["prefill:schedule", "prefill:build", "prefill:dispatch",
-             "prefill:wait", "prefill:collect"]
+             "prefill:deliver", "prefill:wait", "prefill:collect"]
     present = [n for n in order if n in by_name]
     assert present[0] == "prefill:schedule"
     first = by_name[present[0]]
@@ -177,12 +177,15 @@ def test_chrome_trace_schema_and_phase_reconstruction():
     # the host's tracks only: what the device did is the profiler's to say
     assert not any(n.endswith(":device") for n in by_name)
     # what the event carries from before its schedule began lies before
-    # it, in the order the loop ran it: output, deliver, intake
-    before = [by_name[f"prefill:{n}"] for n in ("output", "deliver",
-                                                  "intake")]
+    # it, in the order the loop ran it: output, intake; the previous
+    # step's deliver runs at the seam, between dispatch and wait
+    before = [by_name[f"prefill:{n}"] for n in ("output", "intake")]
     assert before[-1]["ts"] + before[-1]["dur"] \
         == pytest.approx(first["ts"], abs=2)
     assert [b["ts"] for b in before] == sorted(b["ts"] for b in before)
+    slices = [by_name[n] for n in order]
+    for a, b in zip(slices, slices[1:]):
+        assert a["ts"] + a["dur"] == pytest.approx(b["ts"], abs=2)
     # request track: root slice + children on tid 7
     assert all(e["tid"] == 7 for e in req)
     names = {e["name"] for e in req}
@@ -242,6 +245,84 @@ def test_phase_adds_to_the_open_dict_and_makes_no_annotation(monkeypatch):
     assert _CountingAnnotation.made == [
         ("gllm:wait", {}),
         ("gllm:dispatch", {"step": 7, "rows": 2, "tokens": 2})]
+
+
+@pytest.mark.parametrize("how", ["sleeps", "spins"])
+def test_phase_reads_the_threads_cpu_time_beside_the_wall(how):
+    """A phase that blocks reads wall >> CPU, one that computes reads
+    them equal; both land in the two per-phase counters, whose
+    difference is what ``engine.interp_wait_ms_per_step`` reads."""
+    wall, cpu = obs_spans._phase_counters("output")
+    w0, c0 = wall.get(), cpu.get()
+    with phase("output") as ph:
+        if how == "sleeps":
+            time.sleep(0.05)
+        else:
+            t_end = time.thread_time() + 0.05
+            while time.thread_time() < t_end:
+                pass
+    take_phases()
+    assert wall.get() - w0 == pytest.approx(ph.seconds)
+    assert cpu.get() - c0 == pytest.approx(ph.cpu_seconds)
+    assert ph.seconds >= 0.05
+    if how == "sleeps":
+        assert ph.cpu_seconds < 0.01
+    else:
+        # alone on its core the two agree; a loaded test host may take
+        # the core away, which only ever makes the wall longer
+        assert 0.05 <= ph.cpu_seconds <= ph.seconds + 1e-4
+    # a nested span (add=False) is in its parent's time: not counted
+    wall_fu, _ = obs_spans._phase_counters("first_use")
+    f0 = wall_fu.get()
+    with phase("first_use", add=False):
+        pass
+    assert wall_fu.get() == f0
+
+
+def _prom(rows):
+    return "".join(f"{name}{labels} {value}\n"
+                   for name, labels, value in rows)
+
+
+def _interp_wait_run(wall, cpu, steps):
+    """A run's two /metrics texts: zero at the first end, the given
+    seconds by phase and dispatches at the second."""
+    def text(scale):
+        rows = [("gllm_sampler_program_total", '{kind="greedy"}',
+                 steps * scale)]
+        for clock, by_phase in (("wall", wall), ("cpu", cpu)):
+            rows += [(f"gllm_engine_phase_{clock}_seconds_total",
+                      f'{{phase="{p}"}}', v * scale)
+                     for p, v in by_phase.items()]
+        return _prom(rows)
+    return {"prom0": text(0), "prom1": text(1)}
+
+
+def test_interp_wait_metric_reads_wall_minus_cpu_over_the_host_phases():
+    sys.path.insert(0, os.path.join(REPO, "perfbench"))
+    try:
+        from run import load_module
+        read = load_module("layer_metrics",
+                           "engine.interp_wait_ms_per_step").read
+    finally:
+        sys.path.remove(os.path.join(REPO, "perfbench"))
+    host = {"intake": 0.02, "schedule": 0.2, "build": 0.9,
+            "dispatch": 0.5, "output": 0.6, "deliver": 0.4}
+    blocked = {"wait": 18.0, "readback": 0.1, "idle": 3.0}
+    # 1000 dispatches; the thread ran for all of its host time but 3 s
+    # of ``build`` and 0.2 s of ``schedule``: 3.2 ms a step. The time
+    # blocked in wait / readback / idle is not the interpreter's.
+    cpu = dict(host, build=host["build"], **{k: 0.001 for k in blocked})
+    wall = dict(host, build=host["build"] + 3.0,
+                schedule=host["schedule"] + 0.2, **blocked)
+    assert read(_interp_wait_run(wall, cpu, 1000)) \
+        == pytest.approx(3.2)
+    assert read(_interp_wait_run(cpu, cpu, 1000)) == pytest.approx(0.0)
+    # a program without the counters (the parent), or no /metrics read
+    parent = {"prom0": _prom([("gllm_sampler_program_total", "", 0)]),
+              "prom1": _prom([("gllm_sampler_program_total", "", 500)])}
+    assert read(parent) is None
+    assert read({"prom0": None, "prom1": None}) is None
 
 
 def test_obs_imports_no_jax_until_the_first_capture():
@@ -598,6 +679,40 @@ def test_steptrace_kind_filter(trace_server):
                         "/steptrace?kind=prefill,decode")
     kinds = {e["kind"] for e in json.loads(body)["events"]}
     assert kinds <= {"prefill", "decode"}
+
+
+def test_phase_and_deliver_counters_in_metrics(trace_server):
+    """/metrics carries the engine thread's seconds by phase on both
+    clocks (one label: ``phase``) and how the steps' outputs left."""
+    def sample(text, name, label):
+        for line in text.splitlines():
+            if line.startswith(name + label + " "):
+                return float(line.rsplit(" ", 1)[1])
+        return None
+
+    before = _req(trace_server, "GET", "/metrics")[1].decode()
+    _drive_completion(trace_server)
+    status, body = _req(trace_server, "GET", "/metrics")
+    assert status == 200
+    text = body.decode()
+    for name in ENGINE_PHASES:
+        label = f'{{phase="{name}"}}'
+        wall = sample(text, "gllm_engine_phase_wall_seconds_total", label)
+        cpu = sample(text, "gllm_engine_phase_cpu_seconds_total", label)
+        assert wall is not None and cpu is not None, name
+        assert 0 <= cpu <= wall + 1e-3, (name, cpu, wall)
+    # idling is all wall and next to no CPU
+    assert sample(text, "gllm_engine_phase_cpu_seconds_total",
+                  '{phase="idle"}') \
+        < 0.5 * sample(text, "gllm_engine_phase_wall_seconds_total",
+                       '{phase="idle"}')
+    grew = {when: sample(text, "gllm_deliver_total", f'{{when="{when}"}}')
+            - (sample(before, "gllm_deliver_total",
+                      f'{{when="{when}"}}') or 0.0)
+            for when in ("after_dispatch", "flush")}
+    # one request alone: every step but its last is handed over behind
+    # the next one's dispatch, the last is flushed
+    assert grew["flush"] == 1 and grew["after_dispatch"] >= 2
 
 
 def _hist_count(name):
