@@ -23,6 +23,18 @@ class SchedulerConfig:
     max_decode_seqs: int = 256            # --maxd: decode seqs per batch
     max_prefill_tokens: int = 2048        # --maxp: prefill token budget per batch
     min_prefill_tokens: int = 128         # --minp: throttling lower clamp
+    # --min-token-bucket: the smallest token bucket of a mixed step (a
+    # power of two). Every bucket is a step program to compile; a
+    # deployment whose prompts are long raises it and pads its short last
+    # chunks instead of building programs for them.
+    min_token_bucket: int = 16
+    # --min-row-bucket / --min-page-bucket: the same for the other two
+    # axes of a step program's shape (rows of sequences, page-table
+    # width). A deployment that runs full raises them to its --maxd and
+    # to its --max-model-len in pages and builds one program a token
+    # bucket; steps under the floor are padded to it.
+    min_row_bucket: int = 8
+    min_page_bucket: int = 4
     iter_smooth: int = 16                 # --iterp: waiting-token smoothing divisor
     init_new_token_ratio: float = 0.7     # adaptive KV admission ramp start
     min_new_token_ratio: float = 0.1      # ramp floor

@@ -145,6 +145,38 @@ class RequestOutput:
         return self.finish_reason is not None
 
 
+def refuse_for_windowed(config: EngineConfig) -> None:
+    """A model with windowed latent layers keeps of each sequence, in
+    those layers, a ring of the window's last rows and nothing older. So
+    whatever needs a sequence's rows again after the fact, or writes
+    rows that may be taken back, is refused at start-up (ROADMAP B4 lists
+    these beside the hybrid models'): a cached prefix has no rows to
+    resume from, a tier below the pages would hold the full layers' half
+    of a sequence, a rejected draft or a discarded fused block has
+    already overwritten the ring, and the unified step re-forms steps."""
+    cache, par = config.cache, config.parallel
+    asked = [
+        ("--enable-prefix-caching", cache.enable_prefix_caching),
+        ("a host or disk KV tier (--kv-host-pool-*, --kv-disk-path, "
+         "--prefix-peers)",
+         cache.host_pool_configured or cache.kvstore_configured),
+        ("--spec-decode / --spec-fused", bool(config.spec_decode)),
+        ("--unified-step", config.unified_step),
+        ("fused multi-step decoding (--multi-step-decode, "
+         "--decode-chain-len, --ondevice-finish)",
+         config.multi_step_decode > 1 or config.ondevice_finish
+         or config.decode_chain_len is not None),
+        ("tp / pp / dp / sp > 1", par.world_size > 1),
+    ]
+    bad = [name for name, on in asked if on]
+    if bad:
+        raise ValueError(
+            "a model with windowed latent-attention layers "
+            "(layer_types: sliding_attention) keeps only the window's "
+            "rows of a sequence; not supported with it: "
+            + "; ".join(bad))
+
+
 class LLM:
     def __init__(
         self,
@@ -190,6 +222,8 @@ class LLM:
             from gllm_tpu.models.loader import load_hf_config
             model_cfg = from_hf_config(load_hf_config(config.model))
         self.model_cfg = model_cfg
+        if model_cfg.use_swa:
+            refuse_for_windowed(config)
 
         self.tokenizer = tokenizer
         if self.tokenizer is None and config.model and config.tokenizer != "":
@@ -222,6 +256,9 @@ class LLM:
                                            "ssm_snapshot_slots", 0))
             for _ in range(self.dp)]
         self.memory_manager = self.memory_managers[0]
+        if model_cfg.use_swa:
+            from gllm_tpu.memory_manager import _M_SWA_SLOTS
+            self.memory_manager.slot_gauge = _M_SWA_SLOTS
         if getattr(self.runner, "kv_quant", False):
             # int8 KV cache: minted pages queue a device-side scale
             # reset (drained by the runner at dispatch time) so a

@@ -421,6 +421,15 @@ class Handler(BaseHTTPRequestHandler):
                                st.llm.runner.ssm_snapshot_slots}
                               if getattr(st.llm.runner,
                                          "ssm_working_slots", 0) else None),
+                # windowed latent layers: a ring of the window's rows a
+                # sequence and layer (None for a model without them)
+                "swa_rings": ({
+                    "slots": st.llm.runner.ssm_working_slots,
+                    "rows": st.llm.model_cfg.swa_ring_len(
+                        cfg.cache.page_size),
+                    "layers": st.llm.model_cfg.num_swa_layers,
+                    "window": st.llm.model_cfg.sliding_window}
+                    if st.llm.model_cfg.use_swa else None),
                 # the device as jax reports it, so a jax-free parent
                 # (chip_smoke.py) can say what the server ran on
                 "device": _device_info(),
@@ -1089,6 +1098,9 @@ def build_engine_config(args) -> EngineConfig:
             max_decode_seqs=args.maxd,
             max_prefill_tokens=args.maxp,
             min_prefill_tokens=args.minp,
+            min_token_bucket=args.min_token_bucket,
+            min_row_bucket=args.min_row_bucket,
+            min_page_bucket=args.min_page_bucket,
             iter_smooth=args.iterp,
             init_new_token_ratio=args.init_new_token_ratio,
             min_new_token_ratio=args.min_new_token_ratio,
@@ -1170,6 +1182,16 @@ def make_parser() -> argparse.ArgumentParser:
     p.add_argument("--maxp", type=int, default=2048)
     p.add_argument("--minp", type=int, default=128)
     p.add_argument("--iterp", type=int, default=16)
+    p.add_argument("--min-token-bucket", type=int, default=16,
+                   help="smallest token bucket of a mixed step (a power "
+                        "of two): fewer step programs to compile, "
+                        "shorter chunks padded to it")
+    p.add_argument("--min-row-bucket", type=int, default=8,
+                   help="smallest row bucket of a step (a power of two, "
+                        "capped at the largest step's rows)")
+    p.add_argument("--min-page-bucket", type=int, default=4,
+                   help="smallest page-table width of a step (a power of "
+                        "two, capped at --max-model-len in pages)")
     p.add_argument("--pool-role", default="mixed",
                    choices=["prefill", "decode", "mixed"],
                    help="pd-pool role advertised on /server_info "

@@ -41,6 +41,10 @@ from gllm_tpu.utils import cdiv
 _M_SSM_SLOTS = obs.gauge(
     "gllm_ssm_slots_in_use",
     "GDN working slots held by running sequences (of max_num_seqs)")
+_M_SWA_SLOTS = obs.gauge(
+    "gllm_swa_ring_slots_in_use",
+    "Window rings held by running sequences (of max_num_seqs): one a "
+    "sequence in every windowed layer, each of swa_ring_len rows")
 _M_SSM_INTENTS = obs.counter(
     "gllm_ssm_intents_total",
     "GDN slot maintenance handed to the runner, by kind", ("kind",))
@@ -126,6 +130,9 @@ class MemoryManager:
 
         self.ssm_working_slots = ssm_working_slots
         self.ssm_snapshot_slots = ssm_snapshot_slots
+        # the gauge the working slots are counted in (the engine names
+        # _M_SWA_SLOTS where the slots are window rings)
+        self.slot_gauge = _M_SSM_SLOTS
         if ssm_working_slots:
             self.ssm_alloc: Optional[IDAllocator] = IDAllocator(
                 ssm_working_slots, start=1)
@@ -158,8 +165,8 @@ class MemoryManager:
             return
         if getattr(seq, "ssm_slot", None) is None:
             seq.ssm_slot = self.ssm_alloc.allocate()
-            _M_SSM_SLOTS.set(self.ssm_working_slots
-                             - self.ssm_alloc.num_free)
+            self.slot_gauge.set(self.ssm_working_slots
+                                - self.ssm_alloc.num_free)
         snap = getattr(seq, "_ssm_restore_snap", None)
         if snap is not None:
             self.ssm_intents.append(("restore", snap, seq.ssm_slot))
@@ -178,8 +185,8 @@ class MemoryManager:
             self.ssm_intents.append(("zero", slot, 0))
             self.ssm_alloc.free(slot)
             seq.ssm_slot = None
-            _M_SSM_SLOTS.set(self.ssm_working_slots
-                             - self.ssm_alloc.num_free)
+            self.slot_gauge.set(self.ssm_working_slots
+                                - self.ssm_alloc.num_free)
 
     def free_snap_after_drain(self, snap: int) -> None:
         """Return a snapshot slot to the pool only once the currently

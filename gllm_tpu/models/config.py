@@ -62,6 +62,10 @@ class ModelConfig:
     # (parallel/shardings.kv_cache_specs) read it so the spec pytree
     # mirrors the cache's scale leaves.
     kv_cache_quant: bool = False
+    # Set by the runner when cache.kv_cache_dtype == "fp8": a DSA model's
+    # index keys then ride per-token f32 scales, a leaf of the cache that
+    # the spec builders have to mirror (parallel/shardings.latent_kv_specs)
+    kv_cache_fp8: bool = False
     decoder_sparse_step: int = 1      # every Nth layer is MoE (qwen2-moe)
     mlp_only_layers: Tuple[int, ...] = ()
     shared_expert_intermediate_size: int = 0
@@ -100,10 +104,67 @@ class ModelConfig:
     index_n_heads: int = 0
     index_head_dim: int = 0
     index_topk: int = 0
+    # score the indexer with fp8 operands where its keys are cached in fp8
+    # (the reference's GLLM_DSA_FP8_SCORE); a key of config.json, never an
+    # environment read: it decides numerics
+    index_fp8_score: bool = False
 
     @property
     def use_dsa(self) -> bool:
         return self.index_topk > 0 and self.index_n_heads > 0
+
+    # Windowed latent-attention layers beside the full ones (dots3_note:
+    # ``layer_types`` marks a layer "sliding_attention"). They have a
+    # latent geometry of their own (the ``swa_*`` keys of the config), a
+    # rotary base of their own, no indexer, and attend the last
+    # ``sliding_window`` tokens, the current one counted. Their rows live
+    # in a ring per sequence (models/deepseek.py), never in pages.
+    sliding_window: int = 0
+    swa_num_heads: int = 0
+    swa_q_lora_rank: int = 0
+    swa_kv_lora_rank: int = 0
+    swa_qk_nope_head_dim: int = 0
+    swa_qk_rope_head_dim: int = 0
+    swa_v_head_dim: int = 0
+    swa_rope_theta: float = 10000.0
+    # "headwise": out = concat_h(sigmoid(u W_g)_h o_h) W_o, one scalar a
+    # head ("" = no gate)
+    attn_gate: str = ""
+    swa_attn_gate: str = ""
+    # c_q, c_kv scaled by sqrt(hidden / rank) after their norms
+    mla_lora_rescale: bool = False
+    # Expert parallelism as one chip's share: the router is
+    # ``num_experts`` wide and chooses among all of them; this process
+    # holds ``experts_held`` of them from ``expert_first`` on and computes
+    # their part of the layer (0 = all of them: the layer is whole).
+    experts_held: int = 0
+    expert_first: int = 0
+
+    @property
+    def use_swa(self) -> bool:
+        return "sliding_attention" in self.layer_types
+
+    @property
+    def num_local_experts(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
+    def swa_cache_width(self) -> int:
+        """A windowed layer's latent row as stored: whole lanes, as
+        ``mla_cache_width`` (1088 -> 1152 for dots3_note)."""
+        width = self.swa_kv_lora_rank + self.swa_qk_rope_head_dim
+        return width + (-width) % 128
+
+    def swa_ring_len(self, page_size: int) -> int:
+        """Rows of one sequence's ring in a windowed layer:
+        ``ceil(window / page) + 1`` pages, so a whole window is always
+        there beside the row being written."""
+        return (-(-self.sliding_window // page_size) + 1) * page_size
+
+    @property
+    def num_swa_layers(self) -> int:
+        return sum(1 for t in self.stage_layer_types
+                   if t == "sliding_attention")
 
     # Multimodal (Qwen-VL family — reference models/qwen2_5_vl.py,
     # rotary_embedding.py:607-706). mrope_section sums to rot_dim/2;
@@ -169,6 +230,12 @@ class ModelConfig:
     @property
     def use_hybrid(self) -> bool:
         return "linear_attention" in self.layer_types
+
+    @property
+    def use_seq_slots(self) -> bool:
+        """Does a sequence hold a slot of per-sequence state beside its
+        pages (recurrent state, or a windowed layer's ring)?"""
+        return self.use_hybrid or self.use_swa
 
     @property
     def stage_layer_types(self) -> Tuple[str, ...]:
@@ -246,7 +313,8 @@ def _eos_tuple(v) -> Optional[Tuple[int, ...]]:
 
 
 # a config.json that names its model_type and no architecture
-_ARCH_OF_MODEL_TYPE = {"olmo_hybrid": "OlmoHybridForCausalLM"}
+_ARCH_OF_MODEL_TYPE = {"olmo_hybrid": "OlmoHybridForCausalLM",
+                       "dots3_note": "Dots3NoteForCausalLM"}
 
 
 def from_hf_config(hf: Dict[str, Any]) -> ModelConfig:
@@ -381,6 +449,38 @@ def from_hf_config(hf: Dict[str, Any]) -> ModelConfig:
             norm_zero_centered=False,
         )
         hf = {**hf, "rope_theta": 10000.0 if theta is None else theta}
+    if arch == "Dots3NoteForCausalLM":
+        # config.json of dots-studio/dots3-note-prev (model_type
+        # dots3_note): DeepSeek-V3.2's keys for the full layers, the
+        # ``swa_*`` keys for the windowed ones. ``ep_share`` is this
+        # repo's own key: {"chips", "rank", "n_routed_experts"} says that
+        # ``n_routed_experts`` counts the experts HELD here, one of
+        # ``chips`` equal shares of the published count
+        share = hf.get("ep_share") or {}
+        held = hf["n_routed_experts"]
+        extra = dict(
+            layer_types=tuple(hf.get("layer_types", ())),
+            sliding_window=hf.get("sliding_window_size", 0) or 0,
+            swa_num_heads=hf.get("swa_num_attention_heads", 0) or 0,
+            swa_q_lora_rank=hf.get("swa_q_lora_rank", 0) or 0,
+            swa_kv_lora_rank=hf.get("swa_kv_lora_rank", 0) or 0,
+            swa_qk_nope_head_dim=hf.get("swa_qk_nope_head_dim", 0) or 0,
+            swa_qk_rope_head_dim=hf.get("swa_qk_rope_head_dim", 0) or 0,
+            swa_v_head_dim=hf.get("swa_v_head_dim", 0) or 0,
+            swa_rope_theta=hf.get("swa_rope_theta", 10000.0),
+            attn_gate=hf.get("attention_gate_type") or "",
+            swa_attn_gate=hf.get("swa_attention_gate_type") or "",
+            mla_lora_rescale=bool(hf.get("apply_mla_qkv_lora_rescale")),
+        )
+        if share:
+            if share["n_routed_experts"] != held * share["chips"]:
+                raise ValueError(
+                    f"ep_share: {share['chips']} chips x {held} experts "
+                    f"held are not the {share['n_routed_experts']} "
+                    "published")
+            extra.update(experts_held=held,
+                         expert_first=held * share.get("rank", 0))
+            hf = {**hf, "n_routed_experts": share["n_routed_experts"]}
     num_heads = hf["num_attention_heads"]
     hidden = hf["hidden_size"]
     head_dim = hf.get("head_dim") or hidden // num_heads
@@ -454,6 +554,7 @@ def from_hf_config(hf: Dict[str, Any]) -> ModelConfig:
         index_n_heads=hf.get("index_n_heads", 0) or 0,
         index_head_dim=hf.get("index_head_dim", 0) or 0,
         index_topk=hf.get("index_topk", 0) or 0,
+        index_fp8_score=bool(hf.get("index_fp8_score", False)),
         scoring_func=hf.get("scoring_func", "softmax") or "softmax",
         topk_method=hf.get("topk_method", "greedy") or "greedy",
         **extra,

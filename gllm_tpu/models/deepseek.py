@@ -19,6 +19,12 @@ TPU-native re-design of the reference deepseek_v2.py (730 LoC,
 - YaRN rope with mscale folded into the cos/sin table and the extra
   mscale**2 factor folded into the softmax scale
   (gllm_tpu/ops/rope.py:yarn_softmax_scale_mult).
+- **The same family with ``layer_types``** (dots3_note): full layers that
+  choose ``index_topk`` positions with DeepSeek-V3.2's indexer beside
+  windowed layers with a latent geometry of their own (``geom``), runs of
+  same-kind layers as scans (``layer_runs``), the windowed layers' rows in
+  a ring a sequence (``LatentKVCache.swa``), headwise gates, and an
+  expert layer that holds a share of its experts (``_held_experts``).
 """
 
 from __future__ import annotations
@@ -31,7 +37,7 @@ import jax.numpy as jnp
 from gllm_tpu.batching import StepBatch
 from gllm_tpu.models import dense
 from gllm_tpu.models.config import ModelConfig
-from gllm_tpu.models.moe import select_experts
+from gllm_tpu.obs import metrics as obs
 from gllm_tpu.ops import (fused_add_rms_norm, paged_attention, rms_norm,
                           silu_and_mul)
 from gllm_tpu.ops.attention import AttentionMetadata
@@ -43,61 +49,170 @@ Params = dict
 
 
 class LatentKVCache(NamedTuple):
-    """latent: [L, num_pages, page_size, kv_lora_rank + qk_rope_head_dim];
+    """latent: [L, num_pages, page_size, kv_lora_rank + qk_rope_head_dim]
+    of the layers that attend their whole context (all of them, unless
+    ``layer_types`` names windowed ones);
     index_k: parallel DSA indexer-key cache [L, num_pages, page_size,
-    index_head_dim], stored fp8-e4m3 with per-token scales in
+    index_head_dim] in the cache's dtype; under an fp8 cache
+    (``--kv-cache-dtype fp8``) fp8-e4m3 payloads with per-token scales in
     ``index_scale`` [L, num_pages, page_size] (the reference's packed
     132-byte store_index_k_fp8 layout, layers/ops/cache_kernels.py — here
     two parallel paged arrays instead of byte-packing, which XLA can't
-    slice)."""
+    slice);
+    swa: the windowed layers' latent rows, a ring per sequence slot
+    [L_swa, slots, ring, swa_cache_width]: position p of a sequence lies
+    in row ``p % ring`` of its slot, so a layer never holds more of a
+    sequence than its window (slot 0 is the padding rows' dummy);
+    stats: [N_STATS] int32, what THIS step's indexer and expert layers
+    counted (``STATS``); the runner hands it to the host beside the
+    step's tokens."""
     latent: jnp.ndarray
     index_k: Optional[jnp.ndarray] = None
     index_scale: Optional[jnp.ndarray] = None
+    swa: Optional[jnp.ndarray] = None
+    stats: Optional[jnp.ndarray] = None
 
 
-def index_cache_fp8() -> bool:
-    """fp8 index-K storage (the reference's fixed layout) — default on;
-    ``GLLM_TPU_DSA_INDEX_DTYPE=native`` keeps the cache in the model
-    dtype. Read once per process (the choice is baked into compiled
-    programs)."""
-    import os
-    return os.environ.get("GLLM_TPU_DSA_INDEX_DTYPE", "fp8") == "fp8"
+# what ``LatentKVCache.stats`` holds, in order: positions the indexer
+# scored and positions it chose (summed over the step's tokens and the
+# full layers), routed assignments to experts held here and to absent
+# ones, held experts with a token, and expert layers run (the last four
+# summed over the expert layers)
+STATS = ("dsa_seen", "dsa_chosen", "moe_held", "moe_absent", "moe_touched",
+         "moe_layers")
+
+# docs/observability.md: the counters a per-layer metric of the benchmark
+# reads (perfbench/layer_metrics/dsa.*, moe.*)
+_M_DSA = obs.counter(
+    "gllm_dsa_positions_total",
+    "Positions the DSA indexer scored (seen) and chose (chosen), summed "
+    "over tokens and full-attention layers", ("what",))
+_M_MOE_ASSIGN = obs.counter(
+    "gllm_moe_assignments_total",
+    "Routed (token, expert) assignments by where the expert lives: held "
+    "by this process or absent (another chip of the expert-parallel "
+    "deployment), summed over expert layers", ("where",))
+_M_MOE_TOUCHED = obs.counter(
+    "gllm_moe_experts_touched_total",
+    "Held experts that had at least one token, summed over expert layers "
+    "and steps, by the kind of step (decode: one token a row; mixed: a "
+    "prefill chunk rides)", ("step",))
+_M_MOE_STEPS = obs.counter(
+    "gllm_moe_layer_steps_total",
+    "Expert layers run, summed over steps (the denominator of experts "
+    "touched a layer and step), by the kind of step", ("step",))
 
 
-def fp8_score() -> bool:
-    """Score the lightning indexer with fp8 operands (reference
-    GLLM_DSA_FP8_SCORE): q rows quantized per (seq, query, head), the
-    fp8×fp8 dot accumulated in f32 and rescaled. Off by default (bf16/f32
-    scoring of dequantized keys)."""
-    import os
-    return os.environ.get("GLLM_DSA_FP8_SCORE", "0") == "1"
+def count_stats(stats, decode_only: bool) -> None:
+    """One step's ``LatentKVCache.stats`` (host array) into the counters."""
+    v = [int(x) for x in stats]
+    kind = "decode" if decode_only else "mixed"
+    _M_DSA.inc(v[0], what="seen")
+    _M_DSA.inc(v[1], what="chosen")
+    _M_MOE_ASSIGN.inc(v[2], where="held")
+    _M_MOE_ASSIGN.inc(v[3], where="absent")
+    _M_MOE_TOUCHED.inc(v[4], step=kind)
+    _M_MOE_STEPS.inc(v[5], step=kind)
 
 
 _FP8_MAX = 448.0     # float8_e4m3fn finite max
+FULL, SWA = "full_attention", "sliding_attention"
+BQ = 128             # queries of one work item of the chunk loop
+
+
+def has_stats(cfg: ModelConfig) -> bool:
+    return bool(cfg.use_swa or cfg.experts_held)
 
 
 def init_kv_cache(cfg: ModelConfig, num_pages: int, page_size: int,
-                  dtype=jnp.bfloat16) -> LatentKVCache:
-    latent = jnp.zeros(
-        (cfg.num_stage_layers, num_pages, page_size, cfg.mla_cache_width),
-        dtype)
-    index_k = index_scale = None
+                  dtype=jnp.bfloat16, num_slots: int = 0) -> LatentKVCache:
+    n_full = cfg.num_attn_layers if cfg.use_swa else cfg.num_stage_layers
+    latent = jnp.zeros((n_full, num_pages, page_size, cfg.mla_cache_width),
+                       dtype)
+    index_k = index_scale = swa = None
     if cfg.use_dsa:
-        if index_cache_fp8():
-            index_k = jnp.zeros((cfg.num_stage_layers, num_pages,
-                                 page_size, cfg.index_head_dim),
-                                jnp.float8_e4m3fn)
-            index_scale = jnp.ones((cfg.num_stage_layers, num_pages,
-                                    page_size), jnp.float32)
+        shape = (n_full, num_pages, page_size, cfg.index_head_dim)
+        if jnp.dtype(dtype) == jnp.float8_e4m3fn:
+            index_k = jnp.zeros(shape, jnp.float8_e4m3fn)
+            index_scale = jnp.ones(shape[:-1], jnp.float32)
         else:
-            index_k = jnp.zeros((cfg.num_stage_layers, num_pages,
-                                 page_size, cfg.index_head_dim), dtype)
-    return LatentKVCache(latent, index_k, index_scale)
+            index_k = jnp.zeros(shape, dtype)
+    if cfg.use_swa:
+        swa = jnp.zeros((cfg.num_swa_layers, max(num_slots, 1),
+                         cfg.swa_ring_len(page_size), cfg.swa_cache_width),
+                        dtype)
+    stats = jnp.zeros((len(STATS),), jnp.int32) if has_stats(cfg) else None
+    return LatentKVCache(latent, index_k, index_scale, swa, stats)
 
 
 def make_rope_table(cfg: ModelConfig) -> jnp.ndarray:
-    return compute_rope_cos_sin(cfg.qk_rope_head_dim, cfg.max_position,
+    full = compute_rope_cos_sin(cfg.qk_rope_head_dim, cfg.max_position,
                                 cfg.rope_theta, cfg.rope_scaling)
+    if not cfg.use_swa:
+        return full
+    # [2, max_position, rope]: the full layers' table, then the windowed
+    # layers' (a rotary base of their own)
+    assert cfg.swa_qk_rope_head_dim == cfg.qk_rope_head_dim
+    return jnp.stack([full, compute_rope_cos_sin(
+        cfg.swa_qk_rope_head_dim, cfg.max_position, cfg.swa_rope_theta,
+        None)])
+
+
+class Geom(NamedTuple):
+    """One attention kind's sizes (the full layers' from the DeepSeek
+    keys, the windowed layers' from the ``swa_*`` keys)."""
+    heads: int
+    q_lora: int
+    lora: int
+    nope: int
+    rope: int
+    v: int
+    width: int          # the cache row as stored
+    gate: str
+    window: int         # 0 = the whole context
+    scale: float
+
+
+def geom(cfg: ModelConfig, kind: str = FULL) -> Geom:
+    if kind == SWA:
+        return Geom(cfg.swa_num_heads, cfg.swa_q_lora_rank,
+                    cfg.swa_kv_lora_rank, cfg.swa_qk_nope_head_dim,
+                    cfg.swa_qk_rope_head_dim, cfg.swa_v_head_dim,
+                    cfg.swa_cache_width, cfg.swa_attn_gate,
+                    cfg.sliding_window,
+                    (cfg.swa_qk_nope_head_dim
+                     + cfg.swa_qk_rope_head_dim) ** -0.5)
+    return Geom(cfg.num_heads, cfg.q_lora_rank, cfg.kv_lora_rank,
+                cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim,
+                cfg.mla_cache_width, cfg.attn_gate, 0,
+                (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+                * yarn_softmax_scale_mult(cfg.rope_scaling))
+
+
+def layer_runs(cfg: ModelConfig) -> Tuple[Tuple[str, str, int], ...]:
+    """This stage's layers as runs of one kind: ((attention kind, "dense"
+    | "moe", count), ...). Each run is one ``lax.scan`` over its stacked
+    parameters. Without ``layer_types`` that is DeepSeek's two runs: the
+    leading dense layers, then the expert layers."""
+    first, last = cfg.stage_layers
+    runs = []
+    for i in range(first, last):
+        kind = (cfg.layer_types[i] if cfg.use_swa else FULL,
+                "dense" if i < cfg.first_k_dense_replace else "moe")
+        if runs and runs[-1][:2] == list(kind):
+            runs[-1][2] += 1
+        else:
+            runs.append(list(kind) + [1])
+    return tuple(tuple(r) for r in runs)
+
+
+def run_params(params: "Params", cfg: ModelConfig):
+    """The stacked parameters of each run of ``layer_runs(cfg)``."""
+    if cfg.use_swa:
+        return [params["runs"][f"run{i}"]
+                for i in range(len(layer_runs(cfg)))]
+    return [params["dense_layers" if mlp == "dense" else "moe_layers"]
+            for _, mlp, _ in layer_runs(cfg)]
 
 
 # ---------------------------------------------------------------------------
@@ -140,13 +255,136 @@ def deepseek_route(router_logits: jnp.ndarray, e_bias: Optional[jnp.ndarray],
     return weights, ids.astype(jnp.int32)
 
 
-def _moe_block(lp: Params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
+def _shared_expert(lp: Params, x: jnp.ndarray) -> jnp.ndarray:
+    sg = qmm(x, lp["shared_gate_proj"])
+    su = qmm(x, lp["shared_up_proj"])
+    return qmm(silu_and_mul(jnp.concatenate([sg, su], axis=-1)),
+               lp["shared_down_proj"])
+
+
+_EXPERT_STACKS = ("w_gate", "w_up", "w_down")
+
+
+def _counting_order(bins, n_bins: int):
+    """A stable sort of ``bins`` [A] (integers in [0, n_bins)) by
+    counting: (order [A] with bins[order] ascending, sizes [n_bins]). The
+    rank of an entry within its bin is a running count down the A axis,
+    taken 128 entries at a time as a product with a triangular matrix and
+    carried over the blocks; XLA's sort of 17 k keys with their payload
+    took 14 s to compile in every expert layer of every mixed step
+    program, and a running sum over a long axis (a window reduction) as
+    long."""
+    A, B = bins.shape[0], 128
+    nb = -(-A // B)
+    onehot = (jnp.pad(bins, (0, nb * B - A), constant_values=n_bins)[:, None]
+              == jnp.arange(n_bins, dtype=bins.dtype)[None, :])
+    x = onehot.astype(jnp.float32).reshape(nb, B, n_bins)
+    within = jnp.einsum("ij,bjk->bik", jnp.tril(jnp.ones((B, B), x.dtype)),
+                        x, precision=jax.lax.Precision.HIGHEST)
+    totals = within[:, -1, :]                              # [nb, n_bins]
+    before = jnp.cumsum(totals, axis=0) - totals           # blocks before
+    rank = (within - x + before[:, None, :]).reshape(nb * B, n_bins)[:A]
+    sizes = jnp.sum(totals, axis=0).astype(jnp.int32)
+    starts = jnp.cumsum(sizes) - sizes
+    dest = (starts[bins] + jnp.sum(
+        jnp.where(onehot[:A], rank, 0.0), axis=-1).astype(jnp.int32))
+    order = jnp.zeros((A,), jnp.int32).at[dest].set(
+        jnp.arange(A, dtype=jnp.int32), unique_indices=True)
+    return order, sizes
+
+
+def _held_experts(lp: Params, x, weights, ids, valid, cfg: ModelConfig,
+                  stacks=None, layer=None):
+    """The part of the routed result that the experts HELD here give
+    (``cfg.experts_held`` of them from ``cfg.expert_first`` on; the router
+    chose among all ``cfg.num_experts`` and normalised over all it chose).
+    An assignment to an absent expert, or of a padding row, costs nothing:
+    the assignments are sorted with those last, and a loop takes the held
+    ones ``cap`` at a time: gathered, multiplied, scattered back. ``cap``
+    is a quarter of all assignments where the held experts are at most an
+    eighth of all (twice what even routing gives them), so the loop runs
+    once; a step that routes more here runs it again, and the result is
+    exact whatever the router does. Nothing stands in for the absent
+    experts: their part is left out, as on a chip that waits for no
+    exchange.
+
+    ``stacks``: the expert matrices of ALL the layers of a run, [n, held,
+    ., .] each, with ``layer`` this layer's index among them. Inside a
+    scan over layers XLA copies a layer's slice of a stacked operand out
+    before a grouped product can read it (1.5 GB a layer and step at
+    dots3_note's widths: 4.6 ms of a v5e's bandwidth, measured); so the
+    product takes the whole stack as n x held groups, of which only this
+    layer's have rows, and reads in place what its groups touch.
+    Returns (combined [T, H] float32, stats [4])."""
+    T, H = x.shape
+    K, held = cfg.num_experts_per_tok, cfg.experts_held
+    local = ids - cfg.expert_first
+    mine = (local >= 0) & (local < held) & valid[:, None]
+    flat = jnp.where(mine, local, held).reshape(-1)       # absent -> last
+    n_mine = jnp.sum(mine, dtype=jnp.int32)
+    order, sizes = _counting_order(flat, held + 1)
+    sizes = sizes[:held]
+    ends = jnp.cumsum(sizes)
+    cap = -(-T * K // 4) if held * 8 <= cfg.num_experts else T * K
+    order = jnp.pad(order, (0, cap))
+    flat_w = weights.reshape(-1)
+
+    def part(i, combined):
+        lo = i * cap
+        idx = jax.lax.dynamic_slice_in_dim(order, lo, cap)
+        token_of = idx // K
+        live = lo + jnp.arange(cap) < n_mine
+        # this pass's rows of each expert: its sorted range cut to the pass
+        cut = jnp.clip(ends, lo, lo + cap)
+        sizes_i = cut - jnp.concatenate([jnp.full((1,), lo, cut.dtype),
+                                         cut[:-1]])
+        eids = jnp.minimum(flat[idx], held - 1)
+        xs = x[token_of]
+        if stacks is None:
+            w_gate, w_up, w_down = (lp[k] for k in _EXPERT_STACKS)
+        else:
+            n = stacks[0].shape[0]
+            w_gate, w_up, w_down = (
+                w.reshape((n * held,) + w.shape[2:]) for w in stacks)
+            sizes_i = jax.lax.dynamic_update_slice(
+                jnp.zeros((n * held,), sizes_i.dtype), sizes_i,
+                (layer * held,))
+        gate = qragged_dot(xs, w_gate, sizes_i, eids)
+        up = qragged_dot(xs, w_up, sizes_i, eids)
+        act = silu_and_mul(jnp.concatenate([gate, up], axis=-1))
+        out = qragged_dot(act, w_down, sizes_i, eids)
+        out = jnp.where(live[:, None], out.astype(jnp.float32)
+                        * flat_w[idx][:, None], 0.0)
+        return combined.at[token_of].add(out)
+
+    combined = jax.lax.fori_loop(0, -(-n_mine // cap), part,
+                                 jnp.zeros((T, H), jnp.float32))
+    n_valid = jnp.sum(valid, dtype=jnp.int32) * K
+    stats = jnp.stack([n_mine, n_valid - n_mine,
+                       jnp.sum(sizes > 0, dtype=jnp.int32), jnp.int32(1)])
+    return combined, stats
+
+
+def _moe_block(lp: Params, x: jnp.ndarray, cfg: ModelConfig, valid=None,
+               stacks=None, layer=None):
+    """Returns (the expert layer's output [T, H], stats [4] or None).
+    ``valid`` [T] marks the rows that are tokens (the rest is padding);
+    ``stacks``, ``layer``: see ``_held_experts``."""
     T, H = x.shape
     E, K = cfg.num_experts, cfg.num_experts_per_tok
     logits = x.astype(jnp.float32) @ lp["router"].astype(jnp.float32)
     weights, ids = deepseek_route(logits, lp.get("e_bias"), cfg)
+    stats = None
 
-    if cfg.moe_force_dense:
+    if cfg.experts_held:
+        if cfg.moe_force_dense:
+            raise NotImplementedError(
+                "a share of the experts (experts_held) under dp > 1")
+        if valid is None:
+            valid = jnp.ones((T,), bool)
+        combined, stats = _held_experts(lp, x, weights, ids, valid, cfg,
+                                        stacks, layer)
+    elif cfg.moe_force_dense:
         # DP vmap path — ragged grouped GEMM has no usable batch rule
         # (see gllm_tpu/models/moe.py dense fallback).
         w_gate = deq(lp["w_gate"], x.dtype)
@@ -177,53 +415,224 @@ def _moe_block(lp: Params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
             out * w_sorted)
 
     if cfg.n_shared_experts:
-        sg = qmm(x, lp["shared_gate_proj"])
-        su = qmm(x, lp["shared_up_proj"])
-        shared = qmm(silu_and_mul(jnp.concatenate([sg, su], axis=-1)),
-                     lp["shared_down_proj"])
-        combined = combined + shared
-    return combined.astype(x.dtype)
+        combined = combined + _shared_expert(lp, x)
+    return combined.astype(x.dtype), stats
 
 
 # ---------------------------------------------------------------------------
 # MLA attention (absorbed form)
 # ---------------------------------------------------------------------------
+#
+# Two ways through a step's tokens, for the layers that cannot hand their
+# attention to the paged kernels (the indexer's selection, the window's
+# ring). Both work on the ragged batch as it is: S sequences, sequence s
+# owning the tokens cu[s] .. cu[s + 1].
+#
+# - ROWS: one query a sequence, its first token of the step. That is all of
+#   a decode-only program (``max_q_len == 1``), and the decoding rows of a
+#   mixed one. Every temporary is [S, ...].
+# - CHUNKS: the sequences with more than one token, cut into work items of
+#   ``BQ`` queries of ONE sequence, run one after the other by a loop whose
+#   trip count is the step's own (``lax.fori_loop`` over a traced count):
+#   a 2048-token chunk is 16 items, whatever else is in the step. Every
+#   temporary is [BQ, ...], so no tensor grows with tokens x context.
 
-def _indexer_topk_slots(lp, x, q_resid, batch: StepBatch, index_cache,
-                        index_scale, cfg: ModelConfig, cos_sin, *,
-                        max_q_len: int):
-    """DSA lightning indexer (reference deepseek_v32.py:86-338): score each
-    query against its sequence's cached indexer keys — ReLU(q·k)·scale
-    weighted per head and summed — causally mask, top-k, and return
-    (updated index cache, [T, k] physical KV slots with -1 padding).
+class Ragged(NamedTuple):
+    """What both ways read of the batch's layout."""
+    cu: jnp.ndarray          # [S + 1]
+    q_lens: jnp.ndarray      # [S]
+    kv_lens: jnp.ndarray     # [S] context after this step
+    first: jnp.ndarray       # [S] flat index of each sequence's first token
+    items: jnp.ndarray       # [S] work items of the chunk loop
+    item_cum: jnp.ndarray    # [S] ... summed up to and with s
+    seq_of: jnp.ndarray      # [T] the sequence of each token
+    valid: jnp.ndarray       # [T] is a token (the rest is padding)
+    chunked: jnp.ndarray     # [T] ... of a sequence with more than one
 
-    Indexer rope is NON-interleaved (neox half-split), unlike the main MLA
-    rope; same YaRN table."""
+
+def _ragged(md: AttentionMetadata, T: int, max_q_len: int) -> Ragged:
+    cu = md.cu_q_lens
+    q_lens = cu[1:] - cu[:-1]
+    items = jnp.where(q_lens > 1, -(-q_lens // BQ), 0)
+    if max_q_len == 1:
+        items = jnp.zeros_like(items)
+    tok = jnp.arange(T, dtype=jnp.int32)
+    seq_of = jnp.clip(jnp.searchsorted(cu[1:], tok, side="right"),
+                      0, q_lens.shape[0] - 1).astype(jnp.int32)
+    valid = tok < cu[-1]
+    return Ragged(cu, q_lens, md.kv_lens, jnp.clip(cu[:-1], 0, T - 1),
+                  items, jnp.cumsum(items), seq_of, valid,
+                  valid & (q_lens[seq_of] > 1))
+
+
+def _attend(q, keys, mask, *, scale, lora):
+    """softmax(q . k * scale) over the masked keys, values the keys' first
+    ``lora`` lanes. q [N, H, W]; keys [N, K, W] (each query its own) or
+    [K, W] (shared); mask [N, K]. Returns [N, H, lora] float32; a query
+    with no key gives zeros."""
+    eq = "nhw,nkw->nhk" if keys.ndim == 3 else "nhw,kw->nhk"
+    sc = jnp.einsum(eq, q, keys.astype(q.dtype),
+                    preferred_element_type=jnp.float32) * scale
+    sc = jnp.where(mask[:, None, :], sc, -jnp.inf)
+    m = jnp.max(sc, axis=-1, keepdims=True)
+    p = jnp.exp(sc - jnp.where(jnp.isfinite(m), m, 0.0))
+    denom = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+    ev = "nhk,nkl->nhl" if keys.ndim == 3 else "nhk,kl->nhl"
+    out = jnp.einsum(ev, p.astype(q.dtype),
+                     keys[..., :lora].astype(q.dtype),
+                     preferred_element_type=jnp.float32)
+    return out / denom
+
+
+def _chunk_loop(rg: Ragged, T: int, out_shape, item_fn):
+    """Run ``item_fn(s, q_start, q_pos0, n_valid) -> [BQ, ...]`` over the
+    work items and lay the valid rows into a [T, ...] float32 buffer. s:
+    the item's sequence; q_start: flat index of its first query; q_pos0:
+    that query's position; n_valid: queries it really has."""
+    def body(w, buf):
+        s = jnp.searchsorted(rg.item_cum, w, side="right").astype(jnp.int32)
+        j = w - (rg.item_cum[s] - rg.items[s])
+        q_start = rg.cu[s] + j * BQ
+        n_valid = jnp.minimum(BQ, rg.q_lens[s] - j * BQ)
+        q_pos0 = rg.kv_lens[s] - rg.q_lens[s] + j * BQ
+        res = item_fn(s, q_start, q_pos0, n_valid).astype(jnp.float32)
+        start = (q_start,) + (0,) * len(out_shape)
+        old = jax.lax.dynamic_slice(buf, start, (BQ,) + out_shape)
+        keep = (jnp.arange(BQ) < n_valid).reshape(
+            (BQ,) + (1,) * len(out_shape))
+        return jax.lax.dynamic_update_slice(
+            buf, jnp.where(keep, res, old), start)
+
+    buf = jnp.zeros((T + BQ,) + out_shape, jnp.float32)
+    return jax.lax.fori_loop(0, rg.item_cum[-1], body, buf)[:T]
+
+
+def _pad_rows(a, before=0, after=BQ):
+    return jnp.pad(a, ((before, after),) + ((0, 0),) * (a.ndim - 1))
+
+
+KV_WIDTHS = 4           # page-table widths a work item chooses among
+
+
+def _largest(x, vis, k: int):
+    """Mask [N, K] of the ``k`` largest entries of x [N, K] among the
+    visible ones (all of them where there are no more; among equal
+    entries the earlier position, as ``lax.top_k`` orders them). No sort:
+    the k-th largest value is found bit by bit on the order-preserving
+    integer image of the floats, 32 counting passes over x, and XLA's
+    sort of [128, 9472] with its payload took 14 s to compile in every
+    full layer of every step program."""
+    bits = jax.lax.bitcast_convert_type(
+        jnp.where(vis, x, -jnp.inf).astype(jnp.float32), jnp.int32)
+    # floats order as their bits do, negatives reversed; as unsigned
+    u = jax.lax.bitcast_convert_type(
+        jnp.where(bits < 0, ~bits, bits | jnp.int32(-2 ** 31)), jnp.uint32)
+
+    def bit(i, t):
+        cand = t | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(jnp.uint32)))
+        enough = jnp.sum(u >= cand[:, None], axis=-1) >= k
+        return jnp.where(enough, cand, t)
+
+    t = jax.lax.fori_loop(0, 32, bit, jnp.zeros(x.shape[:1], jnp.uint32))
+    above = u > t[:, None]
+    ties = (u == t[:, None]) & vis
+    need = k - jnp.sum(above, axis=-1)
+    # of the ties the first ``need`` by position: the position of the
+    # last one taken, found the same way (a cumulative sum over the row
+    # is a window reduction that XLA compiles for as long as the sort)
+    pos = jnp.arange(x.shape[1], dtype=jnp.int32)
+    nbits = max(1, (x.shape[1] - 1).bit_length())
+
+    def pbit(i, p):
+        cand = p | (jnp.int32(1) << (nbits - 1 - i))
+        before = jnp.sum(ties & (pos[None, :] < cand[:, None]), axis=-1)
+        return jnp.where(before < need, cand, p)
+
+    last = jax.lax.fori_loop(0, nbits, pbit,
+                             jnp.zeros(x.shape[:1], jnp.int32))
+    ties &= (pos[None, :] <= last[:, None]) & (need > 0)[:, None]
+    return (above | ties) & vis
+
+
+def _index_logits(qi, wi, kg, kscl, cfg: ModelConfig):
+    """I(t, s) = sum_j w_tj ReLU(qI_tj . kI_s) * head_dim^-0.5 for queries
+    qi [N, nh, hd], their head weights wi [N, nh] and keys kg [N, K, hd]
+    (each query its own sequence's) or [K, hd]; ``kscl`` the keys' fp8
+    scales or None. Returns [N, K] float32."""
+    hd = cfg.index_head_dim
+    own = kg.ndim == 3
+    eq = "nhd,nkd->nhk" if own else "nhd,kd->nhk"
+    if kscl is not None and cfg.index_fp8_score:
+        # fp8 x fp8 scoring (reference GLLM_DSA_FP8_SCORE): quantize q per
+        # score row too; the dot accumulates in f32 and the two scales
+        # rescale the raw scores (both positive: they commute with ReLU)
+        qf = qi.astype(jnp.float32)
+        qscl = jnp.maximum(jnp.max(jnp.abs(qf), axis=-1), 1e-6) / _FP8_MAX
+        raw = jnp.einsum(eq, (qf / qscl[..., None]).astype(kg.dtype), kg,
+                         preferred_element_type=jnp.float32)
+        ks = kscl[:, None, :] if own else kscl[None, None, :]
+        sc = raw * qscl[..., None] * ks * hd ** -0.5
+    elif kscl is not None:
+        kf = kg.astype(jnp.float32) * kscl[..., None]
+        sc = jnp.einsum(eq, qi.astype(jnp.float32), kf) * hd ** -0.5
+    else:
+        sc = jnp.einsum(eq, qi, kg.astype(qi.dtype),
+                        preferred_element_type=jnp.float32) * hd ** -0.5
+    return jnp.einsum("nhk,nh->nk", jax.nn.relu(sc), wi)
+
+
+def _index_qkw(lp, x, q_resid, batch: StepBatch, cfg: ModelConfig, cos_sin):
+    """The indexer's queries [T, nh, hd], this step's keys [T, hd] and the
+    head weights [T, nh] (reference deepseek_v32.py:86-338). Indexer rope
+    is NON-interleaved (neox half-split), unlike the main MLA rope; same
+    table."""
     from gllm_tpu.ops.rope import apply_rope
-
     T = x.shape[0]
-    nh, hd = cfg.index_n_heads, cfg.index_head_dim
-    rope = cfg.qk_rope_head_dim
-    md = batch.attn
-
+    nh, hd, rope = cfg.index_n_heads, cfg.index_head_dim, cfg.qk_rope_head_dim
     q = qmm(q_resid, lp["idx_wq_b"]).reshape(T, nh, hd)
-    k = x @ lp["idx_wk"]                                 # [T, hd]
-    # k_norm is a LayerNorm (weight + bias), unlike the RMSNorms elsewhere.
-    kf = k.astype(jnp.float32)
+    # k_norm is a LayerNorm (weight + bias), unlike the RMSNorms elsewhere
+    kf = (x @ lp["idx_wk"]).astype(jnp.float32)
     mu = jnp.mean(kf, axis=-1, keepdims=True)
     var = jnp.mean((kf - mu) ** 2, axis=-1, keepdims=True)
     k = ((kf - mu) * jax.lax.rsqrt(var + 1e-6)
          * lp["idx_k_norm_w"].astype(jnp.float32)
          + lp["idx_k_norm_b"].astype(jnp.float32)).astype(x.dtype)
-
     q_rot, k_rot = apply_rope(q[..., :rope], k[:, None, :rope],
                               batch.positions, cos_sin)
     q = jnp.concatenate([q_rot, q[..., rope:]], axis=-1)
     k = jnp.concatenate([k_rot[:, 0], k[:, rope:]], axis=-1)
     # fp32 head weighting with n_heads**-0.5 folded in (reference
     # head_weights)
-    weights = (x.astype(jnp.float32)
-               @ lp["idx_weights"].astype(jnp.float32)) * nh ** -0.5
+    w = (x.astype(jnp.float32)
+         @ lp["idx_weights"].astype(jnp.float32)) * nh ** -0.5
+    return q, k, w
+
+
+def _dsa_attention(lp, x, q_resid, q_full, batch: StepBatch, latent_cache,
+                   index_cache, index_scale, cfg: ModelConfig, cos_sin, *,
+                   max_q_len: int, g: Geom):
+    """DSA: the indexer scores every visible position of a token's
+    sequence, the ``index_topk`` largest are chosen (all of them while
+    there are no more), and the token attends the chosen latent rows only.
+    The step's own rows and index keys are in the pages already.
+
+    How the chosen rows are read follows from what a v5e does well. A
+    work item's 128 queries choose 2048 rows each, together nearly every
+    row of a context of a few times that: the item reads its sequence's
+    rows once, whole pages in position order, and attends all of them
+    under the choice's mask, a plain matrix product. XLA's gather of 128
+    x 2048 single rows into a temporary ran at a tenth of the memory
+    bandwidth and took, with the page lookup of every chosen position,
+    three quarters of a mixed step (PERF.md, PR 34). A decoding row does
+    the same (2048 rows of 16 spread over a context of a few times that
+    touch nearly every page of it). Reading only the chosen rows, which
+    contexts of many times the top-k need, is not here (ROADMAP B7).
+    Returns (out_lat [T, H, lora] float32, index_cache, index_scale,
+    stats [2])."""
+    T = x.shape[0]
+    md = batch.attn
+    hd = cfg.index_head_dim
+    qi, ki, wi = _index_qkw(lp, x, q_resid, batch, cfg, cos_sin)
 
     # store this step's keys into the parallel paged index cache
     P, page, _ = index_cache.shape
@@ -231,7 +640,7 @@ def _indexer_topk_slots(lp, x, q_resid, batch: StepBatch, index_cache,
     if index_scale is not None:
         # fp8 store (reference store_index_k_fp8): per-token amax scale,
         # quantized payload + f32 scale land in parallel paged arrays
-        kf = k.astype(jnp.float32)
+        kf = ki.astype(jnp.float32)
         scl = jnp.maximum(jnp.max(jnp.abs(kf), axis=-1), 1e-6) / _FP8_MAX
         index_cache = flat_k.at[batch.slot_mapping].set(
             (kf / scl[:, None]).astype(flat_k.dtype)
@@ -240,97 +649,165 @@ def _indexer_topk_slots(lp, x, q_resid, batch: StepBatch, index_cache,
             batch.slot_mapping].set(scl).reshape(P, page)
     else:
         index_cache = flat_k.at[batch.slot_mapping].set(
-            k.astype(flat_k.dtype)).reshape(index_cache.shape)
+            ki.astype(flat_k.dtype)).reshape(index_cache.shape)
 
-    # per-seq gather (same ragged layout as the XLA attention oracle)
     S, max_pages = md.page_table.shape
     max_kv = max_pages * page
-    q_lens = md.cu_q_lens[1:] - md.cu_q_lens[:-1]
-    local = jnp.arange(max_q_len, dtype=jnp.int32)
-    q_idx = jnp.clip(md.cu_q_lens[:-1, None] + local[None, :], 0, T - 1)
-    q_valid = local[None, :] < q_lens[:, None]           # [S, Qmax]
-
-    kg = index_cache[md.page_table].reshape(S, max_kv, hd)
-    qg = q[q_idx]                                        # [S, Q, nh, hd]
-    wg = weights[q_idx]                                  # [S, Q, nh]
-    if index_scale is not None:
-        kscl = index_scale[md.page_table].reshape(S, max_kv)
-        if fp8_score():
-            # fp8×fp8 scoring (reference GLLM_DSA_FP8_SCORE): quantize q
-            # per score row too; the dot accumulates in f32 and the two
-            # scales rescale the raw scores — scaling commutes with the
-            # ReLU because both scales are positive.
-            qf = qg.astype(jnp.float32)
-            qscl = jnp.maximum(jnp.max(jnp.abs(qf), axis=-1),
-                               1e-6) / _FP8_MAX        # [S, Q, nh]
-            qq = (qf / qscl[..., None]).astype(index_cache.dtype)
-            raw = jnp.einsum("sqhd,skd->sqhk", qq, kg,
-                             preferred_element_type=jnp.float32)
-            sc = (raw * qscl[..., None] * kscl[:, None, None, :]
-                  * hd ** -0.5)
-        else:
-            kf32 = kg.astype(jnp.float32) * kscl[..., None]
-            sc = jnp.einsum("sqhd,skd->sqhk", qg.astype(jnp.float32),
-                            kf32) * hd ** -0.5
-    else:
-        sc = jnp.einsum("sqhd,skd->sqhk", qg.astype(jnp.float32),
-                        kg.astype(jnp.float32)) * hd ** -0.5
-    logits = jnp.einsum("sqhk,sqh->sqk", jax.nn.relu(sc), wg)
-
-    kv_pos = jnp.arange(max_kv, dtype=jnp.int32)
-    q_pos = md.kv_lens[:, None] - q_lens[:, None] + local[None, :]
-    visible = (kv_pos[None, None, :] <= q_pos[:, :, None])
-    visible &= kv_pos[None, None, :] < md.kv_lens[:, None, None]
-    visible &= q_valid[:, :, None]
-    logits = jnp.where(visible, logits, -jnp.inf)
-
     kk = min(cfg.index_topk, max_kv)
-    top_logits, top_pos = jax.lax.top_k(logits, kk)      # [S, Q, kk]
-    # token position → physical slot; invalid selections → -1
-    slots_all = (md.page_table[:, kv_pos // page] * page
-                 + kv_pos % page)                        # [S, max_kv]
-    sel_slots = jnp.take_along_axis(
-        slots_all[:, None, :].repeat(max_q_len, axis=1), top_pos, axis=2)
-    sel_slots = jnp.where(jnp.isfinite(top_logits), sel_slots, -1)
+    kv_pos = jnp.arange(max_kv, dtype=jnp.int32)
+    rg = _ragged(md, T, max_q_len)
 
-    # back to the flat token layout [T, kk]
-    flat_sel = jnp.full((T, kk), -1, jnp.int32)
-    src = jnp.where(q_valid[..., None], sel_slots,
-                    -1).reshape(S * max_q_len, kk)
-    flat_sel = flat_sel.at[q_idx.reshape(-1)].max(src.astype(jnp.int32))
-    return index_cache, index_scale, flat_sel
+    def of_seq(cache, pt):
+        """A sequence's rows in position order: [.., pages x page, width]
+        (whole pages: the fast kind of gather)."""
+        return cache[pt].reshape(pt.shape[:-1] + (pt.shape[-1] * page,)
+                                 + cache.shape[2:])
+
+    def index_logits(q, w, pt):
+        ks = of_seq(index_scale, pt) if index_scale is not None else None
+        return _index_logits(q, w, of_seq(index_cache, pt), ks, cfg)
+
+    # ROWS: the first token of every sequence
+    r_pos = rg.kv_lens - rg.q_lens
+    logits = index_logits(qi[rg.first], wi[rg.first], md.page_table)
+    vis = (kv_pos[None, :] <= r_pos[:, None]) & (rg.q_lens > 0)[:, None]
+    rows = _attend(q_full[rg.first], of_seq(latent_cache, md.page_table),
+                   _largest(logits, vis, kk), scale=g.scale, lora=g.lora)
+    out = jnp.zeros((T, g.heads, g.lora), jnp.float32).at[rg.first].set(
+        jnp.where((rg.q_lens == 1)[:, None, None], rows, 0.0))
+
+    if max_q_len > 1:
+        qi_p, wi_p, qf_p = _pad_rows(qi), _pad_rows(wi), _pad_rows(q_full)
+        # The page table is as wide as the step's LONGEST context needs
+        # (a decoding row's, as a rule), and a chunk early in its prompt
+        # sees a fraction of that. So an item takes the narrowest of a few
+        # widths that holds its last query's context: the variants differ
+        # in nothing but how many pages they read (``lax.switch``; a
+        # prompt of 6 k tokens under a table of 9.4 k does half the work).
+        widths = sorted({min(max_pages, -(-max_pages * i // KV_WIDTHS))
+                         for i in range(1, KV_WIDTHS + 1)})
+
+        def item_of(n_pages):
+            def run(s, q_start, q_pos0):
+                cut = lambda a: jax.lax.dynamic_slice_in_dim(a, q_start, BQ)
+                pt = md.page_table[s, :n_pages]
+                q_pos = q_pos0 + jnp.arange(BQ, dtype=jnp.int32)
+                chosen = _largest(
+                    index_logits(cut(qi_p), cut(wi_p), pt),
+                    kv_pos[None, :n_pages * page] <= q_pos[:, None],
+                    min(cfg.index_topk, n_pages * page))
+                return _attend(cut(qf_p), of_seq(latent_cache, pt), chosen,
+                               scale=g.scale, lora=g.lora)
+            return run
+
+        variants = [item_of(n) for n in widths]
+        limits = jnp.asarray([n * page for n in widths], jnp.int32)
+
+        def item(s, q_start, q_pos0, n_valid):
+            which = jnp.sum(limits < q_pos0 + BQ)     # first that holds it
+            return jax.lax.switch(
+                jnp.minimum(which, len(widths) - 1), variants, s, q_start,
+                q_pos0)
+
+        chunks = _chunk_loop(rg, T, (g.heads, g.lora), item)
+        out = jnp.where(rg.chunked[:, None, None], chunks, out)
+
+    seen = jnp.where(rg.valid, batch.positions + 1, 0)
+    stats = jnp.stack([jnp.sum(seen, dtype=jnp.int32),
+                       jnp.sum(jnp.minimum(seen, cfg.index_topk),
+                               dtype=jnp.int32)])
+    return out, index_cache, index_scale, stats
 
 
-def _sparse_mla(q_full, latent_cache, sel_slots, *, scale, lora):
-    """Attend only the indexer-selected physical slots: gather latent rows
-    per query and run dense attention over [T, k] keys (the role of the
-    reference's sparse FlashMLA kernels; Pallas gather kernel TODO)."""
-    P, page, width = latent_cache.shape
-    flat = latent_cache.reshape(P * page, width)
-    keys = flat[jnp.maximum(sel_slots, 0)]               # [T, k, width]
-    valid = sel_slots >= 0
-    scores = jnp.einsum("thd,tkd->thk", q_full.astype(jnp.float32),
-                        keys.astype(jnp.float32)) * scale
-    scores = jnp.where(valid[:, None, :], scores, -jnp.inf)
-    m = jnp.max(scores, axis=-1, keepdims=True)
-    p = jnp.exp(scores - jnp.where(jnp.isfinite(m), m, 0.0))
-    p = jnp.where(valid[:, None, :], p, 0.0)
-    denom = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
-    return jnp.einsum("thk,tkl->thl", p / denom,
-                      keys[..., :lora].astype(jnp.float32))
+def _swa_attention(q_full, entry, batch: StepBatch, ring, slot_base, *,
+                   max_q_len: int, g: Geom):
+    """A windowed layer: token t attends the positions (t - window, t] of
+    its sequence. What lies before this step is in the sequence's ring
+    (position p in row p % R of its slot), what this step brings is in
+    ``entry`` [T, W]; the step's last R rows a sequence go into the ring
+    at the end. Returns (out_lat [T, H, lora] float32, ring)."""
+    T = q_full.shape[0]
+    md = batch.attn
+    n_slots, R, W = ring.shape
+    win = g.window
+    rg = _ragged(md, T, max_q_len)
+    slots = batch.ssm_slots + slot_base
+    cs = rg.kv_lens - rg.q_lens              # positions before this step
+    ridx = jnp.arange(R, dtype=jnp.int32)
+
+    def ring_pos(cs_):
+        """The position each ring row holds, for a sequence with ``cs_``
+        positions written (-1: none)."""
+        last = cs_[..., None] - 1
+        return jnp.where(last >= 0, last - (last - ridx) % R, -1)
+
+    # ROWS: the first token of every sequence over [ring | itself]
+    own = entry[rg.first]
+    rp = ring_pos(cs)                                        # [S, R]
+    keys = jnp.concatenate([ring[slots], own[:, None, :]], axis=1)
+    mask = jnp.concatenate(
+        [(rp >= 0) & (rp > cs[:, None] - win),
+         jnp.ones((rp.shape[0], 1), bool)], axis=1)
+    mask &= (rg.q_lens > 0)[:, None]
+    rows = _attend(q_full[rg.first], keys, mask, scale=g.scale, lora=g.lora)
+    out = jnp.zeros((T, g.heads, g.lora), jnp.float32).at[rg.first].set(
+        jnp.where((rg.q_lens == 1)[:, None, None], rows, 0.0))
+
+    if max_q_len > 1:
+        back = -(-(win - 1) // BQ) * BQ      # in-step keys before the item
+        qf_p, e_p = _pad_rows(q_full), _pad_rows(entry, before=back)
+        local = jnp.arange(BQ, dtype=jnp.int32)
+        kloc = jnp.arange(back + BQ, dtype=jnp.int32) - back
+
+        def item(s, q_start, q_pos0, n_valid):
+            q = jax.lax.dynamic_slice_in_dim(qf_p, q_start, BQ)
+            step_keys = jax.lax.dynamic_slice_in_dim(e_p, q_start,
+                                                     back + BQ)
+            # in-step key at flat q_start + kloc: same sequence iff not
+            # before the sequence's first token; position q_pos0 + kloc
+            same = q_start + kloc >= rg.cu[s]
+            dist = local[:, None] - kloc[None, :]
+            m_step = same[None, :] & (dist >= 0) & (dist < win)
+            rp_s = ring_pos(cs[s])                           # [R]
+            q_pos = q_pos0 + local
+            m_ring = (rp_s >= 0)[None, :] & (
+                rp_s[None, :] > q_pos[:, None] - win)
+            keys = jnp.concatenate([ring[slots[s]], step_keys], axis=0)
+            return _attend(q, keys, jnp.concatenate([m_ring, m_step], 1),
+                           scale=g.scale, lora=g.lora)
+
+        chunks = _chunk_loop(rg, T, (g.heads, g.lora), item)
+        out = jnp.where(rg.chunked[:, None, None], chunks, out)
+
+    # the step's rows into the ring: of each sequence the last R (an
+    # earlier one would land on a later one's row), padding rows into the
+    # dummy slot
+    pos = batch.positions
+    live = rg.valid & (pos >= rg.kv_lens[rg.seq_of] - R)
+    dst = jnp.where(live, slots[rg.seq_of] * R + pos % R,
+                    slot_base * R + jnp.arange(T, dtype=jnp.int32) % R)
+    ring = ring.reshape(n_slots * R, W).at[dst].set(
+        entry.astype(ring.dtype)).reshape(ring.shape)
+    return out, ring
 
 
 def _mla_attention(lp, x, batch: StepBatch, latent_cache, cfg: ModelConfig,
-                   cos_sin, *, max_q_len: int, scale: float,
-                   attn_impl: str = "xla", index_cache=None,
-                   index_scale=None):
+                   cos_sin, *, max_q_len: int, attn_impl: str = "xla",
+                   index_cache=None, index_scale=None, kind: str = FULL,
+                   ring=None, slot_base=0):
+    """One latent-attention layer of ``kind``. Returns (output [T, hidden],
+    latent_cache, index_cache, index_scale, ring, stats [2] or None)."""
     T = x.shape[0]
-    Hq = cfg.num_heads
-    nope, rope, lora = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
-                        cfg.kv_lora_rank)
+    g = geom(cfg, kind)
+    Hq, nope, rope, lora = g.heads, g.nope, g.rope, g.lora
+    eps, H = cfg.rms_norm_eps, cfg.hidden_size
+    s_q = (H / g.q_lora) ** 0.5 if cfg.mla_lora_rescale else 1.0
+    s_kv = (H / lora) ** 0.5 if cfg.mla_lora_rescale else 1.0
 
-    if cfg.q_lora_rank:
-        qa = rms_norm(x @ lp["q_a_proj"], lp["q_a_norm"], cfg.rms_norm_eps)
+    if g.q_lora:
+        qa = rms_norm(x @ lp["q_a_proj"], lp["q_a_norm"], eps)
+        if cfg.mla_lora_rescale:
+            qa = (qa * s_q).astype(x.dtype)
         q = qmm(qa, lp["q_b_proj"])
     else:
         qa = x
@@ -339,21 +816,24 @@ def _mla_attention(lp, x, batch: StepBatch, latent_cache, cfg: ModelConfig,
     q_nope, q_pe = q[..., :nope], q[..., nope:]
 
     kv_a = x @ lp["kv_a_proj"]                        # [T, lora + rope]
-    c_kv = rms_norm(kv_a[:, :lora], lp["kv_a_norm"], cfg.rms_norm_eps)
+    c_kv = rms_norm(kv_a[:, :lora], lp["kv_a_norm"], eps)
+    if cfg.mla_lora_rescale:
+        c_kv = (c_kv * s_kv).astype(x.dtype)
     k_pe = kv_a[:, lora:][:, None, :]                 # [T, 1, rope]
     q_pe, k_pe = apply_rope_interleaved(q_pe, k_pe, batch.positions, cos_sin)
 
     # Latent cache row = [c_kv | k_pe | 0-pad] — the row is padded to the
-    # 128-lane tile (cfg.mla_cache_width) so Pallas can DMA pages; write
-    # via flat slot scatter.
+    # 128-lane tile (cfg.mla_cache_width) so Pallas can DMA pages
     entry = jnp.concatenate([c_kv, k_pe[:, 0, :]], axis=-1)
-    L_pages, page, width = latent_cache.shape
-    pad = width - entry.shape[-1]
+    pad = g.width - entry.shape[-1]
     if pad:
         entry = jnp.pad(entry, ((0, 0), (0, pad)))
-    flat = latent_cache.reshape(L_pages * page, width)
-    latent_cache = flat.at[batch.slot_mapping].set(
-        entry.astype(flat.dtype)).reshape(latent_cache.shape)
+    if kind == FULL:
+        # write via flat slot scatter
+        L_pages, page, width = latent_cache.shape
+        flat = latent_cache.reshape(L_pages * page, width)
+        latent_cache = flat.at[batch.slot_mapping].set(
+            entry.astype(flat.dtype)).reshape(latent_cache.shape)
 
     # Absorb q_nope through W_UK → latent space; MQA over the latent cache.
     q_lat = jnp.einsum("thn,hnl->thl", q_nope.astype(jnp.float32),
@@ -363,14 +843,16 @@ def _mla_attention(lp, x, batch: StepBatch, latent_cache, cfg: ModelConfig,
         # zero q over the pad lanes — scores are unchanged
         q_full = jnp.pad(q_full, ((0, 0), (0, 0), (0, pad)))
 
-    if cfg.use_dsa:
-        # DSA: indexer top-k physical slots, then sparse attention over
-        # only the selected latent rows (reference deepseek_v32.py).
-        index_cache, index_scale, sel = _indexer_topk_slots(
-            lp, x, qa, batch, index_cache, index_scale, cfg, cos_sin,
-            max_q_len=max_q_len)
-        out_lat = _sparse_mla(q_full, latent_cache, sel, scale=scale,
-                              lora=lora).astype(x.dtype)
+    stats = None
+    if kind == SWA:
+        out_lat, ring = _swa_attention(q_full, entry, batch, ring,
+                                       slot_base, max_q_len=max_q_len, g=g)
+    elif cfg.use_dsa:
+        # DSA: the indexer's choice of positions, then attention over the
+        # chosen latent rows only (reference deepseek_v32.py).
+        out_lat, index_cache, index_scale, stats = _dsa_attention(
+            lp, x, qa, q_full, batch, latent_cache, index_cache,
+            index_scale, cfg, cos_sin, max_q_len=max_q_len, g=g)
     else:
         # MQA over the latent cache; values are the latent prefix of the
         # keys (v_cache=None → the Pallas kernels read v from the k block
@@ -378,45 +860,58 @@ def _mla_attention(lp, x, batch: StepBatch, latent_cache, cfg: ModelConfig,
         # gather).
         kc = latent_cache[:, :, None, :]              # [P, page, 1, width]
         out_lat = paged_attention(q_full, kc, None, batch.attn,
-                                  scale=scale, max_q_len=max_q_len,
+                                  scale=g.scale, max_q_len=max_q_len,
                                   impl=attn_impl,
                                   v_dim=lora)         # [T, Hq, lora]
     out = jnp.einsum("thl,hlv->thv", out_lat.astype(jnp.float32),
-                     lp["w_uv"].astype(jnp.float32)).astype(x.dtype)
-    return (qmm(out.reshape(T, Hq * cfg.v_head_dim), lp["o_proj"]),
-            latent_cache, index_cache, index_scale)
+                     lp["w_uv"].astype(jnp.float32))
+    if g.gate == "headwise":
+        gate = jax.nn.sigmoid((x @ lp["attn_gate"]).astype(jnp.float32))
+        out = out * gate[:, :, None]
+    elif g.gate:
+        raise NotImplementedError(f"attention gate {g.gate!r}")
+    out = out.astype(x.dtype)
+    return (qmm(out.reshape(T, Hq * g.v), lp["o_proj"]),
+            latent_cache, index_cache, index_scale, ring, stats)
 
 
 # ---------------------------------------------------------------------------
 # Params
 # ---------------------------------------------------------------------------
 
-def _mla_layer_init(cfg, L, dtype, w, ks):
+def _mla_layer_init(cfg, L, dtype, w, ks, kind: str = FULL):
     H = cfg.hidden_size
-    Hq, nope, rope = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-    lora, v = cfg.kv_lora_rank, cfg.v_head_dim
+    g = geom(cfg, kind)
+    Hq, nope, rope, lora, v = g.heads, g.nope, g.rope, g.lora, g.v
     scale = H ** -0.5
+    # where the latents are rescaled after their norms (mla_lora_rescale),
+    # the matrices that read them are drawn that much smaller: queries,
+    # keys and values then have unit variance, as in a trained model, and
+    # the softmax is not a hard maximum that one rounding flips
+    s_q = (H / g.q_lora) ** 0.5 if cfg.mla_lora_rescale and g.q_lora else 1.0
+    s_kv = (H / lora) ** 0.5 if cfg.mla_lora_rescale else 1.0
     lp = {
         "input_norm": jnp.ones((L, H), dtype),
         "post_attn_norm": jnp.ones((L, H), dtype),
         "kv_a_proj": w(next(ks), (L, H, lora + rope), scale),
         "kv_a_norm": jnp.ones((L, lora), dtype),
-        "w_uk": w(next(ks), (L, Hq, nope, lora), lora ** -0.5),
-        "w_uv": w(next(ks), (L, Hq, lora, v), lora ** -0.5),
+        "w_uk": w(next(ks), (L, Hq, nope, lora), lora ** -0.5 / s_kv),
+        "w_uv": w(next(ks), (L, Hq, lora, v), lora ** -0.5 / s_kv),
         "o_proj": w(next(ks), (L, Hq * v, H), (Hq * v) ** -0.5),
     }
-    if cfg.q_lora_rank:
-        lp["q_a_proj"] = w(next(ks), (L, H, cfg.q_lora_rank), scale)
-        lp["q_a_norm"] = jnp.ones((L, cfg.q_lora_rank), dtype)
-        lp["q_b_proj"] = w(next(ks), (L, cfg.q_lora_rank,
-                                      Hq * (nope + rope)),
-                           cfg.q_lora_rank ** -0.5)
+    if g.q_lora:
+        lp["q_a_proj"] = w(next(ks), (L, H, g.q_lora), scale)
+        lp["q_a_norm"] = jnp.ones((L, g.q_lora), dtype)
+        lp["q_b_proj"] = w(next(ks), (L, g.q_lora, Hq * (nope + rope)),
+                           g.q_lora ** -0.5 / s_q)
     else:
         lp["q_proj"] = w(next(ks), (L, H, Hq * (nope + rope)), scale)
-    if cfg.use_dsa:
+    if g.gate:
+        lp["attn_gate"] = w(next(ks), (L, H, Hq), scale)
+    if cfg.use_dsa and kind == FULL:
         nh, hd = cfg.index_n_heads, cfg.index_head_dim
         q_in = cfg.q_lora_rank or H
-        lp["idx_wq_b"] = w(next(ks), (L, q_in, nh * hd), q_in ** -0.5)
+        lp["idx_wq_b"] = w(next(ks), (L, q_in, nh * hd), q_in ** -0.5 / s_q)
         lp["idx_wk"] = w(next(ks), (L, H, hd), scale)
         lp["idx_k_norm_w"] = jnp.ones((L, hd), dtype)
         lp["idx_k_norm_b"] = jnp.zeros((L, hd), dtype)
@@ -424,43 +919,67 @@ def _mla_layer_init(cfg, L, dtype, w, ks):
     return lp
 
 
+def _mlp_init(cfg, lp, L, mlp: str, dtype, w, ks):
+    H = cfg.hidden_size
+    scale = H ** -0.5
+    if mlp == "dense":
+        I = cfg.intermediate_size
+        lp["gate_proj"] = w(next(ks), (L, H, I), scale)
+        lp["up_proj"] = w(next(ks), (L, H, I), scale)
+        lp["down_proj"] = w(next(ks), (L, I, H), I ** -0.5)
+        return lp
+    E, Eh = cfg.num_experts, cfg.num_local_experts
+    I = cfg.moe_intermediate_size
+    lp["router"] = w(next(ks), (L, H, E), scale)
+    if cfg.topk_method == "noaux_tc":
+        lp["e_bias"] = jnp.zeros((L, E), jnp.float32)
+    lp["w_gate"] = w(next(ks), (L, Eh, H, I), scale)
+    lp["w_up"] = w(next(ks), (L, Eh, H, I), scale)
+    lp["w_down"] = w(next(ks), (L, Eh, I, H), I ** -0.5)
+    SI = cfg.n_shared_experts * I
+    lp["shared_gate_proj"] = w(next(ks), (L, H, SI), scale)
+    lp["shared_up_proj"] = w(next(ks), (L, H, SI), scale)
+    lp["shared_down_proj"] = w(next(ks), (L, SI, H), SI ** -0.5)
+    return lp
+
+
 def init_params(cfg: ModelConfig, seed: int = 0,
                 dtype=jnp.bfloat16) -> Params:
+    """Seeded random weights (``--load-format dummy``). DeepSeek's two
+    runs draw from ``split(key, 64)`` in order, as they always have; a
+    model with ``layer_types`` has as many runs as its pattern has
+    changes, so its n-th draw takes ``fold_in(key, n)``, whatever the
+    number of draws (perfbench/reference/dots3_note.py draws the same)."""
     H = cfg.hidden_size
-    first, last = cfg.stage_layers
-    n_dense = max(0, min(cfg.first_k_dense_replace, last) - first)
-    n_moe = (last - first) - n_dense
     key = jax.random.key(seed)
-    ks = iter(jax.random.split(key, 64))
+    if cfg.use_swa:
+        import itertools
+        ks = (jax.random.fold_in(key, i) for i in itertools.count())
+    else:
+        ks = iter(jax.random.split(key, 64))
 
     def w(k, shape, scale):
-        return (jax.random.normal(k, shape, jnp.float32)
-                * scale).astype(dtype)
+        a = (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
+        if a.size >= 1 << 27:
+            # eager draws run ahead of the device: each holds its float32
+            # form until its cast has run, and a run's expert stack is 3 GB
+            # of float32. Where the step programs came from the compile
+            # cache the host ran three stacks ahead and the process peaked
+            # at 16.7 of a v5e's 16.9 GB (15.2 on a cold start; PERF.md,
+            # PR 34). One large leaf at a time (a no-op while traced).
+            jax.block_until_ready(a)
+        return a
 
     params: Params = {}
     scale = H ** -0.5
-    if n_dense:
-        ld = _mla_layer_init(cfg, n_dense, dtype, w, ks)
-        I = cfg.intermediate_size
-        ld["gate_proj"] = w(next(ks), (n_dense, H, I), scale)
-        ld["up_proj"] = w(next(ks), (n_dense, H, I), scale)
-        ld["down_proj"] = w(next(ks), (n_dense, I, H), I ** -0.5)
-        params["dense_layers"] = ld
-    if n_moe:
-        lm = _mla_layer_init(cfg, n_moe, dtype, w, ks)
-        E = cfg.num_experts
-        I = cfg.moe_intermediate_size
-        lm["router"] = w(next(ks), (n_moe, H, E), scale)
-        if cfg.topk_method == "noaux_tc":
-            lm["e_bias"] = jnp.zeros((n_moe, E), jnp.float32)
-        lm["w_gate"] = w(next(ks), (n_moe, E, H, I), scale)
-        lm["w_up"] = w(next(ks), (n_moe, E, H, I), scale)
-        lm["w_down"] = w(next(ks), (n_moe, E, I, H), I ** -0.5)
-        SI = cfg.n_shared_experts * I
-        lm["shared_gate_proj"] = w(next(ks), (n_moe, H, SI), scale)
-        lm["shared_up_proj"] = w(next(ks), (n_moe, H, SI), scale)
-        lm["shared_down_proj"] = w(next(ks), (n_moe, SI, H), SI ** -0.5)
-        params["moe_layers"] = lm
+    stacks = [_mlp_init(cfg, _mla_layer_init(cfg, n, dtype, w, ks, kind),
+                        n, mlp, dtype, w, ks)
+              for kind, mlp, n in layer_runs(cfg)]
+    if cfg.use_swa:
+        params["runs"] = {f"run{i}": lp for i, lp in enumerate(stacks)}
+    else:
+        for (_, mlp, _), lp in zip(layer_runs(cfg), stacks):
+            params["dense_layers" if mlp == "dense" else "moe_layers"] = lp
     if cfg.is_first_stage:
         params["embed"] = w(next(ks), (cfg.vocab_size, H), 1.0)
     if cfg.is_last_stage:
@@ -477,9 +996,6 @@ def init_params(cfg: ModelConfig, seed: int = 0,
 def forward(params, kv: LatentKVCache, batch: StepBatch, cfg: ModelConfig,
             *, cos_sin, attn_impl: str = "xla", max_q_len: int,
             hidden_in=None, residual_in=None):
-    head_dim = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
-    scale = head_dim ** -0.5 * yarn_softmax_scale_mult(cfg.rope_scaling)
-
     if cfg.is_first_stage:
         # Out-of-vocab placeholder ids (Kimi's media pad sits past the LM
         # vocab) clamp in the gather; those rows are fully replaced by the
@@ -493,16 +1009,22 @@ def forward(params, kv: LatentKVCache, batch: StepBatch, cfg: ModelConfig,
     else:
         hidden, residual = hidden_in, residual_in
 
+    none = jnp.zeros((), jnp.float32)       # a carry that is not there
     cache = kv.latent
-    icache = kv.index_k if cfg.use_dsa else jnp.zeros((), jnp.float32)
+    icache = kv.index_k if cfg.use_dsa else none
     has_iscale = cfg.use_dsa and kv.index_scale is not None
-    iscale = kv.index_scale if has_iscale else jnp.zeros((), jnp.float32)
-    first, last = cfg.stage_layers
-    n_dense = max(0, min(cfg.first_k_dense_replace, last) - first)
+    iscale = kv.index_scale if has_iscale else none
+    ring = kv.swa if cfg.use_swa else none
+    with_stats = kv.stats is not None
+    stats = jnp.zeros((len(STATS),), jnp.int32)
+    valid = jnp.arange(hidden.shape[0]) < batch.attn.cu_q_lens[-1]
+    tables = {FULL: cos_sin[0], SWA: cos_sin[1]} if cfg.use_swa else {
+        FULL: cos_sin}
 
-    def make_step(mlp_fn, layer_offset):
-        def layer_step(carry, lp):
-            h, res, cache, icache, iscale, li = carry
+    def make_step(kind, mlp, stacks):
+        def layer_step(carry, xs):
+            lp, ri = xs
+            h, res, cache, icache, iscale, ring, stats, li, wi = carry
             normed, res = fused_add_rms_norm(h, res, lp["input_norm"],
                                              cfg.rms_norm_eps)
             # Flat-view stacked-cache addressing (same re-design as
@@ -514,6 +1036,9 @@ def forward(params, kv: LatentKVCache, batch: StepBatch, cfg: ModelConfig,
             # step. All MLA helpers (latent scatter, paged MQA, DSA
             # indexer/sparse gather) are shape-generic over the flat
             # leading axis; every layer's page 0 is its own dummy page.
+            # ``li`` counts the layers that hold pages, ``wi`` the
+            # windowed ones, whose rings are stacked the same way (slot 0
+            # of each layer is its dummy).
             L, P, page = cache.shape[0], cache.shape[1], cache.shape[2]
             batch_l = batch._replace(
                 slot_mapping=batch.slot_mapping + li * (P * page),
@@ -524,36 +1049,56 @@ def forward(params, kv: LatentKVCache, batch: StepBatch, cfg: ModelConfig,
                   if cfg.use_dsa else None)
             isc = (iscale.reshape((L * P,) + iscale.shape[2:])
                    if has_iscale else None)
-            attn_out, lc, ic, isc = _mla_attention(
-                lp, normed, batch_l, lc, cfg, cos_sin,
-                max_q_len=max_q_len, scale=scale, attn_impl=attn_impl,
-                index_cache=ic, index_scale=isc)
-            cache = lc.reshape(cache.shape)
-            if cfg.use_dsa:
-                icache = ic.reshape(icache.shape)
-            if has_iscale:
-                iscale = isc.reshape(iscale.shape)
+            rc = (ring.reshape((-1,) + ring.shape[2:])
+                  if cfg.use_swa else None)
+            attn_out, lc, ic, isc, rc, dsa = _mla_attention(
+                lp, normed, batch_l, lc, cfg, tables[kind],
+                max_q_len=max_q_len, attn_impl=attn_impl,
+                index_cache=ic, index_scale=isc, kind=kind, ring=rc,
+                slot_base=wi * ring.shape[1] if cfg.use_swa else 0)
+            if kind == SWA:
+                ring = rc.reshape(ring.shape)
+                wi = wi + 1
+            else:
+                cache = lc.reshape(cache.shape)
+                if cfg.use_dsa:
+                    icache = ic.reshape(icache.shape)
+                if has_iscale:
+                    iscale = isc.reshape(iscale.shape)
+                li = li + 1
             normed2, res = fused_add_rms_norm(attn_out, res,
                                               lp["post_attn_norm"],
                                               cfg.rms_norm_eps)
-            return (mlp_fn(lp, normed2), res, cache, icache, iscale,
-                    li + 1), None
+            if mlp == "dense":
+                out, moe = dense._mlp(lp, normed2), None
+            else:
+                out, moe = _moe_block(lp, normed2, cfg, valid, stacks, ri)
+            if with_stats:
+                if dsa is not None:
+                    stats = stats.at[0:2].add(dsa)
+                if moe is not None:
+                    stats = stats.at[2:6].add(moe)
+            return (out, res, cache, icache, iscale, ring, stats, li,
+                    wi), None
         return layer_step
 
-    li = jnp.int32(0)
-    if "dense_layers" in params:
-        (hidden, residual, cache, icache, iscale, li), _ = jax.lax.scan(
-            make_step(dense._mlp, 0), (hidden, residual, cache, icache,
-                                       iscale, li),
-            params["dense_layers"])
-    if "moe_layers" in params:
-        (hidden, residual, cache, icache, iscale, li), _ = jax.lax.scan(
-            make_step(lambda lp, x: _moe_block(lp, x, cfg), n_dense),
-            (hidden, residual, cache, icache, iscale, li),
-            params["moe_layers"])
+    carry = (hidden, residual, cache, icache, iscale, ring, stats,
+             jnp.int32(0), jnp.int32(0))
+    for (kind, mlp, n), lp in zip(layer_runs(cfg), run_params(params, cfg)):
+        stacks = None
+        if (mlp == "moe" and cfg.experts_held and n > 1 and all(
+                isinstance(lp[k], jax.Array) for k in _EXPERT_STACKS)):
+            # the held experts' stacks stay whole (``_held_experts``)
+            stacks = tuple(lp[k] for k in _EXPERT_STACKS)
+            lp = {k: v for k, v in lp.items() if k not in _EXPERT_STACKS}
+        carry, _ = jax.lax.scan(make_step(kind, mlp, stacks), carry,
+                                (lp, jnp.arange(n, dtype=jnp.int32)))
+    hidden, residual, cache, icache, iscale, ring, stats = carry[:7]
     return hidden, residual, LatentKVCache(
         cache, icache if cfg.use_dsa else kv.index_k,
-        iscale if has_iscale else kv.index_scale)
+        iscale if has_iscale else kv.index_scale,
+        ring if cfg.use_swa else kv.swa,
+        stats if with_stats else kv.stats)
 
 
 compute_logits = dense.compute_logits

@@ -489,6 +489,10 @@ def load_deepseek_params(model_dir: str, cfg: ModelConfig,
                          dtype=jnp.bfloat16,
                          progress_cb=None) -> dict:
     from gllm_tpu.models import deepseek
+    if cfg.use_swa:
+        raise NotImplementedError(
+            "no checkpoint rules for a model with windowed latent layers "
+            f"({cfg.architecture}): it is served with --load-format dummy")
     template = jax.eval_shape(lambda: deepseek.init_params(cfg, dtype=dtype))
     return _load_params(model_dir, template, deepseek_rules(cfg),
                         progress_cb)

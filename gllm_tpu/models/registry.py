@@ -151,6 +151,10 @@ _MLA_ARCHS = (
     "DeepseekV2ForCausalLM",
     "DeepseekV3ForCausalLM",
     "DeepseekV32ForCausalLM",
+    # dots-studio/dots3-note-prev (model_type dots3_note): DSA full layers
+    # beside windowed latent layers with a geometry of their own, headwise
+    # gates; models/deepseek.py reads both geometries from the config
+    "Dots3NoteForCausalLM",
 )
 
 _VL_ARCHS = (

@@ -116,11 +116,11 @@ def kv_cache_specs(cfg: ModelConfig, tp: int):
 
 def latent_kv_specs(cfg: ModelConfig, tp: int):
     """MLA latent cache is MQA-shaped (no head axis) → replicated over tp."""
-    from gllm_tpu.models.deepseek import LatentKVCache, index_cache_fp8
+    from gllm_tpu.models.deepseek import LatentKVCache
     return LatentKVCache(
         P(None, None, None, None),
         P(None, None, None, None) if cfg.use_dsa else None,
-        P(None, None, None) if (cfg.use_dsa and index_cache_fp8())
+        P(None, None, None) if (cfg.use_dsa and cfg.kv_cache_fp8)
         else None)
 
 

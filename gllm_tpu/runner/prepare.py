@@ -57,7 +57,7 @@ class BatchBuilder:
     def __init__(self, config: EngineConfig, page_size: int,
                  vocab_size: int = 0, hidden_size: int = 0,
                  use_mm: bool = False, use_ssm: bool = False,
-                 mm_embed_dim: int = 0):
+                 mm_embed_dim: int = 0, seq_slots: bool = False):
         self.config = config
         self.page_size = page_size
         self.vocab_size = vocab_size
@@ -67,6 +67,9 @@ class BatchBuilder:
         self.mm_embed_dim = mm_embed_dim or hidden_size
         self.use_mm = use_mm
         self.use_ssm = use_ssm
+        # a per-sequence slot rides the batch (``ssm_slots``): recurrent
+        # state, or a windowed layer's ring
+        self.use_slots = use_ssm or seq_slots
         sc = config.scheduler
         # Upper bounds for the shape buckets. Speculative decoding adds up
         # to spec_k draft rows per decode seq.
@@ -76,6 +79,11 @@ class BatchBuilder:
         self.max_seqs = min(config.max_num_seqs,
                             sc.max_decode_seqs + sc.max_prefill_tokens)
         self.max_pages_per_seq = config.max_pages_per_seq
+        # the buckets' floors (--min-row-bucket, --min-token-bucket,
+        # --min-page-bucket); a step has a token for every row
+        self.min_row_bucket = min(sc.min_row_bucket, self.max_seqs)
+        self.min_token_bucket = max(sc.min_token_bucket, self.min_row_bucket)
+        self.min_page_bucket = sc.min_page_bucket
         # Unified mixed-batch step (--unified-step): ONE signature family
         # — max_q_len is pinned to the token bucket for every batch, so
         # the compile key collapses to (pow2 row bucket × pow2 token
@@ -95,7 +103,7 @@ class BatchBuilder:
         gather extent) by the *live* maximum context in this batch instead
         of max_model_len — decode cost tracks actual sequence lengths.
         """
-        s = bucket_size(batch.num_seqs, 8, self.max_seqs)
+        s = bucket_size(batch.num_seqs, self.min_row_bucket, self.max_seqs)
         rows = [it.num_new_tokens + len(it.draft_tokens)
                 for it in batch.items]
         max_q = max(rows)
@@ -118,7 +126,8 @@ class BatchBuilder:
         elif max_q == 1:
             t, q = s, 1          # pure decode: one token per seq
         else:
-            t = bucket_size(sum(rows), 16, self.max_tokens)
+            t = bucket_size(sum(rows), self.min_token_bucket,
+                            self.max_tokens)
             if self.use_ssm:
                 # the rows that prefill run the chunked rule in a packed
                 # layout sized by the token bucket (models/hybrid.py): take
@@ -148,7 +157,8 @@ class BatchBuilder:
                      + len(it.draft_tokens), self.page_size),
                 len(it.seq.page_table))
             for it in batch.items)
-        p = bucket_size(max_pages, 4, self.max_pages_per_seq)
+        p = bucket_size(max_pages, self.min_page_bucket,
+                        self.max_pages_per_seq)
         return t, s, q, p
 
     def empty(self, signature, force_extras=frozenset(),
@@ -198,7 +208,7 @@ class BatchBuilder:
                 if "spec" in force_extras else None),
             plp_targets=(np.zeros(t_pad, np.int32)
                          if "plp" in force_extras else None),
-            ssm_slots=(np.zeros(s_pad, np.int32) if self.use_ssm
+            ssm_slots=(np.zeros(s_pad, np.int32) if self.use_slots
                        else None),
             mrope_positions=(np.zeros((3, t_pad), np.int32)
                              if self.use_mm else None),
@@ -366,7 +376,7 @@ class BatchBuilder:
                 # presence even when this replica's batch has none
                 mm_embeds = np.zeros((t_pad, self.mm_embed_dim),
                                      np.float32)
-        if self.use_ssm:
+        if self.use_slots:
             ssm_slots = np.zeros(s_pad, np.int32)   # padding → dummy slot 0
 
         want_plp = force_plp or any(
@@ -458,10 +468,11 @@ class BatchBuilder:
                                 count=K)
         rep_penalty[:K] = np.fromiter((sp.repetition_penalty for sp in sps),
                                       np.float32, count=K)
-        if self.use_ssm:
+        if self.use_slots:
             ssm_slots[:K] = np.fromiter(
                 (getattr(it.seq, "ssm_slot", None) or 0 for it in items),
                 np.int32, count=K)
+        if self.use_ssm:
             n_chunk = int((ns > 1).sum())
             _M_GDN_ROWS.inc(K - n_chunk, path="recurrent")
             if n_chunk:
@@ -620,7 +631,7 @@ class BatchBuilder:
             mm_embeds=mm_embeds,
             mm_mask=(mm_mask
                      if self.use_mm and mm_embeds is not None else None),
-            ssm_slots=ssm_slots if self.use_ssm else None,
+            ssm_slots=ssm_slots if self.use_slots else None,
             plp_targets=plp_targets,
             spec_rows=spec_rows_arr,
             spec_drafts=spec_drafts_arr,

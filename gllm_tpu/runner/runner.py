@@ -483,10 +483,12 @@ def resolve_kv_quant(config: EngineConfig, model_cfg: ModelConfig):
     ``model_cfg.kv_cache_quant``; the forward detects quant structurally
     (KVCache.k_scale is not None). Shared by ModelRunner and
     PPModelRunner so the propagation can never diverge."""
+    import dataclasses as _dc
     kv_quant = config.cache.kv_cache_dtype == "int8"
     if kv_quant and not model_cfg.kv_cache_quant:
-        import dataclasses as _dc
         model_cfg = _dc.replace(model_cfg, kv_cache_quant=True)
+    if config.cache.kv_cache_dtype == "fp8" and not model_cfg.kv_cache_fp8:
+        model_cfg = _dc.replace(model_cfg, kv_cache_fp8=True)
     return kv_quant, model_cfg
 
 
@@ -557,6 +559,7 @@ class ModelRunner:
                                     hidden_size=model_cfg.hidden_size,
                                     use_mm=model_cfg.use_mm,
                                     use_ssm=model_cfg.use_hybrid,
+                                    seq_slots=model_cfg.use_swa,
                                     mm_embed_dim=model_cfg.mm_embed_dim)
         if model_cfg.use_mm:
             from gllm_tpu.utils import LRUBytesCache
@@ -642,11 +645,15 @@ class ModelRunner:
                 if (config.cache.enable_prefix_caching
                     or (config.spec_decode
                         and not config.overlap_scheduling)) else 0)
+        elif model_cfg.use_swa:
+            # slot 0 dummy + one ring per live seq in every windowed layer
+            self.ssm_working_slots = config.max_num_seqs
+            self.ssm_snapshot_slots = 0
         else:
             self.ssm_working_slots = self.ssm_snapshot_slots = 0
         self.num_pages = (config.cache.num_pages
                           or self.determine_num_pages())
-        if model_cfg.use_hybrid:
+        if model_cfg.use_seq_slots:
             kw = {"num_slots": (1 + self.ssm_working_slots
                                 + self.ssm_snapshot_slots)}
         else:
@@ -688,6 +695,17 @@ class ModelRunner:
             1 + self.ssm_working_slots + self.ssm_snapshot_slots
             if model_cfg.use_hybrid else 0, self._ssm_pool_bytes(),
             self._gdn_chunk_temp_bytes())
+        if model_cfg.use_swa:
+            latent, index, rings = self.latent_pool_bytes()
+            logger.info(
+                "[startup] latent pools: %d pages of %d tokens in %d full "
+                "layers: latent rows %d bytes, index keys %d bytes; "
+                "window rings %d slots of %d rows in %d windowed layers "
+                "= %d bytes", self.num_pages, config.cache.page_size,
+                model_cfg.num_attn_layers, latent, index,
+                1 + self.ssm_working_slots,
+                model_cfg.swa_ring_len(config.cache.page_size),
+                model_cfg.num_swa_layers, rings)
         _M_KV_DTYPE.set(1, dtype=jnp.dtype(self._kv_dtype()).name)
         # Fused on-device speculation (config.spec_fused,
         # docs/speculative_decoding.md#fused): draft+verify inside the
@@ -779,12 +797,13 @@ class ModelRunner:
             # reference's 132-byte store_index_k_fp8 layout).
             per_tok = cfg.mla_cache_width * itemsize
             if cfg.use_dsa:
-                from gllm_tpu.models.deepseek import index_cache_fp8
-                if index_cache_fp8():
-                    per_tok += cfg.index_head_dim + 4
-                else:
-                    per_tok += cfg.index_head_dim * itemsize
-            return (n_layers or cfg.num_stage_layers) * page * per_tok
+                # the index keys beside the rows, in the cache's dtype;
+                # an fp8 cache adds their f32 per-token scale
+                per_tok += cfg.index_head_dim * itemsize + (
+                    4 if itemsize == 1 else 0)
+            # windowed layers hold rings, not pages
+            return (n_layers or (cfg.num_attn_layers if cfg.use_swa
+                                 else cfg.num_stage_layers)) * page * per_tok
         tp = self.config.parallel.tp
         shards = tp if (self.mesh is not None
                         and cfg.num_kv_heads % tp == 0) else 1
@@ -800,6 +819,52 @@ class ModelRunner:
             # ~0.2% of the page, but sizing must not over-promise
             per_page += (2 * n_kv_layers * cfg.num_kv_heads * 4) // shards
         return per_page
+
+    def latent_pool_bytes(self) -> Tuple[int, int, int]:
+        """Device bytes of a windowed-latent model's three pools: the full
+        layers' latent rows, their index keys, the windowed layers' rings
+        (what the start-up line says; tests/test_tpu_compile.py holds it
+        to the TPU compiler's count)."""
+        cfg, page = self.model_cfg, self.config.cache.page_size
+        itemsize = jnp.dtype(self._kv_dtype()).itemsize
+        tokens = cfg.num_attn_layers * self.num_pages * page
+        return (tokens * cfg.mla_cache_width * itemsize,
+                tokens * (cfg.index_head_dim * itemsize
+                          + (4 if itemsize == 1 else 0))
+                if cfg.use_dsa else 0,
+                self._ring_pool_bytes())
+
+    def _ring_pool_bytes(self) -> int:
+        cfg = self.model_cfg
+        if not cfg.use_swa:
+            return 0
+        rows = (1 + self.ssm_working_slots) * cfg.swa_ring_len(
+            self.config.cache.page_size)
+        return (cfg.num_swa_layers * rows * cfg.swa_cache_width
+                * jnp.dtype(self._kv_dtype()).itemsize)
+
+    def _swa_temp_bytes(self) -> int:
+        """What the largest step of a windowed-latent model needs beside
+        the 512 MB of other step buffers (models/deepseek.py): the rows of
+        every sequence's context read as pages with their float32 scores
+        (the decoding rows' way through a full layer), one work item's
+        scores over the longest context, and the experts' gathered rows
+        and products at a quarter of the step's assignments. 2.8 GB at
+        dots3_note's widths, 64 rows and 9472 tokens; the TPU compiler
+        counts 2.43 GiB of temporaries for that step
+        (tests/test_tpu_compile.py)."""
+        cfg = self.model_cfg
+        if not cfg.use_swa:
+            return 0
+        from gllm_tpu.models.deepseek import BQ
+        ctx = self.config.max_model_len
+        rows = self.builder.max_seqs * ctx * (
+            cfg.mla_cache_width * 2 + cfg.num_heads * 4)
+        item = BQ * ctx * 4 * (cfg.index_n_heads + 2 * cfg.num_heads)
+        assigned = self.builder.max_tokens * cfg.num_experts_per_tok // 4
+        moe = assigned * (cfg.hidden_size * 6
+                          + cfg.moe_intermediate_size * 8)
+        return rows + item + moe
 
     def _ssm_pool_bytes(self, cfg: Optional[ModelConfig] = None) -> int:
         """Device bytes of the GDN slot pools of ``cfg``'s stage (this
@@ -861,6 +926,8 @@ class ModelRunner:
         # pass would refine this; 512 MB covers the bucketed step buffers).
         free -= 512 * 1024 * 1024
         free -= self._ssm_pool_bytes() + self._gdn_chunk_temp_bytes()
+        if self.model_cfg.use_swa:
+            free -= self._ring_pool_bytes() + self._swa_temp_bytes()
         num = stable_page_count(int(free // self._kv_bytes_per_page()))
         min_pages = cdiv(self.config.max_model_len,
                          self.config.cache.page_size) + 2
@@ -926,6 +993,10 @@ class ModelRunner:
                 aux.update(spec_aux(params, hidden, residual, batch, cfg,
                                     token_counts, logprobs_k,
                                     spec_sampled))
+            if getattr(kv, "stats", None) is not None:
+                # what the step's indexer and expert layers counted
+                # (models/deepseek.py STATS): to the host with the tokens
+                aux["stats"] = (kv.stats,)
             return tokens, kv, aux
 
         if self.dp > 1:
@@ -1110,6 +1181,10 @@ class ModelRunner:
         slots from snapshots — all before the next step reads them
         (reference SSMSegment.copy_state / free_working zeroing)."""
         for r, (s_src, s_dst, z, r_src, r_dst) in self._drained_ssm_ops():
+            if not self.model_cfg.use_hybrid:
+                # a windowed layer's ring needs no zeroing: what a row
+                # holds follows from the positions its tenant has written
+                continue
             if self.dp > 1:
                 conv, rec = _ssm_apply_replica(
                     self.kv.conv, self.kv.rec, jnp.int32(r), s_src, s_dst,
@@ -1392,6 +1467,9 @@ class ModelRunner:
                     prompt_lp=want_plp, ring=ring,
                     spec_sampled=spec_sampled, all_greedy=all_greedy)
             _start_host_copy((tokens, aux))
+        if "stats" in aux:
+            # beside the step's counts, whether it was a decode-only step
+            aux = dict(aux, stats=aux["stats"] + (np.asarray(max_q == 1),))
         return tokens, aux, sched_batch.num_seqs
 
     def _use_ring(self, sched_batch: ScheduledBatch, t_pad: int) -> bool:
@@ -2083,6 +2161,9 @@ class ModelRunner:
                 out_aux = {k: tuple(_to_host(a) for a in v)
                            for k, v in aux.items()
                            if not k.startswith("_")}
+        if "stats" in out_aux:
+            from gllm_tpu.models.deepseek import count_stats
+            count_stats(*out_aux.pop("stats"))
         if host.ndim == 3:              # spec block: [K, S, k+1]
             return host[:, :n, :], out_aux
         return (host[..., :n] if host.ndim == 2 else host[:n]), out_aux

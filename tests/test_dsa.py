@@ -110,50 +110,88 @@ def _greedy(llm, prompts, n=8):
                                        ignore_eos=True))]
 
 
-def test_fp8_index_cache_is_default_and_sized():
-    """The index-K cache stores fp8 payloads + f32 per-token scales
-    (reference store_index_k_fp8 132-byte layout) and the page-budget
-    accounting reflects it."""
-    from gllm_tpu.models import deepseek
+@pytest.mark.parametrize("kv_dtype", ["auto", "fp8"])
+def test_index_cache_follows_the_cache_dtype_and_is_sized(kv_dtype):
+    """The index-K cache is stored in the served cache's dtype; under an
+    fp8 cache (the server's own --kv-cache-dtype fp8, no environment
+    variable) as fp8 payloads + f32 per-token scales (reference
+    store_index_k_fp8 132-byte layout). The page-budget accounting
+    reflects either."""
     mcfg = ModelConfig(**V32)
-    llm = build_llm(mcfg)
+    llm = build_llm(mcfg, kv_cache_dtype=kv_dtype)
     kv = llm.runner.kv
-    assert kv.index_k.dtype == jnp.float8_e4m3fn
-    assert kv.index_scale is not None
-    assert kv.index_scale.shape == kv.index_k.shape[:-1]
-    # bytes/page: latent*itemsize + index_head_dim*1 + 4 (scale)
-    per_tok = (mcfg.mla_cache_width * 4
-               + mcfg.index_head_dim + 4)
+    if kv_dtype == "fp8":
+        assert kv.index_k.dtype == jnp.float8_e4m3fn
+        assert kv.index_scale is not None
+        assert kv.index_scale.shape == kv.index_k.shape[:-1]
+        # bytes/page: (latent + index_head_dim) * 1 + 4 (scale)
+        per_tok = mcfg.mla_cache_width + mcfg.index_head_dim + 4
+    else:
+        assert kv.index_k.dtype == kv.latent.dtype == jnp.float32
+        assert kv.index_scale is None
+        per_tok = (mcfg.mla_cache_width + mcfg.index_head_dim) * 4
     assert llm.runner._kv_bytes_per_page() == \
         mcfg.num_layers * 4 * per_tok
 
 
-def test_fp8_index_cache_matches_native(monkeypatch):
-    """Greedy outputs with the fp8 index cache equal the native-dtype
-    cache: on these float32 tiny models the quantization error is far
-    below the argmax decision margins, and the sparse==dense oracle
-    (above) already ran with fp8 on."""
+def test_no_environment_variable_decides_the_index_cache(monkeypatch):
+    """The two environment reads are gone: neither variable changes the
+    cache or the scores."""
     from gllm_tpu.models import deepseek
     mcfg = ModelConfig(**V32)
     params = deepseek.init_params(mcfg, seed=3, dtype=jnp.float32)
     prompts = [[7, 3, 11, 23, 9, 2], [5, 5, 19]]
-    fp8 = _greedy(build_llm(mcfg, params=params), prompts)
-    monkeypatch.setenv("GLLM_TPU_DSA_INDEX_DTYPE", "native")
+    base = _greedy(build_llm(mcfg, params=params), prompts)
+    monkeypatch.setenv("GLLM_TPU_DSA_INDEX_DTYPE", "fp8")
+    monkeypatch.setenv("GLLM_DSA_FP8_SCORE", "1")
+    llm = build_llm(mcfg, params=params)
+    assert llm.runner.kv.index_k.dtype == jnp.float32
+    assert _greedy(llm, prompts) == base
+    assert not hasattr(deepseek, "index_cache_fp8")
+    assert not hasattr(deepseek, "fp8_score")
+
+
+@pytest.mark.parametrize("what", ["index_keys", "whole_cache"])
+def test_fp8_index_cache_matches_native(what):
+    """Greedy outputs with the index keys cached in fp8 equal the
+    native-dtype cache's, all eight tokens: on these float32 tiny models
+    the keys' quantization error is far below the selection's margins
+    (``index_keys``: fp8 payloads + scales beside NATIVE latent rows, so
+    the selection alone is held). Under ``--kv-cache-dtype fp8`` the
+    latent rows are rounded too (``whole_cache``) and their own error may
+    move a late argmax: there the first tokens are what is held equal."""
+    from gllm_tpu.models import deepseek
+    mcfg = ModelConfig(**V32)
+    params = deepseek.init_params(mcfg, seed=3, dtype=jnp.float32)
+    prompts = [[7, 3, 11, 23, 9, 2], [5, 5, 19]]
     native = _greedy(build_llm(mcfg, params=params), prompts)
-    monkeypatch.delenv("GLLM_TPU_DSA_INDEX_DTYPE")
-    assert fp8 == native
+    if what == "index_keys":
+        llm = build_llm(mcfg, params=params)
+        kv = llm.runner.kv
+        llm.runner.kv = kv._replace(
+            index_k=jnp.zeros(kv.index_k.shape, jnp.float8_e4m3fn),
+            index_scale=jnp.ones(kv.index_k.shape[:-1], jnp.float32))
+        assert llm.runner.kv.latent.dtype == jnp.float32
+        assert _greedy(llm, prompts) == native
+    else:
+        fp8 = _greedy(build_llm(mcfg, params=params, kv_cache_dtype="fp8"),
+                      prompts)
+        assert [o[:2] for o in fp8] == [o[:2] for o in native]
 
 
-def test_fp8_scoring_flag(monkeypatch):
-    """GLLM_DSA_FP8_SCORE=1 (reference flag name) scores the indexer with
-    fp8 operands; the tiny-model greedy outputs still match the f32
-    scoring path (selection indices survive the quantization)."""
+def test_fp8_scoring_flag():
+    """``index_fp8_score`` (a key of the model's config.json; the
+    reference's GLLM_DSA_FP8_SCORE) scores the indexer with fp8 operands
+    where the keys are cached in fp8; the tiny-model greedy outputs still
+    match the f32 scoring of the same cache (selection indices survive
+    the quantization)."""
     from gllm_tpu.models import deepseek
     mcfg = ModelConfig(**V32)
     params = deepseek.init_params(mcfg, seed=3, dtype=jnp.float32)
     prompts = [[7, 3, 11, 23, 9, 2, 31, 8]]
-    base = _greedy(build_llm(mcfg, params=params), prompts)
-    monkeypatch.setenv("GLLM_DSA_FP8_SCORE", "1")
-    fp8s = _greedy(build_llm(mcfg, params=params), prompts)
-    monkeypatch.delenv("GLLM_DSA_FP8_SCORE")
+    base = _greedy(build_llm(mcfg, params=params, kv_cache_dtype="fp8"),
+                   prompts)
+    fp8s = _greedy(build_llm(
+        dataclasses.replace(mcfg, index_fp8_score=True), params=params,
+        kv_cache_dtype="fp8"), prompts)
     assert base == fp8s

@@ -562,3 +562,138 @@ def test_gdn_chunk_scan_kernel_compiles_for_v5e_at_96_by_192(topo, on_tpu):
         sds((N,), jnp.int32)).compile()
     assert has_kernel(compiled)
     assert "gdn_chunk_scan" in compiled.as_text()
+
+
+# ---- the latent-attention step at dots3-note-prev's widths ------------------
+
+def dots3_cfg() -> ModelConfig:
+    """perfbench/configs/dots3-note-prev.json: published widths (full
+    layers of 128 heads over rows of 640 as stored, windowed layers of 64
+    heads over rows of 1152, 32 held experts of 5120 x 1536), 5 layers."""
+    import json
+    from gllm_tpu.models.config import from_hf_config
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "perfbench", "configs",
+        "dots3-note-prev.json")
+    with open(path) as f:
+        return from_hf_config(json.load(f))
+
+
+def _dots3_runner(topo, monkeypatch):
+    return make_runner(dots3_cfg(), topo, num_pages=37120,
+                       monkeypatch=monkeypatch, max_num_seqs=64,
+                       max_model_len=9472, attention_impl="auto")
+
+
+def _dots3_pools_are_what_the_startup_line_says(runner, pools: int):
+    """The three pools as the TPU stores them within 1 % of
+    ModelRunner.latent_pool_bytes."""
+    said = sum(runner.latent_pool_bytes())
+    assert abs(pools / said - 1) < 0.01, (pools, said)
+    assert runner.latent_pool_bytes() == (
+        2 * 37120 * 16 * 640 * 2, 2 * 37120 * 16 * 128 * 2,
+        3 * 65 * 544 * 1152 * 2)
+
+
+def _weight_bytes(runner) -> int:
+    return sum(int(np.prod(x.shape)) * x.dtype.itemsize
+               for x in jax.tree.leaves(runner.params))
+
+
+def test_dots3_pools_as_the_tpu_stores_them(topo, on_tpu, monkeypatch):
+    """The guard on the start-up line's three pools, from a program that
+    takes the caches and does nothing with them (a second of compiling:
+    its arguments are the pools in the TPU's own layouts)."""
+    runner = _dots3_runner(topo, monkeypatch)
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    touch = jax.jit(lambda kv: jax.tree.map(lambda a: a.ravel()[0], kv))
+    mem = touch.lower(_structs(runner.kv, one)).compile().memory_analysis()
+    _dots3_pools_are_what_the_startup_line_says(
+        runner, mem.argument_size_in_bytes)
+
+
+@pytest.mark.slow
+def test_dots3_decode_step_compiles_for_v5e(topo, on_tpu, monkeypatch):
+    """The configuration's decode step (the cell holds rows and
+    page-table width at their largest, --min-row-bucket 64 and
+    --min-page-bucket 592, so this is its one decode program): 64 rows at
+    contexts of 9216 tokens (576 pages: the 592-page bucket) through two
+    DSA layers (index scores over
+    [64, 64, 9472], top 2048, rows of 640 gathered) and three windowed
+    ones (rings of 544 rows of 1152), 32 held experts a layer whose stacks
+    are read in place. Counted from shapes by the compiler; nothing
+    runs."""
+    runner = _dots3_runner(topo, monkeypatch)
+    c = compile_of(runner.step_async,
+                   _with_slots(decode_batch(runner, 64, 576)))
+    mem = c.compiled.memory_analysis()
+    print(f"\n[compile] dots3 decode: {c.seconds:.1f}s, "
+          f"{mem.argument_size_in_bytes / GiB:.2f} GiB of arguments, "
+          f"{mem.temp_size_in_bytes / GiB:.3f} GiB temp")
+    _dots3_pools_are_what_the_startup_line_says(
+        runner, mem.argument_size_in_bytes - _weight_bytes(runner))
+    # a full layer reads its 64 sequences' rows as whole pages (0.72 GiB
+    # at 592 pages of 16 rows of 640) and scores them; nothing else is
+    # of that size
+    assert mem.temp_size_in_bytes < 1.5 * GiB
+    text = c.compiled.as_text()
+    # the grouped product is XLA's own kernel, and no layer's expert stack
+    # is copied out of the run's (1.5 GB a layer and step when it was)
+    assert "ragged-dot" in text and has_kernel(c.compiled)
+    assert "bf16[32,5120,1536]{2,1,0:T(8,128)(2,1)} fusion(" not in text
+
+
+@pytest.mark.slow
+def test_dots3_mixed_step_compiles_for_v5e(topo, on_tpu, monkeypatch):
+    """The configuration's largest mixed step: one 2048-token chunk at the
+    end of a 9216-token context beside 63 decoding rows (2112 tokens). The chunk's
+    selection and attention run 128 queries at a time, so no temporary
+    grows with tokens x context: all of them fit beside 10 GB of weights
+    and pools."""
+    runner = _dots3_runner(topo, monkeypatch)
+    batch = prefill_batch(runner, 2048, ndecode=63, npages=576,
+                          table_pages=576)
+    c = compile_of(runner.step_async, _with_slots(batch))
+    mem = c.compiled.memory_analysis()
+    print(f"\n[compile] dots3 mixed: {c.seconds:.1f}s, "
+          f"{mem.argument_size_in_bytes / GiB:.2f} GiB of arguments, "
+          f"{mem.temp_size_in_bytes / GiB:.3f} GiB temp")
+    _dots3_pools_are_what_the_startup_line_says(
+        runner, mem.argument_size_in_bytes - _weight_bytes(runner))
+    assert mem.temp_size_in_bytes < 3.5 * GiB
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14 * GiB
+
+
+def test_dots3_windowed_attention_compiles_for_v5e_at_the_chunk(topo,
+                                                                on_tpu):
+    """The windowed layers' attention alone at the cell's geometry: 2112
+    tokens of 64 heads over rows of 1152 as stored, 64 sequences, rings of
+    544 rows; a work item attends [ring | 640 rows of the step]."""
+    from gllm_tpu.batching import StepBatch
+    from gllm_tpu.models import deepseek as ds
+    from gllm_tpu.ops.attention import AttentionMetadata
+    cfg = dots3_cfg()
+    g = ds.geom(cfg, ds.SWA)
+    assert (g.heads, g.width, g.lora, g.window) == (64, 1152, 1024, 513)
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    T, S = 2112, 64
+
+    def attend(q, entry, pos, cu, kv_lens, slots, ring):
+        batch = StepBatch(
+            token_ids=None, positions=pos, slot_mapping=None,
+            logits_indices=None, sampling=None, ssm_slots=slots,
+            attn=AttentionMetadata(cu, kv_lens, None, jnp.int32(S)))
+        return ds._swa_attention(q, entry, batch, ring, 0, max_q_len=T, g=g)
+
+    t0 = time.monotonic()
+    compiled = jax.jit(attend).lower(
+        sds((T, 64, 1152), jnp.bfloat16), sds((T, 1152), jnp.bfloat16),
+        sds((T,), jnp.int32), sds((S + 1,), jnp.int32), sds((S,), jnp.int32),
+        sds((S,), jnp.int32),
+        sds((3 * 65, 544, 1152), jnp.bfloat16)).compile()
+    mem = compiled.memory_analysis()
+    print(f"\n[compile] dots3 windowed attention: "
+          f"{time.monotonic() - t0:.1f}s, "
+          f"{mem.temp_size_in_bytes / GiB:.3f} GiB temp")
+    assert mem.temp_size_in_bytes < 1.5 * GiB
