@@ -163,6 +163,15 @@ def _attention(lp, x, batch: StepBatch, k_all, v_all, cfg: ModelConfig,
         q = q + lp["q_bias"]
         k = k + lp["k_bias"]
         v = v + lp["v_bias"]
+    # The reshapes below must not be folded into the three dots: a folded
+    # dot wants its weight as [heads, D, hidden], which in the TPU's tiled
+    # layout is no view of the stored [hidden, heads*D], so the compiler
+    # cuts the layer's slice out of the [L, ...] stack and transposes it,
+    # every layer of every step (31.5 MB a layer at Qwen3-4B's widths).
+    # Behind the barrier the dots stay 2-D and read the stack in place, as
+    # the MLP's and o_proj's do. Held by tests/test_tpu_compile.py
+    # (test_dense_cell_projections_read_the_stack_in_place).
+    q, k, v = jax.lax.optimization_barrier((q, k, v))
     q = shard_hint(q.reshape(T, Hq, D), None, "tp", None)
     k = shard_hint(k.reshape(T, Hkv, D), None, "tp", None)
     v = shard_hint(v.reshape(T, Hkv, D), None, "tp", None)

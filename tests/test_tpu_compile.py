@@ -8,14 +8,16 @@ compile that passes is not a chip run — nothing executes here.
 Code that asks ``jax.default_backend()`` would take its CPU branch during
 such a compile, so the tests steer it (``on_tpu`` fixture) instead of the
 program growing an option. Tier-1 keeps the cheap cases (decode kernel,
-one decode step, one prefill step); the long compiles are ``slow`` and
-run as the step before any chip call:
+one decode step, one prefill step, the dense cell's decode and prefill
+step read for what they do to the stacked weights); the long compiles
+are ``slow`` and run as the step before any chip call:
 
     JAX_PLATFORMS=cpu python -m pytest tests/test_tpu_compile.py -m slow -q
 """
 
 import dataclasses
 import os
+import re
 import time
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -78,6 +80,22 @@ def qwen3_8b_cfg() -> ModelConfig:
         hidden_size=4096, num_layers=36, num_heads=32, num_kv_heads=8,
         head_dim=128, intermediate_size=12288, max_position=4096,
         rope_theta=1000000.0, qk_norm=True, tie_word_embeddings=False)
+
+
+def _perfbench_hf(name: str) -> dict:
+    """perfbench/configs/<name>.json: the config.json the cell serves."""
+    import json
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "perfbench", "configs", name + ".json")
+    with open(path) as f:
+        return json.load(f)
+
+
+# The benchmark's dense configuration: Qwen3-4B, every width and all 36
+# layers as published.
+def qwen3_4b_cfg() -> ModelConfig:
+    from gllm_tpu.models.config import from_hf_config
+    return from_hf_config(_perfbench_hf("qwen3-4b"))
 
 
 def _structs(tree, sharding_of):
@@ -314,6 +332,62 @@ def test_prefill_step_compiles_for_v5e(topo, on_tpu, monkeypatch):
     assert has_kernel(c.compiled)
 
 
+# the kinds of step `qwen3-4b.reason` runs, at its pool of 2800 pages
+DENSE_CELL_STEPS = {
+    "decode": lambda r: decode_batch(r, 32, 64),
+    "prefill": lambda r: prefill_batch(r, 16),
+    "mixed": lambda r: prefill_batch(r, 512, ndecode=31, npages=64),
+}
+
+
+def _computations(text: str):
+    """(header, instruction lines, fused?) of each computation of an
+    optimized HLO module. A fused computation is one some ``fusion``
+    calls: its lines are steps inside one device operation. The lines of
+    the others (entry, loop bodies) are the operations the device runs."""
+    fused = set(re.findall(r"\bcalls=(%[\w.\-]+)", text))
+    header, lines = None, []
+    for ln in text.splitlines():
+        if header is None:
+            if ln.endswith("{") and ln.startswith(("%", "ENTRY ")):
+                header, lines = ln, []
+        elif ln == "}":
+            name = header.removeprefix("ENTRY ").split(" ", 1)[0]
+            yield header, lines, name in fused
+            header = None
+        else:
+            lines.append(ln)
+
+
+@pytest.mark.parametrize("kind", [
+    "decode", "prefill", pytest.param("mixed", marks=pytest.mark.slow)])
+def test_dense_cell_projections_read_the_stack_in_place(topo, on_tpu,
+                                                        monkeypatch, kind):
+    """The q, k and v dots of the dense decoder read the stacked
+    [36, 2560, out] weights where they lie, as the MLP's do: the stack is
+    an operand of the fusion that holds the dot, and no operation of its
+    own, fusion or copy, has one layer of a stack as its result. Without
+    dense._attention's barrier there are three such pairs, 31.5 MB a
+    layer, in every kind of step (docs/stacked_layers.md, which also says
+    how a new projection is checked here)."""
+    runner = make_runner(qwen3_4b_cfg(), topo, num_pages=2800,
+                         monkeypatch=monkeypatch, max_num_seqs=64)
+    c = compile_of(runner.step_async, DENSE_CELL_STEPS[kind](runner))
+    print(f"\n[compile] qwen3-4b {kind}: {c.seconds:.1f}s")
+    assert has_kernel(c.compiled)
+    comps = list(_computations(c.compiled.as_text()))
+    for out, ndots in ((4096, 1), (1024, 2)):      # q; k and v
+        one_layer = re.compile(
+            rf"= bf16\[(1,)?2560,{out}\]\S* (fusion|copy)\(")
+        moved = [ln.strip()[:140] for _, lines, fused in comps if not fused
+                 for ln in lines if one_layer.search(ln)]
+        assert not moved, moved
+        dots = [h for h, lines, fused in comps
+                if fused and f": bf16[36,2560,{out}]" in h
+                and any(" convolution(" in ln for ln in lines)]
+        assert len(dots) == ndots, dots
+
+
 FAST = dict(overlap_scheduling=True, pipelined_loop=True, unified_step=True,
             decode_slot_batching=True, ondevice_finish=True,
             decode_chain_len=16)
@@ -471,13 +545,8 @@ def test_pp4_stage_sized_step_compiles_for_v5e(topo, on_tpu, monkeypatch):
 def olmo_hybrid_cfg(layers: int = 16) -> ModelConfig:
     """perfbench/configs/olmo-hybrid-7b.json: published widths (GDN heads
     of 96 / 192, attention heads of 128), ``layers`` of the 32 layers."""
-    import json
     from gllm_tpu.models.config import from_hf_config
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "perfbench", "configs",
-        "olmo-hybrid-7b.json")
-    with open(path) as f:
-        hf = json.load(f)
+    hf = _perfbench_hf("olmo-hybrid-7b")
     return from_hf_config(dict(hf, num_hidden_layers=layers,
                                layer_types=hf["layer_types"][:layers]))
 
@@ -570,13 +639,8 @@ def dots3_cfg() -> ModelConfig:
     """perfbench/configs/dots3-note-prev.json: published widths (full
     layers of 128 heads over rows of 640 as stored, windowed layers of 64
     heads over rows of 1152, 32 held experts of 5120 x 1536), 5 layers."""
-    import json
     from gllm_tpu.models.config import from_hf_config
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "perfbench", "configs",
-        "dots3-note-prev.json")
-    with open(path) as f:
-        return from_hf_config(json.load(f))
+    return from_hf_config(_perfbench_hf("dots3-note-prev"))
 
 
 def _dots3_runner(topo, monkeypatch):
