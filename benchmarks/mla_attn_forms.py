@@ -1,0 +1,231 @@
+"""Dense latent attention on the chip, kernel by kernel: which blocks, and
+which form for a step with many query tokens.
+
+    python benchmarks/mla_attn_forms.py [--cpu-rehearsal] [--heads 64]
+
+through the chip tool (one chip, ~6 min). At A.X-K1's geometry (64 query
+heads over one latent row of 512 + 64 lanes stored as 640, values the first
+512) it times, one layer's attention call each, by the host's clock around
+``iters`` back-to-back calls that end in ``block_until_ready``:
+
+- ``ragged``: ``ragged_paged_attention`` in the ABSORBED form (queries
+  folded through W_uk into latent space, 2 x 640 + 2 x 512 FLOP a head and
+  query-key pair) for a 2048-token chunk over 8192 and 16384 cached rows,
+  and for a cell's mixed step (31 decoding rows at 12.6 k of context and a
+  320-token question behind a 12.6 k document), over ``q_rows`` x
+  ``kv_block`` (``ops/pallas/tuning.ragged_blocks``);
+- ``decode``: ``paged_decode_attention`` for 32 rows at 12.6 k of context;
+- ``decompressed``: the same chunks with keys and values EXPANDED per head
+  (c_kv W_uk -> [ctx, 64, 128] beside the shared rotary part, c_kv W_uv ->
+  [ctx, 64, 128]; 2 x 192 + 2 x 128 FLOP a pair and 2 x 512 x 64 x 256 a
+  context row to expand), in plain XLA, a group of heads at a time.
+
+Each line gives the call's milliseconds, the FLOP it has to do (causal:
+what the mask leaves) and that as a share of the chip's peak
+(perfbench/peaks.json). Results: ``chiprun_out/mla_attn_forms.json``.
+"""
+
+import argparse
+import json
+import os
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--cpu-rehearsal", action="store_true")
+    ap.add_argument("--heads", type=int, default=64)
+    ap.add_argument("--iters", type=int, default=5)
+    args = ap.parse_args()
+    if args.cpu_rehearsal:
+        os.environ["JAX_PLATFORMS"] = "cpu"
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from gllm_tpu.ops.pallas.decode_attention import paged_decode_attention
+    from gllm_tpu.ops.pallas.ragged_attention import ragged_paged_attention
+    from gllm_tpu.ops.pallas.tuning import get as tuned
+    from gllm_tpu.utils import tpu_compiler_options
+
+    on_chip = jax.default_backend() == "tpu"
+    if not on_chip and not args.cpu_rehearsal:
+        sys.exit("no TPU here: run through the chip tool, or pass "
+                 "--cpu-rehearsal")
+    small = not on_chip
+    H = 4 if small else args.heads
+    lora, rope, nope, vd = (32, 8, 16, 16) if small else (512, 64, 128, 128)
+    width = lora + rope + (-(lora + rope)) % (8 if small else 128)
+    page = 16
+    chunk = 64 if small else 2048
+    contexts = (128, 256) if small else (8192, 16384)
+    doc, question, rows = ((96, 24, 4) if small else (12600, 320, 32))
+    dtype = jnp.float32 if small else jnp.bfloat16
+    scale = (nope + rope) ** -0.5 * 1.8134
+    with open(os.path.join(ROOT, "perfbench", "peaks.json")) as f:
+        peaks = json.load(f)["devices"]
+    kind = jax.devices()[0].device_kind
+    peak = peaks.get(kind, {}).get("flops_per_s") if on_chip else None
+    opts = tpu_compiler_options() if on_chip else None
+    key = jax.random.key(0)
+
+    def pool_for(n_seqs, ctx):
+        """A pool that holds ``n_seqs`` sequences of ``ctx`` tokens, and
+        the page table that lays them out one after the other."""
+        per = -(-ctx // page)
+        pt = 1 + np.arange(n_seqs * per, dtype=np.int32).reshape(n_seqs, per)
+        pool = jax.random.normal(key, (n_seqs * per + 1, page, 1, width),
+                                 jnp.float32).astype(dtype)
+        return pool, jnp.asarray(pt)
+
+    def timed(fn, *a):
+        out = jax.block_until_ready(fn(*a))         # compile + first run
+        t0 = time.monotonic()
+        for _ in range(args.iters):
+            out = fn(*a)
+        jax.block_until_ready(out)
+        return (time.monotonic() - t0) / args.iters * 1e3
+
+    results = []
+
+    def note(what, ms, flop, **kw):
+        line = dict(what=what, ms=round(ms, 3), gflop=round(flop / 1e9, 1),
+                    **kw)
+        if peak:
+            line["peak_pct"] = round(100 * flop / (ms * 1e-3) / peak, 1)
+        results.append(line)
+        print(json.dumps(line), flush=True)
+
+    pair_abs = 2 * width + 2 * lora         # as the kernel computes it
+    pair_dec = 2 * (nope + rope) + 2 * vd
+
+    def causal_pairs(q_len, ctx):
+        """(query, key) pairs a chunk of ``q_len`` at the end of a context
+        of ``ctx`` + ``q_len`` attends."""
+        return q_len * ctx + q_len * (q_len + 1) // 2
+
+    # ---- the absorbed form on the ragged kernel -------------------------
+    grid = ([(64, 32)] if small else
+            [(r, b) for r in (512, 1024, 2048) for b in (128, 256, 512)])
+
+    def sweep_ragged(what, flop, q, pool, cu, kl, pt, **kw):
+        """One ragged call's time at every block pair of the grid."""
+        for q_rows, kvb in grid:
+            fn = jax.jit(lambda q, k, cu, kl, pt, qb=max(8, q_rows // H),
+                         kvb=kvb: ragged_paged_attention(
+                q, k, None, cu, kl, pt, scale=scale, q_block=qb,
+                kv_block=kvb, v_dim=lora, interpret=small),
+                compiler_options=opts)
+            try:
+                ms = timed(fn, q, pool, cu, kl, pt)
+            except Exception as e:      # Mosaic refusing a block pair
+                print(f"{what} {kw} q_rows={q_rows} kv_block={kvb}: "
+                      f"{str(e)[:200]}", flush=True)
+                continue
+            note(what, ms, flop, q_rows=q_rows, kv_block=kvb, **kw)
+
+    for ctx in contexts:
+        pool, pt = pool_for(1, ctx + chunk)
+        q = jax.random.normal(key, (chunk, H, width), jnp.float32
+                              ).astype(dtype)
+        sweep_ragged("ragged_absorbed_chunk",
+                     causal_pairs(chunk, ctx) * H * pair_abs, q, pool,
+                     jnp.asarray([0, chunk], jnp.int32),
+                     jnp.asarray([ctx + chunk], jnp.int32), pt, ctx=ctx)
+
+    # a cell's mixed step: rows - 1 decoding rows and one question
+    pool, pt = pool_for(rows, doc + question)
+    T = rows - 1 + question
+    q = jax.random.normal(key, (T, H, width), jnp.float32).astype(dtype)
+    sweep_ragged(
+        "ragged_absorbed_mixed_step",
+        ((rows - 1) * doc + causal_pairs(question, doc)) * H * pair_abs, q,
+        pool, jnp.asarray(list(range(rows)) + [T], jnp.int32),
+        jnp.asarray([doc] * (rows - 1) + [doc + question], jnp.int32), pt,
+        rows=rows, doc=doc, question=question)
+
+    # ---- the decode kernel ----------------------------------------------
+    cfg = tuned("decode")
+    qd = jax.random.normal(key, (rows, H, width), jnp.float32).astype(dtype)
+    kld = jnp.asarray([doc] * rows, jnp.int32)
+    for kvb, grp in ([(32, 2)] if small else
+                     [(cfg["kv_block"], int(cfg.get("group", 1))),
+                      (512, 4), (256, 8), (128, 4)]):
+        fn = jax.jit(lambda q, k, kl, pt, kvb=kvb, grp=grp:
+                     paged_decode_attention(
+            q, k, None, kl, pt, scale=scale, v_dim=lora, kv_block=kvb,
+            group_size=grp, interpret=small), compiler_options=opts)
+        try:
+            ms = timed(fn, qd, pool, kld, pt)
+        except Exception as e:
+            print(f"decode kv_block={kvb} group={grp}: {str(e)[:200]}",
+                  flush=True)
+            continue
+        nbytes = rows * doc * width * jnp.dtype(dtype).itemsize
+        note("decode_kernel", ms, rows * doc * H * pair_abs, rows=rows,
+             ctx=doc, kv_block=kvb, group=grp,
+             hbm_pct=(round(100 * nbytes / (ms * 1e-3)
+                            / peaks[kind]["bytes_per_s"], 1)
+                      if on_chip else None))
+
+    # ---- the decompressed form in plain XLA ------------------------------
+    w_uk = (jax.random.normal(key, (H, nope, lora), jnp.float32)
+            * lora ** -0.5).astype(dtype)
+    w_uv = (jax.random.normal(key, (H, lora, vd), jnp.float32)
+            * lora ** -0.5).astype(dtype)
+    hg = min(8, H)
+
+    def decompressed(qn, qr, rows_, w_uk, w_uv, ctx):
+        """qn [T, H, nope], qr [T, H, rope], rows_ [ctx + T, width] (a
+        sequence's latent rows, gathered): expand, then attend a group
+        of heads at a time."""
+        c, kr = rows_[:, :lora], rows_[:, lora:lora + rope]
+        kn = jnp.einsum("sl,hnl->hsn", c, w_uk)
+        v = jnp.einsum("sl,hlv->hsv", c, w_uv)
+        kp = jnp.arange(rows_.shape[0])
+        qp = ctx + jnp.arange(qn.shape[0])
+        mask = kp[None, :] <= qp[:, None]
+
+        def group(h0):
+            sl = lambda a, ax: jax.lax.dynamic_slice_in_dim(a, h0, hg, ax)
+            s = (jnp.einsum("thn,hsn->hts", sl(qn, 1), sl(kn, 0),
+                            preferred_element_type=jnp.float32)
+                 + jnp.einsum("thr,sr->hts", sl(qr, 1), kr,
+                              preferred_element_type=jnp.float32)) * scale
+            p = jax.nn.softmax(jnp.where(mask[None], s, -jnp.inf), axis=-1)
+            return jnp.einsum("hts,hsv->htv", p.astype(v.dtype), sl(v, 0),
+                              preferred_element_type=jnp.float32)
+        out = jax.lax.map(group, jnp.arange(0, H, hg))
+        return out.reshape(H, qn.shape[0], vd).astype(qn.dtype)
+
+    for ctx in contexts:
+        rows_ = jax.random.normal(key, (ctx + chunk, width), jnp.float32
+                                  ).astype(dtype)
+        qn = jax.random.normal(key, (chunk, H, nope), jnp.float32
+                               ).astype(dtype)
+        qr = jax.random.normal(key, (chunk, H, rope), jnp.float32
+                               ).astype(dtype)
+        fn = jax.jit(decompressed, static_argnums=(5,),
+                     compiler_options=opts)
+        try:
+            ms = timed(fn, qn, qr, rows_, w_uk, w_uv, ctx)
+        except Exception as e:
+            print(f"decompressed ctx={ctx}: {str(e)[:300]}", flush=True)
+            continue
+        expand = (ctx + chunk) * 2 * lora * H * (nope + vd)
+        note("decompressed_xla_chunk", ms,
+             causal_pairs(chunk, ctx) * H * pair_dec + expand, ctx=ctx,
+             expand_gflop=round(expand / 1e9, 1))
+
+    out_dir = os.path.join(ROOT, "chiprun_out")
+    os.makedirs(out_dir, exist_ok=True)
+    with open(os.path.join(out_dir, "mla_attn_forms.json"), "w") as f:
+        json.dump({"device": kind, "heads": H, "results": results}, f,
+                  indent=1)
+
+
+if __name__ == "__main__":
+    main()

@@ -83,8 +83,21 @@ class ModelConfig:
     n_group: int = 0
     topk_group: int = 0
     scoring_func: str = "softmax"     # softmax (V2) | sigmoid (V3)
-    topk_method: str = "greedy"       # greedy | group_limited_greedy |
-                                      # noaux_tc (V3 bias-corrected)
+    # greedy | none (the plain top-k of all experts) |
+    # group_limited_greedy | noaux_tc (V3: group limit on bias-corrected
+    # scores). Only the last two have a group limit, whatever ``n_group``
+    # says (skt/A.X-K1 states n_group 8 beside topk_method "none")
+    topk_method: str = "greedy"
+
+    @property
+    def route_groups(self) -> int:
+        """Groups the router limits its choice to ``topk_group`` of
+        (0 = no limit: the top-k is over all experts)."""
+        if (self.topk_method in ("group_limited_greedy", "noaux_tc")
+                and self.n_group and self.topk_group
+                and self.topk_group < self.n_group):
+            return self.n_group
+        return 0
 
     @property
     def use_mla(self) -> bool:
@@ -112,6 +125,14 @@ class ModelConfig:
     @property
     def use_dsa(self) -> bool:
         return self.index_topk > 0 and self.index_n_heads > 0
+
+    @property
+    def dense_mla(self) -> bool:
+        """Latent attention over the WHOLE context in every layer (no
+        indexer's selection, no windowed layers): the one kind that hands
+        its attention to ``paged_attention``, one KV head under all query
+        heads."""
+        return self.use_mla and not (self.use_dsa or self.use_swa)
 
     # Windowed latent-attention layers beside the full ones (dots3_note:
     # ``layer_types`` marks a layer "sliding_attention"). They have a
@@ -314,7 +335,8 @@ def _eos_tuple(v) -> Optional[Tuple[int, ...]]:
 
 # a config.json that names its model_type and no architecture
 _ARCH_OF_MODEL_TYPE = {"olmo_hybrid": "OlmoHybridForCausalLM",
-                       "dots3_note": "Dots3NoteForCausalLM"}
+                       "dots3_note": "Dots3NoteForCausalLM",
+                       "axk1": "AXK1ForCausalLM"}
 
 
 def from_hf_config(hf: Dict[str, Any]) -> ModelConfig:
@@ -452,12 +474,7 @@ def from_hf_config(hf: Dict[str, Any]) -> ModelConfig:
     if arch == "Dots3NoteForCausalLM":
         # config.json of dots-studio/dots3-note-prev (model_type
         # dots3_note): DeepSeek-V3.2's keys for the full layers, the
-        # ``swa_*`` keys for the windowed ones. ``ep_share`` is this
-        # repo's own key: {"chips", "rank", "n_routed_experts"} says that
-        # ``n_routed_experts`` counts the experts HELD here, one of
-        # ``chips`` equal shares of the published count
-        share = hf.get("ep_share") or {}
-        held = hf["n_routed_experts"]
+        # ``swa_*`` keys for the windowed ones
         extra = dict(
             layer_types=tuple(hf.get("layer_types", ())),
             sliding_window=hf.get("sliding_window_size", 0) or 0,
@@ -472,15 +489,25 @@ def from_hf_config(hf: Dict[str, Any]) -> ModelConfig:
             swa_attn_gate=hf.get("swa_attention_gate_type") or "",
             mla_lora_rescale=bool(hf.get("apply_mla_qkv_lora_rescale")),
         )
-        if share:
-            if share["n_routed_experts"] != held * share["chips"]:
-                raise ValueError(
-                    f"ep_share: {share['chips']} chips x {held} experts "
-                    f"held are not the {share['n_routed_experts']} "
-                    "published")
-            extra.update(experts_held=held,
-                         expert_first=held * share.get("rank", 0))
-            hf = {**hf, "n_routed_experts": share["n_routed_experts"]}
+    share = hf.get("ep_share")
+    if share:
+        # this repo's own key, read for every family models/deepseek.py
+        # serves: {"chips", "rank", "n_routed_experts"} says that
+        # ``n_routed_experts`` counts the experts HELD here, one of
+        # ``chips`` equal shares of the published count
+        from gllm_tpu.models.registry import _MLA_ARCHS
+        if arch not in _MLA_ARCHS:
+            raise ValueError(f"ep_share: {arch} holds no share of its "
+                             "experts (models/deepseek.py's families do)")
+        held = hf["n_routed_experts"]
+        if share["n_routed_experts"] != held * share["chips"]:
+            raise ValueError(
+                f"ep_share: {share['chips']} chips x {held} experts "
+                f"held are not the {share['n_routed_experts']} "
+                "published")
+        extra.update(experts_held=held,
+                     expert_first=held * share.get("rank", 0))
+        hf = {**hf, "n_routed_experts": share["n_routed_experts"]}
     num_heads = hf["num_attention_heads"]
     hidden = hf["hidden_size"]
     head_dim = hf.get("head_dim") or hidden // num_heads

@@ -103,6 +103,25 @@ _M_MOE_STEPS = obs.counter(
     "touched a layer and step), by the kind of step", ("step",))
 
 
+_M_MLA_ROWS = obs.counter(
+    "gllm_mla_rows_read_total",
+    "Latent rows a step's dense latent attention has to read: every "
+    "sequence's context after the step, summed over the step's sequences "
+    "and the layers (a prefill chunk's queries share their sequence's "
+    "rows), by the kind of step (decode: one token a row; mixed: a "
+    "prefill chunk rides)", ("step",))
+
+
+def count_rows_read(cfg: ModelConfig, kv_lens, decode_only: bool) -> None:
+    """One step's latent rows into the counter, from the batch's
+    ``kv_lens`` as the host built them (no device value is read). For
+    dense latent attention (``cfg.dense_mla``): a selection or a window
+    bounds the other kinds' reads, and their own counters say by how
+    much."""
+    _M_MLA_ROWS.inc(int(kv_lens.sum()) * cfg.num_stage_layers,
+                    step="decode" if decode_only else "mixed")
+
+
 def count_stats(stats, decode_only: bool) -> None:
     """One step's ``LatentKVCache.stats`` (host array) into the counters."""
     v = [int(x) for x in stats]
@@ -230,10 +249,15 @@ def deepseek_route(router_logits: jnp.ndarray, e_bias: Optional[jnp.ndarray],
         scores = jax.nn.sigmoid(logits)
     else:
         scores = jax.nn.softmax(logits, axis=-1)
-    choice = scores + e_bias if e_bias is not None else scores
+    # the method decides, not the keys a config happens to carry: only
+    # noaux_tc corrects by the bias, only it and group_limited_greedy
+    # limit the choice to ``topk_group`` groups (``cfg.route_groups``)
+    choice = scores
+    if e_bias is not None and cfg.topk_method == "noaux_tc":
+        choice = scores + e_bias
 
-    if cfg.n_group and cfg.topk_group and cfg.topk_group < cfg.n_group:
-        g = cfg.n_group
+    g = cfg.route_groups
+    if g:
         grouped = choice.reshape(T, g, E // g)
         if cfg.topk_method == "noaux_tc":
             # group score = sum of top-2 member scores (V3)

@@ -155,6 +155,10 @@ _MLA_ARCHS = (
     # beside windowed latent layers with a geometry of their own, headwise
     # gates; models/deepseek.py reads both geometries from the config
     "Dots3NoteForCausalLM",
+    # skt/A.X-K1 (model_type axk1): DeepSeek-V3's block with dense latent
+    # attention in every layer (q-LoRA, YaRN), a sigmoid router whose
+    # topk_method "none" is the plain top-8 of all 192 experts
+    "AXK1ForCausalLM",
 )
 
 _VL_ARCHS = (

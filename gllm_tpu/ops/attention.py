@@ -272,8 +272,8 @@ def _paged_attention(
                     f"S={metadata.kv_lens.shape[0]}")
             from gllm_tpu.ops.pallas.decode_attention import (
                 paged_decode_attention)
-            from gllm_tpu.ops.pallas.tuning import get as tuned
-            cfg = tuned("decode")
+            from gllm_tpu.ops.pallas.tuning import decode_blocks
+            cfg = decode_blocks(k_cache.shape[2])
             out = paged_decode_attention(
                 q, k_cache, v_cache, metadata.kv_lens, metadata.page_table,
                 scale=scale, interpret=interpret, v_dim=v_dim,
@@ -283,8 +283,8 @@ def _paged_attention(
         else:
             from gllm_tpu.ops.pallas.ragged_attention import (
                 ragged_paged_attention)
-            from gllm_tpu.ops.pallas.tuning import get as tuned
-            blocks = tuned("ragged")
+            from gllm_tpu.ops.pallas.tuning import ragged_blocks
+            blocks = ragged_blocks(q.shape[1], k_cache.shape[2])
             out = ragged_paged_attention(
                 q, k_cache, v_cache, metadata.cu_q_lens, metadata.kv_lens,
                 metadata.page_table, scale=scale, interpret=interpret,

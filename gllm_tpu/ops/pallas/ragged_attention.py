@@ -49,6 +49,7 @@ Design (TPU-first):
 from __future__ import annotations
 
 import functools
+import logging
 
 import jax
 import jax.numpy as jnp
@@ -56,13 +57,29 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from gllm_tpu.ops.pallas.paged_kv import (block_kv, kv_stream_specs,
-                                          make_fetch_fns, unpack_refs)
+                                          make_fetch_fns, mxu_operand,
+                                          unpack_refs)
+
+logger = logging.getLogger(__name__)
 
 DEFAULT_KV_BLOCK = 256
 DEFAULT_Q_BLOCK = 128
 DEFAULT_GROUP = 4
 NEG_INF = float("-inf")
 LOG2E = 1.4426950408889634
+
+
+@functools.lru_cache(maxsize=None)
+def _announce_mqa(operand: str, heads: int, lanes: int, v_dim: int,
+                  bq: int, bk: int) -> None:
+    """Once per process and geometry, as the first program that holds the
+    kernel under one KV head is traced (the blocks follow the geometry:
+    ``tuning.ragged_blocks``)."""
+    logger.info(
+        "[startup] ragged_paged_attention under one KV head: %d query "
+        "heads x %d lanes (values: the first %d), q, K and p enter the MXU "
+        "as %s; q blocks of %d tokens = %d rows, kv blocks of %d tokens",
+        heads, lanes, v_dim, operand, bq, bq * heads, bk)
 
 
 def _rescale_add(x, dm_i):
@@ -111,8 +128,10 @@ def _online_update(scores, vt, m, l, acc, kv_axis: int, mqa: bool,
         p = jnp.exp(scores - safe_m)
         l_new = l * alpha + jnp.sum(p, axis=kv_axis, keepdims=True)
     if mqa:
+        # a 16-bit value block (the latent cache as stored) takes p in
+        # its own dtype: one MXU pass, float32 accumulation
         pv = jax.lax.dot_general(                   # [R, Dv]
-            p, vt, (((1,), (0,)), ((), ())),
+            p.astype(vt.dtype), vt, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
     else:
         pv = jax.lax.dot_general(                   # [H?, R, Dv]
@@ -187,14 +206,22 @@ def _kernel(cu_ref, kv_lens_ref, pt_ref, first_ref, last_ref,
         vs_buf=vs_buf)
 
     def _ragged_body():
-        q_raw = q_ref[...].astype(jnp.float32) * eff_scale  # [BQ, Hq, D]
+        if mqa:
+            # one KV head under every query head (the latent cache): a
+            # block is rows x lanes of MXU work, so q and the cache enter
+            # as stored where they are 16-bit (``mxu_operand``) and the
+            # float32 scores take the scale
+            q_raw, score_scale = q_ref[...], eff_scale
+        else:
+            q_raw = q_ref[...].astype(jnp.float32) * eff_scale  # [BQ, Hq, D]
+            score_scale = None
         _ragged_block(q_raw, cu_ref, kv_lens_ref, o_ref, start_fetch,
                       wait_fetch, k_buf, v_buf, ks_buf, vs_buf,
                       t_start=t_start, s0=s0, s1=s1, bk=bk, rows=rows,
                       kv_axis=kv_axis, num_kv_heads=num_kv_heads,
                       group=group, head_dim=head_dim, v_dim=v_dim,
                       q_blk=q_blk, shared_kv=shared_kv, mqa=mqa,
-                      amla=amla)
+                      amla=amla, score_scale=score_scale)
 
     if not unified:
         _ragged_body()
@@ -223,15 +250,19 @@ def _ragged_block(q, cu_ref, kv_lens_ref, o_ref, start_fetch, wait_fetch,
                   k_buf, v_buf, ks_buf, vs_buf, *, t_start, s0, s1,
                   bk: int, rows: int, kv_axis: int, num_kv_heads: int,
                   group: int, head_dim: int, v_dim: int, q_blk: int,
-                  shared_kv: bool, mqa: bool, amla: bool):
+                  shared_kv: bool, mqa: bool, amla: bool,
+                  score_scale=None):
     """The ragged (prefill/mixed) block body: loop the sequences
     overlapping this q block, stream each one's causal KV range with
-    double-buffered DMA, masked kv-head-batched dots."""
+    double-buffered DMA, masked kv-head-batched dots. ``score_scale``
+    (MQA only): q arrives unscaled in its own dtype and the scores take
+    the scale."""
     if mqa:
         # Hkv == 1 (MLA latent): flat 2-D rows [BQ*Hq, D]; the caches
         # arrive 3-D with the singleton head axis squeezed (Mosaic's
         # sublane tiling rejects slicing a size-1 second-minor dim).
-        qh = q.reshape(rows, head_dim)
+        operand = mxu_operand(q.dtype, k_buf.dtype, False)
+        qh = q.reshape(rows, head_dim).astype(operand)
         row_tok = t_start + jax.lax.broadcasted_iota(
             jnp.int32, (rows, 1), 0) // group
     else:
@@ -274,11 +305,11 @@ def _ragged_block(q, cu_ref, kv_lens_ref, o_ref, start_fetch, wait_fetch,
                             head_dim, v_dim, shared_kv, mqa=mqa,
                             ks_buf=ks_buf, vs_buf=vs_buf)
             if mqa:
-                kt = k.astype(jnp.float32)              # [BK, D]
-                vt = v.astype(jnp.float32)              # [BK, Dv]
+                kt = k.astype(operand)                  # [BK, D]
+                vt = v.astype(operand)                  # [BK, Dv]
                 scores = jax.lax.dot_general(           # [R, BK]
                     qh, kt, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)
+                    preferred_element_type=jnp.float32) * score_scale
             else:
                 kt = k.astype(jnp.float32).transpose(1, 0, 2)
                 vt = v.astype(jnp.float32).transpose(1, 0, 2)
@@ -494,6 +525,10 @@ def ragged_paged_attention(
     if rem:
         page_table = jnp.pad(page_table,
                              ((0, 0), (0, pages_per_block - rem)))
+    if mqa and not interpret:
+        _announce_mqa(mxu_operand(q.dtype, k_cache.dtype, False).name,
+                      num_q_heads, head_dim, v_dim, bq,
+                      pages_per_block * page_size)
 
     # Per-block overlapping sequence range: seq s covers tokens
     # [cu[s], cu[s+1]); searchsorted over the upper bounds finds the first

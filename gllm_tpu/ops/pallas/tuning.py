@@ -29,6 +29,16 @@ logger = logging.getLogger(__name__)
 BUILTIN = {
     "default": {
         "ragged": {"q_block": 128, "kv_block": 256},
+        # the ragged kernel under ONE KV head (the MLA latent cache): every
+        # query head shares the key block, so a q block is q_block x heads
+        # rows of MXU work over head_dim lanes, and its windows, float32
+        # accumulator and score tile grow with heads x lanes, not with
+        # q_block alone. ``q_rows`` bounds the rows; ``ragged_blocks``
+        # turns it into the q block of a geometry
+        "ragged_mqa": {"q_rows": 1024, "kv_block": 256},
+        # the decode kernel under one KV head: a block's bytes a token are
+        # one latent row, not heads x (K + V)
+        "decode_mqa": {"kv_block": 256},
         "decode": {"kv_block": 256},
         # the unified mixed-batch kernel (--unified-step): one geometry
         # for every paged step; ``group`` is the decode-class DMA
@@ -82,4 +92,27 @@ def get(kernel: str) -> dict:
     out = dict(t.get("default", {}).get(kernel, {}))
     out.update(t.get(device_tag(), {}).get(kernel, {}))
     out.pop("comment", None)
+    return out
+
+
+def ragged_blocks(num_q_heads: int, num_kv_heads: int) -> dict:
+    """{"q_block", "kv_block"} of the ragged kernel at a geometry. With
+    several KV heads the table's pair as swept (``ragged``). Under one KV
+    head (MLA: 64 or 128 query heads over a latent row of 640 lanes) the
+    q block is what keeps ``q_rows`` rows in VMEM: the table's 512 x 128,
+    swept at 8 KV heads of 128, is refused there by Mosaic (128.29 MB of
+    VMEM at 64 heads x 640 lanes; tests/test_tpu_compile.py pins it)."""
+    if num_kv_heads != 1:
+        return get("ragged")
+    cfg = get("ragged_mqa")
+    return {"q_block": max(8, int(cfg["q_rows"]) // num_q_heads // 8 * 8),
+            "kv_block": int(cfg["kv_block"])}
+
+
+def decode_blocks(num_kv_heads: int) -> dict:
+    """{"kv_block", "group"} of the decode kernel: the ``decode`` entry,
+    with what ``decode_mqa`` says laid over it under one KV head."""
+    out = get("decode")
+    if num_kv_heads == 1:
+        out.update(get("decode_mqa"))
     return out

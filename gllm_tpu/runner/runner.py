@@ -393,6 +393,27 @@ def resolve_attn_impl(impl: str, cfg: ModelConfig, tp: int, pack: int,
             "[startup] hybrid: full attention -> %s; GDN recurrent step "
             "and chunk scan -> %s; GDN in-chunk half -> xla (ops/gdn.py)",
             "pallas" if why is None else f"xla ({why})", gdn)
+    if cfg.dense_mla:
+        # dense latent attention: every layer attends its whole context
+        # over the paged latent pool, one KV head under all query heads
+        from gllm_tpu.ops.pallas.tuning import decode_blocks, ragged_blocks
+        if why is None:
+            dec = decode_blocks(1)
+            blocks = ragged_blocks(cfg.num_heads // max(tp, 1)
+                                   if tp_sharded else cfg.num_heads, 1)
+            logger.info(
+                "[startup] latent attention (%d heads over rows of %d "
+                "lanes, values the first %d): decode steps -> pallas "
+                "paged_decode_attention (kv_block %d, group %d); mixed "
+                "steps -> pallas ragged_paged_attention (q_block %d, "
+                "kv_block %d: the blocks follow heads x lanes, "
+                "ops/pallas/tuning.ragged_blocks)",
+                cfg.num_heads, cfg.mla_cache_width, cfg.kv_lora_rank,
+                dec["kv_block"], int(dec.get("group", 1)),
+                blocks["q_block"], blocks["kv_block"])
+        else:
+            logger.info("[startup] latent attention: decode steps and "
+                        "mixed steps -> xla (%s)", why)
     if why is None:
         return "pallas"
     if impl == "pallas":
@@ -695,6 +716,23 @@ class ModelRunner:
             1 + self.ssm_working_slots + self.ssm_snapshot_slots
             if model_cfg.use_hybrid else 0, self._ssm_pool_bytes(),
             self._gdn_chunk_temp_bytes())
+        if model_cfg.dense_mla:
+            # dense latent attention: what the chip holds, in one line
+            # beside the line that says which kernel serves which kind of
+            # step (resolve_attn_impl)
+            logger.info(
+                "[startup] latent model: weights %d bytes (%d of %d routed "
+                "experts a layer held here); latent pool %d pages of %d "
+                "tokens x %d layers x %d stored lanes = %d bytes%s",
+                sum(x.size * x.dtype.itemsize
+                    for x in jax.tree.leaves(self.params)),
+                model_cfg.num_local_experts, model_cfg.num_experts,
+                self.num_pages, config.cache.page_size,
+                model_cfg.num_stage_layers, model_cfg.mla_cache_width,
+                self.latent_pool_bytes()[0],
+                "; prefix cache on: a request claims the cached whole "
+                "pages of its prompt and computes the rest"
+                if config.cache.enable_prefix_caching else "")
         if model_cfg.use_swa:
             latent, index, rings = self.latent_pool_bytes()
             logger.info(
@@ -1443,6 +1481,9 @@ class ModelRunner:
         self._apply_swap_intents()
         self._step_count += 1
         host, max_q, token_counts = self.builder.build(sched_batch)
+        if self.model_cfg.dense_mla:
+            from gllm_tpu.models.deepseek import count_rows_read
+            count_rows_read(self.model_cfg, host.attn.kv_lens, max_q == 1)
         batch, layout = pack(host, (self._step_count,))
         if prev_handle is not None:
             batch = self._splice_prev(batch, sched_batch, prev_handle[0])

@@ -116,3 +116,66 @@ def test_mla_pallas_matches_xla(tmp_path):
                                            ignore_eos=True))]
 
     assert run("pallas") == run("xla")
+
+
+# ---- the router follows ``topk_method`` ------------------------------------
+
+def _route_by_hand(scores, bias, method, n_group, topk_group, k):
+    """The four methods as their papers have them, a token at a time."""
+    import numpy as np
+    ids = []
+    for s in scores:
+        choice = s + bias if method == "noaux_tc" else s.copy()
+        if method in ("group_limited_greedy", "noaux_tc"):
+            groups = choice.reshape(n_group, -1)
+            rank = (np.sort(groups, axis=1)[:, -2:].sum(1)
+                    if method == "noaux_tc" else groups.max(1))
+            keep = np.argsort(-rank, kind="stable")[:topk_group]
+            mask = np.full(n_group, -np.inf)
+            mask[keep] = 0.0
+            choice = (groups + mask[:, None]).reshape(-1)
+        ids.append(np.argsort(-choice, kind="stable")[:k])
+    return np.stack(ids)
+
+
+@pytest.mark.parametrize("method", ["greedy", "group_limited_greedy",
+                                    "noaux_tc", "none"])
+def test_router_follows_topk_method_not_the_presence_of_n_group(method):
+    """A config that carries ``n_group`` / ``topk_group`` (skt/A.X-K1 does,
+    beside ``topk_method`` "none") is limited to groups only by the two
+    methods that have a group limit, and corrected by the bias only by
+    noaux_tc; "none" and "greedy" are the plain top-k of all experts. The
+    input puts the 8 largest scores into 3 groups of which the limit keeps
+    2, so the limited and the plain choice differ on every token."""
+    import jax.numpy as jnp
+    import numpy as np
+    from gllm_tpu.models.config import from_hf_config
+    from gllm_tpu.models.deepseek import deepseek_route
+    E, K, G, TG = 32, 4, 8, 2
+    cfg = from_hf_config(dict(
+        BASE, architectures=["DeepseekV3ForCausalLM"], n_routed_experts=E,
+        num_experts_per_tok=K, n_group=G, topk_group=TG,
+        topk_method=method, scoring_func="sigmoid", norm_topk_prob=True,
+        routed_scaling_factor=2.5))
+    assert cfg.route_groups == (G if method in ("group_limited_greedy",
+                                                "noaux_tc") else 0)
+    rng = np.random.default_rng(7)
+    logits = rng.standard_normal((16, E)).astype(np.float32)
+    # the four largest logits of every token in four different groups
+    for t in range(16):
+        logits[t, [0, 5, 10, 15]] = [4.0, 3.5, 3.0, 2.5]
+    bias = (rng.standard_normal(E) * 0.05).astype(np.float32)
+    scores = 1 / (1 + np.exp(-logits.astype(np.float64)))
+    w, ids = deepseek_route(jnp.asarray(logits), jnp.asarray(bias), cfg)
+    want = _route_by_hand(scores, bias, method, G, TG, K)
+    assert np.array_equal(np.sort(np.asarray(ids), 1), np.sort(want, 1))
+    plain = _route_by_hand(scores, bias, "none", G, TG, K)
+    limited = method in ("group_limited_greedy", "noaux_tc")
+    assert np.array_equal(np.sort(want, 1), np.sort(plain, 1)) != limited
+    if not limited:
+        assert np.array_equal(np.sort(want, 1)[0], [0, 5, 10, 15])
+    # weights: the chosen scores (never the biased ones), normalised, x 2.5
+    picked = np.take_along_axis(scores, np.asarray(ids), 1)
+    np.testing.assert_allclose(
+        np.asarray(w), 2.5 * picked / picked.sum(1, keepdims=True),
+        rtol=1e-5)

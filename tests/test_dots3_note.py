@@ -23,9 +23,9 @@ from gllm_tpu.sampling_params import SamplingParams
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _reference():
-    path = os.path.join(ROOT, "perfbench", "reference", "dots3_note.py")
-    spec = importlib.util.spec_from_file_location("t_ref_dots3", path)
+def _reference(name="dots3_note"):
+    path = os.path.join(ROOT, "perfbench", "reference", name + ".py")
+    spec = importlib.util.spec_from_file_location("t_ref_" + name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
@@ -114,13 +114,28 @@ def test_published_config_file_keeps_the_catalogs_widths():
 
 # ---- the expert layer as a share ------------------------------------------
 
-def _moe_layer(seed=3, bias=None):
-    """An uncut expert layer at TINY's sizes: (uncut model dict, reference
-    layer dict with all 32 experts, x [T, H])."""
-    uncut = dict(TINY, n_routed_experts=32)
+# A.X-K1's router at small widths: sigmoid, the plain top-8 of all 192
+# (``topk_method`` "none" beside inert ``n_group`` / ``topk_group``),
+# normalised, times 2.5; one of 16 chips holds 12 experts
+TINY_AXK1 = dict(
+    model_type="axk1", vocab_size=256, hidden_size=64, num_hidden_layers=2,
+    num_attention_heads=4, num_key_value_heads=4, intermediate_size=96,
+    max_position_embeddings=512, rms_norm_eps=1e-6, q_lora_rank=32,
+    kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+    rope_theta=10000, first_k_dense_replace=1, n_routed_experts=12,
+    num_experts_per_tok=8, moe_intermediate_size=32, n_shared_experts=1,
+    routed_scaling_factor=2.5, scoring_func="sigmoid", topk_method="none",
+    n_group=8, topk_group=4, norm_topk_prob=True,
+    ep_share={"chips": 16, "rank": 0, "n_routed_experts": 192})
+
+
+def _moe_layer(seed=3, bias=None, tiny=TINY, e=32):
+    """An uncut expert layer at ``tiny``'s sizes: (uncut model dict,
+    reference layer dict with all ``e`` experts, x [T, H])."""
+    uncut = dict(tiny, n_routed_experts=e)
     del uncut["ep_share"]
     rng = np.random.default_rng(seed)
-    h, i, e = 64, 32, 32
+    h, i = 64, 32
 
     def w(*shape, scale):
         return jnp.asarray(rng.standard_normal(shape) * scale, jnp.float32)
@@ -135,37 +150,46 @@ def _moe_layer(seed=3, bias=None):
     return uncut, layer, w(37, h, scale=1.0)
 
 
-@pytest.mark.parametrize("case", ["even", "skewed"])
+@pytest.mark.parametrize("case", ["even", "skewed", "axk1_16x12"])
 def test_eight_shares_and_the_shared_expert_once_make_the_uncut_layer(case):
     """The guide's share test: the routed parts that the eight shares of
     four experts give, plus what every chip computes alike (the shared
     expert) counted once, add up to the uncut reference's layer. ``skewed``
     biases the router onto share 0, so that it holds more than the quarter
-    of all assignments one pass of its loop takes."""
-    bias = None
+    of all assignments one pass of its loop takes. ``axk1_16x12``: sixteen
+    shares of twelve of 192 experts at A.X-K1's router (sigmoid, the plain
+    top-8, scaling 2.5, no bias), against that model's own reference."""
+    bias, tiny, ref, chips, held_n, k = None, TINY, REF, 8, 4, 2
     if case == "skewed":
         bias = jnp.zeros((32,), jnp.float32).at[:4].set(5.0)
-    uncut, layer, x = _moe_layer(bias=bias)
+    if case == "axk1_16x12":
+        tiny, ref, chips, held_n, k = (TINY_AXK1, _reference("axk1"), 16,
+                                       12, 8)
+    uncut, layer, x = _moe_layer(bias=bias, tiny=tiny, e=chips * held_n)
+    if case == "axk1_16x12":
+        del layer["e_bias"]     # topk_method "none": the layer has none
     with jax.default_matmul_precision("highest"):
-        want = (REF.routed_part(uncut, x, layer, REF._mm)
-                + REF.shared_part(x, layer, REF._mm))
+        want = (ref.routed_part(uncut, x, layer, ref._mm)
+                + ref.shared_part(x, layer, ref._mm))
         valid = jnp.arange(x.shape[0]) < 33        # four padding rows
         shared = deepseek._shared_expert(layer, x)
         total, held_sum = shared.astype(jnp.float32), 0
-        for rank in range(8):
-            cfg = from_hf_config(dict(TINY, ep_share=dict(
-                TINY["ep_share"], rank=rank)))
-            lp = dict(layer, **{k: layer[k][4 * rank:4 * rank + 4]
-                                for k in ("w_gate", "w_up", "w_down")})
+        for rank in range(chips):
+            cfg = from_hf_config(dict(tiny, ep_share=dict(
+                tiny["ep_share"], rank=rank)))
+            assert cfg.route_groups == 0
+            lp = dict(layer, **{
+                name: layer[name][held_n * rank:held_n * (rank + 1)]
+                for name in ("w_gate", "w_up", "w_down")})
             out, stats = deepseek._moe_block(lp, x, cfg, valid)
             total = total + (out - shared)
             held, absent, touched, layers = (int(v) for v in stats)
-            assert held + absent == 33 * 2 and layers == 1
-            assert 0 <= touched <= 4
+            assert held + absent == 33 * k and layers == 1
+            assert 0 <= touched <= held_n
             if case == "skewed" and rank == 0:
                 assert held > 37 * 2 // 4       # a second pass of the loop
             held_sum += held
-        assert held_sum == 33 * 2
+        assert held_sum == 33 * k
     np.testing.assert_allclose(np.asarray(total[:33]), np.asarray(want[:33]),
                                rtol=2e-5, atol=2e-5)
 

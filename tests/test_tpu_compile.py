@@ -254,22 +254,31 @@ def _kernel_args(topo, *, S, T, Hq=32, Hkv=8, D=64, pack=2, P=8192,
     # the benchmark's decode-bound cells: 32 rows, head_dim 128 unpacked
     dict(S=32, T=32, Hkv=8, D=128, pack=1, P=2800),       # qwen3-4b
     dict(S=32, T=32, Hkv=32, D=128, pack=1, P=4320),      # olmo-hybrid-7b
-], ids=["smoke_packed", "dense_cell_hkv8", "hybrid_cell_hkv32"])
+    # a.x-k1: 64 query heads over ONE KV head, the latent row of 640 lanes
+    # whose first 512 are the values (no V cache), 5 layers of 35072 pages
+    dict(S=32, T=32, Hq=64, Hkv=1, D=640, pack=1, P=5 * 35072, pages=1088,
+         v_dim=512),
+], ids=["smoke_packed", "dense_cell_hkv8", "hybrid_cell_hkv32",
+        "mla_cell_hkv1"])
 def test_decode_kernel_compiles_for_v5e(topo, on_tpu, geometry):
     """Mosaic accepts the decode kernel's block update at every geometry
-    served on the chip, with the block and group the table gives, under
-    the step programs' own compiler options; and the view of the pool
-    the kernel reads (heads folded into a page's rows) costs no copy of
-    the cache."""
+    served on the chip, with the block and group the table gives (under
+    one KV head: its ``decode_mqa`` entry), under the step programs' own
+    compiler options; and the view of the pool the kernel reads (heads
+    folded into a page's rows) costs no copy of the cache."""
     from gllm_tpu.ops.pallas.decode_attention import paged_decode_attention
-    from gllm_tpu.ops.pallas.tuning import get as tuned
+    from gllm_tpu.ops.pallas.tuning import decode_blocks
     from gllm_tpu.utils import tpu_compiler_options
-    cfg = tuned("decode")
+    geometry = dict(geometry)
+    v_dim = geometry.pop("v_dim", None)
+    cfg = decode_blocks(geometry.get("Hkv", 8))
     assert "group" in cfg, "expected the tpu_v5_lite table entry"
+    if v_dim:
+        assert cfg["kv_block"] == 512, "expected the decode_mqa entry"
     q, kc, vc, _cu, kv_lens, pt = _kernel_args(topo, **geometry)
     fn = jax.jit(lambda q, k, v, kl, pt: paged_decode_attention(
-        q, k, v, kl, pt, scale=0.125, kv_block=cfg["kv_block"],
-        group_size=int(cfg["group"])),
+        q, k, None if v_dim else v, kl, pt, scale=0.125, v_dim=v_dim,
+        kv_block=cfg["kv_block"], group_size=int(cfg["group"])),
         compiler_options=tpu_compiler_options())
     compiled = fn.lower(q, kc, vc, kv_lens, pt).compile()
     assert has_kernel(compiled)
@@ -761,3 +770,124 @@ def test_dots3_windowed_attention_compiles_for_v5e_at_the_chunk(topo,
           f"{time.monotonic() - t0:.1f}s, "
           f"{mem.temp_size_in_bytes / GiB:.3f} GiB temp")
     assert mem.temp_size_in_bytes < 1.5 * GiB
+
+
+# ---- dense latent attention at a.x-k1's widths ------------------------------
+
+def axk1_cfg() -> ModelConfig:
+    """perfbench/configs/a.x-k1.json: published widths (64 heads over rows
+    of 640 as stored, q-LoRA 1536, YaRN), 12 held experts of 7168 x 2048,
+    5 layers."""
+    from gllm_tpu.models.config import from_hf_config
+    return from_hf_config(_perfbench_hf("a.x-k1"))
+
+
+def _axk1_runner(topo, monkeypatch):
+    from gllm_tpu.config import SchedulerConfig as SC
+    flags = _perfbench_hf("a.x-k1")["server_flags"]
+    val = lambda name: int(flags[flags.index(name) + 1])
+    runner = make_runner(
+        axk1_cfg(), topo, num_pages=val("--num-pages"),
+        monkeypatch=monkeypatch, max_num_seqs=val("--max-num-seqs"),
+        max_model_len=val("--max-model-len"), attention_impl="auto")
+    assert runner.attn_impl == "pallas"
+    return runner
+
+
+def _mla_ragged(topo, q_block, kv_block, T=2080, S=32, pages=1088):
+    from gllm_tpu.ops.pallas.ragged_attention import ragged_paged_attention
+    from gllm_tpu.utils import tpu_compiler_options
+    q, kc, _vc, cu, kv_lens, pt = _kernel_args(
+        topo, S=S, T=T, Hq=64, Hkv=1, D=640, pack=1, P=5 * 35072,
+        pages=pages)
+    fn = jax.jit(lambda q, k, cu, kl, pt: ragged_paged_attention(
+        q, k, None, cu, kl, pt, scale=0.1, q_block=q_block,
+        kv_block=kv_block, v_dim=512),
+        compiler_options=tpu_compiler_options())
+    return fn.lower(q, kc, cu, kv_lens, pt).compile()
+
+
+def test_mla_ragged_kernel_compiles_for_v5e_with_blocks_from_the_geometry(
+        topo, on_tpu):
+    """The ragged kernel at the cell's mixed step (512 token slots, 64
+    heads over one KV head of 640 lanes, values the first 512) with the
+    blocks ``tuning.ragged_blocks`` gives that geometry: q blocks of 8
+    tokens = 512 rows, kv blocks of 512 tokens."""
+    from gllm_tpu.ops.pallas.tuning import ragged_blocks
+    blocks = ragged_blocks(64, 1)
+    assert blocks == {"q_block": 8, "kv_block": 512}
+    assert ragged_blocks(32, 8) == {"q_block": 512, "kv_block": 128}
+    t0 = time.monotonic()
+    compiled = _mla_ragged(topo, T=512, **blocks)
+    print(f"\n[compile] a.x-k1 ragged kernel, 512 slots: "
+          f"{time.monotonic() - t0:.1f}s")
+    assert has_kernel(compiled)
+    assert "ragged_paged_attention" in compiled.as_text()
+
+
+@pytest.mark.slow
+def test_mla_ragged_kernel_is_refused_at_the_blocks_swept_for_8_kv_heads(
+        topo, on_tpu):
+    """Why the blocks follow the geometry: the table's ``ragged`` pair (512
+    x 128, swept at 8 KV heads of 128; 128 x 128 after
+    ``effective_q_block``) makes windows of [128, 64, 640] in and [128, 64,
+    512] out under one KV head of 640 lanes, and Mosaic runs out of VMEM
+    (128.29 MB of 128 with float32 operands, before PR 37). If this starts
+    passing, the kernel has shrunk and ``ragged_mqa`` may take larger
+    blocks."""
+    from gllm_tpu.ops.pallas.tuning import get as tuned
+    blocks = tuned("ragged")
+    assert (blocks["q_block"], blocks["kv_block"]) == (512, 128)
+    with pytest.raises(Exception, match="(?i)vmem|memory"):
+        _mla_ragged(topo, **blocks)
+
+
+@pytest.mark.slow
+def test_axk1_decode_step_compiles_for_v5e(topo, on_tpu, monkeypatch):
+    """The cell's one decode program: 32 rows at contexts of 17088 tokens
+    (1068 pages: the 1088-page bucket) through five dense latent layers on
+    ``paged_decode_attention``, 12 held experts a layer whose stacks are
+    read in place. Counted from shapes by the compiler; nothing runs."""
+    runner = _axk1_runner(topo, monkeypatch)
+    c = compile_of(runner.step_async, decode_batch(runner, 32, 1068))
+    mem = c.compiled.memory_analysis()
+    print(f"\n[compile] a.x-k1 decode: {c.seconds:.1f}s, "
+          f"{mem.argument_size_in_bytes / GiB:.2f} GiB of arguments, "
+          f"{mem.temp_size_in_bytes / GiB:.3f} GiB temp")
+    text = c.compiled.as_text()
+    # operations by their names, as the trace shows them
+    assert "%paged_decode_attention" in text and "%ragged-dot" in text
+    assert "%ragged_paged_attention" not in text
+    # weights 6.98 GB + the latent pool 3.59 GB, as the configuration's
+    # ``derived`` has them, and nothing of the pool's size beside them
+    derived = _perfbench_hf("a.x-k1")["derived"]
+    want = derived["weight_bytes"] + derived["latent_pool_bytes"]
+    assert abs(mem.argument_size_in_bytes / want - 1) < 0.01
+    assert _weight_bytes(runner) == derived["weight_bytes"]
+    assert mem.temp_size_in_bytes < 0.5 * GiB
+    # no layer's expert stack is copied out of the run's
+    assert "bf16[12,7168,2048]{2,1,0:T(8,128)(2,1)} fusion(" not in text
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("tokens", [320, 2048])
+def test_axk1_mixed_step_compiles_for_v5e(topo, on_tpu, monkeypatch, tokens):
+    """The cell's mixed steps: a question of 320 tokens (the 512-slot
+    program, every request of the window) or a 2048-token chunk of the
+    fill (the 2080-slot program) at the end of a 17088-token context
+    beside 31 decoding rows, on ``ragged_paged_attention`` in the absorbed
+    form with the blocks of the geometry."""
+    runner = _axk1_runner(topo, monkeypatch)
+    batch = prefill_batch(runner, tokens, ndecode=31, npages=1068,
+                          table_pages=1068)
+    c = compile_of(runner.step_async, batch)
+    mem = c.compiled.memory_analysis()
+    print(f"\n[compile] a.x-k1 mixed, {tokens} + 31 tokens: "
+          f"{c.seconds:.1f}s, "
+          f"{mem.argument_size_in_bytes / GiB:.2f} GiB of arguments, "
+          f"{mem.temp_size_in_bytes / GiB:.3f} GiB temp")
+    text = c.compiled.as_text()
+    assert "%ragged_paged_attention" in text
+    assert "%paged_decode_attention" not in text
+    assert mem.temp_size_in_bytes < 2.0 * GiB
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14 * GiB
