@@ -156,6 +156,66 @@ def build_ragged(q_block, kv_block, kv_dtype="auto", **workload):
     return run, (q, kc, vc)
 
 
+# The ragged sweep's inputs: the two ``reason`` cells' mixed steps first
+# (31 rows decoding at the mix's contexts and one fresh prompt, in the
+# token bucket the runner gives the step, served as the dispatch serves
+# them: the riding rows by the decode kernel, the prompt by the ragged
+# kernel; chained over layers inside one program; at the dense cell's 8 KV
+# heads and the hybrid's 32; these decide the winner), then, for the
+# record, a whole ``--maxp`` chunk of a long
+# fresh prompt beside the same rows (no cell sends one; a winner must not
+# be paid for there) and the old input (8 chunks of 128 tokens over 1024
+# of context each, the ragged kernel alone).
+RAGGED_SHAPES = (
+    dict(name="dense_cell_prompt320", rows=31, prompt=320, tokens=512,
+         layers=36),
+    dict(name="dense_cell_prompt512", rows=31, prompt=512, tokens=1024,
+         layers=36),
+    dict(name="hybrid_cell_prompt320", rows=31, prompt=320, tokens=512,
+         layers=36, Hkv=32, pool=4320),
+    dict(name="chunk2048", rows=31, prompt=2048, tokens=2112, layers=12),
+)
+
+
+def build_mixed_step(q_block, kv_block, rows=31, prompt=320, tokens=512,
+                     Hq=32, Hkv=8, D=128, page=16, pool=2800, layers=36,
+                     seed=38):
+    """Jitted body + buffers of ``layers`` mixed-step attention calls as
+    ``ops/attention._mixed_step_attention`` makes them, with the ragged
+    kernel at ``q_block`` x ``kv_block`` (a child process sweeps one pair:
+    ``tuning.ragged_blocks`` is replaced in it)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from benchmarks.decode_attn_ablation import (build_inputs,
+                                                 reason_contexts)
+    from gllm_tpu.ops import attention
+    from gllm_tpu.ops.pallas import tuning
+    from gllm_tpu.utils import tpu_compiler_options
+    tuning.ragged_blocks = lambda *_: {"q_block": q_block,
+                                       "kv_block": kv_block}
+    rng = np.random.default_rng(seed)
+    lens = np.append(reason_contexts(rng, rows), prompt).astype(np.int32)
+    _, kc, vc, kl, pt = build_inputs(rng, Hq, Hkv, pool, rows + 1, lens,
+                                     page, D, jnp.bfloat16)
+    q = jax.random.normal(jax.random.key(seed), (tokens, Hq, D),
+                          jnp.bfloat16)
+    md = attention.AttentionMetadata(
+        jnp.asarray(list(range(rows + 1)) + [rows + prompt], jnp.int32),
+        kl, pt, jnp.asarray(rows + 1, jnp.int32))
+    interp = _interp()
+
+    @functools.partial(jax.jit, compiler_options=tpu_compiler_options())
+    def run(q, kc, vc):
+        def layer(q, _):
+            out = attention._mixed_step_attention(
+                q, kc, vc, md, None, None, scale=D ** -0.5,
+                interpret=interp, v_dim=None)
+            return (q + out * 1e-3).astype(q.dtype), None
+        return jax.lax.scan(layer, q, None, length=layers)[0]
+    return run, (q, kc, vc)
+
+
 UNIFIED_MIXES = ("decode", "balanced", "prefill")
 
 
@@ -268,6 +328,9 @@ def time_unified(q_block, kv_block, gsz, iters=8, kv_dtype="auto"):
 
 
 def time_ragged(q_block, kv_block, iters=12, kv_dtype="auto"):
+    """Per-layer ms summed over the cells' mixed steps (the ranking;
+    ``RAGGED_SHAPES``), each shape's line printed, and the old input's
+    beside them."""
     # Interpret mode (CPU smoke) runs each grid program as traced
     # python — the silicon-shaped workload would take hours per point.
     # Shrink so every point times standalone in seconds; the silicon
@@ -284,7 +347,24 @@ def time_ragged(q_block, kv_block, iters=12, kv_dtype="auto"):
     bq = effective_q_block(q_block, kv_block, q.shape[1], q.shape[0])
     print(f"EFFECTIVE ragged:{bq}:{kv_block}", flush=True)
 
-    return _time_reps(run, q, iters, *args, reps=reps)
+    old = _time_reps(run, q, iters, *args, reps=reps)
+    if _interp() or kv_dtype != "auto":
+        return old
+    print(f"RAGGED s8_t1024_ctx1024 q={bq} kv={kv_block}: {old:.4f} ms a "
+          "call (the ragged kernel alone)", flush=True)
+    ranked = 0.0
+    for shape in RAGGED_SHAPES:
+        shape = dict(shape)
+        name = shape.pop("name")
+        run, (q, *args) = build_mixed_step(q_block, kv_block, **shape)
+        ms = (_time_reps(run, q, max(5, iters // 4), *args, reps=reps)
+              / shape["layers"])
+        print(f"RAGGED {name} q={bq} kv={kv_block}: {ms:.4f} ms a layer "
+              "(decode kernel for the riding rows + ragged kernel)",
+              flush=True)
+        if "_cell_" in name:
+            ranked += ms
+    return ranked
 
 
 # The decode sweep's inputs: the two benchmark cells' geometries first
@@ -449,6 +529,9 @@ def main():
     ap.add_argument("--vmem-probe", action="store_true")
     ap.add_argument("--kernel", choices=("ragged", "decode", "unified"),
                     default=None)
+    ap.add_argument("--blocks", default=None,
+                    help="comma-separated block sizes of the ragged sweep "
+                         f"(default {','.join(map(str, BLOCKS))})")
     ap.add_argument("--kv-dtype", choices=("auto", "int8"), default="auto",
                     help="sweep the kernels against an int8 quantized "
                          "cache (kv_cache_dtype=int8 serving shape); "
@@ -582,7 +665,7 @@ def main():
     def report(kind, cfg, ms, out):
         say(f"[tune] {kind} {cfg}: {'%.4f ms' % ms if ms else 'FAIL'}")
         for ln in out.splitlines():
-            if ln.startswith("DECODE "):     # per-shape times and floors
+            if ln.startswith(("DECODE ", "RAGGED ")):   # per-shape times
                 say("[tune]   " + ln)
         if ms is None:
             # a FAIL without its error is undiagnosable after the
@@ -598,7 +681,11 @@ def main():
         # alias to one entry, keyed by the EFFECTIVE config the child
         # compiled, and share the min of their timings
         eff_ms = {}
-        for qb, kb in itertools.product(BLOCKS, BLOCKS):
+        # a config is ranked by its time over the cells' mixed steps
+        # (RAGGED_SHAPES)
+        blocks = (tuple(int(b) for b in args.blocks.split(","))
+                  if args.blocks else BLOCKS)
+        for qb, kb in itertools.product(blocks, blocks):
             ms, out = run_inner(f"ragged:{qb}:{kb}:{args.kv_dtype}")
             eff = effective_spec(out, f"ragged:{qb}:{kb}")
             if ms is not None:

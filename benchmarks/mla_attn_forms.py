@@ -13,7 +13,12 @@ heads over one latent row of 512 + 64 lanes stored as 640, values the first
   query-key pair) for a 2048-token chunk over 8192 and 16384 cached rows,
   and for a cell's mixed step (31 decoding rows at 12.6 k of context and a
   320-token question behind a 12.6 k document), over ``q_rows`` x
-  ``kv_block`` (``ops/pallas/tuning.ragged_blocks``);
+  ``kv_block`` (``ops/pallas/tuning.ragged_blocks``); the mixed step twice:
+  the ragged kernel alone over all 32 sequences (as every mixed step ran
+  before PR 38) and as the dispatch serves it since
+  (``ops/attention._mixed_step_attention``: the 31 riding rows by the
+  decode kernel at the table's ``decode_mqa`` blocks, the question by the
+  ragged kernel at the swept pair);
 - ``decode``: ``paged_decode_attention`` for 32 rows at 12.6 k of context;
 - ``decompressed``: the same chunks with keys and values EXPANDED per head
   (c_kv W_uk -> [ctx, 64, 128] beside the shared rotary part, c_kv W_uv ->
@@ -26,6 +31,7 @@ what the mask leaves) and that as a share of the chip's peak
 """
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -46,6 +52,8 @@ def main():
     import jax
     import jax.numpy as jnp
     import numpy as np
+    from gllm_tpu.ops import attention
+    from gllm_tpu.ops.pallas import tuning
     from gllm_tpu.ops.pallas.decode_attention import paged_decode_attention
     from gllm_tpu.ops.pallas.ragged_attention import ragged_paged_attention
     from gllm_tpu.ops.pallas.tuning import get as tuned
@@ -111,14 +119,25 @@ def main():
     grid = ([(64, 32)] if small else
             [(r, b) for r in (512, 1024, 2048) for b in (128, 256, 512)])
 
-    def sweep_ragged(what, flop, q, pool, cu, kl, pt, **kw):
-        """One ragged call's time at every block pair of the grid."""
+    def split_call(q, k, cu, kl, pt, qb, kvb):
+        """The mixed step as the dispatch serves it, the ragged kernel at
+        ``qb`` x ``kvb`` (the decode kernel's blocks are the table's)."""
+        tuning.ragged_blocks = lambda *_: {"q_block": qb, "kv_block": kvb}
+        return attention._mixed_step_attention(
+            q, k, None, attention.AttentionMetadata(
+                cu, kl, pt, jnp.asarray(kl.shape[0], jnp.int32)),
+            None, None, scale=scale, interpret=small, v_dim=lora)
+
+    def sweep_ragged(what, flop, q, pool, cu, kl, pt, split=False, **kw):
+        """One ragged call's time at every block pair of the grid
+        (``split``: the call the dispatch makes of a mixed step)."""
         for q_rows, kvb in grid:
-            fn = jax.jit(lambda q, k, cu, kl, pt, qb=max(8, q_rows // H),
-                         kvb=kvb: ragged_paged_attention(
-                q, k, None, cu, kl, pt, scale=scale, q_block=qb,
-                kv_block=kvb, v_dim=lora, interpret=small),
-                compiler_options=opts)
+            call = split_call if split else (
+                lambda q, k, cu, kl, pt, qb, kvb: ragged_paged_attention(
+                    q, k, None, cu, kl, pt, scale=scale, q_block=qb,
+                    kv_block=kvb, v_dim=lora, interpret=small))
+            fn = jax.jit(functools.partial(call, qb=max(8, q_rows // H),
+                                           kvb=kvb), compiler_options=opts)
             try:
                 ms = timed(fn, q, pool, cu, kl, pt)
             except Exception as e:      # Mosaic refusing a block pair
@@ -140,12 +159,16 @@ def main():
     pool, pt = pool_for(rows, doc + question)
     T = rows - 1 + question
     q = jax.random.normal(key, (T, H, width), jnp.float32).astype(dtype)
-    sweep_ragged(
-        "ragged_absorbed_mixed_step",
-        ((rows - 1) * doc + causal_pairs(question, doc)) * H * pair_abs, q,
-        pool, jnp.asarray(list(range(rows)) + [T], jnp.int32),
-        jnp.asarray([doc] * (rows - 1) + [doc + question], jnp.int32), pt,
-        rows=rows, doc=doc, question=question)
+    # the ragged kernel alone over all the sequences (every mixed step
+    # before PR 38), then the step as the dispatch splits it
+    for what, split in (("ragged_absorbed_mixed_step", False),
+                        ("split_mixed_step", True)):
+        sweep_ragged(
+            what,
+            ((rows - 1) * doc + causal_pairs(question, doc)) * H * pair_abs,
+            q, pool, jnp.asarray(list(range(rows)) + [T], jnp.int32),
+            jnp.asarray([doc] * (rows - 1) + [doc + question], jnp.int32),
+            pt, split=split, rows=rows, doc=doc, question=question)
 
     # ---- the decode kernel ----------------------------------------------
     cfg = tuned("decode")

@@ -51,6 +51,15 @@ _M_STEPS = obs.counter("gllm_steps_total",
                        "engine iterations by step kind", ("kind",))
 _M_STEP_TOKENS = obs.counter("gllm_step_tokens_total",
                              "tokens computed by step kind", ("kind",))
+# A step with a row of more than one token (a joining prompt's chunk, a
+# spec-decode row) splits its attention on the Pallas path: the leading
+# one-token rows go to the decode kernel, the rest to the ragged kernel
+# (ops/attention._mixed_step_attention). Counted from the scheduled batch
+# by the rule the device reads off ``cu_q_lens``.
+_M_MIXED_ROWS = obs.counter(
+    "gllm_mixed_step_rows_total",
+    "sequences of mixed steps by the attention kernel that serves them "
+    "(decode|ragged)", ("kernel",))
 _M_DECODE_STEPS = obs.counter(
     "gllm_decode_steps_total",
     "decode steps by fusion (fused counts each sub-step of a block)",
@@ -1369,6 +1378,11 @@ class LLM:
         _M_STEP_LAT.observe(wall, kind=kind)
         _M_STEPS.inc(kind=kind)
         _M_STEP_TOKENS.inc(ev["tokens"], kind=kind)
+        if not fused and self.runner.fwd_attn_impl == "pallas":
+            for b in batches:
+                for kernel, n in zip(("decode", "ragged"),
+                                     b.mixed_step_rows or ()):
+                    _M_MIXED_ROWS.inc(n, kernel=kernel)
         if decode_steps:
             _M_DECODE_STEPS.inc(decode_steps,
                                 fused="true" if fused else "false")

@@ -13,7 +13,9 @@ Three implementations:
 - ``pallas``: pure-decode batches (max_q_len == 1) run the per-sequence
   decode kernel (gllm_tpu/ops/pallas/decode_attention.py); mixed/prefill
   batches run the ragged varlen kernel
-  (gllm_tpu/ops/pallas/ragged_attention.py). Both stream KV pages through
+  (gllm_tpu/ops/pallas/ragged_attention.py) for their chunks and the
+  decode kernel for the one-token rows that ride ahead of them
+  (``_mixed_step_attention``). Both stream KV pages through
   VMEM with double-buffered DMA; MLA passes ``v_cache=None`` so values are
   read as the latent prefix of each key block (one DMA stream).
 - ``unified``: the ``--unified-step`` path — EVERY paged step (decode,
@@ -270,27 +272,14 @@ def _paged_attention(
                 raise ValueError(
                     f"pallas decode path requires T == S, got T={q.shape[0]} "
                     f"S={metadata.kv_lens.shape[0]}")
-            from gllm_tpu.ops.pallas.decode_attention import (
-                paged_decode_attention)
-            from gllm_tpu.ops.pallas.tuning import decode_blocks
-            cfg = decode_blocks(k_cache.shape[2])
-            out = paged_decode_attention(
+            out = _decode_kernel(
                 q, k_cache, v_cache, metadata.kv_lens, metadata.page_table,
-                scale=scale, interpret=interpret, v_dim=v_dim,
-                kv_block=cfg["kv_block"],
-                group_size=int(cfg.get("group", 1)),
-                k_scale=k_scale, v_scale=v_scale)
+                k_scale, v_scale, scale=scale, interpret=interpret,
+                v_dim=v_dim)
         else:
-            from gllm_tpu.ops.pallas.ragged_attention import (
-                ragged_paged_attention)
-            from gllm_tpu.ops.pallas.tuning import ragged_blocks
-            blocks = ragged_blocks(q.shape[1], k_cache.shape[2])
-            out = ragged_paged_attention(
-                q, k_cache, v_cache, metadata.cu_q_lens, metadata.kv_lens,
-                metadata.page_table, scale=scale, interpret=interpret,
-                v_dim=v_dim, q_block=blocks["q_block"],
-                kv_block=blocks["kv_block"],
-                k_scale=k_scale, v_scale=v_scale)
+            out = _mixed_step_attention(
+                q, k_cache, v_cache, metadata, k_scale, v_scale,
+                scale=scale, interpret=interpret, v_dim=v_dim)
         if pack > 1:
             # The packed p·v_packed dot produced every lane block; keep
             # each head's own block (the rest mixed other heads' values).
@@ -301,6 +290,68 @@ def _paged_attention(
                 out, slot[None, :, None, None], axis=2)[:, :, 0]
         return out
     raise ValueError(f"unknown attention impl {impl!r}")
+
+
+def _decode_kernel(q, k_cache, v_cache, kv_lens, page_table, k_scale,
+                   v_scale, *, scale: float, interpret: bool, v_dim,
+                   name=None):
+    """The decode kernel over one query row a sequence, at the table's
+    blocks for the cache's KV heads."""
+    from gllm_tpu.ops.pallas.decode_attention import paged_decode_attention
+    from gllm_tpu.ops.pallas.tuning import decode_blocks
+    cfg = decode_blocks(k_cache.shape[2])
+    return paged_decode_attention(
+        q, k_cache, v_cache, kv_lens, page_table, scale=scale,
+        interpret=interpret, v_dim=v_dim, kv_block=cfg["kv_block"],
+        group_size=int(cfg.get("group", 1)), k_scale=k_scale,
+        v_scale=v_scale, name=name)
+
+
+# What the riding rows' call is named in the HLO and on the trace's
+# ``XLA Ops`` line. It starts with the ragged kernel's name because a mixed
+# step's attention is read as ONE piece of work there (perfbench's
+# ``^%ragged_paged_attention``: the chunk and the rows that ride, over all
+# the time spent on them, whatever implements them), and a step program is
+# classed as decode-only by holding ``paged_decode_attention``.
+DECODE_ROWS_NAME = "ragged_paged_attention_decode_rows"
+
+
+def _mixed_step_attention(q, k_cache, v_cache, md: AttentionMetadata,
+                          k_scale, v_scale, *, scale: float,
+                          interpret: bool, v_dim):
+    """A batch with ``max_q_len > 1``, split by what ``cu_q_lens`` shows:
+    the leading one-token sequences (the decode prefix: the scheduler packs
+    decoding rows first, and token ``s`` IS sequence ``s`` there) go to the
+    decode kernel, everything else (prefill chunks, a one-token sequence
+    behind a chunk, spec-decode rows of ``1 + k`` tokens) to the ragged
+    kernel, whose q block computes its every row against each sequence it
+    overlaps: 256 rows for one live one in the dense cell (PERF.md
+    section 6, PR 38). Both calls take the same page table; each has the
+    other's sequences at ``kv_len`` 0, which both kernels skip without a
+    fetch or a dot. The prefix length is traced: no compile axis."""
+    from gllm_tpu.ops.pallas.ragged_attention import (
+        _decode_prefix_len, ragged_paged_attention)
+    from gllm_tpu.ops.pallas.tuning import ragged_blocks
+    T, S = q.shape[0], md.kv_lens.shape[0]
+    riding = jnp.arange(S, dtype=jnp.int32) < _decode_prefix_len(
+        md.cu_q_lens, S)
+    rows = _decode_kernel(
+        q[:S] if T >= S else jnp.pad(q, ((0, S - T), (0, 0), (0, 0))),
+        k_cache, v_cache, jnp.where(riding, md.kv_lens, 0), md.page_table,
+        k_scale, v_scale, scale=scale, interpret=interpret, v_dim=v_dim,
+        name=DECODE_ROWS_NAME)
+    blocks = ragged_blocks(q.shape[1], k_cache.shape[2])
+    out = ragged_paged_attention(
+        q, k_cache, v_cache, md.cu_q_lens,
+        jnp.where(riding, 0, md.kv_lens), md.page_table, scale=scale,
+        interpret=interpret, v_dim=v_dim, q_block=blocks["q_block"],
+        kv_block=blocks["kv_block"], k_scale=k_scale, v_scale=v_scale)
+    # the riding rows are the first min(S, T) tokens at most: write those
+    # rows, not a select over all T (at 512 x 64 x 512 that is 100 MB of
+    # traffic a layer)
+    n = min(S, T)
+    head = jnp.where(riding[:n, None, None], rows[:n], out[:n])
+    return jax.lax.dynamic_update_slice(out, head, (0, 0, 0))
 
 
 def _xla_paged_attention(q, k_cache, v_cache, md: AttentionMetadata, *,

@@ -185,7 +185,7 @@ def _kernel(kv_lens_ref, pt_ref,            # scalar prefetch
 
 @functools.partial(jax.jit,
                    static_argnames=("scale", "kv_block", "interpret",
-                                    "v_dim", "group_size"))
+                                    "v_dim", "group_size", "name"))
 def paged_decode_attention(
     q: jnp.ndarray,            # [S, Hq, D]
     k_cache: jnp.ndarray,      # [num_pages, page_size, Hkv, D]
@@ -200,6 +200,8 @@ def paged_decode_attention(
     group_size: int = 1,       # seqs per grid program (see _kernel)
     k_scale: Optional[jnp.ndarray] = None,   # [num_pages, Hkv] f32 (int8)
     v_scale: Optional[jnp.ndarray] = None,
+    name: Optional[str] = None,   # the call's name in the HLO and the trace
+                                  # (None: this function's)
 ) -> jnp.ndarray:
     S, num_q_heads, head_dim = q.shape
     num_pages, page_size, num_kv_heads, _ = k_cache.shape
@@ -278,5 +280,6 @@ def paged_decode_attention(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name=name,
     )(*inputs)
     return out[:S] if s_pad != S else out

@@ -99,9 +99,10 @@ def ragged_blocks(num_q_heads: int, num_kv_heads: int) -> dict:
     """{"q_block", "kv_block"} of the ragged kernel at a geometry. With
     several KV heads the table's pair as swept (``ragged``). Under one KV
     head (MLA: 64 or 128 query heads over a latent row of 640 lanes) the
-    q block is what keeps ``q_rows`` rows in VMEM: the table's 512 x 128,
-    swept at 8 KV heads of 128, is refused there by Mosaic (128.29 MB of
-    VMEM at 64 heads x 640 lanes; tests/test_tpu_compile.py pins it)."""
+    q block is what keeps ``q_rows`` rows in VMEM: a pair swept at 8 KV
+    heads of 128 (512 x 128, the ``ragged`` entry until PR 38) is refused
+    there by Mosaic (128.29 MB of VMEM at 64 heads x 640 lanes;
+    tests/test_tpu_compile.py pins it)."""
     if num_kv_heads != 1:
         return get("ragged")
     cfg = get("ragged_mqa")

@@ -407,7 +407,8 @@ def resolve_attn_impl(impl: str, cfg: ModelConfig, tp: int, pack: int,
                 "paged_decode_attention (kv_block %d, group %d); mixed "
                 "steps -> pallas ragged_paged_attention (q_block %d, "
                 "kv_block %d: the blocks follow heads x lanes, "
-                "ops/pallas/tuning.ragged_blocks)",
+                "ops/pallas/tuning.ragged_blocks) and, for the rows that "
+                "decode beside the chunks, the decode kernel",
                 cfg.num_heads, cfg.mla_cache_width, cfg.kv_lora_rank,
                 dec["kv_block"], int(dec.get("group", 1)),
                 blocks["q_block"], blocks["kv_block"])

@@ -179,6 +179,21 @@ class ScheduledBatch:
         return sum(1 for it in self.items if it.num_new_tokens == 1
                    and not it.seq.is_prefilling)
 
+    @property
+    def mixed_step_rows(self) -> Optional[Tuple[int, int]]:
+        """(rows the decode kernel serves, rows the ragged kernel serves)
+        of a step that holds a row of more than one token; None for a step
+        of one-token rows, which is the decode kernel's whole. The host's
+        reading of the rule ``ops/attention._mixed_step_attention`` reads
+        off ``cu_q_lens``: the leading items of one new token and no
+        drafts ride; a one-token item behind a longer one does not."""
+        rows = [it.num_new_tokens + len(it.draft_tokens)
+                for it in self.items]
+        if max(rows) == 1:
+            return None
+        riding = next(i for i, n in enumerate(rows) if n > 1)
+        return riding, len(rows) - riding
+
 
 @dataclasses.dataclass
 class SeqOutput:

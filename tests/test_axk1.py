@@ -200,9 +200,9 @@ def test_blocks_follow_the_geometry_and_are_announced(monkeypatch, caplog):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     # several KV heads: the table's pair as swept; one KV head: rows
     assert tuning.ragged_blocks(32, 8) == tuning.get("ragged")
-    assert tuning.ragged_blocks(64, 1) == {"q_block": 8, "kv_block": 512}
+    assert tuning.ragged_blocks(64, 1) == {"q_block": 16, "kv_block": 512}
     assert tuning.ragged_blocks(128, 1) == {"q_block": 8, "kv_block": 512}
-    assert tuning.ragged_blocks(16, 1)["q_block"] == 32
+    assert tuning.ragged_blocks(16, 1)["q_block"] == 64
     assert tuning.decode_blocks(8) == tuning.get("decode")
     assert tuning.decode_blocks(1)["kv_block"] == 512
     cfg = from_hf_config(_config_file())
@@ -213,8 +213,9 @@ def test_blocks_follow_the_geometry_and_are_announced(monkeypatch, caplog):
     assert len(said) == 1, caplog.text
     assert "decode steps -> pallas paged_decode_attention (kv_block 512" \
         in said[0]
-    assert "mixed steps -> pallas ragged_paged_attention (q_block 8, " \
+    assert "mixed steps -> pallas ragged_paged_attention (q_block 16, " \
         "kv_block 512" in said[0]
+    assert said[0].endswith("the decode kernel")
     # a latent rank that is no multiple of 128 lanes: XLA, and it says why
     caplog.clear()
     import dataclasses

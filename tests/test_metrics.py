@@ -134,6 +134,40 @@ def test_prometheus_rendering():
 
 # ---- steptrace ring -------------------------------------------------------
 
+@pytest.mark.parametrize("rows,impl,want", [
+    # 3 decoding rows, a chunk, and a final chunk of one token behind it
+    ([1, 1, 1, 7, 1], "pallas", (3, 2)),
+    # a spec-decode row (1 + 2 drafts) is the ragged kernel's
+    ([1, (1, 2), 1], "pallas", (1, 2)),
+    # a decode-only step is the decode kernel's whole: nothing to count
+    ([1, 1, 1], "pallas", (0, 0)),
+    # no kernel serves a row where attention is not on the Pallas path
+    ([1, 1, 1, 7, 1], "xla", (0, 0)),
+], ids=["mixed", "drafts", "decode_only", "xla"])
+def test_mixed_step_rows_counter(rows, impl, want):
+    """``gllm_mixed_step_rows_total``: a mixed step's sequences by the
+    kernel the dispatch gives them, by the rule the device reads off
+    ``cu_q_lens`` (ops/attention._mixed_step_attention)."""
+    from types import SimpleNamespace
+    from gllm_tpu.engine import llm
+    from gllm_tpu.scheduler import ScheduledBatch, ScheduledSeq
+
+    items = []
+    for r in rows:
+        n, drafts = r if isinstance(r, tuple) else (r, 0)
+        items.append(ScheduledSeq(SimpleNamespace(seq_id=len(items)), n, 0,
+                                  draft_tokens=(0,) * drafts))
+    batch = ScheduledBatch(items)
+    engine = SimpleNamespace(runner=SimpleNamespace(fwd_attn_impl=impl),
+                             tracing=False)
+    read = lambda: tuple(llm._M_MIXED_ROWS.get(kernel=k)
+                         for k in ("decode", "ragged"))
+    before = read()
+    llm.LLM._emit_step(engine, "prefill", {"tokens": batch.total_tokens},
+                       [batch], {"t_enter": 0.0}, 0.0, 0.0, 0.001)
+    assert tuple(a - b for a, b in zip(read(), before)) == want
+
+
 def test_steptrace_ring_rollover():
     tr = StepTrace(capacity=8)
     for i in range(20):

@@ -235,6 +235,24 @@ def has_kernel(compiled) -> bool:
     return "tpu_custom_call" in compiled.as_text()
 
 
+def attention_calls(compiled) -> list:
+    """The attention kernels' custom calls in the optimized HLO, by the
+    names the trace's ``XLA Ops`` line shows them under (an operation's
+    name there is its whole HLO line)."""
+    return sorted(set(re.findall(
+        r"^\s*(?:ROOT )?%(\w*attention\w*?)(?:\.\d+)? = \S+ custom-call\(",
+        compiled.as_text(), re.M)))
+
+
+# What the benchmark's readers hold a mixed step to (perfbench
+# ``trace_patterns``: ``^%ragged_paged_attention`` is a mixed step's
+# attention, ``^%paged_decode_attention`` marks a decode-only step): the
+# chunk's call and the riding rows' call, the decode kernel under a name
+# of the ragged kernel's family (ops/attention.DECODE_ROWS_NAME).
+MIXED_STEP_CALLS = ["ragged_paged_attention",
+                    "ragged_paged_attention_decode_rows"]
+
+
 # ---- kernels alone ---------------------------------------------------------
 
 def _kernel_args(topo, *, S, T, Hq=32, Hkv=8, D=64, pack=2, P=8192,
@@ -339,6 +357,7 @@ def test_prefill_step_compiles_for_v5e(topo, on_tpu, monkeypatch):
     runner = make_runner(smoke_model_cfg(), topo, monkeypatch=monkeypatch)
     c = compile_of(runner.step_async, prefill_batch(runner, 16))
     assert has_kernel(c.compiled)
+    assert attention_calls(c.compiled) == MIXED_STEP_CALLS
 
 
 # the kinds of step `qwen3-4b.reason` runs, at its pool of 2800 pages
@@ -582,12 +601,13 @@ def test_hybrid_steps_compile_for_v5e_with_attention_on_pallas(
     # the pools as the TPU stores them are what the runner sizes them as
     kv_args = sum(np.prod(x.shape) * x.dtype.itemsize
                   for x in (runner.kv.k, runner.kv.v))
-    for name, batch, bound in (
-            ("decode", decode_batch(runner, 32, 64), 0.5 * GiB),
+    for name, batch, bound, calls in (
+            ("decode", decode_batch(runner, 32, 64), 0.5 * GiB,
+             ["paged_decode_attention"]),
             ("mixed", prefill_batch(runner, 512, ndecode=31, npages=64),
-             1.25 * GiB)):
+             1.25 * GiB, MIXED_STEP_CALLS)):
         c = compile_of(runner.step_async, _with_slots(batch))
-        assert has_kernel(c.compiled)
+        assert attention_calls(c.compiled) == calls
         mem = c.compiled.memory_analysis()
         print(f"\n[compile] olmo-hybrid {name}: {c.seconds:.1f}s, "
               f"{mem.argument_size_in_bytes / GiB:.2f} GiB of arguments, "
@@ -811,12 +831,12 @@ def test_mla_ragged_kernel_compiles_for_v5e_with_blocks_from_the_geometry(
         topo, on_tpu):
     """The ragged kernel at the cell's mixed step (512 token slots, 64
     heads over one KV head of 640 lanes, values the first 512) with the
-    blocks ``tuning.ragged_blocks`` gives that geometry: q blocks of 8
-    tokens = 512 rows, kv blocks of 512 tokens."""
-    from gllm_tpu.ops.pallas.tuning import ragged_blocks
+    blocks ``tuning.ragged_blocks`` gives that geometry: q blocks of 16
+    tokens = 1024 rows, kv blocks of 512 tokens."""
+    from gllm_tpu.ops.pallas.tuning import get as tuned, ragged_blocks
     blocks = ragged_blocks(64, 1)
-    assert blocks == {"q_block": 8, "kv_block": 512}
-    assert ragged_blocks(32, 8) == {"q_block": 512, "kv_block": 128}
+    assert blocks == {"q_block": 16, "kv_block": 512}
+    assert ragged_blocks(32, 8) == tuned("ragged")
     t0 = time.monotonic()
     compiled = _mla_ragged(topo, T=512, **blocks)
     print(f"\n[compile] a.x-k1 ragged kernel, 512 slots: "
@@ -828,18 +848,15 @@ def test_mla_ragged_kernel_compiles_for_v5e_with_blocks_from_the_geometry(
 @pytest.mark.slow
 def test_mla_ragged_kernel_is_refused_at_the_blocks_swept_for_8_kv_heads(
         topo, on_tpu):
-    """Why the blocks follow the geometry: the table's ``ragged`` pair (512
-    x 128, swept at 8 KV heads of 128; 128 x 128 after
-    ``effective_q_block``) makes windows of [128, 64, 640] in and [128, 64,
-    512] out under one KV head of 640 lanes, and Mosaic runs out of VMEM
-    (128.29 MB of 128 with float32 operands, before PR 37). If this starts
-    passing, the kernel has shrunk and ``ragged_mqa`` may take larger
-    blocks."""
-    from gllm_tpu.ops.pallas.tuning import get as tuned
-    blocks = tuned("ragged")
-    assert (blocks["q_block"], blocks["kv_block"]) == (512, 128)
+    """Why the blocks follow the geometry: the pair the ``ragged`` entry
+    held when latent attention came (512 x 128, swept at 8 KV heads of 128;
+    128 x 128 after ``effective_q_block``) makes windows of [128, 64, 640]
+    in and [128, 64, 512] out under one KV head of 640 lanes, and Mosaic
+    runs out of VMEM (128.29 MB of 128 with float32 operands, before
+    PR 37). If this starts passing, the kernel has shrunk and
+    ``ragged_mqa`` may take larger blocks."""
     with pytest.raises(Exception, match="(?i)vmem|memory"):
-        _mla_ragged(topo, **blocks)
+        _mla_ragged(topo, q_block=512, kv_block=128)
 
 
 @pytest.mark.slow
@@ -886,8 +903,6 @@ def test_axk1_mixed_step_compiles_for_v5e(topo, on_tpu, monkeypatch, tokens):
           f"{c.seconds:.1f}s, "
           f"{mem.argument_size_in_bytes / GiB:.2f} GiB of arguments, "
           f"{mem.temp_size_in_bytes / GiB:.3f} GiB temp")
-    text = c.compiled.as_text()
-    assert "%ragged_paged_attention" in text
-    assert "%paged_decode_attention" not in text
+    assert attention_calls(c.compiled) == MIXED_STEP_CALLS
     assert mem.temp_size_in_bytes < 2.0 * GiB
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14 * GiB
