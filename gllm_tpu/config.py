@@ -288,8 +288,11 @@ class EngineConfig:
     # request-scoped span trees (gllm_tpu/obs/spans.py) + the per-step
     # phase fields on steptrace events, exported via GET /trace and
     # ``obs.dump --format chrome``. Default ON — pure host dict work off
-    # the device path; ``--no-tracing`` disables the span layer for this
-    # engine (token streams are byte-identical either way).
+    # the device path (a span a prompt chunk and the roll-ups at a
+    # request's finish; nothing per decoding row or step);
+    # ``--no-tracing`` disables the span layer for this engine (token
+    # streams are byte-identical either way; the steptrace ring, its
+    # ``first_token`` event a request included, stays on).
     tracing: bool = True
     # ---- request-lifecycle robustness (docs/robustness.md) ----
     # Admission control: cap the serving engine's intake queue and the

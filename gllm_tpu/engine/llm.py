@@ -67,8 +67,14 @@ _M_DECODE_STEPS = obs.counter(
 # Request-latency histograms (OpenAI-serving vocabulary): TTFT = arrival
 # to first sampled token, TPOT = mean inter-token time after the first,
 # ITL = per-token inter-arrival, queue = arrival to first schedule.
+# "Arrival" is Sequence.arrival_time: the allocation in submit, on the
+# HANDLER thread, before the sequence goes onto the intake queue; the
+# first token is stamped at the collect (_observe_outputs), before the
+# hand-over to the handler threads.
 _M_TTFT = obs.histogram("gllm_request_ttft_seconds",
-                        "time to first token per request")
+                        "submit (handler thread, before the intake "
+                        "queue) to the collect that brought the first "
+                        "token (before the hand-over to the handler)")
 _M_TPOT = obs.histogram("gllm_request_tpot_seconds",
                         "mean time per output token after the first",
                         buckets=obs.FAST_LATENCY_BUCKETS)
@@ -78,7 +84,9 @@ _M_ITL = obs.histogram("gllm_request_itl_seconds",
 _M_E2E = obs.histogram("gllm_request_e2e_seconds",
                        "arrival-to-finish latency per request")
 _M_QUEUE = obs.histogram("gllm_request_queue_seconds",
-                         "arrival-to-first-schedule wait per request")
+                         "submit (handler thread, before the intake "
+                         "queue) to first schedule: holds the intake "
+                         "wait gllm_http_admit_lag_seconds also holds")
 _M_FINISHED = obs.counter("gllm_requests_finished_total",
                           "requests finished by reason", ("reason",))
 # Overlap decode-chain breaks by reason (docs/overlap_scheduling.md):
@@ -1259,38 +1267,21 @@ class LLM:
         phases["t_enter"] = sched_ph.t0
         return InFlight(batch, handle, time.monotonic(), phases, **flags)
 
-    def _record_spans(self, batch, t_dispatch: float, now: float,
-                      extra: Optional[dict] = None) -> None:
-        """Request-scoped span events for one collected step: each live
-        sequence in the batch gets one child span [dispatch → collect]
-        — ``prefill_chunk``, ``decode_step``, or ``decode_chain`` for a
-        fused block (obs/spans.py; no-op for requests the span tracker
-        never opened)."""
-        from gllm_tpu.sequence import HOLE_SEQ_ID
+    def _record_spans(self, batch, t_dispatch: float, now: float) -> None:
+        """Request-scoped span events for one collected step that holds
+        a prompt chunk: each row that carries one gets a
+        ``prefill_chunk`` child [dispatch → collect] (obs/spans.py; no-op
+        for requests the span tracker never opened). The rows that
+        decode get nothing: their steps are on the ring once, as step
+        events, and a request's tree gets one ``decode`` child at its
+        finish."""
         dur = (now - t_dispatch) * 1e3
-        if isinstance(batch, list):
-            meta = {"k": len(batch)}
-            if extra and extra.get("k_exec") is not None:
-                meta["k_exec"] = extra["k_exec"]
-                meta["dead_substeps"] = extra.get("dead_substeps")
-            self.spans.event_many(
-                [it.seq.seq_id for it in batch[0].items
-                 if it.seq.seq_id != HOLE_SEQ_ID],
-                "decode_chain", t_dispatch, dur, meta)
-            return
-        decode_rows = []
         for it in batch.items:
-            sid = it.seq.seq_id
-            if sid == HOLE_SEQ_ID:
-                continue
             if (it.num_new_tokens > 1
                     or it.computed_before < it.seq.prompt_len):
-                self.spans.event(sid, "prefill_chunk", t_dispatch, dur,
-                            tokens=it.num_new_tokens)
-            else:
-                decode_rows.append(sid)
-        if decode_rows:
-            self.spans.event_many(decode_rows, "decode_step", t_dispatch, dur)
+                self.spans.event(it.seq.seq_id, "prefill_chunk",
+                                 t_dispatch, dur,
+                                 tokens=it.num_new_tokens)
 
     def _record_step(self, batch, t0: float, t_dispatch: float,
                      extra: Optional[dict], phases: dict) -> None:
@@ -1342,7 +1333,7 @@ class LLM:
         self._emit_step(kind, ev, [batch], phases, t0, t_dispatch, now,
                         decode_steps=(len(batch) if fused
                                       else int(decode_only)),
-                        fused=fused, span_extra=extra)
+                        fused=fused)
 
     def _record_step_dp(self, live, t0: float, t_dispatch: float,
                         phases: dict, inflight: Optional[int] = None) -> None:
@@ -1365,15 +1356,16 @@ class LLM:
 
     def _emit_step(self, kind: str, ev: dict, batches, phases, t0: float,
                    t_dispatch: float, now: float, decode_steps: int = 0,
-                   fused: bool = False,
-                   span_extra: Optional[dict] = None) -> None:
+                   fused: bool = False) -> None:
         """The tail every step path shares (single runner, sync dp, dp
         super-step — one implementation so they cannot drift): the
         latency histogram, per-kind counters, the steptrace event with
         the engine-loop phase breakdown (the entry's own dict from
         dispatch time plus what the thread measured since — the
         collect's ``wait`` / ``readback``; docs/observability.md#tracing),
-        request spans."""
+        and the ``prefill_chunk`` spans of a step that holds a prompt
+        chunk (``decode_steps`` is 0 there: a decode-only step and a
+        fused block make no call into ``SpanTrace``)."""
         wall = now - t0
         _M_STEP_LAT.observe(wall, kind=kind)
         _M_STEPS.inc(kind=kind)
@@ -1393,9 +1385,9 @@ class LLM:
         ev.update(spans.step_phases(merged))
         ev["step_wall_ms"] = round((now - phases["t_enter"]) * 1e3, 3)
         TRACE.record(kind, **ev)
-        if self.tracing:
+        if self.tracing and not decode_steps:
             for b in batches:
-                self._record_spans(b, t_dispatch, now, span_extra)
+                self._record_spans(b, t_dispatch, now)
 
     def _observe_outputs(self, outs) -> None:
         """Per-request latency bookkeeping over one iteration's outputs
@@ -1415,6 +1407,13 @@ class LLM:
                         if seq.first_sched_time:
                             _M_QUEUE.observe(seq.first_sched_time
                                              - seq.arrival_time)
+                    if not seq.submitted_t:
+                        # no serving engine submitted it (generate):
+                        # its last stage ends here. A served request's
+                        # event is written by the handler thread that
+                        # takes its first chunk (deliver_output)
+                        seq.first_token_out = True
+                        spans.FirstToken(seq).record()
                 elif seq.last_token_time:
                     _M_ITL.observe(now - seq.last_token_time)
                 seq.last_token_time = now
@@ -1427,16 +1426,9 @@ class LLM:
                     _M_TPOT.observe((seq.last_token_time
                                      - seq.first_token_time) / (n - 1))
                 if self.tracing:
-                    # close the request's span tree: accumulated
-                    # detokenize/stream wall as one rolled-up child,
-                    # then the finish (obs/spans.py)
-                    detok = getattr(seq, "_detok_s", 0.0)
-                    if detok:
-                        self.spans.event(seq.seq_id, "detokenize",
-                                    now - detok, detok * 1e3,
-                                    accumulated=True)
-                    self.spans.finish(seq.seq_id, out.finish_reason, now,
-                                 output_tokens=n)
+                    # close the request's span tree with its roll-ups
+                    # (one decode child, the detokenize wall)
+                    self.spans.close(seq, out.finish_reason, now)
 
     def _schedule_multi(self, prev_batch, multi: int):
         """Chain up to ``multi`` decode steps off ``prev_batch`` for one

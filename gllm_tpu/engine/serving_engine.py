@@ -59,7 +59,7 @@ from typing import List, Optional
 from gllm_tpu import faults
 from gllm_tpu.engine.llm import LLM
 from gllm_tpu.obs import metrics as obs
-from gllm_tpu.obs.spans import phase
+from gllm_tpu.obs.spans import FirstToken, phase
 from gllm_tpu.obs.steptrace import TRACE
 from gllm_tpu.sampling_params import SamplingParams
 
@@ -163,6 +163,10 @@ class StreamChunk:
     # token in EMIT_LAG_EVERY (0.0 on the others): the handler thread
     # observes gllm_http_emit_lag_seconds against it
     t_deliver: float = 0.0
+    # on the chunk of a request's FIRST token: its stamps so far
+    # (obs/spans.FirstToken). The thread that takes the chunk ends the
+    # request's last stage and records its ``first_token`` event
+    first_token: Optional[FirstToken] = None
 
 
 class RequestHandle:
@@ -232,9 +236,14 @@ def deliver_output(llm: LLM, out, handle: RequestHandle,
         text = full[emitted.get(out.seq.seq_id, 0):]
         emitted[out.seq.seq_id] = len(full)
     if out.new_token_id is not None or out.finish_reason:
-        lp = None
+        lp = first = None
         if out.new_token_id is not None and out.seq.output_logprobs:
             lp = out.seq.output_logprobs[-1]
+        if out.new_token_id is not None and not out.seq.first_token_out:
+            out.seq.first_token_out = True
+            first = FirstToken(
+                out.seq, now or time.monotonic(),
+                llm.spans if getattr(llm, "tracing", False) else None)
         handle.chunks.put(StreamChunk(
             token_id=out.new_token_id,
             text=text,
@@ -246,7 +255,8 @@ def deliver_output(llm: LLM, out, handle: RequestHandle,
                              if out.finish_reason else None),
             final_text=final_text,
             t_deliver=(now if out.seq.num_output_tokens
-                       % EMIT_LAG_EVERY == 1 else 0.0)))
+                       % EMIT_LAG_EVERY == 1 else 0.0),
+            first_token=first))
     if out.finish_reason is not None:
         emitted.pop(out.seq.seq_id, None)
 
@@ -529,9 +539,17 @@ class ServingEngine:
                     target_dp=target_dp)
             _M_SUBMITTED.inc()
             _M_ACTIVE.set(len(self._handles))
+        self._enqueue(seq)
+        return handle
+
+    def _enqueue(self, seq) -> None:
+        """Onto the intake queue, stamped: where the handler's stage
+        (``parse``) ends and the wait for the engine loop's pass
+        (``intake``) begins. The stamp precedes the put, so the engine
+        thread never reads a sequence without it."""
+        seq.submitted_t = time.monotonic()
         self._intake.put(seq)
         self._wake.set()
-        return handle
 
     def _alloc_committed(self, llm, prompt_ids, committed_ids,
                          sampling_params):
@@ -605,8 +623,7 @@ class ServingEngine:
                     self._journal.commit(seq.seq_id, t)
             _M_SUBMITTED.inc()
             _M_ACTIVE.set(len(self._handles))
-        self._intake.put(seq)
-        self._wake.set()
+        self._enqueue(seq)
         return handle
 
     def push_prefix(self, prompt_ids: List[int], target_addr: str,
@@ -863,9 +880,11 @@ class ServingEngine:
                     llm.add_seq(seq)
             except ValueError as e:
                 self._deliver_error(seq.seq_id, "error", str(e))
-            received_t = getattr(seq, "received_t", None)
-            if received_t is not None:
-                _M_ADMIT_LAG.observe(time.monotonic() - received_t)
+            # where ``intake`` ends (obs/spans.first_token_stamps); the
+            # admit lag is read off the same instant
+            seq.admitted_t = time.monotonic()
+            if seq.received_t is not None:
+                _M_ADMIT_LAG.observe(seq.admitted_t - seq.received_t)
             drained = True
 
     def _deliver(self, llm, outputs) -> None:
@@ -1156,7 +1175,7 @@ class ServingEngine:
                 if self._journal is not None:
                     self._journal.adopt(seq.seq_id, entry)
                 _M_ACTIVE.set(len(self._handles))
-            self._intake.put(seq)
+            self._enqueue(seq)
             _M_REPLAYED.inc(outcome="replayed")
             replayed += 1
         # fresh loop under the bumped generation

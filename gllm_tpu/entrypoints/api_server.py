@@ -240,11 +240,18 @@ class Handler(BaseHTTPRequestHandler):
     def _sse(self, obj, chunk=None) -> None:
         """One SSE event, flushed. ``chunk``: the StreamChunk it carries;
         its ``t_deliver`` stamp (set by deliver_output on the engine
-        thread) to this flush is the emit lag of the token."""
+        thread) to this flush is the emit lag of the token. The flush of
+        a request's first token ends its last stage (``emit``): this
+        thread writes its ``first_token`` event."""
         self.wfile.write(b"data: " + json.dumps(obj).encode() + b"\n\n")
         self.wfile.flush()
-        if chunk is not None and chunk.t_deliver:
-            _M_EMIT_LAG.observe(time.monotonic() - chunk.t_deliver)
+        if chunk is not None and (chunk.t_deliver
+                                  or chunk.first_token is not None):
+            now = time.monotonic()
+            if chunk.t_deliver:
+                _M_EMIT_LAG.observe(now - chunk.t_deliver)
+            if chunk.first_token is not None:
+                chunk.first_token.record(now)
 
     # ---- routes -----------------------------------------------------------
 
@@ -748,6 +755,10 @@ class Handler(BaseHTTPRequestHandler):
             err_ev = None
             try:
                 for chunk_out in handle:
+                    if chunk_out.first_token is not None:
+                        # its text may be held back as potential markup:
+                        # the stages end where the chunk is taken
+                        chunk_out.first_token.record()
                     emit(*stream.feed(chunk_out.text or ""))
                     fin = chunk_out.finish_reason or fin
                     if fin in ("error", "abort", "deadline") and (
@@ -870,6 +881,10 @@ class Handler(BaseHTTPRequestHandler):
         usage = proto.usage_dict(0, 0)
         lp, plp, final_text = [], None, None
         for chunk in handle:
+            if chunk.first_token is not None:
+                # nothing is flushed for the token of an unstreamed
+                # reply: its stages end here, with no ``emit``
+                chunk.first_token.record()
             if chunk.text:
                 text_parts.append(chunk.text)
             if chunk.token_id is not None and chunk.logprob is not None:
@@ -1401,7 +1416,8 @@ def make_parser() -> argparse.ArgumentParser:
     p.add_argument("--no-tracing", action="store_true",
                    help="disable the request-span tracing layer "
                         "(GET /trace request tracks; the engine-phase "
-                        "attribution on /steptrace stays on). Token "
+                        "attribution and the first_token events on "
+                        "/steptrace stay on). Token "
                         "streams are byte-identical either way "
                         "(docs/observability.md#tracing)")
     p.add_argument("--skip-warmup", action="store_true",

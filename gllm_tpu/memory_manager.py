@@ -26,6 +26,7 @@ telemetry happens in the runner, which passes ``num_pages`` here.
 from __future__ import annotations
 
 import hashlib
+import time
 from collections import OrderedDict
 from typing import Dict, List, Optional, Tuple
 
@@ -405,6 +406,7 @@ class PrefixMemoryManager(MemoryManager):
         keeps). Claimed pages get ref_count++ and enter seq.page_table.
         """
         assert seq.num_computed_tokens == 0 and not seq.page_table
+        t_probe = time.monotonic()
         self.query_tokens += seq.prompt_len
         _M_PFX_QUERY.inc(seq.prompt_len)
         matched_digest = b"root"
@@ -469,7 +471,8 @@ class PrefixMemoryManager(MemoryManager):
         for t in page_tiers[:matched]:
             pages[t] = pages.get(t, 0) + 1
         TRACE.record("prefix", query_tokens=seq.prompt_len,
-                     hit_tokens=seq.num_computed_tokens, pages=pages)
+                     hit_tokens=seq.num_computed_tokens, pages=pages,
+                     ms=round((time.monotonic() - t_probe) * 1e3, 3))
         return seq.num_computed_tokens
 
     def register_computed_pages(self, seq: Sequence, extra_key: bytes = b"") -> None:

@@ -8,9 +8,18 @@ attribution stack; what the DEVICE did in the same milliseconds is read
 from a profiler capture, on whose clock the phases below also sit
 (perfbench/host_gaps.py):
 
-- :class:`SpanTrace` — one span tree per request
-  (queued → prefill chunks → decode chains → detokenize → finish),
-  completed trees held in a bounded ring like the steptrace;
+- :func:`first_token_stamps` / :class:`FirstToken` — a request's way
+  to its first token as ONE list of ``time.monotonic()`` stamps, each
+  taken where a stage ends (body read, intake put, add_seq, first
+  schedule, first token, hand-over, flush); the stages are the
+  differences of consecutive stamps, so they add up to the whole. One
+  ``first_token`` event a request on the steptrace ring carries them
+  (always on: per request, not per token or step);
+- :class:`SpanTrace` — one span tree per request, built from the same
+  stamps (parse → intake → queued → one child a prefill chunk →
+  handover → emit → one rolled-up decode → detokenize → finish),
+  completed trees held in a bounded ring like the steptrace. A step
+  that carries no prompt chunk makes no call into it;
 - :class:`phase` — the one timing primitive of the engine loop: a
   context manager that adds its wall time to the open step's phase dict
   (the steptrace ``ph`` field) and, only while a profiler capture runs,
@@ -38,21 +47,32 @@ from collections import deque
 from typing import Dict, Iterable, List, Optional
 
 from gllm_tpu.obs import metrics as _metrics
+from gllm_tpu.obs.steptrace import TRACE
 
 __all__ = ["SpanTrace", "SPANS", "chrome_trace", "SPAN_PHASES",
            "ENGINE_PHASES", "HOST_PHASES", "phase", "take_phases",
-           "step_phases", "set_capture", "capturing"]
+           "step_phases", "set_capture", "capturing",
+           "FIRST_TOKEN_STAGES", "first_token_stamps", "FirstToken"]
 
+# The stages of a request's way to its first token, each named for the
+# stamp that ends it (docs/observability.md stage table): the
+# ``first_token`` event carries ``<stage>_ms``.
+FIRST_TOKEN_STAGES = ("parse", "intake", "queue", "compute", "handover",
+                      "emit")
 # Span phase taxonomy (docs/observability.md span-phase catalog): the
-# child spans a request tree may carry. ``queued`` = arrival → first
-# schedule; ``prefill_chunk`` = one scheduled prompt chunk (dispatch →
-# collect); ``decode_step`` = one UNfused decode dispatch carrying the
-# request; ``decode_chain`` = one fused multi-step block (k sub-steps,
-# ``k_exec`` executed under on-device finish); ``detokenize`` =
-# accumulated host detokenization/stream time (one rolled-up span at
-# finish).
-SPAN_PHASES = ("queued", "prefill_chunk", "decode_step", "decode_chain",
-               "detokenize")
+# child spans a request tree may carry, in the order a request passes
+# them. ``parse`` / ``intake`` / ``queued`` / ``handover`` / ``emit`` are
+# the stages above, from the same stamps (``compute`` is drawn as its
+# parts: one ``prefill_chunk`` = one step that carried a chunk of the
+# prompt, dispatch → collect); ``decode`` = first token → finish, ONE
+# rolled-up span at finish (``tokens``, ``tpot_ms``; what each decoding
+# step did is on its ``decode`` / ``fused_block`` step event);
+# ``detokenize`` = accumulated host detokenization/stream time (one
+# rolled-up span at finish).
+SPAN_PHASES = ("parse", "intake", "queued", "prefill_chunk", "handover",
+               "emit", "decode", "detokenize")
+_TREE_CHILD = {"parse": "parse", "intake": "intake", "queue": "queued",
+               "handover": "handover", "emit": "emit"}
 
 # Engine-loop phases (docs/observability.md phase catalog): the closed
 # vocabulary of what the engine thread does with its time, each opened
@@ -235,6 +255,84 @@ def step_phases(phases: dict) -> dict:
     return out
 
 
+# ---- a request's way to its first token ------------------------------------
+
+def first_token_stamps(seq, t_deliver: float = 0.0) -> list:
+    """The ONE list of stamps a request has on its way to its first
+    token, as ``[(name, time.monotonic())]`` in the order they were
+    taken: the first entry is where the account starts (``received``:
+    the front end's body read; ``arrival``: the allocation, for a
+    request no serving engine submitted), every later one is named for
+    the stage of :data:`FIRST_TOKEN_STAGES` that ends at it (the last,
+    ``emit``, is the recording thread's to add: :class:`FirstToken`). A stamp
+    not taken is left out, so a stage not passed is absent and the next
+    one runs from the last stamp there is: the differences of
+    consecutive entries always add up to last minus first."""
+    if seq.submitted_t:
+        marks = (("received", seq.received_t),
+                 ("parse", seq.submitted_t), ("intake", seq.admitted_t))
+    else:
+        marks = (("arrival", seq.arrival_time),)
+    marks += (("queue", seq.first_sched_time),
+              ("compute", seq.first_token_time),
+              ("handover", t_deliver))
+    return [(name, t) for name, t in marks if t]
+
+
+def _stage_spans(stamps: list) -> list:
+    """``(stage, start, seconds)`` of every consecutive pair."""
+    return [(name, t0, t1 - t0)
+            for (_, t0), (name, t1) in zip(stamps, stamps[1:])]
+
+
+class FirstToken:
+    """A request's stamps up to its first token and the counts beside
+    them, frozen where they are taken (``deliver_output`` on the engine
+    thread for a served request, whose first chunk carries this to the
+    thread that takes it; ``_observe_outputs`` for one no serving engine
+    submitted). ``record`` adds the flush (``t_emit``; none where nothing
+    is flushed for the token: an unstreamed reply) and writes the
+    request's ONE ``first_token`` event onto the steptrace ring; with
+    ``spans``, the ``handover`` and ``emit`` children onto its tree as
+    well. ``chunks`` = steps that carried a chunk of the prompt (the one
+    that sampled the token included), ``cached_tokens`` = the prefix hit
+    of its last admission."""
+
+    __slots__ = ("stamps", "fields", "spans")
+
+    def __init__(self, seq, t_deliver: float = 0.0,
+                 spans: Optional["SpanTrace"] = None):
+        self.stamps = first_token_stamps(seq, t_deliver)
+        self.fields = {"seq_id": seq.seq_id,
+                       "prompt_tokens": seq.prompt_len,
+                       "cached_tokens": seq.num_cached_tokens,
+                       "chunks": seq.prefill_chunks + 1,
+                       "passes_waited": seq.passes_waited}
+        self.spans = spans
+
+    def record(self, t_emit: float = 0.0) -> Optional[dict]:
+        """Write the event, once; returns its fields."""
+        stamps, self.stamps = self.stamps, None
+        if stamps is None:
+            return None
+        if t_emit:
+            stamps.append(("emit", t_emit))
+        ev = self.fields
+        for name, _, sec in _stage_spans(stamps):
+            ev[name + "_ms"] = round(sec * 1e3, 3)
+        ev["total_ms"] = round((stamps[-1][1] - stamps[0][1]) * 1e3, 3)
+        t0, at = TRACE.t0, dict(stamps)
+        for key, name in (("t_received", "received"),
+                          ("t_first_sched", "queue"),
+                          ("t_token", "compute")):
+            if name in at:
+                ev[key] = round(at[name] - t0, 6)
+        TRACE.record("first_token", **ev)
+        if self.spans is not None:
+            self.spans.stages(ev["seq_id"], stamps, ("handover", "emit"))
+        return ev
+
+
 class SpanTrace:
     """Bounded per-request span trees.
 
@@ -243,7 +341,8 @@ class SpanTrace:
     ``finish`` moves a tree into a fixed-capacity completed ring.
     A tree caps its child-phase list at ``max_phases``; later events
     roll up into per-phase ``{n, ms}`` aggregates instead of growing
-    without bound (a 10k-token decode must not hold 10k dicts).
+    without bound (a long prompt in small chunks can reach it; the
+    decoding steps were never the tree's to hold one by one).
     """
 
     def __init__(self, capacity: Optional[int] = None,
@@ -269,23 +368,41 @@ class SpanTrace:
 
     # ---- lifecycle ---------------------------------------------------------
 
-    def begin(self, seq_id: int, arrival_t: float, admitted_t: float,
+    def begin(self, seq_id: int, stamps: list,
               prompt_tokens: int = 0) -> None:
-        """Open a request tree at admission; records the ``queued``
-        phase [arrival → first schedule]. Idempotent per seq_id."""
+        """Open a request tree at its first schedule, from the stamps
+        it carries by then (:func:`first_token_stamps`): the tree starts
+        at the first one and gets a child a stage passed (``parse``,
+        ``intake``, ``queued``). Idempotent per seq_id."""
         with self._lock:
             if seq_id in self._open:
                 return
             if len(self._open) >= self.max_open:
                 self.untracked += 1
                 return
-            rec = {"seq_id": seq_id, "t0": arrival_t, "t1": None,
+            rec = {"seq_id": seq_id, "t0": stamps[0][1], "t1": None,
                    "reason": None, "prompt_tokens": prompt_tokens,
                    "output_tokens": 0, "phases": [], "agg": {}}
             self._open[seq_id] = rec
-        if admitted_t > arrival_t:
-            self.event(seq_id, "queued", arrival_t,
-                       (admitted_t - arrival_t) * 1e3)
+            self._stages_locked(rec, stamps, _TREE_CHILD)
+
+    def stages(self, seq_id: int, stamps: list, only) -> None:
+        """The stages ``only`` names, of a request's stamps, as child
+        spans: onto its open tree, or onto its finished one (the first
+        token of a short request leaves after its tree has closed)."""
+        with self._lock:
+            rec = self._open.get(seq_id)
+            if rec is None:
+                rec = next((r for r in reversed(self._done)
+                            if r["seq_id"] == seq_id), None)
+            if rec is not None:
+                self._stages_locked(rec, stamps, only)
+
+    def _stages_locked(self, rec, stamps, only) -> None:
+        for name, t, sec in _stage_spans(stamps):
+            if name in only and sec > 0:
+                self._append_locked(rec, _TREE_CHILD[name], t, sec * 1e3,
+                                    None)
 
     def event(self, seq_id: int, ph: str, t: float, dur_ms: float,
               **meta) -> None:
@@ -293,24 +410,14 @@ class SpanTrace:
         to an open tree; silently dropped when the request is
         untracked (holes, bounded-out requests, tracing off)."""
         with self._lock:
-            self._event_locked(seq_id, ph, t, dur_ms, meta)
+            rec = self._open.get(seq_id)
+            if rec is not None:
+                self._append_locked(rec, ph, t, dur_ms, meta)
 
-    def event_many(self, seq_ids, ph: str, t: float, dur_ms: float,
-                   meta: Optional[dict] = None) -> None:
-        """One identical child span for many requests (a decode batch's
-        rows all share one dispatch→collect interval) under a SINGLE
-        lock acquisition — the engine hot path records one of these per
-        step, so per-row locking would be the dominant tracing cost."""
-        with self._lock:
-            for sid in seq_ids:
-                self._event_locked(sid, ph, t, dur_ms, meta)
-
-    def _event_locked(self, seq_id, ph, t, dur_ms, meta) -> None:
-        rec = self._open.get(seq_id)
-        if rec is None:
-            return
+    def _append_locked(self, rec, ph, t, dur_ms, meta) -> None:
         if len(rec["phases"]) >= self.max_phases:
-            agg = rec["agg"].setdefault(ph, {"n": 0, "ms": 0.0})
+            agg = rec.setdefault("agg", {}).setdefault(
+                ph, {"n": 0, "ms": 0.0})
             agg["n"] += 1
             agg["ms"] += dur_ms
             return
@@ -318,6 +425,29 @@ class SpanTrace:
         if meta:
             ev.update(meta)
         rec["phases"].append(ev)
+
+    def close(self, seq, reason: str, t: float) -> Optional[dict]:
+        """Close ``seq``'s tree with the roll-ups computed here and now:
+        ONE ``decode`` child from its first token to ``t`` (``tokens``
+        after the first, ``tpot_ms``), the accumulated ``detokenize``
+        wall, then :meth:`finish`."""
+        n = seq.num_output_tokens
+        with self._lock:
+            rec = self._open.get(seq.seq_id)
+            if rec is not None:
+                t_first = seq.first_token_time
+                if n > 1 and t_first:
+                    ms = (t - t_first) * 1e3
+                    self._append_locked(
+                        rec, "decode", t_first, ms,
+                        {"tokens": n - 1,
+                         "tpot_ms": round(ms / (n - 1), 3)})
+                detok = getattr(seq, "_detok_s", 0.0)
+                if detok:
+                    self._append_locked(rec, "detokenize", t - detok,
+                                        detok * 1e3,
+                                        {"accumulated": True})
+        return self.finish(seq.seq_id, reason, t, output_tokens=n)
 
     def finish(self, seq_id: int, reason: str, t: float,
                output_tokens: int = 0, **meta) -> Optional[dict]:

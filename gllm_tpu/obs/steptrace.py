@@ -66,9 +66,11 @@ __all__ = ["StepTrace", "TRACE", "summarize"]
 #                  rescheduled
 #   prefix       - one prefix-cache admission probe
 #                  (PrefixMemoryManager.match_prefix): ``query_tokens``,
-#                  ``hit_tokens``, and ``pages`` — claimed page counts
+#                  ``hit_tokens``, ``pages`` — claimed page counts
 #                  keyed by the serving tier (hbm/host/disk/peer,
-#                  docs/kv_offload.md)
+#                  docs/kv_offload.md) — and ``ms``, the wall time of
+#                  the call on the engine thread (hashing the prompt's
+#                  pages and claiming the hits)
 #   loop_stall   - the pipelined engine loop failed to run further ahead
 #                  (config.pipelined_loop); ``reason``: readback (the
 #                  next step needs host-committed state), rebuild
@@ -76,6 +78,22 @@ __all__ = ["StepTrace", "TRACE", "summarize"]
 #                  entries — ``invalidated`` counts them), pages (no KV
 #                  room to speculate), depth (the overlap_depth cap was
 #                  binding); ``depth`` = in-flight entries at the stall
+#   first_token  - one a request that produced a token: its way to the
+#                  first one as stages on one clock (obs/spans.py
+#                  first_token_stamps) — ``parse_ms`` / ``intake_ms`` /
+#                  ``queue_ms`` / ``compute_ms`` / ``handover_ms`` /
+#                  ``emit_ms`` (a stage not passed is absent) adding up
+#                  to ``total_ms``; ``seq_id``, ``prompt_tokens``,
+#                  ``cached_tokens`` (the prefix hit), ``chunks`` (steps
+#                  that carried a chunk of its prompt), ``passes_waited``
+#                  (admission passes that went by without it), and
+#                  ``t_received`` / ``t_first_sched`` / ``t_token`` in
+#                  the ring's seconds: the step events between the
+#                  latter two are the ones that carried it. Recorded by
+#                  the thread that ends the request's last stage (the
+#                  handler that flushes or takes its first chunk; the
+#                  engine thread for LLM.generate), so its ``t`` is that
+#                  stage's end
 #
 # Step events (prefill/decode/fused_block) additionally carry the
 # performance-attribution fields (docs/observability.md#tracing):
@@ -88,7 +106,8 @@ __all__ = ["StepTrace", "TRACE", "summarize"]
 # cache).
 STEP_KINDS = ("prefill", "decode", "unified_step", "fused_block",
               "pp_stage", "compile", "chain_break", "fault",
-              "quarantine", "prefix", "loop_stall", "recovery")
+              "quarantine", "prefix", "loop_stall", "recovery",
+              "first_token")
 # recovery (config.engine_recovery, docs/robustness.md#recovery-
 # lifecycle) event phases: begin (latch handed to the supervisor),
 # partition (streams split into replayable vs dropped), rebuild_fail
@@ -218,7 +237,11 @@ def summarize(events: List[dict]) -> dict:
     total_tokens = dispatches = 0
     # prefix-cache attribution: per-window hit rate + tier split
     pfx_queries = pfx_query_tokens = pfx_hit_tokens = 0
+    pfx_ms = 0.0
     pfx_pages: Dict[str, int] = {}
+    # first_token events: requests, and the sum of every ``*_ms`` field
+    first_tokens = 0
+    first_ms: Dict[str, float] = {}
     # engine-loop phase breakdown (events carrying ``ph`` —
     # docs/observability.md#tracing)
     host_phase: Dict[str, float] = {}
@@ -230,8 +253,15 @@ def summarize(events: List[dict]) -> dict:
             pfx_queries += 1
             pfx_query_tokens += int(e.get("query_tokens", 0))
             pfx_hit_tokens += int(e.get("hit_tokens", 0))
+            pfx_ms += float(e.get("ms", 0.0))
             for tier, n in (e.get("pages") or {}).items():
                 pfx_pages[tier] = pfx_pages.get(tier, 0) + int(n)
+            continue
+        if k == "first_token":
+            first_tokens += 1
+            for name, v in e.items():
+                if name.endswith("_ms"):
+                    first_ms[name] = first_ms.get(name, 0.0) + float(v)
             continue
         if k == "compile":
             compiles += 1
@@ -345,7 +375,18 @@ def summarize(events: List[dict]) -> dict:
             "hit_rate": (round(pfx_hit_tokens / pfx_query_tokens, 4)
                          if pfx_query_tokens else 0.0),
             "pages_by_tier": pfx_pages,
+            # the engine thread's wall inside the probes of the window
+            "match_ms": round(pfx_ms, 3),
         } if pfx_queries else None),
+        # requests whose first token left in the window, and each stage
+        # of their way to it: its sum over ALL of them (a stage a request
+        # did not pass adds nothing), so the stages' means add up to
+        # ``total_ms``'s (None when the window saw no such event)
+        "first_token": ({
+            "requests": first_tokens,
+            "mean_ms": {k: round(v / first_tokens, 3)
+                        for k, v in first_ms.items()},
+        } if first_tokens else None),
         # ---- performance attribution (docs/observability.md#tracing;
         # None/{} when the window's events predate the tracing layer) --
         # host wall by engine-loop phase over the window

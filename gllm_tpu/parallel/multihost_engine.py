@@ -397,10 +397,13 @@ class MultihostEngine:
     # ---- host-0 frontend side ---------------------------------------------
 
     def submit(self, token_ids: List[int], sampling_params,
-               on_register=None, mm_input: Optional[dict] = None) -> int:
+               on_register=None, mm_input: Optional[dict] = None,
+               received_t: Optional[float] = None) -> int:
         """``on_register(seq_id)`` runs under the intake lock BEFORE the
         request becomes visible to the engine loop — callers register
-        their output handles there so no chunk can be dropped."""
+        their output handles there so no chunk can be dropped.
+        ``received_t``: the front end's body read, the first stamp of
+        the request's way to its first token (obs/spans.py)."""
         assert self.is_host0
         mm_state = None
         if mm_input:
@@ -415,6 +418,8 @@ class MultihostEngine:
             seq.mm = mm_state
             if on_register is not None:
                 on_register(seq.seq_id)
+            seq.received_t = received_t
+            seq.submitted_t = time.monotonic()
             self._pending.append(RequestDesc(
                 seq.seq_id, list(token_ids),
                 dataclasses.asdict(sampling_params), mm=mm_wire))
@@ -576,6 +581,9 @@ class MultihostEngine:
                                             **mm)
             try:
                 llm.add_seq(seq)
+                # where the tick's broadcast ends: host 0's sequences
+                # carry submit's stamp, this is their ``intake``
+                seq.admitted_t = time.monotonic()
             except ValueError as e:
                 # deterministic on every host (same validation) — only
                 # host 0 reports
@@ -747,9 +755,9 @@ class MultihostServingEngine:
         # target_dp (per-DP-endpoint pinning) is accepted for interface
         # parity with ServingEngine but ignored: the multihost plane runs
         # dp=1 per host group (replica routing happens in the engine loop).
-        # received_t likewise: gllm_http_admit_lag_seconds is observed in
-        # ServingEngine's intake drain; here intake rides the tick
-        # broadcast and has no such point
+        # received_t: gllm_http_admit_lag_seconds is observed in
+        # ServingEngine's intake drain and has no such point here (intake
+        # rides the tick broadcast); the request's stamps take it
         if disagg_items:
             # coordinator runs on host 0; the admit reaches every host as
             # a tick event (gate-B flips ride the blob channel)
@@ -778,7 +786,8 @@ class MultihostServingEngine:
             self._handles[sid] = box["handle"]
 
         self.engine.submit(token_ids, sampling_params,
-                           on_register=on_register, mm_input=mm_input)
+                           on_register=on_register, mm_input=mm_input,
+                           received_t=received_t)
         return box["handle"]
 
     def abort(self, seq_id: int) -> None:

@@ -37,7 +37,7 @@ from typing import Deque, List, Optional, Tuple
 from gllm_tpu.config import EngineConfig
 from gllm_tpu.memory_manager import MemoryManager
 from gllm_tpu.obs import metrics as obs
-from gllm_tpu.obs.spans import SPANS
+from gllm_tpu.obs.spans import SPANS, first_token_stamps
 from gllm_tpu.sequence import (HOLE_SEQ_ID, Sequence, SequenceStatus,
                                make_hole_seq)
 from gllm_tpu.utils import bucket_size, cdiv
@@ -275,6 +275,11 @@ class Scheduler:
         # — a shared ring would merge co-resident engines' trees); the
         # global is the standalone-scheduler fallback.
         self.spans = SPANS
+        # Admission passes so far (_schedule_prefill calls): read at
+        # add_seq and at a sequence's first schedule, the difference is
+        # the passes that went by without admitting it (the first_token
+        # event's ``passes_waited``).
+        self.passes = 0
 
     # ---- intake -----------------------------------------------------------
 
@@ -295,6 +300,7 @@ class Scheduler:
                 f"request needs {need} KV pages but the pool has only "
                 f"{self.mm.allocator.num_total}")
         seq.status = SequenceStatus.WAITING
+        seq.passes_waited = self.passes     # the counter, until admitted
         self.waiting.append(seq)
 
     def abort_seq(self, seq_id: int) -> None:
@@ -552,6 +558,7 @@ class Scheduler:
         speculative batch invalidates."""
         protect = {it.seq.seq_id for it in items}
         max_seqs = self.config.max_num_seqs
+        self.passes += 1
 
         # 1) continue partially prefilled running seqs (already admitted).
         for seq in [s for s in self.running
@@ -637,13 +644,13 @@ class Scheduler:
                 # queue-time anchor (request histograms, engine/llm.py);
                 # a preempted seq keeps its original admission time
                 seq.first_sched_time = time.monotonic()
+                seq.passes_waited = self.passes - seq.passes_waited - 1
                 if getattr(self.config, "tracing", True):
-                    # open the request's span tree (obs/spans.py): the
-                    # "queued" phase is arrival → this first schedule
-                    self.spans.begin(seq.seq_id,
-                                seq.arrival_time or seq.first_sched_time,
-                                seq.first_sched_time,
-                                prompt_tokens=seq.prompt_len)
+                    # open the request's span tree (obs/spans.py) with
+                    # the stages it has been through: parse, intake and
+                    # queued, from the stamps it carries
+                    self.spans.begin(seq.seq_id, first_token_stamps(seq),
+                                     prompt_tokens=seq.prompt_len)
             _M_ADMIT.inc()
             self.running.append(seq)
             items.append(ScheduledSeq(seq, n, seq.num_computed_tokens))
@@ -1177,6 +1184,7 @@ class Scheduler:
                 continue  # handled in _process_aborts
             finish: Optional[str] = None
             if not it.samples:
+                seq.prefill_chunks += 1
                 seq.num_computed_tokens = (it.computed_before
                                            + it.num_new_tokens)
                 self.mm.register_computed_pages(seq)
@@ -1302,8 +1310,7 @@ class Scheduler:
             # span tree here (first close wins: the serving engine may
             # already have recorded a more specific reason, e.g.
             # "deadline")
-            self.spans.finish(seq.seq_id, "abort",
-                              time.monotonic())
+            self.spans.close(seq, "abort", time.monotonic())
 
     def _process_aborts(self) -> None:
         if not self._aborted_ids:

@@ -81,6 +81,25 @@ class Sequence:
         self.first_sched_time = 0.0
         self.first_token_time = 0.0
         self.last_token_time = 0.0
+        # The other stamps of a request's way to its first token
+        # (obs/spans.py first_token_stamps; all time.monotonic()): the
+        # front end's body read (None where no front end read one), the
+        # handler's put onto the intake queue, the engine thread's
+        # add_seq in the intake drain. A request that no serving engine
+        # submitted (LLM.generate) keeps the latter two at 0.0.
+        self.received_t: Optional[float] = None
+        self.submitted_t = 0.0
+        self.admitted_t = 0.0
+        # Steps that carried a chunk of the prompt and sampled nothing
+        # (the step that brings the first token is one more), and the
+        # scheduler's admission passes that went by without admitting it
+        # (Scheduler.passes at add_seq, then the difference at the first
+        # schedule).
+        self.prefill_chunks = 0
+        self.passes_waited = 0
+        # its first_token event is written, or rides its first chunk to
+        # the thread that will write it: exactly one a request
+        self.first_token_out = False
 
         self.status = SequenceStatus.WAITING
         self.num_computed_tokens = 0

@@ -66,6 +66,16 @@ _XFAIL = {
         "which ISSUE 37's configuration serves with; a benchmark PR has to "
         "take it off the list (tests/perfbench/test_manifest.py may not be "
         "edited by the PR that adds the cell)",
+    # ISSUE 39 adds ``kv.prefix_match_p50_ms`` with ``a.x-k1.docqa`` as its
+    # one cell (the only cell whose server probes a prefix cache, so the
+    # only one where its reader finds something), and the accepted test
+    # pins the metrics that list that cell alone to PR 37's eight.
+    "test_kernels_axk1.py::"
+    "test_every_new_metric_is_this_cells_alone_and_has_its_reader":
+        "the accepted test pins the metrics that list a.x-k1.docqa alone "
+        "to PR 37's eight; ISSUE 39 adds kv.prefix_match_p50_ms for that "
+        "cell alone; a benchmark PR has to widen the list "
+        "(tests/perfbench/test_kernels_axk1.py may not be edited here)",
 }
 
 

@@ -85,18 +85,20 @@ def test_every_documented_metric_is_registered():
 #
 # Same no-drift contract for the trace vocabularies: every
 # ``TRACE.record("<kind>", ...)`` call site in gllm_tpu/ must have a row
-# in the doc's event-kind catalog (and vice versa), and every
-# ``SPANS.event(..., "<phase>", ...)``-recorded span phase a row in the
-# span-phase catalog. The catalogs are marker-delimited tables so the
-# doc can mention kind-words in prose without tripping the guard.
+# in the doc's event-kind catalog (and vice versa), and every span phase
+# a request tree may carry (spans.SPAN_PHASES; a literal
+# ``SPANS.event(..., "<phase>", ...)`` call site must use one of them) a
+# row in the span-phase catalog. The catalogs are marker-delimited tables
+# so the doc can mention kind-words in prose without tripping the guard.
 
 _TRACE_RE = re.compile(r"\bTRACE\.record\(\s*\n?\s*['\"]([a-z_]+)['\"]")
-# SPANS.event(sid, "phase", ...) / SPANS.event_many(ids, "phase", ...)
-# — also matches the tracker-internal self.event(...) call that records
-# the "queued" phase in spans.py. The first argument may be a bracketed
-# list comprehension (no commas/parens), so [^,()]+ spans it.
+# SPANS.event(sid, "phase", ...) and the tracker-internal
+# self._append_locked(rec, "phase", ...) of the roll-ups in spans.py.
+# The children that come from a request's stamps (parse, intake, queued,
+# handover, emit) are named by a table there, not by a call site.
 _SPAN_RE = re.compile(
-    r"\.event(?:_many)?\(\s*\n?\s*[^,()]+,\s*\n?\s*['\"]([a-z_]+)['\"]")
+    r"\.(?:event|_append_locked)\(\s*\n?\s*[^,()]+,\s*\n?\s*"
+    r"['\"]([a-z_]+)['\"]")
 
 
 def _scan(regex):
@@ -147,19 +149,32 @@ def test_every_trace_kind_is_documented_and_vice_versa():
 
 
 def test_every_span_phase_is_documented_and_vice_versa():
+    from gllm_tpu.obs.spans import _TREE_CHILD, SPAN_PHASES
     recorded = _scan(_SPAN_RE)
     assert recorded, "source scan found no SPANS.event call sites"
+    stray = sorted(set(recorded) - set(SPAN_PHASES))
+    assert not stray, (
+        "SPANS.event call sites using phases absent from "
+        f"spans.SPAN_PHASES (extend the taxonomy): {stray}")
+    # every phase of the taxonomy is written somewhere: by a call site,
+    # or from a request's stamps
+    unwritten = sorted(set(SPAN_PHASES) - set(recorded)
+                       - set(_TREE_CHILD.values()))
+    assert not unwritten, f"span phases nothing records: {unwritten}"
     documented = _catalog("span-phase-catalog")
-    missing = sorted(set(recorded) - documented)
+    missing = sorted(set(SPAN_PHASES) - documented)
     assert not missing, (
         "span phases with no docs/observability.md span-phase-catalog "
-        "row: "
-        + ", ".join(f"{p} ({os.path.relpath(recorded[p], REPO)})"
-                    for p in missing))
-    ghosts = sorted(documented - set(recorded))
+        f"row: {missing}")
+    ghosts = sorted(documented - set(SPAN_PHASES))
     assert not ghosts, (
-        "span-phase-catalog rows no SPANS.event call site emits "
+        "span-phase-catalog rows outside spans.SPAN_PHASES "
         f"(fix the doc): {ghosts}")
+    # the retired per-step children stay out of the package
+    for name in ("decode_step", "decode_chain", "event_many"):
+        assert name not in recorded
+        assert name not in open(os.path.join(
+            PKG, "obs", "spans.py")).read()
 
 
 # ---- engine-loop phases (ISSUE 24) -----------------------------------------

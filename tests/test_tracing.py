@@ -2,6 +2,11 @@
 
 - SpanTrace lifecycle units: begin/event/finish, ring bound/eviction,
   open-bound untracking, phase-cap rollup, idempotent close;
+- a request's way to its first token (ISSUE 39): the stages are the
+  consecutive differences of one list of stamps, exactly one
+  ``first_token`` event a request on every path, the tree built from
+  the same stamps, and no call into SpanTrace from a decode-only step
+  (pure host: a scripted engine over the real scheduler, no jit);
 - summarize() attribution math (host_ms_by_phase, blocked_ms_by_phase,
   first-use wall) on synthetic events;
 - the phase clock (obs/spans.phase): the closed vocabulary in ``ph`` on
@@ -13,8 +18,8 @@
 - engine e2e on a dummy-weight CPU model: step events carry the phase
   breakdown, the phase-sum ≈ step-wall invariant holds on the
   synchronous engine, span trees complete for every request
-  (queued → prefill → decode → finish) and fused chains record
-  decode_chain spans;
+  (queued → prefill chunks → one rolled-up decode → finish), fused
+  chains included;
 - terminal paths (abort / deadline / quarantine) close spans;
 - tracing=False: zero spans recorded, token streams byte-identical;
 - /trace + /steptrace?kind= + POST /profile HTTP surface;
@@ -51,14 +56,20 @@ def _clean_spans():
 
 # ---- SpanTrace units -------------------------------------------------------
 
+def _stamps(arrival_t, first_sched_t):
+    """What first_token_stamps gives at the first schedule of a request
+    that no serving engine submitted."""
+    return [("arrival", arrival_t), ("queue", first_sched_t)]
+
+
 def test_span_lifecycle_and_ring_bound():
     tr = SpanTrace(capacity=4, max_open=8, max_phases=64)
-    tr.begin(1, arrival_t=10.0, admitted_t=10.5, prompt_tokens=7)
+    tr.begin(1, _stamps(10.0, 10.5), prompt_tokens=7)
     assert tr.open_count == 1
-    tr.begin(1, arrival_t=99.0, admitted_t=99.5)     # idempotent
+    tr.begin(1, _stamps(99.0, 99.5))                 # idempotent
     assert tr.open_count == 1
     tr.event(1, "prefill_chunk", 10.6, 3.0, tokens=7)
-    tr.event(999, "decode_step", 0.0, 1.0)           # untracked: no-op
+    tr.event(999, "prefill_chunk", 0.0, 1.0)         # untracked: no-op
     rec = tr.finish(1, "stop", 11.0, output_tokens=3)
     assert rec["reason"] == "stop" and rec["output_tokens"] == 3
     assert rec["phases"][0]["ph"] == "queued"
@@ -68,7 +79,7 @@ def test_span_lifecycle_and_ring_bound():
     assert tr.finish(1, "stop", 12.0) is None        # second close: no-op
     # ring eviction: capacity 4 keeps the newest 4 completed trees
     for sid in range(2, 9):
-        tr.begin(sid, sid * 1.0, sid * 1.0 + 0.1)
+        tr.begin(sid, _stamps(sid * 1.0, sid * 1.0 + 0.1))
         tr.finish(sid, "length", sid * 1.0 + 1)
     assert [r["seq_id"] for r in tr.spans()] == [5, 6, 7, 8]
     assert tr.dropped == 4
@@ -76,16 +87,17 @@ def test_span_lifecycle_and_ring_bound():
 
 def test_span_open_bound_and_phase_cap():
     tr = SpanTrace(capacity=8, max_open=2, max_phases=3)
-    tr.begin(1, 0.0, 0.1)
-    tr.begin(2, 0.0, 0.1)
-    tr.begin(3, 0.0, 0.1)                            # over the bound
+    tr.begin(1, _stamps(1.0, 1.1))
+    tr.begin(2, _stamps(1.0, 1.1))
+    tr.begin(3, _stamps(1.0, 1.1))                   # over the bound
     assert tr.open_count == 2 and tr.untracked == 1
-    # phase cap: later events roll up into per-phase aggregates
+    # phase cap (a long prompt in small chunks can reach it): later
+    # events roll up into per-phase aggregates
     for i in range(6):
-        tr.event(1, "decode_step", float(i), 2.0)
+        tr.event(1, "prefill_chunk", float(i), 2.0)
     rec = tr.finish(1, "length", 10.0)
-    assert len(rec["phases"]) == 3                   # queued + 2 decode
-    agg = rec["agg"]["decode_step"]
+    assert len(rec["phases"]) == 3                   # queued + 2 chunks
+    agg = rec["agg"]["prefill_chunk"]
     assert agg["n"] == 4 and agg["ms"] == pytest.approx(8.0)
 
 
@@ -139,6 +151,362 @@ def test_summarize_without_attribution_fields_is_none():
     assert s["blocked_ms_by_phase"] is None and s["first_use_ms"] == 0.0
 
 
+# ---- a request's way to its first token (ISSUE 39) -------------------------
+#
+# Pure host: ``HostEngine`` is LLM's own record keeping (_record_step,
+# _emit_step, _record_spans, _observe_outputs, borrowed as they are) over
+# the real Scheduler and memory manager, with a scripted 'device' that
+# samples token 7; ServingEngine, deliver_output and the api_server's
+# handler methods are the real ones. No jit, no socket.
+
+def _spy_on(tr):
+    """Log every call into a SpanTrace instance (its public methods)."""
+    calls = []
+    for name in ("begin", "stages", "event", "close", "finish"):
+        def wrapped(*a, _real=getattr(tr, name), _name=name, **kw):
+            calls.append((_name, a, kw))
+            return _real(*a, **kw)
+        setattr(tr, name, wrapped)
+    return calls
+
+
+class HostEngine:
+    tokenizer = None
+    model_cfg = None
+    unified = False
+
+    def __init__(self, maxp=6, tracing=True, prefix=False):
+        from gllm_tpu.engine.llm import LLM
+        from gllm_tpu.memory_manager import make_memory_manager
+        from gllm_tpu.scheduler import Scheduler
+        cls = type(self)
+        for name in ("_record_step", "_emit_step", "_record_spans",
+                     "_observe_outputs", "_allocate_seq"):
+            if not hasattr(cls, name):
+                setattr(cls, name, getattr(LLM, name))
+        self.config = EngineConfig(
+            max_model_len=256, max_num_seqs=8, tracing=tracing,
+            scheduler=SchedulerConfig(max_prefill_tokens=maxp,
+                                      min_prefill_tokens=2,
+                                      max_decode_seqs=8),
+            cache=CacheConfig(page_size=4, num_pages=64,
+                              enable_prefix_caching=prefix))
+        self.scheduler = Scheduler(
+            self.config, make_memory_manager(64, 4, prefix))
+        self.tracing = tracing
+        self.spans = self.scheduler.spans = SpanTrace()
+        self.runner = type("R", (), {"fwd_attn_impl": "xla"})()
+        self._in_flight = []
+        self._next_seq_id = 0
+        self.steps = []                 # kind of every step, in order
+        self.on_step = None             # hook(n) after step n's collect
+
+    def add_seq(self, seq):
+        self.scheduler.add_seq(seq)
+
+    def abort(self, seq_id):
+        self.scheduler.abort_seq(seq_id)
+
+    @property
+    def has_unfinished(self):
+        return self.scheduler.has_unfinished
+
+    def close(self):
+        pass
+
+    def step(self, after_dispatch=None):
+        sched_ph = phase("schedule").start()
+        batch = self.scheduler.schedule_once()
+        sched_ph.stop()
+        if batch is None:
+            return []
+        phases = take_phases()
+        phases["t_enter"] = sched_ph.t0
+        t_dispatch = time.monotonic()
+        if after_dispatch is not None:
+            after_dispatch()
+        self.steps.append("decode" if batch.num_decode == batch.num_seqs
+                          else "prefill")
+        self._record_step(batch, time.monotonic(), t_dispatch, None, phases)
+        outs = self.scheduler.process_output(batch, [7] * batch.num_seqs,
+                                             ())
+        self._observe_outputs(outs)
+        if self.on_step is not None:
+            self.on_step(len(self.steps))
+        return outs
+
+    def generate(self, prompts, max_tokens=4):
+        seqs = [self._allocate_seq(p, SamplingParams(
+            max_tokens=max_tokens, **GREEDY)) for p in prompts]
+        for s in seqs:
+            self.add_seq(s)
+        while self.has_unfinished:
+            self.step()
+        return seqs
+
+
+GREEDY = dict(temperature=0.0, ignore_eos=True)
+STAGES = ("parse_ms", "intake_ms", "queue_ms", "compute_ms", "handover_ms",
+          "emit_ms")
+
+
+def _first_tokens(mark):
+    from gllm_tpu.obs.steptrace import TRACE
+    return TRACE.events(since=mark, kinds=["first_token"])
+
+
+def _handler(engine):
+    """An api_server Handler with no socket behind it: what it writes
+    goes into a buffer."""
+    import io
+    import types
+    from gllm_tpu.entrypoints.api_server import Handler
+    h = Handler.__new__(Handler)
+    h.wfile = io.BytesIO()
+    h.state = types.SimpleNamespace(engine=engine)
+    return h
+
+
+def test_stages_are_consecutive_differences_of_one_list_of_stamps():
+    from gllm_tpu.obs.steptrace import TRACE
+    from gllm_tpu.sequence import Sequence
+    seq = Sequence(5, list(range(9)), SamplingParams(max_tokens=3))
+    seq.arrival_time = 100.0011         # inside parse: not a stamp
+    (seq.received_t, seq.submitted_t, seq.admitted_t, seq.first_sched_time,
+     seq.first_token_time) = 100.0, 100.0023, 100.0131, 100.0134, 100.0519
+    seq.num_cached_tokens, seq.prefill_chunks, seq.passes_waited = 4, 1, 2
+    stamps = obs_spans.first_token_stamps(seq, 100.0541)
+    assert [n for n, _ in stamps] == ["received", "parse", "intake",
+                                      "queue", "compute", "handover"]
+    assert [t for _, t in stamps] == sorted(t for _, t in stamps)
+    mark = TRACE.mark()
+    first = obs_spans.FirstToken(seq, 100.0541)
+    ev = first.record(100.0549)
+    assert first.record(100.06) is None             # once
+    (on_ring,) = _first_tokens(mark)
+    assert {k: on_ring[k] for k in ev} == ev
+    want = dict(parse_ms=2.3, intake_ms=10.8, queue_ms=0.3,
+                compute_ms=38.5, handover_ms=2.2, emit_ms=0.8)
+    assert {k: ev[k] for k in STAGES} == pytest.approx(want, abs=1e-6)
+    assert ev["total_ms"] == pytest.approx(54.9, abs=1e-6)
+    assert sum(ev[k] for k in STAGES) == pytest.approx(ev["total_ms"],
+                                                       abs=1e-6)
+    assert (ev["seq_id"], ev["prompt_tokens"], ev["cached_tokens"],
+            ev["chunks"], ev["passes_waited"]) == (5, 9, 4, 2, 2)
+    # the three instants lie on the ring's clock
+    assert ev["t_first_sched"] - ev["t_received"] \
+        == pytest.approx(0.0134, abs=2e-6)
+    assert ev["t_token"] == pytest.approx(100.0519 - TRACE.t0, abs=1e-6)
+    # a stamp not taken: its stage is absent (never 0), the next one runs
+    # from the last stamp there is, and the whole still adds up
+    seq.received_t = None
+    seq.admitted_t = 0.0
+    ev = obs_spans.FirstToken(seq, 100.0541).record()
+    assert {k for k in ev if k.endswith("_ms")} == {
+        "queue_ms", "compute_ms", "handover_ms", "total_ms"}
+    assert "t_received" not in ev
+    assert ev["queue_ms"] == pytest.approx(11.1, abs=1e-6)
+    assert ev["total_ms"] == pytest.approx(51.8, abs=1e-6)
+
+
+def test_offline_requests_record_at_the_collect_without_a_front():
+    from gllm_tpu.obs.steptrace import TRACE
+    eng = HostEngine(maxp=6)
+    mark = TRACE.mark()
+    seqs = eng.generate([list(range(10)), [3, 4]], max_tokens=4)
+    evs = {e["seq_id"]: e for e in _first_tokens(mark)}
+    assert sorted(evs) == [s.seq_id for s in seqs]      # one a request
+    long, short = (evs[s.seq_id] for s in seqs)
+    for e in (long, short):
+        assert {k for k in e if k.endswith("_ms")} == {
+            "queue_ms", "compute_ms", "total_ms"}
+        assert e["total_ms"] == pytest.approx(
+            e["queue_ms"] + e["compute_ms"], abs=2e-3)
+        assert "t_received" not in e and e["t_token"] > e["t_first_sched"]
+    # ten tokens through a budget of six: two steps carried its prompt;
+    # the short one waited out the pass that had no budget left for it
+    assert (long["chunks"], long["prompt_tokens"]) == (2, 10)
+    assert (short["chunks"], short["passes_waited"]) == (1, 1)
+    assert long["passes_waited"] == 0
+    # the steps that carried a request lie between its two instants
+    steps = [e for e in TRACE.events(since=mark)
+             if e["kind"] in ("prefill", "decode")]
+    carried = [e for e in steps
+               if long["t_first_sched"] <= e["t"] <= long["t_token"]]
+    assert [e["kind"] for e in carried] == ["prefill", "prefill"]
+
+
+def test_a_decode_only_step_makes_no_call_into_spantrace():
+    eng = HostEngine(maxp=16)
+    calls = _spy_on(eng.spans)
+    seqs = [eng._allocate_seq(p, SamplingParams(max_tokens=6, **GREEDY))
+            for p in ([1, 2, 3, 4, 5], [6, 7, 8])]
+    for s in seqs:
+        eng.add_seq(s)
+    eng.step()
+    assert eng.steps == ["prefill"]
+    assert [c[0] for c in calls] == ["begin", "begin", "event", "event"]
+    del calls[:]
+    for _ in range(4):
+        eng.step()
+    assert eng.steps[1:] == ["decode"] * 4 and calls == []
+    eng.step()                                  # the finishing step
+    assert [c[0] for c in calls] == ["close", "finish"] * 2
+    for rec in eng.spans.spans():
+        assert [p["ph"] for p in rec["phases"]] == [
+            "queued", "prefill_chunk", "decode"]
+        assert rec["phases"][-1]["tokens"] == 5
+
+
+def test_aborted_before_its_first_token_records_nothing():
+    from gllm_tpu.obs.steptrace import TRACE
+    eng = HostEngine(maxp=6)
+    mark = TRACE.mark()
+    seq = eng._allocate_seq(list(range(10)),
+                            SamplingParams(max_tokens=4, **GREEDY))
+    eng.add_seq(seq)
+    eng.step()                                  # the first chunk of two
+    assert seq.prefill_chunks == 1 and not seq.first_token_time
+    eng.abort(seq.seq_id)
+    while eng.has_unfinished:
+        eng.step()
+    assert seq.finish_reason == "abort"
+    assert _first_tokens(mark) == []
+    (rec,) = eng.spans.spans()
+    assert rec["reason"] == "abort"
+    assert [p["ph"] for p in rec["phases"]] == ["queued", "prefill_chunk"]
+
+
+def test_preempted_before_its_first_token_records_one_event():
+    from gllm_tpu.obs.steptrace import TRACE
+    eng = HostEngine(maxp=6)
+    mark = TRACE.mark()
+    seq = eng._allocate_seq(list(range(10)),
+                            SamplingParams(max_tokens=3, **GREEDY))
+    eng.add_seq(seq)
+    eng.step()
+    t_first_sched = seq.first_sched_time
+    assert eng.scheduler._preempt_one(set())    # its chunk is thrown away
+    assert seq.num_computed_tokens == 0
+    while eng.has_unfinished:
+        eng.step()
+    (ev,) = _first_tokens(mark)
+    # the clock of its first schedule stands; the prompt went through the
+    # device in three steps, one of them twice over
+    assert seq.first_sched_time == t_first_sched
+    assert ev["chunks"] == 3 and ev["seq_id"] == seq.seq_id
+    assert ev["t_first_sched"] == pytest.approx(
+        t_first_sched - TRACE.t0, abs=2e-6)
+    (rec,) = eng.spans.spans()
+    assert [p["ph"] for p in rec["phases"]].count("prefill_chunk") == 3
+
+
+@pytest.fixture
+def served():
+    from gllm_tpu.engine.serving_engine import ServingEngine
+    eng = HostEngine(maxp=6)
+    serving = ServingEngine(eng)
+    yield eng, serving
+    serving.shutdown()
+
+
+def test_streamed_request_records_once_at_the_flush_with_all_six_stages(
+        served):
+    from gllm_tpu.obs.steptrace import TRACE
+    eng, serving = served
+    mark = TRACE.mark()
+    h = _handler(serving)
+    t_body = time.monotonic()
+    handle = serving.submit(list(range(10)),
+                            SamplingParams(max_tokens=5, **GREEDY),
+                            received_t=t_body)
+    h._stream(handle, lambda text, fin: {"choices": [{"text": text,
+                                                      "finish_reason": fin}]})
+    assert h.wfile.getvalue().count(b'"choices"') == 5
+    (ev,) = _first_tokens(mark)
+    assert {k for k in ev if k.endswith("_ms")} == set(STAGES) | {
+        "total_ms"}
+    assert all(ev[k] >= 0 for k in STAGES)
+    assert sum(ev[k] for k in STAGES) == pytest.approx(ev["total_ms"],
+                                                       abs=4e-3)
+    assert ev["t_received"] == pytest.approx(t_body - TRACE.t0, abs=2e-6)
+    assert (ev["chunks"], ev["prompt_tokens"]) == (2, 10)
+    # the event's own instant is the flush: the end of its last stage
+    assert ev["t"] >= ev["t_token"]
+    assert ev["t"] - ev["t_received"] == pytest.approx(
+        ev["total_ms"] / 1e3, abs=2e-3)
+    # the tree of the finished request, from the same stamps
+    deadline = time.monotonic() + 10
+    while not eng.spans.spans():
+        assert time.monotonic() < deadline
+        time.sleep(0.005)
+    (rec,) = eng.spans.spans()
+    phs = [p["ph"] for p in rec["phases"]]
+    assert sorted(phs) == sorted(["parse", "intake", "queued",
+                                  "prefill_chunk", "prefill_chunk",
+                                  "handover", "emit", "decode"])
+    assert phs[:3] == ["parse", "intake", "queued"]
+    assert rec["t0"] == t_body
+    by = {p["ph"]: p for p in rec["phases"]}
+    for name, field in (("parse", "parse_ms"), ("intake", "intake_ms"),
+                        ("queued", "queue_ms"),
+                        ("handover", "handover_ms"), ("emit", "emit_ms")):
+        assert by[name]["dur_ms"] == pytest.approx(ev[field], abs=2e-3)
+    assert by["decode"]["tokens"] == 4
+
+
+def test_unstreamed_request_records_once_where_the_chunk_is_taken(served):
+    from gllm_tpu.obs.steptrace import TRACE
+    eng, serving = served
+    mark = TRACE.mark()
+    h = _handler(serving)
+    handle = serving.submit([1, 2, 3], SamplingParams(max_tokens=4,
+                                                      **GREEDY),
+                            received_t=time.monotonic())
+    got = h._collect(handle)
+    assert got["finish"] == "length"
+    assert got["usage"]["completion_tokens"] == 4
+    (ev,) = _first_tokens(mark)
+    # nothing is flushed for the token: no ``emit``, never a 0
+    assert {k for k in ev if k.endswith("_ms")} == (
+        set(STAGES) - {"emit_ms"}) | {"total_ms"}
+    assert sum(ev[k] for k in STAGES if k in ev) == pytest.approx(
+        ev["total_ms"], abs=4e-3)
+    # a second request, submitted with no front end's stamp: no ``parse``
+    handle = serving.submit([4, 5, 6], SamplingParams(max_tokens=1,
+                                                      **GREEDY))
+    h._collect(handle)
+    evs = _first_tokens(mark)
+    assert len(evs) == 2
+    assert "parse_ms" not in evs[1] and "t_received" not in evs[1]
+    assert "intake_ms" in evs[1]
+    # one token: its tree had closed before the first token left; the
+    # hand-over is on it all the same, and there is no decode to roll up
+    rec = [r for r in eng.spans.spans() if r["seq_id"] == handle.seq_id][-1]
+    assert [p["ph"] for p in rec["phases"]] == [
+        "intake", "queued", "prefill_chunk", "handover"]
+
+
+def test_prefix_probe_event_carries_its_wall_time():
+    from gllm_tpu.obs.steptrace import TRACE
+    eng = HostEngine(maxp=16, prefix=True)
+    mark = TRACE.mark()
+    eng.generate([list(range(12))], max_tokens=2)
+    eng.generate([list(range(12)) + [40, 41]], max_tokens=2)
+    probes = TRACE.events(since=mark, kinds=["prefix"])
+    assert [p["hit_tokens"] for p in probes] == [0, 12]
+    assert all(isinstance(p["ms"], float) and 0 <= p["ms"] < 1e3
+               for p in probes)
+    firsts = _first_tokens(mark)
+    assert [e["cached_tokens"] for e in firsts] == [0, 12]
+    s = summarize(TRACE.events(since=mark))
+    assert s["prefix"]["match_ms"] == pytest.approx(
+        sum(p["ms"] for p in probes), abs=2e-3)
+    assert s["first_token"]["requests"] == 2
+    assert set(s["first_token"]["mean_ms"]) == {"queue_ms", "compute_ms",
+                                                "total_ms"}
+
+
 # ---- chrome_trace schema ---------------------------------------------------
 
 def test_chrome_trace_schema_and_phase_reconstruction():
@@ -148,9 +516,16 @@ def test_chrome_trace_schema_and_phase_reconstruction():
                       "intake": 0.0002})
     spans = [{"seq_id": 7, "t0": 100.0, "t1": 100.2, "reason": "stop",
               "prompt_tokens": 5, "output_tokens": 3,
-              "phases": [{"ph": "queued", "t": 100.0, "dur_ms": 10.0},
-                         {"ph": "decode_chain", "t": 100.05,
-                          "dur_ms": 20.0, "k": 8}]}]
+              "phases": [{"ph": "parse", "t": 100.0, "dur_ms": 2.0},
+                         {"ph": "intake", "t": 100.002, "dur_ms": 6.0},
+                         {"ph": "queued", "t": 100.008, "dur_ms": 2.0},
+                         {"ph": "prefill_chunk", "t": 100.011,
+                          "dur_ms": 30.0, "tokens": 5},
+                         {"ph": "handover", "t": 100.042, "dur_ms": 2.0},
+                         {"ph": "emit", "t": 100.044, "dur_ms": 0.5},
+                         {"ph": "decode", "t": 100.042,
+                          "dur_ms": 150.0, "tokens": 2,
+                          "tpot_ms": 75.0}]}]
     doc = chrome_trace(tr.events(), spans, span_t0=100.0)
     evs = doc["traceEvents"]
     assert isinstance(evs, list) and evs
@@ -189,7 +564,13 @@ def test_chrome_trace_schema_and_phase_reconstruction():
     # request track: root slice + children on tid 7
     assert all(e["tid"] == 7 for e in req)
     names = {e["name"] for e in req}
-    assert "queued" in names and "decode_chain" in names
+    # the request track draws every child the tree has: the stages up
+    # to the first token and the one rolled-up decode, with its meta
+    assert {"parse", "intake", "queued", "prefill_chunk", "handover",
+            "emit", "decode"} <= names
+    (dec,) = [e for e in req if e["name"] == "decode"]
+    assert dec["args"] == {"tokens": 2, "tpot_ms": 75.0}
+    assert dec["ts"] == pytest.approx(0.042 * 1e6, abs=2)
     assert any(n.startswith("request 7") for n in names)
     json.dumps(doc)                                   # serializable
 
@@ -448,21 +829,49 @@ def test_sync_engine_phase_breakdown_and_spans():
     for r in recs.values():
         assert r["reason"] == "length"
         phs = [p["ph"] for p in r["phases"]]
+        # no front end: the tree opens with the wait for the schedule;
+        # then a child a prompt chunk and ONE decode for all the rest
         assert phs[0] == "queued"
-        assert "prefill_chunk" in phs and "decode_step" in phs
+        assert phs.count("prefill_chunk") == 1
+        assert phs.count("decode") == 1
+        (dec,) = [p for p in r["phases"] if p["ph"] == "decode"]
+        assert dec["tokens"] == r["output_tokens"] - 1
+        assert dec["t"] + dec["dur_ms"] / 1e3 == pytest.approx(r["t1"])
         assert r["t1"] > r["t0"]
+    # ... and one first_token event a request, written at the collect
+    firsts = TRACE.events(since=mark, kinds=["first_token"])
+    assert sorted(e["seq_id"] for e in firsts) == sorted(recs)
+    for e in firsts:
+        assert {"queue_ms", "compute_ms"} == {
+            k for k in e if k.endswith("_ms")} - {"total_ms"}
+        assert e["total_ms"] == pytest.approx(
+            e["queue_ms"] + e["compute_ms"], abs=2e-3)
+        assert e["chunks"] == 1 and "t_received" not in e
 
 
-def test_fused_engine_records_decode_chain_spans():
+def test_fused_engine_rolls_its_blocks_up_into_one_decode_span():
+    """A fused block's k / k_exec / dead_substeps are on its
+    ``fused_block`` step event; the request's tree gets one ``decode``
+    child for all of them, and the blocks make no call into
+    SpanTrace."""
+    from gllm_tpu.obs.steptrace import TRACE
     llm = make_llm(overlap_scheduling=True, multi_step_decode=4)
+    calls = _spy_on(llm.spans)
+    mark = TRACE.mark()
     outs = llm.generate(prompt_token_ids=[[2, 4, 6, 8]],
                         sampling_params=SamplingParams(max_tokens=12,
                                                        **GREEDY))
     assert outs[0].num_output_tokens == 12
     (rec,) = llm.spans.spans()
-    chains = [p for p in rec["phases"] if p["ph"] == "decode_chain"]
-    assert chains and all(c["k"] >= 1 for c in chains)
+    assert [p["ph"] for p in rec["phases"]] == ["queued",
+                                                "prefill_chunk", "decode"]
+    assert rec["phases"][-1]["tokens"] == 11
     assert llm.spans.open_count == 0
+    blocks = TRACE.events(since=mark, kinds=["fused_block"])
+    assert blocks and all(b["k"] >= 1 for b in blocks)
+    # the prefill step's chunk, then the finish with its roll-up: one
+    # call each, whatever the number of blocks in between
+    assert [c[0] for c in calls] == ["begin", "event", "close", "finish"]
 
 
 def _dp2(cfg):
