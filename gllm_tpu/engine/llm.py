@@ -136,6 +136,18 @@ _M_SPEC_FUSED = obs.counter(
 # host-committed state), rebuild (promised-vs-actual divergence
 # invalidated speculated entries), pages (no KV room to speculate),
 # depth (the overlap_depth cap was the binding constraint).
+# Prepared launch (docs/overlap_scheduling.md#prepared-launch): decode
+# steps that were scheduled, built and placed under the step before them,
+# by what became of them at that step's collect. ``fired``: launched from
+# the collect, before its output; ``dropped_arrival``: a request was on
+# the intake queue (or waiting), so the joining step is formed as always;
+# ``dropped_finish``: a collected token ended a row (EOS, stop id, the
+# model length); ``dropped_other``: an abort, a deadline about to close a
+# stream, push work, the loop stopping, a collect that raised.
+_M_PREPARED = obs.counter(
+    "gllm_prepared_steps_total",
+    "decode steps prepared under the running step, by outcome "
+    "(fired|dropped_arrival|dropped_finish|dropped_other)", ("outcome",))
 _M_INFLIGHT = obs.gauge(
     "gllm_inflight_depth",
     "dispatched-but-uncollected engine entries after the latest fill "
@@ -160,6 +172,19 @@ class RequestOutput:
     @property
     def finished(self) -> bool:
         return self.finish_reason is not None
+
+
+def prepares_next_step(config: EngineConfig) -> bool:
+    """Does this engine's loop prepare the next decode step under the
+    running one (docs/overlap_scheduling.md#prepared-launch)? The default
+    loop does: one runner, one step in flight. Whatever runs ahead by its
+    own means keeps its loop (``overlap_scheduling``'s chain, the pp
+    depth, the dp super-step), and ``enforce_eager`` stays the plain arm
+    the tests compare against. No option of its own."""
+    par = config.parallel
+    return (par.dp == 1 and par.pp == 1
+            and not config.overlap_scheduling and not config.enforce_eager
+            and (config.pp_pipeline_depth or 1) == 1)
 
 
 def refuse_for_windowed(config: EngineConfig) -> None:
@@ -387,6 +412,27 @@ class LLM:
                 "--unified-step is inert for hybrid (GDN) models: "
                 "legacy dispatch and step kinds retained")
         self.futures = FutureMap()
+        # Prepared launch (docs/overlap_scheduling.md#prepared-launch):
+        # the default loop (one runner, one step in flight, nothing
+        # queued behind it) prepares the next decode step under the
+        # running one; every loop that runs ahead by its own means, and
+        # the plain arm the tests compare against, stays as it is.
+        self._prepares = prepares_next_step(config)
+        # ... and only while there is room to prepare it in: the last
+        # collect of a decode step blocked for twice as long as the
+        # loop's host work took between two collects (the device's step
+        # outlasts the host's turn by far; the same reading in either
+        # order, since the work is the same). Where the host is the
+        # slower side (a model so small that its tokens are all but
+        # ready when the loop comes for them) the plain order costs the
+        # same, and its blocking wait is what gives the handler threads
+        # the interpreter once a pass. A clock is one host's own: the
+        # hosts of a multihost engine have to decide alike, so they
+        # always prepare.
+        import jax
+        self._own_clock = jax.process_count() == 1
+        self._room_to_prepare = not self._own_clock
+        self._t_collected = time.monotonic()
         # Encoder disaggregation (gllm_tpu/disagg/): set by init_disagg on
         # LM nodes; monolith engines leave it None.
         self.disagg_coordinator = None
@@ -687,7 +733,8 @@ class LLM:
 
     # ---- main loops -------------------------------------------------------
 
-    def step(self, after_dispatch: Optional[Callable[[], None]] = None
+    def step(self, after_dispatch: Optional[Callable[[], None]] = None,
+             hold_launch: Optional[Callable[[], Optional[str]]] = None
              ) -> List[SeqOutput]:
         """One engine iteration.
 
@@ -698,6 +745,16 @@ class LLM:
         and not between two of them (``ServingEngine._run_loop`` hands
         the previous step's chunks to the handler threads there). A pass
         that returns without anything in flight never calls it.
+
+        ``hold_launch``, beside it, is asked at the collect, where a
+        decode step prepared under the running one is about to be
+        launched (:meth:`_prepare_next`,
+        docs/overlap_scheduling.md#prepared-launch): what the loop cannot
+        see from in here, as a reason to drop the prepared step
+        (``"arrival"``: a request is on the caller's intake queue;
+        ``"other"``) or None. A caller with no queue of its own
+        (``generate``) passes none, and the step is launched whenever no
+        collected token has ended a row.
 
         Keeps up to ``pp`` microbatches in flight (the pipeline depth —
         reference scheduler.py:358-364 keeps pp_size batches running), then
@@ -954,36 +1011,63 @@ class LLM:
                 # gate-B-blocked seqs park in waiting; don't spin hot
                 time.sleep(0.002)
             return []
-        if after_dispatch is not None:
-            after_dispatch()
-        # Fault points (gllm_tpu/faults.py, docs/robustness.md): fired
-        # BEFORE the in-flight pop so quarantine_step_failure still sees
-        # the batch it must attribute the failure to; the stall mimics a
-        # hung device dispatch blocking the loop inside collect.
-        faults.FAULTS.maybe_stall("dispatch_stall")
-        faults.FAULTS.maybe_raise("step_exception")
-        entry = self._in_flight.popleft()
-        batch, handle, t_dispatch, phases = (entry.batch, entry.handle,
-                                             entry.t_dispatch,
-                                             entry.phases)
-        if not self._in_flight:
-            # pipeline drained: the tip (this very batch, or older) is
-            # collected — a future burst must root a fresh chain, not
-            # retain the old batch/handle or fail a stale extension
-            self._chain_tip = None
-        if entry.invalid:
-            # reconciliation discard (pipelined loop): the speculated
-            # schedule assumed a sequence alive that has since finished
-            # — unwind the in-flight bookkeeping WITHOUT committing
-            # tokens or blocking on the device (its writes are harmless:
-            # live rows' positions are rewritten identically by the
-            # rebuild, dead rows' pages free once the counts drain); the
-            # sync path re-schedules the same positions from committed
-            # state next pass.
-            self.scheduler.discard_batch(batch)
-            return []
-        t0 = time.monotonic()
-        tokens, aux = self.runner.collect(handle)
+        # the step after the one in flight is prepared BEFORE the seam:
+        # the handler threads are idle then (the chunks they will send
+        # are handed over at the seam), so the work shares the
+        # interpreter with nobody, and the handlers have the whole of the
+        # wait to themselves
+        prepared = (self._prepare_next(self._in_flight[0])
+                    if self._prepares and self._room_to_prepare
+                    and len(self._in_flight) == 1 else None)
+        try:
+            if after_dispatch is not None:
+                after_dispatch()
+            # Fault points (gllm_tpu/faults.py, docs/robustness.md): fired
+            # BEFORE the in-flight pop so quarantine_step_failure still
+            # sees the batch it must attribute the failure to; the stall
+            # mimics a hung device dispatch blocking the loop inside
+            # collect.
+            faults.FAULTS.maybe_stall("dispatch_stall")
+            faults.FAULTS.maybe_raise("step_exception")
+            entry = self._in_flight.popleft()
+            batch, handle, t_dispatch, phases = (entry.batch, entry.handle,
+                                                 entry.t_dispatch,
+                                                 entry.phases)
+            if not self._in_flight:
+                # pipeline drained: the tip (this very batch, or older) is
+                # collected — a future burst must root a fresh chain, not
+                # retain the old batch/handle or fail a stale extension
+                self._chain_tip = None
+            if entry.invalid:
+                # reconciliation discard (pipelined loop): the speculated
+                # schedule assumed a sequence alive that has since
+                # finished — unwind the in-flight bookkeeping WITHOUT
+                # committing tokens or blocking on the device (its writes
+                # are harmless: live rows' positions are rewritten
+                # identically by the rebuild, dead rows' pages free once
+                # the counts drain); the sync path re-schedules the same
+                # positions from committed state next pass.
+                self.scheduler.discard_batch(batch)
+                return []
+            t0 = time.monotonic()
+            tokens, aux = self.runner.collect(handle)
+        except BaseException:
+            if prepared is not None:
+                self._drop_prepared(prepared, "other")
+            raise
+        t_collected = time.monotonic()
+        if self._prepares and batch.num_decode == batch.num_seqs:
+            # room to prepare in, read off a decode step (the kind that
+            # is prepared, and the shortest): the loop's whole turn, from
+            # the last collect's end to this one's start (launch, output,
+            # the caller's part, intake, this pass's forming or
+            # preparing, the seam), against the wait
+            self._room_to_prepare = (
+                not self._own_clock
+                or t_collected - t0 > 2 * (t0 - self._t_collected))
+        self._t_collected = t_collected
+        if prepared is not None:
+            self._launch_prepared(prepared, entry, tokens, hold_launch)
         # ``output``: everything between the collect and the return —
         # the step's own record keeping first (it takes the phases
         # measured up to here, so this span rides with the NEXT event)
@@ -995,8 +1079,93 @@ class LLM:
             if isinstance(batch, list) \
                     and aux.get("spec_counts") is not None:
                 extra = self._spec_block_stats(batch, aux)
-            self._record_step(batch, t0, t_dispatch, extra, phases)
+            self._record_step(batch, t0, t_dispatch, extra, phases,
+                              entry.prepared, t_collected)
             return self._commit_step(batch, tokens, aux, extra)
+
+    def _prepare_next(self, entry: InFlight):
+        """Prepared launch, first half, between the dispatch of
+        ``entry`` (the one step in flight) and the ``after_dispatch``
+        seam: if every row of it samples and nothing waits, schedule the
+        step after it from token COUNTS (``schedule_chain``: pages,
+        positions, slots) and let the runner build, pack and place it,
+        its input tokens the running step's on-device sampled tokens.
+        The work keeps its phase names, ``schedule`` and ``build``; it
+        lies under the device's step. Returns the runner's prepared step,
+        or None where today's order stands: work waiting (a sequence
+        parked for its embeddings too), a running sequence the batch
+        does not hold, a host-side stop scan, a hybrid model's row that
+        is about to snapshot its state for the prefix cache, and
+        whatever ``schedule_chain`` refuses (a mid-prompt chunk, a row
+        at its length, penalties, host-side drafts, an abort, no page
+        without preemption). Every gate in here reads state that the
+        hosts of a multihost engine share, so they decide alike."""
+        batch, sched = entry.batch, self.scheduler
+        if (sched.waiting or len(batch.items) != len(sched.running)
+                or any(it.seq.sampling_params.stop for it in batch.items)):
+            return None
+        mm = self.memory_manager
+        if getattr(mm, "ssm_snap_alloc", None) is not None and any(
+                (it.computed_before + it.num_new_tokens) % mm.page_size == 0
+                for it in batch.items):
+            # a hybrid model under the prefix cache: a row of the running
+            # step ends on a page boundary, where its commit snapshots
+            # the recurrent state (``register_computed_pages``), and that
+            # needs the state as this step leaves it: nothing of the row
+            # in flight
+            return None
+        with spans.phase("schedule"):
+            chain = sched.schedule_chain(batch, 1)
+        if not chain:
+            return None
+        return self.runner.prepare_step(chain[0], entry.handle)
+
+    def _drop_prepared(self, prepared, why: str) -> None:
+        """The prepared step is not launched: its rows' in-flight counts
+        and the sampling ordinal go back, the pages it reserved stay on
+        the tables (``Scheduler.discard_batch``), and the pass goes on in
+        today's order."""
+        self.scheduler.discard_batch(prepared.sched_batch)
+        self.runner.discard_prepared()
+        _M_PREPARED.inc(outcome="dropped_" + why)
+
+    def _launch_prepared(self, prepared, entry: InFlight, tokens,
+                         hold_launch) -> None:
+        """Prepared launch, second half, with ``entry``'s tokens on the
+        host: launch the prepared step BEFORE ``entry``'s output, unless
+        something has come up that it could not know of when it was
+        prepared — a token that ends a row, an abort, or what
+        ``hold_launch`` reports. Nothing launched is ever taken back."""
+        mml = self.config.max_model_len
+        eos = self.eos_token_ids
+        sched = self.scheduler
+        if any(it.seq.would_finish(tok, eos) is not None
+               or it.seq.num_tokens + 1 >= mml
+               for it, tok in zip(entry.batch.items, tokens.tolist())):
+            why = "finish"
+        elif sched.holds_abort(entry.batch):
+            why = "other"
+        elif sched.waiting:
+            why = "arrival"
+        else:
+            why = hold_launch() if hold_launch is not None else None
+        if why is not None:
+            self._drop_prepared(prepared, why)
+            return
+        # this pass's host work and the wait belong to the collected
+        # step's event, as they always have; the launched step's own
+        # phases begin here
+        for name, sec in spans.take_phases().items():
+            entry.phases[name] = entry.phases.get(name, 0.0) + sec
+        t_launch = time.monotonic()
+        handle = self.runner.launch_prepared(prepared)
+        phases = spans.take_phases()
+        phases["t_enter"] = t_launch
+        self._in_flight.append(InFlight(
+            prepared.sched_batch, handle, time.monotonic(), phases,
+            chained=True, prepared=True))
+        sched.count_pass()
+        _M_PREPARED.inc(outcome="fired")
 
     def _commit_step(self, batch, tokens, aux, extra) -> List[SeqOutput]:
         """Advance scheduler state by one collected single-runner entry
@@ -1284,12 +1453,16 @@ class LLM:
                                  tokens=it.num_new_tokens)
 
     def _record_step(self, batch, t0: float, t_dispatch: float,
-                     extra: Optional[dict], phases: dict) -> None:
+                     extra: Optional[dict], phases: dict,
+                     prepared: bool = False,
+                     now: Optional[float] = None) -> None:
         """Step-kind attribution for one collected single-runner
         iteration: what kind of step it was and how many tokens it
         carried; :meth:`_emit_step` does the rest. Host wall clock only
-        — the handle was already collected."""
-        now = time.monotonic()
+        — the handle was already collected, at ``now`` (the loop may
+        have launched the step it had prepared since)."""
+        if now is None:
+            now = time.monotonic()
         fused = isinstance(batch, list)
         b = batch[-1] if fused else batch
         mix = None
@@ -1328,6 +1501,8 @@ class LLM:
             ev["k"] = len(batch)
         if mix is not None:
             ev["mix"] = mix
+        if prepared:
+            ev["prepared"] = True
         if extra:
             ev.update(extra)
         self._emit_step(kind, ev, [batch], phases, t0, t_dispatch, now,
@@ -1384,7 +1559,7 @@ class LLM:
             merged[name] = merged.get(name, 0.0) + sec
         ev.update(spans.step_phases(merged))
         ev["step_wall_ms"] = round((now - phases["t_enter"]) * 1e3, 3)
-        TRACE.record(kind, **ev)
+        TRACE.record(kind, t_mono=now, **ev)
         if self.tracing and not decode_steps:
             for b in batches:
                 self._record_spans(b, t_dispatch, now)

@@ -50,7 +50,9 @@ class InFlight:
     descend from it, not from anything older; ``promises`` is the set
     of seq ids a speculative re-form assumed alive; ``invalid`` marks
     an entry reconciliation dropped (collected as a discard, never
-    committed)."""
+    committed). ``prepared``: the entry was built under the step before
+    it and launched from that step's collect (the default loop's
+    prepared launch; it rides its step event)."""
 
     batch: object
     handle: object
@@ -60,6 +62,7 @@ class InFlight:
     roots: bool = False
     promises: frozenset = frozenset()
     invalid: bool = False
+    prepared: bool = False
 
     @property
     def tip(self):

@@ -762,7 +762,13 @@ class ServingEngine:
         deadline about to close a stream, the loop's exit — the pending
         outputs are flushed at once; a superseded generation drops them
         (they were never committed to the journal, so replay recomputes
-        them)."""
+        them).
+
+        A decode step that ``LLM.step`` prepared under the running one
+        is launched from the collect, before ``output``
+        (docs/overlap_scheduling.md#prepared-launch): the next pass then
+        finds it in flight, forms nothing, and reaches the seam at once.
+        ``hold_launch`` is this loop's say in that launch."""
         llm = self.llm
         pending: list = []      # collected, committed in the scheduler,
                                 # not yet delivered
@@ -795,6 +801,21 @@ class ServingEngine:
                 # inside llm.step: not a failure of the step
                 raise _HandOverFailed() from e
 
+        def hold_launch() -> Optional[str]:
+            """Asked by ``LLM.step`` at the collect, before it launches
+            the decode step it prepared under the running one: what only
+            this loop can see. A request on the intake queue has to ride
+            the very next program, so that step is formed as always;
+            push work, a deadline about to close a stream and the loop's
+            end are the next pass's to handle before anything launches."""
+            if not self._intake.empty():
+                return "arrival"
+            if (self._stop or self._gen != gen
+                    or not self._push_work.empty()
+                    or self._expired_deadlines()):
+                return "other"
+            return None
+
         while not self._stop and self._gen == gen:
             self._heartbeat = time.monotonic()
             # chaos point (docs/robustness.md#recovery-lifecycle): dies
@@ -824,7 +845,8 @@ class ServingEngine:
                 continue
             handed = False
             try:
-                outputs = llm.step(after_dispatch=hand_over)
+                outputs = llm.step(after_dispatch=hand_over,
+                                   hold_launch=hold_launch)
             except _HandOverFailed as e:
                 raise e.__cause__     # the loop dies of it, as it always has
             except Exception as e:

@@ -553,7 +553,11 @@ def chrome_trace(step_events: Iterable[dict], spans: Iterable[dict] = (),
     flushed hand-over, which ran before the schedule, is drawn there
     too; wait = the pipelined slack between dispatch end and collect
     start); the previous step's output and this pass's intake lie
-    before it.
+    before it. A step launched PREPARED (``prepared`` on its event,
+    docs/overlap_scheduling.md#prepared-launch) was dispatched first:
+    its ``[t - step_wall, t]`` holds dispatch → output (of the step
+    before it) → intake → schedule → build (of the step after it) →
+    deliver → wait → collect.
     Request spans use absolute monotonic times; ``span_t0`` (the
     steptrace ring's epoch) rebases them onto the same axis.
     """
@@ -583,17 +587,28 @@ def chrome_trace(step_events: Iterable[dict], spans: Iterable[dict] = (),
                 "tokens": e.get("tokens")}
         if "k" in e:
             args["k"] = e["k"]
+        before = [(name, float(ph.get(name, 0.0)) / 1e3)
+                  for name in ("intake", "output")]
+        order = (("schedule", sched), ("build", build),
+                 ("dispatch", disp), ("deliver", deliver),
+                 ("wait", wait), ("collect", coll))
+        if e.get("prepared"):
+            # launched from the collect of the step before it: that
+            # step's output and this pass's intake lie INSIDE the wall
+            wait = max(0.0, wait - sum(dur for _, dur in before))
+            order = (("dispatch", disp), *reversed(before),
+                     ("schedule", sched), ("build", build),
+                     ("deliver", deliver), ("wait", wait),
+                     ("collect", coll))
+            before = []
         t = end - wall
-        for name in ("intake", "output"):
-            dur = float(ph.get(name, 0.0)) / 1e3
+        for name, dur in before:
             if dur > 0:
                 t -= dur
                 events.append(_x(f"{e.get('kind', 'step')}:{name}", t,
                                  dur, _PID_ENGINE, _ENGINE_TIDS[name]))
         t = end - wall
-        for name, dur in (("schedule", sched), ("build", build),
-                          ("dispatch", disp), ("deliver", deliver),
-                          ("wait", wait), ("collect", coll)):
+        for name, dur in order:
             if dur > 0:
                 events.append(_x(f"{e.get('kind', 'step')}:{name}", t,
                                  dur, _PID_ENGINE, _ENGINE_TIDS[name],

@@ -137,12 +137,18 @@ class StepTrace:
         self._lock = threading.Lock()
         self._t0 = time.monotonic()
 
-    def record(self, kind: str, **fields) -> None:
+    def record(self, kind: str, t_mono: Optional[float] = None,
+               **fields) -> None:
+        """``t_mono``: the ``time.monotonic()`` instant the event is OF,
+        where that is not the instant it is recorded at (a step's
+        collect end, when the loop launched the next step before it
+        wrote the record)."""
         ev = {"seq": 0, "t": 0.0, "kind": kind}
         ev.update(fields)
         with self._lock:
             ev["seq"] = self._next_seq
-            ev["t"] = round(time.monotonic() - self._t0, 6)
+            ev["t"] = round((time.monotonic() if t_mono is None
+                             else t_mono) - self._t0, 6)
             self._buf[self._next_seq % self.capacity] = ev
             self._next_seq += 1
 

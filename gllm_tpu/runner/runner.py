@@ -18,6 +18,7 @@ of its machinery:
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import logging
 import threading
@@ -512,6 +513,23 @@ def resolve_kv_quant(config: EngineConfig, model_cfg: ModelConfig):
     if config.cache.kv_cache_dtype == "fp8" and not model_cfg.kv_cache_fp8:
         model_cfg = _dc.replace(model_cfg, kv_cache_fp8=True)
     return kv_quant, model_cfg
+
+
+@dataclasses.dataclass
+class PreparedStep:
+    """One step built, packed and placed, not yet launched: what
+    ``ModelRunner._build_step`` hands to ``_launch_step``. ``host`` is
+    the batch as built (its shapes name the signature, its ``kv_lens``
+    the rows a latent step reads), ``batch`` the same on the device,
+    ``flags`` the step program's static arguments in the order the
+    signature has always had them."""
+
+    sched_batch: ScheduledBatch
+    host: StepBatch
+    batch: PackedBatch
+    token_counts: object
+    layout: object
+    flags: dict
 
 
 class ModelRunner:
@@ -1315,10 +1333,18 @@ class ModelRunner:
         """Place the host (numpy) leaves of ``tree`` for a step dispatch:
         one jax call, one transfer per leaf, each counted
         (gllm_step_h2d_arrays_total). Leaves that are on the device
-        already (spliced-in tokens of the previous step) pass through."""
-        _M_H2D.inc(sum(isinstance(x, np.ndarray)
-                       for x in jax.tree.leaves(tree)))
-        return jax.device_put(tree, sharding)
+        already (spliced-in tokens of the previous step) pass through;
+        with no ``sharding`` given they are not handed to jax at all
+        (across processes such an array is not one ``device_put``
+        takes)."""
+        leaves, treedef = jax.tree.flatten(tree)
+        host = [i for i, x in enumerate(leaves) if isinstance(x, np.ndarray)]
+        _M_H2D.inc(len(host))
+        if sharding is not None:
+            return jax.device_put(tree, sharding)
+        for i, x in zip(host, jax.device_put([leaves[i] for i in host])):
+            leaves[i] = x
+        return jax.tree.unflatten(treedef, leaves)
 
     @staticmethod
     def _lp_flags(sched_batch: ScheduledBatch):
@@ -1466,7 +1492,10 @@ class ModelRunner:
     def step_async(self, sched_batch: ScheduledBatch, prev_handle=None):
         """Launch one step; returns an opaque handle whose tokens are an
         uncommitted device future (jax async dispatch — the host does not
-        block until ``collect``).
+        block until ``collect``). Two halves with nothing between them:
+        :meth:`_build_step` (everything up to the jit call) and
+        :meth:`_launch_step`; a PREPARED launch runs the same halves at
+        two times (:meth:`prepare_step`, :meth:`launch_prepared`).
 
         ``prev_handle``: chain this step off a previous entry's
         ON-DEVICE sampled tokens — rows whose ``src_rows`` entry is >= 0
@@ -1476,15 +1505,47 @@ class ModelRunner:
         in one dispatch — the chain absorbing a prefill chunk instead
         of breaking (docs/overlap_scheduling.md#unified-step)."""
         build = phase("build").start()
-        if self.model_cfg.use_mm:
-            self._prepare_mm(sched_batch)
         self._apply_ssm_intents()
         self._apply_swap_intents()
+        return self._launch_step(
+            self._build_step(sched_batch, prev_handle), build)
+
+    def prepare_step(self, sched_batch: ScheduledBatch,
+                     prev_handle) -> "PreparedStep":
+        """The first half of :meth:`step_async`, while ``prev_handle``'s
+        step still runs (docs/overlap_scheduling.md#prepared-launch): the
+        batch built, packed and placed, its input tokens the previous
+        step's on-device sampled tokens. Nothing here says that a
+        program ran, and nothing touches ``self.kv`` or the pending
+        slot / swap intents: :meth:`launch_prepared` does, or
+        :meth:`discard_prepared` takes the step's ordinal back."""
+        with phase("build"):
+            return self._build_step(sched_batch, prev_handle)
+
+    def launch_prepared(self, prepared: "PreparedStep"):
+        """The second half of :meth:`step_async` for a prepared step: the
+        intents that precede it, the counts of a dispatch, the jit call.
+        Returns the handle."""
+        build = phase("build").start()
+        self._apply_ssm_intents()
+        self._apply_swap_intents()
+        return self._launch_step(prepared, build)
+
+    def discard_prepared(self) -> None:
+        """The prepared step is not launched: the sampling ordinal goes
+        back, so the step that runs in its place draws as it would have
+        (the scheduler's side is ``Scheduler.discard_batch``)."""
+        self._step_count -= 1
+
+    def _build_step(self, sched_batch: ScheduledBatch,
+                    prev_handle) -> "PreparedStep":
+        """Host work of one step up to the jit call, inside the caller's
+        ``build`` phase: batch build, pack, the splice of chained tokens,
+        the transfer, the static flags."""
+        if self.model_cfg.use_mm:
+            self._prepare_mm(sched_batch)
         self._step_count += 1
         host, max_q, token_counts = self.builder.build(sched_batch)
-        if self.model_cfg.dense_mla:
-            from gllm_tpu.models.deepseek import count_rows_read
-            count_rows_read(self.model_cfg, host.attn.kv_lens, max_q == 1)
         batch, layout = pack(host, (self._step_count,))
         if prev_handle is not None:
             batch = self._splice_prev(batch, sched_batch, prev_handle[0])
@@ -1492,22 +1553,36 @@ class ModelRunner:
         lp_k, want_plp = self._lp_flags(sched_batch)
         ring = (prev_handle is None
                 and self._use_ring(sched_batch, host.token_ids.shape[0]))
-        spec_sampled = _spec_sampled(sched_batch.items)
         all_greedy = _all_greedy(sched_batch.items)
+        return PreparedStep(
+            sched_batch, host, batch, token_counts, layout,
+            dict(max_q_len=max_q, logprobs_k=lp_k, prompt_lp=want_plp,
+                 ring=ring, spec_sampled=_spec_sampled(sched_batch.items),
+                 all_greedy=all_greedy))
+
+    def _launch_step(self, prepared: "PreparedStep", build):
+        """What says that a program ran, then the jit call: the counts
+        (``num_dispatches``, the sampler counter every ``*_per_step``
+        metric divides by, the rows a latent step reads, the first
+        sighting of a signature) close the caller's ``build`` phase;
+        ``self.kv`` is read here, whatever ran since the step was built."""
+        sched_batch, host, flags = (prepared.sched_batch, prepared.host,
+                                    prepared.flags)
+        max_q = flags["max_q_len"]
+        if self.model_cfg.dense_mla:
+            from gllm_tpu.models.deepseek import count_rows_read
+            count_rows_read(self.model_cfg, host.attn.kv_lens, max_q == 1)
         new_sig = self._note_dispatch(
-            "step", host, (max_q, lp_k, want_plp, ring, spec_sampled,
-                           all_greedy), all_greedy)
+            "step", host, tuple(flags.values()), flags["all_greedy"])
         build.stop()
         from gllm_tpu.parallel.mesh import mesh_context
         with phase("dispatch", **self._span_args(
                 sched_batch.num_seqs, sched_batch.total_tokens)):
             with mesh_context(self.mesh), first_use(new_sig):
                 tokens, self.kv, aux = self._step_fn(
-                    self.params, self.kv, batch, self.cos_sin,
-                    token_counts, self.rng_key, layout=layout,
-                    max_q_len=max_q, logprobs_k=lp_k,
-                    prompt_lp=want_plp, ring=ring,
-                    spec_sampled=spec_sampled, all_greedy=all_greedy)
+                    self.params, self.kv, prepared.batch, self.cos_sin,
+                    prepared.token_counts, self.rng_key,
+                    layout=prepared.layout, **flags)
             _start_host_copy((tokens, aux))
         if "stats" in aux:
             # beside the step's counts, whether it was a decode-only step

@@ -306,6 +306,19 @@ class Scheduler:
     def abort_seq(self, seq_id: int) -> None:
         self._aborted_ids.add(seq_id)
 
+    def holds_abort(self, batch: ScheduledBatch) -> bool:
+        """A row of ``batch`` has an abort pending (``_process_aborts``
+        reaps it on the next ``schedule_once``)."""
+        return bool(self._aborted_ids) and any(
+            it.seq.seq_id in self._aborted_ids for it in batch.items)
+
+    def count_pass(self) -> None:
+        """A step launched without ``schedule_once`` (the engine's
+        prepared launch) is a scheduling pass all the same: the admission
+        ratio decays and the stats line is due as they would have been."""
+        self._decay_ratio()
+        self._maybe_log_stats()
+
     @property
     def has_unfinished(self) -> bool:
         return bool(self.waiting or self.running)

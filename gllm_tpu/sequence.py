@@ -229,16 +229,26 @@ class Sequence:
         terminators (reference llm_engine.py finish_tokens membership
         check; GLM4 has three eos ids, Llama-3 two).
         """
+        return self._finish_at(self.token_ids[-1], self.num_output_tokens,
+                               eos_token_ids)
+
+    def would_finish(self, token: int, eos_token_ids) -> Optional[str]:
+        """What :meth:`check_finish` will say once ``token`` is appended:
+        the same rule, asked before the token is committed."""
+        return self._finish_at(token, self.num_output_tokens + 1,
+                               eos_token_ids)
+
+    def _finish_at(self, last: int, num_output: int,
+                   eos_token_ids) -> Optional[str]:
         sp = self.sampling_params
-        last = self.token_ids[-1]
         if isinstance(eos_token_ids, int):
             eos_token_ids = (eos_token_ids,)
-        if self.num_output_tokens >= sp.min_tokens:
+        if num_output >= sp.min_tokens:
             if not sp.ignore_eos and eos_token_ids and last in eos_token_ids:
                 return "stop"
             if last in sp.stop_token_ids:
                 return "stop"
-        if self.num_output_tokens >= sp.max_tokens:
+        if num_output >= sp.max_tokens:
             return "length"
         return None
 

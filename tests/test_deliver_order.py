@@ -94,7 +94,7 @@ class ScriptedLLM:
     def close(self):
         pass
 
-    def step(self, after_dispatch=None):
+    def step(self, after_dispatch=None, hold_launch=None):
         for s in list(self.running):
             if s.seq_id in self._aborted:
                 s.status = SequenceStatus.ABORTED
