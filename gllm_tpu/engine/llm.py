@@ -266,6 +266,13 @@ class LLM:
         self.model_cfg = model_cfg
         if model_cfg.use_swa:
             refuse_for_windowed(config)
+        if model_cfg.use_mamba and config.parallel.world_size > 1:
+            raise ValueError(
+                "a model with Mamba-2 layers (layer_types: mamba) is "
+                "served by one chip: its slot pool and kernels are not "
+                "partitioned and its layer pattern has no period for the "
+                "pp runner's stages; not supported with it: tp / pp / dp "
+                "/ sp > 1")
 
         self.tokenizer = tokenizer
         if self.tokenizer is None and config.model and config.tokenizer != "":
@@ -295,7 +302,8 @@ class LLM:
                 ssm_working_slots=getattr(self.runner,
                                           "ssm_working_slots", 0),
                 ssm_snapshot_slots=getattr(self.runner,
-                                           "ssm_snapshot_slots", 0))
+                                           "ssm_snapshot_slots", 0),
+                ssm_chunk=model_cfg.ssm_chunk)
             for _ in range(self.dp)]
         self.memory_manager = self.memory_managers[0]
         if model_cfg.use_swa:

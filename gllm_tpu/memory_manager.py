@@ -41,14 +41,16 @@ from gllm_tpu.utils import cdiv
 # runner is asked for (snapshot / zero / restore, applied before a step).
 _M_SSM_SLOTS = obs.gauge(
     "gllm_ssm_slots_in_use",
-    "GDN working slots held by running sequences (of max_num_seqs)")
+    "Working slots of recurrent state (GDN or Mamba-2 layers) held by "
+    "running sequences (of max_num_seqs)")
 _M_SWA_SLOTS = obs.gauge(
     "gllm_swa_ring_slots_in_use",
     "Window rings held by running sequences (of max_num_seqs): one a "
     "sequence in every windowed layer, each of swa_ring_len rows")
 _M_SSM_INTENTS = obs.counter(
     "gllm_ssm_intents_total",
-    "GDN slot maintenance handed to the runner, by kind", ("kind",))
+    "Recurrent-state slot maintenance handed to the runner, by kind",
+    ("kind",))
 
 # Prefix-cache metrics (docs/observability.md): lifetime token counters —
 # rate(hit)/rate(query) gives the windowed hit rate in any scraper; the
@@ -106,7 +108,8 @@ class MemoryManager:
     """
 
     def __init__(self, num_pages: int, page_size: int,
-                 ssm_working_slots: int = 0, ssm_snapshot_slots: int = 0):
+                 ssm_working_slots: int = 0, ssm_snapshot_slots: int = 0,
+                 ssm_chunk: int = 64):
         if num_pages < 2:
             raise ValueError("need at least 2 pages (one is the dummy page)")
         self.page_size = page_size
@@ -131,6 +134,9 @@ class MemoryManager:
 
         self.ssm_working_slots = ssm_working_slots
         self.ssm_snapshot_slots = ssm_snapshot_slots
+        # tokens in a chunk of the recurrent layers' chunked rule
+        # (ModelConfig.ssm_chunk): the scheduler's cap on multi-token rows
+        self.ssm_chunk = ssm_chunk
         # the gauge the working slots are counted in (the engine names
         # _M_SWA_SLOTS where the slots are window rings)
         self.slot_gauge = _M_SSM_SLOTS
@@ -525,7 +531,8 @@ class PrefixMemoryManager(MemoryManager):
 def make_memory_manager(num_pages: int, page_size: int,
                         enable_prefix_caching: bool,
                         ssm_working_slots: int = 0,
-                        ssm_snapshot_slots: int = 0) -> MemoryManager:
+                        ssm_snapshot_slots: int = 0,
+                        ssm_chunk: int = 64) -> MemoryManager:
     cls = PrefixMemoryManager if enable_prefix_caching else MemoryManager
     return cls(num_pages, page_size, ssm_working_slots=ssm_working_slots,
-               ssm_snapshot_slots=ssm_snapshot_slots)
+               ssm_snapshot_slots=ssm_snapshot_slots, ssm_chunk=ssm_chunk)

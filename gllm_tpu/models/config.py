@@ -248,9 +248,61 @@ class ModelConfig:
     # RMSNorm weights stored zero-centred: forward scales by (1 + weight)
     norm_zero_centered: bool = True
 
+    # Mamba-2 state-space layers (NemotronH's ``M`` blocks; layer_types
+    # marks them "mamba", its expert blocks "moe"): ``mamba_num_heads``
+    # heads of ``mamba_head_dim`` channels over a state of
+    # ``ssm_state_size``, B and C shared by the heads of each of
+    # ``mamba_n_groups`` groups, the chunked rule in chunks of
+    # ``mamba_chunk_size`` tokens. ``time_step_*`` are the published
+    # initialiser's (dummy weights draw ``dt_bias`` from them).
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    ssm_state_size: int = 0
+    mamba_n_groups: int = 1
+    mamba_chunk_size: int = 128
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    # the form of an expert: "swiglu" (silu(x W_g) * x W_u) W_d, three
+    # matrices, or "relu2" relu(x W_u)^2 W_d, two
+    expert_act: str = "swiglu"
+
+    @property
+    def use_mamba(self) -> bool:
+        return "mamba" in self.layer_types
+
     @property
     def use_hybrid(self) -> bool:
-        return "linear_attention" in self.layer_types
+        """A layer of this model holds per-sequence RECURRENT state in
+        the slot pool (``ssm_slot_shapes`` says what a slot of it stores):
+        Gated-DeltaNet or Mamba-2 layers. The one test every fence of the
+        runner, the pp runner and the engine reads."""
+        return "linear_attention" in self.layer_types or self.use_mamba
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def ssm_slot_shapes(self) -> Tuple[Tuple[int, ...], Tuple[int, ...]]:
+        """What one slot of one recurrent layer stores, float32: (the
+        convolution's window [taps - 1, channels], the recurrent state
+        [heads, rows, lanes])."""
+        taps = self.linear_conv_kernel_dim - 1
+        if self.use_mamba:
+            return ((taps, self.gdn_conv_dim),
+                    (self.mamba_num_heads, self.mamba_head_dim,
+                     self.ssm_state_size))
+        return ((taps, self.gdn_conv_dim),
+                (self.linear_num_value_heads, self.linear_key_head_dim,
+                 self.linear_value_head_dim))
+
+    @property
+    def ssm_chunk(self) -> int:
+        """Tokens in a chunk of the recurrent layers' chunked rule (the
+        packed layout of a mixed step: ops/gdn.gdn_chunk_slots)."""
+        from gllm_tpu.ops.gdn import GDN_CHUNK
+        return self.mamba_chunk_size if self.use_mamba else GDN_CHUNK
 
     @property
     def use_seq_slots(self) -> bool:
@@ -286,11 +338,20 @@ class ModelConfig:
 
     @property
     def num_linear_layers(self) -> int:
+        """Layers of this stage that hold recurrent state."""
         return sum(1 for t in self.stage_layer_types
-                   if t == "linear_attention")
+                   if t in ("linear_attention", "mamba"))
+
+    @property
+    def num_moe_layers(self) -> int:
+        return sum(1 for t in self.stage_layer_types if t == "moe")
 
     @property
     def gdn_conv_dim(self) -> int:
+        """Channels of a recurrent layer's short convolution."""
+        if self.use_mamba:
+            return (self.mamba_d_inner
+                    + 2 * self.mamba_n_groups * self.ssm_state_size)
         return (2 * self.linear_num_key_heads * self.linear_key_head_dim
                 + self.linear_num_value_heads * self.linear_value_head_dim)
 
@@ -336,7 +397,11 @@ def _eos_tuple(v) -> Optional[Tuple[int, ...]]:
 # a config.json that names its model_type and no architecture
 _ARCH_OF_MODEL_TYPE = {"olmo_hybrid": "OlmoHybridForCausalLM",
                        "dots3_note": "Dots3NoteForCausalLM",
-                       "axk1": "AXK1ForCausalLM"}
+                       "axk1": "AXK1ForCausalLM",
+                       "nemotron_h": "NemotronHForCausalLM"}
+
+# hybrid_override_pattern's letters (NemotronH)
+_NEMOTRON_KINDS = {"M": "mamba", "E": "moe", "*": "full_attention"}
 
 
 def from_hf_config(hf: Dict[str, Any]) -> ModelConfig:
@@ -489,16 +554,56 @@ def from_hf_config(hf: Dict[str, Any]) -> ModelConfig:
             swa_attn_gate=hf.get("swa_attention_gate_type") or "",
             mla_lora_rescale=bool(hf.get("apply_mla_qkv_lora_rescale")),
         )
+    if arch == "NemotronHForCausalLM":
+        # config.json of nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B (model_type
+        # nemotron_h): a block is ONE mixer, by the letters of
+        # ``hybrid_override_pattern``; attention carries no rotary
+        # embedding (``rope_theta`` is an inert key); the expert layer is
+        # DeepSeek-V3's router (the row has no ``topk_method``: noaux_tc)
+        # over experts of two matrices and relu^2
+        pattern = hf["hybrid_override_pattern"]
+        bad = sorted(set(pattern) - set(_NEMOTRON_KINDS))
+        if bad or len(pattern) != hf["num_hidden_layers"]:
+            raise ValueError(
+                f"hybrid_override_pattern {pattern!r}: "
+                + (f"no block for {bad} (M, E and * are served; '-' is a "
+                   "dense MLP block, which this model does not have)"
+                   if bad else f"{len(pattern)} letters for "
+                   f"{hf['num_hidden_layers']} layers"))
+        if hf.get("mlp_hidden_act", "relu2") != "relu2":
+            raise ValueError("NemotronH experts are relu2, not "
+                             f"{hf['mlp_hidden_act']!r}")
+        extra = dict(
+            layer_types=tuple(_NEMOTRON_KINDS[c] for c in pattern),
+            mamba_num_heads=hf["mamba_num_heads"],
+            mamba_head_dim=hf["mamba_head_dim"],
+            ssm_state_size=hf["ssm_state_size"],
+            mamba_n_groups=hf.get("n_groups", 1),
+            mamba_chunk_size=hf.get("chunk_size", 128),
+            linear_conv_kernel_dim=hf.get("conv_kernel", 4),
+            time_step_min=hf.get("time_step_min", 0.001),
+            time_step_max=hf.get("time_step_max", 0.1),
+            time_step_floor=hf.get("time_step_floor", 1e-4),
+            expert_act="relu2", use_rope=False, attn_output_gate=False,
+            norm_zero_centered=False,
+        )
+        hf = {**hf, "scoring_func": "sigmoid",
+              "topk_method": hf.get("topk_method") or "noaux_tc",
+              "rms_norm_eps": hf.get("layer_norm_epsilon",
+                                     hf.get("norm_eps", 1e-5)),
+              "shared_expert_intermediate_size":
+                  hf.get("moe_shared_expert_intermediate_size", 0)}
     share = hf.get("ep_share")
     if share:
         # this repo's own key, read for every family models/deepseek.py
         # serves: {"chips", "rank", "n_routed_experts"} says that
         # ``n_routed_experts`` counts the experts HELD here, one of
         # ``chips`` equal shares of the published count
-        from gllm_tpu.models.registry import _MLA_ARCHS
-        if arch not in _MLA_ARCHS:
+        from gllm_tpu.models.registry import _MLA_ARCHS, _NEMOTRON_H_ARCHS
+        if arch not in _MLA_ARCHS + _NEMOTRON_H_ARCHS:
             raise ValueError(f"ep_share: {arch} holds no share of its "
-                             "experts (models/deepseek.py's families do)")
+                             "experts (models/deepseek.py's families and "
+                             "models/nemotron_h.py's do)")
         held = hf["n_routed_experts"]
         if share["n_routed_experts"] != held * share["chips"]:
             raise ValueError(

@@ -279,7 +279,16 @@ def deepseek_route(router_logits: jnp.ndarray, e_bias: Optional[jnp.ndarray],
     return weights, ids.astype(jnp.int32)
 
 
-def _shared_expert(lp: Params, x: jnp.ndarray) -> jnp.ndarray:
+def relu2(x: jnp.ndarray) -> jnp.ndarray:
+    return jnp.square(jax.nn.relu(x))
+
+
+def _shared_expert(lp: Params, x: jnp.ndarray,
+                   act: str = "swiglu") -> jnp.ndarray:
+    """The shared expert in the form ``ModelConfig.expert_act`` names."""
+    if act == "relu2":
+        return qmm(relu2(qmm(x, lp["shared_up_proj"])),
+                   lp["shared_down_proj"])
     sg = qmm(x, lp["shared_gate_proj"])
     su = qmm(x, lp["shared_up_proj"])
     return qmm(silu_and_mul(jnp.concatenate([sg, su], axis=-1)),
@@ -287,6 +296,12 @@ def _shared_expert(lp: Params, x: jnp.ndarray) -> jnp.ndarray:
 
 
 _EXPERT_STACKS = ("w_gate", "w_up", "w_down")
+
+
+def expert_stacks(cfg: ModelConfig) -> Tuple[str, ...]:
+    """The stacked matrices of a routed expert, by its form
+    (``cfg.expert_act``): gated SiLU of three, or relu^2 of two."""
+    return _EXPERT_STACKS[1:] if cfg.expert_act == "relu2" else _EXPERT_STACKS
 
 
 def _counting_order(bins, n_bins: int):
@@ -317,8 +332,19 @@ def _counting_order(bins, n_bins: int):
     return order, sizes
 
 
+def _grouped_dot(xs, w, sizes, eids, impl: str):
+    """The grouped product over the rows' experts: XLA's ``ragged_dot``
+    (through ``qragged_dot``: plain or quantized stacks), or the Pallas
+    kernel (ops/pallas/grouped_matmul.py) over a plain stack."""
+    if impl == "pallas" and isinstance(w, jax.Array):
+        from gllm_tpu.ops.pallas.grouped_matmul import grouped_matmul
+        return grouped_matmul(xs, w, sizes,
+                              interpret=jax.default_backend() == "cpu")
+    return qragged_dot(xs, w, sizes, eids)
+
+
 def _held_experts(lp: Params, x, weights, ids, valid, cfg: ModelConfig,
-                  stacks=None, layer=None):
+                  stacks=None, layer=None, grouped: str = "xla"):
     """The part of the routed result that the experts HELD here give
     (``cfg.experts_held`` of them from ``cfg.expert_first`` on; the router
     chose among all ``cfg.num_experts`` and normalised over all it chose).
@@ -332,13 +358,16 @@ def _held_experts(lp: Params, x, weights, ids, valid, cfg: ModelConfig,
     experts: their part is left out, as on a chip that waits for no
     exchange.
 
-    ``stacks``: the expert matrices of ALL the layers of a run, [n, held,
-    ., .] each, with ``layer`` this layer's index among them. Inside a
+    ``stacks``: the expert matrices (``expert_stacks(cfg)``: three for
+    the gated SiLU form, two for relu^2) of ALL the layers of a run, [n,
+    held, ., .] each, with ``layer`` this layer's index among them. Inside a
     scan over layers XLA copies a layer's slice of a stacked operand out
     before a grouped product can read it (1.5 GB a layer and step at
     dots3_note's widths: 4.6 ms of a v5e's bandwidth, measured); so the
     product takes the whole stack as n x held groups, of which only this
     layer's have rows, and reads in place what its groups touch.
+    ``grouped``: how the relu^2 form's two grouped products run ("xla" |
+    "pallas": ``_grouped_dot``; the gated form's three are XLA's).
     Returns (combined [T, H] float32, stats [4])."""
     T, H = x.shape
     K, held = cfg.num_experts_per_tok, cfg.experts_held
@@ -365,18 +394,21 @@ def _held_experts(lp: Params, x, weights, ids, valid, cfg: ModelConfig,
         eids = jnp.minimum(flat[idx], held - 1)
         xs = x[token_of]
         if stacks is None:
-            w_gate, w_up, w_down = (lp[k] for k in _EXPERT_STACKS)
+            ws = [lp[k] for k in expert_stacks(cfg)]
         else:
             n = stacks[0].shape[0]
-            w_gate, w_up, w_down = (
-                w.reshape((n * held,) + w.shape[2:]) for w in stacks)
+            ws = [w.reshape((n * held,) + w.shape[2:]) for w in stacks]
             sizes_i = jax.lax.dynamic_update_slice(
                 jnp.zeros((n * held,), sizes_i.dtype), sizes_i,
                 (layer * held,))
-        gate = qragged_dot(xs, w_gate, sizes_i, eids)
-        up = qragged_dot(xs, w_up, sizes_i, eids)
-        act = silu_and_mul(jnp.concatenate([gate, up], axis=-1))
-        out = qragged_dot(act, w_down, sizes_i, eids)
+        if cfg.expert_act == "relu2":
+            act = relu2(_grouped_dot(xs, ws[0], sizes_i, eids, grouped))
+            out = _grouped_dot(act, ws[-1], sizes_i, eids, grouped)
+        else:
+            gate = qragged_dot(xs, ws[0], sizes_i, eids)
+            up = qragged_dot(xs, ws[1], sizes_i, eids)
+            act = silu_and_mul(jnp.concatenate([gate, up], axis=-1))
+            out = qragged_dot(act, ws[-1], sizes_i, eids)
         out = jnp.where(live[:, None], out.astype(jnp.float32)
                         * flat_w[idx][:, None], 0.0)
         return combined.at[token_of].add(out)
@@ -439,7 +471,7 @@ def _moe_block(lp: Params, x: jnp.ndarray, cfg: ModelConfig, valid=None,
             out * w_sorted)
 
     if cfg.n_shared_experts:
-        combined = combined + _shared_expert(lp, x)
+        combined = combined + _shared_expert(lp, x, cfg.expert_act)
     return combined.astype(x.dtype), stats
 
 

@@ -43,6 +43,7 @@ from gllm_tpu.ops.attention import tp_sharded
 from gllm_tpu.ops.gdn import (causal_conv1d, chunk_gated_delta_rule_packed,
                               chunk_gated_delta_rule_pool,
                               gdn_chunk_slots, gdn_impl_for, l2norm,
+                              packed_chunks, packed_slot_of_token,
                               recurrent_gated_delta_step, rms_norm_gated)
 from gllm_tpu.ops.rope import apply_rope
 from gllm_tpu.ops.quant import qmm
@@ -319,22 +320,8 @@ def _gdn_chunk_rows(mixed, g, beta, cu, slots, dummy, conv_state, rec_state,
     S = slots.shape[0]
     K = conv_w.shape[-1]
     N, C = gdn_chunk_slots(T, S)
-    q_lens = cu[1:] - cu[:-1]
-    is_pre = q_lens > 1
-    n_ch = jnp.where(is_pre, (q_lens + C - 1) // C, 0)       # [S]
-    ch_end = jnp.cumsum(n_ch)
-    ch_start = ch_end - n_ch
-    c_idx = jnp.arange(N, dtype=jnp.int32)
-    live = c_idx < ch_end[-1]
-    row = jnp.minimum(jnp.searchsorted(ch_end, c_idx, side="right"),
-                      S - 1).astype(jnp.int32)
-    j = c_idx - ch_start[row]                # chunk number inside its row
-    first = live & (j == 0)
-    tok0 = cu[row] + j * C
-    local = jnp.arange(C, dtype=jnp.int32)
-    n_valid = jnp.where(live, jnp.clip(q_lens[row] - j * C, 0, C), 0)
-    valid = local[None, :] < n_valid[:, None]                # [N, C]
-    tok = jnp.clip(tok0[:, None] + local[None, :], 0, T - 1)
+    (is_pre, ch_start, ch_end, live, row, first, tok0, n_valid, valid,
+     tok) = packed_chunks(cu, T, S, N, C)
 
     with jax.named_scope("gdn_conv"):
         # the K-1 inputs before each chunk: the row's carried state for
@@ -366,12 +353,7 @@ def _gdn_chunk_rows(mixed, g, beta, cu, slots, dummy, conv_state, rec_state,
             rec_state = rec_state.at[w_slots].set(states[:S])
     else:
         raise ValueError(f"GDN impl {impl!r}: 'pallas' or 'xla'")
-    # where each flat token sits in the packed layout
-    t_idx = jnp.arange(T, dtype=jnp.int32)
-    t_row = jnp.minimum(jnp.searchsorted(cu[1:], t_idx, side="right"),
-                        S - 1).astype(jnp.int32)
-    t_local = t_idx - cu[t_row]
-    slot_of_token = (ch_start[t_row] + t_local // C) * C + t_local % C
+    slot_of_token, t_row = packed_slot_of_token(cu, ch_start, T, S, C)
     return core, slot_of_token, t_row, conv_state, rec_state
 
 

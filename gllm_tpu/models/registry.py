@@ -135,6 +135,19 @@ def get_model_def(cfg: ModelConfig) -> ModelDef:
             param_specs=hybrid_param_specs,
             kv_specs=hybrid_kv_specs,
         )
+    if cfg.architecture in _NEMOTRON_H_ARCHS:
+        from gllm_tpu.models import nemotron_h
+        return ModelDef(
+            family="nemotron_h",
+            init_params=nemotron_h.init_params,
+            forward=nemotron_h.forward,
+            compute_logits=nemotron_h.compute_logits,
+            make_rope_table=nemotron_h.make_rope_table,
+            load_params=nemotron_h.load_params,
+            init_kv_cache=nemotron_h.init_kv_cache,
+            param_specs=nemotron_h.no_mesh_specs,
+            kv_specs=nemotron_h.no_mesh_specs,
+        )
     raise NotImplementedError(
         f"architecture {cfg.architecture!r} not supported yet; "
         f"dense: {_DENSE_ARCHS}, moe: {_MOE_ARCHS}, mla: {_MLA_ARCHS}, "
@@ -185,6 +198,14 @@ _HYBRID_ARCHS = (
 )
 
 
+_NEMOTRON_H_ARCHS = (
+    # nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B (model_type nemotron_h): blocks
+    # of one mixer each (Mamba-2 | relu^2 experts | GQA without rotary) in
+    # the pattern ``hybrid_override_pattern`` spells (models/nemotron_h.py)
+    "NemotronHForCausalLM",
+)
+
+
 def supported_architectures() -> Dict[str, str]:
     out = {a: "dense" for a in _DENSE_ARCHS}
     out.update({a: "moe" for a in _MOE_ARCHS})
@@ -193,4 +214,5 @@ def supported_architectures() -> Dict[str, str]:
     out.update({a: "vl3" for a in _VL3_ARCHS})
     out["KimiK25ForConditionalGeneration"] = "kimi"
     out.update({a: "hybrid" for a in _HYBRID_ARCHS})
+    out.update({a: "nemotron_h" for a in _NEMOTRON_H_ARCHS})
     return out

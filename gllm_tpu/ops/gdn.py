@@ -21,7 +21,7 @@ Design notes:
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -30,27 +30,30 @@ import jax.numpy as jnp
 GDN_CHUNK = 64      # tokens in a chunk of the chunked rule
 
 
-def gdn_chunk_slots(tokens_pad: int, seqs_pad: int) -> Tuple[int, int]:
+def gdn_chunk_slots(tokens_pad: int, seqs_pad: int,
+                    chunk: int = GDN_CHUNK) -> Tuple[int, int]:
     """(chunks, tokens per chunk) of the packed layout a mixed step of
     ``tokens_pad`` token slots and ``seqs_pad`` rows runs the chunked rule
     over. A prefilling row of n tokens takes ceil(n / C) chunks, so the
     rows of a step need at most tokens / C + (rows that prefill) of them;
     the layout holds twice tokens / C where the row bucket allows that
     many rows, and the batch builder picks a token bucket whose layout
-    holds the step (``BatchBuilder.shape_signature``)."""
-    c = min(GDN_CHUNK, tokens_pad)
+    holds the step (``BatchBuilder.shape_signature``). ``chunk``: the
+    model's chunk (``ModelConfig.ssm_chunk``: 64 for the GDN rule, 128 for
+    Mamba-2's)."""
+    c = min(chunk, tokens_pad)
     n = tokens_pad // c
     return n + min(seqs_pad, n), c
 
 
-def gdn_chunk_rows_cap(max_tokens: int) -> int:
+def gdn_chunk_rows_cap(max_tokens: int, chunk: int = GDN_CHUNK) -> int:
     """Rows with more than one new token that a step of a hybrid model may
     hold (the scheduler's cap): what the layout of the LARGEST token
     bucket takes beside the step's tokens. Rows of n_i tokens need
     sum(ceil(n_i / C)) <= tokens // C + rows chunks, and that layout has
     max_tokens // C + min(row bucket, max_tokens // C) of them, so a step
     under the cap always finds a built-in bucket that holds it."""
-    return max_tokens // min(GDN_CHUNK, max_tokens)
+    return max_tokens // min(chunk, max_tokens)
 
 
 def gdn_chunks_needed(q_lens, c: int) -> int:
@@ -59,13 +62,63 @@ def gdn_chunks_needed(q_lens, c: int) -> int:
     return sum(-(-n // c) for n in q_lens if n > 1)
 
 
+class PackedChunks(NamedTuple):
+    """Where the rows of a mixed step that prefill sit in the packed
+    layout of ``gdn_chunk_slots`` (N chunks of C tokens): per row [S] and
+    per chunk [N] index arithmetic shared by the GDN and Mamba-2 layers."""
+    is_pre: jnp.ndarray     # [S] the row has more than one new token
+    ch_start: jnp.ndarray   # [S] the row's first chunk
+    ch_end: jnp.ndarray     # [S] one past its last chunk
+    live: jnp.ndarray       # [N] the chunk belongs to a row
+    row: jnp.ndarray        # [N] which row
+    first: jnp.ndarray      # [N] it is its row's first chunk
+    tok0: jnp.ndarray       # [N] flat index of its first token
+    n_valid: jnp.ndarray    # [N] real tokens in it
+    valid: jnp.ndarray      # [N, C]
+    tok: jnp.ndarray        # [N, C] flat token index (clipped)
+
+
+def packed_chunks(cu: jnp.ndarray, T: int, S: int, N: int,
+                  C: int) -> PackedChunks:
+    q_lens = cu[1:] - cu[:-1]
+    is_pre = q_lens > 1
+    n_ch = jnp.where(is_pre, (q_lens + C - 1) // C, 0)       # [S]
+    ch_end = jnp.cumsum(n_ch)
+    ch_start = ch_end - n_ch
+    c_idx = jnp.arange(N, dtype=jnp.int32)
+    live = c_idx < ch_end[-1]
+    row = jnp.minimum(jnp.searchsorted(ch_end, c_idx, side="right"),
+                      S - 1).astype(jnp.int32)
+    j = c_idx - ch_start[row]                # chunk number inside its row
+    first = live & (j == 0)
+    tok0 = cu[row] + j * C
+    local = jnp.arange(C, dtype=jnp.int32)
+    n_valid = jnp.where(live, jnp.clip(q_lens[row] - j * C, 0, C), 0)
+    valid = local[None, :] < n_valid[:, None]                # [N, C]
+    tok = jnp.clip(tok0[:, None] + local[None, :], 0, T - 1)
+    return PackedChunks(is_pre, ch_start, ch_end, live, row, first, tok0,
+                        n_valid, valid, tok)
+
+
+def packed_slot_of_token(cu: jnp.ndarray, ch_start: jnp.ndarray, T: int,
+                         S: int, C: int):
+    """(for each flat token its slot in the packed layout [T], its row
+    [T]); valid for the tokens of rows that prefill."""
+    t_idx = jnp.arange(T, dtype=jnp.int32)
+    t_row = jnp.minimum(jnp.searchsorted(cu[1:], t_idx, side="right"),
+                        S - 1).astype(jnp.int32)
+    t_local = t_idx - cu[t_row]
+    return (ch_start[t_row] + t_local // C) * C + t_local % C, t_row
+
+
 def l2norm(x: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
     inv = jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
     return x * inv
 
 
 def causal_conv1d(x: jnp.ndarray, state: jnp.ndarray, weight: jnp.ndarray,
-                  q_lens: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                  q_lens: jnp.ndarray, bias: Optional[jnp.ndarray] = None
+                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Depthwise causal conv with carried state (reference
     mamba/causal_conv1d_triton.py semantics, varlen + state slots).
 
@@ -74,7 +127,7 @@ def causal_conv1d(x: jnp.ndarray, state: jnp.ndarray, weight: jnp.ndarray,
         first (channels last, as the slot pool keeps them: on the TPU the
         channel axis lies along the lanes and a row of the pool moves
         without a change of layout)
-    weight: [C, K]
+    weight: [C, K]; bias: [C] or None (Mamba-2's convolution has one)
     Returns (silu(conv(x)) [S, T, C], new_state [S, K-1, C]) where the new
     state holds the last K-1 valid inputs (padding excluded).
     """
@@ -85,6 +138,8 @@ def causal_conv1d(x: jnp.ndarray, state: jnp.ndarray, weight: jnp.ndarray,
                           axis=1)                     # [S, K-1+T, C]
     out = sum(buf[:, j:j + T, :] * weight[:, j].astype(jnp.float32)
               for j in range(K))
+    if bias is not None:
+        out = out + bias.astype(jnp.float32)
     out = jax.nn.silu(out)
     # new state = inputs at positions q_len-1 ... q_len-(K-1) of the valid
     # region, i.e. buf rows [q_len, q_len+K-2] (buf row i holds input i-K+1)

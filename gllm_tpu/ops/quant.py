@@ -201,6 +201,7 @@ QUANT_LEAVES = frozenset({
     "q_b_proj", "shared_gate_proj", "shared_up_proj", "shared_down_proj",
     "w_gate", "w_up", "w_down",
     "in_qkvz", "out_proj",                       # hybrid GDN projections
+    "in_proj",                                   # Mamba-2's (out_proj too)
 })
 
 _MODES = {"int8": jnp.int8, "fp8": jnp.float8_e4m3fn}

@@ -29,7 +29,8 @@ import numpy as np
 from gllm_tpu.batching import StepBatch
 from gllm_tpu.config import EngineConfig
 from gllm_tpu.ops.attention import AttentionMetadata
-from gllm_tpu.ops.gdn import gdn_chunk_slots, gdn_chunks_needed
+from gllm_tpu.ops.gdn import (GDN_CHUNK, gdn_chunk_slots,
+                              gdn_chunks_needed)
 from gllm_tpu.obs import metrics as obs
 from gllm_tpu.ops.sampling import SamplingMetadata
 from gllm_tpu.scheduler import ScheduledBatch
@@ -51,13 +52,31 @@ _M_GDN_CHUNK_TOKENS = obs.counter(
     "gllm_gdn_chunk_tokens_total",
     "real tokens in those layouts (the new tokens of the rows that "
     "prefilled)")
+# the same three for a model whose recurrent layers are Mamba-2
+_M_MAMBA_ROWS = obs.counter(
+    "gllm_mamba_rows_total",
+    "rows of a Mamba-2 model's steps by the rule they took: recurrent "
+    "(one new token) or chunk (more)", ("path",))
+_M_MAMBA_CHUNK_SLOTS = obs.counter(
+    "gllm_mamba_chunk_slots_total",
+    "token slots of the packed layouts the Mamba-2 chunked rule computed "
+    "over (chunks x tokens per chunk of each mixed step)")
+_M_MAMBA_CHUNK_TOKENS = obs.counter(
+    "gllm_mamba_chunk_tokens_total",
+    "real tokens in those layouts (the new tokens of the rows that "
+    "prefilled)")
+_SSM_COUNTERS = {
+    "gdn": (_M_GDN_ROWS, _M_GDN_CHUNK_SLOTS, _M_GDN_CHUNK_TOKENS),
+    "mamba": (_M_MAMBA_ROWS, _M_MAMBA_CHUNK_SLOTS, _M_MAMBA_CHUNK_TOKENS),
+}
 
 
 class BatchBuilder:
     def __init__(self, config: EngineConfig, page_size: int,
                  vocab_size: int = 0, hidden_size: int = 0,
                  use_mm: bool = False, use_ssm: bool = False,
-                 mm_embed_dim: int = 0, seq_slots: bool = False):
+                 mm_embed_dim: int = 0, seq_slots: bool = False,
+                 ssm_chunk: int = GDN_CHUNK, ssm_kind: str = "gdn"):
         self.config = config
         self.page_size = page_size
         self.vocab_size = vocab_size
@@ -67,6 +86,10 @@ class BatchBuilder:
         self.mm_embed_dim = mm_embed_dim or hidden_size
         self.use_mm = use_mm
         self.use_ssm = use_ssm
+        # the recurrent layers' chunk (ModelConfig.ssm_chunk) sizes a
+        # mixed step's packed layout; their kind names the counters
+        self.ssm_chunk = ssm_chunk
+        self._m_rows, self._m_slots, self._m_tokens = _SSM_COUNTERS[ssm_kind]
         # a per-sequence slot rides the batch (``ssm_slots``): recurrent
         # state, or a windowed layer's ring
         self.use_slots = use_ssm or seq_slots
@@ -136,7 +159,7 @@ class BatchBuilder:
                 # holds every step the scheduler forms
                 # (ops/gdn.gdn_chunk_rows_cap)
                 while True:
-                    n, c = gdn_chunk_slots(t, s)
+                    n, c = gdn_chunk_slots(t, s, self.ssm_chunk)
                     if gdn_chunks_needed(rows, c) <= n:
                         break
                     if t >= self.max_tokens:
@@ -474,12 +497,12 @@ class BatchBuilder:
                 np.int32, count=K)
         if self.use_ssm:
             n_chunk = int((ns > 1).sum())
-            _M_GDN_ROWS.inc(K - n_chunk, path="recurrent")
+            self._m_rows.inc(K - n_chunk, path="recurrent")
             if n_chunk:
-                n, c = gdn_chunk_slots(t_pad, s_pad)
-                _M_GDN_ROWS.inc(n_chunk, path="chunk")
-                _M_GDN_CHUNK_SLOTS.inc(n * c)
-                _M_GDN_CHUNK_TOKENS.inc(int(ns[ns > 1].sum()))
+                n, c = gdn_chunk_slots(t_pad, s_pad, self.ssm_chunk)
+                self._m_rows.inc(n_chunk, path="chunk")
+                self._m_slots.inc(n * c)
+                self._m_tokens.inc(int(ns[ns > 1].sum()))
 
         for i, it in enumerate(items):
             sp = sps[i]

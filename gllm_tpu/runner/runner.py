@@ -379,21 +379,26 @@ def resolve_attn_impl(impl: str, cfg: ModelConfig, tp: int, pack: int,
     elif tp_sharded and not pallas_tp_ok(cfg, tp):
         why = f"head counts do not divide over tp={tp}"
     if cfg.use_hybrid:
-        # the two kinds of layer choose apart: the GDN layers' head dims
-        # (96 / 192 in Olmo-Hybrid) say nothing about the full-attention
-        # layers' kernels
+        # the two kinds of layer choose apart: the recurrent layers' head
+        # dims (96 / 192 in Olmo-Hybrid, 64 / 128 in NemotronH) say nothing
+        # about the full-attention layers' kernels
         from gllm_tpu.ops.gdn import gdn_impl_for
+        rule, files, half = (
+            ("Mamba-2", "mamba2_recurrent.py, mamba2_scan.py",
+             "ops/mamba2.py") if cfg.use_mamba else
+            ("GDN", "gdn_recurrent.py, gdn_scan.py", "ops/gdn.py"))
         if gdn_impl_for("pallas" if why is None else "xla",
                         tp > 1) == "pallas":
-            gdn = "pallas (ops/pallas/gdn_recurrent.py, gdn_scan.py)"
+            gdn = f"pallas (ops/pallas/{files})"
         elif why is not None:
             gdn = "xla (attention runs no Pallas kernel here)"
         else:
             gdn = f"xla (the slot pool is sharded over tp={tp})"
         logger.info(
-            "[startup] hybrid: full attention -> %s; GDN recurrent step "
-            "and chunk scan -> %s; GDN in-chunk half -> xla (ops/gdn.py)",
-            "pallas" if why is None else f"xla ({why})", gdn)
+            "[startup] hybrid: full attention -> %s; %s recurrent step "
+            "and chunk scan -> %s; %s in-chunk half -> xla (%s)",
+            "pallas" if why is None else f"xla ({why})", rule, gdn, rule,
+            half)
     if cfg.dense_mla:
         # dense latent attention: every layer attends its whole context
         # over the paged latent pool, one KV head under all query heads
@@ -600,7 +605,10 @@ class ModelRunner:
                                     use_mm=model_cfg.use_mm,
                                     use_ssm=model_cfg.use_hybrid,
                                     seq_slots=model_cfg.use_swa,
-                                    mm_embed_dim=model_cfg.mm_embed_dim)
+                                    mm_embed_dim=model_cfg.mm_embed_dim,
+                                    ssm_chunk=model_cfg.ssm_chunk,
+                                    ssm_kind=("mamba" if model_cfg.use_mamba
+                                              else "gdn"))
         if model_cfg.use_mm:
             from gllm_tpu.utils import LRUBytesCache
             self._mm_cache = LRUBytesCache()
@@ -735,6 +743,36 @@ class ModelRunner:
             1 + self.ssm_working_slots + self.ssm_snapshot_slots
             if model_cfg.use_hybrid else 0, self._ssm_pool_bytes(),
             self._gdn_chunk_temp_bytes())
+        if model_cfg.use_mamba:
+            # a state-space hybrid: what the chip holds, in one line
+            slots = 1 + self.ssm_working_slots + self.ssm_snapshot_slots
+            logger.info(
+                "[startup] state-space model: weights %d bytes (%d of %d "
+                "routed experts a layer held here); Mamba-2 slot pool %d "
+                "slots x %d layers x %d bytes as the TPU stores them = %d "
+                "bytes; KV pool of the %d attention layers %d bytes",
+                sum(x.size * x.dtype.itemsize
+                    for x in jax.tree.leaves(self.params)),
+                model_cfg.num_local_experts, model_cfg.num_experts, slots,
+                model_cfg.num_linear_layers,
+                self._ssm_pool_bytes() // (model_cfg.num_linear_layers
+                                           * slots),
+                self._ssm_pool_bytes(), model_cfg.num_attn_layers,
+                self.num_pages * self._kv_bytes_per_page())
+            # which kernel multiplies the held experts (models/deepseek.
+            # _grouped_dot): it falls back silently otherwise, and a
+            # --quantization run times another kernel than a plain one
+            from gllm_tpu.ops.gdn import gdn_impl_for
+            if gdn_impl_for(self.attn_impl,
+                            config.parallel.tp > 1) != "pallas":
+                experts = "xla ragged_dot (the Mamba-2 kernels run in XLA)"
+            elif config.quantization:
+                experts = ("xla ragged_dot (the Pallas kernel reads plain "
+                           f"stacks, these are {config.quantization})")
+            else:
+                experts = "pallas gmm (ops/pallas/grouped_matmul.py)"
+            logger.info("[startup] held experts: grouped products -> %s",
+                        experts)
         if model_cfg.dense_mla:
             # dense latent attention: what the chip holds, in one line
             # beside the line that says which kernel serves which kind of
@@ -924,25 +962,25 @@ class ModelRunner:
         return rows + item + moe
 
     def _ssm_pool_bytes(self, cfg: Optional[ModelConfig] = None) -> int:
-        """Device bytes of the GDN slot pools of ``cfg``'s stage (this
-        runner's whole model by default), as the TPU stores them: a
+        """Device bytes of the recurrent layers' slot pools of ``cfg``'s
+        stage (this runner's whole model by default; what a slot stores:
+        ``ModelConfig.ssm_slot_shapes``), as the TPU stores them: a
         float32 array lies in tiles of 8 x 128 over its last two
-        dimensions, so a [Dk, Dv] state of 96 x 192 takes 96 x 256 (a
-        third more than its elements); the convolution pool's last two
-        dimensions are folded into the slot axis's tile (measured with
-        the TPU compiler: tests/test_tpu_compile.py)."""
+        dimensions, so a GDN state of 96 x 192 takes 96 x 256 (a third
+        more than its elements; a Mamba-2 state of 64 x 128 its own
+        size); the convolution pool's last two dimensions are folded
+        into the slot axis's tile (measured with the TPU compiler:
+        tests/test_tpu_compile.py)."""
         cfg = cfg or self.model_cfg
         if not cfg.use_hybrid:
             return 0
         slots = 1 + self.ssm_working_slots + self.ssm_snapshot_slots
+        (taps, channels), (heads, rows, lanes) = cfg.ssm_slot_shapes
 
         def up(n, m):
             return -(-n // m) * m
-        rec = (slots * cfg.linear_num_value_heads
-               * up(cfg.linear_key_head_dim, 8)
-               * up(cfg.linear_value_head_dim, 128))
-        conv = (up(slots, 8) * cfg.gdn_conv_dim
-                * (cfg.linear_conv_kernel_dim - 1))
+        rec = slots * heads * up(rows, 8) * up(lanes, 128)
+        conv = up(slots, 8) * channels * taps
         return cfg.num_linear_layers * (rec + conv) * 4
 
     def _gdn_chunk_temp_bytes(self) -> int:
@@ -960,12 +998,21 @@ class ModelRunner:
         cfg = self.model_cfg
         if not cfg.use_hybrid:
             return 0
-        from gllm_tpu.ops.gdn import GDN_CHUNK, gdn_chunk_slots
-        per_slot = 4 * cfg.linear_num_value_heads * (
-            6 * cfg.linear_key_head_dim + 6 * cfg.linear_value_head_dim
-            + 4 * GDN_CHUNK)
+        from gllm_tpu.ops.gdn import gdn_chunk_slots
         n, c = gdn_chunk_slots(self.builder.max_tokens,
-                               self.builder.max_seqs)
+                               self.builder.max_seqs, cfg.ssm_chunk)
+        if cfg.use_mamba:
+            # Mamba-2's rule (ops/mamba2.py): dt x, its decayed and
+            # transposed forms, the in-chunk output, the output and a
+            # spare (6 P), C e^l and a spare (2 N) and three C x C
+            # matrices a head
+            per_slot = 4 * cfg.mamba_num_heads * (
+                6 * cfg.mamba_head_dim + 2 * cfg.ssm_state_size
+                + 3 * cfg.ssm_chunk)
+        else:
+            per_slot = 4 * cfg.linear_num_value_heads * (
+                6 * cfg.linear_key_head_dim + 6 * cfg.linear_value_head_dim
+                + 4 * cfg.ssm_chunk)
         return n * c * per_slot
 
     def determine_num_pages(self) -> int:

@@ -264,7 +264,8 @@ class Scheduler:
             spec_rows = config.spec_k if config.spec_decode else 0
             self._chunk_rows_cap = gdn_chunk_rows_cap(
                 self.sched_cfg.max_prefill_tokens
-                + self.sched_cfg.max_decode_seqs * (1 + spec_rows))
+                + self.sched_cfg.max_decode_seqs * (1 + spec_rows),
+                self.mm.ssm_chunk)
         self.chain_break_reason: Optional[str] = None
         # Why the last schedule_reform refused (pipelined loop — feeds
         # the engine's loop_stall reason classification): spec / shape /

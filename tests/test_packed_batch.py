@@ -440,7 +440,8 @@ def test_the_manifest_names_the_counters_reader():
         "better": "lower", "source": "program_counter", "layer": "runner",
         "moves": "output_tok_s",
         "workloads": ["qwen3-4b.reason", "olmo-hybrid-7b.reason",
-                      "dots3-note-prev.longdoc", "a.x-k1.docqa"]}]
+                      "dots3-note-prev.longdoc", "a.x-k1.docqa",
+                      "nemotron-3-nano-30b-a3b.reason"]}]
     path = os.path.join(root, "perfbench", "layer_metrics",
                         "runner.h2d_arrays_per_step.py")
     spec = importlib.util.spec_from_file_location("h2d_reader", path)

@@ -906,3 +906,126 @@ def test_axk1_mixed_step_compiles_for_v5e(topo, on_tpu, monkeypatch, tokens):
     assert attention_calls(c.compiled) == MIXED_STEP_CALLS
     assert mem.temp_size_in_bytes < 2.0 * GiB
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14 * GiB
+
+
+# ---- the state-space hybrid at Nemotron 3 Nano's widths ---------------------
+
+def nemotron_cfg() -> ModelConfig:
+    """perfbench/configs/nemotron-3-nano-30b-a3b.json: published widths
+    (Mamba-2 of 64 heads x 64 over a state of 128, GQA 32 / 2 of 128, 64
+    held relu^2 experts of 2688 x 1856), the first 16 of 52 blocks."""
+    from gllm_tpu.models.config import from_hf_config
+    return from_hf_config(_perfbench_hf("nemotron-3-nano-30b-a3b"))
+
+
+def _nemotron_runner(topo, monkeypatch):
+    return make_runner(nemotron_cfg(), topo, num_pages=8320,
+                       monkeypatch=monkeypatch, max_num_seqs=64,
+                       max_model_len=4096, attention_impl="auto")
+
+
+def _pallas_calls(compiled) -> list:
+    # a kernel with two results has a tuple type, with blanks in it
+    return sorted(set(re.findall(
+        r"^\s*(?:ROOT )?%(mamba2_\w+?|gmm)(?:\.\d+)? = [^\n]*? custom-call\(",
+        compiled.as_text(), re.M)))
+
+
+def test_mamba2_recurrent_kernel_compiles_for_v5e_at_64_by_128(topo, on_tpu):
+    """The decode kernel of the Mamba-2 layers alone, at the cell's
+    shapes: 64 rows of 64 heads of 64 x 128 (8 groups) in a pool of 7 x 65
+    slots. Mosaic takes the row-to-column turns and the half-lane rows."""
+    from gllm_tpu.ops.pallas.mamba2_recurrent import mamba2_recurrent_step
+    from gllm_tpu.utils import tpu_compiler_options
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one)
+    S, H, P, N, G, slots = 64, 64, 64, 128, 8, 455
+    fn = jax.jit(mamba2_recurrent_step.__wrapped__, donate_argnums=(4,),
+                 compiler_options=tpu_compiler_options())
+    compiled = fn.lower(sds((S, H, P)), sds((S, H)), sds((S, G, N)),
+                        sds((S, G, N)), sds((slots, H, P, N)),
+                        sds((S,), jnp.int32)).compile()
+    assert has_kernel(compiled)
+    assert "mamba2_recurrent_step" in compiled.as_text()
+
+
+def test_mamba2_chunk_scan_kernel_compiles_for_v5e_at_64_by_128(topo,
+                                                                on_tpu):
+    """The chunked rule's inter-chunk scan alone, at the cell's largest
+    layout: the 2112-token bucket's 32 chunks of 128 tokens, a group of 8
+    heads a grid step, in place in a pool of 7 x 65 slots."""
+    from gllm_tpu.ops.pallas.mamba2_scan import mamba2_chunk_scan
+    from gllm_tpu.utils import tpu_compiler_options
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one)
+    Nc, H, C, P, N, G, slots = 32, 64, 128, 64, 128, 8, 455
+    fn = jax.jit(mamba2_chunk_scan.__wrapped__, donate_argnums=(5,),
+                 compiler_options=tpu_compiler_options())
+    compiled = fn.lower(
+        sds((Nc, H, C, P)), sds((Nc, H, C, N)), sds((Nc, H, P, C)),
+        sds((Nc, G, C, N)), sds((Nc, H, 1, N)), sds((slots, H, P, N)),
+        sds((Nc,), jnp.int32), sds((Nc,), jnp.int32)).compile()
+    assert has_kernel(compiled)
+    assert "mamba2_chunk_scan" in compiled.as_text()
+
+
+def _nemotron_step(runner, batch, calls, mamba, temp_bound):
+    """Compile one step of the cell and hold it to: the attention kernels
+    at 2 KV heads under 16 query heads each, the Mamba-2 kernels and the
+    grouped product as Pallas calls, no copy of an expert or projection
+    stack, the weights as the configuration derives them and the slot pool
+    as ``_ssm_pool_bytes`` says, both within 1 %."""
+    c = compile_of(runner.step_async, _with_slots(batch))
+    text = c.compiled.as_text()
+    assert attention_calls(c.compiled) == calls
+    assert _pallas_calls(c.compiled) == ["gmm"] + mamba
+    mem = c.compiled.memory_analysis()
+    print(f"\n[compile] nemotron {calls[0]}: {c.seconds:.1f}s, "
+          f"{mem.argument_size_in_bytes / GiB:.2f} GiB of arguments, "
+          f"{mem.temp_size_in_bytes / GiB:.3f} GiB temp, "
+          f"{mem.generated_code_size_in_bytes / 1e6:.1f} MB of code")
+    assert mem.temp_size_in_bytes < temp_bound, mem.temp_size_in_bytes
+    # a stack whose last dimension is not whole lanes would be laid out
+    # transposed and copied back whole in every step (models/nemotron_h
+    # ``lanes``): no copy of a [7, ...] bf16 stack is left
+    assert not re.findall(r"= bf16\[7,[\d,]+\]\S* copy\(", text)
+    derived = _perfbench_hf("nemotron-3-nano-30b-a3b")["derived"]
+    weights = _weight_bytes(runner)
+    assert weights == derived["weight_bytes"]
+    kv_args = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                  for x in (runner.kv.k, runner.kv.v))
+    assert kv_args == derived["kv_pool_bytes"]
+    state = mem.argument_size_in_bytes - weights - kv_args
+    assert abs(state / runner._ssm_pool_bytes() - 1) < 0.01, (
+        state, runner._ssm_pool_bytes())
+    assert abs(runner._ssm_pool_bytes() / derived["state_pool_bytes"]
+               - 1) < 0.01
+
+
+def test_nemotron_decode_step_compiles_for_v5e(topo, on_tpu, monkeypatch):
+    """The cell's decode step: 64 rows at 129 pages in the 256-page
+    bucket, 11.3 GiB of arguments (10.88 GB of weights as stored, 0.99 GB
+    of state, 0.27 GB of KV) and 0.05 GiB of temporaries. Counted from
+    shapes by the compiler; nothing runs."""
+    runner = _nemotron_runner(topo, monkeypatch)
+    assert runner.attn_impl == "pallas"
+    _nemotron_step(runner, decode_batch(runner, 64, 129),
+                   ["paged_decode_attention"], ["mamba2_recurrent_step"],
+                   0.25 * GiB)
+
+
+@pytest.mark.slow
+def test_nemotron_largest_mixed_step_compiles_for_v5e(topo, on_tpu,
+                                                      monkeypatch):
+    """The cell's largest mixed step: a 2048-token chunk beside 63
+    decoding rows (the 2112-token program, 32 chunks of 128 in the packed
+    layout): both Mamba-2 kernels, both attention kernels, under 1.25 GiB
+    of temporaries beside 11.3 GiB of arguments."""
+    runner = _nemotron_runner(topo, monkeypatch)
+    _nemotron_step(runner, prefill_batch(runner, 2048, ndecode=63,
+                                         npages=129),
+                   MIXED_STEP_CALLS,
+                   ["mamba2_chunk_scan", "mamba2_recurrent_step"],
+                   1.25 * GiB)
