@@ -2,6 +2,7 @@
 which form for a step with many query tokens.
 
     python benchmarks/mla_attn_forms.py [--cpu-rehearsal] [--heads 64]
+    python benchmarks/mla_attn_forms.py --chosen-rows [--cpu-rehearsal]
 
 through the chip tool (one chip, ~6 min). At A.X-K1's geometry (64 query
 heads over one latent row of 512 + 64 lanes stored as 640, values the first
@@ -28,6 +29,18 @@ heads over one latent row of 512 + 64 lanes stored as 640, values the first
 Each line gives the call's milliseconds, the FLOP it has to do (causal:
 what the mask leaves) and that as a share of the chip's peak
 (perfbench/peaks.json). Results: ``chiprun_out/mla_attn_forms.json``.
+
+``--chosen-rows`` times, instead of all that (one chip, ~3 min), the
+decoding rows of a selected-attention layer at dots3-note-prev's geometry
+(PR 43): 64 rows of 128 heads over rows of 640 lanes, contexts drawn
+4096-9216 under a page table of 592 pages, each row attending 2048
+positions drawn from those it sees. ``chosen_rows_kernel``:
+``paged_decode_attention`` under the choice's mask over kv_block x group
+(``decode_mqa_chosen`` in ops/pallas/tables.json), with the same call
+without the mask beside it; ``chosen_rows_xla``: what the kernel took the
+place of (``models/deepseek._attend`` over the rows' whole pages), whose
+result the kernel's is compared with. Results:
+``chiprun_out/dsa_rows_forms.json``.
 """
 
 import argparse
@@ -46,6 +59,7 @@ def main():
     ap.add_argument("--cpu-rehearsal", action="store_true")
     ap.add_argument("--heads", type=int, default=64)
     ap.add_argument("--iters", type=int, default=5)
+    ap.add_argument("--chosen-rows", action="store_true")
     args = ap.parse_args()
     if args.cpu_rehearsal:
         os.environ["JAX_PLATFORMS"] = "cpu"
@@ -99,6 +113,13 @@ def main():
 
     results = []
 
+    def save(name, **kw):
+        out_dir = os.path.join(ROOT, "chiprun_out")
+        os.makedirs(out_dir, exist_ok=True)
+        with open(os.path.join(out_dir, name), "w") as f:
+            json.dump({"device": kind, "results": results, **kw}, f,
+                      indent=1)
+
     def note(what, ms, flop, **kw):
         line = dict(what=what, ms=round(ms, 3), gflop=round(flop / 1e9, 1),
                     **kw)
@@ -109,6 +130,12 @@ def main():
 
     pair_abs = 2 * width + 2 * lora         # as the kernel computes it
     pair_dec = 2 * (nope + rope) + 2 * vd
+
+    if args.chosen_rows:
+        chosen_rows(args, small, dtype, width, lora, scale, pair_abs,
+                    peaks.get(kind, {}).get("bytes_per_s"), opts, timed,
+                    note)
+        return save("dsa_rows_forms.json")
 
     def causal_pairs(q_len, ctx):
         """(query, key) pairs a chunk of ``q_len`` at the end of a context
@@ -243,11 +270,80 @@ def main():
              causal_pairs(chunk, ctx) * H * pair_dec + expand, ctx=ctx,
              expand_gflop=round(expand / 1e9, 1))
 
-    out_dir = os.path.join(ROOT, "chiprun_out")
-    os.makedirs(out_dir, exist_ok=True)
-    with open(os.path.join(out_dir, "mla_attn_forms.json"), "w") as f:
-        json.dump({"device": kind, "heads": H, "results": results}, f,
-                  indent=1)
+    save("mla_attn_forms.json", heads=H)
+
+
+def chosen_rows(args, small, dtype, width, lora, scale, pair_abs, hbm,
+                opts, timed, note):
+    """The ``--chosen-rows`` lines (the module docstring)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from gllm_tpu.models import deepseek
+    from gllm_tpu.ops.pallas.decode_attention import paged_decode_attention
+    from gllm_tpu.ops.pallas.tuning import decode_blocks
+    H, S, page = (4, 6, 16) if small else (128, 64, 16)
+    lo, hi, pages, topk = ((40, 150, 10, 32) if small
+                           else (4096, 9216, 592, 2048))
+    rng = np.random.default_rng(43)
+    lens = rng.integers(lo, hi + 1, S).astype(np.int32)
+    per = -(-hi // page)
+    pt = np.zeros((S, pages), np.int32)
+    pt[:, :per] = 1 + np.arange(S * per, dtype=np.int32).reshape(S, per)
+    mask = np.zeros((S, pages * page), bool)
+    for s, n in enumerate(lens):
+        mask[s, rng.choice(n, min(topk, n), replace=False)] = True
+    key = jax.random.key(1)
+    pool = jax.random.normal(key, (S * per + 1, page, width),
+                             jnp.float32).astype(dtype)
+    q = jax.random.normal(jax.random.key(2), (S, H, width),
+                          jnp.float32).astype(dtype)
+    lens_j, pt_j, mask_j = (jnp.asarray(a) for a in (lens, pt, mask))
+    ctx_rows = int(lens.sum())
+    row_bytes = width * jnp.dtype(dtype).itemsize
+
+    def line(what, ms, read, **kw):
+        """``read``: the context rows the call reads."""
+        note(what, ms, read * H * pair_abs, ns_a_row=round(
+            ms * 1e6 / read, 3), hbm_pct=round(
+            100 * read * row_bytes / (ms * 1e-3) / hbm, 1) if hbm else None,
+            **kw)
+
+    xla = jax.jit(lambda q, pool, pt, m: deepseek._attend(
+        q, pool[pt].reshape(S, pages * page, width), m, scale=scale,
+        lora=lora), compiler_options=opts)
+    want = np.asarray(xla(q, pool, pt_j, mask_j), np.float32)
+    line("chosen_rows_xla", timed(xla, q, pool, pt_j, mask_j),
+         S * pages * page, rows=S, padded_ctx=pages * page)
+
+    table = decode_blocks(1, chosen=True)
+    grid = ([(32, 2)] if small else
+            [(table["kv_block"], int(table.get("group", 1))), (512, 4),
+             (256, 4), (1024, 4), (512, 2), (1024, 2), (256, 8), (512, 1),
+             (1024, 1), (2048, 1), (2048, 2)])
+    for kvb, grp in dict.fromkeys(grid):
+        for masked in (True, False):
+            fn = jax.jit(lambda q, k, kl, pt, m, kvb=kvb, grp=grp,
+                         masked=masked: paged_decode_attention(
+                q, k[:, :, None, :], None, kl, pt, scale=scale, v_dim=lora,
+                kv_block=kvb, group_size=grp, interpret=small,
+                chosen=m if masked else None,
+                name=deepseek.DSA_ROWS_NAME if masked else None),
+                compiler_options=opts)
+            try:
+                ms = timed(fn, q, pool, lens_j, pt_j, mask_j)
+            except Exception as e:      # Mosaic refusing a block pair
+                print(f"chosen_rows kv_block={kvb} group={grp} "
+                      f"masked={masked}: {str(e)[:200]}", flush=True)
+                continue
+            err = None
+            if masked:
+                got = np.asarray(fn(q, pool, lens_j, pt_j, mask_j),
+                                 np.float32)
+                err = float(np.abs(got - want).max())
+            line("chosen_rows_kernel", ms, ctx_rows, rows=S,
+                 ctx_mean=round(ctx_rows / S), kv_block=kvb, group=grp,
+                 masked=masked, max_abs_diff_from_xla=err)
 
 
 if __name__ == "__main__":

@@ -122,6 +122,42 @@ def count_rows_read(cfg: ModelConfig, kv_lens, decode_only: bool) -> None:
                     step="decode" if decode_only else "mixed")
 
 
+_M_DSA_ROWS = obs.counter(
+    "gllm_dsa_rows_attended_total",
+    "One-token rows (decoding rows, alone or beside a chunk) a step's "
+    "selected-attention layers attended, summed over the full layers, by "
+    "what computed them: the paged decode kernel under the selection's "
+    "mask (kernel) or the gather of whole pages and XLA's products (xla)",
+    ("path",))
+
+# The masked decode call's name in the HLO and on the trace's ``XLA Ops``
+# line: its own, so that a reader tells it from the other families'
+# ``paged_decode_attention`` / ``ragged_paged_attention_decode_rows``.
+DSA_ROWS_NAME = "dsa_rows_decode_attention"
+
+
+def dsa_rows_path(attn_impl: str, meshed: Optional[bool] = None) -> str:
+    """What computes a selected-attention layer's one-token rows:
+    ``kernel`` where the runner runs attention on Pallas and no mesh is
+    bound (the other families' Pallas calls go through a ``shard_map``
+    under tp, which this call does not have), else ``xla``. ``meshed``:
+    whether the runner has a mesh, asked outside a trace; inside one the
+    bound mesh is read."""
+    if meshed is None:
+        from gllm_tpu.parallel.mesh import active_mesh
+        meshed = bool(active_mesh().shape_tuple)
+    return "kernel" if attn_impl == "pallas" and not meshed else "xla"
+
+
+def count_rows_attended(cfg: ModelConfig, cu_q_lens, path: str) -> None:
+    """One step's one-token rows x full layers into the counter, from the
+    batch's ``cu_q_lens`` as the host built them (no device value is
+    read)."""
+    q_lens = cu_q_lens[1:] - cu_q_lens[:-1]
+    layers = cfg.num_attn_layers if cfg.use_swa else cfg.num_stage_layers
+    _M_DSA_ROWS.inc(int((q_lens == 1).sum()) * layers, path=path)
+
+
 def count_stats(stats, decode_only: bool) -> None:
     """One step's ``LatentKVCache.stats`` (host array) into the counters."""
     v = [int(x) for x in stats]
@@ -666,7 +702,7 @@ def _index_qkw(lp, x, q_resid, batch: StepBatch, cfg: ModelConfig, cos_sin):
 
 def _dsa_attention(lp, x, q_resid, q_full, batch: StepBatch, latent_cache,
                    index_cache, index_scale, cfg: ModelConfig, cos_sin, *,
-                   max_q_len: int, g: Geom):
+                   max_q_len: int, g: Geom, attn_impl: str = "xla"):
     """DSA: the indexer scores every visible position of a token's
     sequence, the ``index_topk`` largest are chosen (all of them while
     there are no more), and the token attends the chosen latent rows only.
@@ -681,8 +717,17 @@ def _dsa_attention(lp, x, q_resid, q_full, batch: StepBatch, latent_cache,
     bandwidth and took, with the page lookup of every chosen position,
     three quarters of a mixed step (PERF.md, PR 34). A decoding row does
     the same (2048 rows of 16 spread over a context of a few times that
-    touch nearly every page of it). Reading only the chosen rows, which
-    contexts of many times the top-k need, is not here (ROADMAP B7).
+    touch nearly every page of it), and where attention runs on Pallas
+    (``dsa_rows_path``) it does so on the paged decode kernel: the
+    kernel streams the row's own pages, its context and no more, and
+    takes the choice as a mask over positions, so no [rows, padded
+    context, width] copy of the pages and no float32 scores over them
+    exist (5.5 ms a full layer of the cell's decode step when they did;
+    PERF.md, PR 43). Elsewhere (CPU, ``attention_impl=xla``, a mesh) the
+    rows are gathered as whole pages and attended in XLA, which is also
+    the oracle the kernel is tested against. Both read every page of a
+    context: reading only the chosen rows, which contexts of many times
+    the top-k need, is not here (ROADMAP B7).
     Returns (out_lat [T, H, lora] float32, index_cache, index_scale,
     stats [2])."""
     T = x.shape[0]
@@ -727,10 +772,24 @@ def _dsa_attention(lp, x, q_resid, q_full, batch: StepBatch, latent_cache,
     r_pos = rg.kv_lens - rg.q_lens
     logits = index_logits(qi[rg.first], wi[rg.first], md.page_table)
     vis = (kv_pos[None, :] <= r_pos[:, None]) & (rg.q_lens > 0)[:, None]
-    rows = _attend(q_full[rg.first], of_seq(latent_cache, md.page_table),
-                   _largest(logits, vis, kk), scale=g.scale, lora=g.lora)
+    chosen = _largest(logits, vis, kk)
+    if dsa_rows_path(attn_impl) == "kernel":
+        # a sequence that brings a chunk has its context at 0 here: the
+        # kernel skips it without a fetch or a dot and gives zeros
+        from gllm_tpu.ops.attention import _decode_kernel
+        rows = _decode_kernel(
+            q_full[rg.first], latent_cache[:, :, None, :], None,
+            jnp.where(rg.q_lens == 1, rg.kv_lens, 0), md.page_table,
+            None, None, scale=g.scale, v_dim=g.lora, chosen=chosen,
+            interpret=jax.default_backend() != "tpu", name=DSA_ROWS_NAME
+        ).astype(jnp.float32)
+    else:
+        rows = jnp.where(
+            (rg.q_lens == 1)[:, None, None],
+            _attend(q_full[rg.first], of_seq(latent_cache, md.page_table),
+                    chosen, scale=g.scale, lora=g.lora), 0.0)
     out = jnp.zeros((T, g.heads, g.lora), jnp.float32).at[rg.first].set(
-        jnp.where((rg.q_lens == 1)[:, None, None], rows, 0.0))
+        rows)
 
     if max_q_len > 1:
         qi_p, wi_p, qf_p = _pad_rows(qi), _pad_rows(wi), _pad_rows(q_full)
@@ -908,7 +967,8 @@ def _mla_attention(lp, x, batch: StepBatch, latent_cache, cfg: ModelConfig,
         # chosen latent rows only (reference deepseek_v32.py).
         out_lat, index_cache, index_scale, stats = _dsa_attention(
             lp, x, qa, q_full, batch, latent_cache, index_cache,
-            index_scale, cfg, cos_sin, max_q_len=max_q_len, g=g)
+            index_scale, cfg, cos_sin, max_q_len=max_q_len, g=g,
+            attn_impl=attn_impl)
     else:
         # MQA over the latent cache; values are the latent prefix of the
         # keys (v_cache=None → the Pallas kernels read v from the k block

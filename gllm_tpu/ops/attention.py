@@ -294,17 +294,18 @@ def _paged_attention(
 
 def _decode_kernel(q, k_cache, v_cache, kv_lens, page_table, k_scale,
                    v_scale, *, scale: float, interpret: bool, v_dim,
-                   name=None):
+                   name=None, chosen=None):
     """The decode kernel over one query row a sequence, at the table's
-    blocks for the cache's KV heads."""
+    blocks for the cache's KV heads (and for a selection's mask,
+    ``chosen``, where the call takes one)."""
     from gllm_tpu.ops.pallas.decode_attention import paged_decode_attention
     from gllm_tpu.ops.pallas.tuning import decode_blocks
-    cfg = decode_blocks(k_cache.shape[2])
+    cfg = decode_blocks(k_cache.shape[2], chosen=chosen is not None)
     return paged_decode_attention(
         q, k_cache, v_cache, kv_lens, page_table, scale=scale,
         interpret=interpret, v_dim=v_dim, kv_block=cfg["kv_block"],
         group_size=int(cfg.get("group", 1)), k_scale=k_scale,
-        v_scale=v_scale, name=name)
+        v_scale=v_scale, name=name, chosen=chosen)
 
 
 # What the riding rows' call is named in the HLO and on the trace's

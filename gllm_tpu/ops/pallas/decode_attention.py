@@ -40,6 +40,19 @@ Design (TPU-first, not a Triton translation):
   leading ``v_dim`` lanes of each key block (the latent prefix) — one DMA
   stream instead of two (reference MLA shares the latent cache the same
   way, layers/attention.py:272-293).
+- A selection over positions: ``chosen`` ([S, positions] bool) is one
+  more condition on a block's rows beside "holds a token of the context"
+  (a selected-attention layer's decoding rows: the indexer's top-k,
+  models/deepseek.py ``_dsa_attention``). The kernel still streams every
+  page of a context and attends under the mask, which is the right trade
+  while the chosen rows touch nearly every page; a group's mask rides
+  into VMEM as int32 [gsz, blocks, block rows] and a round takes its
+  block's row by index. Without the argument the program has no such
+  operand: the call traces to the kernel it always was. At 128 heads over
+  rows of 640 lanes the mask costs nothing that can be measured (1.765
+  against 1.780 ms a call of 64 rows at 6.7 k of context, PR 43:
+  docs/onchip_pr43/dsa_rows_forms.json), and the products, not the
+  bytes, bind (36 % of the peak FLOP/s, 38 % of the rows' HBM time).
 
 What binds (PERF.md section 6, PR 28; the kernel alone on a v5e at 32
 rows of 320-1909 tokens, bf16, 8 and 32 kv heads of 128): the update
@@ -102,15 +115,21 @@ def _kernel(kv_lens_ref, pt_ref,            # scalar prefetch
             *refs,
             page_size: int, pages_per_block: int, scale: float,
             num_kv_heads: int, v_dim: int, shared_kv: bool, gsz: int,
-            quant: bool):
+            quant: bool, masked: bool):
     """``gsz`` sequences per grid program, TWO buffer slots each: in
     round ``r`` every sequence that still has a block ``r`` starts the
     fetch of its block ``r + 1``, waits for block ``r`` and attends it,
     so ``gsz`` to ``2 gsz`` blocks are in flight while one is attended,
     and a sequence that outlives its group keeps its own double buffer.
     The flash state lives in VMEM scratch and only live sequences touch
-    it (see the module docstring for what binds)."""
+    it (see the module docstring for what binds). ``masked``: the first
+    ref is the group's selection [gsz, blocks, BK * Hkv] (int32, nonzero
+    where a row counts), of which a round takes its block's [1, BK * Hkv]
+    by the block's index on the sublane axis."""
     *refs, m_ref, l_ref, acc_ref = refs
+    chosen_ref = None
+    if masked:
+        chosen_ref, *refs = refs
     (q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf,
      vs_buf, sems) = unpack_refs(refs, shared_kv, quant)
     gi = pl.program_id(0)
@@ -172,7 +191,8 @@ def _kernel(kv_lens_ref, pt_ref,            # scalar prefetch
                     q_ref[g], k_buf, v_buf, 2 * g + parity, own_tokens,
                     kv_lens[g] - r * bk, scale, v_dim, shared_kv,
                     m_ref[g], l_ref[g], acc_ref[g], ks_buf=ks_buf,
-                    vs_buf=vs_buf)
+                    vs_buf=vs_buf, chosen=(
+                        chosen_ref[g, pl.ds(r, 1), :] if masked else None))
 
             pl.when(r == n_blocks[g])(functools.partial(hand_over, g))
 
@@ -202,6 +222,10 @@ def paged_decode_attention(
     v_scale: Optional[jnp.ndarray] = None,
     name: Optional[str] = None,   # the call's name in the HLO and the trace
                                   # (None: this function's)
+    chosen: Optional[jnp.ndarray] = None,    # [S, max_pages * page_size]
+                                  # bool: a row attends a position of its
+                                  # context only where this is true (None:
+                                  # the program has no such operand)
 ) -> jnp.ndarray:
     S, num_q_heads, head_dim = q.shape
     num_pages, page_size, num_kv_heads, _ = k_cache.shape
@@ -236,6 +260,22 @@ def paged_decode_attention(
         page_table = jnp.pad(page_table,
                              ((0, 0), (0, pages_per_block - rem)))
         max_pages += pages_per_block - rem
+    masked = chosen is not None
+    if masked:
+        # as the kernel slices it: int32 (a block's [1, rows] leaves VMEM
+        # by a dynamic index on the sublane axis, which Mosaic takes for
+        # 32-bit words), a row a (token, kv head) as the pool folds them,
+        # whole blocks
+        if chosen.ndim != 2 or chosen.shape[0] != S or (
+                chosen.shape[1] > max_pages * page_size):
+            raise ValueError(
+                f"chosen {chosen.shape} is not [{S} rows, at most "
+                f"{max_pages * page_size} positions]")
+        chosen = jnp.pad(chosen.astype(jnp.int32), (
+            (0, 0), (0, max_pages * page_size - chosen.shape[1])))
+        if num_kv_heads > 1:
+            chosen = jnp.repeat(chosen, num_kv_heads, axis=1)
+        chosen = chosen.reshape(S, max_pages // pages_per_block, -1)
 
     # pad the seq axis to a whole number of groups; padded rows have
     # kv_len 0 (skip every round) and dummy page-table rows
@@ -245,10 +285,12 @@ def paged_decode_attention(
         q = jnp.pad(q, ((0, s_pad - S), (0, 0), (0, 0)))
         kv_lens = jnp.pad(kv_lens, (0, s_pad - S))
         page_table = jnp.pad(page_table, ((0, s_pad - S), (0, 0)))
+        if masked:
+            chosen = jnp.pad(chosen, ((0, s_pad - S), (0, 0), (0, 0)))
     kernel = functools.partial(
         _kernel, page_size=page_size, pages_per_block=pages_per_block,
         scale=scale, num_kv_heads=num_kv_heads, v_dim=v_dim,
-        shared_kv=shared_kv, gsz=gsz, quant=quant)
+        shared_kv=shared_kv, gsz=gsz, quant=quant, masked=masked)
 
     kv_specs, scratch_shapes, kv_inputs = kv_stream_specs(
         k_cache, v_cache, pages_per_block, slots=2 * gsz, k_scale=k_scale,
@@ -261,6 +303,11 @@ def paged_decode_attention(
                      memory_space=pltpu.VMEM),
     ] + kv_specs
     inputs = [kv_lens, page_table, q] + kv_inputs
+    if masked:
+        in_specs.insert(0, pl.BlockSpec(
+            (gsz,) + chosen.shape[1:], lambda s, *_: (s, 0, 0),
+            memory_space=pltpu.VMEM))
+        inputs.insert(2, chosen)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,

@@ -182,7 +182,7 @@ def mxu_operand(q_dtype, kv_dtype, quant: bool):
 
 def attend_block(q, k_buf, v_buf, slot, own_tokens, tokens_left, scale,
                  v_dim: int, shared_kv: bool, m, l, acc, ks_buf=None,
-                 vs_buf=None):
+                 vs_buf=None, chosen=None):
     """One kv-block online-softmax update of the decode kernel.
 
     The block is consumed as the DMA left it: ``k_buf[slot]`` is
@@ -195,7 +195,13 @@ def attend_block(q, k_buf, v_buf, slot, own_tokens, tokens_left, scale,
     ``p @ V`` over the same folded rows is the per-head sum. ``q`` is
     [Hq, D] as it arrived; ``scale`` multiplies the float32 scores.
     (m, l, acc) is the running flash-attention state ([Hq, 1], [Hq, 1],
-    [Hq, Dv] float32); returns it updated."""
+    [Hq, Dv] float32); returns it updated.
+
+    ``chosen`` ([1, R] int32, or None: no such operand in the program) is
+    a selection over the block's rows: a row counts where it is nonzero
+    AND holds a token of the context. Only under a selection can a block
+    hold nothing that counts; ``m`` then stays at -inf, and the exponents
+    are taken against 0 in its place (``exp(-inf - -inf)`` is a NaN)."""
     quant = ks_buf is not None
     k = k_buf[slot]                                  # [ppb, page*Hkv, D]
     ppb, page_rows, head_dim = k.shape
@@ -220,11 +226,16 @@ def attend_block(q, k_buf, v_buf, slot, own_tokens, tokens_left, scale,
     scores = jax.lax.dot_general(                    # [Hq, R]
         q.astype(operand), k.astype(operand), (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
-    scores = jnp.where(own_tokens < tokens_left, scores, -jnp.inf)
+    counts = own_tokens < tokens_left
+    if chosen is not None:
+        counts &= chosen != 0
+    scores = jnp.where(counts, scores, -jnp.inf)
 
     m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
-    alpha = jnp.exp(m - m_new)
-    p = jnp.exp(scores - m_new)
+    m_exp = m_new if chosen is None else jnp.where(
+        m_new == -jnp.inf, 0.0, m_new)
+    alpha = jnp.exp(m - m_exp)
+    p = jnp.exp(scores - m_exp)
     l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
     if operand.itemsize == 2:                        # p in two parts
         hi = p.astype(operand)

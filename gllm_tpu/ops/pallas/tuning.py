@@ -39,6 +39,11 @@ BUILTIN = {
         # the decode kernel under one KV head: a block's bytes a token are
         # one latent row, not heads x (K + V)
         "decode_mqa": {"kv_block": 256},
+        # ... under a selection's mask (a selected-attention layer's
+        # decoding rows, models/deepseek.py): 128 query heads where
+        # ``decode_mqa`` was swept at 64, so the flash state and the
+        # score tile of a sequence are twice as large
+        "decode_mqa_chosen": {},
         "decode": {"kv_block": 256},
         # the unified mixed-batch kernel (--unified-step): one geometry
         # for every paged step; ``group`` is the decode-class DMA
@@ -110,10 +115,14 @@ def ragged_blocks(num_q_heads: int, num_kv_heads: int) -> dict:
             "kv_block": int(cfg["kv_block"])}
 
 
-def decode_blocks(num_kv_heads: int) -> dict:
+def decode_blocks(num_kv_heads: int, chosen: bool = False) -> dict:
     """{"kv_block", "group"} of the decode kernel: the ``decode`` entry,
-    with what ``decode_mqa`` says laid over it under one KV head."""
+    with what ``decode_mqa`` says laid over it under one KV head, and
+    over that what ``decode_mqa_chosen`` says for the call that takes a
+    selection's mask (``chosen``)."""
     out = get("decode")
     if num_kv_heads == 1:
         out.update(get("decode_mqa"))
+        if chosen:
+            out.update(get("decode_mqa_chosen"))
     return out

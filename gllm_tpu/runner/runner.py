@@ -801,6 +801,27 @@ class ModelRunner:
                 1 + self.ssm_working_slots,
                 model_cfg.swa_ring_len(config.cache.page_size),
                 model_cfg.num_swa_layers, rings)
+        if model_cfg.use_dsa:
+            from gllm_tpu.models.deepseek import BQ, dsa_rows_path
+            from gllm_tpu.ops.pallas.tuning import decode_blocks
+            self.dsa_rows_path = dsa_rows_path(self.fwd_attn_impl,
+                                               self.mesh is not None)
+            if self.dsa_rows_path == "kernel":
+                blocks = decode_blocks(1, chosen=True)
+                how = ("pallas paged_decode_attention under the "
+                       "selection's mask (kv_block %d, group %d)"
+                       % (blocks["kv_block"], blocks.get("group", 1)))
+            elif self.fwd_attn_impl == "pallas":
+                how = ("xla (whole pages gathered and attended under the "
+                       "mask: the masked kernel call has no shard_map to "
+                       "run under this mesh)")
+            else:
+                how = ("xla (whole pages gathered and attended under the "
+                       f"mask: attention runs as {self.fwd_attn_impl})")
+            logger.info(
+                "[startup] selected attention: a decoding row's chosen "
+                "positions -> %s; a chunk's work items of %d queries -> "
+                "xla", how, BQ)
         _M_KV_DTYPE.set(1, dtype=jnp.dtype(self._kv_dtype()).name)
         # Fused on-device speculation (config.spec_fused,
         # docs/speculative_decoding.md#fused): draft+verify inside the
@@ -1619,6 +1640,10 @@ class ModelRunner:
         if self.model_cfg.dense_mla:
             from gllm_tpu.models.deepseek import count_rows_read
             count_rows_read(self.model_cfg, host.attn.kv_lens, max_q == 1)
+        if self.model_cfg.use_dsa:
+            from gllm_tpu.models.deepseek import count_rows_attended
+            count_rows_attended(self.model_cfg, host.attn.cu_q_lens,
+                                self.dsa_rows_path)
         new_sig = self._note_dispatch(
             "step", host, tuple(flags.values()), flags["all_greedy"])
         build.stop()

@@ -211,12 +211,16 @@ def test_whole_expert_layer_is_what_it_was():
 # ---- selection ---------------------------------------------------------------
 
 @pytest.mark.slow
-def test_selection_that_takes_everything_equals_the_dense_latent_path():
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_selection_that_takes_everything_equals_the_dense_latent_path(impl):
     """Contexts under ``index_topk``: the blocked selection takes every
     visible position and has to give the dense latent path's tokens (the
     same parameters; the dense path never reads the indexer's). Tier-1 holds
     the same code to the same claim at DeepSeek-V3.2's keys:
-    tests/test_dsa.py::test_dsa_sparse_equals_dense_when_topk_covers."""
+    tests/test_dsa.py::test_dsa_sparse_equals_dense_when_topk_covers.
+    ``pallas`` (interpret mode): the decoding rows under a mask that takes
+    everything on the decode kernel, against the same kernel unmasked on
+    the dense side."""
     model = dict(TINY, index_topk=200, layer_types=["full_attention"] * 5,
                  num_hidden_layers=5)
     cfg = from_hf_config(model)
@@ -227,7 +231,7 @@ def test_selection_that_takes_everything_equals_the_dense_latent_path():
     def run(mcfg):
         llm = LLM(config=EngineConfig(
             load_format="dummy", dtype="float32", max_model_len=256,
-            max_num_seqs=8,
+            max_num_seqs=8, attention_impl=impl,
             scheduler=SchedulerConfig(max_prefill_tokens=32,
                                       max_decode_seqs=8),
             cache=CacheConfig(page_size=4, num_pages=256)),

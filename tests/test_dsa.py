@@ -195,3 +195,144 @@ def test_fp8_scoring_flag():
         dataclasses.replace(mcfg, index_fp8_score=True), params=params,
         kv_cache_dtype="fp8"), prompts)
     assert base == fp8s
+
+
+# ---- a decoding row on the decode kernel under the selection's mask --------
+
+def _dsa_layer_call(kind):
+    """One full layer's ``_dsa_attention`` at toy sizes in bf16, a context
+    several times the top-k: (call(attn_impl) -> (out, index_cache, stats),
+    the flat indices of the one-token rows, of the chunk's rows and of
+    the padding). ``decode``: four rows, one of them padding; ``mixed``:
+    two decoding rows, a 37-token chunk and a one-token row behind it."""
+    import jax
+    from gllm_tpu.batching import StepBatch
+    from gllm_tpu.models import deepseek as ds
+    from gllm_tpu.ops.attention import AttentionMetadata
+    cfg = dataclasses.replace(
+        ModelConfig(**V32), index_topk=16, kv_lora_rank=128,
+        qk_rope_head_dim=8)
+    g = ds.geom(cfg)
+    page, P, max_pages = 4, 64, 24
+    if kind == "decode":
+        q_lens, ctx, T, max_q = [1, 1, 0, 1], [50, 90, 0, 7], 4, 1
+    else:
+        q_lens, ctx, T, max_q = [1, 1, 37, 1], [60, 23, 49, 31], 48, 37
+    S = len(q_lens)
+    cu = np.concatenate([[0], np.cumsum(q_lens)]).astype(np.int32)
+    kv_lens = np.asarray(ctx, np.int32)
+    pt = np.zeros((S, max_pages), np.int32)
+    nxt = 1
+    for s in range(S):
+        n = -(-ctx[s] // page)
+        pt[s, :n] = np.arange(nxt, nxt + n)
+        nxt += n
+    pos = np.zeros(T, np.int32)
+    slots = np.zeros(T, np.int32)
+    for s in range(S):
+        p = ctx[s] - q_lens[s] + np.arange(q_lens[s])
+        pos[cu[s]:cu[s + 1]] = p
+        slots[cu[s]:cu[s + 1]] = pt[s, p // page] * page + p % page
+    ks = iter(jax.random.split(jax.random.key(4), 16))
+    bf = lambda shape, scale=1.0: (jax.random.normal(
+        next(ks), shape, jnp.float32) * scale).astype(jnp.bfloat16)
+    H, nh, hd = cfg.hidden_size, cfg.index_n_heads, cfg.index_head_dim
+    lp = {"idx_wq_b": bf((cfg.q_lora_rank, nh * hd), 0.2),
+          "idx_wk": bf((H, hd), 0.2), "idx_weights": bf((H, nh), 0.2),
+          "idx_k_norm_w": jnp.ones((hd,), jnp.bfloat16),
+          "idx_k_norm_b": jnp.zeros((hd,), jnp.bfloat16)}
+    x, q_resid = bf((T, H)), bf((T, cfg.q_lora_rank))
+    q_full = bf((T, g.heads, g.width))
+    latent, index = bf((P, page, g.width)), bf((P, page, hd))
+    batch = StepBatch(
+        token_ids=None, positions=jnp.asarray(pos),
+        slot_mapping=jnp.asarray(slots), logits_indices=None, sampling=None,
+        attn=AttentionMetadata(jnp.asarray(cu), jnp.asarray(kv_lens),
+                               jnp.asarray(pt), jnp.int32(S)))
+
+    def call(attn_impl):
+        fn = jax.jit(lambda *a: ds._dsa_attention(
+            lp, *a, None, cfg, ds.make_rope_table(cfg), max_q_len=max_q,
+            g=g, attn_impl=attn_impl))
+        out, icache, _, stats = fn(x, q_resid, q_full, batch, latent, index)
+        text = str(jax.make_jaxpr(fn)(x, q_resid, q_full, batch, latent,
+                                      index))
+        return np.asarray(out), np.asarray(icache, np.float32), \
+            np.asarray(stats), ds.DSA_ROWS_NAME in text
+    rows = [int(cu[s]) for s in range(S) if q_lens[s] == 1]
+    chunk = [t for s in range(S) if q_lens[s] > 1
+             for t in range(cu[s], cu[s + 1])]
+    return call, rows, chunk, list(range(int(cu[-1]), T))
+
+
+@pytest.mark.parametrize("kind", ["decode", "mixed"])
+def test_decoding_rows_on_the_masked_kernel_equal_the_xla_rows(kind):
+    """``attn_impl="pallas"`` (interpret mode here) sends the one-token
+    rows to ``paged_decode_attention`` under ``_largest``'s mask, contexts
+    of 2-6 times the top-k; the result is the XLA rows' within the one
+    rounding of the kernel's output to bf16. A sequence that brings a
+    chunk gets its rows from the chunk loop on both paths (its first row,
+    which the XLA form computes and discards, never reaches the kernel:
+    context 0); the index cache and the counts do not depend on the path;
+    padding reads zeros."""
+    call, rows, chunk, padding = _dsa_layer_call(kind)
+    want, icache_x, stats_x, named_x = call("xla")
+    got, icache_k, stats_k, named_k = call("pallas")
+    assert named_k and not named_x
+    assert np.abs(want[rows]).max() > 0.1
+    np.testing.assert_allclose(got[rows], want[rows], rtol=2 ** -7,
+                               atol=2e-3)
+    np.testing.assert_array_equal(got[chunk], want[chunk])
+    assert bool(chunk) == (kind == "mixed")
+    assert not got[padding].any() and not want[padding].any()
+    np.testing.assert_array_equal(icache_k, icache_x)
+    np.testing.assert_array_equal(stats_k, stats_x)
+
+
+def test_rows_path_follows_the_resolved_impl_and_the_mesh():
+    from gllm_tpu.models.deepseek import dsa_rows_path
+    assert dsa_rows_path("pallas") == "kernel"
+    assert dsa_rows_path("xla") == "xla"
+    assert dsa_rows_path("unified") == "xla"
+    assert dsa_rows_path("pallas", meshed=True) == "xla"
+    assert dsa_rows_path("pallas", meshed=False) == "kernel"
+    import jax
+    from jax.sharding import Mesh
+    from gllm_tpu.parallel.mesh import mesh_context
+    with mesh_context(Mesh(np.array(jax.devices()[:2]), ("tp",))):
+        assert dsa_rows_path("pallas") == "xla"
+
+
+def test_truncated_topk_serves_the_same_tokens_on_the_kernel_path():
+    """The engine end to end in float32, contexts past the top-k: greedy
+    tokens of ``attention_impl="pallas"`` (the masked decode call in
+    interpret mode) equal the XLA path's, and the counter says which
+    path attended the one-token rows: decoding rows x 3 layers, every
+    layer of DeepSeek-V3.2 being a full one."""
+    from gllm_tpu.models import deepseek
+    mcfg = dataclasses.replace(ModelConfig(**V32), index_topk=8)
+    params = deepseek.init_params(mcfg, seed=3, dtype=jnp.float32)
+    rng = np.random.default_rng(1)
+    prompts = [[int(x) for x in rng.integers(2, 250, size=n)]
+               for n in (30, 11)]
+
+    def run(impl):
+        cfg = EngineConfig(
+            load_format="dummy", dtype="float32", max_model_len=128,
+            attention_impl=impl,
+            scheduler=SchedulerConfig(max_prefill_tokens=64),
+            cache=CacheConfig(page_size=4, num_pages=128))
+        llm = LLM(config=cfg, model_cfg=mcfg, params=params)
+        before = {p: deepseek._M_DSA_ROWS.get(path=p)
+                  for p in ("kernel", "xla")}
+        toks = _greedy(llm, prompts, n=6)
+        return toks, llm.runner.dsa_rows_path, {
+            p: deepseek._M_DSA_ROWS.get(path=p) - before[p] for p in before}
+
+    toks_x, path_x, grew_x = run("xla")
+    toks_k, path_k, grew_k = run("pallas")
+    assert toks_k == toks_x
+    assert (path_x, path_k) == ("xla", "kernel")
+    # the prompts' prefill emits token 1; five decode steps of two rows
+    assert grew_x == {"kernel": 0, "xla": 2 * 5 * 3}
+    assert grew_k == {"kernel": 2 * 5 * 3, "xla": 0}

@@ -309,3 +309,173 @@ def test_pages_left_unfetched_never_reach_the_result(shared_kv):
                             pt, 8, 128 ** -0.5)[..., :Dv]
     assert np.isfinite(_as_f64(got)).all()
     np.testing.assert_allclose(_as_f64(got), want, rtol=2 ** -8, atol=1e-5)
+
+
+# ---- under a selection's mask (``chosen``) ----------------------------------
+
+def _chosen_group():
+    """The group the v5e table gives the masked call."""
+    import json
+    import os
+    from gllm_tpu.ops.pallas import tuning
+    with open(os.path.join(os.path.dirname(tuning.__file__),
+                           "tables.json")) as f:
+        table = json.load(f)["tpu_v5_lite"]
+    return int({**table["decode_mqa"],
+                **table.get("decode_mqa_chosen", {})}["group"])
+
+
+def chosen_ref(q, k_cache, v_cache, kv_lens, pt, chosen, scale):
+    """``dense_decode_ref`` over the chosen positions of each context; a
+    row with none gives zeros."""
+    S, Hq, _ = q.shape
+    group = Hq // k_cache.shape[2]
+    out = np.zeros((S, Hq, v_cache.shape[-1]), q.dtype)
+    for s in range(S):
+        kv = int(kv_lens[s])
+        keep = np.flatnonzero(chosen[s, :kv])
+        if not keep.size:
+            continue
+        k = np.concatenate([k_cache[p] for p in pt[s]])[keep]
+        v = np.concatenate([v_cache[p] for p in pt[s]])[keep]
+        for h in range(Hq):
+            sc = (q[s, h] @ k[:, h // group].T) * scale
+            p_ = np.exp(sc - sc.max())
+            out[s, h] = (p_ / p_.sum()) @ v[:, h // group]
+    return out
+
+
+def _latent_case(rng, shapes, Hq=128, W=640, page=16):
+    """dots3-note-prev's full layers: ``Hq`` heads over ONE KV head of
+    ``W`` lanes in bf16, pages of 16; a mask that keeps ~40 % of each
+    row's positions."""
+    pages = 2 + sum(-(-kv // page) for kv in shapes)
+    q, kc, _, kv_lens, pt = build_case(rng, shapes, Hq, 1, W, page, pages)
+    q, kc = jnp.asarray(q, jnp.bfloat16), jnp.asarray(kc, jnp.bfloat16)
+    chosen = rng.random((len(shapes), pt.shape[1] * page)) < 0.4
+    return q, kc, kv_lens, pt, chosen
+
+
+CHOSEN_ROWS = ["drawn", "first_block_empty", "last_block_empty",
+               "nothing_chosen", "context_0"]
+
+
+@pytest.mark.parametrize("gsz", sorted({1, _chosen_group()}))
+@pytest.mark.parametrize("row", CHOSEN_ROWS)
+def test_chosen_positions_at_the_latent_cell_geometry(row, gsz):
+    """128 heads x 640 lanes, values the first 512, one KV head, bf16,
+    against float64 arithmetic on the same inputs: the middle row of
+    three is the case (a block of the context, the first or the last,
+    in which nothing is chosen; a row with nothing chosen at all, which
+    reads zeros; a row of context 0), between two live rows whose
+    results it must not touch."""
+    rng = np.random.default_rng(43)
+    B = CELL_BLOCK
+    shapes = [2 * B + 5, 0 if row == "context_0" else 3 * B - 9, B + 1]
+    q, kc, kv_lens, pt, chosen = _latent_case(rng, shapes)
+    if row == "first_block_empty":
+        chosen[1, :B] = False
+    elif row == "last_block_empty":
+        chosen[1, 2 * B:] = False
+    elif row == "nothing_chosen":
+        chosen[1] = False
+    got = paged_decode_attention(
+        q, kc, None, jnp.asarray(kv_lens), jnp.asarray(pt),
+        scale=192 ** -0.5, kv_block=B, interpret=True, v_dim=512,
+        group_size=gsz, chosen=jnp.asarray(chosen))
+    assert got.shape == (3, 128, 512) and got.dtype == jnp.bfloat16
+    k64 = _as_f64(kc)
+    want = chosen_ref(_as_f64(q), k64, k64[..., :512], kv_lens, pt, chosen,
+                      192 ** -0.5)
+    assert np.isfinite(_as_f64(got)).all()
+    np.testing.assert_allclose(_as_f64(got), want, rtol=2 ** -8, atol=1e-5)
+    if row in ("nothing_chosen", "context_0"):
+        assert not np.asarray(got[1]).any()
+    assert np.asarray(got[0]).any() and np.asarray(got[2]).any()
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_everything_chosen_is_the_unmasked_call_bit_for_bit(dtype):
+    rng = np.random.default_rng(7)
+    shapes = [CELL_BLOCK + 3, 0, 3 * CELL_BLOCK, 17]
+    q, kc, kv_lens, pt, chosen = _latent_case(rng, shapes, Hq=16, W=256)
+    q, kc = q.astype(dtype), kc.astype(dtype)
+    call = lambda **kw: np.asarray(paged_decode_attention(
+        q, kc, None, jnp.asarray(kv_lens), jnp.asarray(pt), scale=0.1,
+        kv_block=CELL_BLOCK, interpret=True, v_dim=128, group_size=2,
+        **kw).astype(jnp.float32))
+    np.testing.assert_array_equal(
+        call(chosen=jnp.ones(chosen.shape, bool)), call())
+    assert not np.array_equal(call(chosen=jnp.asarray(chosen)), call())
+
+
+def test_chosen_positions_under_folded_kv_heads():
+    """Several KV heads in a block's rows: a position's choice holds for
+    every head's row of it."""
+    rng = np.random.default_rng(9)
+    shapes = [37, 0, 64, 5]
+    q, kc, vc, kv_lens, pt = build_case(rng, shapes, 8, 2, 64, 8, 24)
+    chosen = rng.random((4, pt.shape[1] * 8)) < 0.5
+    chosen[3] = False
+    got = paged_decode_attention(
+        jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc),
+        jnp.asarray(kv_lens), jnp.asarray(pt), scale=0.125, kv_block=16,
+        interpret=True, group_size=2, chosen=jnp.asarray(chosen))
+    want = chosen_ref(q, kc, vc, kv_lens, pt, chosen, 0.125)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-4, atol=2e-4)
+
+
+def test_chosen_positions_with_pages_left_unfetched():
+    """The rule of ``test_pages_left_unfetched_never_reach_the_result``
+    under a mask: VMEM that starts as NaNs, last blocks fetched to their
+    last page, and a block whose chosen positions all lie past the
+    context's end."""
+    from jax.experimental.pallas import tpu as pltpu
+    rng = np.random.default_rng(1)
+    shapes = [5, 70, 0, 33]                 # blocks of 32
+    q, kc, kv_lens, pt, chosen = _latent_case(rng, shapes, Hq=8, W=128,
+                                              page=8)
+    chosen[3, 32:33] = False                # block 1 of row 3: none in reach
+    got = paged_decode_attention(
+        q, kc, None, jnp.asarray(kv_lens), jnp.asarray(pt),
+        scale=128 ** -0.5, kv_block=32, group_size=2, v_dim=64,
+        chosen=jnp.asarray(chosen),
+        interpret=pltpu.InterpretParams(uninitialized_memory="nan"))
+    k64 = _as_f64(kc)
+    want = chosen_ref(_as_f64(q), k64, k64[..., :64], kv_lens, pt, chosen,
+                      128 ** -0.5)
+    assert np.isfinite(_as_f64(got)).all()
+    np.testing.assert_allclose(_as_f64(got), want, rtol=2 ** -8, atol=1e-5)
+
+
+def test_a_mask_of_another_extent_is_refused():
+    rng = np.random.default_rng(0)
+    q, kc, kv_lens, pt, chosen = _latent_case(rng, [20, 9], Hq=8, W=128)
+    with pytest.raises(ValueError, match="chosen"):
+        paged_decode_attention(
+            q, kc, None, jnp.asarray(kv_lens), jnp.asarray(pt), scale=0.1,
+            kv_block=32, interpret=True, v_dim=64,
+            chosen=jnp.ones((3, chosen.shape[1]), bool))
+
+
+def test_without_a_mask_the_program_has_no_such_operand():
+    """Absence is a fact of the trace: the unmasked call's kernel takes
+    the operands it always took (contexts, page table, q, K, V), the
+    masked one the selection besides."""
+    import jax
+    rng = np.random.default_rng(0)
+    q, kc, vc, kv_lens, pt = build_case(rng, [20, 9], 8, 2, 64, 8, 8)
+    args = [jnp.asarray(a) for a in (q, kc, vc, kv_lens, pt)]
+    chosen = jnp.ones((2, pt.shape[1] * 8), bool)
+
+    def kernel_eqn(**kw):
+        jaxpr = jax.make_jaxpr(lambda *a: paged_decode_attention(
+            *a, scale=0.125, kv_block=16, interpret=True, **kw))(*args)
+        inner = jaxpr.jaxpr.eqns[-1].params["jaxpr"]
+        eqns = [e for e in inner.eqns if e.primitive.name == "pallas_call"]
+        assert len(eqns) == 1
+        return eqns[0]
+
+    plain, masked = kernel_eqn(), kernel_eqn(chosen=chosen)
+    assert len(plain.invars) == 5 and len(masked.invars) == 6
+    assert len(str(plain.params["jaxpr"])) < len(str(masked.params["jaxpr"]))

@@ -705,6 +705,69 @@ def test_dots3_pools_as_the_tpu_stores_them(topo, on_tpu, monkeypatch):
         runner, mem.argument_size_in_bytes)
 
 
+def _dsa_rows_calls(text: str) -> list:
+    """The HLO lines of the masked decode calls (a selected-attention
+    layer's decoding rows, ``models/deepseek.DSA_ROWS_NAME``)."""
+    from gllm_tpu.models.deepseek import DSA_ROWS_NAME
+    return [ln.strip() for ln in text.splitlines()
+            if re.match(rf"\s*(ROOT )?%{DSA_ROWS_NAME}[.\d]* = ", ln)]
+
+
+def _patterns_matching(line: str) -> list:
+    """The configuration's ``trace_patterns.kernels`` (read, not edited)
+    that match an operation's whole HLO line, as perfbench/trace_reduce.py
+    applies them to the names on the trace's ``XLA Ops`` line."""
+    kernels = _perfbench_hf("dots3-note-prev")["trace_patterns"]["kernels"]
+    return sorted(k for k, pat in kernels.items() if re.search(pat, line))
+
+
+def test_dots3_masked_decode_call_compiles_and_is_read_as_sparse_mla(
+        topo, on_tpu):
+    """The decoding rows' call of a full layer alone, at the cell's
+    geometry (64 rows of 128 heads over the two full layers' flat pool of
+    rows of 640 lanes, a page table of 592, the mask over its 9472
+    positions) and the table's blocks for it: Mosaic takes the kernel, the
+    pool is not copied, and the call's HLO line is what the benchmark's
+    ``sparse_mla`` pattern finds (the pool's ``[.., 16, 640]``) and no
+    other pattern does: the mask travels as ``s32[rows, blocks, block]``,
+    which is neither the indexer's ``u32[`` nor a scores' shape."""
+    from gllm_tpu.models.deepseek import DSA_ROWS_NAME
+    from gllm_tpu.ops.pallas.decode_attention import paged_decode_attention
+    from gllm_tpu.ops.pallas.tuning import decode_blocks
+    from gllm_tpu.utils import tpu_compiler_options
+    blocks = decode_blocks(1, chosen=True)
+    assert blocks["kv_block"] == 1024, "expected the decode_mqa_chosen entry"
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    fn = jax.jit(lambda q, k, kl, pt, m: paged_decode_attention(
+        q, k[:, :, None, :], None, kl, pt, scale=0.1, v_dim=512, chosen=m,
+        kv_block=blocks["kv_block"], group_size=int(blocks["group"]),
+        name=DSA_ROWS_NAME), compiler_options=tpu_compiler_options())
+    text = fn.lower(
+        sds((64, 128, 640), jnp.bfloat16),
+        sds((2 * 37120, 16, 640), jnp.bfloat16), sds((64,), jnp.int32),
+        sds((64, 592), jnp.int32), sds((64, 9472), jnp.bool_)
+    ).compile().as_text()
+    calls = _dsa_rows_calls(text)
+    assert len(calls) == 1 and "tpu_custom_call" in calls[0]
+    assert _patterns_matching(calls[0]) == ["sparse_mla"]
+    assert not [ln for ln in text.splitlines()
+                if " copy(" in ln and "[74240," in ln]
+
+
+def _dots3_rows_are_on_the_masked_kernel(text: str):
+    """Both full layers hold the masked decode call, each read as
+    ``sparse_mla`` alone, and no operation gathers the 64 sequences' whole
+    pages (``[64, 9472, 640]`` / ``[37888, 16, 640]``: 776 MB a layer when
+    the rows were attended in XLA); the chunk loop's items gather one
+    sequence's pages, a quarter to the whole of its table."""
+    calls = _dsa_rows_calls(text)
+    assert len(calls) == 2, calls
+    for call in calls:
+        assert _patterns_matching(call) == ["sparse_mla"], call
+    assert not re.search(r"\[64,9472,640\]|\[37888,16,640\]", text)
+
+
 @pytest.mark.slow
 def test_dots3_decode_step_compiles_for_v5e(topo, on_tpu, monkeypatch):
     """The configuration's decode step (the cell holds rows and
@@ -725,11 +788,13 @@ def test_dots3_decode_step_compiles_for_v5e(topo, on_tpu, monkeypatch):
           f"{mem.temp_size_in_bytes / GiB:.3f} GiB temp")
     _dots3_pools_are_what_the_startup_line_says(
         runner, mem.argument_size_in_bytes - _weight_bytes(runner))
-    # a full layer reads its 64 sequences' rows as whole pages (0.72 GiB
-    # at 592 pages of 16 rows of 640) and scores them; nothing else is
-    # of that size
-    assert mem.temp_size_in_bytes < 1.5 * GiB
+    # a full layer's rows stream through the masked decode kernel; the
+    # 0.72 GiB copy of their pages (592 pages of 16 rows of 640) and the
+    # float32 scores over it are gone: what is left is the indexer's keys
+    # and scores
+    assert mem.temp_size_in_bytes < 0.5 * GiB
     text = c.compiled.as_text()
+    _dots3_rows_are_on_the_masked_kernel(text)
     # the grouped product is XLA's own kernel, and no layer's expert stack
     # is copied out of the run's (1.5 GB a layer and step when it was)
     assert "ragged-dot" in text and has_kernel(c.compiled)
@@ -755,6 +820,7 @@ def test_dots3_mixed_step_compiles_for_v5e(topo, on_tpu, monkeypatch):
         runner, mem.argument_size_in_bytes - _weight_bytes(runner))
     assert mem.temp_size_in_bytes < 3.5 * GiB
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14 * GiB
+    _dots3_rows_are_on_the_masked_kernel(c.compiled.as_text())
 
 
 def test_dots3_windowed_attention_compiles_for_v5e_at_the_chunk(topo,
