@@ -192,8 +192,8 @@ def build_mixed_step(q_block, kv_block, rows=31, prompt=320, tokens=512,
     from gllm_tpu.ops import attention
     from gllm_tpu.ops.pallas import tuning
     from gllm_tpu.utils import tpu_compiler_options
-    tuning.ragged_blocks = lambda *_: {"q_block": q_block,
-                                       "kv_block": kv_block}
+    tuning.ragged_blocks = lambda *_, **__: {"q_block": q_block,
+                                             "kv_block": kv_block}
     rng = np.random.default_rng(seed)
     lens = np.append(reason_contexts(rng, rows), prompt).astype(np.int32)
     _, kc, vc, kl, pt = build_inputs(rng, Hq, Hkv, pool, rows + 1, lens,
@@ -444,6 +444,151 @@ def time_decode(kv_block, gsz=1, iters=25, kv_dtype="auto"):
     return ranked
 
 
+# ---------------------------------------------------------------------------
+# one geometry's own entries: a windowed GQA cell (--geometry)
+# ---------------------------------------------------------------------------
+
+def sweep_geometry(hq: int, hkv: int, window: int, rows: int = 16,
+                   layers: int = 6, D: int = 128, page: int = 16):
+    """Both kernels at ONE geometry in this one process (a refused config
+    raises and is reported; nothing here has hung the compiler): the
+    decode kernel over kv_block x group for ``rows`` rows at the document
+    cell's contexts (drawn 8.5-17 k under a table of 1088 pages), and a
+    mixed step as the dispatch serves it (``rows - 1`` riding rows on the
+    decode kernel at the blocks the decode sweep chose, a 320-token
+    question behind 12.6 k of cached document, or a 2048-token chunk
+    behind 8 k, on the ragged kernel over q_block x kv_block). The full
+    layer's calls are swept; a windowed layer's (``window``) are timed
+    once each at the winner, since they take the geometry's pair too.
+    Prints one line a config and, last, the two table entries
+    ``<kernel>@<hq>x<hkv>`` as JSON."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from benchmarks.decode_attn_ablation import build_inputs
+    from gllm_tpu.ops import attention
+    from gllm_tpu.ops.pallas import tuning
+    from gllm_tpu.ops.pallas.decode_attention import paged_decode_attention
+    from gllm_tpu.utils import tpu_compiler_options
+    interp = _interp()
+    if interp:
+        rows, layers, D, window = 4, 1, 32, 64
+    rng = np.random.default_rng(44)
+    lo, hi = (100, 300) if interp else (8500, 17000)
+    ctx = np.linspace(lo, hi, rows).astype(np.int32)
+    rng.shuffle(ctx)
+    pool = int(-(-ctx // page).sum()) + 2
+    table = 32 if interp else 1088
+    dtype = jnp.float32 if interp else jnp.bfloat16
+    q, kc, vc, kl, pt = build_inputs(rng, hq, hkv, pool, rows, ctx, page, D,
+                                     dtype)
+    pt = jnp.pad(pt, ((0, 0), (0, max(0, table - pt.shape[1]))))[:, :table]
+    opts = None if interp else tpu_compiler_options()
+
+    def timed(run, *args):
+        iters, reps = (1, 1) if interp else (4, 3)
+        out = jax.block_until_ready(run(*args))
+        best = None
+        for _ in range(reps):
+            t0 = time.monotonic()
+            for _ in range(iters):
+                out = run(*args)
+            jax.block_until_ready(out)
+            dt = (time.monotonic() - t0) / iters / layers * 1e3
+            best = dt if best is None else min(best, dt)
+        return best
+
+    def attempt(label, build):
+        try:
+            ms = timed(*build())
+        except Exception as e:            # Mosaic's refusal, by its text
+            print(f"GEOMETRY {label}: FAIL "
+                  f"{str(e).strip().splitlines()[0][:160]}", flush=True)
+            return None
+        print(f"GEOMETRY {label}: {ms:.4f} ms a layer", flush=True)
+        return ms
+
+    def decode_call(kb, gsz, win):
+        def build():
+            @functools.partial(jax.jit, compiler_options=opts)
+            def run(q, kc, vc, kl, pt):
+                def layer(q, _):
+                    out = paged_decode_attention(
+                        q, kc, vc, kl, pt, scale=D ** -0.5, kv_block=kb,
+                        group_size=gsz, interpret=interp, window=win)
+                    return (q + out * 1e-3).astype(q.dtype), None
+                return jax.lax.scan(layer, q, None, length=layers)[0]
+            return run, q, kc, vc, kl, pt
+        tag = "window" if win else "full"
+        return attempt(f"decode {tag} kv={kb} group={gsz}", build)
+
+    for win in (None, window):
+        rows_read = int(np.minimum(ctx, win).sum() if win else ctx.sum())
+        floor = 2 * hkv * D * 2 * rows_read / HBM_BYTES_PER_S * 1e3
+        print(f"GEOMETRY decode {'window' if win else 'full'}: {rows} "
+              f"rows, {rows_read} rows of context to read = {floor:.4f} "
+              f"ms at 819 GB/s", flush=True)
+    res = {}
+    for kb, gsz in itertools.product(
+            (256,) if interp else (128, 256, 512, 1024),
+            (2,) if interp else (1, 2, 4, 8)):
+        ms = decode_call(kb, gsz, None)
+        if ms:
+            res[(kb, gsz)] = ms
+    kb, gsz = min(res, key=res.get) if res else (256, 4)
+    best = {"decode": {"kv_block": kb, "group": gsz}}
+    decode_call(kb, gsz, window)
+    # the mixed steps: the riding rows at the decode sweep's winner
+    tuning.decode_blocks = lambda *_, **__: best["decode"]
+    doc, question, chunk, behind = ((96, 24, 64, 64) if interp
+                                    else (12600, 320, 2048, 8192))
+    shapes = (("question", question, doc, 32 if interp else 512),
+              ("chunk", chunk, behind, chunk + rows))
+
+    def mixed_calls(qb, kb, win):
+        tuning.ragged_blocks = lambda *_, **__: {"q_block": qb,
+                                                 "kv_block": kb}
+        total = 0.0
+        for name, new, cached, tokens in shapes:
+            lens = np.append(ctx[:rows - 1], cached + new).astype(np.int32)
+            qq = jax.random.normal(jax.random.key(1), (tokens, hq, D),
+                                   dtype)
+            md = attention.AttentionMetadata(
+                jnp.asarray(list(range(rows)) + [rows - 1 + new],
+                            jnp.int32), jnp.asarray(lens), pt,
+                jnp.asarray(rows, jnp.int32))
+
+            def build(qq=qq, md=md):
+                @functools.partial(jax.jit, compiler_options=opts)
+                def run(q, kc, vc):
+                    def layer(q, _):
+                        out = attention._mixed_step_attention(
+                            q, kc, vc, md, None, None, scale=D ** -0.5,
+                            interpret=interp, v_dim=None, window=win)
+                        return (q + out * 1e-3).astype(q.dtype), None
+                    return jax.lax.scan(layer, q, None, length=layers)[0]
+                return run, qq, kc, vc
+            tag = "window" if win else "full"
+            ms = attempt(f"mixed {tag} {name} q={qb} kv={kb}", build)
+            total = None if ms is None or total is None else total + ms
+        return total
+
+    res = {}
+    for qb, kb in itertools.product(
+            (16,) if interp else (16, 32, 64, 128),
+            (64,) if interp else (128, 256, 512)):
+        total = mixed_calls(qb, kb, None)
+        if total:
+            res[(qb, kb)] = total
+    if res:
+        qb, kb = min(res, key=res.get)
+        best["ragged"] = {"q_block": qb, "kv_block": kb}
+        mixed_calls(qb, kb, window)
+    print("GEOMETRY_BEST " + json.dumps(
+        {f"{k}@{hq}x{hkv}": v for k, v in best.items()}), flush=True)
+    return 0.0
+
+
 VMEM_PROBE_CONFIGS = ((128, 256), (256, 256), (256, 512), (512, 512),
                       (1024, 512), (1024, 1024), (2048, 1024))
 
@@ -532,6 +677,11 @@ def main():
     ap.add_argument("--blocks", default=None,
                     help="comma-separated block sizes of the ragged sweep "
                          f"(default {','.join(map(str, BLOCKS))})")
+    ap.add_argument("--geometry", default=None, metavar="HQxHKV[:WINDOW]",
+                    help="sweep both kernels at one geometry of query x "
+                         "kv heads in one child (sweep_geometry): the "
+                         "table's <kernel>@HQxHKV entries; the windowed "
+                         "calls (default 4096) are timed at the winners")
     ap.add_argument("--kv-dtype", choices=("auto", "int8"), default="auto",
                     help="sweep the kernels against an int8 quantized "
                          "cache (kv_cache_dtype=int8 serving shape); "
@@ -557,6 +707,8 @@ def main():
                               int(parts[3]),
                               kv_dtype=(parts[4] if len(parts) > 4
                                         else "auto"))
+        elif parts[0] == "geometry":
+            ms = sweep_geometry(int(parts[1]), int(parts[2]), int(parts[3]))
         elif parts[0] == "vmem":
             vmem_probe_one(int(parts[1]), int(parts[2]))
             print("RESULT 0.0", flush=True)
@@ -623,6 +775,21 @@ def main():
             json.dump(table, f, indent=1, sort_keys=True)
         print(f"[tune] wrote {_TABLES_PATH} for {tag}",
               file=sys.stderr)
+
+    if args.geometry:
+        heads, _, window = args.geometry.partition(":")
+        hq, hkv = heads.split("x")
+        global CONFIG_TIMEOUT_S
+        CONFIG_TIMEOUT_S = 3000             # one child holds the sweep
+        _, out = run_inner(f"geometry:{hq}:{hkv}:{window or 4096}")
+        log_path = os.path.join(REPO, "chiprun_out",
+                                "kernel_tune_geometry.log")
+        os.makedirs(os.path.dirname(log_path), exist_ok=True)
+        with open(log_path, "w") as f:
+            f.write(out)
+        print("\n".join(ln for ln in out.splitlines()
+                        if ln.startswith("GEOMETRY")), flush=True)
+        return
 
     if args.vmem_probe:
         last_ok_mb = None

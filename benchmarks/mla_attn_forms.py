@@ -149,7 +149,8 @@ def main():
     def split_call(q, k, cu, kl, pt, qb, kvb):
         """The mixed step as the dispatch serves it, the ragged kernel at
         ``qb`` x ``kvb`` (the decode kernel's blocks are the table's)."""
-        tuning.ragged_blocks = lambda *_: {"q_block": qb, "kv_block": kvb}
+        tuning.ragged_blocks = lambda *_, **__: {"q_block": qb,
+                                                  "kv_block": kvb}
         return attention._mixed_step_attention(
             q, k, None, attention.AttentionMetadata(
                 cu, kl, pt, jnp.asarray(kl.shape[0], jnp.int32)),

@@ -187,36 +187,66 @@ def prepares_next_step(config: EngineConfig) -> bool:
             and (config.pp_pipeline_depth or 1) == 1)
 
 
-def refuse_for_windowed(config: EngineConfig) -> None:
-    """A model with windowed latent layers keeps of each sequence, in
-    those layers, a ring of the window's last rows and nothing older. So
-    whatever needs a sequence's rows again after the fact, or writes
+def _asked_for(config: EngineConfig) -> dict:
+    """What a start-up fence may name, by a short key: (the option as a
+    user wrote it, whether this configuration asks for it)."""
+    cache, par = config.cache, config.parallel
+    return {
+        "prefix": ("--enable-prefix-caching", cache.enable_prefix_caching),
+        "tiers": ("a host or disk KV tier (--kv-host-pool-*, "
+                  "--kv-disk-path, --prefix-peers)",
+                  cache.host_pool_configured or cache.kvstore_configured),
+        "spec": ("--spec-decode / --spec-fused", bool(config.spec_decode)),
+        "unified": ("--unified-step", config.unified_step),
+        "fused": ("fused multi-step decoding (--multi-step-decode, "
+                  "--decode-chain-len, --ondevice-finish)",
+                  config.multi_step_decode > 1 or config.ondevice_finish
+                  or config.decode_chain_len is not None),
+        "mesh": ("tp / pp / dp / sp > 1", par.world_size > 1),
+        "int8_kv": ("--kv-cache-dtype int8", cache.kv_cache_dtype == "int8"),
+    }
+
+
+def _refuse(config: EngineConfig, keys, why: str) -> None:
+    asked = _asked_for(config)
+    bad = [asked[k][0] for k in keys if asked[k][1]]
+    if bad:
+        raise ValueError(why + "; ".join(bad))
+
+
+def refuse_for_rings(config: EngineConfig) -> None:
+    """A model whose windowed layers keep RINGS (``ModelConfig.use_swa``:
+    the windowed latent layers of models/deepseek.py) holds of each
+    sequence, in those layers, the window's last rows and nothing older.
+    So whatever needs a sequence's rows again after the fact, or writes
     rows that may be taken back, is refused at start-up (ROADMAP B4 lists
     these beside the hybrid models'): a cached prefix has no rows to
     resume from, a tier below the pages would hold the full layers' half
     of a sequence, a rejected draft or a discarded fused block has
-    already overwritten the ring, and the unified step re-forms steps."""
-    cache, par = config.cache, config.parallel
-    asked = [
-        ("--enable-prefix-caching", cache.enable_prefix_caching),
-        ("a host or disk KV tier (--kv-host-pool-*, --kv-disk-path, "
-         "--prefix-peers)",
-         cache.host_pool_configured or cache.kvstore_configured),
-        ("--spec-decode / --spec-fused", bool(config.spec_decode)),
-        ("--unified-step", config.unified_step),
-        ("fused multi-step decoding (--multi-step-decode, "
-         "--decode-chain-len, --ondevice-finish)",
-         config.multi_step_decode > 1 or config.ondevice_finish
-         or config.decode_chain_len is not None),
-        ("tp / pp / dp / sp > 1", par.world_size > 1),
-    ]
-    bad = [name for name, on in asked if on]
-    if bad:
-        raise ValueError(
-            "a model with windowed latent-attention layers "
-            "(layer_types: sliding_attention) keeps only the window's "
-            "rows of a sequence; not supported with it: "
-            + "; ".join(bad))
+    already overwritten the ring, and the unified step re-forms steps. A
+    model whose windowed layers keep PAGES is not refused here
+    (``refuse_for_paged_windows``)."""
+    _refuse(config, ("prefix", "tiers", "spec", "unified", "fused", "mesh"),
+            "a model whose windowed layers keep rings (windowed latent "
+            "attention: a slot of the window's last rows a sequence and "
+            "layer, nothing older) cannot give a sequence's rows back; "
+            "not supported with it: ")
+
+
+def refuse_for_paged_windows(config: EngineConfig) -> None:
+    """A model whose windowed GQA layers keep their rows in the paged pool
+    (``ModelConfig.paged_windows``: models/cohere2_moe.py)
+    has the prefix cache and the plain and the prepared loop. What would
+    need work that has not been done is refused by name, never run
+    without its window: the windowed Pallas calls have no shard_map and
+    the expert layer no exchange (any mesh); the unified kernel and the
+    int8 cache's kernels know no window; the fused and speculative
+    programs and the tiers below the pages have not been run with this
+    family's cache."""
+    _refuse(config, ("mesh", "int8_kv", "tiers", "spec", "unified", "fused"),
+            "a model whose windowed GQA layers keep their rows in the "
+            "paged pool is served on the plain path with or without the "
+            "prefix cache; not supported with it yet: ")
 
 
 class LLM:
@@ -265,7 +295,9 @@ class LLM:
             model_cfg = from_hf_config(load_hf_config(config.model))
         self.model_cfg = model_cfg
         if model_cfg.use_swa:
-            refuse_for_windowed(config)
+            refuse_for_rings(config)
+        elif model_cfg.paged_windows:
+            refuse_for_paged_windows(config)
         if model_cfg.use_mamba and config.parallel.world_size > 1:
             raise ValueError(
                 "a model with Mamba-2 layers (layer_types: mamba) is "

@@ -134,12 +134,19 @@ class ModelConfig:
         heads."""
         return self.use_mla and not (self.use_dsa or self.use_swa)
 
-    # Windowed latent-attention layers beside the full ones (dots3_note:
-    # ``layer_types`` marks a layer "sliding_attention"). They have a
-    # latent geometry of their own (the ``swa_*`` keys of the config), a
-    # rotary base of their own, no indexer, and attend the last
-    # ``sliding_window`` tokens, the current one counted. Their rows live
-    # in a ring per sequence (models/deepseek.py), never in pages.
+    # Windowed layers beside the full ones (``layer_types`` marks a layer
+    # "sliding_attention"): they attend the last ``sliding_window``
+    # tokens, the current one counted. Two families have them, and they
+    # keep those layers' rows differently:
+    # - windowed LATENT layers (dots3_note, models/deepseek.py) have a
+    #   latent geometry of their own (the ``swa_*`` keys of the config), a
+    #   rotary base of their own and no indexer, and keep of a sequence a
+    #   RING of the window's last rows, never pages (``use_swa``);
+    # - windowed GQA layers (cohere2_moe, models/cohere2_moe.py) share the
+    #   full layers' geometry and keep their rows in the one paged pool
+    #   under the one page table, behind the window too
+    #   (``has_windowed_layers`` without ``use_swa``): the kernels skip
+    #   the pages behind the window, the prefix cache stays.
     sliding_window: int = 0
     swa_num_heads: int = 0
     swa_q_lora_rank: int = 0
@@ -162,8 +169,26 @@ class ModelConfig:
     expert_first: int = 0
 
     @property
-    def use_swa(self) -> bool:
+    def has_windowed_layers(self) -> bool:
+        """``layer_types`` names windowed layers, however their rows are
+        kept."""
         return "sliding_attention" in self.layer_types
+
+    @property
+    def use_swa(self) -> bool:
+        """The windowed layers keep RINGS: a sequence holds, in each of
+        them, a slot of the window's last rows and nothing older (the
+        windowed latent layers of models/deepseek.py). What needs a
+        sequence's rows again after the fact is refused for these
+        (engine/llm.refuse_for_rings). A windowed GQA model keeps pages
+        (models/cohere2_moe.py) and is no ``use_swa`` model."""
+        return self.has_windowed_layers and self.use_mla
+
+    @property
+    def paged_windows(self) -> bool:
+        """The windowed layers keep their rows in the paged pool beside
+        the full layers', under the one page table."""
+        return self.has_windowed_layers and not self.use_swa
 
     @property
     def num_local_experts(self) -> int:
@@ -266,6 +291,13 @@ class ModelConfig:
     # the form of an expert: "swiglu" (silu(x W_g) * x W_u) W_d, three
     # matrices, or "relu2" relu(x W_u)^2 W_d, two
     expert_act: str = "swiglu"
+
+    # Cohere's block (cohere2_moe, models/cohere2_moe.py): every norm is a
+    # mean-subtracting LayerNorm without bias ("layer"; "rms" elsewhere),
+    # the logits take ``logit_scale``, and the ``num_shared_experts``
+    # shared experts' outputs are averaged
+    norm_kind: str = "rms"
+    logit_scale: float = 1.0
 
     @property
     def use_mamba(self) -> bool:
@@ -398,10 +430,56 @@ def _eos_tuple(v) -> Optional[Tuple[int, ...]]:
 _ARCH_OF_MODEL_TYPE = {"olmo_hybrid": "OlmoHybridForCausalLM",
                        "dots3_note": "Dots3NoteForCausalLM",
                        "axk1": "AXK1ForCausalLM",
-                       "nemotron_h": "NemotronHForCausalLM"}
+                       "nemotron_h": "NemotronHForCausalLM",
+                       "cohere2_moe": "Cohere2MoeForCausalLM"}
 
 # hybrid_override_pattern's letters (NemotronH)
 _NEMOTRON_KINDS = {"M": "mamba", "E": "moe", "*": "full_attention"}
+
+
+def _cohere2_moe(hf: Dict[str, Any]):
+    """config.json of CohereLabs/command-a-plus-05-2026 (model_type
+    cohere2_moe) -> (the keys ``from_hf_config`` reads, the extra fields).
+    A parallel block over ONE LayerNorm; GQA whose "sliding_attention"
+    layers carry rotary embedding (pairs (2i, 2i+1): ``rope_gptj``) and a
+    window and whose "full_attention" layers carry no position at all; a
+    sigmoid router's plain top-k over gated SiLU experts of
+    ``intermediate_size`` and ``num_shared_experts`` shared ones, averaged
+    (served as one shared expert of their widths on end, times 1 / n)."""
+    unserved = [
+        ("use_parallel_block", True), ("use_gated_activation", True),
+        ("use_qk_norm", False), ("first_k_dense_replace", 0),
+        ("attention_bias", False), ("rotary_pct", 1),
+        ("position_embedding_type", "rope_gptj"),
+        ("expert_selection_fn", "sigmoid"), ("hidden_act", "silu"),
+        ("shared_expert_combination_strategy", "average")]
+    bad = [f"{k}={hf[k]!r}" for k, want in unserved
+           if k in hf and hf[k] != want]
+    types = tuple(hf.get("layer_types") or ())
+    if len(types) != hf["num_hidden_layers"] or set(types) - {
+            "sliding_attention", "full_attention"}:
+        bad.append(f"layer_types={types!r} for "
+                   f"{hf['num_hidden_layers']} layers")
+    if bad:
+        raise ValueError(
+            "cohere2_moe: models/cohere2_moe.py serves the published "
+            "block (" + ", ".join(f"{k}={w!r}" for k, w in unserved)
+            + "), not " + ", ".join(bad))
+    rope = hf.get("rope_parameters") or {}
+    inter, shared = hf["intermediate_size"], hf.get("num_shared_experts", 0)
+    extra = dict(layer_types=types,
+                 sliding_window=hf.get("sliding_window", 0) or 0,
+                 norm_kind="layer", logit_scale=hf.get("logit_scale", 1.0))
+    hf = {**hf,
+          "rms_norm_eps": hf.get("layer_norm_eps", 1e-5),
+          "rope_theta": rope.get("rope_theta", hf.get("rope_theta", 1e4)),
+          "rope_scaling": None,
+          "moe_intermediate_size": inter,
+          "n_shared_experts": shared,
+          "shared_expert_intermediate_size": shared * inter,
+          "scoring_func": "sigmoid", "topk_method": "none",
+          "routed_scaling_factor": 1.0}
+    return hf, extra
 
 
 def from_hf_config(hf: Dict[str, Any]) -> ModelConfig:
@@ -593,26 +671,32 @@ def from_hf_config(hf: Dict[str, Any]) -> ModelConfig:
                                      hf.get("norm_eps", 1e-5)),
               "shared_expert_intermediate_size":
                   hf.get("moe_shared_expert_intermediate_size", 0)}
+    if arch == "Cohere2MoeForCausalLM":
+        hf, extra = _cohere2_moe(hf)
     share = hf.get("ep_share")
     if share:
-        # this repo's own key, read for every family models/deepseek.py
-        # serves: {"chips", "rank", "n_routed_experts"} says that
-        # ``n_routed_experts`` counts the experts HELD here, one of
-        # ``chips`` equal shares of the published count
-        from gllm_tpu.models.registry import _MLA_ARCHS, _NEMOTRON_H_ARCHS
-        if arch not in _MLA_ARCHS + _NEMOTRON_H_ARCHS:
+        # this repo's own key, read for every family that holds a share of
+        # its experts: {"chips", "rank", <count>} where <count> is the
+        # config's own key for the number of routed experts
+        # (``n_routed_experts``; ``num_experts`` for cohere2_moe) says
+        # that this key of the config counts the experts HELD here, one
+        # of ``chips`` equal shares of the published count
+        from gllm_tpu.models.registry import (_COHERE2_MOE_ARCHS, _MLA_ARCHS,
+                                              _NEMOTRON_H_ARCHS)
+        if arch not in _MLA_ARCHS + _NEMOTRON_H_ARCHS + _COHERE2_MOE_ARCHS:
             raise ValueError(f"ep_share: {arch} holds no share of its "
-                             "experts (models/deepseek.py's families and "
-                             "models/nemotron_h.py's do)")
-        held = hf["n_routed_experts"]
-        if share["n_routed_experts"] != held * share["chips"]:
+                             "experts (the families of models/deepseek.py,"
+                             " nemotron_h.py and cohere2_moe.py do)")
+        count = ("num_experts" if arch in _COHERE2_MOE_ARCHS
+                 else "n_routed_experts")
+        held = hf[count]
+        if share[count] != held * share["chips"]:
             raise ValueError(
                 f"ep_share: {share['chips']} chips x {held} experts "
-                f"held are not the {share['n_routed_experts']} "
-                "published")
+                f"held are not the {share[count]} published")
         extra.update(experts_held=held,
                      expert_first=held * share.get("rank", 0))
-        hf = {**hf, "n_routed_experts": share["n_routed_experts"]}
+        hf = {**hf, count: share[count]}
     num_heads = hf["num_attention_heads"]
     hidden = hf["hidden_size"]
     head_dim = hf.get("head_dim") or hidden // num_heads
@@ -658,7 +742,8 @@ def from_hf_config(hf: Dict[str, Any]) -> ModelConfig:
         attention_bias=attention_bias,
         qk_norm=qk_norm,
         partial_rotary_factor=hf.get("partial_rotary_factor", 1.0) or 1.0,
-        rope_interleaved=is_glm4 or is_glm,
+        rope_interleaved=(is_glm4 or is_glm
+                          or arch == "Cohere2MoeForCausalLM"),
         sandwich_norms=is_glm4,
         eos_token_id=_eos_tuple(hf.get("eos_token_id")),
         bos_token_id=_first_eos(hf.get("bos_token_id")),

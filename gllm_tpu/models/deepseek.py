@@ -402,8 +402,8 @@ def _held_experts(lp: Params, x, weights, ids, valid, cfg: ModelConfig,
     dots3_note's widths: 4.6 ms of a v5e's bandwidth, measured); so the
     product takes the whole stack as n x held groups, of which only this
     layer's have rows, and reads in place what its groups touch.
-    ``grouped``: how the relu^2 form's two grouped products run ("xla" |
-    "pallas": ``_grouped_dot``; the gated form's three are XLA's).
+    ``grouped``: how the grouped products run ("xla" | "pallas":
+    ``_grouped_dot``).
     Returns (combined [T, H] float32, stats [4])."""
     T, H = x.shape
     K, held = cfg.num_experts_per_tok, cfg.experts_held
@@ -441,10 +441,10 @@ def _held_experts(lp: Params, x, weights, ids, valid, cfg: ModelConfig,
             act = relu2(_grouped_dot(xs, ws[0], sizes_i, eids, grouped))
             out = _grouped_dot(act, ws[-1], sizes_i, eids, grouped)
         else:
-            gate = qragged_dot(xs, ws[0], sizes_i, eids)
-            up = qragged_dot(xs, ws[1], sizes_i, eids)
+            gate = _grouped_dot(xs, ws[0], sizes_i, eids, grouped)
+            up = _grouped_dot(xs, ws[1], sizes_i, eids, grouped)
             act = silu_and_mul(jnp.concatenate([gate, up], axis=-1))
-            out = qragged_dot(act, ws[-1], sizes_i, eids)
+            out = _grouped_dot(act, ws[-1], sizes_i, eids, grouped)
         out = jnp.where(live[:, None], out.astype(jnp.float32)
                         * flat_w[idx][:, None], 0.0)
         return combined.at[token_of].add(out)

@@ -29,8 +29,8 @@ import jax.numpy as jnp
 from gllm_tpu.batching import StepBatch
 from gllm_tpu.models.config import ModelConfig
 from gllm_tpu.ops import (apply_rope, compute_rope_cos_sin,
-                          fused_add_rms_norm, paged_attention, rms_norm,
-                          silu_and_mul, write_kv, write_kv_quant)
+                          fused_add_rms_norm, layer_norm, paged_attention,
+                          rms_norm, silu_and_mul, write_kv, write_kv_quant)
 from gllm_tpu.ops.rope import apply_mrope, apply_rope_interleaved
 from gllm_tpu.ops.quant import qmm
 from gllm_tpu.parallel.mesh import shard_hint
@@ -130,7 +130,8 @@ def init_params(cfg: ModelConfig, seed: int = 0,
 
 def _attention(lp, x, batch: StepBatch, k_all, v_all, cfg: ModelConfig,
                cos_sin, *, attn_impl: str, max_q_len: int, li,
-               ks_all=None, vs_all=None):
+               ks_all=None, vs_all=None, use_rope: bool = True,
+               window: Optional[int] = None):
     """One layer's attention against the STACKED [L, P, ...] cache.
 
     The cache is addressed through a flat [L*P, ...] view with the layer
@@ -146,7 +147,12 @@ def _attention(lp, x, batch: StepBatch, k_all, v_all, cfg: ModelConfig,
     (kv_cache_dtype=int8): new rows quantize at write time against the
     running per-page absmax scale and the kernels dequantize in VMEM —
     the flat [L*P, Hkv] scale view is indexed by the same offset page
-    ids as the cache itself."""
+    ids as the cache itself.
+
+    ``use_rope`` False leaves q and k without a positional term and
+    ``window`` bounds what a query attends to its last ``window``
+    positions (a model whose layers differ in kind says both per layer:
+    models/cohere2_moe.py); the rows stay in the pages either way."""
     T = x.shape[0]
     Hq, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     L, P, page_size = k_all.shape[0], k_all.shape[1], k_all.shape[2]
@@ -179,7 +185,9 @@ def _attention(lp, x, batch: StepBatch, k_all, v_all, cfg: ModelConfig,
         # per-head RMSNorm over D (reference qwen3.py adds q/k norms)
         q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
         k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
-    if cfg.mrope_section and batch.mrope_positions is not None:
+    if not use_rope:
+        pass                                  # no positional term at all
+    elif cfg.mrope_section and batch.mrope_positions is not None:
         q, k = apply_mrope(q, k, batch.mrope_positions, cos_sin,
                            cfg.mrope_section,
                            interleaved=cfg.mrope_interleaved)
@@ -213,7 +221,8 @@ def _attention(lp, x, batch: StepBatch, k_all, v_all, cfg: ModelConfig,
         attn = paged_attention(q, k_cache, v_cache, md,
                                scale=D ** -0.5, max_q_len=max_q_len,
                                impl=attn_impl,
-                               k_scale=k_scale, v_scale=v_scale)
+                               k_scale=k_scale, v_scale=v_scale,
+                               window=window)
     out = qmm(attn.reshape(T, Hq * D), lp["o_proj"])
     return (out, k_cache.reshape(k_all.shape),
             v_cache.reshape(v_all.shape),
@@ -303,17 +312,25 @@ def forward(
     return hidden, residual, KVCache(k_all, v_all, ks_all, vs_all)
 
 
+def _head(params: Params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
+    """Final norm and head of the rows ``x``: float32 logits. The norm is
+    the model's kind (``cfg.norm_kind``); ``cfg.logit_scale`` other than 1
+    multiplies the logits (Cohere)."""
+    norm = layer_norm if cfg.norm_kind == "layer" else rms_norm
+    normed = norm(x, params["final_norm"], cfg.rms_norm_eps)
+    head = (params["embed"].T if cfg.tie_word_embeddings
+            else params["lm_head"])
+    logits = (normed @ head).astype(jnp.float32)
+    return logits if cfg.logit_scale == 1.0 else logits * cfg.logit_scale
+
+
 def compute_full_logits(params: Params, hidden: jnp.ndarray,
                         residual: jnp.ndarray,
                         cfg: ModelConfig) -> jnp.ndarray:
     """Logits for EVERY token row [T, V] (prompt-logprob path). Single
     source of truth for the final-norm + head projection; compute_logits
     is the [S]-row gather specialization of the same math."""
-    final = hidden + residual
-    normed = rms_norm(final, params["final_norm"], cfg.rms_norm_eps)
-    head = (params["embed"].T if cfg.tie_word_embeddings
-            else params["lm_head"])
-    return shard_hint((normed @ head).astype(jnp.float32), None, None)
+    return shard_hint(_head(params, hidden + residual, cfg), None, None)
 
 
 def compute_logits(params: Params, hidden: jnp.ndarray,
@@ -327,13 +344,10 @@ def compute_logits(params: Params, hidden: jnp.ndarray,
     """
     final = hidden + residual
     sel = final[batch.logits_indices]                       # [S, H]
-    sel = rms_norm(sel, params["final_norm"], cfg.rms_norm_eps)
-    head = (params["embed"].T if cfg.tie_word_embeddings
-            else params["lm_head"])
     # All-gather the vocab-sharded logits before sampling (the reference's
     # logits all-gather, vocab_parallel_embedding.py): the sampler sorts over
     # the full vocab per row.
-    return shard_hint((sel @ head).astype(jnp.float32), None, None)
+    return shard_hint(_head(params, sel, cfg), None, None)
 
 
 def make_rope_table(cfg: ModelConfig) -> jnp.ndarray:

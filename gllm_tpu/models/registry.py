@@ -26,6 +26,12 @@ class ModelDef:
     kv_specs: Callable             # (cfg, tp) -> cache PartitionSpec pytree
     # VL models: (params, cfg, pixels, grid_thw) -> [n_rows, mm_embed_dim]
     embed_mm: Optional[Callable] = None
+    # (cfg, kv_lens, decode_only): a step's rows into the family's counter,
+    # from the host's batch at dispatch
+    count_rows_read: Optional[Callable] = None
+    # (cfg, *, weight_bytes, num_pages, page_bytes, page_size, prefix_cache,
+    # attn_impl, quantized) -> what the chip holds, one start-up line
+    startup_line: Optional[Callable] = None
 
 
 def _dense_def() -> ModelDef:
@@ -148,6 +154,21 @@ def get_model_def(cfg: ModelConfig) -> ModelDef:
             param_specs=nemotron_h.no_mesh_specs,
             kv_specs=nemotron_h.no_mesh_specs,
         )
+    if cfg.architecture in _COHERE2_MOE_ARCHS:
+        from gllm_tpu.models import cohere2_moe
+        return ModelDef(
+            family="cohere2_moe",
+            init_params=cohere2_moe.init_params,
+            forward=cohere2_moe.forward,
+            compute_logits=cohere2_moe.compute_logits,
+            make_rope_table=cohere2_moe.make_rope_table,
+            load_params=cohere2_moe.load_params,
+            init_kv_cache=cohere2_moe.init_kv_cache,
+            param_specs=cohere2_moe.no_mesh_specs,
+            kv_specs=cohere2_moe.no_mesh_specs,
+            count_rows_read=cohere2_moe.count_rows_read,
+            startup_line=cohere2_moe.startup_line,
+        )
     raise NotImplementedError(
         f"architecture {cfg.architecture!r} not supported yet; "
         f"dense: {_DENSE_ARCHS}, moe: {_MOE_ARCHS}, mla: {_MLA_ARCHS}, "
@@ -206,6 +227,16 @@ _NEMOTRON_H_ARCHS = (
 )
 
 
+_COHERE2_MOE_ARCHS = (
+    # CohereLabs/command-a-plus-05-2026 (model_type cohere2_moe): a parallel
+    # block over one LayerNorm, GQA layers with a window and rotary
+    # embedding beside full layers without positions, all in the one paged
+    # pool; sigmoid-routed experts beside averaged shared ones
+    # (models/cohere2_moe.py)
+    "Cohere2MoeForCausalLM",
+)
+
+
 def supported_architectures() -> Dict[str, str]:
     out = {a: "dense" for a in _DENSE_ARCHS}
     out.update({a: "moe" for a in _MOE_ARCHS})
@@ -215,4 +246,5 @@ def supported_architectures() -> Dict[str, str]:
     out["KimiK25ForConditionalGeneration"] = "kimi"
     out.update({a: "hybrid" for a in _HYBRID_ARCHS})
     out.update({a: "nemotron_h" for a in _NEMOTRON_H_ARCHS})
+    out.update({a: "cohere2_moe" for a in _COHERE2_MOE_ARCHS})
     return out

@@ -8,8 +8,8 @@ XLA reference implementation (runs everywhere, used as the test oracle) and a
 Pallas TPU kernel, selected via :func:`gllm_tpu.ops.attention.paged_attention`.
 """
 
-from gllm_tpu.ops.layers import (fused_add_rms_norm, rms_norm, silu_and_mul,
-                                 gelu_and_mul)
+from gllm_tpu.ops.layers import (fused_add_rms_norm, layer_norm, rms_norm,
+                                 silu_and_mul, gelu_and_mul)
 from gllm_tpu.ops.rope import apply_rope, compute_rope_cos_sin
 from gllm_tpu.ops.kv_cache import write_kv, write_kv_quant
 from gllm_tpu.ops.attention import paged_attention
@@ -19,6 +19,7 @@ __all__ = [
     "compute_rope_cos_sin",
     "fused_add_rms_norm",
     "gelu_and_mul",
+    "layer_norm",
     "paged_attention",
     "rms_norm",
     "silu_and_mul",

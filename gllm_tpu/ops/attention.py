@@ -84,12 +84,22 @@ def pallas_tp_compatible(num_q_heads: int, num_kv_heads: int,
 
 
 def paged_attention(q, k_cache, v_cache, metadata, *, scale, max_q_len,
-                    impl="xla", v_dim=None, k_scale=None, v_scale=None):
+                    impl="xla", v_dim=None, k_scale=None, v_scale=None,
+                    window=None):
     """Public entry: dispatch to the (jitted) single-shard implementation,
     wrapping the Pallas path in shard_map when a TP shard context is set.
     ``k_scale``/``v_scale`` ([num_pages, Hkv] f32) mark an int8 quantized
     cache — both implementations dequantize on the read path (in-kernel
-    for Pallas, on the gathered pages for XLA)."""
+    for Pallas, on the gathered pages for XLA). ``window`` (static): a
+    query at position t attends positions t - window < j <= t of its
+    sequence's pages and nothing older (a windowed GQA layer whose rows
+    stay in the paged pool); the Pallas calls then carry the names
+    ``WINDOW_NAMES`` and fetch only the pages the window overlaps."""
+    if window and (impl == "unified" or (
+            impl == "pallas" and _SHARD_CTX is not None)):
+        raise NotImplementedError(
+            "windowed paged attention under the unified kernel or a tp "
+            "shard context")
     if impl in ("pallas", "unified") and _SHARD_CTX is not None:
         mesh, axis = _SHARD_CTX
         tp = mesh.shape[axis]
@@ -101,7 +111,7 @@ def paged_attention(q, k_cache, v_cache, metadata, *, scale, max_q_len,
                                    impl=impl)
     return _paged_attention(q, k_cache, v_cache, metadata, k_scale,
                             v_scale, scale=scale, max_q_len=max_q_len,
-                            impl=impl, v_dim=v_dim)
+                            impl=impl, v_dim=v_dim, window=window)
 
 
 def _pallas_sharded(q, k_cache, v_cache, metadata, *, scale, max_q_len,
@@ -180,7 +190,7 @@ def _pallas_sharded(q, k_cache, v_cache, metadata, *, scale, max_q_len,
 
 
 @functools.partial(jax.jit, static_argnames=("max_q_len", "scale", "impl",
-                                             "v_dim"))
+                                             "v_dim", "window"))
 def _paged_attention(
     q: jnp.ndarray,            # [T, Hq, D]
     k_cache: jnp.ndarray,      # [num_pages, page_size, Hkv, D]
@@ -196,6 +206,7 @@ def _paged_attention(
     max_q_len: int,
     impl: str = "xla",
     v_dim: Optional[int] = None,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     if v_cache is None and v_dim is None:
         raise ValueError("v_dim required when v_cache is None")
@@ -221,7 +232,8 @@ def _paged_attention(
                 v_scale = jnp.repeat(v_scale, pack, axis=1)
         return _xla_paged_attention(q, k_cache, v_cache, metadata,
                                     scale=scale, max_q_len=max_q_len,
-                                    k_scale=k_scale, v_scale=v_scale)
+                                    k_scale=k_scale, v_scale=v_scale,
+                                    window=window)
     if impl in ("pallas", "unified"):
         backend = jax.default_backend()
         if backend == "cpu":
@@ -275,11 +287,13 @@ def _paged_attention(
             out = _decode_kernel(
                 q, k_cache, v_cache, metadata.kv_lens, metadata.page_table,
                 k_scale, v_scale, scale=scale, interpret=interpret,
-                v_dim=v_dim)
+                v_dim=v_dim, window=window,
+                name=WINDOW_NAMES["decode"] if window else None)
         else:
             out = _mixed_step_attention(
                 q, k_cache, v_cache, metadata, k_scale, v_scale,
-                scale=scale, interpret=interpret, v_dim=v_dim)
+                scale=scale, interpret=interpret, v_dim=v_dim,
+                window=window)
         if pack > 1:
             # The packed p·v_packed dot produced every lane block; keep
             # each head's own block (the rest mixed other heads' values).
@@ -294,18 +308,19 @@ def _paged_attention(
 
 def _decode_kernel(q, k_cache, v_cache, kv_lens, page_table, k_scale,
                    v_scale, *, scale: float, interpret: bool, v_dim,
-                   name=None, chosen=None):
+                   name=None, chosen=None, window=None):
     """The decode kernel over one query row a sequence, at the table's
     blocks for the cache's KV heads (and for a selection's mask,
-    ``chosen``, where the call takes one)."""
+    ``chosen``, or a ``window``, where the call takes one)."""
     from gllm_tpu.ops.pallas.decode_attention import paged_decode_attention
     from gllm_tpu.ops.pallas.tuning import decode_blocks
-    cfg = decode_blocks(k_cache.shape[2], chosen=chosen is not None)
+    cfg = decode_blocks(k_cache.shape[2], chosen=chosen is not None,
+                        num_q_heads=q.shape[1])
     return paged_decode_attention(
         q, k_cache, v_cache, kv_lens, page_table, scale=scale,
         interpret=interpret, v_dim=v_dim, kv_block=cfg["kv_block"],
         group_size=int(cfg.get("group", 1)), k_scale=k_scale,
-        v_scale=v_scale, name=name, chosen=chosen)
+        v_scale=v_scale, name=name, chosen=chosen, window=window)
 
 
 # What the riding rows' call is named in the HLO and on the trace's
@@ -316,10 +331,18 @@ def _decode_kernel(q, k_cache, v_cache, kv_lens, page_table, k_scale,
 # classed as decode-only by holding ``paged_decode_attention``.
 DECODE_ROWS_NAME = "ragged_paged_attention_decode_rows"
 
+# The windowed calls' names (``paged_attention(window=...)``), their own so
+# that a reader tells a windowed layer's time from a full layer's in one
+# step program: the decode-only step's call, the mixed step's chunk call,
+# and its riding rows' (which, as above, begins with the chunk call's).
+WINDOW_NAMES = {"decode": "swa_paged_decode_attention",
+                "ragged": "swa_ragged_paged_attention",
+                "rows": "swa_ragged_paged_attention_decode_rows"}
+
 
 def _mixed_step_attention(q, k_cache, v_cache, md: AttentionMetadata,
                           k_scale, v_scale, *, scale: float,
-                          interpret: bool, v_dim):
+                          interpret: bool, v_dim, window=None):
     """A batch with ``max_q_len > 1``, split by what ``cu_q_lens`` shows:
     the leading one-token sequences (the decode prefix: the scheduler packs
     decoding rows first, and token ``s`` IS sequence ``s`` there) go to the
@@ -340,13 +363,15 @@ def _mixed_step_attention(q, k_cache, v_cache, md: AttentionMetadata,
         q[:S] if T >= S else jnp.pad(q, ((0, S - T), (0, 0), (0, 0))),
         k_cache, v_cache, jnp.where(riding, md.kv_lens, 0), md.page_table,
         k_scale, v_scale, scale=scale, interpret=interpret, v_dim=v_dim,
-        name=DECODE_ROWS_NAME)
+        name=WINDOW_NAMES["rows"] if window else DECODE_ROWS_NAME,
+        window=window)
     blocks = ragged_blocks(q.shape[1], k_cache.shape[2])
     out = ragged_paged_attention(
         q, k_cache, v_cache, md.cu_q_lens,
         jnp.where(riding, 0, md.kv_lens), md.page_table, scale=scale,
         interpret=interpret, v_dim=v_dim, q_block=blocks["q_block"],
-        kv_block=blocks["kv_block"], k_scale=k_scale, v_scale=v_scale)
+        kv_block=blocks["kv_block"], k_scale=k_scale, v_scale=v_scale,
+        window=window, name=WINDOW_NAMES["ragged"] if window else None)
     # the riding rows are the first min(S, T) tokens at most: write those
     # rows, not a select over all T (at 512 x 64 x 512 that is 100 MB of
     # traffic a layer)
@@ -357,7 +382,7 @@ def _mixed_step_attention(q, k_cache, v_cache, md: AttentionMetadata,
 
 def _xla_paged_attention(q, k_cache, v_cache, md: AttentionMetadata, *,
                          scale: float, max_q_len: int,
-                         k_scale=None, v_scale=None):
+                         k_scale=None, v_scale=None, window=None):
     T, num_q_heads, head_dim = q.shape
     num_pages, page_size, num_kv_heads, _ = k_cache.shape
     v_dim = v_cache.shape[-1]     # may differ from head_dim (MLA: values
@@ -393,6 +418,8 @@ def _xla_paged_attention(q, k_cache, v_cache, md: AttentionMetadata, *,
     visible = (kv_pos[None, None, :] <= q_pos[:, :, None])           # [S,Q,K]
     visible &= (kv_pos[None, None, :] < md.kv_lens[:, None, None])
     visible &= q_valid[:, :, None]
+    if window:
+        visible &= kv_pos[None, None, :] > q_pos[:, :, None] - window
 
     qg = qg.reshape(S, max_q_len, num_kv_heads, group, head_dim)
     scores = jnp.einsum("sqhgd,skhd->shgqk", qg.astype(jnp.float32),

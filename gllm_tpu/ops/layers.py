@@ -24,6 +24,18 @@ def rms_norm(x: jnp.ndarray, weight: jnp.ndarray,
     return (normed * weight.astype(jnp.float32)).astype(dtype)
 
 
+def layer_norm(x: jnp.ndarray, weight: jnp.ndarray,
+               eps: float = 1e-5) -> jnp.ndarray:
+    """Mean-subtracting LayerNorm without a bias (Cohere's):
+    (x - mean(x)) / sqrt(var(x) + eps) * weight."""
+    dtype = x.dtype
+    xf = x.astype(jnp.float32)
+    centred = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(centred * centred, axis=-1, keepdims=True)
+    normed = centred * jax.lax.rsqrt(var + eps)
+    return (normed * weight.astype(jnp.float32)).astype(dtype)
+
+
 def fused_add_rms_norm(x: jnp.ndarray, residual: jnp.ndarray,
                        weight: jnp.ndarray, eps: float = 1e-6):
     """residual' = x + residual; y = rms_norm(residual').

@@ -53,6 +53,12 @@ Design (TPU-first, not a Triton translation):
   against 1.780 ms a call of 64 rows at 6.7 k of context, PR 43:
   docs/onchip_pr43/dsa_rows_forms.json), and the products, not the
   bytes, bind (36 % of the peak FLOP/s, 38 % of the rows' HBM time).
+- A window: ``window`` (static) makes a row attend the last ``window``
+  positions of its context only, the current one counted. Its first
+  block is the one that holds position ``kv_len - window``; the blocks
+  behind it are neither fetched nor scored, and that first block's rows
+  from behind the window are masked (``attend_block(tokens_from=...)``).
+  Without the argument the program is the kernel it always was.
 
 What binds (PERF.md section 6, PR 28; the kernel alone on a v5e at 32
 rows of 320-1909 tokens, bf16, 8 and 32 kv heads of 128): the update
@@ -115,7 +121,7 @@ def _kernel(kv_lens_ref, pt_ref,            # scalar prefetch
             *refs,
             page_size: int, pages_per_block: int, scale: float,
             num_kv_heads: int, v_dim: int, shared_kv: bool, gsz: int,
-            quant: bool, masked: bool):
+            quant: bool, masked: bool, window: Optional[int]):
     """``gsz`` sequences per grid program, TWO buffer slots each: in
     round ``r`` every sequence that still has a block ``r`` starts the
     fetch of its block ``r + 1``, waits for block ``r`` and attends it,
@@ -125,7 +131,8 @@ def _kernel(kv_lens_ref, pt_ref,            # scalar prefetch
     it (see the module docstring for what binds). ``masked``: the first
     ref is the group's selection [gsz, blocks, BK * Hkv] (int32, nonzero
     where a row counts), of which a round takes its block's [1, BK * Hkv]
-    by the block's index on the sublane axis."""
+    by the block's index on the sublane axis. ``window``: a sequence's
+    rounds begin at the block that holds position ``kv_len - window``."""
     *refs, m_ref, l_ref, acc_ref = refs
     chosen_ref = None
     if masked:
@@ -159,8 +166,12 @@ def _kernel(kv_lens_ref, pt_ref,            # scalar prefetch
     # next program's sequence in the round after its last block, so the
     # HBM stays busy while a group's longest context runs out alone, and
     # no program opens with a DMA's latency.
+    def first_block(s):
+        return jnp.maximum(kv_lens_ref[s] - window, 0) // bk
+
     def start_first(program, g):
-        fetch(start_fetch, program * gsz + g, 2 * g, 0)
+        s = program * gsz + g
+        fetch(start_fetch, s, 2 * g, first_block(s) if window else 0)
 
     for g in range(gsz):
         pl.when(gi == 0)(functools.partial(start_first, 0, g))
@@ -172,6 +183,9 @@ def _kernel(kv_lens_ref, pt_ref,            # scalar prefetch
     seq_ids = [gi * gsz + g for g in range(gsz)]
     kv_lens = [kv_lens_ref[s] for s in seq_ids]
     n_blocks = [pl.cdiv(kv_len, bk) for kv_len in kv_lens]
+    if window:
+        first = [first_block(s) for s in seq_ids]
+        n_blocks = [n - f for n, f in zip(n_blocks, first)]
     m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
     l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
     acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
@@ -185,14 +199,17 @@ def _kernel(kv_lens_ref, pt_ref,            # scalar prefetch
         for g in range(gsz):
             @pl.when(r < n_blocks[g])
             def _(g=g):
-                fetch(start_fetch, seq_ids[g], 2 * g + 1 - parity, r + 1)
-                fetch(wait_fetch, seq_ids[g], 2 * g + parity, r)
+                blk = first[g] + r if window else r
+                fetch(start_fetch, seq_ids[g], 2 * g + 1 - parity, blk + 1)
+                fetch(wait_fetch, seq_ids[g], 2 * g + parity, blk)
                 m_ref[g], l_ref[g], acc_ref[g] = attend_block(
                     q_ref[g], k_buf, v_buf, 2 * g + parity, own_tokens,
-                    kv_lens[g] - r * bk, scale, v_dim, shared_kv,
+                    kv_lens[g] - blk * bk, scale, v_dim, shared_kv,
                     m_ref[g], l_ref[g], acc_ref[g], ks_buf=ks_buf,
                     vs_buf=vs_buf, chosen=(
-                        chosen_ref[g, pl.ds(r, 1), :] if masked else None))
+                        chosen_ref[g, pl.ds(r, 1), :] if masked else None),
+                    tokens_from=(kv_lens[g] - window - blk * bk
+                                 if window else None))
 
             pl.when(r == n_blocks[g])(functools.partial(hand_over, g))
 
@@ -205,7 +222,8 @@ def _kernel(kv_lens_ref, pt_ref,            # scalar prefetch
 
 @functools.partial(jax.jit,
                    static_argnames=("scale", "kv_block", "interpret",
-                                    "v_dim", "group_size", "name"))
+                                    "v_dim", "group_size", "name",
+                                    "window"))
 def paged_decode_attention(
     q: jnp.ndarray,            # [S, Hq, D]
     k_cache: jnp.ndarray,      # [num_pages, page_size, Hkv, D]
@@ -226,6 +244,8 @@ def paged_decode_attention(
                                   # bool: a row attends a position of its
                                   # context only where this is true (None:
                                   # the program has no such operand)
+    window: Optional[int] = None,  # a row attends its last ``window``
+                                  # positions only (None: all of them)
 ) -> jnp.ndarray:
     S, num_q_heads, head_dim = q.shape
     num_pages, page_size, num_kv_heads, _ = k_cache.shape
@@ -261,6 +281,8 @@ def paged_decode_attention(
                              ((0, 0), (0, pages_per_block - rem)))
         max_pages += pages_per_block - rem
     masked = chosen is not None
+    if masked and window:
+        raise NotImplementedError("a selection's mask under a window")
     if masked:
         # as the kernel slices it: int32 (a block's [1, rows] leaves VMEM
         # by a dynamic index on the sublane axis, which Mosaic takes for
@@ -290,7 +312,8 @@ def paged_decode_attention(
     kernel = functools.partial(
         _kernel, page_size=page_size, pages_per_block=pages_per_block,
         scale=scale, num_kv_heads=num_kv_heads, v_dim=v_dim,
-        shared_kv=shared_kv, gsz=gsz, quant=quant, masked=masked)
+        shared_kv=shared_kv, gsz=gsz, quant=quant, masked=masked,
+        window=window)
 
     kv_specs, scratch_shapes, kv_inputs = kv_stream_specs(
         k_cache, v_cache, pages_per_block, slots=2 * gsz, k_scale=k_scale,

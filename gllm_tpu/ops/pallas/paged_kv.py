@@ -182,7 +182,7 @@ def mxu_operand(q_dtype, kv_dtype, quant: bool):
 
 def attend_block(q, k_buf, v_buf, slot, own_tokens, tokens_left, scale,
                  v_dim: int, shared_kv: bool, m, l, acc, ks_buf=None,
-                 vs_buf=None, chosen=None):
+                 vs_buf=None, chosen=None, tokens_from=None):
     """One kv-block online-softmax update of the decode kernel.
 
     The block is consumed as the DMA left it: ``k_buf[slot]`` is
@@ -201,7 +201,13 @@ def attend_block(q, k_buf, v_buf, slot, own_tokens, tokens_left, scale,
     a selection over the block's rows: a row counts where it is nonzero
     AND holds a token of the context. Only under a selection can a block
     hold nothing that counts; ``m`` then stays at -inf, and the exponents
-    are taken against 0 in its place (``exp(-inf - -inf)`` is a NaN)."""
+    are taken against 0 in its place (``exp(-inf - -inf)`` is a NaN).
+
+    ``tokens_from`` (a traced scalar, or None: no such condition in the
+    program) is a window's lower edge within the block: a row counts only
+    where its token's index in the block is at least that (a windowed
+    layer's first fetched block holds up to a block less one token from
+    behind the window)."""
     quant = ks_buf is not None
     k = k_buf[slot]                                  # [ppb, page*Hkv, D]
     ppb, page_rows, head_dim = k.shape
@@ -229,10 +235,12 @@ def attend_block(q, k_buf, v_buf, slot, own_tokens, tokens_left, scale,
     counts = own_tokens < tokens_left
     if chosen is not None:
         counts &= chosen != 0
+    if tokens_from is not None:
+        counts &= own_tokens >= tokens_from
     scores = jnp.where(counts, scores, -jnp.inf)
 
     m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
-    m_exp = m_new if chosen is None else jnp.where(
+    m_exp = m_new if chosen is None and tokens_from is None else jnp.where(
         m_new == -jnp.inf, 0.0, m_new)
     alpha = jnp.exp(m - m_exp)
     p = jnp.exp(scores - m_exp)

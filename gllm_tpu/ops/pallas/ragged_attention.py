@@ -44,6 +44,13 @@ Design (TPU-first):
   (m/l/acc carried across the sequence loop).
 - Values may have a different head dim than keys (Dv != D) to serve the MLA
   absorbed path, where v is the latent prefix of k.
+- A window (``window``, static; the legacy path only): a query at
+  position t attends positions t - window < j <= t. Of a sequence, a q
+  block streams the kv blocks from the one that holds the window's start
+  of its FIRST overlapping row to the causal limit of its last; blocks
+  behind that are neither fetched nor scored, and rows of a fetched block
+  that lie behind a query's own window are masked. Without the argument
+  the program is the kernel it always was.
 """
 
 from __future__ import annotations
@@ -188,7 +195,7 @@ def _kernel(cu_ref, kv_lens_ref, pt_ref, first_ref, last_ref,
             page_size: int, pages_per_block: int, scale: float,
             num_kv_heads: int, group: int, head_dim: int, v_dim: int,
             q_blk: int, shared_kv: bool, mqa: bool, quant: bool,
-            unified: bool, gsz: int, amla: bool):
+            unified: bool, gsz: int, amla: bool, window=None):
     (q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf,
      vs_buf, sems) = unpack_refs(refs, shared_kv, quant)
     b = pl.program_id(0)
@@ -221,7 +228,7 @@ def _kernel(cu_ref, kv_lens_ref, pt_ref, first_ref, last_ref,
                       kv_axis=kv_axis, num_kv_heads=num_kv_heads,
                       group=group, head_dim=head_dim, v_dim=v_dim,
                       q_blk=q_blk, shared_kv=shared_kv, mqa=mqa,
-                      amla=amla, score_scale=score_scale)
+                      amla=amla, score_scale=score_scale, window=window)
 
     if not unified:
         _ragged_body()
@@ -251,7 +258,7 @@ def _ragged_block(q, cu_ref, kv_lens_ref, o_ref, start_fetch, wait_fetch,
                   bk: int, rows: int, kv_axis: int, num_kv_heads: int,
                   group: int, head_dim: int, v_dim: int, q_blk: int,
                   shared_kv: bool, mqa: bool, amla: bool,
-                  score_scale=None):
+                  score_scale=None, window=None):
     """The ragged (prefill/mixed) block body: loop the sequences
     overlapping this q block, stream each one's causal KV range with
     double-buffered DMA, masked kv-head-batched dots. ``score_scale``
@@ -287,16 +294,26 @@ def _ragged_block(q, cu_ref, kv_lens_ref, o_ref, start_fetch, wait_fetch,
         kv_limit = kv_len - q_len + (hi - 1 - q_start) + 1
         kv_limit = jnp.where(hi > lo, jnp.minimum(kv_limit, kv_len), 0)
         n_blocks = pl.cdiv(kv_limit, bk)
+        if window:
+            # the first overlapping row's window starts the stream: the
+            # loop counts the blocks from ``b0`` on
+            p_lo = kv_len - q_len + (lo - q_start)
+            b0 = jnp.where(hi > lo,
+                           jnp.maximum(p_lo - window + 1, 0) // bk, 0)
+            n_blocks = jnp.maximum(n_blocks - b0, 0)
 
         @pl.when(n_blocks > 0)
         def _():
-            start_fetch(0, s, 0)
+            start_fetch(0, s, b0 if window else 0)
 
         def blk_body(i, carry2):
             m, l, acc = carry2
             slot = jax.lax.rem(i, 2)
+            more = i + 1 < n_blocks
+            if window:
+                i = b0 + i
 
-            @pl.when(i + 1 < n_blocks)
+            @pl.when(more)
             def _():
                 start_fetch(1 - slot, s, i + 1)
 
@@ -321,6 +338,8 @@ def _ragged_block(q, cu_ref, kv_lens_ref, o_ref, start_fetch, wait_fetch,
             in_seq = (row_tok >= q_start) & (row_tok < q_end)
             q_pos = kv_len - q_len + (row_tok - q_start)
             visible = in_seq & (kv_pos <= q_pos) & (kv_pos < kv_len)
+            if window:
+                visible &= kv_pos > q_pos - window
             scores = jnp.where(visible, scores, NEG_INF)
             return _online_update(scores, vt, m, l, acc, kv_axis, mqa,
                                   amla)
@@ -470,7 +489,7 @@ def _decode_prefix_len(cu_q_lens, S: int):
 @functools.partial(
     jax.jit,
     static_argnames=("scale", "q_block", "kv_block", "interpret", "v_dim",
-                     "unified", "group_size", "amla"))
+                     "unified", "group_size", "amla", "window", "name"))
 def ragged_paged_attention(
     q: jnp.ndarray,            # [T, Hq, D] packed ragged tokens
     k_cache: jnp.ndarray,      # [num_pages, page_size, Hkv, D]
@@ -489,6 +508,10 @@ def ragged_paged_attention(
     unified: bool = False,     # per-row-class block geometry + AMLA
     group_size: int = DEFAULT_GROUP,   # decode-class DMA interleave depth
     amla=None,                 # None → ride with ``unified``
+    window=None,               # a query attends its last ``window``
+                               # positions only (None: all before it)
+    name=None,                 # the call's name in the HLO and the trace
+                               # (None: this function's)
 ) -> jnp.ndarray:
     T, num_q_heads, head_dim = q.shape
     _, page_size, num_kv_heads, _ = k_cache.shape
@@ -501,6 +524,8 @@ def ragged_paged_attention(
         v_dim = v_cache.shape[-1]
     S, max_pages = page_table.shape
     group = num_q_heads // num_kv_heads
+    if window and unified:
+        raise NotImplementedError("a window under the unified kernel")
 
     # MQA (MLA latent cache): squeeze the singleton head axis — Mosaic's
     # sublane tiling rejects slicing a size-1 second-minor dim.
@@ -558,7 +583,8 @@ def ragged_paged_attention(
         _kernel, page_size=page_size, pages_per_block=pages_per_block,
         scale=scale, num_kv_heads=num_kv_heads, group=group,
         head_dim=head_dim, v_dim=v_dim, q_blk=bq, shared_kv=shared_kv,
-        mqa=mqa, quant=quant, unified=unified, gsz=gsz, amla=amla)
+        mqa=mqa, quant=quant, unified=unified, gsz=gsz, amla=amla,
+        window=window)
 
     # decode-class blocks hold one buffer slot per in-group sequence;
     # the ragged path keeps using slots 0/1 of the same scratch
@@ -592,5 +618,6 @@ def ragged_paged_attention(
             dimension_semantics=("arbitrary",)) if interpret else
         pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
+        name=name,
     )(*inputs)
     return out[:T]

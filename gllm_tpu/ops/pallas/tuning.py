@@ -100,6 +100,16 @@ def get(kernel: str) -> dict:
     return out
 
 
+def _at(kernel: str, num_q_heads, num_kv_heads: int) -> dict:
+    """What a geometry's own entry lays over ``kernel``'s: one swept at
+    ``<kernel>@<query heads>x<kv heads>`` (128 x 8 is sixteen query heads
+    a KV head where the table's pair was swept at four). A geometry
+    without an entry keeps the table's pair."""
+    if not num_q_heads:
+        return {}
+    return get(f"{kernel}@{num_q_heads}x{num_kv_heads}")
+
+
 def ragged_blocks(num_q_heads: int, num_kv_heads: int) -> dict:
     """{"q_block", "kv_block"} of the ragged kernel at a geometry. With
     several KV heads the table's pair as swept (``ragged``). Under one KV
@@ -109,20 +119,26 @@ def ragged_blocks(num_q_heads: int, num_kv_heads: int) -> dict:
     there by Mosaic (128.29 MB of VMEM at 64 heads x 640 lanes;
     tests/test_tpu_compile.py pins it)."""
     if num_kv_heads != 1:
-        return get("ragged")
+        out = get("ragged")
+        out.update(_at("ragged", num_q_heads, num_kv_heads))
+        return out
     cfg = get("ragged_mqa")
     return {"q_block": max(8, int(cfg["q_rows"]) // num_q_heads // 8 * 8),
             "kv_block": int(cfg["kv_block"])}
 
 
-def decode_blocks(num_kv_heads: int, chosen: bool = False) -> dict:
+def decode_blocks(num_kv_heads: int, chosen: bool = False,
+                  num_q_heads: int = 0) -> dict:
     """{"kv_block", "group"} of the decode kernel: the ``decode`` entry,
     with what ``decode_mqa`` says laid over it under one KV head, and
     over that what ``decode_mqa_chosen`` says for the call that takes a
-    selection's mask (``chosen``)."""
+    selection's mask (``chosen``); under several KV heads a geometry's
+    own entry (``_at``)."""
     out = get("decode")
     if num_kv_heads == 1:
         out.update(get("decode_mqa"))
         if chosen:
             out.update(get("decode_mqa_chosen"))
+    else:
+        out.update(_at("decode", num_q_heads, num_kv_heads))
     return out

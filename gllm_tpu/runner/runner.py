@@ -421,6 +421,36 @@ def resolve_attn_impl(impl: str, cfg: ModelConfig, tp: int, pack: int,
         else:
             logger.info("[startup] latent attention: decode steps and "
                         "mixed steps -> xla (%s)", why)
+    if cfg.paged_windows:
+        # windowed GQA layers beside full ones, all in the paged pool:
+        # which kernel serves which kind of layer and step
+        from gllm_tpu.ops.attention import DECODE_ROWS_NAME, WINDOW_NAMES
+        from gllm_tpu.ops.pallas.tuning import decode_blocks, ragged_blocks
+        kinds = cfg.stage_layer_types
+        n_swa = kinds.count("sliding_attention")
+        if why is None:
+            geo = (cfg.num_heads, cfg.num_kv_heads)
+            dec = decode_blocks(geo[1], num_q_heads=geo[0])
+            rag = ragged_blocks(*geo)
+            logger.info(
+                "[startup] windowed GQA (%d query heads over %d KV heads "
+                "of %d; window %d in %d of %d layers, rotary there only): "
+                "windowed layers -> pallas %s (decode steps), %s (a mixed "
+                "step's chunks) and %s (its decoding rows): pages behind "
+                "the window are neither fetched nor scored; full layers "
+                "-> pallas paged_decode_attention, ragged_paged_attention "
+                "and %s; both kinds at the geometry's blocks: decode "
+                "kv_block %d, group %d; ragged q_block %d, kv_block %d",
+                *geo, cfg.head_dim, cfg.sliding_window, n_swa, len(kinds),
+                WINDOW_NAMES["decode"], WINDOW_NAMES["ragged"],
+                WINDOW_NAMES["rows"], DECODE_ROWS_NAME, dec["kv_block"],
+                int(dec.get("group", 1)), rag["q_block"], rag["kv_block"])
+        else:
+            logger.info(
+                "[startup] windowed GQA (window %d in %d of %d layers): "
+                "every layer and step -> xla under the window's mask, "
+                "whole page tables gathered (%s)", cfg.sliding_window,
+                n_swa, len(kinds), why)
     if why is None:
         return "pallas"
     if impl == "pallas":
@@ -751,8 +781,7 @@ class ModelRunner:
                 "routed experts a layer held here); Mamba-2 slot pool %d "
                 "slots x %d layers x %d bytes as the TPU stores them = %d "
                 "bytes; KV pool of the %d attention layers %d bytes",
-                sum(x.size * x.dtype.itemsize
-                    for x in jax.tree.leaves(self.params)),
+                self.weight_bytes(),
                 model_cfg.num_local_experts, model_cfg.num_experts, slots,
                 model_cfg.num_linear_layers,
                 self._ssm_pool_bytes() // (model_cfg.num_linear_layers
@@ -781,8 +810,7 @@ class ModelRunner:
                 "[startup] latent model: weights %d bytes (%d of %d routed "
                 "experts a layer held here); latent pool %d pages of %d "
                 "tokens x %d layers x %d stored lanes = %d bytes%s",
-                sum(x.size * x.dtype.itemsize
-                    for x in jax.tree.leaves(self.params)),
+                self.weight_bytes(),
                 model_cfg.num_local_experts, model_cfg.num_experts,
                 self.num_pages, config.cache.page_size,
                 model_cfg.num_stage_layers, model_cfg.mla_cache_width,
@@ -790,6 +818,15 @@ class ModelRunner:
                 "; prefix cache on: a request claims the cached whole "
                 "pages of its prompt and computes the rest"
                 if config.cache.enable_prefix_caching else "")
+        if self.model_def.startup_line is not None:
+            logger.info("[startup] %s", self.model_def.startup_line(
+                model_cfg, weight_bytes=self.weight_bytes(),
+                num_pages=self.num_pages,
+                page_bytes=self._kv_bytes_per_page(),
+                page_size=config.cache.page_size,
+                prefix_cache=config.cache.enable_prefix_caching,
+                attn_impl=self.attn_impl,
+                quantized=bool(config.quantization)))
         if model_cfg.use_swa:
             latent, index, rings = self.latent_pool_bytes()
             logger.info(
@@ -895,6 +932,10 @@ class ModelRunner:
                     "kv_cache_dtype='int8' on the pallas path needs "
                     "num_kv_heads % tp == 0 (the replicated-KV slice "
                     "path is gated); use attention_impl='xla'")
+
+    def weight_bytes(self) -> int:
+        return sum(x.size * x.dtype.itemsize
+                   for x in jax.tree.leaves(self.params))
 
     def _kv_dtype(self):
         kd = self.config.cache.kv_cache_dtype
@@ -1640,6 +1681,9 @@ class ModelRunner:
         if self.model_cfg.dense_mla:
             from gllm_tpu.models.deepseek import count_rows_read
             count_rows_read(self.model_cfg, host.attn.kv_lens, max_q == 1)
+        if self.model_def.count_rows_read is not None:
+            self.model_def.count_rows_read(self.model_cfg,
+                                           host.attn.kv_lens, max_q == 1)
         if self.model_cfg.use_dsa:
             from gllm_tpu.models.deepseek import count_rows_attended
             count_rows_attended(self.model_cfg, host.attn.cu_q_lens,

@@ -1095,3 +1095,124 @@ def test_nemotron_largest_mixed_step_compiles_for_v5e(topo, on_tpu,
                    MIXED_STEP_CALLS,
                    ["mamba2_chunk_scan", "mamba2_recurrent_step"],
                    1.25 * GiB)
+
+
+# ---- windowed GQA in pages at command-a-plus-05-2026's widths ---------------
+
+COHERE = "command-a-plus-05-2026"
+
+
+def cohere2_cfg() -> ModelConfig:
+    """perfbench/configs/command-a-plus-05-2026.json: published widths (128
+    query heads over 8 KV heads of 128, window 4096 in 3 of 4 layers, 16
+    held experts of 4096 x 4096 and four shared ones), one period of the
+    32 layers."""
+    from gllm_tpu.models.config import from_hf_config
+    return from_hf_config(_perfbench_hf(COHERE))
+
+
+def _cohere2_runner(topo, monkeypatch):
+    flags = _perfbench_hf(COHERE)["server_flags"]
+    val = lambda name: int(flags[flags.index(name) + 1])
+    runner = make_runner(
+        cohere2_cfg(), topo, num_pages=val("--num-pages"),
+        monkeypatch=monkeypatch, max_num_seqs=val("--max-num-seqs"),
+        max_model_len=val("--max-model-len"), attention_impl="auto")
+    assert runner.attn_impl == "pallas"
+    return runner
+
+
+@pytest.mark.parametrize("window", [4096, None], ids=["window", "full"])
+def test_gqa_128_by_8_kernels_compile_for_v5e(topo, on_tpu, window):
+    """Mosaic takes both kernels at the cell's geometry (128 query heads,
+    sixteen a KV head, over 8 KV heads of 128; 16 rows under a table of
+    1088 pages in a pool of 4 x 17280) with the blocks the table gives that
+    geometry, with the window and without, and the windowed calls carry
+    their own names."""
+    from gllm_tpu.ops import attention
+    from gllm_tpu.utils import tpu_compiler_options
+    q, kc, vc, cu, kv_lens, pt = _kernel_args(
+        topo, S=16, T=512, Hq=128, Hkv=8, D=128, pack=1, P=4 * 17280,
+        pages=1088)
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+    def call(max_q_len, rows):
+        fn = jax.jit(lambda q, k, v, cu, kl, pt: attention.paged_attention(
+            q, k, v, attention.AttentionMetadata(cu, kl, pt, kl[0]),
+            scale=128 ** -0.5, max_q_len=max_q_len, impl="pallas",
+            window=window), compiler_options=tpu_compiler_options())
+        qq = jax.ShapeDtypeStruct((rows,) + q.shape[1:], q.dtype,
+                                  sharding=one)
+        return attention_calls(fn.lower(qq, kc, vc, cu, kv_lens,
+                                        pt).compile())
+
+    names = attention.WINDOW_NAMES
+    assert call(1, 16) == ([names["decode"]] if window
+                           else ["paged_decode_attention"])
+    assert call(512, 512) == (
+        sorted([names["ragged"], names["rows"]]) if window
+        else MIXED_STEP_CALLS)
+
+
+def _cohere2_step(runner, batch, calls, temp_bound):
+    """Compile one step of the cell and hold it to: the full layer's and
+    the windowed layers' attention calls under their names, the weights
+    and the pool as the configuration derives them and as the start-up
+    line says, both within 1 % of the compiler's argument count, and no
+    copy of a layer's expert stack."""
+    c = compile_of(runner.step_async, batch)
+    text = c.compiled.as_text()
+    mem = c.compiled.memory_analysis()
+    print(f"\n[compile] {COHERE} {calls[0]}: {c.seconds:.1f}s, "
+          f"{mem.argument_size_in_bytes / GiB:.2f} GiB of arguments, "
+          f"{mem.temp_size_in_bytes / GiB:.3f} GiB temp, "
+          f"{mem.generated_code_size_in_bytes / 1e6:.1f} MB of code")
+    assert attention_calls(c.compiled) == sorted(calls)
+    assert _pallas_calls(c.compiled) == ["gmm"]     # the held experts
+    derived = _perfbench_hf(COHERE)["derived"]
+    assert runner.weight_bytes() == derived["weight_bytes"]
+    pool = runner.num_pages * runner._kv_bytes_per_page()
+    assert pool == derived["kv_pool_bytes"]
+    assert abs(mem.argument_size_in_bytes / (derived["weight_bytes"] + pool)
+               - 1) < 0.01
+    assert mem.temp_size_in_bytes < temp_bound, mem.temp_size_in_bytes
+    assert "bf16[16,4096,4096]{2,1,0:T(8,128)(2,1)} fusion(" not in text
+    assert not re.findall(r"= bf16\[4,[\d,]+\]\S* copy\(", text)
+    # no layer of a stacked leaf is cut out as an operation of its own
+    # (a fusion or a copy whose result is one layer's matrix: 134 MB for
+    # q or o, 537 MB for a shared matrix), as the dense cell's are not
+    moved = [ln.strip()[:140]
+             for _, lines, fused in _computations(text) if not fused
+             for ln in lines if re.search(
+                 r"= bf16\[(1,)?(4096|16384),(16384|4096|1024)\]\S* "
+                 r"(fusion|copy)\(", ln)]
+    assert not moved, moved
+
+
+def test_cohere2_decode_step_compiles_for_v5e(topo, on_tpu, monkeypatch):
+    """The cell's one decode program: 16 rows at contexts of 17088 tokens
+    (1068 pages: the 1088-page bucket), three windowed layers on
+    ``swa_paged_decode_attention`` and the full one on
+    ``paged_decode_attention``, 16 held experts a layer read in place;
+    9.47 GB of weights and 4.53 GB of pages. Counted from shapes by the
+    compiler; nothing runs."""
+    from gllm_tpu.ops.attention import WINDOW_NAMES
+    runner = _cohere2_runner(topo, monkeypatch)
+    _cohere2_step(runner, decode_batch(runner, 16, 1068),
+                  ["paged_decode_attention", WINDOW_NAMES["decode"]],
+                  0.5 * GiB)
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("tokens", [320, 2048])
+def test_cohere2_mixed_step_compiles_for_v5e(topo, on_tpu, monkeypatch,
+                                             tokens):
+    """The cell's mixed steps: a question of 320 tokens (the 512-slot
+    program, every request of the window) or a 2048-token chunk of the
+    fill at the end of a 17088-token context beside 15 decoding rows."""
+    from gllm_tpu.ops.attention import WINDOW_NAMES
+    runner = _cohere2_runner(topo, monkeypatch)
+    batch = prefill_batch(runner, tokens, ndecode=15, npages=1068,
+                          table_pages=1068)
+    _cohere2_step(runner, batch, MIXED_STEP_CALLS + [
+        WINDOW_NAMES["ragged"], WINDOW_NAMES["rows"]], 2.0 * GiB)
