@@ -319,15 +319,22 @@ class ModelConfig:
     def ssm_slot_shapes(self) -> Tuple[Tuple[int, ...], Tuple[int, ...]]:
         """What one slot of one recurrent layer stores, float32: (the
         convolution's window [taps - 1, channels], the recurrent state
-        [heads, rows, lanes])."""
+        [heads or groups of heads, rows, lanes]). A Mamba-2 state is a
+        head's own [head_dim, state]; the Gated-DeltaNet states lie g
+        heads abreast, [Nv / g, Dk, g x Dv] (``ops/gdn.pack_state``: g =
+        2 and 384 lanes at Olmo-Hybrid's 192, g = 1 at Qwen3-Next's
+        128), so that the lanes are whole 128-lane tiles and the TPU
+        stores the elements alone."""
         taps = self.linear_conv_kernel_dim - 1
         if self.use_mamba:
             return ((taps, self.gdn_conv_dim),
                     (self.mamba_num_heads, self.mamba_head_dim,
                      self.ssm_state_size))
+        from gllm_tpu.ops.gdn import gdn_heads_abreast
+        Nv, Dv = self.linear_num_value_heads, self.linear_value_head_dim
+        g = gdn_heads_abreast(Nv, Dv)
         return ((taps, self.gdn_conv_dim),
-                (self.linear_num_value_heads, self.linear_key_head_dim,
-                 self.linear_value_head_dim))
+                (Nv // g, self.linear_key_head_dim, g * Dv))
 
     @property
     def ssm_chunk(self) -> int:

@@ -43,8 +43,10 @@ from gllm_tpu.ops.attention import tp_sharded
 from gllm_tpu.ops.gdn import (causal_conv1d, chunk_gated_delta_rule_packed,
                               chunk_gated_delta_rule_pool,
                               gdn_chunk_slots, gdn_impl_for, l2norm,
-                              packed_chunks, packed_slot_of_token,
-                              recurrent_gated_delta_step, rms_norm_gated)
+                              pack_state, packed_chunks,
+                              packed_slot_of_token,
+                              recurrent_gated_delta_step, rms_norm_gated,
+                              unpack_state)
 from gllm_tpu.ops.rope import apply_rope
 from gllm_tpu.ops.quant import qmm
 
@@ -56,7 +58,9 @@ class HybridKV(NamedTuple):
     k: jnp.ndarray      # [La, num_pages, page_size, Hkv, D]
     v: jnp.ndarray
     conv: jnp.ndarray   # [Lg, num_slots, K-1, conv_dim] f32
-    rec: jnp.ndarray    # [Lg, num_slots, Nv, Dk, Dv] f32
+    # the states as ops/gdn.pack_state lays them: g heads abreast, so that
+    # a slot's lanes are whole 128-lane tiles (``cfg.ssm_slot_shapes``)
+    rec: jnp.ndarray    # [Lg, num_slots, Nv / g, Dk, g Dv] f32
 
 
 def period_pattern(cfg: ModelConfig) -> Tuple[str, ...]:
@@ -76,15 +80,12 @@ def init_kv_cache(cfg: ModelConfig, num_pages: int, page_size: int,
                   dtype=jnp.bfloat16, num_slots: int = 2) -> HybridKV:
     La, Lg = cfg.num_attn_layers, cfg.num_linear_layers
     kv_shape = (La, num_pages, page_size, cfg.kv_cache_heads, cfg.head_dim)
-    K = cfg.linear_conv_kernel_dim
+    conv, rec = cfg.ssm_slot_shapes
     return HybridKV(
         k=jnp.zeros(kv_shape, dtype),
         v=jnp.zeros(kv_shape, dtype),
-        conv=jnp.zeros((Lg, num_slots, K - 1, cfg.gdn_conv_dim),
-                       jnp.float32),
-        rec=jnp.zeros((Lg, num_slots, cfg.linear_num_value_heads,
-                       cfg.linear_key_head_dim, cfg.linear_value_head_dim),
-                      jnp.float32),
+        conv=jnp.zeros((Lg, num_slots) + conv, jnp.float32),
+        rec=jnp.zeros((Lg, num_slots) + rec, jnp.float32),
     )
 
 
@@ -301,9 +302,10 @@ def _gdn_recurrent_rows(mixed, g, beta, slots, conv_state, rec_state,
                 rec_state, slots,
                 interpret=jax.default_backend() == "cpu")
         elif impl == "xla":
+            n = rec_state.shape[-1] // vh.shape[-1]     # heads abreast
             core, new_r = recurrent_gated_delta_step(
-                qh, kh, vh, g, beta, rec_state[slots])
-            rec_state = rec_state.at[slots].set(new_r)
+                qh, kh, vh, g, beta, unpack_state(rec_state[slots], n))
+            rec_state = rec_state.at[slots].set(pack_state(new_r, n))
         else:
             raise ValueError(f"GDN impl {impl!r}: 'pallas' or 'xla'")
     return core, conv_state, rec_state
@@ -344,13 +346,15 @@ def _gdn_chunk_rows(mixed, g, beta, cu, slots, dummy, conv_state, rec_state,
             first, rec_state, interpret=jax.default_backend() == "cpu")
     elif impl == "xla":
         # chunks past the last row scan into a scratch row of the states
+        n = rec_state.shape[-1] // vh.shape[-1]         # heads abreast
+        states = unpack_state(rec_state[slots], n)
         states = jnp.concatenate(
-            [rec_state[slots], jnp.zeros((1,) + rec_state.shape[1:],
-                                         rec_state.dtype)], axis=0)
+            [states, jnp.zeros_like(states[:1])], axis=0)
         core, states = chunk_gated_delta_rule_packed(
             qh, kh, vh, g_s, beta_s, jnp.where(live, row, S), first, states)
         with jax.named_scope("gdn_chunk_scan"):
-            rec_state = rec_state.at[w_slots].set(states[:S])
+            rec_state = rec_state.at[w_slots].set(
+                pack_state(states[:S], n))
     else:
         raise ValueError(f"GDN impl {impl!r}: 'pallas' or 'xla'")
     slot_of_token, t_row = packed_slot_of_token(cu, ch_start, T, S, C)
@@ -363,9 +367,9 @@ def _gdn_layer(lp, x, batch: StepBatch, conv_state, rec_state,
 
     conv_state/rec_state: the slot pools of ALL this stage's GDN layers,
     layers and slots on one axis ([Lg * num_slots, K-1, conv_dim] /
-    [Lg * num_slots, Nv, Dk, Dv]: views of the stacked pools, as the paged
-    KV is addressed, so that no layer's pool is cut out of the scan's carry
-    and put back); this layer's slots begin at ``slot_base``, its dummy
+    [Lg * num_slots, Nv / g, Dk, g Dv]: views of the stacked pools, as the
+    paged KV is addressed, so that no layer's pool is cut out of the scan's
+    carry and put back); this layer's slots begin at ``slot_base``, its dummy
     slot first. Reads/writes go through batch.ssm_slots (HF
     Qwen3NextGatedDeltaNet / fla GatedDeltaNet math).
     """

@@ -21,6 +21,7 @@ Design notes:
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -28,6 +29,37 @@ import jax.numpy as jnp
 
 
 GDN_CHUNK = 64      # tokens in a chunk of the chunked rule
+
+
+def gdn_heads_abreast(heads: int, dv: int) -> int:
+    """How many heads' states lie side by side along the lanes of a slot
+    of the pool (``pack_state``): the fewest whose ``g x Dv`` lanes are a
+    whole number of the TPU's 128-lane tiles, so that the pool stores its
+    elements and no padding (2 at Olmo-Hybrid's 192, 1 at Qwen3-Next's
+    128); 1, the heads each alone, where the heads do not divide into
+    such groups."""
+    g = 128 // math.gcd(dv, 128)
+    return g if heads % g == 0 else 1
+
+
+def pack_state(state: jnp.ndarray, g: int) -> jnp.ndarray:
+    """[.., H, Dk, Dv] -> [.., H / g, Dk, g x Dv], as the slot pool
+    stores a state: head ``j g + i`` in lanes ``[i Dv, (i + 1) Dv)`` of
+    group ``j``. The one definition the XLA rule, the kernels
+    (ops/pallas/gdn_recurrent.py, gdn_scan.py) and the tests share."""
+    *lead, H, Dk, Dv = state.shape
+    n = len(lead)
+    return state.reshape(*lead, H // g, g, Dk, Dv).swapaxes(
+        n + 1, n + 2).reshape(*lead, H // g, Dk, g * Dv)
+
+
+def unpack_state(packed: jnp.ndarray, g: int) -> jnp.ndarray:
+    """``pack_state``'s inverse: [.., H / g, Dk, g x Dv] -> [.., H, Dk,
+    Dv]."""
+    *lead, G, Dk, lanes = packed.shape
+    n = len(lead)
+    return packed.reshape(*lead, G, Dk, g, lanes // g).swapaxes(
+        n + 1, n + 2).reshape(*lead, G * g, Dk, lanes // g)
 
 
 def gdn_chunk_slots(tokens_pad: int, seqs_pad: int,
@@ -353,16 +385,17 @@ def chunk_gated_delta_rule_pool(
     beta: jnp.ndarray,       # [N, C, H] (0 on padded tokens)
     slot: jnp.ndarray,       # [N] int32: the pool slot of each chunk's seq
     first: jnp.ndarray,      # [N] bool: the chunk is its sequence's first
-    pool: jnp.ndarray,       # [P, H, Dk, Dv] f32: every slot's state
+    pool: jnp.ndarray,       # [P, H / n, Dk, n Dv] f32: every slot's state
     *,
     interpret: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """``chunk_gated_delta_rule_packed`` with the inter-chunk scan in the
-    Pallas kernel (ops/pallas/gdn_scan.py), in place in the slot pool: a
-    sequence's state is read from ``pool[slot[n]]`` where it begins and
-    left there after its last chunk; no state is gathered out of the pool
-    or scattered back, and the slots no chunk names are not touched.
-    Chunks past the last sequence name a dummy slot and carry g = beta = 0.
+    Pallas kernel (ops/pallas/gdn_scan.py), in place in the slot pool
+    (states as ``pack_state`` lays them): a sequence's state is read from
+    ``pool[slot[n]]`` where it begins and left there after its last
+    chunk; no state is gathered out of the pool or scattered back, and
+    the slots no chunk names are not touched. Chunks past the last
+    sequence name a dummy slot and carry g = beta = 0.
 
     Returns (out [N, C, H, Dv] f32, pool).
     """

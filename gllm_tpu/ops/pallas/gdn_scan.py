@@ -48,8 +48,10 @@ def _kernel(slot_ref, first_ref, qg_ref, kdt_ref, v2_ref, kcd_ref, attn_ref,
             dl_ref, pool_ref, out_ref, new_ref, state):
     del slot_ref                        # used by the index maps alone
     n = pl.program_id(1)
+    g = qg_ref.shape[0]                 # heads abreast in the state
+    dv = state.shape[1] // g
 
-    # a head's first chunk without a sequence of its own (nothing
+    # a group's first chunk without a sequence of its own (nothing
     # prefills) starts from its slot's state too: whatever the scratch
     # held would otherwise end in that slot
     @pl.when((first_ref[n] != 0) | (n == 0))
@@ -59,12 +61,13 @@ def _kernel(slot_ref, first_ref, qg_ref, kdt_ref, v2_ref, kcd_ref, attn_ref,
     def dot(a, b):
         return jax.lax.dot(a, b, preferred_element_type=jnp.float32)
 
-    st = state[:]                                       # [Dk, Dv] f32
-    v_new = v2_ref[0, 0] - dot(kcd_ref[0, 0], st)       # [C, Dv]
-    out_ref[0, 0] = dot(qg_ref[0, 0], st) + dot(attn_ref[0, 0], v_new)
-    st = st * dl_ref[0, 0] + dot(kdt_ref[0, 0], v_new)
-    state[:] = st
-    new_ref[0, 0] = st
+    for i in range(g):
+        own = slice(i * dv, (i + 1) * dv)               # head i's lanes
+        st = state[:, own]                              # [Dk, Dv] f32
+        v_new = v2_ref[i, 0] - dot(kcd_ref[i, 0], st)   # [C, Dv]
+        out_ref[i, 0] = dot(qg_ref[i, 0], st) + dot(attn_ref[i, 0], v_new)
+        state[:, own] = st * dl_ref[i, 0] + dot(kdt_ref[i, 0], v_new)
+    new_ref[0, 0] = state[:]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",),
@@ -76,42 +79,49 @@ def gdn_chunk_scan(
     kcd: jnp.ndarray,     # [H, N, C, Dk] f32: Tmat @ (k_beta * e^g)
     attn: jnp.ndarray,    # [H, N, C, C]  f32: masked local scores
     dl: jnp.ndarray,      # [H, N, 1, Dv] f32: e^{g_C} over the lanes
-    pool: jnp.ndarray,    # [P, H, Dk, Dv] f32: every slot's state
+    pool: jnp.ndarray,    # [P, H / g, Dk, g Dv] f32: every slot's state
     slot: jnp.ndarray,    # [N] int32: the slot of each chunk's sequence
     first: jnp.ndarray,   # [N] bool / int: the chunk is its sequence's first
     *,
     interpret: bool = False,
 ):
     """Returns (out [H, N, C, Dv] f32, pool with the final state of every
-    sequence in its slot). The chunks of one sequence are consecutive;
-    chunks past the last sequence name a dummy slot and carry operands
-    that are the identity on the state (g = beta = 0)."""
+    sequence in its slot). The pool holds a state as
+    ``ops/gdn.pack_state`` lays it, ``g`` heads abreast (read from its
+    shape). The chunks of one sequence are consecutive; chunks past the
+    last sequence name a dummy slot and carry operands that are the
+    identity on the state (g = beta = 0)."""
     H, N, C, Dk = qg.shape
     Dv = v2.shape[-1]
+    P, G, _, lanes = pool.shape
+    g = lanes // Dv
+    if (G * g, pool.shape[2], g * Dv) != (H, Dk, lanes):
+        raise ValueError(f"a pool of {pool.shape} does not hold states "
+                         f"of {(H, Dk, Dv)} as pack_state lays them")
 
-    def blk(*tail):
-        return pl.BlockSpec((1, 1) + tail,
+    def blk(*tail):     # the g heads of a group, one chunk
+        return pl.BlockSpec((g, 1) + tail,
                             lambda h, n, slot, first: (h, n, 0, 0),
                             memory_space=pltpu.VMEM)
 
-    state = pl.BlockSpec((1, 1, Dk, Dv),
+    state = pl.BlockSpec((1, 1, Dk, lanes),
                          lambda h, n, slot, first: (slot[n], h, 0, 0),
                          memory_space=pltpu.VMEM)
     out, pool = pl.pallas_call(
         _kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(H, N),
+            num_scalar_prefetch=2, grid=(G, N),
             in_specs=[blk(C, Dk), blk(Dk, C), blk(C, Dv), blk(C, Dk),
                       blk(C, C), blk(1, Dv), state],
             out_specs=[blk(C, Dv), state],
-            scratch_shapes=[pltpu.VMEM((Dk, Dv), jnp.float32)]),
+            scratch_shapes=[pltpu.VMEM((Dk, lanes), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct((H, N, C, Dv), jnp.float32),
                    jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
         # operand 8 (after the two prefetched arrays) is the pool: output 1
         input_output_aliases={8: 1},
-        # the chunk axis is a scan over the VMEM-resident state; a head's
+        # the chunk axis is a scan over the VMEM-resident state; a group's
         # blocks of the pool are its own, but the axis stays sequential so
-        # that a slot's block is written back before another head's grid
+        # that a slot's block is written back before another group's grid
         # steps could be reordered around it
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),

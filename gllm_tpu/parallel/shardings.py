@@ -302,8 +302,9 @@ def hybrid_kv_specs(cfg: ModelConfig, tp: int):
     from gllm_tpu.models.hybrid import HybridKV
     kv_heads_ok = cfg.num_kv_heads % tp == 0
     kv_spec = P(None, None, None, _tp_if(kv_heads_ok), None)
-    # GDN states shard over the value-head axis when divisible.
-    vh_ok = cfg.linear_num_value_heads % tp == 0
+    # GDN states shard over the value-head axis when divisible: the
+    # axis of the groups of heads that lie abreast in a slot
+    vh_ok = cfg.ssm_slot_shapes[1][0] % tp == 0
     return HybridKV(
         k=kv_spec, v=kv_spec,
         conv=P(None, None, None, None),

@@ -765,14 +765,17 @@ class ModelRunner:
         self.swap_manager = None
         logger.info("KV cache: %d pages × %d tokens (%s)", self.num_pages,
                     config.cache.page_size, self._kv_dtype().__name__)
+        window, state = (model_cfg.ssm_slot_shapes if model_cfg.use_hybrid
+                         else ((), ()))
         logger.info(
             "[startup] pools: paged KV %d pages = %d bytes; GDN state %d "
-            "slots = %d bytes; beside them the largest mixed step's "
-            "chunked-rule temporaries, ~%d bytes", self.num_pages,
-            self.num_pages * self._kv_bytes_per_page(),
+            "slots = %d bytes (a layer's slot: window %s + state %s "
+            "float32, as the TPU stores them); beside them the largest "
+            "mixed step's chunked-rule temporaries, ~%d bytes",
+            self.num_pages, self.num_pages * self._kv_bytes_per_page(),
             1 + self.ssm_working_slots + self.ssm_snapshot_slots
             if model_cfg.use_hybrid else 0, self._ssm_pool_bytes(),
-            self._gdn_chunk_temp_bytes())
+            window, state, self._gdn_chunk_temp_bytes())
         if model_cfg.use_mamba:
             # a state-space hybrid: what the chip holds, in one line
             slots = 1 + self.ssm_working_slots + self.ssm_snapshot_slots
@@ -1028,11 +1031,14 @@ class ModelRunner:
         stage (this runner's whole model by default; what a slot stores:
         ``ModelConfig.ssm_slot_shapes``), as the TPU stores them: a
         float32 array lies in tiles of 8 x 128 over its last two
-        dimensions, so a GDN state of 96 x 192 takes 96 x 256 (a third
-        more than its elements; a Mamba-2 state of 64 x 128 its own
-        size); the convolution pool's last two dimensions are folded
-        into the slot axis's tile (measured with the TPU compiler:
-        tests/test_tpu_compile.py)."""
+        dimensions. The slot shapes are whole tiles wherever the heads
+        allow it, so the pool takes its elements' bytes: Olmo-Hybrid's
+        GDN states of 96 x 192 lie two heads abreast, 96 x 384 (a head
+        alone would take 96 x 256, a third more than its elements; it
+        does where the heads do not pair up), a Mamba-2 state of 64 x 128
+        is a tile's multiple as it is; the convolution pool's last two
+        dimensions are folded into the slot axis's tile (measured with
+        the TPU compiler: tests/test_tpu_compile.py)."""
         cfg = cfg or self.model_cfg
         if not cfg.use_hybrid:
             return 0

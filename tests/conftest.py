@@ -4,15 +4,36 @@ Multi-device TP/DP/EP/PP logic is tested on a virtual CPU mesh (the reference
 tests its distributed modes as multi-process single-host for the same reason —
 SURVEY.md §4). Must run before any test imports jax. Forced (env for child
 processes, config for this one) so a test run never takes the chip.
+
+One XLA compilation cache for the run (below): most of a run's time is
+XLA compiling the same tiny programs again, for every engine a test builds
+and in every worker.
 """
 
 import os
+import shutil
+import tempfile
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (
         flags + " --xla_force_host_platform_device_count=8").strip()
+
+# The cache is a directory made anew for the run (so every run starts cold
+# and none reads what another tree compiled), named in the environment
+# before jax is imported: jax reads the variable itself, and the xdist
+# workers and the processes tests start inherit it from the process that
+# made it, which removes it at the end. The key is the program and its
+# compile options, so a hit is the executable a compile would have given.
+# A directory the environment already names is used as it is. The floor
+# on compile seconds is zeroed as ``utils.enable_compilation_cache`` zeroes
+# it: the programs here are small.
+_OWN_XLA_CACHE = None
+if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    _OWN_XLA_CACHE = tempfile.mkdtemp(prefix="gllm_tests_xla_")
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = _OWN_XLA_CACHE
+os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
 
 import jax  # noqa: E402
 
@@ -34,6 +55,11 @@ def multi_device_cpu():
         f"topology tests need >= 4 forced host devices, got {n}: "
         "xla_force_host_platform_device_count was set too late")
     return jax.devices()[:4]
+
+
+def pytest_unconfigure(config):
+    if _OWN_XLA_CACHE is not None:
+        shutil.rmtree(_OWN_XLA_CACHE, ignore_errors=True)
 
 
 def pytest_configure(config):
@@ -115,7 +141,33 @@ _XFAIL = {
 }
 
 
+# The files that take longest, longest first (seconds of worker time in
+# the driver's run of six workers, which hands out a file at a time in the
+# order of collection: PR 45's 1156 s run read 757 for the first and 102
+# for the last). Collected ahead of the rest, in this order, so that no
+# worker is left alone with a long file at the end of the run (the 345 s
+# of test_tpu_compile.py were handed out ~900 s in, and five workers
+# idled behind it). Every other file, and the tests inside a file, keep
+# their order.
+_LONGEST_FIRST = (
+    "test_cohere2_moe.py", "perfbench/test_rehearsal.py",
+    "test_tpu_compile.py", "test_multihost_serving.py",
+    "test_nemotron_h.py", "perfbench/test_reference_cohere2_moe.py",
+    "test_dsa.py", "perfbench/test_rehearsal_olmo_hybrid.py",
+    "test_pallas_decode_attention.py", "test_hybrid_qwen3next.py",
+    "test_hybrid_olmo.py", "test_unified_step.py",
+    "perfbench/test_reference_nemotron_h.py", "test_spec_fused.py",
+    "perfbench/test_reference_olmo_hybrid.py", "test_pipeline_parallel.py",
+    "test_spec_decode.py", "test_moe_models.py",
+    "test_pallas_ragged_attention.py", "test_dp_serving.py",
+    "test_kv_quant.py",
+)
+
+
 def pytest_collection_modifyitems(config, items):
+    place = {name: i for i, name in enumerate(_LONGEST_FIRST)}
+    items.sort(key=lambda item: place.get(
+        item.nodeid.split("::")[0].removeprefix("tests/"), len(place)))
     for item in items:
         for tail, why in _XFAIL.items():
             if item.nodeid.endswith(tail):

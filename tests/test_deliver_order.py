@@ -419,6 +419,10 @@ def test_crash_with_outputs_pending_replays_exactly(rig):
     chunks = collect(eng.submit([5, 6], sp(7)))
     assert [c.token_id for c in chunks] == expected_tokens([5, 6], 7)
     assert chunks[-1].finish_reason == "length"
+    # the supervisor counts a recovery once the replay is under way, on
+    # its own thread: the scripted replay can finish first
+    wait_until(lambda: eng.supervisor.recoveries >= 1,
+               what="the recovery counted")
     assert eng.supervisor.recoveries == 1
     assert ("deliver", 3) not in llm.log     # died pending
 

@@ -161,16 +161,51 @@ def test_causal_conv1d_state_handoff():
                                rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("S,H,Dk,Dv", [(5, 3, 12, 24), (8, 2, 8, 16),
-                                       (3, 4, 128, 128)])
+# (S, H, Dk, Dv): heads each alone in a slot (24 and 16 lanes, too few
+# heads to fill a tile; 128 lanes, whole as they are; three heads of 192,
+# which do not pair up) and heads abreast (``gdn.gdn_heads_abreast``: two
+# of 192 at Olmo-Hybrid's widths, four of 32, sixteen of 24)
+STATE_SHAPES = [(5, 3, 12, 24), (8, 2, 8, 16), (3, 4, 128, 128),
+                (3, 4, 96, 192), (2, 3, 96, 192), (4, 8, 8, 32),
+                (3, 16, 8, 24)]
+ABREAST = {(3, 24): 1, (2, 16): 1, (4, 128): 1, (4, 192): 2, (3, 192): 1,
+           (8, 32): 4, (16, 24): 16}
+
+
+@pytest.mark.parametrize("S,H,Dk,Dv", STATE_SHAPES)
+def test_state_pack_round_trip(S, H, Dk, Dv):
+    """``pack_state`` lays g heads abreast along the lanes, head j g + i
+    in lanes [i Dv, (i + 1) Dv) of group j, g the fewest heads whose lanes
+    are whole 128-lane tiles (1 where the heads do not divide into such
+    groups); ``unpack_state`` is its inverse, under any leading axes."""
+    g = gdn.gdn_heads_abreast(H, Dv)
+    assert g == ABREAST[H, Dv]
+    assert g == 1 or (g * Dv) % 128 == 0 and ((g - 1) * Dv) % 128
+    state = rand(np.random.default_rng(8), S, 2, H, Dk, Dv)
+    packed = np.asarray(gdn.pack_state(jnp.asarray(state), g))
+    assert packed.shape == (S, 2, H // g, Dk, g * Dv)
+    for h in range(H):
+        np.testing.assert_array_equal(
+            packed[:, :, h // g, :, (h % g) * Dv:(h % g + 1) * Dv],
+            state[:, :, h])
+    np.testing.assert_array_equal(
+        np.asarray(gdn.unpack_state(jnp.asarray(packed), g)), state)
+    np.testing.assert_array_equal(
+        np.asarray(gdn.unpack_state(jnp.asarray(packed[0, 0]), g)),
+        state[0, 0])
+
+
+@pytest.mark.parametrize("S,H,Dk,Dv", STATE_SHAPES)
 def test_pallas_recurrent_step_matches_xla(S, H, Dk, Dv):
     """The in-place decode kernel (ops/pallas/gdn_recurrent.py, interpret
     mode on CPU) is numerically the XLA recurrent step between a gather
     and a scatter, at head dims that need not be equal or 128-aligned and
-    beta up to 2; slots no row names are left as they were."""
+    beta up to 2, over a pool that holds the states as ``pack_state`` lays
+    them; slots no row names are left as they were."""
     from gllm_tpu.ops.pallas.gdn_recurrent import gdn_recurrent_step
     rng = np.random.default_rng(5)
     P = 2 * S + 1
+    n = gdn.gdn_heads_abreast(H, Dv)
     q, k = rand(rng, S, H, Dk), rand(rng, S, H, Dk)
     v = rand(rng, S, H, Dv)
     g = -np.abs(rand(rng, S, H))
@@ -182,13 +217,36 @@ def test_pallas_recurrent_step_matches_xla(S, H, Dk, Dv):
     got, new_pool = gdn_recurrent_step(
         gdn.l2norm(jnp.asarray(q)) * Dk ** -0.5, gdn.l2norm(jnp.asarray(k)),
         jnp.asarray(v), jnp.asarray(g), jnp.asarray(beta),
-        jnp.asarray(pool), jnp.asarray(slots), interpret=True)
+        gdn.pack_state(jnp.asarray(pool), n), jnp.asarray(slots),
+        interpret=True)
+    assert new_pool.shape == (P, H // n, Dk, n * Dv)
+    new_pool = np.asarray(gdn.unpack_state(new_pool, n))
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(new_pool)[slots],
-                               np.asarray(ref_state), rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(new_pool[slots], np.asarray(ref_state),
+                               rtol=1e-5, atol=1e-5)
     rest = np.setdiff1d(np.arange(P), slots)
-    np.testing.assert_array_equal(np.asarray(new_pool)[rest], pool[rest])
+    np.testing.assert_array_equal(new_pool[rest], pool[rest])
+
+
+def test_pallas_kernels_refuse_a_pool_that_cannot_hold_the_heads():
+    """The kernels read how many heads lie abreast from the pool's shape:
+    a pool whose lanes are no whole number of heads' Dv, or whose groups
+    do not add up to the heads, is refused, not read askew."""
+    from gllm_tpu.ops.pallas.gdn_recurrent import gdn_recurrent_step
+    from gllm_tpu.ops.pallas.gdn_scan import gdn_chunk_scan
+    z = lambda *shape: jnp.zeros(shape, jnp.float32)
+    S, H, Dk, Dv, P, N, C = 2, 4, 8, 32, 3, 2, 4
+    for bad in ((P, 2, Dk, 80), (P, 1, Dk, 2 * Dv), (P, 2, 2 * Dk, 2 * Dv)):
+        with pytest.raises(ValueError, match="pack_state"):
+            gdn_recurrent_step(z(S, H, Dk), z(S, H, Dk), z(S, H, Dv),
+                               z(S, H), z(S, H), z(*bad),
+                               jnp.zeros(S, jnp.int32), interpret=True)
+        with pytest.raises(ValueError, match="pack_state"):
+            gdn_chunk_scan(z(H, N, C, Dk), z(H, N, Dk, C), z(H, N, C, Dv),
+                           z(H, N, C, Dk), z(H, N, C, C), z(H, N, 1, Dv),
+                           z(*bad), jnp.zeros(N, jnp.int32),
+                           jnp.zeros(N, jnp.int32), interpret=True)
 
 
 def test_pallas_recurrent_step_rows_on_the_dummy_slot_are_harmless():
@@ -200,7 +258,7 @@ def test_pallas_recurrent_step_rows_on_the_dummy_slot_are_harmless():
     q, k, v = rand(rng, S, H, Dk), rand(rng, S, H, Dk), rand(rng, S, H, Dv)
     g = -np.abs(rand(rng, S, H))
     beta = rng.uniform(0.0, 2.0, (S, H)).astype(np.float32)
-    pool = rand(rng, P, H, Dk, Dv)
+    pool = rand(rng, P, H, Dk, Dv)      # two heads of 16: each alone
     slots = np.array([0, 3, 0, 1, 0, 4], np.int32)
     args = [jnp.asarray(a) for a in (q, k, v, g, beta)]
     got, new_pool = gdn_recurrent_step(*args, jnp.asarray(pool),
@@ -239,16 +297,21 @@ def _packed_case(rng, lens, H, Dk, Dv, C, spare=2):
     return q, k, v, g, beta, row, first
 
 
-@pytest.mark.parametrize("lens,chunk", [([7], 4), ([64, 20, 33], 16),
-                                        ([100, 1, 31], 32)])
-def test_pallas_scan_matches_xla(lens, chunk):
+@pytest.mark.parametrize("lens,chunk,H,Dk,Dv", [
+    ([7], 4, 3, 12, 24), ([64, 20, 33], 16, 3, 12, 24),
+    ([100, 1, 31], 32, 3, 12, 24), ([64, 20, 33], 16, 4, 96, 192),
+    ([7], 4, 2, 128, 128), ([100, 1, 31], 32, 3, 96, 192),
+    ([64, 20, 33], 16, 8, 8, 32)])
+def test_pallas_scan_matches_xla(lens, chunk, H, Dk, Dv):
     """The fused VMEM-scan kernel (ops/pallas/gdn_scan.py, interpret mode
     on CPU), in place in the slot pool over the packed layout, is
     numerically the XLA chunk scan between a gather and a scatter, at head
-    dims that are neither equal nor 128-aligned and beta up to 2; slots no
+    dims that are neither equal nor 128-aligned and beta up to 2, with the
+    heads each alone in a slot or abreast (``STATE_SHAPES``); slots no
     chunk names are left as they were."""
     rng = np.random.default_rng(3)
-    H, Dk, Dv, P = 3, 12, 24, 9
+    P = 9
+    n = gdn.gdn_heads_abreast(H, Dv)
     q, k, v, g, beta, row, first = _packed_case(rng, lens, H, Dk, Dv, chunk)
     R = len(lens)
     pool = rand(rng, P, H, Dk, Dv)
@@ -261,17 +324,79 @@ def test_pallas_scan_matches_xla(lens, chunk):
     slot = np.where(row < R, slots[np.minimum(row, R - 1)], 0)
     got, new_pool = gdn.chunk_gated_delta_rule_pool(
         *args, jnp.asarray(slot, jnp.int32), jnp.asarray(first),
-        jnp.asarray(pool), interpret=True)
+        gdn.pack_state(jnp.asarray(pool), n), interpret=True)
+    assert new_pool.shape == (P, H // n, Dk, n * Dv)
+    new_pool = np.asarray(gdn.unpack_state(new_pool, n))
     live = row < R
     assert np.abs(np.asarray(ref)[live]).max() > 0.1
     np.testing.assert_allclose(np.asarray(got)[live], np.asarray(ref)[live],
                                rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(new_pool)[slots],
-                               np.asarray(ref_states)[:R],
+    np.testing.assert_allclose(new_pool[slots], np.asarray(ref_states)[:R],
                                rtol=1e-5, atol=1e-5)
     rest = np.setdiff1d(np.arange(1, P), slots)
-    np.testing.assert_array_equal(np.asarray(new_pool)[rest], pool[rest])
-    assert np.isfinite(np.asarray(new_pool)).all()
+    np.testing.assert_array_equal(new_pool[rest], pool[rest])
+    assert np.isfinite(new_pool).all()
+
+
+@pytest.mark.parametrize("H,Dk,Dv", [(2, 8, 8), (4, 96, 192), (2, 128, 128),
+                                     (3, 96, 192)])
+def test_pallas_chunks_then_recurrent_steps_through_one_pool(H, Dk, Dv):
+    """A prompt's chunks leave in its slot the state that its decode steps
+    read: the scan kernel and then the recurrent kernel, both in place in
+    ONE pool (heads alone or abreast), give what the float32 recurrent
+    rule gives token by token; the sequence beside it, which only
+    decodes, and the slots neither names are what they would be alone."""
+    from gllm_tpu.ops.pallas.gdn_recurrent import gdn_recurrent_step
+    rng = np.random.default_rng(9)
+    C, P, n_pre, n_dec = 8, 5, 13, 3
+    n = gdn.gdn_heads_abreast(H, Dv)
+    q, k, v, g, beta, row, first = _packed_case(rng, [n_pre], H, Dk, Dv, C,
+                                                spare=1)
+    pool = rand(rng, P, H, Dk, Dv)
+    own, other = 3, 1                   # the prompt's slot, a decoder's
+    dq, dk_ = rand(rng, n_dec, 2, H, Dk), rand(rng, n_dec, 2, H, Dk)
+    dv_ = rand(rng, n_dec, 2, H, Dv)
+    dg = -np.abs(rand(rng, n_dec, 2, H))
+    dbeta = rng.uniform(0.0, 2.0, (n_dec, 2, H)).astype(np.float32)
+
+    # the reference: one token at a time, states as [H, Dk, Dv]
+    flat = lambda a: a[:2].reshape((2 * C,) + a.shape[2:])[:n_pre]
+    st = {own: jnp.asarray(pool[own])[None],
+          other: jnp.asarray(pool[other])[None]}
+    want_pre = []
+    for t in range(n_pre):
+        o, st[own] = gdn.recurrent_gated_delta_step(
+            *(jnp.asarray(flat(a)[t][None]) for a in (q, k, v, g, beta)),
+            st[own])
+        want_pre.append(np.asarray(o[0]))
+    want_dec = np.zeros((n_dec, 2, H, Dv), np.float32)
+    for t in range(n_dec):
+        for r, s in enumerate((own, other)):
+            o, st[s] = gdn.recurrent_gated_delta_step(
+                *(jnp.asarray(a[t, r][None])
+                  for a in (dq, dk_, dv_, dg, dbeta)), st[s])
+            want_dec[t, r] = np.asarray(o[0])
+
+    packed = gdn.pack_state(jnp.asarray(pool), n)
+    slot = np.where(row < 1, own, 0).astype(np.int32)
+    got_pre, packed = gdn.chunk_gated_delta_rule_pool(
+        *(jnp.asarray(a) for a in (q, k, v, g, beta)), jnp.asarray(slot),
+        jnp.asarray(first), packed, interpret=True)
+    tol = dict(rtol=2e-4, atol=5e-5)
+    np.testing.assert_allclose(
+        np.asarray(got_pre[:2]).reshape(-1, H, Dv)[:n_pre],
+        np.stack(want_pre), **tol)
+    for t in range(n_dec):
+        got, packed = gdn_recurrent_step(
+            gdn.l2norm(jnp.asarray(dq[t])) * Dk ** -0.5,
+            gdn.l2norm(jnp.asarray(dk_[t])), jnp.asarray(dv_[t]),
+            jnp.asarray(dg[t]), jnp.asarray(dbeta[t]), packed,
+            jnp.asarray([own, other], jnp.int32), interpret=True)
+        np.testing.assert_allclose(np.asarray(got), want_dec[t], **tol)
+    after = np.asarray(gdn.unpack_state(packed, n))
+    for s in (own, other):
+        np.testing.assert_allclose(after[s], np.asarray(st[s][0]), **tol)
+    np.testing.assert_array_equal(after[[2, 4]], pool[[2, 4]])
 
 
 def test_pallas_scan_ragged_padding():

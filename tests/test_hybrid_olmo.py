@@ -27,14 +27,14 @@ TINY = dict(PUBLISHED, **PUBLISHED["rehearsal"]["model"])
 SEED = 2 ** 31 + 9
 
 
-def make_llm(attention_impl="auto", **sched):
+def make_llm(attention_impl="auto", model=TINY, **sched):
     from gllm_tpu.engine.llm import LLM
     return LLM(config=EngineConfig(
         load_format="dummy", dtype="float32", seed=SEED, max_model_len=256,
         max_num_seqs=8, attention_impl=attention_impl,
         scheduler=SchedulerConfig(max_decode_seqs=8, **sched),
         cache=CacheConfig(page_size=4, num_pages=256)),
-        model_cfg=from_hf_config(TINY))
+        model_cfg=from_hf_config(model))
 
 
 def test_config_reads_the_catalogs_keys():
@@ -93,17 +93,24 @@ def test_mixed_step_rows_equal_the_same_rows_run_apart():
             np.testing.assert_allclose(got[2], want[2], atol=5e-3)
 
 
-def test_pallas_kernels_serve_what_xla_serves():
+@pytest.mark.parametrize("value_head_dim,rec_slot", [
+    (24, (4, 12, 24)), (64, (2, 12, 128))], ids=["alone", "abreast"])
+def test_pallas_kernels_serve_what_xla_serves(value_head_dim, rec_slot):
     """``attention_impl="pallas"`` on the CPU (interpret mode): the paged
-    attention kernels with 4 KV heads and the in-place recurrent kernel,
-    in decode-only and in mixed steps, against the XLA paths."""
+    attention kernels with 4 KV heads and the two in-place GDN kernels,
+    in decode-only and in mixed steps, against the XLA paths, over a slot
+    pool whose states lie each head alone or two abreast
+    (``ops/gdn.pack_state``: the XLA rule unpacks what it gathers)."""
+    model = dict(TINY, linear_value_head_dim=value_head_dim)
+    assert from_hf_config(model).ssm_slot_shapes[1] == rec_slot
     rng = np.random.default_rng(5)
     prompts = [[int(t) for t in rng.integers(2, 500, n)] for n in (8, 30)]
     sp = SamplingParams(temperature=0.0, max_tokens=5, ignore_eos=True,
                         logprobs=3)
     got, want = ([(o.output_token_ids,
                    np.array([lps for _, _, lps in o.logprobs]))
-                  for o in make_llm(impl, max_prefill_tokens=16).generate(
+                  for o in make_llm(impl, model=model,
+                                    max_prefill_tokens=16).generate(
                       prompt_token_ids=prompts, sampling_params=[sp, sp])]
                  for impl in ("pallas", "xla"))
     for g, w in zip(got, want):
@@ -302,10 +309,18 @@ def test_slot_pool_is_sized_by_the_bytes_the_tpu_stores():
     cfg = from_hf_config(PUBLISHED)
     fake = type("R", (), dict(model_cfg=cfg, ssm_working_slots=32,
                               ssm_snapshot_slots=0))()
-    # 33 slots x 12 layers: 30 x 96 x 256 (192 lies in two tiles of 128
-    # lanes) and the 11520 x 3 window with the slot axis rounded up to 40
+    # 33 slots x 12 layers: 15 pairs of heads of 96 x 384 (192 lanes
+    # alone would lie in two tiles of 128: 30 x 96 x 256, 1234206720 B in
+    # all) and the 11520 x 3 window with the slot axis rounded up to 40
     assert ModelRunner._ssm_pool_bytes(fake) == \
-        12 * 4 * (33 * 30 * 96 * 256 + 40 * 11520 * 3) == 1234206720
+        12 * 4 * (33 * 30 * 96 * 192 + 40 * 11520 * 3) == 942243840
+    # heads that do not pair up lie each alone, padded as they were
+    odd = type("R", (), dict(model_cfg=dataclasses.replace(
+        cfg, linear_num_key_heads=29, linear_num_value_heads=29),
+        ssm_working_slots=32, ssm_snapshot_slots=0))()
+    assert odd.model_cfg.ssm_slot_shapes[1] == (29, 96, 192)
+    assert ModelRunner._ssm_pool_bytes(odd) == \
+        12 * 4 * (33 * 29 * 96 * 256 + 40 * 11136 * 3)
     dense = type("R", (), dict(model_cfg=dataclasses.replace(
         cfg, layer_types=()), ssm_working_slots=0, ssm_snapshot_slots=0))()
     assert ModelRunner._ssm_pool_bytes(dense) == 0

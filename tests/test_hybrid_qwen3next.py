@@ -205,12 +205,25 @@ def test_hybrid_dp2_matches_dp1(tmp_path):
     assert run(2) == run(1)
 
 
-def test_hybrid_tp2_matches_tp1(tmp_path):
+@pytest.mark.parametrize("value_head_dim,rec_slot,sharded", [
+    (8, (4, 8, 8), True),       # each head alone in a slot
+    (64, (2, 8, 128), True),    # two abreast: a group a shard
+    (32, (1, 8, 128), False),   # all four abreast: nothing to shard
+], ids=["alone", "two_abreast", "four_abreast"])
+def test_hybrid_tp2_matches_tp1(tmp_path, value_head_dim, rec_slot,
+                                sharded):
     """GDN stack under tensor parallelism (GSPMD hybrid_param_specs /
-    hybrid_kv_specs shard the attention and value-head axes) —
+    hybrid_kv_specs shard the attention axes and the slot pool's axis of
+    heads, or of groups of heads abreast, where tp divides it) —
     byte-identical to tp=1."""
     from gllm_tpu.config import ParallelConfig
-    make_ckpt(tmp_path)
+    from gllm_tpu.models.config import from_hf_config
+    from gllm_tpu.parallel.shardings import hybrid_kv_specs
+    make_ckpt(tmp_path, linear_value_head_dim=value_head_dim)
+    mcfg = from_hf_config(dict(BASE, linear_value_head_dim=value_head_dim,
+                               architectures=["Qwen3NextForCausalLM"]))
+    assert mcfg.ssm_slot_shapes[1] == rec_slot
+    assert (hybrid_kv_specs(mcfg, 2).rec[2] == "tp") == sharded
     want = [o.output_token_ids for o in make_llm(str(tmp_path)).generate(
         prompt_token_ids=[[5, 9, 23], [7, 12, 2, 44]],
         sampling_params=SamplingParams(temperature=0.0, max_tokens=8,

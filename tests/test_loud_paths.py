@@ -263,7 +263,8 @@ def test_gdn_pallas_never_falls_through_to_the_xla_scan(monkeypatch):
     mixed = jnp.zeros((T, conv_dim))
     g = beta = jnp.zeros((T, 2))
     conv_state = jnp.zeros((P, 3, conv_dim))
-    rec_state = jnp.zeros((P, 2, 96, 192))
+    rec_state = jnp.zeros((P, 1, 96, 384))     # the two heads abreast
+    assert cfg.ssm_slot_shapes[1] == rec_state.shape[1:]
     conv_w = jnp.zeros((conv_dim, 4))
     cu = jnp.asarray([0, 1, 16], jnp.int32)
     slots = jnp.asarray([1, 2], jnp.int32)

@@ -126,7 +126,8 @@ def test_configuration_file_holds_the_catalogs_row_key_by_key():
     olmo = from_hf_config(json.load(open(os.path.join(
         ROOT, "perfbench", "configs", "olmo-hybrid-7b.json"))))
     assert olmo.use_hybrid and not olmo.use_mamba and olmo.ssm_chunk == 64
-    assert olmo.ssm_slot_shapes == ((3, 11520), (30, 96, 192))
+    # two heads of 96 x 192 abreast: 384 lanes, three whole tiles
+    assert olmo.ssm_slot_shapes == ((3, 11520), (15, 96, 384))
 
 
 def test_derived_sizes_are_the_arithmetic_of_the_widths():

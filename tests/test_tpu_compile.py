@@ -618,13 +618,21 @@ def test_hybrid_steps_compile_for_v5e_with_attention_on_pallas(
         # 33 slots: what ModelRunner._ssm_pool_bytes says, within 1 %
         assert abs(state / runner._ssm_pool_bytes() - 1) < 0.01, (
             state, runner._ssm_pool_bytes(), slots)
+        # and that is the states' ELEMENTS, within 1 %, beside the window
+        # pool (whose slot axis lies in tiles of 8: 40): the states lie
+        # two heads abreast (96 x 384) and the TPU pads none of their
+        # lanes (a head alone, 96 x 192 in 96 x 256: 1.23 GB in all)
+        elements = 12 * 4 * (slots * 30 * 96 * 192 + 40 * 3 * 11520)
+        assert abs(state / elements - 1) < 0.01, (state, elements)
 
 
 def test_gdn_recurrent_kernel_compiles_for_v5e_at_96_by_192(topo, on_tpu):
     """The decode kernel of the GDN layers alone, at the cell's shapes: 32
-    rows of 30 heads of 96 x 192 in a pool of 12 x 33 slots. Mosaic takes
-    the unaligned head dims (a block's last two dims are the array's) and
-    the row-to-column conversion."""
+    rows of 30 heads of 96 x 192, two abreast, in a pool of 12 x 33 slots
+    of 15 x 96 x 384. Mosaic takes the row-to-column conversion, the
+    lane mask that spreads a head's column over its 192 of a group's 384
+    lanes, and the stores that lay two heads' rows side by side; the
+    pool among the arguments is its elements."""
     from gllm_tpu.ops.pallas.gdn_recurrent import gdn_recurrent_step
     from gllm_tpu.utils import tpu_compiler_options
     one = jax.sharding.SingleDeviceSharding(topo.devices[0])
@@ -634,17 +642,23 @@ def test_gdn_recurrent_kernel_compiles_for_v5e_at_96_by_192(topo, on_tpu):
     fn = jax.jit(gdn_recurrent_step.__wrapped__, donate_argnums=(5,),
                  compiler_options=tpu_compiler_options())
     compiled = fn.lower(sds((S, H, Dk)), sds((S, H, Dk)), sds((S, H, Dv)),
-                        sds((S, H)), sds((S, H)), sds((P, H, Dk, Dv)),
+                        sds((S, H)), sds((S, H)),
+                        sds((P, H // 2, Dk, 2 * Dv)),
                         sds((S,), jnp.int32)).compile()
     assert has_kernel(compiled)
     assert "gdn_recurrent_step" in compiled.as_text()
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        == P * H * Dk * Dv * 4
 
 
 def test_gdn_chunk_scan_kernel_compiles_for_v5e_at_96_by_192(topo, on_tpu):
     """The chunked rule's inter-chunk scan alone, at the cell's shapes:
     the 1024-token bucket's 32 chunks of 64 tokens, 30 heads of 96 x 192,
-    in place in a pool of 12 x 33 slots. Mosaic takes the unaligned head
-    dims in the blocks and in the three matrix products."""
+    two abreast, in place in a pool of 12 x 33 slots of 15 x 96 x 384.
+    Mosaic takes the unaligned head dims in the operands' blocks and in
+    the matrix products, and a head's 192 of the state's 384 lanes; the
+    benchmark's ``gdn_chunk`` pattern still finds the call by its [30,
+    chunks, 64, .] operands."""
     from gllm_tpu.ops.pallas.gdn_scan import gdn_chunk_scan
     from gllm_tpu.utils import tpu_compiler_options
     one = jax.sharding.SingleDeviceSharding(topo.devices[0])
@@ -656,10 +670,15 @@ def test_gdn_chunk_scan_kernel_compiles_for_v5e_at_96_by_192(topo, on_tpu):
     compiled = fn.lower(
         sds((H, N, C, Dk)), sds((H, N, Dk, C)), sds((H, N, C, Dv)),
         sds((H, N, C, Dk)), sds((H, N, C, C)), sds((H, N, 1, Dv)),
-        sds((P, H, Dk, Dv)), sds((N,), jnp.int32),
+        sds((P, H // 2, Dk, 2 * Dv)), sds((N,), jnp.int32),
         sds((N,), jnp.int32)).compile()
     assert has_kernel(compiled)
-    assert "gdn_chunk_scan" in compiled.as_text()
+    call, = [ln.strip().removeprefix("ROOT ")
+             for ln in compiled.as_text().splitlines()
+             if re.match(r"\s*(ROOT )?%gdn_chunk_scan[.\d]* = ", ln)]
+    kernels = _perfbench_hf("olmo-hybrid-7b")["trace_patterns"]["kernels"]
+    assert re.search(kernels["gdn_chunk"], call)
+    assert re.search(kernels["gdn_chunk_scan"], call)
 
 
 # ---- the latent-attention step at dots3-note-prev's widths ------------------
