@@ -320,7 +320,8 @@ def time_unified(q_block, kv_block, gsz, iters=8, kv_dtype="auto"):
         run, (q, *args) = build_unified(q_block, kv_block, gsz, mix=mix,
                                         kv_dtype=kv_dtype, shrink=shrink)
         from gllm_tpu.ops.pallas.ragged_attention import effective_q_block
-        bq = effective_q_block(q_block, kv_block, q.shape[1], q.shape[0])
+        bq = effective_q_block(q_block, kv_block, q.shape[1], q.shape[0],
+                               *args[0].shape[2:])
         print(f"EFFECTIVE unified:{bq}:{kv_block}:{gsz} mix={mix}",
               flush=True)
         total += _time_reps(run, q, iters, *args, reps=reps)
@@ -344,7 +345,8 @@ def time_ragged(q_block, kv_block, iters=12, kv_dtype="auto"):
     # the VMEM clamp can alias two requested configs to one program; name
     # the program actually compiled so the parent dedupes the ranking
     from gllm_tpu.ops.pallas.ragged_attention import effective_q_block
-    bq = effective_q_block(q_block, kv_block, q.shape[1], q.shape[0])
+    bq = effective_q_block(q_block, kv_block, q.shape[1], q.shape[0],
+                           *args[0].shape[2:])
     print(f"EFFECTIVE ragged:{bq}:{kv_block}", flush=True)
 
     old = _time_reps(run, q, iters, *args, reps=reps)
@@ -448,41 +450,64 @@ def time_decode(kv_block, gsz=1, iters=25, kv_dtype="auto"):
 # one geometry's own entries: a windowed GQA cell (--geometry)
 # ---------------------------------------------------------------------------
 
-def sweep_geometry(hq: int, hkv: int, window: int, rows: int = 16,
-                   layers: int = 6, D: int = 128, page: int = 16):
+def sweep_geometry(hq: int, hkv: int, window: int, rows: int = 0,
+                   layers: int = 6, D: int = 128, page: int = 16,
+                   ragged_only: bool = False, pairs=None):
     """Both kernels at ONE geometry in this one process (a refused config
-    raises and is reported; nothing here has hung the compiler): the
-    decode kernel over kv_block x group for ``rows`` rows at the document
-    cell's contexts (drawn 8.5-17 k under a table of 1088 pages), and a
-    mixed step as the dispatch serves it (``rows - 1`` riding rows on the
-    decode kernel at the blocks the decode sweep chose, a 320-token
-    question behind 12.6 k of cached document, or a 2048-token chunk
-    behind 8 k, on the ragged kernel over q_block x kv_block). The full
-    layer's calls are swept; a windowed layer's (``window``) are timed
-    once each at the winner, since they take the geometry's pair too.
-    Prints one line a config and, last, the two table entries
-    ``<kernel>@<hq>x<hkv>`` as JSON."""
+    raises and is reported; nothing here has hung the compiler). With a
+    ``window`` the geometry is the document cell's: the decode kernel over
+    kv_block x group for ``rows`` (16) rows at its contexts (drawn
+    8.5-17 k under a table of 1088 pages), and a mixed step as the
+    dispatch serves it (``rows - 1`` riding rows on the decode kernel at
+    the blocks the decode sweep chose, a 320-token question behind 12.6 k
+    of cached document, or a 2048-token chunk behind 8 k, on the ragged
+    kernel over q_block x kv_block). The full layer's calls are swept; a
+    windowed layer's are timed once each at the winner, since they take
+    the geometry's pair too. Without one (``window`` 0) it is a ``reason``
+    cell's: ``rows`` (32) rows mid-answer, and a mixed step of ``rows - 1``
+    of them beside a fresh prompt of 320 tokens (512 slots) or 512 (1024).
+    ``ragged_only`` leaves the decode sweep out: the riding rows run at
+    the table's blocks; ``pairs`` names the (q_block, kv_block) to time
+    in place of the product. At the winner the chunk's rows are held against
+    float32 arithmetic on the same inputs. Prints one line a config and,
+    last, the table entries ``<kernel>@<hq>x<hkv>`` as JSON."""
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from benchmarks.decode_attn_ablation import build_inputs
+    from benchmarks.decode_attn_ablation import (build_inputs,
+                                                 reason_contexts)
     from gllm_tpu.ops import attention
     from gllm_tpu.ops.pallas import tuning
     from gllm_tpu.ops.pallas.decode_attention import paged_decode_attention
     from gllm_tpu.utils import tpu_compiler_options
     interp = _interp()
+    rows = rows or (16 if window else 32)
     if interp:
-        rows, layers, D, window = 4, 1, 32, 64
+        rows, layers, D, window = 4, 1, 32, 64 if window else 0
     rng = np.random.default_rng(44)
-    lo, hi = (100, 300) if interp else (8500, 17000)
-    ctx = np.linspace(lo, hi, rows).astype(np.int32)
-    rng.shuffle(ctx)
-    pool = int(-(-ctx // page).sum()) + 2
-    table = 32 if interp else 1088
+    if window:
+        lo, hi = (100, 300) if interp else (8500, 17000)
+        ctx = np.linspace(lo, hi, rows).astype(np.int32)
+        rng.shuffle(ctx)
+    else:
+        ctx = reason_contexts(rng, rows) // (8 if interp else 1)
     dtype = jnp.float32 if interp else jnp.bfloat16
-    q, kc, vc, kl, pt = build_inputs(rng, hq, hkv, pool, rows, ctx, page, D,
-                                     dtype)
-    pt = jnp.pad(pt, ((0, 0), (0, max(0, table - pt.shape[1]))))[:, :table]
+    if window:
+        # the last row's pages hold the longest step sequence (12920)
+        pool = int(-(-ctx // page).sum()) + 2
+        q, kc, vc, kl, pt = build_inputs(rng, hq, hkv, pool, rows, ctx,
+                                         page, D, dtype)
+        table = 32 if interp else 1088
+        pt = jnp.pad(pt, ((0, 0), (0, max(0, table - pt.shape[1]))))[
+            :, :table]
+    else:
+        # ... a fresh prompt of up to 512 tokens
+        own = np.append(ctx[:rows - 1], 64 if interp else 512).astype(
+            np.int32)
+        pool = int(-(-own // page).sum()) + 2
+        q, kc, vc, kl, pt = build_inputs(rng, hq, hkv, pool, rows, own,
+                                         page, D, dtype)
+        kl = jnp.asarray(ctx)
     opts = None if interp else tpu_compiler_options()
 
     def timed(run, *args):
@@ -522,41 +547,89 @@ def sweep_geometry(hq: int, hkv: int, window: int, rows: int = 16,
         tag = "window" if win else "full"
         return attempt(f"decode {tag} kv={kb} group={gsz}", build)
 
-    for win in (None, window):
-        rows_read = int(np.minimum(ctx, win).sum() if win else ctx.sum())
-        floor = 2 * hkv * D * 2 * rows_read / HBM_BYTES_PER_S * 1e3
-        print(f"GEOMETRY decode {'window' if win else 'full'}: {rows} "
-              f"rows, {rows_read} rows of context to read = {floor:.4f} "
-              f"ms at 819 GB/s", flush=True)
-    res = {}
-    for kb, gsz in itertools.product(
-            (256,) if interp else (128, 256, 512, 1024),
-            (2,) if interp else (1, 2, 4, 8)):
-        ms = decode_call(kb, gsz, None)
-        if ms:
-            res[(kb, gsz)] = ms
-    kb, gsz = min(res, key=res.get) if res else (256, 4)
-    best = {"decode": {"kv_block": kb, "group": gsz}}
-    decode_call(kb, gsz, window)
-    # the mixed steps: the riding rows at the decode sweep's winner
-    tuning.decode_blocks = lambda *_, **__: best["decode"]
-    doc, question, chunk, behind = ((96, 24, 64, 64) if interp
-                                    else (12600, 320, 2048, 8192))
-    shapes = (("question", question, doc, 32 if interp else 512),
-              ("chunk", chunk, behind, chunk + rows))
+    best = {}
+    if not ragged_only:
+        for win in (None, window) if window else (None,):
+            rows_read = int(np.minimum(ctx, win).sum() if win
+                            else ctx.sum())
+            floor = 2 * hkv * D * 2 * rows_read / HBM_BYTES_PER_S * 1e3
+            print(f"GEOMETRY decode {'window' if win else 'full'}: {rows} "
+                  f"rows, {rows_read} rows of context to read = "
+                  f"{floor:.4f} ms at 819 GB/s", flush=True)
+        res = {}
+        for kb, gsz in itertools.product(
+                (256,) if interp else (128, 256, 512, 1024),
+                (2,) if interp else (1, 2, 4, 8)):
+            ms = decode_call(kb, gsz, None)
+            if ms:
+                res[(kb, gsz)] = ms
+        kb, gsz = min(res, key=res.get) if res else (256, 4)
+        best["decode"] = {"kv_block": kb, "group": gsz}
+        if window:
+            decode_call(kb, gsz, window)
+        # the mixed steps: the riding rows at the decode sweep's winner
+        tuning.decode_blocks = lambda *_, **__: best["decode"]
+    if window:
+        doc, question, chunk, behind = ((96, 24, 64, 64) if interp
+                                        else (12600, 320, 2048, 8192))
+        shapes = (("question", question, doc, 32 if interp else 512),
+                  ("chunk", chunk, behind, chunk + rows))
+    else:
+        shapes = ((("prompt40", 40, 0, 64), ("prompt64", 64, 0, 128))
+                  if interp else
+                  (("prompt320", 320, 0, 512), ("prompt512", 512, 0, 1024)))
+
+    def step_inputs(new, cached, tokens):
+        lens = np.append(ctx[:rows - 1], cached + new).astype(np.int32)
+        qq = jax.random.normal(jax.random.key(1), (tokens, hq, D), dtype)
+        md = attention.AttentionMetadata(
+            jnp.asarray(list(range(rows)) + [rows - 1 + new], jnp.int32),
+            jnp.asarray(lens), pt, jnp.asarray(rows, jnp.int32))
+        return qq, md
+
+    def check(qb, kb, win):
+        """The first shape's chunk on the ragged kernel against float32
+        arithmetic (XLA, highest precision) on its sequence's pages."""
+        _, new, cached, tokens = shapes[0]
+        qq, md = step_inputs(new, cached, tokens)
+        tuning.ragged_blocks = lambda *_, **__: {"q_block": qb,
+                                                 "kv_block": kb}
+        got = jax.jit(lambda q, kc, vc: attention._mixed_step_attention(
+            q, kc, vc, md, None, None, scale=D ** -0.5, interpret=interp,
+            v_dim=None, window=win), compiler_options=opts)(qq, kc, vc)
+        got = got[rows - 1:rows - 1 + new].astype(jnp.float32)
+        n = cached + new
+
+        @jax.jit
+        def want(q, kc, vc):
+            f32 = functools.partial(jnp.asarray, dtype=jnp.float32)
+            k = f32(kc[pt[rows - 1]]).reshape(-1, hkv, D)[:n]
+            v = f32(vc[pt[rows - 1]]).reshape(-1, hkv, D)[:n]
+            qs = f32(q[rows - 1:rows - 1 + new]).reshape(new, hkv, -1, D)
+            sc = jnp.einsum("thgd,khd->hgtk", qs, k,
+                            precision="highest") * D ** -0.5
+            pos = cached + jnp.arange(new)[:, None]
+            at = jnp.arange(n)[None, :]
+            vis = at <= pos
+            if win:
+                vis &= at > pos - win
+            pr = jax.nn.softmax(jnp.where(vis, sc, -jnp.inf), axis=-1)
+            return jnp.einsum("hgtk,khd->thgd", pr, v,
+                              precision="highest").reshape(new, hq, D)
+        ref = want(qq, kc, vc)
+        err = float(jnp.sqrt(jnp.mean((got - ref) ** 2)
+                             / jnp.mean(ref ** 2)))
+        print(f"GEOMETRY check {'window' if win else 'full'} "
+              f"{shapes[0][0]} q={qb} kv={kb}: rel_rms {err:.3e} against "
+              f"float32 arithmetic ({got.dtype.name} from "
+              f"{qq.dtype.name})", flush=True)
 
     def mixed_calls(qb, kb, win):
         tuning.ragged_blocks = lambda *_, **__: {"q_block": qb,
                                                  "kv_block": kb}
         total = 0.0
         for name, new, cached, tokens in shapes:
-            lens = np.append(ctx[:rows - 1], cached + new).astype(np.int32)
-            qq = jax.random.normal(jax.random.key(1), (tokens, hq, D),
-                                   dtype)
-            md = attention.AttentionMetadata(
-                jnp.asarray(list(range(rows)) + [rows - 1 + new],
-                            jnp.int32), jnp.asarray(lens), pt,
-                jnp.asarray(rows, jnp.int32))
+            qq, md = step_inputs(new, cached, tokens)
 
             def build(qq=qq, md=md):
                 @functools.partial(jax.jit, compiler_options=opts)
@@ -574,8 +647,8 @@ def sweep_geometry(hq: int, hkv: int, window: int, rows: int = 16,
         return total
 
     res = {}
-    for qb, kb in itertools.product(
-            (16,) if interp else (16, 32, 64, 128),
+    for qb, kb in pairs or itertools.product(
+            (16,) if interp else (16, 32, 64, 128, 256),
             (64,) if interp else (128, 256, 512)):
         total = mixed_calls(qb, kb, None)
         if total:
@@ -583,7 +656,10 @@ def sweep_geometry(hq: int, hkv: int, window: int, rows: int = 16,
     if res:
         qb, kb = min(res, key=res.get)
         best["ragged"] = {"q_block": qb, "kv_block": kb}
-        mixed_calls(qb, kb, window)
+        check(qb, kb, None)
+        if window:
+            mixed_calls(qb, kb, window)
+            check(qb, kb, window)
     print("GEOMETRY_BEST " + json.dumps(
         {f"{k}@{hq}x{hkv}": v for k, v in best.items()}), flush=True)
     return 0.0
@@ -680,8 +756,17 @@ def main():
     ap.add_argument("--geometry", default=None, metavar="HQxHKV[:WINDOW]",
                     help="sweep both kernels at one geometry of query x "
                          "kv heads in one child (sweep_geometry): the "
-                         "table's <kernel>@HQxHKV entries; the windowed "
-                         "calls (default 4096) are timed at the winners")
+                         "table's <kernel>@HQxHKV entries. With a window "
+                         "the document cell's contexts and mixed steps, "
+                         "the windowed calls timed at the winners; "
+                         "without, a reason cell's. --kernel ragged "
+                         "leaves the decode sweep out")
+    ap.add_argument("--pairs", default="", metavar="QxKV[,QxKV...]",
+                    help="the ragged pairs a --geometry run times (default: "
+                         "the product of 16-256 and 128-512)")
+    ap.add_argument("--rows", type=int, default=0,
+                    help="rows of a --geometry step (default 16 with a "
+                         "window, 32 without)")
     ap.add_argument("--kv-dtype", choices=("auto", "int8"), default="auto",
                     help="sweep the kernels against an int8 quantized "
                          "cache (kv_cache_dtype=int8 serving shape); "
@@ -708,7 +793,11 @@ def main():
                               kv_dtype=(parts[4] if len(parts) > 4
                                         else "auto"))
         elif parts[0] == "geometry":
-            ms = sweep_geometry(int(parts[1]), int(parts[2]), int(parts[3]))
+            ms = sweep_geometry(int(parts[1]), int(parts[2]), int(parts[3]),
+                                rows=int(parts[4]),
+                                ragged_only=parts[5] == "ragged",
+                                pairs=[tuple(map(int, p.split("x")))
+                                       for p in parts[6].split(",") if p])
         elif parts[0] == "vmem":
             vmem_probe_one(int(parts[1]), int(parts[2]))
             print("RESULT 0.0", flush=True)
@@ -781,9 +870,10 @@ def main():
         hq, hkv = heads.split("x")
         global CONFIG_TIMEOUT_S
         CONFIG_TIMEOUT_S = 3000             # one child holds the sweep
-        _, out = run_inner(f"geometry:{hq}:{hkv}:{window or 4096}")
+        _, out = run_inner(f"geometry:{hq}:{hkv}:{window or 0}:"
+                           f"{args.rows}:{args.kernel}:{args.pairs}")
         log_path = os.path.join(REPO, "chiprun_out",
-                                "kernel_tune_geometry.log")
+                                f"kernel_tune_geometry_{hq}x{hkv}.log")
         os.makedirs(os.path.dirname(log_path), exist_ok=True)
         with open(log_path, "w") as f:
             f.write(out)

@@ -121,17 +121,60 @@ def make_fetch_fns(pt_ref, k_hbm, v_hbm, k_buf, v_buf, sems,
     return start_fetch, wait_fetch
 
 
+def heads_a_load(num_kv_heads: int, kv_dtype) -> int:
+    """KV heads one load of ``head_rows`` brings: a 32-bit word of a page
+    as the pool stores it ([page * Hkv, D], row = token * Hkv + head)
+    holds the same lane of ``4 // itemsize`` consecutive rows, so two
+    heads of a token where the cache is 16-bit and its heads pair up;
+    else one."""
+    return 2 if (jnp.dtype(kv_dtype).itemsize == 2
+                 and num_kv_heads % 2 == 0) else 1
+
+
+def head_rows(buf, s_buf, slot, g, bk: int, num_kv_heads: int, dim: int):
+    """The rows of KV heads ``n * g .. n * g + n - 1`` (``g`` traced or
+    static, ``n`` = ``heads_a_load``) out of the current VMEM block, each
+    [BK, dim] in the cache's dtype as stored: never widened, never
+    transposed. The block lies as the DMA left it, [ppb, page * Hkv, dim]
+    with the heads folded into the rows, so a head is every Hkv-th row: a
+    sublane-strided load. Mosaic strides 32-bit words only, and a word of
+    a 16-bit block packs rows 2i (low half) and 2i + 1, which are two
+    heads of one token: the words of a head PAIR are loaded at a stride of
+    Hkv / 2 and each half is cut out by an integer truncation (a pack, no
+    float32 on the way). A float32 block strides its own rows. int8 blocks
+    (``s_buf``: the pages' [ppb, Hkv] scale rows; dequantized here, float32
+    out) and 16-bit blocks under an odd head count stride their narrow rows,
+    which only the interpreter does (the chip path refuses int8 caches
+    before it gets here)."""
+    dtype = buf.dtype
+    n = heads_a_load(num_kv_heads, dtype)
+    page = buf.shape[2] // num_kv_heads
+    if n == 2:
+        words = buf.bitcast(jnp.uint32)[
+            slot, :, pl.ds(g, page, stride=num_kv_heads // 2), :]
+        words = words.reshape(bk, dim)
+        return [jax.lax.bitcast_convert_type(
+            (words >> (16 * b)).astype(jnp.uint16), dtype) for b in (0, 1)]
+    x = buf[slot, :, pl.ds(g, page, stride=num_kv_heads), :]
+    if s_buf is not None:
+        x = x.astype(jnp.float32) * s_buf[slot, :, pl.ds(g, 1)][:, :, None]
+    return [x.reshape(bk, dim)]
+
+
 def block_kv(k_buf, v_buf, slot, bk: int, num_kv_heads: int,
              head_dim: int, v_dim: int, shared_kv: bool,
              mqa: bool = False, ks_buf=None, vs_buf=None):
-    """The current VMEM block as ([BK, Hkv, D] keys, [BK, Hkv, Dv] values);
-    shared-kv mode slices values from the key block (latent prefix).
-    ``mqa`` mode (Hkv == 1, 3-D cache without the singleton head axis —
-    Mosaic's sublane tiling rejects slicing a size-1 second-minor dim)
-    returns 2-D [BK, D] / [BK, Dv]. int8 blocks (ks_buf/vs_buf present)
-    come back dequantized to f32: each page's [ppb, Hkv] scale row
-    broadcasts over its page_size × head_dim slab — a VPU multiply on
-    data already resident in VMEM, in the shadow of the block's MXU dots.
+    """The current VMEM block, whole, as ([BK, Hkv, D] keys, [BK, Hkv, Dv]
+    values) out of the row-folded pages; shared-kv mode slices values
+    from the key block (latent prefix). ``mqa`` mode (Hkv == 1, pages
+    without the singleton head axis — Mosaic's sublane tiling rejects
+    slicing a size-1 second-minor dim) returns 2-D [BK, D] / [BK, Dv].
+    int8 blocks (ks_buf/vs_buf present) come back dequantized to f32:
+    each page's [ppb, Hkv] scale row broadcasts over its page_size x
+    head_dim slab — a VPU multiply on data already resident in VMEM, in
+    the shadow of the block's MXU dots. The ragged body under several KV
+    heads takes ``head_rows`` a load at a time instead; this is the
+    unified kernel's decode class's view, and the one KV head's.
     """
     quant = ks_buf is not None
     if mqa:
@@ -139,17 +182,17 @@ def block_kv(k_buf, v_buf, slot, bk: int, num_kv_heads: int,
         k = k_buf[slot].reshape(bk, head_dim)
         v = k[:, :v_dim] if shared_kv else v_buf[slot].reshape(bk, v_dim)
         return k, v
-    kb = k_buf[slot]                           # [ppb, page, Hkv, D]
-    if quant:
-        kb = kb.astype(jnp.float32) * ks_buf[slot][:, None, :, None]
-    k = kb.reshape(bk, num_kv_heads, head_dim)
-    if shared_kv:
-        v = k[..., :v_dim]
-    else:
-        vb = v_buf[slot]
+
+    def whole(buf, s_buf, dim):
+        x = buf[slot]                          # [ppb, page * Hkv, dim]
         if quant:
-            vb = vb.astype(jnp.float32) * vs_buf[slot][:, None, :, None]
-        v = vb.reshape(bk, num_kv_heads, v_dim)
+            s = s_buf[slot]                    # [ppb, Hkv]
+            per_row = jnp.tile(s, (1, x.shape[1] // s.shape[1]))
+            x = x.astype(jnp.float32) * per_row[..., None]
+        return x.reshape(bk, num_kv_heads, dim)
+
+    k = whole(k_buf, ks_buf, head_dim)
+    v = k[..., :v_dim] if shared_kv else whole(v_buf, vs_buf, v_dim)
     return k, v
 
 
@@ -263,11 +306,11 @@ def kv_stream_specs(k_cache, v_cache, pages_per_block: int, slots: int = 2,
                     k_scale=None, v_scale=None):
     """(in_specs_tail, scratch_shapes, inputs_tail) for the KV streams.
 
-    The caches arrive as their kernel reads a page — [P, page, Hkv, D],
-    [P, page, D] with the singleton head axis squeezed (MQA), or
-    [P, page * Hkv, D] with the heads folded into the rows (the decode
-    kernels) — and a block's scratch is ``pages_per_block`` such pages
-    per slot. Appends the v stream only when a distinct v cache exists;
+    The caches arrive as their kernel reads a page — [P, page * Hkv, D]
+    with the heads folded into the rows, or [P, page, D] with the
+    singleton head axis squeezed (MQA) — and a block's scratch is
+    ``pages_per_block`` such pages per slot. Appends the v stream only
+    when a distinct v cache exists;
     the DMA semaphore array always comes last in scratch. ``slots`` is
     the buffer-slot count: 2 for the double-buffer kernels, two per
     sequence of a group for the decode kernel.

@@ -38,10 +38,27 @@ Design (TPU-first):
   (same discipline as decode_attention.py); the kv-block loop bound is the
   causal limit of this q block within that sequence, so HBM traffic is the
   actual context, not the padded page-table width.
-- GQA layout: the q block is reshaped to [Hkv, BQ*G, D] so scores are one
-  kv-head-batched MXU dot per kv block; rows outside the current sequence
-  are masked with -inf and contribute nothing to their online softmax state
-  (m/l/acc carried across the sequence loop).
+- GQA layout: the pool reaches the kernel as [P, page * Hkv, D], the heads
+  folded into the rows (the pool's own order: no data moves; the decode
+  kernel's view), so a fetched block is [BK * Hkv, D] and KV head h's keys
+  every Hkv-th row of it: a sublane-strided load, of a head PAIR's 32-bit
+  words where the cache is 16-bit (``paged_kv.head_rows``: each half cut
+  out by an integer truncation). A block is never widened to float32 and
+  never transposed. The q block is re-laid once to [Hkv, BQ*G, D] in VMEM
+  scratch, and a ROLLED loop over the loads makes two plain 2-D products a
+  head and kv block ([BQ*G, D] x [BK, D]^T, then p x [BK, Dv]) against
+  per-head flash state in VMEM scratch (m/l/acc, carried across the
+  sequence loop), so the live score tile is one KV head's ([BQ*G, BK]).
+  q, K and V enter the MXU as ``paged_kv.mxu_operand`` says: a 16-bit
+  cache under a q of its dtype as stored, the float32 scores taking the
+  scale and p going into the value product as its rounding plus the
+  rounding's remainder (nothing lost against float32 operands); a float32
+  cache, int8 blocks dequantized in VMEM or a q of another dtype in
+  float32. Rows outside the current sequence are masked with -inf (the
+  mask is one [BQ*G, BK] a kv block, shared by the heads) and contribute
+  nothing to their state. Under ONE KV head (the latent cache) a block is
+  one product of every query head's rows and the state is the loops'
+  carry (PR 37).
 - Values may have a different head dim than keys (Dv != D) to serve the MLA
   absorbed path, where v is the latent prefix of k.
 - A window (``window``, static; the legacy path only): a query at
@@ -63,7 +80,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from gllm_tpu.ops.pallas.paged_kv import (block_kv, kv_stream_specs,
+from gllm_tpu.ops.pallas.paged_kv import (block_kv, head_rows,
+                                          heads_a_load, kv_stream_specs,
                                           make_fetch_fns, mxu_operand,
                                           unpack_refs)
 
@@ -89,6 +107,34 @@ def _announce_mqa(operand: str, heads: int, lanes: int, v_dim: int,
         heads, lanes, v_dim, operand, bq, bq * heads, bk)
 
 
+@functools.lru_cache(maxsize=None)
+def _announce_heads(operand: str, p_parts: int, heads: int, kv_heads: int,
+                    bq: int, bk: int) -> None:
+    """Once per process and form, as the first program that holds the
+    kernel under several KV heads is traced."""
+    logger.info(
+        "[startup] ragged_paged_attention under %d KV heads: %d query "
+        "heads each, a KV head at a time; q and a head's K and V enter the "
+        "MXU as %s, %s; q blocks of %d tokens = %d rows a head, kv blocks "
+        "of %d tokens", kv_heads, heads // kv_heads, operand,
+        "p in two parts" if p_parts == 2 else "float32 operands", bq,
+        bq * (heads // kv_heads), bk)
+
+
+def block_form(q_dtype, kv_dtype, quant: bool = False):
+    """(operand dtype's name, parts p enters the value product in): the
+    form the ragged body under several KV heads takes, chosen from what
+    it sees of a call (``mxu_operand``) and from nothing else."""
+    operand = mxu_operand(jnp.dtype(q_dtype), jnp.dtype(kv_dtype), quant)
+    return operand.name, _p_parts(operand)
+
+
+def _p_parts(operand) -> int:
+    """p goes into a 16-bit value product as its rounding plus the
+    rounding's remainder; into a float32 one as it is."""
+    return 2 if operand.itemsize == 2 else 1
+
+
 def _rescale_add(x, dm_i):
     """``x * 2^dm_i`` (``dm_i`` <= 0, int32, shape broadcastable to x)
     via an integer ADD on the f32 exponent field — AMLA's mul-by-add.
@@ -106,9 +152,11 @@ def _rescale_add(x, dm_i):
                      jnp.where(ex + dm_i > 0, y, 0.0))
 
 
-def _online_update(scores, vt, m, l, acc, kv_axis: int, mqa: bool,
-                   amla: bool):
-    """One kv-block online-softmax update over pre-masked ``scores``.
+def _online_update(scores, vt, m, l, acc, kv_axis: int, one_head: bool,
+                   amla: bool, p_parts: int = 1):
+    """One kv-block online-softmax update over pre-masked ``scores``:
+    [R, BK] against one KV head's [BK, Dv] values (``one_head``), or
+    [Hkv, R, BK] against [Hkv, BK, Dv] in one head-batched product.
 
     Classic mode is the exact math both legacy kernels use (exp-domain
     max, VPU multiply rescale). AMLA mode expects ``scores`` in the
@@ -117,7 +165,14 @@ def _online_update(scores, vt, m, l, acc, kv_axis: int, mqa: bool,
     of two, applied to l/acc by ``_rescale_add`` — the block's only
     rescale multiplies become integer adds. Rows with nothing visible
     yet keep m == -inf; the 0.0 stand-in keeps their p/alpha at exactly
-    0 (no nan from -inf - -inf)."""
+    0 (no nan from -inf - -inf).
+
+    ``p_parts`` (one head's 16-bit values): 2 sends p into the value
+    product as its rounding to the values' dtype plus the rounding's
+    remainder, stacked along the rows of ONE product
+    (``paged_kv.attend_block``'s form: nothing is lost against float32
+    operands); 1 takes p rounded once (the latent cache, PR 37) or, with
+    float32 values, as it is."""
     m_blk = jnp.max(scores, axis=kv_axis, keepdims=True)
     if amla:
         m_blk = jnp.ceil(m_blk)
@@ -134,14 +189,19 @@ def _online_update(scores, vt, m, l, acc, kv_axis: int, mqa: bool,
         alpha = jnp.exp(m - safe_m)
         p = jnp.exp(scores - safe_m)
         l_new = l * alpha + jnp.sum(p, axis=kv_axis, keepdims=True)
-    if mqa:
-        # a 16-bit value block (the latent cache as stored) takes p in
-        # its own dtype: one MXU pass, float32 accumulation
+    if one_head and p_parts == 2:
+        hi = p.astype(vt.dtype)
+        lo = (p - hi.astype(jnp.float32)).astype(vt.dtype)
+        pv = jax.lax.dot_general(                   # [2 R, Dv]
+            jnp.concatenate([hi, lo], axis=0), vt,
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        pv = pv[:p.shape[0]] + pv[p.shape[0]:]
+    elif one_head:
         pv = jax.lax.dot_general(                   # [R, Dv]
             p.astype(vt.dtype), vt, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
     else:
-        pv = jax.lax.dot_general(                   # [H?, R, Dv]
+        pv = jax.lax.dot_general(                   # [Hkv, R, Dv]
             p, vt, (((kv_axis,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
     if amla:
@@ -176,15 +236,22 @@ def vmem_tile_limit_b() -> float:
 
 
 def effective_q_block(q_block: int, kv_block: int, num_q_heads: int,
-                      T: int) -> int:
+                      T: int, num_kv_heads: int = 1, v_dim: int = 0) -> int:
     """The q block actually compiled: the requested block (tests use small
-    ones to force blocks that span sequences), scaled down while the f32
-    score tile would crowd VMEM next to the double-buffered KV blocks.
-    Exposed so the block-size sweep can tell when two requested configs
-    alias the same program."""
+    ones to force blocks that span sequences), halved while the float32
+    tiles that live through a kv block would crowd VMEM next to the
+    double-buffered KV blocks. Under several KV heads the body attends a
+    KV head at a time, so what is live is ONE head's score tile
+    ([BQ * G, BK]) beside the accumulators of all of them
+    ([Hkv, BQ * G, Dv]); under one KV head the tile is every query head's
+    and the table's ``q_rows`` already bounds the rows. Exposed so the
+    block-size sweep can tell when two requested configs alias the same
+    program."""
     limit_b = vmem_tile_limit_b()
+    group = num_q_heads // num_kv_heads
+    acc = num_q_heads * v_dim if num_kv_heads > 1 else 0
     bq = min(q_block, T)
-    while num_q_heads * bq * kv_block * 4 > limit_b and bq > 16:
+    while (group * kv_block + acc) * bq * 4 > limit_b and bq > 16:
         bq //= 2
     return bq
 
@@ -196,6 +263,11 @@ def _kernel(cu_ref, kv_lens_ref, pt_ref, first_ref, last_ref,
             num_kv_heads: int, group: int, head_dim: int, v_dim: int,
             q_blk: int, shared_kv: bool, mqa: bool, quant: bool,
             unified: bool, gsz: int, amla: bool, window=None):
+    heads_scr = None
+    if not mqa:
+        # the per-head q rows and flash state of the ragged body (below)
+        *refs, q_scr, m_scr, l_scr, acc_scr = refs
+        heads_scr = (q_scr, m_scr, l_scr, acc_scr)
     (q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf,
      vs_buf, sems) = unpack_refs(refs, shared_kv, quant)
     b = pl.program_id(0)
@@ -204,7 +276,6 @@ def _kernel(cu_ref, kv_lens_ref, pt_ref, first_ref, last_ref,
     s1 = last_ref[b]
     bk = pages_per_block * page_size
     rows = q_blk * group
-    kv_axis = 1 if mqa else 2
     eff_scale = scale * (LOG2E if amla else 1.0)
 
     start_fetch, wait_fetch = make_fetch_fns(
@@ -213,22 +284,26 @@ def _kernel(cu_ref, kv_lens_ref, pt_ref, first_ref, last_ref,
         vs_buf=vs_buf)
 
     def _ragged_body():
-        if mqa:
-            # one KV head under every query head (the latent cache): a
-            # block is rows x lanes of MXU work, so q and the cache enter
-            # as stored where they are 16-bit (``mxu_operand``) and the
-            # float32 scores take the scale
+        operand = mxu_operand(q_ref.dtype, k_buf.dtype, quant)
+        if mqa or operand.itemsize == 2:
+            # q and the cache enter the MXU as stored where they are
+            # 16-bit (``mxu_operand``; under one KV head, the latent
+            # cache, whatever they are) and the float32 scores take the
+            # scale
             q_raw, score_scale = q_ref[...], eff_scale
         else:
+            # float32 operands (a float32 cache, int8 blocks dequantized
+            # in VMEM, a q of another dtype): q takes the scale
             q_raw = q_ref[...].astype(jnp.float32) * eff_scale  # [BQ, Hq, D]
             score_scale = None
         _ragged_block(q_raw, cu_ref, kv_lens_ref, o_ref, start_fetch,
                       wait_fetch, k_buf, v_buf, ks_buf, vs_buf,
                       t_start=t_start, s0=s0, s1=s1, bk=bk, rows=rows,
-                      kv_axis=kv_axis, num_kv_heads=num_kv_heads,
-                      group=group, head_dim=head_dim, v_dim=v_dim,
-                      q_blk=q_blk, shared_kv=shared_kv, mqa=mqa,
-                      amla=amla, score_scale=score_scale, window=window)
+                      num_kv_heads=num_kv_heads, group=group,
+                      head_dim=head_dim, v_dim=v_dim, q_blk=q_blk,
+                      shared_kv=shared_kv, mqa=mqa, amla=amla,
+                      operand=operand, score_scale=score_scale,
+                      window=window, heads_scr=heads_scr)
 
     if not unified:
         _ragged_body()
@@ -255,33 +330,53 @@ def _kernel(cu_ref, kv_lens_ref, pt_ref, first_ref, last_ref,
 
 def _ragged_block(q, cu_ref, kv_lens_ref, o_ref, start_fetch, wait_fetch,
                   k_buf, v_buf, ks_buf, vs_buf, *, t_start, s0, s1,
-                  bk: int, rows: int, kv_axis: int, num_kv_heads: int,
-                  group: int, head_dim: int, v_dim: int, q_blk: int,
-                  shared_kv: bool, mqa: bool, amla: bool,
-                  score_scale=None, window=None):
+                  bk: int, rows: int, num_kv_heads: int, group: int,
+                  head_dim: int, v_dim: int, q_blk: int, shared_kv: bool,
+                  mqa: bool, amla: bool, operand, score_scale=None,
+                  window=None, heads_scr=None):
     """The ragged (prefill/mixed) block body: loop the sequences
     overlapping this q block, stream each one's causal KV range with
-    double-buffered DMA, masked kv-head-batched dots. ``score_scale``
-    (MQA only): q arrives unscaled in its own dtype and the scores take
-    the scale."""
+    double-buffered DMA, and attend each fetched block under a mask of
+    [rows, BK] (``rows`` = BQ * G score rows of one KV head, row r the
+    token r // G). ``score_scale``: q arrives unscaled in its own dtype
+    and the float32 scores take the scale (None: q is scaled already).
+
+    Under ONE KV head (``mqa``) the block is one product of every query
+    head's rows and the flash state is the loops' carry. Under several,
+    a ROLLED loop takes a KV head (of a 16-bit cache: a pair) at a time:
+    its keys and values are strided rows of the block as the DMA left it
+    (``paged_kv.head_rows``), its q rows and flash state lie in VMEM
+    scratch (``heads_scr``: q [Hkv, rows, D] in ``operand``, re-laid once
+    a q block; m, l, acc float32), and the live score tile is that one
+    head's. Operands as ``mxu_operand`` says: 16-bit as stored with p in
+    two parts, else float32."""
     if mqa:
         # Hkv == 1 (MLA latent): flat 2-D rows [BQ*Hq, D]; the caches
         # arrive 3-D with the singleton head axis squeezed (Mosaic's
         # sublane tiling rejects slicing a size-1 second-minor dim).
-        operand = mxu_operand(q.dtype, k_buf.dtype, False)
         qh = q.reshape(rows, head_dim).astype(operand)
-        row_tok = t_start + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, 1), 0) // group
     else:
-        # [BQ, Hkv, G, D] → [Hkv, BQ, G, D] → [Hkv, BQ*G, D]
-        qh = q.reshape(q_blk, num_kv_heads, group, head_dim) \
-              .transpose(1, 0, 2, 3).reshape(num_kv_heads, rows, head_dim)
-        # token index of each score row: row r → t_start + r // G
-        row_tok = t_start + jax.lax.broadcasted_iota(
-            jnp.int32, (num_kv_heads, rows, 1), 1) // group
+        q_scr, m_scr, l_scr, acc_scr = heads_scr
+        # [BQ, Hkv, G, D] -> [Hkv, BQ, G, D] -> [Hkv, BQ*G, D]: in float32
+        # (exact for a 16-bit q), whose rows Mosaic re-lays
+        q_scr[...] = q.astype(jnp.float32) \
+            .reshape(q_blk, num_kv_heads, group, head_dim) \
+            .transpose(1, 0, 2, 3) \
+            .reshape(num_kv_heads, rows, head_dim).astype(operand)
+        m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+    # p rounded once under one KV head (PR 37), in two parts under several
+    p_parts = 1 if mqa else _p_parts(operand)
+    # token index of each score row: row r -> t_start + r // G
+    row_tok = t_start + jax.lax.broadcasted_iota(
+        jnp.int32, (rows, 1), 0) // group
+    # the flash state: under one KV head the loops' carry
+    state = (jnp.full((rows, 1), NEG_INF, jnp.float32),
+             jnp.zeros((rows, 1), jnp.float32),
+             jnp.zeros((rows, v_dim), jnp.float32)) if mqa else None
 
-    def seq_body(s, carry):
-        m, l, acc = carry
+    def seq_body(s, state):
         q_start = cu_ref[s]
         q_end = cu_ref[s + 1]                 # exclusive
         q_len = q_end - q_start
@@ -306,8 +401,7 @@ def _ragged_block(q, cu_ref, kv_lens_ref, o_ref, start_fetch, wait_fetch,
         def _():
             start_fetch(0, s, b0 if window else 0)
 
-        def blk_body(i, carry2):
-            m, l, acc = carry2
+        def blk_body(i, state):
             slot = jax.lax.rem(i, 2)
             more = i + 1 < n_blocks
             if window:
@@ -318,45 +412,64 @@ def _ragged_block(q, cu_ref, kv_lens_ref, o_ref, start_fetch, wait_fetch,
                 start_fetch(1 - slot, s, i + 1)
 
             wait_fetch(slot, s, i)
-            k, v = block_kv(k_buf, v_buf, slot, bk, num_kv_heads,
-                            head_dim, v_dim, shared_kv, mqa=mqa,
-                            ks_buf=ks_buf, vs_buf=vs_buf)
-            if mqa:
-                kt = k.astype(operand)                  # [BK, D]
-                vt = v.astype(operand)                  # [BK, Dv]
+
+            def mask():
+                kv_pos = i * bk + jax.lax.broadcasted_iota(
+                    jnp.int32, (rows, bk), 1)
+                in_seq = (row_tok >= q_start) & (row_tok < q_end)
+                q_pos = kv_len - q_len + (row_tok - q_start)
+                visible = in_seq & (kv_pos <= q_pos) & (kv_pos < kv_len)
+                if window:
+                    visible &= kv_pos > q_pos - window
+                return visible
+
+            def attend(qh, k, v, m, l, acc, visible=None):
                 scores = jax.lax.dot_general(           # [R, BK]
-                    qh, kt, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32) * score_scale
-            else:
-                kt = k.astype(jnp.float32).transpose(1, 0, 2)
-                vt = v.astype(jnp.float32).transpose(1, 0, 2)
-                scores = jax.lax.dot_general(           # [Hkv, R, BK]
-                    qh, kt, (((2,), (2,)), ((0,), (0,))),
+                    qh, k, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32)
-            kv_pos = i * bk + jax.lax.broadcasted_iota(
-                jnp.int32, scores.shape, kv_axis)
-            in_seq = (row_tok >= q_start) & (row_tok < q_end)
-            q_pos = kv_len - q_len + (row_tok - q_start)
-            visible = in_seq & (kv_pos <= q_pos) & (kv_pos < kv_len)
-            if window:
-                visible &= kv_pos > q_pos - window
-            scores = jnp.where(visible, scores, NEG_INF)
-            return _online_update(scores, vt, m, l, acc, kv_axis, mqa,
-                                  amla)
+                if score_scale is not None:
+                    scores = scores * score_scale
+                if visible is None:
+                    visible = mask()
+                scores = jnp.where(visible, scores, NEG_INF)
+                return _online_update(scores, v, m, l, acc, 1, True, amla,
+                                      p_parts)
 
-        return jax.lax.fori_loop(0, n_blocks, blk_body, (m, l, acc))
+            if mqa:
+                k, v = block_kv(k_buf, v_buf, slot, bk, num_kv_heads,
+                                head_dim, v_dim, shared_kv, mqa=True)
+                return attend(qh, k.astype(operand), v.astype(operand),
+                              *state)
 
-    lead = (rows,) if mqa else (num_kv_heads, rows)
-    m0 = jnp.full((*lead, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((*lead, 1), jnp.float32)
-    acc0 = jnp.zeros((*lead, v_dim), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(s0, s1 + 1, seq_body, (m0, l0, acc0))
+            visible = mask()        # one a kv block, shared by the heads
 
-    out = acc / jnp.maximum(l, 1e-30)                   # empty rows → 0
+            def heads_body(g, _):
+                ks = head_rows(k_buf, ks_buf, slot, g, bk, num_kv_heads,
+                               head_dim)
+                vs = ([k[:, :v_dim] for k in ks] if shared_kv else
+                      head_rows(v_buf, vs_buf, slot, g, bk, num_kv_heads,
+                                v_dim))
+                for b, (k, v) in enumerate(zip(ks, vs)):
+                    h = len(ks) * g + b
+                    m_scr[h], l_scr[h], acc_scr[h] = attend(
+                        q_scr[h], k.astype(operand), v.astype(operand),
+                        m_scr[h], l_scr[h], acc_scr[h], visible)
+
+            return jax.lax.fori_loop(
+                0, num_kv_heads // heads_a_load(num_kv_heads, k_buf.dtype),
+                heads_body, None)
+
+        return jax.lax.fori_loop(0, n_blocks, blk_body, state)
+
+    state = jax.lax.fori_loop(s0, s1 + 1, seq_body, state)
+
     if mqa:
+        _, l, acc = state
+        out = acc / jnp.maximum(l, 1e-30)               # empty rows -> 0
         out = out.reshape(q_blk, group, v_dim)          # group == Hq
     else:
-        # [Hkv, BQ*G, Dv] → [BQ, Hkv, G, Dv] → [BQ, Hq, Dv]
+        # [Hkv, BQ*G, Dv] -> [BQ, Hkv, G, Dv] -> [BQ, Hq, Dv]
+        out = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
         out = out.reshape(num_kv_heads, q_blk, group, v_dim) \
                  .transpose(1, 0, 2, 3) \
                  .reshape(q_blk, num_kv_heads * group, v_dim)
@@ -527,19 +640,23 @@ def ragged_paged_attention(
     if window and unified:
         raise NotImplementedError("a window under the unified kernel")
 
-    # MQA (MLA latent cache): squeeze the singleton head axis — Mosaic's
-    # sublane tiling rejects slicing a size-1 second-minor dim.
+    # The kernel reads a page as [page * Hkv, D]: the token-major pool
+    # with the kv heads folded into the rows, which is the pool's own
+    # order (no data moves; the decode kernel's view), and for MQA (the
+    # MLA latent cache) the squeeze of the singleton head axis that
+    # Mosaic's sublane tiling asks for anyway.
     num_pages = k_cache.shape[0]
     mqa = num_kv_heads == 1
     if quant and (mqa or shared_kv):
         raise NotImplementedError(
             "int8 KV cache unsupported for MQA/MLA ragged kernels")
-    if mqa:
-        k_cache = k_cache.reshape(num_pages, page_size, head_dim)
-        if v_cache is not None:
-            v_cache = v_cache.reshape(num_pages, page_size, v_dim)
+    k_cache = k_cache.reshape(num_pages, page_size * num_kv_heads, head_dim)
+    if v_cache is not None:
+        v_cache = v_cache.reshape(num_pages, page_size * num_kv_heads,
+                                  v_dim)
 
-    bq = effective_q_block(q_block, kv_block, num_q_heads, T)
+    bq = effective_q_block(q_block, kv_block, num_q_heads, T, num_kv_heads,
+                           v_dim)
     t_pad = -(-T // bq) * bq
     if t_pad != T:
         q = jnp.pad(q, ((0, t_pad - T), (0, 0), (0, 0)))
@@ -550,10 +667,15 @@ def ragged_paged_attention(
     if rem:
         page_table = jnp.pad(page_table,
                              ((0, 0), (0, pages_per_block - rem)))
-    if mqa and not interpret:
-        _announce_mqa(mxu_operand(q.dtype, k_cache.dtype, False).name,
-                      num_q_heads, head_dim, v_dim, bq,
-                      pages_per_block * page_size)
+    operand = mxu_operand(q.dtype, k_cache.dtype, quant)
+    if not interpret:
+        if mqa:
+            _announce_mqa(operand.name, num_q_heads, head_dim, v_dim, bq,
+                          pages_per_block * page_size)
+        else:
+            _announce_heads(*block_form(q.dtype, k_cache.dtype, quant),
+                            num_q_heads, num_kv_heads, bq,
+                            pages_per_block * page_size)
 
     # Per-block overlapping sequence range: seq s covers tokens
     # [cu[s], cu[s+1]); searchsorted over the upper bounds finds the first
@@ -591,6 +713,13 @@ def ragged_paged_attention(
     kv_specs, scratch_shapes, kv_inputs = kv_stream_specs(
         k_cache, v_cache, pages_per_block, slots=max(2, gsz),
         k_scale=k_scale, v_scale=v_scale)
+    if not mqa:
+        rows = bq * group
+        scratch_shapes += [
+            pltpu.VMEM((num_kv_heads, rows, head_dim), operand),
+            pltpu.VMEM((num_kv_heads, rows, 1), jnp.float32),
+            pltpu.VMEM((num_kv_heads, rows, 1), jnp.float32),
+            pltpu.VMEM((num_kv_heads, rows, v_dim), jnp.float32)]
     in_specs = [
         pl.BlockSpec((bq, num_q_heads, head_dim),
                      lambda b, *_: (b, 0, 0),

@@ -400,7 +400,7 @@ def test_blocks_follow_the_geometry_where_the_table_has_an_entry(monkeypatch):
     assert tuning.decode_blocks(8, num_q_heads=128) == tuning.get(
         "decode@128x8") == {"kv_block": 512, "group": 8}
     assert tuning.ragged_blocks(128, 8) == tuning.get("ragged@128x8") == {
-        "q_block": 16, "kv_block": 512}
+        "q_block": 64, "kv_block": 512}
     assert not [k for k in tuning._table()["tpu_v5_lite"] if "_window" in k]
 
 

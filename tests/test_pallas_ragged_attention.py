@@ -12,7 +12,9 @@ import jax.numpy as jnp
 
 from gllm_tpu.ops.attention import (AttentionMetadata, _paged_attention,
                                     _xla_paged_attention)
-from gllm_tpu.ops.pallas.ragged_attention import ragged_paged_attention
+from gllm_tpu.ops.pallas.paged_kv import heads_a_load
+from gllm_tpu.ops.pallas.ragged_attention import (block_form,
+                                                  ragged_paged_attention)
 
 
 def build_case(rng, seqs, Hq, Hkv, D, page, num_pages, pad_seqs=0):
@@ -137,6 +139,141 @@ def test_matches_xla_oracle(case):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
     assert not np.isnan(np.asarray(got)).any()
+
+
+# The ragged body under several KV heads (PR 46). One batch for every
+# case, at q blocks of 8 tokens and kv blocks of 16: one-token rows whose
+# q block spans sequences, with kv lengths 1 and one short of, on and one
+# past a kv block; a chunk behind a cached prefix (the ``docqa`` shape); a
+# fresh chunk that spans q blocks; padded sequences of kv length 0.
+FORM_SEQS = [(1, 1), (1, 15), (1, 16), (1, 17), (9, 60), (21, 21), (3, 40)]
+FORMS = {
+    # a 16-bit cache under a q of its dtype: as stored, p in two parts
+    "bf16": dict(q="bfloat16", kv="bfloat16", form=("bfloat16", 2)),
+    # a float32 cache keeps float32 operands
+    "f32": dict(q="float32", kv="float32", form=("float32", 1)),
+    # int8 blocks are dequantized in VMEM: float32 operands
+    "int8": dict(q="bfloat16", kv="int8", form=("float32", 1)),
+    # a q of another dtype than the cache's: float32 operands
+    "q_f32": dict(q="float32", kv="bfloat16", form=("float32", 1)),
+}
+# The cells' geometries cut down (query heads x KV heads, the heads a KV
+# head kept): qwen3-4b's 32 x 8, olmo-hybrid's 32 x 32, nemotron's 32 x 2,
+# command-a-plus's 128 x 8 in a full layer and under a window whose edge
+# falls inside a fetched block.
+FORM_GEOMETRIES = {
+    "32x8": dict(Hq=8, Hkv=2),
+    "32x32": dict(Hq=4, Hkv=4),
+    "32x2": dict(Hq=32, Hkv=2),
+    "128x8": dict(Hq=64, Hkv=4),
+    "128x8_window": dict(Hq=64, Hkv=4, window=20),
+    # an odd head count: a 16-bit cache's heads do not pair up in a word
+    "odd_heads": dict(Hq=6, Hkv=3),
+}
+
+
+def _as_f64(x):
+    return np.asarray(jnp.asarray(x, jnp.float32), np.float64)
+
+
+def _kernel_ops(fn, *args):
+    """(primitive, operands' (shape, dtype)) of every operation in the
+    Pallas kernel ``fn`` traces to, loops and branches walked."""
+    import jax
+    ops = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            ops.append((eqn.primitive.name,
+                        [(tuple(v.aval.shape), str(v.aval.dtype))
+                         for v in eqn.invars if hasattr(v, "aval")
+                         and hasattr(v.aval, "shape")]))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    def find(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                walk(eqn.params["jaxpr"])
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                find(sub)
+
+    find(jax.make_jaxpr(fn)(*args).jaxpr)
+    return ops
+
+
+@pytest.mark.parametrize("geometry", list(FORM_GEOMETRIES))
+@pytest.mark.parametrize("form", list(FORMS))
+def test_block_form_is_chosen_from_the_call(form, geometry):
+    """Under several KV heads the ragged body takes its form from the
+    dtypes and the quantization it sees (``block_form``): what the lowered
+    kernel's products take is that form, no KV block is widened or
+    transposed where the operands are 16-bit, and every form agrees with
+    float64 arithmetic on the same inputs (the result is rounded once to
+    q's dtype: half a bf16 ulp = 2**-9, so 2**-8 leaves a factor of two)."""
+    rng = np.random.default_rng(46)
+    want_form = FORMS[form]["form"]
+    geo = dict(FORM_GEOMETRIES[geometry])
+    window = geo.pop("window", None)
+    Hq, Hkv, D, page, pages = geo["Hq"], geo["Hkv"], 64, 8, 40
+    q, kc, vc, md = build_case(rng, FORM_SEQS, Hq, Hkv, D, page, pages,
+                               pad_seqs=2)
+    q = jnp.asarray(q, FORMS[form]["q"])
+    kw = {}
+    if form == "int8":
+        ks, vs = (rng.uniform(0.01, 0.02, (pages, Hkv)).astype(np.float32)
+                  for _ in range(2))
+        kc = rng.integers(-127, 128, kc.shape).astype(np.int8)
+        vc = rng.integers(-127, 128, vc.shape).astype(np.int8)
+        kw.update(k_scale=jnp.asarray(ks), v_scale=jnp.asarray(vs))
+        kc_j, vc_j = jnp.asarray(kc), jnp.asarray(vc)
+        k_ref = kc.astype(np.float64) * ks[:, None, :, None]
+        v_ref = vc.astype(np.float64) * vs[:, None, :, None]
+    else:
+        kc_j, vc_j = (jnp.asarray(x, FORMS[form]["kv"]) for x in (kc, vc))
+        k_ref, v_ref = _as_f64(kc_j), _as_f64(vc_j)
+    assert block_form(q.dtype, kc_j.dtype, form == "int8") == want_form
+
+    bq, bk, G = 8, 16, Hq // Hkv
+    call = lambda q, kc, vc: ragged_paged_attention(
+        q, kc, vc, md.cu_q_lens, md.kv_lens, md.page_table, scale=D ** -0.5,
+        q_block=bq, kv_block=bk, interpret=True, window=window, **kw)
+    ops = _kernel_ops(call, q, kc_j, vc_j)
+    dots = [ins for prim, ins in ops if prim == "dot_general"]
+    rows = bq * G
+    # a head at a time (a rolled loop over the loads; a load of a 16-bit
+    # cache brings a pair): [rows, D] x [BK, D], then p x [BK, D], p's
+    # two parts stacked along the rows of ONE product
+    assert dots == heads_a_load(Hkv, kc_j.dtype) * [
+        [((rows, D), want_form[0]), ((bk, D), want_form[0])],
+        [((want_form[1] * rows, bk), want_form[0]),
+         ((bk, D), want_form[0])]], dots
+    block = {(bk // page, page, D), (bk, D)}
+    if want_form[1] == 2:
+        widened = [ins for prim, ins in ops if prim == "convert_element_type"
+                   and ins[0][0] in block and ins[0][1] != "uint32"]
+        assert not widened, widened
+    relaid = [ins for prim, ins in ops if prim == "transpose"
+              and ins[0][0][-1] == D and len(ins[0][0]) != 4]
+    assert not relaid, relaid        # q and the result alone, both 4-D
+
+    got = call(q, kc_j, vc_j)
+    assert got.dtype == q.dtype
+    q64, cu = _as_f64(q), np.asarray(md.cu_q_lens)
+    want = np.zeros(got.shape)
+    for s, (q_len, kv_len) in enumerate(FORM_SEQS):
+        pt = np.asarray(md.page_table[s])
+        k = np.concatenate([k_ref[p] for p in pt])[:kv_len]
+        v = np.concatenate([v_ref[p] for p in pt])[:kv_len]
+        for t in range(q_len):
+            pos = kv_len - q_len + t
+            lo = max(0, pos - window + 1) if window else 0
+            for h in range(Hq):
+                sc = (q64[cu[s] + t, h] @ k[lo:pos + 1, h // G].T) * D ** -0.5
+                p_ = np.exp(sc - sc.max())
+                want[cu[s] + t, h] = (p_ / p_.sum()) @ v[lo:pos + 1, h // G]
+    rtol = 2 ** -8 if FORMS[form]["q"] == "bfloat16" else 2e-4
+    np.testing.assert_allclose(_as_f64(got), want, rtol=rtol, atol=1e-5)
 
 
 def test_a_mixed_batch_holds_both_kernels_under_the_ragged_name():

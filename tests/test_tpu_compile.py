@@ -319,6 +319,47 @@ def _ragged(topo, *, unified: bool, S: int, T: int, **kw):
     return fn.lower(q, kc, vc, cu, kv_lens, pt).compile()
 
 
+@pytest.mark.parametrize("Hq,Hkv,window", [
+    (32, 8, None),        # qwen3-4b
+    (32, 32, None),       # olmo-hybrid-7b's attention layers
+    (32, 2, None),        # nemotron-3-nano-30b-a3b
+    (128, 8, None),       # command-a-plus-05-2026, a full layer
+    (128, 8, 4096),       # ... a windowed one
+], ids=["32x8", "32x32", "32x2", "128x8", "128x8_window"])
+def test_ragged_kernel_under_several_kv_heads_compiles_for_v5e(
+        topo, on_tpu, Hq, Hkv, window):
+    """Mosaic takes the ragged body under several KV heads (a KV head at
+    a time out of the lane-folded block, 16-bit operands as stored, p in
+    two parts, a rolled loop over the heads) at the cells' geometries and
+    the blocks the table names for each: a 512-slot mixed step under the
+    step programs' own compiler options. The view of the pool the kernel
+    reads (heads folded into a page's lanes) costs no copy of the cache."""
+    from gllm_tpu.ops.pallas.ragged_attention import (
+        block_form, effective_q_block, ragged_paged_attention)
+    from gllm_tpu.ops.pallas.tuning import ragged_blocks
+    from gllm_tpu.utils import tpu_compiler_options
+    blocks = ragged_blocks(Hq, Hkv)
+    q, kc, vc, cu, kv_lens, pt = _kernel_args(
+        topo, S=64, T=512, Hq=Hq, Hkv=Hkv, D=128, pack=1, P=4320,
+        pages=1088 if window else 256)
+    assert block_form(q.dtype, kc.dtype) == ("bfloat16", 2)
+    fn = jax.jit(lambda q, k, v, cu, kl, pt: ragged_paged_attention(
+        q, k, v, cu, kl, pt, scale=128 ** -0.5, window=window, **blocks),
+        compiler_options=tpu_compiler_options())
+    t0 = time.monotonic()
+    compiled = fn.lower(q, kc, vc, cu, kv_lens, pt).compile()
+    bq = effective_q_block(blocks["q_block"], blocks["kv_block"], Hq, 512,
+                           Hkv, 128)
+    print(f"\n[compile] ragged kernel {Hq}x{Hkv}"
+          f"{' window' if window else ''}, 512 slots, q block {bq} "
+          f"(asked {blocks['q_block']}), kv block {blocks['kv_block']}: "
+          f"{time.monotonic() - t0:.1f}s")
+    assert attention_calls(compiled) == ["ragged_paged_attention"]
+    copies = [ln for ln in compiled.as_text().splitlines()
+              if " copy(" in ln and f"[{kc.shape[0]}," in ln]
+    assert not copies, copies
+
+
 @pytest.mark.slow
 def test_ragged_kernel_compiles_for_v5e(topo, on_tpu):
     assert has_kernel(_ragged(topo, unified=False, S=8, T=2048))
@@ -1165,6 +1206,13 @@ def test_gqa_128_by_8_kernels_compile_for_v5e(topo, on_tpu, window):
         return attention_calls(fn.lower(qq, kc, vc, cu, kv_lens,
                                         pt).compile())
 
+    # a KV head at a time: the q block at 128 heads is no longer the 16
+    # tokens that the all-heads score tile clamped it to
+    from gllm_tpu.ops.pallas.ragged_attention import effective_q_block
+    from gllm_tpu.ops.pallas.tuning import ragged_blocks
+    blocks = ragged_blocks(128, 8)
+    assert effective_q_block(blocks["q_block"], blocks["kv_block"], 128,
+                             512, 8, 128) > 16
     names = attention.WINDOW_NAMES
     assert call(1, 16) == ([names["decode"]] if window
                            else ["paged_decode_attention"])
