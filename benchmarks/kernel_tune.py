@@ -216,118 +216,6 @@ def build_mixed_step(q_block, kv_block, rows=31, prompt=320, tokens=512,
     return run, (q, kc, vc)
 
 
-UNIFIED_MIXES = ("decode", "balanced", "prefill")
-
-
-# q_len of a fused-speculation VERIFY row (spec_k + 1 with the default
-# --spec-k 4, docs/speculative_decoding.md#fused): the committed token
-# plus k draft rows ride the unified kernel as one short chunk.
-VERIFY_Q = 5
-
-
-def _unified_workload(mix="balanced", Hq=32, Hkv=8, D=128, page=16,
-                      ctx=1024, kv_dtype="auto", shrink=False):
-    """Representative UNIFIED mixed batch for the --unified-step kernel:
-    a decode prefix (one token per sequence), a VERIFY class
-    (q_len=spec_k+1 draft+verify rows — the fused-speculation geometry,
-    long context behind a short chunk), and prefill chunks, in the three
-    row mixes the serving loop actually emits — decode-heavy (a chain
-    absorbing one arrival), balanced, and prefill-heavy (ramp-up).
-    Returns the same tuple shape as ``_mixed_workload``."""
-    import jax
-    import jax.numpy as jnp
-    shapes = {
-        # (decode rows, verify rows, prefill chunk lengths)
-        "decode": (120, 16, (128,)),
-        "balanced": (64, 32, (256, 256)),
-        "prefill": (8, 8, (512, 512)),
-    }[mix]
-    if shrink:                     # interpret-mode smoke geometry
-        shapes = {"decode": (24, 4, (16,)), "balanced": (8, 4, (32, 32)),
-                  "prefill": (2, 2, (64, 64))}[mix]
-        ctx = min(ctx, 256)
-    nd, nv, chunks = shapes
-    T = nd + nv * VERIFY_Q + sum(chunks)
-    S = nd + nv + len(chunks)
-    P = S * (ctx // page) + 1
-    key = jax.random.key(0)
-    q = jax.random.normal(key, (T, Hq, D), jnp.bfloat16)
-    if kv_dtype == "int8":
-        kq = jax.random.key(1)
-        kc, ks = _quant_caches(kq, (P, page, Hkv, D))
-        vc, vs = _quant_caches(jax.random.fold_in(kq, 1),
-                               (P, page, Hkv, D))
-        caches = (kc, vc, ks, vs)
-    else:
-        caches = (jax.random.normal(key, (P, page, Hkv, D), jnp.bfloat16),
-                  jax.random.normal(key, (P, page, Hkv, D), jnp.bfloat16))
-    lens = [1] * nd + [VERIFY_Q] * nv + list(chunks)
-    cu = [0]
-    for n in lens:
-        cu.append(cu[-1] + n)
-    cu = jnp.asarray(cu, jnp.int32)
-    kv_lens = jnp.asarray([ctx] * nd + [ctx + VERIFY_Q] * nv
-                          + [ctx + c for c in chunks], jnp.int32)
-    mp = max(-(-int(kv) // page) for kv in kv_lens)
-    pt = (jnp.arange(S * mp, dtype=jnp.int32).reshape(S, mp)
-          % (P - 1)) + 1
-    return q, caches, cu, kv_lens, pt, D ** -0.5
-
-
-def build_unified(q_block, kv_block, gsz, mix="balanced",
-                  kv_dtype="auto", shrink=False):
-    """Jitted unified-sweep body + its buffers (caches as ARGS, never
-    closure constants — see build_ragged; the closure guard in
-    tests/test_kernel_tuning.py traces this body too)."""
-    import jax
-    from gllm_tpu.ops.pallas.ragged_attention import ragged_paged_attention
-    from gllm_tpu.utils import tpu_compiler_options
-    q, caches, cu, kl, pt, scale = _unified_workload(
-        mix, kv_dtype=kv_dtype, shrink=shrink)
-    interp = _interp()
-
-    if kv_dtype == "int8":
-        @functools.partial(jax.jit,
-                           compiler_options=tpu_compiler_options())
-        def run(qq, kc, vc, ks, vs):
-            return ragged_paged_attention(
-                qq, kc, vc, cu, kl, pt, scale=scale, q_block=q_block,
-                kv_block=kv_block, interpret=interp, unified=True,
-                group_size=gsz, k_scale=ks, v_scale=vs)
-
-        return run, (q, *caches)
-    kc, vc = caches
-
-    @functools.partial(jax.jit, compiler_options=tpu_compiler_options())
-    def run(qq, kc, vc):
-        return ragged_paged_attention(qq, kc, vc, cu, kl, pt, scale=scale,
-                                      q_block=q_block, kv_block=kv_block,
-                                      interpret=interp, unified=True,
-                                      group_size=gsz)
-
-    return run, (q, kc, vc)
-
-
-def time_unified(q_block, kv_block, gsz, iters=8, kv_dtype="auto"):
-    """One unified config timed over ALL THREE row mixes; RESULT is the
-    mix-summed ms (the serving loop runs all three shapes — a winner
-    must not trade one regime for another)."""
-    shrink = _interp()
-    iters = 1 if shrink else iters
-    reps = 2 if shrink else 3
-    total = 0.0
-    for mix in UNIFIED_MIXES:
-        run, (q, *args) = build_unified(q_block, kv_block, gsz, mix=mix,
-                                        kv_dtype=kv_dtype, shrink=shrink)
-        from gllm_tpu.ops.pallas.ragged_attention import effective_q_block
-        bq = effective_q_block(q_block, kv_block, q.shape[1], q.shape[0],
-                               *args[0].shape[2:])
-        print(f"EFFECTIVE unified:{bq}:{kv_block}:{gsz} mix={mix}",
-              flush=True)
-        total += _time_reps(run, q, iters, *args, reps=reps)
-    return total
-
-
 def time_ragged(q_block, kv_block, iters=12, kv_dtype="auto"):
     """Per-layer ms summed over the cells' mixed steps (the ranking;
     ``RAGGED_SHAPES``), each shape's line printed, and the old input's
@@ -748,7 +636,7 @@ def main():
     ap.add_argument("--write", action="store_true",
                     help="merge winners into gllm_tpu/ops/pallas/tables.json")
     ap.add_argument("--vmem-probe", action="store_true")
-    ap.add_argument("--kernel", choices=("ragged", "decode", "unified"),
+    ap.add_argument("--kernel", choices=("ragged", "decode"),
                     default=None)
     ap.add_argument("--blocks", default=None,
                     help="comma-separated block sizes of the ragged sweep "
@@ -787,11 +675,6 @@ def main():
                              int(parts[2]) if len(parts) > 2 else 1,
                              kv_dtype=(parts[3] if len(parts) > 3
                                        else "auto"))
-        elif parts[0] == "unified":
-            ms = time_unified(int(parts[1]), int(parts[2]),
-                              int(parts[3]),
-                              kv_dtype=(parts[4] if len(parts) > 4
-                                        else "auto"))
         elif parts[0] == "geometry":
             ms = sweep_geometry(int(parts[1]), int(parts[2]), int(parts[3]),
                                 rows=int(parts[4]),
@@ -931,7 +814,7 @@ def main():
             say("\n".join("[tune]   | " + ln
                           for ln in out[-1200:].splitlines()[-12:]))
 
-    results = {"ragged": {}, "decode": {}, "unified": {}}
+    results = {"ragged": {}, "decode": {}}
     best = {}
     if args.kernel in (None, "ragged"):
         # requested configs whose VMEM-clamped program was already timed
@@ -971,25 +854,6 @@ def main():
             kb, gsz = min(ok_d, key=ok_d.get).split("g")
             best["decode"] = {"kv_block": int(kb), "group": int(gsz)}
             write_best({"decode": best["decode"]})
-    if args.kernel in (None, "unified"):
-        # unified mixed-batch sweep (--unified-step geometry): each
-        # config's RESULT is the decode-heavy + balanced + prefill-heavy
-        # mix-summed time (time_unified), so the committed winner never
-        # trades one serving regime for another. The group dimension is
-        # the decode-class DMA interleave depth.
-        for (qb, kb), gsz in itertools.product(
-                itertools.product(BLOCKS[:3], BLOCKS), (2, 4, 8)):
-            ms, out = run_inner(f"unified:{qb}:{kb}:{gsz}:{args.kv_dtype}")
-            results["unified"][f"{qb}x{kb}g{gsz}"] = ms
-            report("unified", f"q={qb} kv={kb} group={gsz} (mix-sum)",
-                   ms, out)
-        ok_u = {k: v for k, v in results["unified"].items() if v}
-        if ok_u:
-            qbkb, gsz = min(ok_u, key=ok_u.get).split("g")
-            qb, kb = qbkb.split("x")
-            best["unified"] = {"q_block": int(qb), "kv_block": int(kb),
-                               "group": int(gsz)}
-            write_best({"unified": best["unified"]})
     say(json.dumps({"results": results, "best": best}))
     print(json.dumps({"results": results, "best": best}))
 

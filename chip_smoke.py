@@ -78,11 +78,13 @@ TINY_WIDTHS = {
     "intermediate_size": 128, "max_position_embeddings": 512,
     "eos_token_id": 1}
 
-FAST_FLAGS = ["--overlap-scheduling", "--pipelined-loop", "--unified-step",
+FAST_FLAGS = ["--overlap-scheduling", "--pipelined-loop",
               "--decode-slot-batching", "--ondevice-finish",
               "--decode-chain-len", "16",
               "--spec-decode", "ngram", "--spec-fused"]
-RETIRED_KINDS = ("prefill", "decode")   # retired under --unified-step
+# what the fast arm's steps are: prompts, fused decode chains, and the
+# single decode steps where a chain could not be extended
+FAST_KINDS = ("prefill", "decode", "fused_block")
 
 # The bf16 tolerance, settled after seeing the chip's numbers (CHANGES.md,
 # PR 21). It is relative to the SPREAD of the reference logprobs (their
@@ -500,11 +502,11 @@ def one_chip(model_dir, env, size, platform, ready_timeout, trim):
                    dict(want, attention_impl="pallas"), ready_timeout)
     compare_prefill(fast, ref)
     compare_prefill(fast, serve, gate=False)
-    check(fast["steps"].get("unified_step", 0) > 0,
-          f"serve-fast: no unified_step steps: {fast['steps']}")
-    retired = {k: v for k, v in fast["steps"].items()
-               if k in RETIRED_KINDS and v}
-    check(not retired, f"serve-fast ran retired step kinds: {retired}")
+    check(fast["steps"].get("fused_block", 0) > 0,
+          f"serve-fast: no fused_block steps: {fast['steps']}")
+    other = {k: v for k, v in fast["steps"].items()
+             if k not in FAST_KINDS and v}
+    check(not other, f"serve-fast ran other step kinds: {other}")
     if platform == "tpu":
         # the children share one compile cache (the set-up programs at
         # least are the same in every arm)
@@ -617,7 +619,7 @@ def main():
             platform = "tpu"
             # Trimmed warm-up grid, never the widths: --maxd bounds the
             # decode buckets the normal warm-up compiles (each costs
-            # seconds to tens of seconds cold; ROADMAP A6).
+            # seconds to tens of seconds cold: ROADMAP, "Set-up").
             trim = ["--maxd", "16", "--max-num-seqs", "16"]
             # The XLA attention path is the CPU platform's own and the
             # oracle; on a TPU it is affordable only small. It pads scores

@@ -193,21 +193,6 @@ class EngineConfig:
     # byte-identical to the sync loop; implies overlap_scheduling.
     # False = today's loop, byte for byte.
     pipelined_loop: bool = False
-    # Unified mixed-batch step (--unified-step,
-    # docs/overlap_scheduling.md#unified-step): one ragged kernel and
-    # one jitted program serve EVERY paged step — decode rows are
-    # q_len=1 rows of the same ragged batch (per-row-class block
-    # geometry + AMLA mul-by-add rescaling inside the one Pallas
-    # kernel), the shape-signature space collapses to (pow2 row bucket
-    # × pow2 token bucket) with the max_q_len axis gone, and under
-    # overlap scheduling a decode chain ABSORBS prefill chunks through
-    # mixed re-formed batches (scheduler.schedule_reform across phase
-    # boundaries) instead of yielding — the chain_breaks
-    # reason="waiting" class and the chain_under_prefill ramp knob are
-    # retired (deprecated no-ops). Greedy + seeded token streams are
-    # byte-identical to the flag-off engine under churn; off =
-    # byte-identical legacy dispatch, kernels included.
-    unified_step: bool = False
     # Persistent-slot decode batching (--decode-slot-batching, overlap
     # scheduling only): chain membership becomes slot-based, so fused
     # decode chains survive sequence finishes — a finished row is masked
@@ -388,37 +373,22 @@ class EngineConfig:
             # further ahead — chains are its primary edge; lifting the
             # flag keeps "--pipelined-loop" a one-flag opt-in
             self.overlap_scheduling = True
-        if self.unified_step:
-            if self.overlap_scheduling and not self.pipelined_loop:
-                # absorbing a prefill chunk into a running chain IS a
-                # speculative mixed re-form — the unified overlap loop
-                # runs on the pipelined FutureMap machinery
-                self.pipelined_loop = True
-            if self.chain_under_prefill:
-                # the ramp-yield policy is obsolete: chains never yield
-                # to prefill under the unified step — they absorb it
-                import logging
-                logging.getLogger(__name__).warning(
-                    "chain_under_prefill is deprecated and ignored "
-                    "under --unified-step: mixed re-formed batches "
-                    "absorb prefill chunks, chains never yield")
-                self.chain_under_prefill = 0
         if self.chain_under_prefill < 0:
             raise ValueError("chain_under_prefill must be >= 0")
         if self.overlap_depth < 1:
             raise ValueError("overlap_depth (--inflight-depth) must be "
                              ">= 1")
-        if (self.pipelined_loop or self.unified_step) \
-                and self.parallel.pp > 1 and self.parallel.dp > 1:
-            # Each fast path composes with pp OR dp, but the combined
+        if self.pipelined_loop and self.parallel.pp > 1 \
+                and self.parallel.dp > 1:
+            # The pipelined loop composes with pp OR dp, but the combined
             # grid would need per-replica stage pipelines driven by the
             # run-ahead loop — refuse loudly rather than silently fall
             # back to the legacy sync dispatch
             # (docs/overlap_scheduling.md#topology-matrix).
             raise ValueError(
-                "--pipelined-loop/--unified-step compose with pp>1 OR "
-                "dp>1, not both at once: run pp with dp=1 or dp with "
-                "pp=1, or drop the flags for the legacy sync pipeline")
+                "--pipelined-loop composes with pp>1 OR dp>1, not both at "
+                "once: run pp with dp=1 or dp with pp=1, or drop the flag "
+                "for the legacy sync pipeline")
         if self.spec_fused:
             if self.spec_decode != "ngram":
                 raise ValueError(

@@ -197,7 +197,6 @@ def _asked_for(config: EngineConfig) -> dict:
                   "--kv-disk-path, --prefix-peers)",
                   cache.host_pool_configured or cache.kvstore_configured),
         "spec": ("--spec-decode / --spec-fused", bool(config.spec_decode)),
-        "unified": ("--unified-step", config.unified_step),
         "fused": ("fused multi-step decoding (--multi-step-decode, "
                   "--decode-chain-len, --ondevice-finish)",
                   config.multi_step_decode > 1 or config.ondevice_finish
@@ -219,14 +218,13 @@ def refuse_for_rings(config: EngineConfig) -> None:
     the windowed latent layers of models/deepseek.py) holds of each
     sequence, in those layers, the window's last rows and nothing older.
     So whatever needs a sequence's rows again after the fact, or writes
-    rows that may be taken back, is refused at start-up (ROADMAP B4 lists
-    these beside the hybrid models'): a cached prefix has no rows to
+    rows that may be taken back, is refused at start-up (ROADMAP,
+    "Windowed layers", lists these): a cached prefix has no rows to
     resume from, a tier below the pages would hold the full layers' half
-    of a sequence, a rejected draft or a discarded fused block has
-    already overwritten the ring, and the unified step re-forms steps. A
-    model whose windowed layers keep PAGES is not refused here
-    (``refuse_for_paged_windows``)."""
-    _refuse(config, ("prefix", "tiers", "spec", "unified", "fused", "mesh"),
+    of a sequence, and a rejected draft or a discarded fused block has
+    already overwritten the ring. A model whose windowed layers keep
+    PAGES is not refused here (``refuse_for_paged_windows``)."""
+    _refuse(config, ("prefix", "tiers", "spec", "fused", "mesh"),
             "a model whose windowed layers keep rings (windowed latent "
             "attention: a slot of the window's last rows a sequence and "
             "layer, nothing older) cannot give a sequence's rows back; "
@@ -239,11 +237,11 @@ def refuse_for_paged_windows(config: EngineConfig) -> None:
     has the prefix cache and the plain and the prepared loop. What would
     need work that has not been done is refused by name, never run
     without its window: the windowed Pallas calls have no shard_map and
-    the expert layer no exchange (any mesh); the unified kernel and the
-    int8 cache's kernels know no window; the fused and speculative
+    the expert layer no exchange (any mesh); the int8 cache's kernels
+    know no window; the fused and speculative
     programs and the tiers below the pages have not been run with this
     family's cache."""
-    _refuse(config, ("mesh", "int8_kv", "tiers", "spec", "unified", "fused"),
+    _refuse(config, ("mesh", "int8_kv", "tiers", "spec", "fused"),
             "a model whose windowed GQA layers keep their rows in the "
             "paged pool is served on the plain path with or without the "
             "prefix cache; not supported with it yet: ")
@@ -435,22 +433,6 @@ class LLM:
         # invalidates that entry (and its chained descendants) at collect
         # time; the sync path rebuilds from committed state.
         self.pipelined = bool(getattr(config, "pipelined_loop", False))
-        # Unified mixed-batch step (--unified-step,
-        # docs/overlap_scheduling.md#unified-step): one dispatch family
-        # (runner/prepare signature collapse + the unified kernel), and
-        # under overlap scheduling the chain absorbs prefill chunks via
-        # mixed re-forms — steps record as kind="unified_step". INERT
-        # for hybrid (GDN) models: re-forms are gated off for them
-        # (cumulative SSM state cannot replay a discarded step), so the
-        # whole flag stays legacy — dispatch, signatures, and step
-        # kinds — keeping the retired-'waiting' invariant true wherever
-        # unified kinds are recorded.
-        self.unified = (bool(getattr(config, "unified_step", False))
-                        and not model_cfg.use_hybrid)
-        if getattr(config, "unified_step", False) and not self.unified:
-            logger.warning(
-                "--unified-step is inert for hybrid (GDN) models: "
-                "legacy dispatch and step kinds retained")
         self.futures = FutureMap()
         # Prepared launch (docs/overlap_scheduling.md#prepared-launch):
         # the default loop (one runner, one step in flight, nothing
@@ -854,13 +836,6 @@ class LLM:
         # re-forms; ``ran_dry`` marks a fill pass that stopped early for
         # a reason other than the depth cap (stall classification).
         pipelined = self.pipelined and overlap
-        # Unified step (docs/overlap_scheduling.md#unified-step): prefill
-        # pressure no longer yields the chain — the next dispatch is a
-        # MIXED re-formed batch carrying the promised decode rows next
-        # to the admitted prefill chunks, so the 'waiting' break class
-        # and the chain_under_prefill ramp are retired. (self.unified
-        # is already False for hybrid models — the flag is inert there.)
-        unified = self.unified and overlap
         ran_dry = False
         while len(self._in_flight) < depth:
             # engine-loop phase attribution: everything from here to the
@@ -885,25 +860,6 @@ class LLM:
                     # pressure subsided without a yield: a later burst
                     # starts its ramp budget from zero, not a stale count
                     self._chained_under_pressure = 0
-                if unified and tip is not None and pressure:
-                    # the chain ABSORBS the waiting work: one mixed
-                    # re-formed dispatch carries the promised decode
-                    # rows next to the admitted prefill chunks — no
-                    # yield, no 'waiting' break, the chain re-roots off
-                    # the mixed entry once every row samples
-                    prev_batch, prev_handle = tip
-                    if isinstance(prev_batch, list):
-                        prev_batch = prev_batch[-1]
-                    if self._dispatch_reform(prev_batch, prev_handle,
-                                             sched_ph, multi, slot_mode,
-                                             False, mixed=True):
-                        continue
-                    # re-forming needs host-committed state — fall
-                    # through to the sync pass, which admits whatever
-                    # the re-form couldn't (as a non-chained entry
-                    # riding the pipeline, like a legacy yield)
-                    self._chain_tip = None
-                    tip = None
                 allow = tip is not None and (
                     not pressure
                     or (cup > 0 and self._chained_under_pressure < cup))
@@ -935,13 +891,9 @@ class LLM:
                         # — each break is a dispatch round trip the chain
                         # would have hidden (step-kind attribution reads
                         # these next to the decode/fused_block split).
-                        # Unified step: the 'waiting' class (ready seqs
-                        # the slots can't seat) is retired — the mixed
-                        # re-form below seats them; record 'reform'.
-                        reason = self.scheduler.chain_break_reason or "shape"
-                        if unified and reason == "waiting":
-                            reason = "reform"
-                        self._note_chain_break(prev_batch, reason)
+                        self._note_chain_break(
+                            prev_batch,
+                            self.scheduler.chain_break_reason or "shape")
                         # Pipelined loop: a membership change is not a
                         # reason to drain — speculatively RE-FORM the
                         # next batch off promised token counts and keep
@@ -949,7 +901,7 @@ class LLM:
                         # when re-forming needs host-committed state.
                         if pipelined and self._dispatch_reform(
                                 prev_batch, prev_handle, sched_ph, multi,
-                                slot_mode, pressure, mixed=unified):
+                                slot_mode, pressure):
                             continue
                         self._chain_tip = None
                         self._chained_under_pressure = 0
@@ -1276,17 +1228,14 @@ class LLM:
 
     def _dispatch_reform(self, prev_batch, prev_handle, sched_ph,
                          multi: int, slot_mode: bool,
-                         pressure: bool, mixed: bool = False) -> bool:
-        """Speculatively re-form and dispatch the next batch off
+                         pressure: bool) -> bool:
+        """Speculatively re-form and dispatch the next decode batch off
         ``prev_batch``'s promised token counts (pipelined loop;
         scheduler.schedule_reform holds the FutureMap contract). The
         re-formed batch fuses with chain links into one multi-step
         dispatch when eligible — finishes no longer cost the fused-block
-        shape. ``mixed=True`` (unified step) re-forms ACROSS the phase
-        boundary: prefill chunks ride the same dispatch with host-known
-        tokens, so a chain absorbs an arrival instead of yielding.
-        Returns False (with a loop_stall recorded) when re-forming
-        needs host-committed state."""
+        shape. Returns False (with a loop_stall recorded) when
+        re-forming needs host-committed state."""
         if self.model_cfg.use_hybrid:
             # the GDN recurrent state is CUMULATIVE: a discarded
             # speculative step leaves the slot advanced by a token that
@@ -1296,8 +1245,7 @@ class LLM:
             # pool is budgeted for per-step rollback here).
             self._note_stall("readback")
             return False
-        batch = self.scheduler.schedule_reform(prev_batch,
-                                               allow_prefill=mixed)
+        batch = self.scheduler.schedule_reform(prev_batch)
         if batch is None:
             reason = self.scheduler.reform_fail_reason
             # pp_budget gets its own stall row: the per-stage throttled
@@ -1308,15 +1256,8 @@ class LLM:
                              else "readback")
             return False
         promises = FutureMap.promised_ids(batch)
-        # fused chain links require an all-decode first step (a mixed
-        # re-form's mid-prompt chunks can't ride step_multi); the gate
-        # reads chunk POSITIONS, not committed counts — a promised row
-        # descending from a final prefill chunk is decode here
-        decode_only = all(it.num_new_tokens == 1
-                          and it.computed_before >= it.seq.prompt_len
-                          for it in batch.items)
         links = (self._schedule_multi_links(batch, multi - 1)
-                 if multi > 1 and decode_only else [])
+                 if multi > 1 else [])
         if links:
             au = links[0].active_until
             k = 1 + len(links)
@@ -1505,7 +1446,6 @@ class LLM:
             now = time.monotonic()
         fused = isinstance(batch, list)
         b = batch[-1] if fused else batch
-        mix = None
         if fused:
             kind = "fused_block"
             tokens = sum(x.total_tokens for x in batch)
@@ -1514,24 +1454,10 @@ class LLM:
                 # token count (scheduled 1/link is only an upper-bound
                 # anchor) — report what actually emitted
                 tokens = extra["spec_tokens"]
-        elif self.unified:
-            # one step kind for the one dispatch family
-            # (docs/observability.md: decode/prefill retired under the
-            # flag); ``mix`` keeps the decode-vs-mixed split readable
-            # (summarize() → mixed_step_frac, unfused accounting)
-            kind = "unified_step"
-            from gllm_tpu.sequence import HOLE_SEQ_ID
-            mix = ("mixed" if any(
-                it.num_new_tokens > 1
-                or it.computed_before < it.seq.prompt_len
-                for it in b.items if it.seq.seq_id != HOLE_SEQ_ID)
-                else "decode")
-            tokens = b.total_tokens
         else:
             kind = ("decode" if b.num_decode == b.num_seqs
                     else "prefill")
             tokens = b.total_tokens
-        decode_only = kind == "decode" or mix == "decode"
         ev = dict(num_seqs=b.num_seqs, tokens=tokens,
                   # entries still in flight AFTER this collect — the
                   # run-ahead depth the loop sustained (summarize() →
@@ -1539,15 +1465,13 @@ class LLM:
                   inflight=len(self._in_flight))
         if fused:
             ev["k"] = len(batch)
-        if mix is not None:
-            ev["mix"] = mix
         if prepared:
             ev["prepared"] = True
         if extra:
             ev.update(extra)
         self._emit_step(kind, ev, [batch], phases, t0, t_dispatch, now,
                         decode_steps=(len(batch) if fused
-                                      else int(decode_only)),
+                                      else int(kind == "decode")),
                         fused=fused)
 
     def _record_step_dp(self, live, t0: float, t_dispatch: float,
@@ -1557,15 +1481,12 @@ class LLM:
         same fields through the same tail as the single runner."""
         now = time.monotonic()
         decode_only = all(b.num_decode == b.num_seqs for b in live)
-        kind = ("unified_step" if self.unified
-                else "decode" if decode_only else "prefill")
+        kind = "decode" if decode_only else "prefill"
         ev = dict(num_seqs=sum(b.num_seqs for b in live),
                   tokens=sum(b.total_tokens for b in live),
                   dp=len(live))
         if inflight is not None:
             ev["inflight"] = inflight
-        if self.unified:
-            ev["mix"] = "decode" if decode_only else "mixed"
         self._emit_step(kind, ev, live, phases, t0, t_dispatch, now,
                         decode_steps=int(decode_only))
 
@@ -1585,7 +1506,7 @@ class LLM:
         _M_STEP_LAT.observe(wall, kind=kind)
         _M_STEPS.inc(kind=kind)
         _M_STEP_TOKENS.inc(ev["tokens"], kind=kind)
-        if not fused and self.runner.fwd_attn_impl == "pallas":
+        if not fused and self.runner.attn_impl == "pallas":
             for b in batches:
                 for kernel, n in zip(("decode", "ragged"),
                                      b.mixed_step_rows or ()):
@@ -1762,7 +1683,6 @@ class LLM:
         byte-identical to the sync dp loop for the usual reason:
         context- resp. (seed, out_step)-determined draws."""
         depth = max(1, self.config.overlap_depth)
-        unified = self.unified
         ran_dry = False
         while len(self._in_flight) < depth:
             sched_ph = spans.phase("schedule").start()
@@ -1784,8 +1704,7 @@ class LLM:
                         # super-step as non-chained rows (src_rows None)
                         nxt[r] = sched.schedule_once()
                         continue
-                    b = sched.schedule_reform(prev_r,
-                                              allow_prefill=unified)
+                    b = sched.schedule_reform(prev_r)
                     if b is None:
                         reason = sched.reform_fail_reason
                         stall = (reason

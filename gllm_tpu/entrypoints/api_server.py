@@ -416,7 +416,6 @@ class Handler(BaseHTTPRequestHandler):
                     "fast_path": {
                         "overlap_scheduling": cfg.overlap_scheduling,
                         "pipelined_loop": cfg.pipelined_loop,
-                        "unified_step": cfg.unified_step,
                         "spec_fused": cfg.spec_fused,
                     },
                 },
@@ -1080,7 +1079,6 @@ def build_engine_config(args) -> EngineConfig:
         attention_impl=args.attention_impl,
         overlap_scheduling=args.overlap_scheduling,
         pipelined_loop=args.pipelined_loop,
-        unified_step=args.unified_step,
         overlap_depth=args.inflight_depth,
         decode_slot_batching=args.decode_slot_batching,
         chain_under_prefill=args.chain_under_prefill,
@@ -1282,17 +1280,6 @@ def make_parser() -> argparse.ArgumentParser:
                         "pipeline; divergence is reconciled at collect "
                         "time (implies --overlap-scheduling; "
                         "docs/overlap_scheduling.md#pipelined-loop)")
-    p.add_argument("--unified-step", action="store_true",
-                   help="one ragged kernel, one dispatch: serve every "
-                        "paged step as a unified mixed batch (decode "
-                        "rows are q_len=1 rows of the ragged batch), "
-                        "collapse the shape-signature space to (row "
-                        "bucket × token bucket), and let decode chains "
-                        "ABSORB prefill chunks through mixed re-formed "
-                        "batches instead of yielding (retires the "
-                        "'waiting' break class and --chain-under-"
-                        "prefill; docs/overlap_scheduling.md#unified-"
-                        "step). Off = byte-identical legacy dispatch")
     p.add_argument("--inflight-depth", type=int, default=2,
                    help="max dispatched-but-uncollected engine entries "
                         "under --overlap-scheduling (the pipelined "

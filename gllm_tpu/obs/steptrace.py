@@ -25,16 +25,7 @@ __all__ = ["StepTrace", "TRACE", "summarize"]
 
 # Step-event kinds recorded by the engine/runner instrumentation:
 #   prefill      - step whose batch carries at least one prefill chunk
-#                  (retired under --unified-step: see unified_step)
-#   decode       - single-step pure-decode dispatch (the UNfused path;
-#                  retired under --unified-step: see unified_step)
-#   unified_step - one unified mixed-batch dispatch (--unified-step,
-#                  docs/overlap_scheduling.md#unified-step): the single
-#                  step kind replacing prefill/decode when the flag is
-#                  on — the ``mix`` field ("decode" | "mixed") keeps the
-#                  composition readable (summarize() folds mix=decode
-#                  into the unfused-decode accounting and reports
-#                  mixed_step_frac over the window)
+#   decode       - single-step pure-decode dispatch (the UNfused path)
 #   fused_block  - multi-step decode block (one dispatch, K sub-steps);
 #                  under fused on-device speculation
 #                  (config.spec_fused) the event also carries
@@ -52,10 +43,7 @@ __all__ = ["StepTrace", "TRACE", "summarize"]
 #                  batch, host-work features), spec (host-driven
 #                  speculation owns dispatch — retired, zero, under
 #                  --spec-fused), finish (legacy membership loss — zero under
-#                  --decode-slot-batching), reform (unified step: the
-#                  chain re-formed through a mixed/grown batch instead
-#                  of waiting — 'waiting' is retired, zero with
-#                  --unified-step on)
+#                  --decode-slot-batching)
 #   fault        - a robustness event (docs/robustness.md): an injected
 #                  fault point fired (``point`` field names it), the
 #                  watchdog detected a stale heartbeat
@@ -104,10 +92,9 @@ __all__ = ["StepTrace", "TRACE", "summarize"]
 # the step) and ``step_wall_ms`` = schedule-start → collect-end.
 # ``compile`` events carry ``first_use_ms`` and ``source`` (compiled |
 # cache).
-STEP_KINDS = ("prefill", "decode", "unified_step", "fused_block",
-              "pp_stage", "compile", "chain_break", "fault",
-              "quarantine", "prefix", "loop_stall", "recovery",
-              "first_token")
+STEP_KINDS = ("prefill", "decode", "fused_block", "pp_stage", "compile",
+              "chain_break", "fault", "quarantine", "prefix", "loop_stall",
+              "recovery", "first_token")
 # recovery (config.engine_recovery, docs/robustness.md#recovery-
 # lifecycle) event phases: begin (latch handed to the supervisor),
 # partition (streams split into replayable vs dropped), rebuild_fail
@@ -116,8 +103,7 @@ STEP_KINDS = ("prefill", "decode", "unified_step", "fused_block",
 # rebuilds within the window → permanent unhealthy).
 RECOVERY_PHASES = ("begin", "partition", "rebuild_fail", "ready",
                    "crash_loop")
-CHAIN_BREAK_REASONS = ("waiting", "pages", "shape", "spec", "finish",
-                       "reform")
+CHAIN_BREAK_REASONS = ("waiting", "pages", "shape", "spec", "finish")
 LOOP_STALL_REASONS = ("readback", "rebuild", "pages", "depth")
 
 
@@ -215,9 +201,6 @@ def summarize(events: List[dict]) -> dict:
     fused_steps = unfused_steps = 0
     fused_ms = unfused_ms = 0.0
     total_ms = 0.0
-    # unified-step composition (--unified-step): collected step events
-    # vs the share of them that carried at least one prefill row
-    step_events = unified_mixed = 0
     compiles = chain_breaks = 0
     break_reasons: Dict[str, int] = {}
     faults_total = quarantines = 0
@@ -326,13 +309,9 @@ def summarize(events: List[dict]) -> dict:
                 if e.get(name + "_ms") is not None:
                     blocked[name] = (blocked.get(name, 0.0)
                                      + float(e[name + "_ms"]))
-        step_events += 1
-        if k == "decode" or (k == "unified_step"
-                             and e.get("mix") == "decode"):
+        if k == "decode":
             unfused_steps += 1
             unfused_ms += wall
-        elif k == "unified_step":
-            unified_mixed += 1
         elif k == "fused_block":
             fused_steps += int(e.get("k", 1))
             fused_ms += wall
@@ -365,13 +344,6 @@ def summarize(events: List[dict]) -> dict:
                              if spec_drafted else None),
         "tokens_per_dispatch": (round(total_tokens / dispatches, 2)
                                 if dispatches else None),
-        # unified step (--unified-step): share of collected step
-        # dispatches that were MIXED unified batches (prefill rows
-        # riding the decode stream — chains absorbing arrivals); None
-        # when the window saw no unified_step events (flag off)
-        "mixed_step_frac": (round(unified_mixed / step_events, 4)
-                            if step_events and "unified_step" in kinds
-                            else None),
         # per-window prefix-cache hit rate by tier (None when the window
         # saw no admission probes — prefix caching off or pure decode)
         "prefix": ({

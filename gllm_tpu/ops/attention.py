@@ -7,7 +7,7 @@ mixed batch of prefill chunks and decode rows against the paged KV cache, with
 causal masking relative to each sequence's already-computed context (chunked
 prefill attends to all cached tokens plus the causal part of its own chunk).
 
-Three implementations:
+Two implementations:
 - ``xla``: gather-based reference. Runs on any backend (CPU tests, fallback),
   numerically the oracle for the Pallas kernels.
 - ``pallas``: pure-decode batches (max_q_len == 1) run the per-sequence
@@ -18,12 +18,6 @@ Three implementations:
   (``_mixed_step_attention``). Both stream KV pages through
   VMEM with double-buffered DMA; MLA passes ``v_cache=None`` so values are
   read as the latent prefix of each key block (one DMA stream).
-- ``unified``: the ``--unified-step`` path — EVERY paged step (decode,
-  mixed, prefill; int8-KV dequant included) runs the ONE ragged kernel
-  with per-row-class block geometry and AMLA mul-by-add rescaling
-  (``ragged_paged_attention(unified=True)``,
-  docs/overlap_scheduling.md#unified-step). The decode kernel is kept
-  only as the legacy path / parity oracle.
 
 Metadata layout (built by the runner, all padded to static bucket shapes):
 - cu_q_lens: [S+1] int32 — cumulative query lengths (padded seqs repeat the
@@ -95,28 +89,24 @@ def paged_attention(q, k_cache, v_cache, metadata, *, scale, max_q_len,
     sequence's pages and nothing older (a windowed GQA layer whose rows
     stay in the paged pool); the Pallas calls then carry the names
     ``WINDOW_NAMES`` and fetch only the pages the window overlaps."""
-    if window and (impl == "unified" or (
-            impl == "pallas" and _SHARD_CTX is not None)):
+    if window and impl == "pallas" and _SHARD_CTX is not None:
         raise NotImplementedError(
-            "windowed paged attention under the unified kernel or a tp "
-            "shard context")
-    if impl in ("pallas", "unified") and _SHARD_CTX is not None:
+            "windowed paged attention under a tp shard context")
+    if impl == "pallas" and _SHARD_CTX is not None:
         mesh, axis = _SHARD_CTX
         tp = mesh.shape[axis]
         if tp > 1:
             return _pallas_sharded(q, k_cache, v_cache, metadata,
                                    scale=scale, max_q_len=max_q_len,
                                    v_dim=v_dim, mesh=mesh, axis=axis,
-                                   k_scale=k_scale, v_scale=v_scale,
-                                   impl=impl)
+                                   k_scale=k_scale, v_scale=v_scale)
     return _paged_attention(q, k_cache, v_cache, metadata, k_scale,
                             v_scale, scale=scale, max_q_len=max_q_len,
                             impl=impl, v_dim=v_dim, window=window)
 
 
 def _pallas_sharded(q, k_cache, v_cache, metadata, *, scale, max_q_len,
-                    v_dim, mesh, axis, k_scale=None, v_scale=None,
-                    impl="pallas"):
+                    v_dim, mesh, axis, k_scale=None, v_scale=None):
     """Run the Pallas kernels per TP shard: q sharded on its head axis, KV
     sharded on the kv-head axis when divisible (else replicated — small-Hkv
     and MLA-MQA caches are replicated by kv_cache_specs), metadata
@@ -156,7 +146,7 @@ def _pallas_sharded(q, k_cache, v_cache, metadata, *, scale, max_q_len,
             if v is not None:
                 v = jax.lax.dynamic_slice_in_dim(v, head, 1, axis=2)
         return _paged_attention(q, k, v, md, ksc, vsc, scale=scale,
-                                max_q_len=max_q_len, impl=impl,
+                                max_q_len=max_q_len, impl="pallas",
                                 v_dim=v_dim)
 
     # Inside an already-set mesh context (the runner's step trace, or the
@@ -234,7 +224,7 @@ def _paged_attention(
                                     scale=scale, max_q_len=max_q_len,
                                     k_scale=k_scale, v_scale=v_scale,
                                     window=window)
-    if impl in ("pallas", "unified"):
+    if impl == "pallas":
         backend = jax.default_backend()
         if backend == "cpu":
             interpret = True
@@ -259,23 +249,7 @@ def _paged_attention(
             q = (q[:, :, None, :] * onehot[None, :, :, None]
                  ).reshape(T, num_q_heads, pack * D)
 
-        if impl == "unified":
-            # ONE kernel, one geometry family, for every paged step:
-            # decode rows are q_len=1 rows of the ragged batch, handled
-            # by the kernel's decode-class blocks (grouped round-robin
-            # fetch — no masked-row waste, no per-seq DMA chain).
-            from gllm_tpu.ops.pallas.ragged_attention import (
-                ragged_paged_attention)
-            from gllm_tpu.ops.pallas.tuning import get as tuned
-            cfg = tuned("unified")
-            out = ragged_paged_attention(
-                q, k_cache, v_cache, metadata.cu_q_lens, metadata.kv_lens,
-                metadata.page_table, scale=scale, interpret=interpret,
-                v_dim=v_dim, q_block=cfg["q_block"],
-                kv_block=cfg["kv_block"], unified=True,
-                group_size=int(cfg.get("group", 4)),
-                k_scale=k_scale, v_scale=v_scale)
-        elif max_q_len == 1:
+        if max_q_len == 1:
             # Pure-decode batch: T == S, one query row per sequence (the
             # layout prepare.py emits for max_q_len == 1). The per-seq
             # decode kernel wins here: its [Hkv, G, BK] dot shape avoids

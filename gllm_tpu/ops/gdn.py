@@ -215,7 +215,7 @@ def gdn_impl_for(attn_impl: str, tp_sharded: bool) -> str:
     and the kernels are not partitioned; XLA otherwise. The same choice
     holds for the chunked rule's inter-chunk scan (ops/pallas/gdn_scan.py
     over the packed layout); its in-chunk half is XLA's either way."""
-    pallas = attn_impl in ("pallas", "unified") and not tp_sharded
+    pallas = attn_impl == "pallas" and not tp_sharded
     return "pallas" if pallas else "xla"
 
 

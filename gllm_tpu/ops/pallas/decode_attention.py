@@ -1,14 +1,5 @@
 """Pallas TPU paged decode attention.
 
-NOTE (unified step, docs/overlap_scheduling.md#unified-step): under
-``--unified-step`` every paged step — pure decode included — routes
-through the unified ragged kernel (ops/pallas/ragged_attention.py,
-``unified=True``), whose decode-class blocks fetch round-robin with one
-slot a sequence and keep a block update of their own (ROADMAP A3). This
-module is the dispatch path with the flag off (the default) and the
-PARITY ORACLE the unified kernel's decode-class path is tested against
-(tests/test_unified_step.py).
-
 The decode half of the reference's core attention kernel
 (sgl_kernel ``flash_attn_with_kvcache`` — /root/reference/gllm/layers/
 attention.py:92-140; Triton split-K analogue in layers/ops/

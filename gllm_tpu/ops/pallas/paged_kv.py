@@ -8,8 +8,9 @@ copy of that discipline.
 
 int8 quantized caches (kv_cache_dtype=int8) add a third/fourth stream: the
 per-page per-head f32 scale rows (``[num_pages, Hkv]``) ride the same page
-DMAs into a tiny VMEM scratch, and ``block_kv`` dequantizes each block in
-VMEM right before the MXU dots — the bf16 cache never exists in HBM, so
+DMAs into a tiny VMEM scratch, and ``attend_block`` (the decode kernel)
+and ``head_rows`` (the ragged body) dequantize each block in VMEM right
+before the MXU dots — the bf16 cache never exists in HBM, so
 the decode read path moves half the bytes.
 """
 
@@ -159,41 +160,6 @@ def head_rows(buf, s_buf, slot, g, bk: int, num_kv_heads: int, dim: int):
     if s_buf is not None:
         x = x.astype(jnp.float32) * s_buf[slot, :, pl.ds(g, 1)][:, :, None]
     return [x.reshape(bk, dim)]
-
-
-def block_kv(k_buf, v_buf, slot, bk: int, num_kv_heads: int,
-             head_dim: int, v_dim: int, shared_kv: bool,
-             mqa: bool = False, ks_buf=None, vs_buf=None):
-    """The current VMEM block, whole, as ([BK, Hkv, D] keys, [BK, Hkv, Dv]
-    values) out of the row-folded pages; shared-kv mode slices values
-    from the key block (latent prefix). ``mqa`` mode (Hkv == 1, pages
-    without the singleton head axis — Mosaic's sublane tiling rejects
-    slicing a size-1 second-minor dim) returns 2-D [BK, D] / [BK, Dv].
-    int8 blocks (ks_buf/vs_buf present) come back dequantized to f32:
-    each page's [ppb, Hkv] scale row broadcasts over its page_size x
-    head_dim slab — a VPU multiply on data already resident in VMEM, in
-    the shadow of the block's MXU dots. The ragged body under several KV
-    heads takes ``head_rows`` a load at a time instead; this is the
-    unified kernel's decode class's view, and the one KV head's.
-    """
-    quant = ks_buf is not None
-    if mqa:
-        assert not quant, "int8 KV cache unsupported in MQA kernel mode"
-        k = k_buf[slot].reshape(bk, head_dim)
-        v = k[:, :v_dim] if shared_kv else v_buf[slot].reshape(bk, v_dim)
-        return k, v
-
-    def whole(buf, s_buf, dim):
-        x = buf[slot]                          # [ppb, page * Hkv, dim]
-        if quant:
-            s = s_buf[slot]                    # [ppb, Hkv]
-            per_row = jnp.tile(s, (1, x.shape[1] // s.shape[1]))
-            x = x.astype(jnp.float32) * per_row[..., None]
-        return x.reshape(bk, num_kv_heads, dim)
-
-    k = whole(k_buf, ks_buf, head_dim)
-    v = k[..., :v_dim] if shared_kv else whole(v_buf, vs_buf, v_dim)
-    return k, v
 
 
 def own_head_tokens(num_q_heads: int, num_kv_heads: int, bk: int):

@@ -6,27 +6,6 @@ semantics, /root/reference/gllm/layers/attention.py:92-140). Replaces the
 dense-gather XLA fallback whose HBM traffic scaled with the *padded*
 page-table extent (round-1 verdict: gigabytes per layer at 4K context).
 
-Unified mode (``unified=True`` — the ``--unified-step`` kernel, adopting
-the ragged-paged-attention formulation of "Ragged Paged Attention: A
-High-Performance and Flexible LLM Inference Kernel for TPU", PAPERS.md):
-this is the SINGLE attention kernel for every non-MLA paged step — decode
-rows are q_len=1 rows of the same ragged batch. Block geometry is
-specialized per ROW CLASS inside the one kernel: a q block lying entirely
-inside the batch's decode prefix (the engine packs decode rows first, one
-token per sequence) runs the grouped round-robin fetch discipline of the
-legacy decode kernel — ``group_size`` sequences in flight per round, one
-buffer slot each, dividing the bare-DMA-latency chain that dominates
-decode — while blocks carrying prefill rows keep the double-buffered
-ragged stream with masked-row MXU dots. The per-block class rides scalar
-prefetch, derived from ``cu_q_lens`` alone (no layout change, no extra
-compile axis), so pure-decode batches do not regress against the
-per-sequence decode kernel (kept in decode_attention.py as the parity
-oracle). Unified mode also applies AMLA-style mul-by-add softmax
-rescaling ("AMLA: MUL by ADD in FlashAttention Rescaling", PAPERS.md) in
-the inner loop: the running max is quantized to integers (log2 domain),
-so the accumulator rescale by 2^dm becomes an integer ADD on the f32
-exponent field instead of a VPU multiply.
-
 Design (TPU-first):
 - grid = (num_q_blocks,) over the FLAT packed token axis. Because blocks are
   aligned with the ragged layout, q and the output use plain VMEM BlockSpecs
@@ -61,7 +40,7 @@ Design (TPU-first):
   carry (PR 37).
 - Values may have a different head dim than keys (Dv != D) to serve the MLA
   absorbed path, where v is the latent prefix of k.
-- A window (``window``, static; the legacy path only): a query at
+- A window (``window``, static): a query at
   position t attends positions t - window < j <= t. Of a sequence, a q
   block streams the kv blocks from the one that holds the window's start
   of its FIRST overlapping row to the causal limit of its last; blocks
@@ -80,18 +59,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from gllm_tpu.ops.pallas.paged_kv import (block_kv, head_rows,
-                                          heads_a_load, kv_stream_specs,
-                                          make_fetch_fns, mxu_operand,
-                                          unpack_refs)
+from gllm_tpu.ops.pallas.paged_kv import (head_rows, heads_a_load,
+                                          kv_stream_specs, make_fetch_fns,
+                                          mxu_operand, unpack_refs)
 
 logger = logging.getLogger(__name__)
 
 DEFAULT_KV_BLOCK = 256
 DEFAULT_Q_BLOCK = 128
-DEFAULT_GROUP = 4
 NEG_INF = float("-inf")
-LOG2E = 1.4426950408889634
 
 
 @functools.lru_cache(maxsize=None)
@@ -135,80 +111,38 @@ def _p_parts(operand) -> int:
     return 2 if operand.itemsize == 2 else 1
 
 
-def _rescale_add(x, dm_i):
-    """``x * 2^dm_i`` (``dm_i`` <= 0, int32, shape broadcastable to x)
-    via an integer ADD on the f32 exponent field — AMLA's mul-by-add.
-
-    Guards: dm_i == 0 returns x untouched (incl. denormals); a result
-    whose biased exponent would leave the normal range (ex + dm_i <= 0)
-    flushes to 0 — by then ``x * 2^dm_i`` is below ~1e-38 and the
-    flash-attention accumulator cannot distinguish it from 0. The
-    integer add only ever runs inside the exponent field when the guard
-    passes, so the sign bit is never touched."""
-    xb = jax.lax.bitcast_convert_type(x, jnp.int32)
-    ex = jnp.bitwise_and(xb, jnp.int32(0x7F800000)) >> 23
-    y = jax.lax.bitcast_convert_type(xb + (dm_i << 23), jnp.float32)
-    return jnp.where(dm_i >= 0, x,
-                     jnp.where(ex + dm_i > 0, y, 0.0))
-
-
-def _online_update(scores, vt, m, l, acc, kv_axis: int, one_head: bool,
-                   amla: bool, p_parts: int = 1):
+def _online_update(scores, vt, m, l, acc, p_parts: int = 1):
     """One kv-block online-softmax update over pre-masked ``scores``:
-    [R, BK] against one KV head's [BK, Dv] values (``one_head``), or
-    [Hkv, R, BK] against [Hkv, BK, Dv] in one head-batched product.
+    [R, BK] against one KV head's [BK, Dv] values.
 
-    Classic mode is the exact math both legacy kernels use (exp-domain
-    max, VPU multiply rescale). AMLA mode expects ``scores`` in the
-    LOG2 domain (q pre-scaled by ``scale * LOG2E``): the running max is
-    quantized with ``ceil`` so every rescale factor is an exact power
-    of two, applied to l/acc by ``_rescale_add`` — the block's only
-    rescale multiplies become integer adds. Rows with nothing visible
+    Exp-domain max, VPU multiply rescale. Rows with nothing visible
     yet keep m == -inf; the 0.0 stand-in keeps their p/alpha at exactly
     0 (no nan from -inf - -inf).
 
-    ``p_parts`` (one head's 16-bit values): 2 sends p into the value
+    ``p_parts`` (16-bit values): 2 sends p into the value
     product as its rounding to the values' dtype plus the rounding's
     remainder, stacked along the rows of ONE product
     (``paged_kv.attend_block``'s form: nothing is lost against float32
     operands); 1 takes p rounded once (the latent cache, PR 37) or, with
     float32 values, as it is."""
-    m_blk = jnp.max(scores, axis=kv_axis, keepdims=True)
-    if amla:
-        m_blk = jnp.ceil(m_blk)
+    m_blk = jnp.max(scores, axis=1, keepdims=True)
     m_new = jnp.maximum(m, m_blk)
     safe_m = jnp.where(m_new == NEG_INF, 0.0, m_new)
-    if amla:
-        p = jnp.exp2(scores - safe_m)
-        # integer-valued by construction (ceil'd maxes); clamp -inf
-        # (first block) below the flush threshold before the int cast
-        dm_i = jnp.maximum(m - safe_m, -160.0).astype(jnp.int32)
-        l_new = (_rescale_add(l, dm_i)
-                 + jnp.sum(p, axis=kv_axis, keepdims=True))
-    else:
-        alpha = jnp.exp(m - safe_m)
-        p = jnp.exp(scores - safe_m)
-        l_new = l * alpha + jnp.sum(p, axis=kv_axis, keepdims=True)
-    if one_head and p_parts == 2:
+    alpha = jnp.exp(m - safe_m)
+    p = jnp.exp(scores - safe_m)
+    l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+    if p_parts == 2:
         hi = p.astype(vt.dtype)
         lo = (p - hi.astype(jnp.float32)).astype(vt.dtype)
         pv = jax.lax.dot_general(                   # [2 R, Dv]
             jnp.concatenate([hi, lo], axis=0), vt,
             (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         pv = pv[:p.shape[0]] + pv[p.shape[0]:]
-    elif one_head:
+    else:
         pv = jax.lax.dot_general(                   # [R, Dv]
             p.astype(vt.dtype), vt, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-    else:
-        pv = jax.lax.dot_general(                   # [Hkv, R, Dv]
-            p, vt, (((kv_axis,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-    if amla:
-        acc_new = _rescale_add(acc, dm_i) + pv
-    else:
-        acc_new = acc * alpha + pv
-    return m_new, l_new, acc_new
+    return m_new, l_new, acc * alpha + pv
 
 
 def vmem_tile_limit_b() -> float:
@@ -256,13 +190,12 @@ def effective_q_block(q_block: int, kv_block: int, num_q_heads: int,
     return bq
 
 
-def _kernel(cu_ref, kv_lens_ref, pt_ref, first_ref, last_ref,
-            cls_ref,                                      # prefetch
+def _kernel(cu_ref, kv_lens_ref, pt_ref, first_ref, last_ref,  # prefetch
             *refs,
             page_size: int, pages_per_block: int, scale: float,
             num_kv_heads: int, group: int, head_dim: int, v_dim: int,
             q_blk: int, shared_kv: bool, mqa: bool, quant: bool,
-            unified: bool, gsz: int, amla: bool, window=None):
+            window=None):
     heads_scr = None
     if not mqa:
         # the per-head q rows and flash state of the ragged body (below)
@@ -276,63 +209,38 @@ def _kernel(cu_ref, kv_lens_ref, pt_ref, first_ref, last_ref,
     s1 = last_ref[b]
     bk = pages_per_block * page_size
     rows = q_blk * group
-    eff_scale = scale * (LOG2E if amla else 1.0)
 
     start_fetch, wait_fetch = make_fetch_fns(
         pt_ref, k_hbm, v_hbm, k_buf, v_buf, sems, pages_per_block,
         shared_kv, ks_hbm=ks_hbm, vs_hbm=vs_hbm, ks_buf=ks_buf,
         vs_buf=vs_buf)
 
-    def _ragged_body():
-        operand = mxu_operand(q_ref.dtype, k_buf.dtype, quant)
-        if mqa or operand.itemsize == 2:
-            # q and the cache enter the MXU as stored where they are
-            # 16-bit (``mxu_operand``; under one KV head, the latent
-            # cache, whatever they are) and the float32 scores take the
-            # scale
-            q_raw, score_scale = q_ref[...], eff_scale
-        else:
-            # float32 operands (a float32 cache, int8 blocks dequantized
-            # in VMEM, a q of another dtype): q takes the scale
-            q_raw = q_ref[...].astype(jnp.float32) * eff_scale  # [BQ, Hq, D]
-            score_scale = None
-        _ragged_block(q_raw, cu_ref, kv_lens_ref, o_ref, start_fetch,
-                      wait_fetch, k_buf, v_buf, ks_buf, vs_buf,
-                      t_start=t_start, s0=s0, s1=s1, bk=bk, rows=rows,
-                      num_kv_heads=num_kv_heads, group=group,
-                      head_dim=head_dim, v_dim=v_dim, q_blk=q_blk,
-                      shared_kv=shared_kv, mqa=mqa, amla=amla,
-                      operand=operand, score_scale=score_scale,
-                      window=window, heads_scr=heads_scr)
-
-    if not unified:
-        _ragged_body()
-        return
-
-    # Per-block ROW-CLASS specialization: class 1 = every token in this
-    # block is its own single-token sequence (the batch's decode
-    # prefix), so the block runs the grouped round-robin fetch
-    # discipline; class 0 keeps the ragged masked-dot path (prefill
-    # chunks, the straddling boundary block, tail padding).
-    @pl.when(cls_ref[b] == 1)
-    def _():
-        _decode_block(q_ref, kv_lens_ref, o_ref, start_fetch, wait_fetch,
-                      k_buf, v_buf, ks_buf, vs_buf, t_start=t_start,
-                      bk=bk, num_kv_heads=num_kv_heads, group=group,
-                      head_dim=head_dim, v_dim=v_dim, q_blk=q_blk,
-                      gsz=gsz, shared_kv=shared_kv, mqa=mqa, amla=amla,
-                      eff_scale=eff_scale)
-
-    @pl.when(cls_ref[b] == 0)
-    def _():
-        _ragged_body()
+    operand = mxu_operand(q_ref.dtype, k_buf.dtype, quant)
+    if mqa or operand.itemsize == 2:
+        # q and the cache enter the MXU as stored where they are 16-bit
+        # (``mxu_operand``; under one KV head, the latent cache, whatever
+        # they are) and the float32 scores take the scale
+        q_raw, score_scale = q_ref[...], scale
+    else:
+        # float32 operands (a float32 cache, int8 blocks dequantized in
+        # VMEM, a q of another dtype): q takes the scale
+        q_raw = q_ref[...].astype(jnp.float32) * scale      # [BQ, Hq, D]
+        score_scale = None
+    _ragged_block(q_raw, cu_ref, kv_lens_ref, o_ref, start_fetch,
+                  wait_fetch, k_buf, v_buf, ks_buf, vs_buf,
+                  t_start=t_start, s0=s0, s1=s1, bk=bk, rows=rows,
+                  num_kv_heads=num_kv_heads, group=group,
+                  head_dim=head_dim, v_dim=v_dim, q_blk=q_blk,
+                  shared_kv=shared_kv, mqa=mqa, operand=operand,
+                  score_scale=score_scale, window=window,
+                  heads_scr=heads_scr)
 
 
 def _ragged_block(q, cu_ref, kv_lens_ref, o_ref, start_fetch, wait_fetch,
                   k_buf, v_buf, ks_buf, vs_buf, *, t_start, s0, s1,
                   bk: int, rows: int, num_kv_heads: int, group: int,
                   head_dim: int, v_dim: int, q_blk: int, shared_kv: bool,
-                  mqa: bool, amla: bool, operand, score_scale=None,
+                  mqa: bool, operand, score_scale=None,
                   window=None, heads_scr=None):
     """The ragged (prefill/mixed) block body: loop the sequences
     overlapping this q block, stream each one's causal KV range with
@@ -432,12 +340,16 @@ def _ragged_block(q, cu_ref, kv_lens_ref, o_ref, start_fetch, wait_fetch,
                 if visible is None:
                     visible = mask()
                 scores = jnp.where(visible, scores, NEG_INF)
-                return _online_update(scores, v, m, l, acc, 1, True, amla,
-                                      p_parts)
+                return _online_update(scores, v, m, l, acc, p_parts)
 
             if mqa:
-                k, v = block_kv(k_buf, v_buf, slot, bk, num_kv_heads,
-                                head_dim, v_dim, shared_kv, mqa=True)
+                # the pages arrive without the singleton head axis
+                # (Mosaic's sublane tiling rejects slicing a size-1
+                # second-minor dim); the latent cache's values are the
+                # leading lanes of its keys
+                k = k_buf[slot].reshape(bk, head_dim)
+                v = (k[:, :v_dim] if shared_kv
+                     else v_buf[slot].reshape(bk, v_dim))
                 return attend(qh, k.astype(operand), v.astype(operand),
                               *state)
 
@@ -476,114 +388,6 @@ def _ragged_block(q, cu_ref, kv_lens_ref, o_ref, start_fetch, wait_fetch,
     o_ref[...] = out.astype(o_ref.dtype)
 
 
-def _decode_block(q_ref, kv_lens_ref, o_ref, start_fetch, wait_fetch, k_buf,
-                  v_buf, ks_buf, vs_buf, *, t_start, bk: int,
-                  num_kv_heads: int, group: int, head_dim: int,
-                  v_dim: int, q_blk: int, gsz: int, shared_kv: bool,
-                  mqa: bool, amla: bool, eff_scale: float):
-    """Decode-class block body: every row r of this q block is its own
-    single-token sequence ``t_start + r`` (the guarantee the per-block
-    class flag encodes), so the masked ragged dots would waste a BQ×
-    factor of MXU rows and — worse — serialize one double-buffered DMA
-    chain per sequence. Instead, process rows in groups of ``gsz`` with
-    the grouped decode kernel's round-robin discipline: one buffer slot
-    per in-group sequence, up to ``gsz`` page DMAs in flight, each
-    sequence's online-softmax state carried across kv rounds.
-
-    The groups run as a ROLLED loop (rows are read from ``q_ref`` and
-    written to ``o_ref`` at a traced index): unrolled in Python, the body
-    was emitted ``q_blk`` times and Mosaic took a minute and a half per
-    shape bucket at the default 128-row block."""
-    lead = (num_kv_heads * group,) if mqa else (num_kv_heads, group)
-    kv_axis = 1 if mqa else 2
-
-    def run_group(g0, gn: int):
-        """Rows [g0, g0 + gn) of the block; ``g0`` static or traced."""
-        seq_ids = [t_start + g0 + g for g in range(gn)]
-        kv_lens = [kv_lens_ref[sid] for sid in seq_ids]
-        n_blocks = [pl.cdiv(kv_len, bk) for kv_len in kv_lens]
-        for g in range(gn):
-            @pl.when(n_blocks[g] > 0)
-            def _(g=g):
-                start_fetch(g, seq_ids[g], 0)
-
-        qs = []
-        for g in range(gn):
-            qg = (q_ref[pl.ds(g0 + g, 1)].astype(jnp.float32)
-                  * eff_scale).reshape(num_kv_heads * group, head_dim)
-            qs.append(qg if mqa
-                      else qg.reshape(num_kv_heads, group, head_dim))
-
-        max_nb = n_blocks[0]
-        for g in range(1, gn):
-            max_nb = jnp.maximum(max_nb, n_blocks[g])
-
-        def body(r, carry):
-            out = list(carry)
-            for g in range(gn):
-                m, l, acc = out[3 * g], out[3 * g + 1], out[3 * g + 2]
-                live = r < n_blocks[g]
-
-                @pl.when(live)
-                def _(g=g):
-                    wait_fetch(g, seq_ids[g], r)
-
-                k, v = block_kv(k_buf, v_buf, g, bk, num_kv_heads,
-                                head_dim, v_dim, shared_kv, mqa=mqa,
-                                ks_buf=ks_buf, vs_buf=vs_buf)
-                if mqa:
-                    kt = k.astype(jnp.float32)             # [BK, D]
-                    vt = v.astype(jnp.float32)             # [BK, Dv]
-                    scores = jax.lax.dot_general(          # [Hq, BK]
-                        qs[g], kt, (((1,), (1,)), ((), ())),
-                        preferred_element_type=jnp.float32)
-                else:
-                    kt = k.astype(jnp.float32).transpose(1, 0, 2)
-                    vt = v.astype(jnp.float32).transpose(1, 0, 2)
-                    scores = jax.lax.dot_general(          # [Hkv, G, BK]
-                        qs[g], kt, (((2,), (2,)), ((0,), (0,))),
-                        preferred_element_type=jnp.float32)
-                kv_pos = r * bk + jax.lax.broadcasted_iota(
-                    jnp.int32, scores.shape, kv_axis)
-                scores = jnp.where(kv_pos < kv_lens[g], scores, NEG_INF)
-                m2, l2, acc2 = _online_update(scores, vt, m, l, acc,
-                                              kv_axis, mqa, amla)
-
-                # re-issue this slot's next block AFTER the buffered
-                # loads above — program order keeps the loads ahead of
-                # the DMA
-                @pl.when(live & (r + 1 < n_blocks[g]))
-                def _(g=g):
-                    start_fetch(g, seq_ids[g], r + 1)
-
-                out[3 * g] = jnp.where(live, m2, m)
-                out[3 * g + 1] = jnp.where(live, l2, l)
-                out[3 * g + 2] = jnp.where(live, acc2, acc)
-            return tuple(out)
-
-        init = []
-        for _ in range(gn):
-            init += [jnp.full((*lead, 1), NEG_INF, jnp.float32),
-                     jnp.zeros((*lead, 1), jnp.float32),
-                     jnp.zeros((*lead, v_dim), jnp.float32)]
-        final = jax.lax.fori_loop(0, max_nb, body, tuple(init))
-        for g in range(gn):
-            l, acc = final[3 * g + 1], final[3 * g + 2]
-            out = acc / jnp.maximum(l, 1e-30)
-            o_ref[pl.ds(g0 + g, 1)] = out.reshape(
-                1, num_kv_heads * group, v_dim).astype(o_ref.dtype)
-
-    n_full, tail = divmod(q_blk, gsz)
-
-    def full_group(gi, carry):
-        run_group(gi * gsz, gsz)
-        return carry
-
-    jax.lax.fori_loop(0, n_full, full_group, 0)
-    if tail:
-        run_group(n_full * gsz, tail)
-
-
 def _decode_prefix_len(cu_q_lens, S: int):
     """Length of the batch's decode prefix — the longest prefix of
     sequences with exactly one token each, which is also the token
@@ -602,7 +406,7 @@ def _decode_prefix_len(cu_q_lens, S: int):
 @functools.partial(
     jax.jit,
     static_argnames=("scale", "q_block", "kv_block", "interpret", "v_dim",
-                     "unified", "group_size", "amla", "window", "name"))
+                     "window", "name"))
 def ragged_paged_attention(
     q: jnp.ndarray,            # [T, Hq, D] packed ragged tokens
     k_cache: jnp.ndarray,      # [num_pages, page_size, Hkv, D]
@@ -618,9 +422,6 @@ def ragged_paged_attention(
     v_dim=None,
     k_scale=None,              # [num_pages, Hkv] f32 (int8 cache)
     v_scale=None,
-    unified: bool = False,     # per-row-class block geometry + AMLA
-    group_size: int = DEFAULT_GROUP,   # decode-class DMA interleave depth
-    amla=None,                 # None → ride with ``unified``
     window=None,               # a query attends its last ``window``
                                # positions only (None: all before it)
     name=None,                 # the call's name in the HLO and the trace
@@ -637,8 +438,6 @@ def ragged_paged_attention(
         v_dim = v_cache.shape[-1]
     S, max_pages = page_table.shape
     group = num_q_heads // num_kv_heads
-    if window and unified:
-        raise NotImplementedError("a window under the unified kernel")
 
     # The kernel reads a page as [page * Hkv, D]: the token-major pool
     # with the kv heads folded into the rows, which is the pool's own
@@ -688,31 +487,14 @@ def ragged_paged_attention(
                                      side="right"),
                     0, S - 1).astype(jnp.int32)
 
-    if amla is None:
-        amla = unified
-    gsz = max(1, min(group_size, bq)) if unified else 1
-    if unified:
-        # Per-block row class (scalar prefetch, traced — not a compile
-        # axis): class 1 iff the whole block lies inside the decode
-        # prefix, where token t IS sequence t. The straddling boundary
-        # block and everything after it run the ragged path.
-        nd = _decode_prefix_len(cu_q_lens, S)
-        cls = (t_starts + bq <= nd).astype(jnp.int32)
-    else:
-        cls = jnp.zeros((nb,), jnp.int32)
-
     kernel = functools.partial(
         _kernel, page_size=page_size, pages_per_block=pages_per_block,
         scale=scale, num_kv_heads=num_kv_heads, group=group,
         head_dim=head_dim, v_dim=v_dim, q_blk=bq, shared_kv=shared_kv,
-        mqa=mqa, quant=quant, unified=unified, gsz=gsz, amla=amla,
-        window=window)
+        mqa=mqa, quant=quant, window=window)
 
-    # decode-class blocks hold one buffer slot per in-group sequence;
-    # the ragged path keeps using slots 0/1 of the same scratch
     kv_specs, scratch_shapes, kv_inputs = kv_stream_specs(
-        k_cache, v_cache, pages_per_block, slots=max(2, gsz),
-        k_scale=k_scale, v_scale=v_scale)
+        k_cache, v_cache, pages_per_block, k_scale=k_scale, v_scale=v_scale)
     if not mqa:
         rows = bq * group
         scratch_shapes += [
@@ -725,11 +507,10 @@ def ragged_paged_attention(
                      lambda b, *_: (b, 0, 0),
                      memory_space=pltpu.VMEM),
     ] + kv_specs
-    inputs = [cu_q_lens, kv_lens, page_table, first, last, cls,
-              q] + kv_inputs
+    inputs = [cu_q_lens, kv_lens, page_table, first, last, q] + kv_inputs
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
+        num_scalar_prefetch=5,
         grid=(nb,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bq, num_q_heads, v_dim),

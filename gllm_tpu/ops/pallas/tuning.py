@@ -45,10 +45,6 @@ BUILTIN = {
         # score tile of a sequence are twice as large
         "decode_mqa_chosen": {},
         "decode": {"kv_block": 256},
-        # the unified mixed-batch kernel (--unified-step): one geometry
-        # for every paged step; ``group`` is the decode-class DMA
-        # interleave depth (the analogue of the decode kernel's group)
-        "unified": {"q_block": 128, "kv_block": 256, "group": 4},
         # f32-score-tile VMEM budget for effective_q_block(); per-device
         # entries are HAND-maintained from kernel_tune.py --vmem-probe's
         # informational output (never auto-written — see the probe's

@@ -153,25 +153,6 @@ class PPModelRunner(ModelRunner):
                                  pack, tp > 1)
         self.kv_pack = pack if impl == "pallas" else 1
         self.attn_impl = impl
-        # Unified mixed-batch step under pp (--unified-step): every
-        # stage program routes attention through the ONE ragged kernel
-        # (same rule as the single runner — the nested tp shard_map
-        # binds each stage's context mesh, ops/attention.py), so the
-        # per-stage throttled mixed batches the scheduler feeds the
-        # pipeline dispatch as one family on every stage.
-        self.fwd_attn_impl = (
-            "unified" if (getattr(config, "unified_step", False)
-                          and impl == "pallas"
-                          and not model_cfg.use_hybrid)
-            else impl)
-        if (getattr(config, "unified_step", False)
-                and not model_cfg.use_hybrid
-                and self.fwd_attn_impl != "unified"
-                and jax.default_backend() == "tpu"):
-            logger.warning(
-                "--unified-step without the unified kernel (attn_impl="
-                "%s): dispatch-shape collapse is active but attention "
-                "runs the legacy path", impl)
         if self.kv_quant:
             self._check_kv_quant()
         from gllm_tpu.runner.prepare import BatchBuilder
@@ -380,7 +361,7 @@ class PPModelRunner(ModelRunner):
     def _make_stage_fn(self, scfg: ModelConfig):
         fwd = self.model_def.forward
         logits_fn = self.model_def.compute_logits
-        attn_impl = getattr(self, "fwd_attn_impl", self.attn_impl)
+        attn_impl = self.attn_impl
 
         @functools.partial(jax.jit,
                            static_argnames=("layout", "max_q_len",
@@ -479,14 +460,11 @@ class PPModelRunner(ModelRunner):
                           _ag(sched_batch.items)), _ag(sched_batch.items))
         _M_MICROBATCH.inc()
         # one pp_stage event PER STAGE, carrying the dispatch family the
-        # stage ran (family) — under --unified-step + token throttling
-        # every stage must show "unified_step" (the acceptance probe the
-        # composition tests read). Dispatch-side only; summarize() skips
+        # stage ran (family). Dispatch-side only; summarize() skips
         # these rows.
         decode_only = (sched_batch.num_decode == sched_batch.num_seqs
                        and not sched_batch.has_drafts)
-        family = ("unified_step" if self.builder.unified
-                  else "decode" if decode_only else "prefill")
+        family = "decode" if decode_only else "prefill"
         for i in range(len(stages)):
             TRACE.record("pp_stage", stage=i, stages=len(stages),
                          family=family, num_seqs=sched_batch.num_seqs,

@@ -107,16 +107,6 @@ class BatchBuilder:
         self.min_row_bucket = min(sc.min_row_bucket, self.max_seqs)
         self.min_token_bucket = max(sc.min_token_bucket, self.min_row_bucket)
         self.min_page_bucket = sc.min_page_bucket
-        # Unified mixed-batch step (--unified-step): ONE signature family
-        # — max_q_len is pinned to the token bucket for every batch, so
-        # the compile key collapses to (pow2 row bucket × pow2 token
-        # bucket × pages) with no separate decode (q=1) population, and
-        # pure decode (T == S) lands on the same family at t == s.
-        # Inert for hybrid (GDN) models — the runner keeps the whole
-        # flag legacy there (kernels, signatures, engine absorb path)
-        # and warns.
-        self.unified = (bool(getattr(config, "unified_step", False))
-                        and not use_ssm)
 
     def shape_signature(self, batch: ScheduledBatch) -> Tuple[int, int, int,
                                                               int]:
@@ -130,23 +120,7 @@ class BatchBuilder:
         rows = [it.num_new_tokens + len(it.draft_tokens)
                 for it in batch.items]
         max_q = max(rows)
-        if self.unified:
-            # ONE dispatch family (--unified-step): max_q rides the
-            # token bucket (no separate q=1 population), and every
-            # MIXED batch pads its token axis to the single schedulable
-            # maximum — max_prefill_tokens + the decode-seq rows — the
-            # natural geometry for token throttling to balance against.
-            # This kills the per-workload token LADDER the legacy split
-            # warms (each prefill composition its own compile): mixed
-            # steps compile once per (row, pages) bucket, and chunked
-            # prefill targets the budget anyway so the padding is small
-            # exactly when mixed steps dominate. Pure decode pins t to
-            # the seq bucket EXACTLY (one token per row — the fused
-            # chains and the chained token splice live here), the t == s
-            # point of the same q == t family.
-            t = s if max_q == 1 else self.max_tokens
-            q = t
-        elif max_q == 1:
+        if max_q == 1:
             t, q = s, 1          # pure decode: one token per seq
         else:
             t = bucket_size(sum(rows), self.min_token_bucket,

@@ -593,32 +593,6 @@ class ModelRunner:
         self.model_def = get_model_def(model_cfg)
         self.kv_pack = 1   # may be raised by _pick_attn_impl (lane packing)
         self.attn_impl = self._pick_attn_impl()
-        # Unified mixed-batch step (--unified-step): every paged step
-        # routes through the ONE ragged kernel (decode rows are q_len=1
-        # rows of the ragged batch; per-row-class block geometry + AMLA
-        # rescaling inside it — ops/attention.py impl="unified"). The
-        # XLA fallback stays the oracle; hybrid (GDN) keeps its own
-        # impl threading (gdn_impl shares the attn_impl string).
-        self.fwd_attn_impl = (
-            "unified" if (getattr(config, "unified_step", False)
-                          and self.attn_impl == "pallas"
-                          and not model_cfg.use_hybrid)
-            else self.attn_impl)
-        if (getattr(config, "unified_step", False)
-                and not model_cfg.use_hybrid
-                and self.fwd_attn_impl != "unified"
-                and jax.default_backend() == "tpu"):
-            # the signature collapse still applies (one dispatch family,
-            # the engine absorb path stays functional via the XLA/legacy
-            # kernels) but the unified Pallas kernel is not serving it —
-            # decode rows pay the legacy kernel's masked-row/gather cost.
-            # Announce it instead of silently regressing on chip. (For
-            # hybrid models the flag is inert end to end — the engine
-            # logs that instead.)
-            logger.warning(
-                "--unified-step without the unified kernel (attn_impl="
-                "%s): dispatch-shape collapse is active but attention "
-                "runs the legacy path", self.attn_impl)
         if self.kv_quant:
             self._check_kv_quant()
         # (Re)set the module-level TP shard context the attention dispatch
@@ -844,20 +818,20 @@ class ModelRunner:
         if model_cfg.use_dsa:
             from gllm_tpu.models.deepseek import BQ, dsa_rows_path
             from gllm_tpu.ops.pallas.tuning import decode_blocks
-            self.dsa_rows_path = dsa_rows_path(self.fwd_attn_impl,
+            self.dsa_rows_path = dsa_rows_path(self.attn_impl,
                                                self.mesh is not None)
             if self.dsa_rows_path == "kernel":
                 blocks = decode_blocks(1, chosen=True)
                 how = ("pallas paged_decode_attention under the "
                        "selection's mask (kv_block %d, group %d)"
                        % (blocks["kv_block"], blocks.get("group", 1)))
-            elif self.fwd_attn_impl == "pallas":
+            elif self.attn_impl == "pallas":
                 how = ("xla (whole pages gathered and attended under the "
                        "mask: the masked kernel call has no shard_map to "
                        "run under this mesh)")
             else:
                 how = ("xla (whole pages gathered and attended under the "
-                       f"mask: attention runs as {self.fwd_attn_impl})")
+                       f"mask: attention runs as {self.attn_impl})")
             logger.info(
                 "[startup] selected attention: a decoding row's chosen "
                 "positions -> %s; a chunk's work items of %d queries -> "
@@ -1113,7 +1087,7 @@ class ModelRunner:
         cfg = self.model_cfg
         fwd = self.model_def.forward
         logits_fn = self.model_def.compute_logits
-        attn_impl = self.fwd_attn_impl
+        attn_impl = self.attn_impl
 
         def lp_aux(params, cfg_, logits, tokens, hidden, residual, batch,
                    token_counts, logprobs_k, prompt_lp):
@@ -1219,7 +1193,7 @@ class ModelRunner:
                 kw = dict(layout=layout, max_q_len=max_q_len,
                           logprobs_k=logprobs_k, prompt_lp=prompt_lp,
                           spec_sampled=spec_sampled, all_greedy=all_greedy)
-                if attn_impl not in ("pallas", "unified") or mesh is None:
+                if attn_impl != "pallas" or mesh is None:
                     # XLA attention: plain vmap over stacked replicas —
                     # GSPMD partitions the batched program over the
                     # dp-sharded leading axis on its own.
@@ -1614,11 +1588,7 @@ class ModelRunner:
 
         ``prev_handle``: chain this step off a previous entry's
         ON-DEVICE sampled tokens — rows whose ``src_rows`` entry is >= 0
-        splice their input token from that array (``_splice_prev``).
-        Under the unified step the batch may be MIXED: promised decode
-        rows ride next to prefill chunks (whose tokens are host-known)
-        in one dispatch — the chain absorbing a prefill chunk instead
-        of breaking (docs/overlap_scheduling.md#unified-step)."""
+        splice their input token from that array (``_splice_prev``)."""
         build = phase("build").start()
         self._apply_ssm_intents()
         self._apply_swap_intents()
@@ -1761,15 +1731,14 @@ class ModelRunner:
         loop): item j takes the previous decode entry's on-device
         sampled token at row ``src_rows[j]`` (a promised in-flight
         row), or keeps the host-built value (-1: a joining decode-ready
-        seq, or — unified step — a prefill chunk whose tokens are all
-        committed). Unlike :meth:`_splice_chain_tokens` the two sides'
-        row buckets may differ (membership changed) and the batch may
-        be MIXED, so the splice is a tiny scatter into the flat token
-        axis at each promised item's row offset (``_scatter_prev``; its
-        offsets and source rows are the dispatch's third array); no new
-        jit-step variant. NOTE prev_tokens is NOT donated into the new
-        step: the previous entry's collect still reads it (its async
-        host copy may be in flight)."""
+        seq). Unlike :meth:`_splice_chain_tokens` the two sides' row
+        buckets may differ (membership changed), so the splice is a tiny
+        scatter into the flat token axis at each promised item's row
+        offset (``_scatter_prev``; its offsets and source rows are the
+        dispatch's third array); no new jit-step variant. NOTE
+        prev_tokens is NOT donated into the new step: the previous
+        entry's collect still reads it (its async host copy may be in
+        flight)."""
         if prev_tokens.ndim == 2:
             prev_tokens = prev_tokens[-1]   # preceding multi-step block
         ir = self._promised_rows(sched_batch)
@@ -1835,10 +1804,7 @@ class ModelRunner:
         FutureMap placeholder resolution, async_utils.py:56-61, without the
         negative-id dance — the sampled-token array is simply spliced in as
         the next step's token_ids). Delegates to :meth:`step_async` with
-        ``prev_handle`` — for a pure-decode chain the computed static
-        flags reduce to exactly the legacy chained dispatch; under the
-        unified step the same entry point serves mixed re-formed
-        batches."""
+        ``prev_handle``."""
         prev_tokens, _, prev_n = prev_handle
         if sched_batch.src_rows is None:
             # re-formed batches (src_rows) legitimately change the seq
@@ -1872,9 +1838,7 @@ class ModelRunner:
         sig = self.builder.shape_signature(chain[-1])
         host, max_q, token_counts = self.builder.build(
             chain[0], force_signature=sig)
-        # chains are all-decode by construction; under the unified
-        # signature max_q rides the token bucket (== seq bucket here)
-        # instead of pinning to 1
+        # chains are all-decode by construction
         assert token_counts is None
         assert all(it.num_new_tokens == 1 for it in chain[0].items)
         # Per-row alive-link count: rows whose seq dies (length cap)
@@ -1928,7 +1892,7 @@ class ModelRunner:
         cfg = self.model_cfg
         fwd = self.model_def.forward
         logits_fn = self.model_def.compute_logits
-        attn_impl = self.fwd_attn_impl
+        attn_impl = self.attn_impl
         page = self.config.cache.page_size
 
         @functools.partial(jax.jit, static_argnames=("layout", "num_steps",
@@ -2064,7 +2028,7 @@ class ModelRunner:
         host-driven precedent); dead rows freeze on the dummy page."""
         cfg = self.model_cfg
         fwd = self.model_def.forward
-        attn_impl = self.fwd_attn_impl
+        attn_impl = self.attn_impl
         page = self.config.cache.page_size
         ngram_n = self.config.spec_ngram
 
@@ -2501,19 +2465,5 @@ class ModelRunner:
             mixed += 1
         logger.info("[startup] phase=warmup seconds=%.2f buckets=%d",
                     time.monotonic() - _t_warm, len(combos) + mixed)
-        if self.builder.unified:
-            # one signature family (q == t): the decode and mixed passes
-            # above warm points of the SAME program population
-            logger.info("warmed %d unified shape buckets (one family)",
-                        len(combos) + mixed)
-        else:
-            logger.info("warmed %d decode + %d mixed shape buckets",
-                        len(combos), mixed)
-
-    @property
-    def num_shape_signatures(self) -> int:
-        """Distinct (kind, shape-bucket, static-flag) dispatch signatures
-        seen so far — the shape-bucket population this runner warmed or
-        compiled at first sight (the unified step must shrink it,
-        docs/overlap_scheduling.md#unified-step)."""
-        return len(self._seen_sigs)
+        logger.info("warmed %d decode + %d mixed shape buckets",
+                    len(combos), mixed)

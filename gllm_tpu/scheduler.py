@@ -564,12 +564,7 @@ class Scheduler:
         return n
 
     def _schedule_prefill(self, items: List[ScheduledSeq],
-                          token_budget: int,
-                          preempt: bool = True) -> None:
-        """``preempt=False`` (speculative re-forms, unified step): an
-        allocation that would need a victim is skipped instead — a
-        preempted victim's freed pages could not be restored if the
-        speculative batch invalidates."""
+                          token_budget: int) -> None:
         protect = {it.seq.seq_id for it in items}
         max_seqs = self.config.max_num_seqs
         self.passes += 1
@@ -590,15 +585,10 @@ class Scheduler:
                     continue        # nothing prefillable yet; stay parked
                 avail = min(avail, limit - seq.num_computed_tokens)
             n = self._ssm_align_chunk(seq, min(avail, token_budget))
-            if not preempt:
-                if not self.mm.can_allocate(self.mm.pages_needed(seq, n)):
-                    continue
-                self.mm.allocate_seq_pages(seq, n)
-            else:
-                protect.add(seq.seq_id)
-                if not self._allocate_with_preemption(seq, n, protect):
-                    protect.discard(seq.seq_id)
-                    continue
+            protect.add(seq.seq_id)
+            if not self._allocate_with_preemption(seq, n, protect):
+                protect.discard(seq.seq_id)
+                continue
             items.append(ScheduledSeq(seq, n, seq.num_computed_tokens))
             token_budget -= n
 
@@ -960,8 +950,7 @@ class Scheduler:
 
     # ---- pipelined loop (speculative re-form) -----------------------------
 
-    def schedule_reform(self, prev: ScheduledBatch,
-                        allow_prefill: bool = False
+    def schedule_reform(self, prev: ScheduledBatch
                         ) -> Optional[ScheduledBatch]:
         """Speculatively RE-FORM the next pure-decode batch off ``prev``'s
         *promised* token counts, before ``prev``'s sampled ids have
@@ -982,16 +971,6 @@ class Scheduler:
         alive: the engine invalidates and rebuilds this batch at collect
         time if the assumption breaks.
 
-        ``allow_prefill=True`` (the unified step,
-        docs/overlap_scheduling.md#unified-step): the re-form crosses
-        what used to be the phase boundary — a promised MID-PREFILL row
-        continues its prompt from the promised frontier (its tokens are
-        all host-known: src -1), committed-state prefill work and
-        waiting admissions ride the same batch under the prefill token
-        budget (never preempting), and the result is a MIXED batch the
-        runner dispatches as one unified step — the chain absorbing a
-        prefill chunk instead of breaking.
-
         Returns None with ``reform_fail_reason`` ∈
         spec/shape/pages/pp_budget when re-forming needs host-committed
         state (the caller falls back to the drain-and-sync path and
@@ -1002,7 +981,6 @@ class Scheduler:
             # token VALUES) — same deferral as schedule_chain
             return self._reform_fail("spec")
         base: List[Tuple[Sequence, int, int]] = []   # (seq, cn0, src row)
-        prefill_cont: List[Tuple[Sequence, int]] = []  # (seq, frontier)
         for i, it in enumerate(prev.items):
             seq = it.seq
             if (seq.seq_id == HOLE_SEQ_ID
@@ -1015,15 +993,7 @@ class Scheduler:
                 # reform that skipped the row forever would leak it
                 return self._reform_fail("shape")
             if it.computed_before + it.num_new_tokens < seq.num_tokens:
-                if not allow_prefill or seq.disagg_prefill_limit is not None:
-                    return self._reform_fail("shape")   # mid-prefill row
-                # unified step: continue the prompt from the promised
-                # frontier — every input token is host-known, no promise
-                # is made for this row (a divergence invalidating this
-                # entry unwinds it through the ordinary cascade)
-                prefill_cont.append(
-                    (seq, it.computed_before + it.num_new_tokens))
-                continue
+                return self._reform_fail("shape")   # mid-prefill row
             sp = seq.sampling_params
             if (sp.repetition_penalty != 1.0 or sp.presence_penalty != 0.0
                     or sp.frequency_penalty != 0.0):
@@ -1073,11 +1043,7 @@ class Scheduler:
                     or sp.frequency_penalty != 0.0):
                 return self._reform_fail("shape")
             base.append((s, s.num_computed_tokens, -1))
-        if not base and not (allow_prefill
-                             and (prefill_cont or self.waiting
-                                  or any(s.num_remaining_tokens > 1
-                                         and not s.num_in_flight
-                                         for s in self.running))):
+        if not base:
             return self._reform_fail("shape")   # nothing left to run
         base = base[:budget]
         page = self.mm.page_size
@@ -1094,33 +1060,6 @@ class Scheduler:
             self.mm.allocate_seq_pages(seq, cover)
             items.append(ScheduledSeq(seq, 1, cn0))
             src_rows.append(src)
-        if allow_prefill:
-            # ---- across the phase boundary (unified step) ----
-            pf_budget = self._prefill_token_budget()
-            max_seqs = self.config.max_num_seqs
-            # promised mid-prefill rows continue from their frontier
-            for seq, frontier in prefill_cont:
-                if pf_budget <= 0 or len(items) >= max_seqs:
-                    continue
-                n = min(seq.num_tokens - frontier, pf_budget)
-                need = max(0, cdiv(frontier + n, page)
-                           - len(seq.page_table))
-                if not self.mm.can_allocate(need):
-                    continue     # never preempt; the row waits a pass
-                self.mm.allocate_seq_pages(
-                    seq, frontier + n - seq.num_computed_tokens)
-                items.append(ScheduledSeq(seq, n, frontier))
-                src_rows.append(-1)
-                pf_budget -= n
-            # committed-state prefill work: the SAME admission path the
-            # sync loop runs (running continuations + waiting-queue
-            # admissions, budget/ratio/span bookkeeping included), minus
-            # preemption
-            before = len(items)
-            self._schedule_prefill(items, pf_budget, preempt=False)
-            src_rows += [-1] * (len(items) - before)
-            if not items:
-                return self._reform_fail("shape")
         for it in items:
             it.seq.num_in_flight += 1
         return ScheduledBatch(items, src_rows=src_rows)

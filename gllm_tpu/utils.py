@@ -262,8 +262,8 @@ def tpu_compiler_options() -> dict:
 
     Scoped-VMEM limit: XLA's default 16 MiB scope can't hold a Pallas
     attention kernel's buffers plus an operand/result XLA chooses to stage
-    in VMEM (the v5e compiler asks 17-21 MiB for the ragged and unified
-    kernels at the serving buckets; tests/test_tpu_compile.py pins the
+    in VMEM (the v5e compiler asks 17-21 MiB for the ragged kernel at the
+    serving buckets; tests/test_tpu_compile.py pins the
     refusal). v5e cores carry 128 MiB of VMEM; 64 MiB leaves ample
     headroom. Passed via jit(compiler_options=...) so a process whose
     XLA_FLAGS are parsed by a CPU-only XLA never sees a TPU-only flag."""

@@ -330,8 +330,7 @@ def test_server_info_advertises_topology_and_fast_path(server):
     assert (par["pp"], par["dp"], par["tp"]) == (1, 1, 1)
     assert par["stage_layers"] is None
     assert set(par["fast_path"]) == {"overlap_scheduling",
-                                     "pipelined_loop", "unified_step",
-                                     "spec_fused"}
+                                     "pipelined_loop", "spec_fused"}
     # the device as jax reports it (chip_smoke.py's jax-free parent
     # reads its verdict's device block from here)
     assert info["device"] == {"platform": "cpu", "kind": "cpu",
@@ -353,7 +352,7 @@ def test_server_info_pp_stage_layers(tmp_path):
             tmp_path, safe_serialization=True)
     cfg = EngineConfig(
         model=str(tmp_path), dtype="float32", max_model_len=128,
-        overlap_scheduling=True, unified_step=True, pipelined_loop=True,
+        overlap_scheduling=True, pipelined_loop=True,
         cache=CacheConfig(page_size=4, num_pages=128),
         parallel=ParallelConfig(pp=2))
     llm = LLM(config=cfg, tokenizer=StubTokenizer())
@@ -367,7 +366,7 @@ def test_server_info_pp_stage_layers(tmp_path):
         assert par["pp"] == 2
         assert par["stage_layers"] == [[0, 2], [2, 4]]
         fp = par["fast_path"]
-        assert fp["unified_step"] and fp["pipelined_loop"]
+        assert fp["overlap_scheduling"] and fp["pipelined_loop"]
         assert not fp["spec_fused"]
     finally:
         httpd.shutdown()

@@ -485,7 +485,6 @@ def test_what_it_cannot_have_yet_is_refused_by_name():
             (dict(cache=CacheConfig(kv_cache_dtype="int8")),
              "--kv-cache-dtype int8"),
             (dict(spec_decode="ngram"), "--spec-decode"),
-            (dict(unified_step=True), "--unified-step"),
             (dict(multi_step_decode=4), "fused multi-step decoding")):
         with pytest.raises(ValueError, match="paged pool.*" + named):
             LLM(config=EngineConfig(**base, **kw), model_cfg=cfg)
@@ -497,10 +496,13 @@ def test_what_it_cannot_have_yet_is_refused_by_name():
     with pytest.raises(ValueError, match="keep rings.*prefix-caching"):
         refuse_for_rings(EngineConfig(**base, cache=CacheConfig(
             enable_prefix_caching=True)))
-    # under a tp shard context or the unified kernel the window is refused,
-    # not dropped
-    from gllm_tpu.ops.attention import paged_attention
+    # under a tp shard context the window is refused, not dropped
+    from gllm_tpu.ops import attention
     q, kc, vc, md = _paged_case(np.random.default_rng(3), [1], [30])
-    with pytest.raises(NotImplementedError, match="window"):
-        paged_attention(q, kc, vc, md, scale=1.0, max_q_len=1,
-                        impl="unified", window=8)
+    attention.set_shard_context(object())
+    try:
+        with pytest.raises(NotImplementedError, match="window"):
+            attention.paged_attention(q, kc, vc, md, scale=1.0,
+                                      max_q_len=1, impl="pallas", window=8)
+    finally:
+        attention.set_shard_context(None)

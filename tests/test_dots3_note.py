@@ -251,7 +251,6 @@ def test_selection_that_takes_everything_equals_the_dense_latent_path(impl):
     (dict(cache=dict(enable_prefix_caching=True)), "prefix-caching"),
     (dict(cache=dict(kv_host_pool_gb=0.5)), "host or disk KV tier"),
     (dict(spec_decode="ngram"), "spec-decode"),
-    (dict(unified_step=True), "unified-step"),
     (dict(multi_step_decode=4), "multi-step"),
     (dict(ondevice_finish=True, overlap_scheduling=True), "multi-step"),
     (dict(parallel=ParallelConfig(tp=2)), "tp / pp / dp"),

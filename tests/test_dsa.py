@@ -293,7 +293,6 @@ def test_rows_path_follows_the_resolved_impl_and_the_mesh():
     from gllm_tpu.models.deepseek import dsa_rows_path
     assert dsa_rows_path("pallas") == "kernel"
     assert dsa_rows_path("xla") == "xla"
-    assert dsa_rows_path("unified") == "xla"
     assert dsa_rows_path("pallas", meshed=True) == "xla"
     assert dsa_rows_path("pallas", meshed=False) == "kernel"
     import jax

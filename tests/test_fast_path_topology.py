@@ -1,16 +1,15 @@
-"""Fast path × topology (ISSUE 20): unified + pipelined across pp / dp.
+"""Fast path × topology (ISSUE 20): the pipelined loop across pp / dp.
 
 The oracle is the same one every other distributed mode answers to
 (tests/test_pipeline_parallel.py): byte-identity of greedy AND seeded
 token streams against the pp=1/dp=1 runs — here under arrival/finish
-churn with ``--unified-step --pipelined-loop`` on, on the forced
+churn with ``--pipelined-loop`` on, on the forced
 multi-device CPU host platform. Flag-off must stay byte-identical to
 the legacy sync pipeline (the lift cannot perturb the default path).
 
-Per-stage throttled unified batches: with ``token_throttling`` + pp=2
-every stage's dispatch rides the unified family (pp_stage events carry
-``family="unified_step"`` on EVERY stage index) and the engine records
-``kind="unified_step"`` step events; the re-form refusal class the
+Per-stage throttled batches: with ``token_throttling`` + pp=2 every
+stage's dispatch carries its microbatch's family (pp_stage events hold
+``family`` on EVERY stage index); the re-form refusal class the
 per-microbatch decode budget introduces (``pp_budget``) gets its own
 reason string and loop_stall steptrace row
 (docs/overlap_scheduling.md#topology-matrix).
@@ -49,7 +48,7 @@ def make_llm(ckpt, *, pp=1, dp=1, tp=1, fast=True,
              method="chunked_prefill", num_pages=256):
     cfg = EngineConfig(
         model=ckpt, dtype="float32", max_model_len=128, max_num_seqs=8,
-        overlap_scheduling=fast, unified_step=fast, pipelined_loop=fast,
+        overlap_scheduling=fast, pipelined_loop=fast,
         overlap_depth=2,
         scheduler=SchedulerConfig(schedule_method=method,
                                   max_prefill_tokens=32,
@@ -105,8 +104,8 @@ def _count_reforms(llm):
     for sch in llm.schedulers:
         orig = sch.schedule_reform
 
-        def spy(prev, allow_prefill=False, _orig=orig):
-            out = _orig(prev, allow_prefill=allow_prefill)
+        def spy(prev, _orig=orig):
+            out = _orig(prev)
             if out is not None:
                 state["reforms"] += 1
             return out
@@ -121,7 +120,7 @@ def _count_reforms(llm):
 # Each churn arm compiles a fresh engine, so these run tens of seconds on
 # the forced-host-device CPU platform.  Tier-1 keeps one e2e identity run
 # per topology axis (dp2 greedy here; pp2 identity rides
-# test_pp_budget_refusal_records_stall_row and the throttled-unified test);
+# test_pp_budget_refusal_records_stall_row and the throttled test);
 # the rest are `slow` — run explicitly with `-m slow` or no marker filter.
 # ---------------------------------------------------------------------------
 
@@ -154,35 +153,34 @@ def test_dp2_fast_path_byte_identical(ckpt, multi_device_cpu, seeded):
 
 @pytest.mark.slow
 def test_pp2_tp2_fast_path_byte_identical(ckpt, multi_device_cpu):
-    """pp×tp grid under the fast path: the unified/pipelined lift rides
-    the per-stage tp shard_map unchanged."""
+    """pp×tp grid under the fast path: the pipelined lift rides the
+    per-stage tp shard_map unchanged."""
     base, _ = churn(ckpt, pp=1, fast=False)
     fast, _ = churn(ckpt, pp=2, tp=2, fast=True)
     assert fast == base
 
 
 # ---------------------------------------------------------------------------
-# per-stage throttled unified batches (token_throttling + pp)
+# per-stage throttled batches (token_throttling + pp)
 # ---------------------------------------------------------------------------
 
-def test_pp2_throttled_unified_on_every_stage(ckpt, multi_device_cpu):
-    """token_throttling + pp=2 + unified step: every collected engine
-    step records kind="unified_step" and every pipeline stage's dispatch
-    rides the unified family — no stage falls back to the split
-    decode/prefill program families."""
+def test_pp2_throttled_family_on_every_stage(ckpt, multi_device_cpu):
+    """token_throttling + pp=2 + the pipelined loop: the stream is the
+    single runner's, and every pipeline stage's dispatch event carries
+    the family of the microbatch it ran."""
     base, _ = churn(ckpt, pp=1, fast=False, method="token_throttling")
     mark = TRACE.mark()
     fast, llm = churn(ckpt, pp=2, fast=True, method="token_throttling")
     assert fast == base
     ev = TRACE.events(since=mark)
     s = summarize(ev)
-    step_kinds = set(s["by_kind"]) - {"fused_block"}
-    assert step_kinds == {"unified_step"}, s["by_kind"]
+    assert set(s["by_kind"]) <= {"prefill", "decode"}, s["by_kind"]
     stage_ev = [e for e in ev if e.get("kind") == "pp_stage"]
     assert stage_ev, "no per-stage dispatch events recorded"
     assert {e["stage"] for e in stage_ev} == {0, 1}
-    assert all(e["family"] == "unified_step" for e in stage_ev), \
-        {(e["stage"], e["family"]) for e in stage_ev}
+    for stage in (0, 1):
+        assert {e["family"] for e in stage_ev if e["stage"] == stage} \
+            == {"prefill", "decode"}
     # per-stage in-flight gauge drained back to zero with the pipeline
     assert llm.runner._mb_inflight == 0
 
@@ -213,7 +211,7 @@ def test_reform_refuses_over_budget_rows(ckpt, multi_device_cpu):
     assert len(prev.items) == 2          # cdiv(4 decode, pp=2)
     # the two seqs the OTHER microbatch owns finish → n_decode halves
     sched.running = [s for s in sched.running if s.num_in_flight]
-    assert sched.schedule_reform(prev, allow_prefill=True) is None
+    assert sched.schedule_reform(prev) is None
     assert sched.reform_fail_reason == "pp_budget"
     sched.discard_batch(prev)
     assert all(s.num_in_flight == 0 for s in seqs)
@@ -230,11 +228,11 @@ def test_pp_budget_refusal_records_stall_row(ckpt, multi_device_cpu):
         state = {"fired": 0}
         orig = llm.scheduler.schedule_reform
 
-        def spy(prev, allow_prefill=False):
+        def spy(prev):
             if state["fired"] < 2 and len(prev.items) >= 2:
                 state["fired"] += 1
                 return llm.scheduler._reform_fail("pp_budget")
-            return orig(prev, allow_prefill=allow_prefill)
+            return orig(prev)
 
         llm.scheduler.schedule_reform = spy
         return state

@@ -31,10 +31,6 @@ def test_builtin_defaults():
     _reset_caches()
     assert tuning.get("ragged") == {"q_block": 128, "kv_block": 256}
     assert tuning.get("decode") == {"kv_block": 256}
-    # the unified mixed-batch kernel (--unified-step) resolves its own
-    # geometry: block sizes + the decode-class DMA interleave depth
-    assert tuning.get("unified") == {"q_block": 128, "kv_block": 256,
-                                     "group": 4}
 
 
 def test_env_override_layering(tmp_path, monkeypatch):
@@ -193,18 +189,8 @@ def test_sweep_bodies_close_over_no_buffers():
     kt = _load_kernel_tune()
     run_r, args_r = kt.build_ragged(64, 64, T=128, S=4, ctx=256)
     run_d, args_d, _ = kt.build_decode(64, gsz=2, S=8, ctx=256)
-    run_u, args_u = kt.build_unified(64, 64, gsz=2, mix="balanced",
-                                     shrink=True)
     for name, run, args in (("ragged", run_r, args_r),
-                            ("decode", run_d, args_d),
-                            ("unified", run_u, args_u)):
-        # the unified workload's token axis must include the verify
-        # class (fused-speculation q_len=spec_k+1 rows priced by the
-        # sweep — ISSUE 13); structural check rides the closure trace
-        if name == "unified":
-            nd, nv, chunks = 8, 4, (32, 32)   # shrink "balanced"
-            assert args[0].shape[0] == (nd + nv * kt.VERIFY_Q
-                                        + sum(chunks)), args[0].shape
+                            ("decode", run_d, args_d)):
         # the caches must be in the argument list (the decode body also
         # takes its lengths and page table there)...
         assert len(args) == (5 if name == "decode" else 3), name

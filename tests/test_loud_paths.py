@@ -229,7 +229,6 @@ def test_gdn_and_attention_choose_their_kernels_apart(monkeypatch, caplog):
     assert "full attention -> pallas" in caplog.text
     assert "GDN recurrent step and chunk scan -> pallas" in caplog.text
     assert gdn_impl_for("pallas", False) == "pallas"
-    assert gdn_impl_for("unified", False) == "pallas"
     # the slot pool is sharded under tp, the kernel is not partitioned
     assert gdn_impl_for("pallas", True) == "xla"
     assert gdn_impl_for("xla", False) == "xla"

@@ -158,7 +158,7 @@ def test_mixed_step_rows_counter(rows, impl, want):
         items.append(ScheduledSeq(SimpleNamespace(seq_id=len(items)), n, 0,
                                   draft_tokens=(0,) * drafts))
     batch = ScheduledBatch(items)
-    engine = SimpleNamespace(runner=SimpleNamespace(fwd_attn_impl=impl),
+    engine = SimpleNamespace(runner=SimpleNamespace(attn_impl=impl),
                              tracing=False)
     read = lambda: tuple(llm._M_MIXED_ROWS.get(kernel=k)
                          for k in ("decode", "ragged"))

@@ -570,7 +570,7 @@ def test_a_mesh_is_refused_and_the_recurrent_fences_hold():
                         "ep_share": {"chips": 2, "n_routed_experts": 8}})
     # the fences of recurrent state read one property, which is true here
     llm = _llm()
-    assert llm.runner.builder.use_ssm and not llm.runner.builder.unified
+    assert llm.runner.builder.use_ssm
     assert not llm.runner.spec_fused
     assert llm.scheduler._chunk_rows_cap == (32 + 8) // 16
     assert llm.memory_manager.ssm_chunk == 16

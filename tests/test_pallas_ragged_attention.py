@@ -13,7 +13,8 @@ import jax.numpy as jnp
 from gllm_tpu.ops.attention import (AttentionMetadata, _paged_attention,
                                     _xla_paged_attention)
 from gllm_tpu.ops.pallas.paged_kv import heads_a_load
-from gllm_tpu.ops.pallas.ragged_attention import (block_form,
+from gllm_tpu.ops.pallas.ragged_attention import (_decode_prefix_len,
+                                                  block_form,
                                                   ragged_paged_attention)
 
 
@@ -91,6 +92,14 @@ SPLIT_CASES = [
     # the int8 cache with its scales
     dict(seqs=[(1, 13), (1, 6), (8, 15)], Hq=4, Hkv=2, D=64, page=4,
          pages=12, int8=True),
+    # ... under rows enough to span decode groups, two chunks and a
+    # padded tail
+    dict(seqs=[(1, k) for k in (3, 9, 14, 6, 30, 8)] + [(5, 9), (7, 7)],
+         pad_seqs=3, Hq=8, Hkv=2, D=32, page=4, pages=40, int8=True),
+    # one KV head with a cache of values of its own: decode rows, a chunk
+    # and a padded tail
+    dict(seqs=[(1, 5), (1, 9), (1, 13), (6, 6)], pad_seqs=3, Hq=4, Hkv=1,
+         D=64, page=4, pages=16),
 ]
 for _case in SPLIT_CASES:
     _case["dispatch"] = True
@@ -305,6 +314,17 @@ def test_a_mixed_batch_holds_both_kernels_under_the_ragged_name():
     assert names(3, q) == ["ragged_paged_attention",
                            "ragged_paged_attention_decode_rows"]
     assert names(1, q[:3]) == ["paged_decode_attention"]
+
+
+def test_decode_prefix_len_derivation():
+    """What the dispatch splits a mixed step by derives from cu_q_lens
+    alone: the decode prefix is the longest run of one-token sequences."""
+    cu = jnp.asarray([0, 1, 2, 3, 8, 9, 9, 9], jnp.int32)  # 3 decode,
+    assert int(_decode_prefix_len(cu, 7)) == 3              # then a chunk
+    cu = jnp.asarray([0, 1, 2, 3, 4, 4, 4], jnp.int32)     # pure decode
+    assert int(_decode_prefix_len(cu, 6)) == 4              # (+ padding)
+    cu = jnp.asarray([0, 5, 6, 7], jnp.int32)               # prefill first
+    assert int(_decode_prefix_len(cu, 3)) == 0
 
 
 def test_q_block_spanning_many_seqs():

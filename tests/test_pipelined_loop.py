@@ -257,8 +257,8 @@ def test_reform_batches_splice_from_device(model_cfg):
     llm = make_llm(model_cfg, pipelined=True)
     reforms = []
     orig = llm.scheduler.schedule_reform
-    def spy(prev, allow_prefill=False):
-        b = orig(prev, allow_prefill=allow_prefill)
+    def spy(prev):
+        b = orig(prev)
         if b is not None:
             reforms.append(b)
         return b

@@ -4,9 +4,12 @@
 A decode step is scheduled, built and placed under the step before it
 and launched from that step's collect, before its output. The contract:
 token streams (and logprobs) are what the plain loop (``enforce_eager``)
-gives, under arrival / finish / abort churn; a request that is on the
+gives, under arrival / finish / abort churn, under a pool so small that
+rows are preempted, and behind a prefix hit; a request that is on the
 caller's intake queue at the collect rides the very next program; a
-token that ends a row drops the prepared step; what a drop leaves behind
+token that ends a row drops the prepared step, and no step is prepared
+behind a row that ends where the host can see it coming (a stop string's
+scan, the row's length); what a drop leaves behind
 is what the plain loop's pass leaves; a fired step places one host array
 where a step built in the gap places two; and no step program is seen
 that the plain loop does not see.
@@ -43,6 +46,8 @@ def _rehearsal_model(name, **over):
 
 
 def _model(family):
+    """The six cells' families at their configurations' rehearsal widths
+    (read from the files, cut to a layer of each kind), and two toys."""
     if family == "dense":
         return ModelConfig(
             architecture="LlamaForCausalLM", vocab_size=512, hidden_size=64,
@@ -52,12 +57,33 @@ def _model(family):
         return _rehearsal_model(
             "olmo-hybrid-7b", num_hidden_layers=4,
             layer_types=["linear_attention"] * 3 + ["full_attention"])
-    return _rehearsal_model(          # a full (DSA) and a windowed layer
-        "dots3-note-prev", num_hidden_layers=2,
-        layer_types=["full_attention", "sliding_attention"])
+    if family == "latent":            # a full (DSA) and a windowed layer
+        return _rehearsal_model(
+            "dots3-note-prev", num_hidden_layers=2,
+            layer_types=["full_attention", "sliding_attention"])
+    if family == "windowed":          # a window of 24 rows in the paged pool
+        return _rehearsal_model(
+            "command-a-plus-05-2026", num_hidden_layers=2,
+            layer_types=["sliding_attention", "full_attention"])
+    if family == "mamba":             # Mamba-2, experts, attention: one each
+        return _rehearsal_model(
+            "nemotron-3-nano-30b-a3b", num_hidden_layers=3,
+            hybrid_override_pattern="ME*")
+    if family == "dense_latent":      # a dense and an expert layer under MLA
+        return _rehearsal_model("a.x-k1", num_hidden_layers=2)
+    assert family == "qwen3next"      # a GDN head alone in a slot, one full
+    from test_hybrid_qwen3next import BASE
+    return from_hf_config(dict(
+        BASE, architectures=["Qwen3NextForCausalLM"], vocab_size=512,
+        num_hidden_layers=2,
+        layer_types=["linear_attention", "full_attention"]))
 
 
-def make_llm(family, eager=False, prefix_cache=False):
+# served under the prefix cache, as their cells are
+PREFIX_CACHED = ("windowed", "dense_latent")
+
+
+def make_llm(family, eager=False, prefix_cache=False, num_pages=256):
     """Rows, pages and a mixed step's tokens held at their largest, as a
     server that runs full holds them: two step programs a sampling mode
     (decode, mixed), so that the file's compiles stay a few seconds."""
@@ -67,21 +93,36 @@ def make_llm(family, eager=False, prefix_cache=False):
         scheduler=SchedulerConfig(max_prefill_tokens=32, max_decode_seqs=8,
                                   min_row_bucket=8, min_page_bucket=32,
                                   min_token_bucket=64),
-        cache=CacheConfig(page_size=4, num_pages=256,
+        cache=CacheConfig(page_size=4, num_pages=num_pages,
                           enable_prefix_caching=prefix_cache)),
         model_cfg=_model(family))
 
 
 @pytest.fixture(scope="module")
 def engines():
-    """One engine a family, built on first use and kept."""
+    """One engine a family and set-up, built on first use and kept.
+    ``twin``: a second one of the same, for the plain arm where an arm
+    leaves something behind that the next would meet (cached pages under
+    the prefix cache; the admission ratio a preemption resets): each arm
+    has its own engine, and both see the same history."""
     made = {}
 
-    def get(family):
-        if family not in made:
-            made[family] = make_llm(family)
-        return made[family]
+    def get(family, twin=False, **kw):
+        kw.setdefault("prefix_cache", family in PREFIX_CACHED)
+        key = (family, twin, *sorted(kw.items()))
+        if key not in made:
+            made[key] = make_llm(family, **kw)
+        return made[key]
     return get
+
+
+def both_arms(engines, family, twins=None, **kw):
+    """(the engine of the plain arm, the engine of the prepared arm): one
+    engine, or twins (under the prefix cache, unless said otherwise)."""
+    llm = engines(family, **kw)
+    if twins is None:
+        twins = llm.config.cache.enable_prefix_caching
+    return (engines(family, twin=True, **kw) if twins else llm), llm
 
 
 @contextlib.contextmanager
@@ -245,21 +286,25 @@ class Driver:
                     self.seqs[k].output_logprobs) for k in self.seqs}
 
 
+def request(key, prompt, max_tokens, seeded, seed):
+    """A script's ``add`` event: greedy, or seeded draws; top-2 logprobs
+    either way (one ``k``, so one program a kind of step)."""
+    kw = dict(max_tokens=max_tokens, ignore_eos=True, logprobs=2)
+    sp = (SamplingParams(temperature=0.8, top_p=0.9, seed=seed, **kw)
+          if seeded else SamplingParams(temperature=0.0, **kw))
+    return ("add", key, [int(t) for t in prompt], sp)
+
+
 def churn_script(seeded):
     """Six requests over forty passes: arrivals under a running step and
     inside a collect; a prompt of two chunks; lengths that end rows at
     different steps; two aborts (one seen before the next step is
-    prepared, one after); top-2 logprobs on every request (one ``k``, so
-    one program a kind of step)."""
+    prepared, one after)."""
     rng = np.random.default_rng(11)
 
     def req(key, n_prompt, max_tokens):
-        kw = dict(max_tokens=max_tokens, ignore_eos=True, logprobs=2)
-        sp = (SamplingParams(temperature=0.8, top_p=0.9, seed=70 + n_prompt,
-                             **kw)
-              if seeded else SamplingParams(temperature=0.0, **kw))
-        return ("add", key,
-                [int(t) for t in rng.integers(2, 500, size=n_prompt)], sp)
+        return request(key, rng.integers(2, 500, size=n_prompt), max_tokens,
+                       seeded, 70 + n_prompt)
 
     return {
         0: {"before": [req("a", 9, 30), req("b", 5, 14)]},
@@ -272,8 +317,42 @@ def churn_script(seeded):
     }
 
 
+def pressure_script(seeded):
+    """Five requests that grow to 47 pages between them in a pool of 32:
+    rows are preempted in mid-run, and taken up again from their first
+    token."""
+    rng = np.random.default_rng(23)
+
+    def req(key, n_prompt, max_tokens):
+        return request(key, rng.integers(2, 500, size=n_prompt), max_tokens,
+                       seeded, 40 + n_prompt)
+
+    return {
+        0: {"before": [req("a", 9, 30), req("b", 6, 28), req("c", 7, 26)]},
+        4: {"seam": [req("d", 5, 30)]},
+        9: {"collect": [req("e", 8, 24)]},
+    }
+
+
+def prefix_script():
+    """Two requests that share eight whole pages: the second comes when
+    the first one's prompt is computed, and hits them."""
+    rng = np.random.default_rng(31)
+    doc = list(rng.integers(2, 500, size=40))
+    return {
+        0: {"before": [request("a", doc, 16, False, 0)]},
+        8: {"seam": [request("b", doc[:32] + list(rng.integers(2, 500,
+                                                               size=6)),
+                             12, False, 0)]},
+    }
+
+
+FAMILIES = ["dense", "hybrid", "latent", "windowed", "mamba", "dense_latent",
+            "qwen3next"]
+
+
 @pytest.mark.parametrize("seeded", [False, True], ids=["greedy", "seeded"])
-@pytest.mark.parametrize("family", ["dense", "hybrid", "latent"])
+@pytest.mark.parametrize("family", FAMILIES)
 def test_streams_and_bookkeeping_match_the_plain_loop(engines, family,
                                                       seeded):
     """Pass for pass the two loops collect the same step, so at every
@@ -282,15 +361,15 @@ def test_streams_and_bookkeeping_match_the_plain_loop(engines, family,
     request joins the program it joins there; streams, finish reasons
     and logprobs are equal; no step program is seen that the plain loop
     does not see."""
-    llm = engines(family)
+    plain, llm = both_arms(engines, family)
     sigs = llm.runner._seen_sigs
-    with plain_order(llm):
-        sigs.clear()
+    with plain_order(plain):
+        plain.runner._seen_sigs.clear()
         before = prepared_counts()
-        want = Driver(llm)
+        want = Driver(plain)
         base = want.run(churn_script(seeded))
         assert prepared_counts() == before      # the plain arm prepares none
-        plain_sigs = set(sigs)
+        plain_sigs = set(plain.runner._seen_sigs)
     sigs.clear()
     got = Driver(llm)
     outs = got.run(churn_script(seeded))
@@ -310,6 +389,66 @@ def test_streams_and_bookkeeping_match_the_plain_loop(engines, family,
         eager = Driver(make_llm("dense", eager=True))
         assert eager.run(churn_script(seeded)) == base
         assert eager.seams == want.seams
+
+
+@pytest.mark.parametrize("seeded", [False, True], ids=["greedy", "seeded"])
+@pytest.mark.parametrize("family", ["dense", "mamba"])
+def test_a_pool_too_small_preempts_in_the_plain_order(engines, family,
+                                                      seeded):
+    """``schedule_chain`` refuses where there is no page without a
+    preemption, so nothing is prepared behind such a step: the pass after
+    it preempts in the plain order, as it always has, and steps are
+    prepared again once the pool has room. The streams are the plain
+    loop's. The ROW preempted need not be: a pass that launches a
+    prepared step does not turn the decode rows' rotation
+    (``Scheduler._decode_offset``), so the plain pass that finds the
+    pool full protects the rows in another order. The logprobs then come
+    from steps of other rows, equal to float32's last digits. On
+    ``mamba`` a preempted row gives its slot of recurrent state back and
+    computes it again from its first token."""
+    plain, llm = both_arms(engines, family, twins=True, num_pages=32)
+    with plain_order(plain):
+        n0 = plain.scheduler.num_preemptions
+        base = Driver(plain).run(pressure_script(seeded))
+        assert plain.scheduler.num_preemptions > n0
+    before, n0 = prepared_counts(), llm.scheduler.num_preemptions
+    outs = Driver(llm).run(pressure_script(seeded))
+    assert llm.scheduler.num_preemptions > n0
+    assert growth(before)["fired"] >= 20
+    assert growth(before)["dropped_arrival"] == 2
+    for key, (tokens, reason, lps) in outs.items():
+        assert (tokens, reason) == base[key][:2] == (tokens, "length")
+        for (lp, top, top_lp), (lp0, top0, top_lp0) in zip(lps,
+                                                           base[key][2]):
+            assert top == top0
+            np.testing.assert_allclose([lp, *top_lp], [lp0, *top_lp0],
+                                       rtol=0, atol=1e-4)
+
+
+@pytest.mark.parametrize("family", ["dense", "windowed", "dense_latent"])
+def test_a_prefix_hit_starts_a_row_the_plain_loop_starts(engines, family):
+    """A request whose prompt begins with whole cached pages joins the
+    running step with those pages claimed, in the program it joins in
+    the plain loop, and the steps around it are prepared."""
+    plain, llm = both_arms(engines, family, prefix_cache=True)
+
+    def run(engine):
+        """(the driver, its streams, the tokens hit in the cache)"""
+        hit = engine.memory_manager.hit_tokens
+        driver = Driver(engine)
+        outs = driver.run(prefix_script())
+        return driver, outs, engine.memory_manager.hit_tokens - hit
+
+    with plain_order(plain):
+        want, base, hit = run(plain)
+    before = prepared_counts()
+    got, outs, hit_prepared = run(llm)
+    assert outs == base
+    assert hit == hit_prepared == 32
+    assert got.seams == want.seams
+    assert got.first_program("b") == want.first_program("b")
+    grew = growth(before)
+    assert grew["fired"] >= 15 and grew["dropped_arrival"] == 1
 
 
 @pytest.mark.parametrize("how", ["eos", "stop_id"])
@@ -343,6 +482,61 @@ def test_a_token_that_ends_a_row_drops_the_prepared_step(engines, how):
     # step, whose one row samples); the one behind the last token was
     # dropped
     assert growth(before) == {"fired": len(cut) - 1, "dropped_finish": 1,
+                              "dropped_arrival": 0, "dropped_other": 0}
+
+
+class _Letters:
+    """A token is a letter: what the stop-string scan needs of one."""
+    eos_token_id = None
+
+    def decode(self, ids, skip_special_tokens=False):
+        return "".join(chr(97 + i % 26) for i in ids)
+
+
+def test_no_step_is_prepared_behind_a_row_under_a_stop_string(engines):
+    """The scan for a stop string runs on the host, over text that is
+    only there after the collect: while such a row runs nothing is
+    prepared, and the stream ends where the plain loop's ends."""
+    llm = engines("dense")
+    prompt = [int(t) for t in np.random.default_rng(6).integers(2, 60, 8)]
+
+    def run(**kw):
+        return Driver(llm).run({0: {"before": [("add", "x", prompt,
+                                                SamplingParams(
+            temperature=0.0, max_tokens=40, ignore_eos=True, **kw))]}})["x"]
+
+    llm.tokenizer = _Letters()
+    try:
+        with plain_order(llm):
+            text = llm.tokenizer.decode(run()[0])
+            stop = text[9:12]
+            base = run(stop=[stop])
+        before = prepared_counts()
+        got = run(stop=[stop])
+    finally:
+        llm.tokenizer = None
+    assert got == base and got[1] == "stop"
+    assert len(got[0]) == text.index(stop) + len(stop) <= 12
+    assert growth(before) == dict.fromkeys(OUTCOMES, 0)
+
+
+def test_no_step_is_prepared_past_a_rows_length(engines):
+    """``schedule_chain`` refuses a row at its ``max_tokens``: the step
+    that samples its last token has nothing prepared behind it, so there
+    is nothing to drop."""
+    llm = engines("dense")
+    script = {0: {"before": [("add", "x", [int(t) for t in
+                                           np.random.default_rng(8)
+                                           .integers(2, 500, 7)],
+                              SamplingParams(temperature=0.0, max_tokens=12,
+                                             ignore_eos=True, logprobs=2))]}}
+    with plain_order(llm):
+        base = Driver(llm).run(script)
+    before = prepared_counts()
+    assert Driver(llm).run(script) == base
+    assert base["x"][1] == "length" and len(base["x"][0]) == 12
+    # the prompt's step and eleven decode steps, each of them prepared
+    assert growth(before) == {"fired": 11, "dropped_finish": 0,
                               "dropped_arrival": 0, "dropped_other": 0}
 
 

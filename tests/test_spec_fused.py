@@ -184,12 +184,10 @@ def test_fused_eos_and_length_identity(ckpt):
     dict(ondevice_finish=True, decode_slot_batching=True),
     dict(pipelined_loop=True, decode_slot_batching=True,
          ondevice_finish=True),
-    dict(unified_step=True, decode_slot_batching=True,
-         ondevice_finish=True),
-], ids=["plain", "odf", "slots", "odf_slots", "pipelined", "unified"])
+], ids=["plain", "odf", "slots", "odf_slots", "pipelined"])
 def test_fused_composition_matrix(flags):
     """spec_fused × {ondevice_finish, decode_slot_batching,
-    pipelined_loop, unified_step}: greedy byte-identity to the plain
+    pipelined_loop}: greedy byte-identity to the plain
     engine, including EOS, stop-token + min_tokens arming, and the
     max_model_len boundary."""
     base = mk()
@@ -365,15 +363,15 @@ def test_spec_fused_unsupported_topologies_error_loudly():
 
 
 def test_fast_paths_refuse_pp_times_dp():
-    """unified_step / pipelined_loop compose with pp OR dp, not the
-    combined grid — per-combination error, not a silent legacy
-    fallback."""
+    """pipelined_loop (asked for, or lifted from nothing: it lifts
+    overlap_scheduling itself) composes with pp OR dp, not the combined
+    grid — an error, not a silent legacy fallback."""
     from gllm_tpu.config import ParallelConfig
-    for kw in (dict(unified_step=True), dict(pipelined_loop=True)):
-        cfg = EngineConfig(load_format="dummy",
-                           parallel=ParallelConfig(pp=2, dp=2), **kw)
-        with pytest.raises(ValueError, match="pp>1 OR\\s+dp>1"):
-            cfg.validate()
+    cfg = EngineConfig(load_format="dummy", pipelined_loop=True,
+                       parallel=ParallelConfig(pp=2, dp=2))
+    with pytest.raises(ValueError, match="pp>1 OR dp>1"):
+        cfg.validate()
+    assert cfg.overlap_scheduling
 
 
 def test_spec_fused_hybrid_model_errors_in_engine():

@@ -305,16 +305,15 @@ def test_decode_kernel_compiles_for_v5e(topo, on_tpu, geometry):
     assert not copies, copies
 
 
-def _ragged(topo, *, unified: bool, S: int, T: int, **kw):
+def _ragged(topo, *, S: int, T: int, **kw):
     from gllm_tpu.ops.pallas.ragged_attention import ragged_paged_attention
     from gllm_tpu.ops.pallas.tuning import get as tuned
     from gllm_tpu.utils import tpu_compiler_options
-    cfg = tuned("unified" if unified else "ragged")
+    cfg = tuned("ragged")
     q, kc, vc, cu, kv_lens, pt = _kernel_args(topo, S=S, T=T, **kw)
     fn = jax.jit(lambda q, k, v, cu, kl, pt: ragged_paged_attention(
         q, k, v, cu, kl, pt, scale=0.125, q_block=cfg["q_block"],
-        kv_block=cfg["kv_block"], unified=unified,
-        group_size=int(cfg.get("group", 4))),
+        kv_block=cfg["kv_block"]),
         compiler_options=tpu_compiler_options())
     return fn.lower(q, kc, vc, cu, kv_lens, pt).compile()
 
@@ -362,12 +361,7 @@ def test_ragged_kernel_under_several_kv_heads_compiles_for_v5e(
 
 @pytest.mark.slow
 def test_ragged_kernel_compiles_for_v5e(topo, on_tpu):
-    assert has_kernel(_ragged(topo, unified=False, S=8, T=2048))
-
-
-@pytest.mark.slow
-def test_unified_kernel_compiles_for_v5e(topo, on_tpu):
-    assert has_kernel(_ragged(topo, unified=True, S=256, T=2304))
+    assert has_kernel(_ragged(topo, S=8, T=2048))
 
 
 @pytest.mark.slow
@@ -379,7 +373,7 @@ def test_ragged_kernel_needs_the_scoped_vmem_option(topo, on_tpu,
     from gllm_tpu import utils
     monkeypatch.setattr(utils, "tpu_compiler_options", lambda: None)
     with pytest.raises(Exception, match="(?i)vmem|memory"):
-        _ragged(topo, unified=False, S=8, T=2048)
+        _ragged(topo, S=8, T=2048)
 
 
 # ---- whole step programs ---------------------------------------------------
@@ -457,7 +451,7 @@ def test_dense_cell_projections_read_the_stack_in_place(topo, on_tpu,
         assert len(dots) == ndots, dots
 
 
-FAST = dict(overlap_scheduling=True, pipelined_loop=True, unified_step=True,
+FAST = dict(overlap_scheduling=True, pipelined_loop=True,
             decode_slot_batching=True, ondevice_finish=True,
             decode_chain_len=16)
 
@@ -481,12 +475,7 @@ SMOKE_PROGRAMS = {
     "fused_block": (dict(overlap_scheduling=True, decode_chain_len=16,
                          ondevice_finish=True),
                     lambda r: r.step_multi([decode_batch(r, 8, 8)] * 16)),
-    "unified_decode": (FAST, lambda r: r.step_async(decode_batch(r, 8, 8))),
-    "unified_mixed": (FAST, lambda r: r.step_async(
-        prefill_batch(r, 2048, ndecode=7))),
-    "unified_prompt_logprobs": (FAST, lambda r: r.step_async(
-        prefill_batch(r, 2048, prompt_logprobs=1))),
-    "unified_fused_block": (FAST, lambda r: r.step_multi(
+    "fast_fused_block": (FAST, lambda r: r.step_multi(
         [decode_batch(r, 8, 8)] * 16)),
     "spec_fused_block": (dict(FAST, spec_decode="ngram", spec_fused=True),
                          lambda r: r.step_spec_multi(_spec_chain(r))),
@@ -502,12 +491,10 @@ SMOKE_PROGRAMS = {
 @pytest.mark.parametrize("variant", SMOKE_PROGRAMS)
 def test_smoke_programs_compile_for_v5e(topo, on_tpu, monkeypatch, variant):
     """Every other program chip_smoke.py dispatches on one chip; prints
-    the compile seconds of each (set-up cost, ROADMAP A6)."""
+    the compile seconds of each (set-up cost: ROADMAP, "Set-up")."""
     engine_kw, dispatch = SMOKE_PROGRAMS[variant]
     runner = make_runner(smoke_model_cfg(), topo, monkeypatch=monkeypatch,
                          **engine_kw)
-    if "unified_step" in engine_kw:
-        assert runner.fwd_attn_impl == "unified"
     c = compile_of(dispatch, runner)
     assert has_kernel(c.compiled) == (runner.attn_impl == "pallas")
     mem = c.compiled.memory_analysis()
@@ -537,7 +524,7 @@ def test_xla_attention_on_tpu_still_copies_the_whole_pool(topo, on_tpu,
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("kernel", ["decode", "ragged", "unified"])
+@pytest.mark.parametrize("kernel", ["decode", "ragged"])
 def test_int8_kv_kernels_are_still_refused_by_mosaic(topo, on_tpu, kernel):
     """Why kv_cache_dtype=int8 raises on the TPU Pallas path
     (runner._check_kv_quant): Mosaic refuses the per-page scale-row DMA
@@ -558,8 +545,7 @@ def test_int8_kv_kernels_are_still_refused_by_mosaic(topo, on_tpu, kernel):
     else:
         fn = jax.jit(lambda q, k, v, cu, kl, pt, ks, vs:
                      ragged_paged_attention(
-                         q, k, v, cu, kl, pt, scale=0.125,
-                         unified=kernel == "unified", k_scale=ks,
+                         q, k, v, cu, kl, pt, scale=0.125, k_scale=ks,
                          v_scale=vs),
                      compiler_options=tpu_compiler_options())
     with pytest.raises(Exception, match="aligned to tiling"):

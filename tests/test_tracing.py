@@ -173,7 +173,6 @@ def _spy_on(tr):
 class HostEngine:
     tokenizer = None
     model_cfg = None
-    unified = False
 
     def __init__(self, maxp=6, tracing=True, prefix=False):
         from gllm_tpu.engine.llm import LLM
@@ -195,7 +194,7 @@ class HostEngine:
             self.config, make_memory_manager(64, 4, prefix))
         self.tracing = tracing
         self.spans = self.scheduler.spans = SpanTrace()
-        self.runner = type("R", (), {"fwd_attn_impl": "xla"})()
+        self.runner = type("R", (), {"attn_impl": "xla"})()
         self._in_flight = []
         self._next_seq_id = 0
         self.steps = []                 # kind of every step, in order
