@@ -298,11 +298,11 @@ class LLM:
             refuse_for_paged_windows(config)
         if model_cfg.use_mamba and config.parallel.world_size > 1:
             raise ValueError(
-                "a model with Mamba-2 layers (layer_types: mamba) is "
-                "served by one chip: its slot pool and kernels are not "
-                "partitioned and its layer pattern has no period for the "
-                "pp runner's stages; not supported with it: tp / pp / dp "
-                "/ sp > 1")
+                "a model with Mamba-2 layers (layer_types: mamba or "
+                "parallel_hybrid) is served by one chip: its slot pool "
+                "and kernels are not partitioned and the pp runner cuts "
+                "no stages for it (NemotronH's pattern has no period); "
+                "not supported with it: tp / pp / dp / sp > 1")
 
         self.tokenizer = tokenizer
         if self.tokenizer is None and config.model and config.tokenizer != "":

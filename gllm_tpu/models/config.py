@@ -9,7 +9,24 @@ field is static at trace time, which is what jit wants.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
+
+
+class Mup(NamedTuple):
+    """Falcon-H1's muP multipliers, by where the forward pass applies them
+    (models/falcon_h1.py; ``lm_head_multiplier`` is ``logit_scale``):
+    the embedding's rows; k after its projection; the normed stream into
+    the attention and the state-space branch and their outputs; one
+    factor a channel of the in-projection's z | x | B | C | dt; the MLP's
+    gate before its activation and its output."""
+    embedding: float = 1.0
+    key: float = 1.0
+    attention_in: float = 1.0
+    attention_out: float = 1.0
+    ssm_in: float = 1.0
+    ssm_out: float = 1.0
+    ssm: Tuple[float, float, float, float, float] = (1.0,) * 5
+    mlp: Tuple[float, float] = (1.0, 1.0)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -274,7 +291,10 @@ class ModelConfig:
     norm_zero_centered: bool = True
 
     # Mamba-2 state-space layers (NemotronH's ``M`` blocks; layer_types
-    # marks them "mamba", its expert blocks "moe"): ``mamba_num_heads``
+    # marks them "mamba", its expert blocks "moe"; Falcon-H1's layers,
+    # "parallel_hybrid": the Mamba-2 heads and the attention heads read one
+    # normed stream side by side, so a layer owns pages AND a slot and
+    # counts as both kinds): ``mamba_num_heads``
     # heads of ``mamba_head_dim`` channels over a state of
     # ``ssm_state_size``, B and C shared by the heads of each of
     # ``mamba_n_groups`` groups, the chunked rule in chunks of
@@ -298,10 +318,14 @@ class ModelConfig:
     # shared experts' outputs are averaged
     norm_kind: str = "rms"
     logit_scale: float = 1.0
+    # Falcon-H1 (falcon_h1, models/falcon_h1.py): the published muP
+    # multipliers of the forward pass (None elsewhere)
+    mup: Optional[Mup] = None
 
     @property
     def use_mamba(self) -> bool:
-        return "mamba" in self.layer_types
+        return ("mamba" in self.layer_types
+                or "parallel_hybrid" in self.layer_types)
 
     @property
     def use_hybrid(self) -> bool:
@@ -357,12 +381,15 @@ class ModelConfig:
 
     @property
     def num_attn_layers(self) -> int:
-        """Full-attention layers OWNED BY THIS STAGE (= the whole model
-        when un-staged) — sizes the stage's paged-KV stack."""
+        """Layers OWNED BY THIS STAGE (= the whole model when un-staged)
+        that keep rows in the paged KV pool: the full-attention layers,
+        and a "parallel_hybrid" layer, which counts here AND among
+        ``num_linear_layers`` (it owns pages and a slot). Sizes the
+        stage's paged-KV stack."""
         if not self.layer_types:
             return self.num_stage_layers
         return sum(1 for t in self.stage_layer_types
-                   if t == "full_attention")
+                   if t in ("full_attention", "parallel_hybrid"))
 
     @property
     def kv_cache_heads(self) -> int:
@@ -379,7 +406,7 @@ class ModelConfig:
     def num_linear_layers(self) -> int:
         """Layers of this stage that hold recurrent state."""
         return sum(1 for t in self.stage_layer_types
-                   if t in ("linear_attention", "mamba"))
+                   if t in ("linear_attention", "mamba", "parallel_hybrid"))
 
     @property
     def num_moe_layers(self) -> int:
@@ -438,7 +465,8 @@ _ARCH_OF_MODEL_TYPE = {"olmo_hybrid": "OlmoHybridForCausalLM",
                        "dots3_note": "Dots3NoteForCausalLM",
                        "axk1": "AXK1ForCausalLM",
                        "nemotron_h": "NemotronHForCausalLM",
-                       "cohere2_moe": "Cohere2MoeForCausalLM"}
+                       "cohere2_moe": "Cohere2MoeForCausalLM",
+                       "falcon_h1": "FalconH1ForCausalLM"}
 
 # hybrid_override_pattern's letters (NemotronH)
 _NEMOTRON_KINDS = {"M": "mamba", "E": "moe", "*": "full_attention"}
@@ -487,6 +515,55 @@ def _cohere2_moe(hf: Dict[str, Any]):
           "scoring_func": "sigmoid", "topk_method": "none",
           "routed_scaling_factor": 1.0}
     return hf, extra
+
+
+def _falcon_h1(hf: Dict[str, Any]):
+    """config.json of tiiuae/Falcon-H1-34B-Instruct (model_type falcon_h1)
+    -> (the keys ``from_hf_config`` reads, the extra fields). Every layer
+    is the same block: attention heads (GQA, rotary over the whole head,
+    halves rotated) and Mamba-2 heads read ONE RMSNorm of the stream side
+    by side, a SwiGLU MLP behind a second norm; muP multipliers at
+    fourteen places. ``mamba_d_ssm`` = heads x head size is d_inner
+    (``mamba_expand`` and ``mlp_expansion_factor`` are inert keys)."""
+    unserved = [
+        ("attn_layer_indices", None), ("mamba_use_mlp", True),
+        ("mamba_rms_norm", True), ("mamba_norm_before_gate", False),
+        ("mamba_conv_bias", True), ("mamba_proj_bias", False),
+        ("attention_bias", False), ("mlp_bias", False),
+        ("projectors_bias", False), ("hidden_act", "silu"),
+        ("rope_scaling", None), ("tie_word_embeddings", False)]
+    bad = [f"{k}={hf[k]!r}" for k, want in unserved
+           if k in hf and hf[k] != want]
+    heads, size = hf["mamba_n_heads"], hf["mamba_d_head"]
+    if hf.get("mamba_d_ssm", heads * size) != heads * size:
+        bad.append(f"mamba_d_ssm={hf['mamba_d_ssm']} for {heads} heads "
+                   f"of {size}")
+    if bad:
+        raise ValueError(
+            "falcon_h1: models/falcon_h1.py serves the published block ("
+            + ", ".join(f"{k}={w!r}" for k, w in unserved)
+            + ", mamba_d_ssm = mamba_n_heads x mamba_d_head), not "
+            + ", ".join(bad))
+    extra = dict(
+        layer_types=("parallel_hybrid",) * hf["num_hidden_layers"],
+        mamba_num_heads=heads, mamba_head_dim=size,
+        ssm_state_size=hf["mamba_d_state"],
+        mamba_n_groups=hf.get("mamba_n_groups", 1),
+        mamba_chunk_size=hf.get("mamba_chunk_size", 128),
+        linear_conv_kernel_dim=hf.get("mamba_d_conv", 4),
+        logit_scale=hf.get("lm_head_multiplier", 1.0),
+        mup=Mup(
+            embedding=hf.get("embedding_multiplier", 1.0),
+            key=hf.get("key_multiplier", 1.0),
+            attention_in=hf.get("attention_in_multiplier", 1.0),
+            attention_out=hf.get("attention_out_multiplier", 1.0),
+            ssm_in=hf.get("ssm_in_multiplier", 1.0),
+            ssm_out=hf.get("ssm_out_multiplier", 1.0),
+            ssm=tuple(hf.get("ssm_multipliers") or (1.0,) * 5),
+            mlp=tuple(hf.get("mlp_multipliers") or (1.0, 1.0))),
+        attn_output_gate=False, norm_zero_centered=False)
+    # the published base is the integer 100000000000, more than 32 bits
+    return {**hf, "rope_theta": float(hf.get("rope_theta", 1e4))}, extra
 
 
 def from_hf_config(hf: Dict[str, Any]) -> ModelConfig:
@@ -680,6 +757,8 @@ def from_hf_config(hf: Dict[str, Any]) -> ModelConfig:
                   hf.get("moe_shared_expert_intermediate_size", 0)}
     if arch == "Cohere2MoeForCausalLM":
         hf, extra = _cohere2_moe(hf)
+    if arch == "FalconH1ForCausalLM":
+        hf, extra = _falcon_h1(hf)
     share = hf.get("ep_share")
     if share:
         # this repo's own key, read for every family that holds a share of

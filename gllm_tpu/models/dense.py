@@ -131,7 +131,7 @@ def init_params(cfg: ModelConfig, seed: int = 0,
 def _attention(lp, x, batch: StepBatch, k_all, v_all, cfg: ModelConfig,
                cos_sin, *, attn_impl: str, max_q_len: int, li,
                ks_all=None, vs_all=None, use_rope: bool = True,
-               window: Optional[int] = None):
+               window: Optional[int] = None, k_mult: float = 1.0):
     """One layer's attention against the STACKED [L, P, ...] cache.
 
     The cache is addressed through a flat [L*P, ...] view with the layer
@@ -152,7 +152,9 @@ def _attention(lp, x, batch: StepBatch, k_all, v_all, cfg: ModelConfig,
     ``use_rope`` False leaves q and k without a positional term and
     ``window`` bounds what a query attends to its last ``window``
     positions (a model whose layers differ in kind says both per layer:
-    models/cohere2_moe.py); the rows stay in the pages either way."""
+    models/cohere2_moe.py); the rows stay in the pages either way.
+    ``k_mult`` other than 1 multiplies k as it leaves its projection
+    (Falcon-H1's ``key_multiplier``: models/falcon_h1.py)."""
     T = x.shape[0]
     Hq, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     L, P, page_size = k_all.shape[0], k_all.shape[1], k_all.shape[2]
@@ -178,6 +180,8 @@ def _attention(lp, x, batch: StepBatch, k_all, v_all, cfg: ModelConfig,
     # the MLP's and o_proj's do. Held by tests/test_tpu_compile.py
     # (test_dense_cell_projections_read_the_stack_in_place).
     q, k, v = jax.lax.optimization_barrier((q, k, v))
+    if k_mult != 1.0:
+        k = (k.astype(jnp.float32) * k_mult).astype(k.dtype)
     q = shard_hint(q.reshape(T, Hq, D), None, "tp", None)
     k = shard_hint(k.reshape(T, Hkv, D), None, "tp", None)
     v = shard_hint(v.reshape(T, Hkv, D), None, "tp", None)

@@ -370,11 +370,14 @@ def _mamba_chunk_rows(xbc, dt, la, cu, slots, dummy, conv_state, rec_state,
 
 
 def _mamba_layer(lp, u, batch: StepBatch, conv_state, rec_state,
-                 cfg: ModelConfig, *, max_q_len: int, slot_base, impl: str):
+                 cfg: ModelConfig, *, max_q_len: int, slot_base, impl: str,
+                 in_scale=None):
     """One Mamba-2 mixer over the flat ragged batch. conv_state /
     rec_state: the slot pools of ALL this stage's Mamba-2 layers, layers
     and slots on one axis (views of the stacked pools); this layer's slots
-    begin at ``slot_base``, its dummy slot first."""
+    begin at ``slot_base``, its dummy slot first. ``in_scale`` (float32,
+    as wide as the stored in-projection): one factor a channel of z | xBC
+    | dt, applied to the projection's output (models/falcon_h1.py)."""
     T = u.shape[0]
     Nh, Din = cfg.mamba_num_heads, cfg.mamba_d_inner
     slots = batch.ssm_slots + slot_base
@@ -383,6 +386,11 @@ def _mamba_layer(lp, u, batch: StepBatch, conv_state, rec_state,
     zxbcdt = jax.lax.optimization_barrier(qmm(u, lp["in_proj"]))
     conv_dim = cfg.gdn_conv_dim
     z = zxbcdt[:, :Din]
+    if in_scale is not None:
+        # in float32 (xBC and dt go on in float32 anyway); the gate back
+        # in the stream's dtype, which the gated norm's output takes
+        zxbcdt = zxbcdt.astype(jnp.float32) * in_scale
+        z = zxbcdt[:, :Din].astype(u.dtype)
     xbc = zxbcdt[:, Din:Din + conv_dim]
     dt = jax.nn.softplus(
         zxbcdt[:, Din + conv_dim:Din + conv_dim + Nh].astype(jnp.float32)

@@ -169,6 +169,19 @@ def get_model_def(cfg: ModelConfig) -> ModelDef:
             count_rows_read=cohere2_moe.count_rows_read,
             startup_line=cohere2_moe.startup_line,
         )
+    if cfg.architecture in _FALCON_H1_ARCHS:
+        from gllm_tpu.models import falcon_h1
+        return ModelDef(
+            family="falcon_h1",
+            init_params=falcon_h1.init_params,
+            forward=falcon_h1.forward,
+            compute_logits=falcon_h1.compute_logits,
+            make_rope_table=falcon_h1.make_rope_table,
+            load_params=falcon_h1.load_params,
+            init_kv_cache=falcon_h1.init_kv_cache,
+            param_specs=falcon_h1.no_mesh_specs,
+            kv_specs=falcon_h1.no_mesh_specs,
+        )
     raise NotImplementedError(
         f"architecture {cfg.architecture!r} not supported yet; "
         f"dense: {_DENSE_ARCHS}, moe: {_MOE_ARCHS}, mla: {_MLA_ARCHS}, "
@@ -237,6 +250,14 @@ _COHERE2_MOE_ARCHS = (
 )
 
 
+_FALCON_H1_ARCHS = (
+    # tiiuae/Falcon-H1-34B-Instruct (model_type falcon_h1): attention heads
+    # and Mamba-2 heads side by side on one norm in EVERY layer (pages and
+    # a slot a layer), muP multipliers, a SwiGLU MLP (models/falcon_h1.py)
+    "FalconH1ForCausalLM",
+)
+
+
 def supported_architectures() -> Dict[str, str]:
     out = {a: "dense" for a in _DENSE_ARCHS}
     out.update({a: "moe" for a in _MOE_ARCHS})
@@ -247,4 +268,5 @@ def supported_architectures() -> Dict[str, str]:
     out.update({a: "hybrid" for a in _HYBRID_ARCHS})
     out.update({a: "nemotron_h" for a in _NEMOTRON_H_ARCHS})
     out.update({a: "cohere2_moe" for a in _COHERE2_MOE_ARCHS})
+    out.update({a: "falcon_h1" for a in _FALCON_H1_ARCHS})
     return out

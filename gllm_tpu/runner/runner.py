@@ -326,6 +326,18 @@ def pick_kv_pack(cfg: ModelConfig, tp_sharded: bool) -> int:
     return pack
 
 
+def plp_block_rows(tokens: int, vocab: int) -> int:
+    """Rows of a step whose prompt logprobs are computed at a time: all of
+    them while their float32 logits stay under 1.5 GB (every vocabulary up
+    to 183 k at a 2048-token chunk), else the largest divisor of
+    ``tokens`` whose logits stay under 0.6 GB (512 of 2048 and 528 of 2112
+    at a vocabulary of 261120)."""
+    if tokens * vocab * 4 <= 1.5e9:
+        return tokens
+    return max((d for d in range(1, tokens + 1)
+                if tokens % d == 0 and d * vocab * 4 <= 0.6e9), default=1)
+
+
 def build_in_place(make, mesh, specs, device=None):
     """Run the array-building ``make()`` so that every leaf is created
     where it will live: straight into its shards under ``mesh`` (``specs``
@@ -753,32 +765,46 @@ class ModelRunner:
         if model_cfg.use_mamba:
             # a state-space hybrid: what the chip holds, in one line
             slots = 1 + self.ssm_working_slots + self.ssm_snapshot_slots
+            La, page = model_cfg.num_attn_layers, config.cache.page_size
+            held = ("%d of %d routed experts a layer held here" % (
+                model_cfg.num_local_experts, model_cfg.num_experts)
+                if model_cfg.num_experts else "no experts")
+            # a layer that is both kinds (models/falcon_h1.py): the same
+            # layer count sizes the page stack and the slot stack
+            both = (" (%d pages x %d tokens x %d layers x %d B; every layer "
+                    "holds pages AND a slot)" % (
+                        self.num_pages, page, La,
+                        self._kv_bytes_per_page() // (page * La))
+                    if "parallel_hybrid" in model_cfg.layer_types else "")
             logger.info(
-                "[startup] state-space model: weights %d bytes (%d of %d "
-                "routed experts a layer held here); Mamba-2 slot pool %d "
+                "[startup] state-space model: weights %d bytes (%s); "
+                "Mamba-2 slot pool %d "
                 "slots x %d layers x %d bytes as the TPU stores them = %d "
-                "bytes; KV pool of the %d attention layers %d bytes",
-                self.weight_bytes(),
-                model_cfg.num_local_experts, model_cfg.num_experts, slots,
+                "bytes; KV pool of the %d attention layers %d bytes%s",
+                self.weight_bytes(), held, slots,
                 model_cfg.num_linear_layers,
                 self._ssm_pool_bytes() // (model_cfg.num_linear_layers
                                            * slots),
-                self._ssm_pool_bytes(), model_cfg.num_attn_layers,
-                self.num_pages * self._kv_bytes_per_page())
-            # which kernel multiplies the held experts (models/deepseek.
-            # _grouped_dot): it falls back silently otherwise, and a
-            # --quantization run times another kernel than a plain one
-            from gllm_tpu.ops.gdn import gdn_impl_for
-            if gdn_impl_for(self.attn_impl,
-                            config.parallel.tp > 1) != "pallas":
-                experts = "xla ragged_dot (the Mamba-2 kernels run in XLA)"
-            elif config.quantization:
-                experts = ("xla ragged_dot (the Pallas kernel reads plain "
-                           f"stacks, these are {config.quantization})")
-            else:
-                experts = "pallas gmm (ops/pallas/grouped_matmul.py)"
-            logger.info("[startup] held experts: grouped products -> %s",
-                        experts)
+                self._ssm_pool_bytes(), La,
+                self.num_pages * self._kv_bytes_per_page(), both)
+            if model_cfg.num_experts:
+                # which kernel multiplies the held experts (models/
+                # deepseek._grouped_dot): it falls back silently otherwise,
+                # and a --quantization run times another kernel than a
+                # plain one
+                from gllm_tpu.ops.gdn import gdn_impl_for
+                if gdn_impl_for(self.attn_impl,
+                                config.parallel.tp > 1) != "pallas":
+                    experts = ("xla ragged_dot (the Mamba-2 kernels run in "
+                               "XLA)")
+                elif config.quantization:
+                    experts = ("xla ragged_dot (the Pallas kernel reads "
+                               f"plain stacks, these are "
+                               f"{config.quantization})")
+                else:
+                    experts = "pallas gmm (ops/pallas/grouped_matmul.py)"
+                logger.info("[startup] held experts: grouped products -> "
+                            "%s", experts)
         if model_cfg.dense_mla:
             # dense latent attention: what the chip holds, in one line
             # beside the line that says which kernel serves which kind of
@@ -1106,11 +1132,27 @@ class ModelRunner:
                 # next tokens (targets built host-side; pad rows target 0).
                 from gllm_tpu.models.dense import compute_full_logits
                 from gllm_tpu.ops.sampling import compute_logprobs
-                full_logits = compute_full_logits(params, hidden,
-                                                  residual, cfg_)
-                aux["plp"] = compute_logprobs(full_logits,
-                                              batch.plp_targets,
-                                              max(logprobs_k, 1))
+
+                def rows_lp(h, r, targets):
+                    return compute_logprobs(
+                        compute_full_logits(params, h, r, cfg_), targets,
+                        max(logprobs_k, 1))
+                T = hidden.shape[0]
+                rows = plp_block_rows(T, cfg_.vocab_size)
+                if rows == T:
+                    aux["plp"] = rows_lp(hidden, residual,
+                                         batch.plp_targets)
+                else:
+                    # a block of rows at a time: the logits of a whole
+                    # chunk never exist (2048 x 261120 in float32 are 2 GB
+                    # beside their bf16 product's 1 GB)
+                    out = jax.lax.map(
+                        lambda a: rows_lp(*a),
+                        tuple(x.reshape((T // rows, rows) + x.shape[1:])
+                              for x in (hidden, residual,
+                                        batch.plp_targets)))
+                    aux["plp"] = jax.tree.map(
+                        lambda a: a.reshape((T,) + a.shape[2:]), out)
             return aux
 
         @functools.partial(jax.jit,

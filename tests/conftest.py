@@ -156,10 +156,12 @@ _LONGEST_FIRST = (
     "test_dsa.py", "perfbench/test_rehearsal_olmo_hybrid.py",
     "test_pallas_decode_attention.py", "test_hybrid_qwen3next.py",
     "test_hybrid_olmo.py", "test_prepared_launch.py",
-    "perfbench/test_reference_nemotron_h.py", "test_spec_fused.py",
+    "perfbench/test_reference_nemotron_h.py", "test_falcon_h1.py",
+    "test_spec_fused.py",
     "perfbench/test_reference_olmo_hybrid.py", "test_pipeline_parallel.py",
     "test_spec_decode.py", "test_moe_models.py",
-    "test_pallas_ragged_attention.py", "test_dp_serving.py",
+    "test_pallas_ragged_attention.py",
+    "perfbench/test_reference_falcon_h1.py", "test_dp_serving.py",
     "test_kv_quant.py",
 )
 

@@ -276,8 +276,11 @@ def _kernel_args(topo, *, S, T, Hq=32, Hkv=8, D=64, pack=2, P=8192,
     # whose first 512 are the values (no V cache), 5 layers of 35072 pages
     dict(S=32, T=32, Hq=64, Hkv=1, D=640, pack=1, P=5 * 35072, pages=1088,
          v_dim=512),
+    # falcon-h1-34b-instruct: 64 rows, five query heads a KV head, the
+    # pages of 6 layers
+    dict(S=64, T=64, Hq=20, Hkv=4, D=128, pack=1, P=6 * 8320),
 ], ids=["smoke_packed", "dense_cell_hkv8", "hybrid_cell_hkv32",
-        "mla_cell_hkv1"])
+        "mla_cell_hkv1", "par_cell_20x4"])
 def test_decode_kernel_compiles_for_v5e(topo, on_tpu, geometry):
     """Mosaic accepts the decode kernel's block update at every geometry
     served on the chip, with the block and group the table gives (under
@@ -289,8 +292,11 @@ def test_decode_kernel_compiles_for_v5e(topo, on_tpu, geometry):
     from gllm_tpu.utils import tpu_compiler_options
     geometry = dict(geometry)
     v_dim = geometry.pop("v_dim", None)
-    cfg = decode_blocks(geometry.get("Hkv", 8))
+    cfg = decode_blocks(geometry.get("Hkv", 8),
+                        num_q_heads=geometry.get("Hq", 0))
     assert "group" in cfg, "expected the tpu_v5_lite table entry"
+    if geometry.get("Hq") == 20:
+        assert cfg["kv_block"] == 512, "expected the decode@20x4 entry"
     if v_dim:
         assert cfg["kv_block"] == 512, "expected the decode_mqa entry"
     q, kc, vc, _cu, kv_lens, pt = _kernel_args(topo, **geometry)
@@ -324,7 +330,8 @@ def _ragged(topo, *, S: int, T: int, **kw):
     (32, 2, None),        # nemotron-3-nano-30b-a3b
     (128, 8, None),       # command-a-plus-05-2026, a full layer
     (128, 8, 4096),       # ... a windowed one
-], ids=["32x8", "32x32", "32x2", "128x8", "128x8_window"])
+    (20, 4, None),        # falcon-h1-34b-instruct: five query heads a KV head
+], ids=["32x8", "32x32", "32x2", "128x8", "128x8_window", "20x4"])
 def test_ragged_kernel_under_several_kv_heads_compiles_for_v5e(
         topo, on_tpu, Hq, Hkv, window):
     """Mosaic takes the ragged body under several KV heads (a KV head at
@@ -1141,6 +1148,128 @@ def test_nemotron_largest_mixed_step_compiles_for_v5e(topo, on_tpu,
                    MIXED_STEP_CALLS,
                    ["mamba2_chunk_scan", "mamba2_recurrent_step"],
                    1.25 * GiB)
+
+
+# ---- attention and Mamba-2 heads side by side at Falcon-H1-34B's widths -----
+
+FALCON = "falcon-h1-34b-instruct"
+
+
+@pytest.mark.parametrize("kernel", ["recurrent", "chunk_scan"])
+def test_mamba2_kernels_compile_for_v5e_at_128_by_256(topo, on_tpu, kernel):
+    """Both Mamba-2 kernels alone at the parallel-hybrid cell's shapes: 32
+    heads of 128 x 256 in 2 groups (a state block of 4.19 MB a row, twice
+    Nemotron 3 Nano's), 64 rows or the 2112-token bucket's 32 chunks of
+    128, in place in a pool of 6 x 65 slots."""
+    from gllm_tpu.ops.pallas.mamba2_recurrent import mamba2_recurrent_step
+    from gllm_tpu.ops.pallas.mamba2_scan import mamba2_chunk_scan
+    from gllm_tpu.utils import tpu_compiler_options
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one)
+    S, Nc, H, C, P, N, G, slots = 64, 32, 32, 128, 128, 256, 2, 390
+    t0 = time.monotonic()
+    if kernel == "recurrent":
+        fn = jax.jit(mamba2_recurrent_step.__wrapped__, donate_argnums=(4,),
+                     compiler_options=tpu_compiler_options())
+        compiled = fn.lower(sds((S, H, P)), sds((S, H)), sds((S, G, N)),
+                            sds((S, G, N)), sds((slots, H, P, N)),
+                            sds((S,), jnp.int32)).compile()
+    else:
+        fn = jax.jit(mamba2_chunk_scan.__wrapped__, donate_argnums=(5,),
+                     compiler_options=tpu_compiler_options())
+        compiled = fn.lower(
+            sds((Nc, H, C, P)), sds((Nc, H, C, N)), sds((Nc, H, P, C)),
+            sds((Nc, G, C, N)), sds((Nc, H, 1, N)), sds((slots, H, P, N)),
+            sds((Nc,), jnp.int32), sds((Nc,), jnp.int32)).compile()
+    print(f"\n[compile] mamba2 {kernel} at 32 x 128 x 256 / 2 groups: "
+          f"{time.monotonic() - t0:.1f}s")
+    assert has_kernel(compiled)
+    assert f"mamba2_{kernel}" in compiled.as_text()
+
+
+def _falcon_step(topo, monkeypatch, make_batch, calls, mamba, temp_bound):
+    """Compile one step of the parallel-hybrid cell and hold it to: both
+    halves' kernels as Pallas calls, the weights, the KV pool and the slot
+    pool as the configuration derives them (one counter of layers for the
+    pages and the slots), and the stacked weights read where they lie: no
+    copy of a whole [6, ...] stack (a transposed layout: the 4.3 GB lesson
+    of PR 41, models/nemotron_h.lanes) and no operation of its own whose
+    result is one layer of a projection's stack (docs/stacked_layers.md)."""
+    from gllm_tpu.models.config import from_hf_config
+    cfg = from_hf_config(_perfbench_hf(FALCON))
+    runner = make_runner(cfg, topo, num_pages=8320, monkeypatch=monkeypatch,
+                         max_num_seqs=64, max_model_len=4096,
+                         attention_impl="auto")
+    assert runner.attn_impl == "pallas"
+    c = compile_of(runner.step_async, _with_slots(make_batch(runner)))
+    text = c.compiled.as_text()
+    assert attention_calls(c.compiled) == calls
+    assert _pallas_calls(c.compiled) == mamba
+    mem = c.compiled.memory_analysis()
+    print(f"\n[compile] falcon-h1 {calls[0]}: {c.seconds:.1f}s, "
+          f"{mem.argument_size_in_bytes / GiB:.2f} GiB of arguments, "
+          f"{mem.temp_size_in_bytes / GiB:.3f} GiB temp, "
+          f"{mem.generated_code_size_in_bytes / 1e6:.1f} MB of code")
+    assert mem.temp_size_in_bytes < temp_bound, mem.temp_size_in_bytes
+    assert not re.findall(r"= bf16\[6,[\d,]+\]\S* copy\(", text)
+    comps = list(_computations(text))
+    one_layer = re.compile(
+        r"= bf16\[(1,)?(5120|2560|4096|21504),(2560|512|9344|5120|21504)\]"
+        r"\S* (fusion|copy)\(")
+    moved = [ln.strip()[:140] for _, lines, fused in comps if not fused
+             for ln in lines if one_layer.search(ln)]
+    assert not moved, moved
+    derived = _perfbench_hf(FALCON)["derived"]
+    weights = _weight_bytes(runner)
+    assert weights == derived["weight_bytes"]
+    kv_args = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                  for x in (runner.kv.k, runner.kv.v))
+    assert kv_args == derived["kv_pool_bytes"] \
+        == runner.num_pages * runner._kv_bytes_per_page()
+    state = mem.argument_size_in_bytes - weights - kv_args
+    assert abs(state / runner._ssm_pool_bytes() - 1) < 0.01, (
+        state, runner._ssm_pool_bytes())
+    assert runner._ssm_pool_bytes() == derived["state_pool_bytes_as_stored"]
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.6 * GiB
+
+
+@pytest.mark.slow
+def test_falcon_h1_decode_step_compiles_for_v5e(topo, on_tpu, monkeypatch):
+    """The cell's decode step: 64 rows at 129 pages in the 256-page
+    bucket, 10.52 GB of weights, 1.66 GB of state and 1.64 GB of KV as
+    arguments. Counted from shapes by the compiler; nothing runs."""
+    _falcon_step(topo, monkeypatch, lambda r: decode_batch(r, 64, 129),
+                 ["paged_decode_attention"], ["mamba2_recurrent_step"],
+                 0.5 * GiB)
+
+
+@pytest.mark.slow
+def test_falcon_h1_largest_mixed_step_compiles_for_v5e(topo, on_tpu,
+                                                       monkeypatch):
+    """The cell's largest mixed step: a 2048-token chunk beside 63
+    decoding rows (the 2112-token program, 32 chunks of 128 in the packed
+    layout): both Mamba-2 kernels, both attention kernels."""
+    _falcon_step(topo, monkeypatch,
+                 lambda r: prefill_batch(r, 2048, ndecode=63, npages=129),
+                 MIXED_STEP_CALLS,
+                 ["mamba2_chunk_scan", "mamba2_recurrent_step"], 1.6 * GiB)
+
+
+@pytest.mark.slow
+def test_falcon_h1_probe_chunk_with_prompt_logprobs_fits_the_chip(
+        topo, on_tpu, monkeypatch):
+    """The comparison's long probe: a 2048-token chunk alone whose every
+    row's logprob is asked for. Over a vocabulary of 261120 the chunk's
+    float32 logits are 2 GB beside their bf16 product's 1 GB (the first
+    chip run's out-of-memory: 15.89 GB of 15.75); computed 512 rows at a
+    time (``runner.plp_block_rows``) the step keeps under 1.2 GiB of
+    temporaries beside its 12.87 GiB of arguments."""
+    _falcon_step(topo, monkeypatch,
+                 lambda r: prefill_batch(r, 2048, npages=129,
+                                         prompt_logprobs=1),
+                 MIXED_STEP_CALLS,
+                 ["mamba2_chunk_scan", "mamba2_recurrent_step"], 1.2 * GiB)
 
 
 # ---- windowed GQA in pages at command-a-plus-05-2026's widths ---------------
