@@ -422,13 +422,14 @@ class PPModelRunner(ModelRunner):
         """PP version: each replica's drained+padded intents (shared
         helper) apply to every hybrid stage's slot pools — slot indices
         are global; each stage holds its own layers' pools."""
-        from gllm_tpu.runner.runner import _ssm_apply
+        from gllm_tpu.runner.runner import _M_SSM_APPLY, _ssm_apply
         for r, (s_src, s_dst, z, r_src, r_dst) in self._drained_ssm_ops():
             for stage in self.replicas[r]:
                 if stage.cfg.num_linear_layers == 0:
                     continue
                 conv, rec = _ssm_apply(stage.kv.conv, stage.kv.rec,
                                        s_src, s_dst, z, r_src, r_dst)
+                _M_SSM_APPLY.inc()
                 stage.kv = stage.kv._replace(conv=conv, rec=rec)
 
     def _run_pipeline(self, stages, sched_batch, step,

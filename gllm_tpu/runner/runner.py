@@ -90,6 +90,11 @@ _M_FIRST_USE = obs.counter(
     "wall seconds of the jit calls that first used a step signature "
     "(trace + lower + compile, or the persistent cache read), by source",
     ("source",))
+_M_SSM_APPLY = obs.counter(
+    "gllm_ssm_apply_calls_total",
+    "slot maintenance programs dispatched in front of a step (one for "
+    "every hybrid stage under pp). gllm_ssm_intents_total over it: slots "
+    "named a call, which the program's device bytes follow")
 _xla_cache_hit = threading.local()
 
 
@@ -189,16 +194,36 @@ def _ssm_update(conv, rec, idx, snap_src, snap_dst, zero_slots, rest_src,
     """Shared SSM slot maintenance body (snapshot → zero → restore).
     ``idx``: index prefix — () for a single pool ([Lg, slots, ...]),
     (r,) for one replica of dp-stacked pools ([dp, Lg, slots, ...]).
-    Padding entries are (0, 0) / slot 0 — the dummy slot, where
-    self-copies and zeroing are harmless."""
-    a = (*idx, slice(None))
-    conv = conv.at[(*a, snap_dst)].set(conv[(*a, snap_src)])
-    rec = rec.at[(*a, snap_dst)].set(rec[(*a, snap_src)])
-    conv = conv.at[(*a, zero_slots)].set(0.0)
-    rec = rec.at[(*a, zero_slots)].set(0.0)
-    conv = conv.at[(*a, rest_dst)].set(conv[(*a, rest_src)])
-    rec = rec.at[(*a, rest_dst)].set(rec[(*a, rest_src)])
-    return conv, rec
+
+    A slot at a time, in place: each entry is one ``dynamic_slice`` and
+    one ``dynamic_update_slice`` of the donated pools along the slot axis,
+    so the program moves the slots it is handed and knows nothing else of
+    a pool's shape (a gather and scatter over that axis rewrote the whole
+    pool wherever its last dimension is not one 128-lane tile). The three
+    classes run as ONE loop over their entries in class order, a zero as
+    a copy of the slot onto itself with zeros chosen for the value; the
+    padding entries (destination 0, the dummy slot no sequence holds) are
+    sorted behind the others and the loop stops short of them."""
+    src = jnp.concatenate([snap_src, zero_slots, rest_src])
+    dst = jnp.concatenate([snap_dst, zero_slots, rest_dst])
+    wipe = jnp.asarray(np.repeat(
+        [False, True, False], [snap_dst.size, zero_slots.size, rest_dst.size]))
+    order = jnp.argsort(dst == 0, stable=True)
+    src, dst, wipe = src[order], dst[order], wipe[order]
+    lead = (1,) * len(idx)
+
+    def moved(pool, i):
+        layers, _, *inner = pool.shape[len(idx):]
+        at = lambda s: (*idx, 0, s, *(0,) * len(inner))
+        slot = jax.lax.dynamic_slice(pool, at(src[i]),
+                                     (*lead, layers, 1, *inner))
+        slot = jnp.where(wipe[i], jnp.zeros((), pool.dtype), slot)
+        return jax.lax.dynamic_update_slice(pool, slot, at(dst[i]))
+
+    def move(i, pools):
+        return tuple(moved(pool, i) for pool in pools)
+
+    return jax.lax.fori_loop(0, jnp.sum(dst != 0), move, (conv, rec))
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1))
@@ -1380,6 +1405,7 @@ class ModelRunner:
             else:
                 conv, rec = _ssm_apply(self.kv.conv, self.kv.rec, s_src,
                                        s_dst, z, r_src, r_dst)
+            _M_SSM_APPLY.inc()
             self.kv = self.kv._replace(conv=conv, rec=rec)
 
     def _apply_swap_intents(self) -> None:

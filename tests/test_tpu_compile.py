@@ -1398,3 +1398,50 @@ def test_cohere2_mixed_step_compiles_for_v5e(topo, on_tpu, monkeypatch,
                           table_pages=1068)
     _cohere2_step(runner, batch, MIXED_STEP_CALLS + [
         WINDOW_NAMES["ragged"], WINDOW_NAMES["rows"]], 2.0 * GiB)
+
+
+# ---- the slot maintenance program at the three slot-pool cells' pools -------
+
+@pytest.mark.parametrize("config,slots,dp", [
+    (FALCON, 65, 1), ("olmo-hybrid-7b", 33, 1),
+    ("nemotron-3-nano-30b-a3b", 65, 1), (FALCON, 33, 2)],
+    ids=["falcon_256_lanes", "hybrid_384_lanes", "state_space_128_lanes",
+         "falcon_dp_stacked"])
+def test_slot_maintenance_moves_slots_not_the_pool(topo, on_tpu, config,
+                                                   slots, dp):
+    """`runner._ssm_apply` at a cell's pools as `ssm_slot_shapes` lays
+    them, index lists of 4: the compiled program reads and writes far
+    less than the pool and keeps less than a slot of temporaries. As a
+    gather and a scatter over the slot axis it read 8.52 GB beside 1.64
+    GB of temporaries at falcon's `[6, 65, 32, 128, 256]` and 6.17 GB at
+    the hybrid's `[12, 33, 15, 96, 384]` (PR 49): XLA splits a last
+    dimension of more than one 128-lane tile in a pass over the whole
+    pool. A change of a pool's layout meets this guard first."""
+    from gllm_tpu.models.config import from_hf_config
+    from gllm_tpu.runner.runner import _ssm_apply, _ssm_apply_replica
+    cfg = from_hf_config(_perfbench_hf(config))
+    one = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one)
+    lead = (cfg.num_linear_layers, slots)
+    conv, rec = (sds((dp,) * (dp > 1) + lead + s)
+                 for s in cfg.ssm_slot_shapes)
+    lists = [sds((4,), jnp.int32)] * 5
+    t0 = time.monotonic()
+    if dp > 1:
+        compiled = _ssm_apply_replica.lower(
+            conv, rec, sds((), jnp.int32), *lists).compile()
+    else:
+        compiled = _ssm_apply.lower(conv, rec, *lists).compile()
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, (list, tuple)) else cost
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    pool = 4 * int(np.prod(rec.shape))
+    slot = pool // (dp * slots)
+    print(f"\n[compile] slot maintenance, rec {rec.shape}: "
+          f"{time.monotonic() - t0:.1f}s, bytes accessed "
+          f"{cost['bytes accessed'] / 1e9:.3f} GB of a pool of "
+          f"{pool / 1e9:.2f}, temporaries {temp / 1e6:.2f} MB of a slot's "
+          f"{slot / 1e6:.1f}")
+    assert cost["bytes accessed"] < pool / 2
+    assert temp < slot
