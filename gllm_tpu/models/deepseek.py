@@ -5,12 +5,13 @@ TPU-native re-design of the reference deepseek_v2.py (730 LoC,
 
 - **MLA with a latent KV cache**: each token caches one
   ``kv_lora_rank + qk_rope_head_dim`` latent row (the V2 paper's compressed
-  KV). Attention runs in the *absorbed* form everywhere (reference uses
-  absorbed decode :272-293 and decompressed chunked prefill; we use absorbed
-  for both — one code path, MQA-shaped, and the paged-attention machinery is
+  KV). Attention runs in the *absorbed* form (reference uses absorbed
+  decode :272-293 and decompressed chunked prefill; we use absorbed for
+  both — one code path, MQA-shaped, and the paged-attention machinery is
   reused with Hkv=1): q_nope is folded through W_UK into latent space,
   scores = q_lat·c_kv + q_pe·k_pe, and the output latent is expanded through
-  W_UV.
+  W_UV. One exception: a prompt chunk in a WINDOWED layer attends
+  decompressed keys ("Two ways through a step's tokens").
 - **DeepSeekMoE**: first_k_dense_replace dense layers then MoE layers (two
   homogeneous lax.scans — keeps O(1) compile depth per block type);
   grouped top-k routing: softmax (V2 greedy/group_limited_greedy) or
@@ -156,6 +157,26 @@ def count_rows_attended(cfg: ModelConfig, cu_q_lens, path: str) -> None:
     q_lens = cu_q_lens[1:] - cu_q_lens[:-1]
     layers = cfg.num_attn_layers if cfg.use_swa else cfg.num_stage_layers
     _M_DSA_ROWS.inc(int((q_lens == 1).sum()) * layers, path=path)
+
+
+_M_SWA_TOKENS = obs.counter(
+    "gllm_swa_tokens_attended_total",
+    "Tokens a step's windowed latent layers attended, summed over the "
+    "windowed layers, by the form that computed them: a chunk's tokens "
+    "over keys and values decompressed once (decompressed), a one-token "
+    "row with w_uk folded into its queries over the latent rows as stored "
+    "(absorbed)", ("form",))
+
+
+def count_swa_tokens(cfg: ModelConfig, cu_q_lens) -> None:
+    """One step's tokens x windowed layers into the counter by the form
+    ``_swa_attention`` gives them, from the batch's ``cu_q_lens`` as the
+    host built them (no device value is read)."""
+    q_lens = cu_q_lens[1:] - cu_q_lens[:-1]
+    chunked = int(q_lens[q_lens > 1].sum())
+    _M_SWA_TOKENS.inc(chunked * cfg.num_swa_layers, form="decompressed")
+    _M_SWA_TOKENS.inc((int(q_lens.sum()) - chunked) * cfg.num_swa_layers,
+                      form="absorbed")
 
 
 def count_stats(stats, decode_only: bool) -> None:
@@ -512,7 +533,7 @@ def _moe_block(lp: Params, x: jnp.ndarray, cfg: ModelConfig, valid=None,
 
 
 # ---------------------------------------------------------------------------
-# MLA attention (absorbed form)
+# MLA attention (absorbed form; a windowed layer's chunks decompressed)
 # ---------------------------------------------------------------------------
 #
 # Two ways through a step's tokens, for the layers that cannot hand their
@@ -522,12 +543,24 @@ def _moe_block(lp: Params, x: jnp.ndarray, cfg: ModelConfig, valid=None,
 #
 # - ROWS: one query a sequence, its first token of the step. That is all of
 #   a decode-only program (``max_q_len == 1``), and the decoding rows of a
-#   mixed one. Every temporary is [S, ...].
+#   mixed one. Every temporary is [S, ...]. Always the ABSORBED form: w_uk
+#   folded into the row's queries, attention over latent rows as they are
+#   stored, w_uv applied to the result. A row reads its keys once, and
+#   decompressing them would cost heads x (nope + v) products a key where
+#   folding costs them once a query.
 # - CHUNKS: the sequences with more than one token, cut into work items of
 #   ``BQ`` queries of ONE sequence, run one after the other by a loop whose
 #   trip count is the step's own (``lax.fori_loop`` over a traced count):
 #   a 2048-token chunk is 16 items, whatever else is in the step. Every
-#   temporary is [BQ, ...], so no tensor grows with tokens x context.
+#   temporary is [BQ, ...], so no tensor grows with tokens x context. A
+#   full layer's items stay absorbed (128 queries read thousands of rows of
+#   their own choosing). A windowed layer's items attend in the
+#   DECOMPRESSED form: a chunk's queries share their keys, each key is
+#   decompressed once (the step's own rows before the loop, a sequence's
+#   ring when the loop reaches it) and a pair then costs nope + rope + v
+#   lanes a head (256 + 128 at dots3_note's widths) where the absorbed
+#   form multiplies width + lora (1152 + 1024); and no [T, heads, lora]
+#   tensor stands between the projections and the loop.
 
 class Ragged(NamedTuple):
     """What both ways read of the batch's layout."""
@@ -557,18 +590,25 @@ def _ragged(md: AttentionMetadata, T: int, max_q_len: int) -> Ragged:
                   valid & (q_lens[seq_of] > 1))
 
 
-def _attend(q, keys, mask, *, scale, lora):
-    """softmax(q . k * scale) over the masked keys, values the keys' first
-    ``lora`` lanes. q [N, H, W]; keys [N, K, W] (each query its own) or
-    [K, W] (shared); mask [N, K]. Returns [N, H, lora] float32; a query
-    with no key gives zeros."""
-    eq = "nhw,nkw->nhk" if keys.ndim == 3 else "nhw,kw->nhk"
-    sc = jnp.einsum(eq, q, keys.astype(q.dtype),
-                    preferred_element_type=jnp.float32) * scale
+def _softmax(sc, mask):
+    """Masked softmax over the last axis of the scores sc [N, H, K] (mask
+    [N, K]) in two parts: (weights whose largest is 1, their sum). A query
+    with no key gets zeros over a sum held away from 0."""
     sc = jnp.where(mask[:, None, :], sc, -jnp.inf)
     m = jnp.max(sc, axis=-1, keepdims=True)
     p = jnp.exp(sc - jnp.where(jnp.isfinite(m), m, 0.0))
-    denom = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+    return p, jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+
+
+def _attend(q, keys, mask, *, scale, lora):
+    """The absorbed form: softmax(q . k * scale) over the masked keys,
+    values the keys' first ``lora`` lanes. q [N, H, W]; keys [N, K, W]
+    (each query its own) or [K, W] (shared); mask [N, K]. Returns [N, H,
+    lora] float32; a query with no key gives zeros."""
+    eq = "nhw,nkw->nhk" if keys.ndim == 3 else "nhw,kw->nhk"
+    sc = jnp.einsum(eq, q, keys.astype(q.dtype),
+                    preferred_element_type=jnp.float32) * scale
+    p, denom = _softmax(sc, mask)
     ev = "nhk,nkl->nhl" if keys.ndim == 3 else "nhk,kl->nhl"
     out = jnp.einsum(ev, p.astype(q.dtype),
                      keys[..., :lora].astype(q.dtype),
@@ -576,18 +616,39 @@ def _attend(q, keys, mask, *, scale, lora):
     return out / denom
 
 
-def _chunk_loop(rg: Ragged, T: int, out_shape, item_fn):
+def _attend_heads(q, parts, *, scale):
+    """The decompressed form: every head its own keys and values, which
+    come in parts (a ring, a stretch of the step) that one softmax spans.
+    q [N, H, D]; parts ((k [K_i, H, D], v [K_i, H, V], mask [N, K_i]),
+    ...). Returns [N, H, V] float32; a query with no key gives zeros.
+    The scores are [N, H, K] as ``_attend``'s are: the shape the
+    benchmark's ``swa_mla`` trace pattern finds a work item by. With the
+    heads first the TPU compiler takes half the time over a mixed step
+    program and the chip the same (PERF.md section 7)."""
+    ks, vs, masks = zip(*parts)
+    k, v = jnp.concatenate(ks), jnp.concatenate(vs)
+    sc = jnp.einsum("nhd,khd->nhk", q, k,
+                    preferred_element_type=jnp.float32) * scale
+    p, denom = _softmax(sc, jnp.concatenate(masks, axis=1))
+    out = jnp.einsum("nhk,khv->nhv", p.astype(q.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return out / denom
+
+
+def _chunk_loop(rg: Ragged, T: int, out_shape, item_fn, seq_fn=None):
     """Run ``item_fn(s, q_start, q_pos0, n_valid) -> [BQ, ...]`` over the
     work items and lay the valid rows into a [T, ...] float32 buffer. s:
     the item's sequence; q_start: flat index of its first query; q_pos0:
-    that query's position; n_valid: queries it really has."""
-    def body(w, buf):
-        s = jnp.searchsorted(rg.item_cum, w, side="right").astype(jnp.int32)
-        j = w - (rg.item_cum[s] - rg.items[s])
+    that query's position; n_valid: queries it really has.
+    ``seq_fn(s)``: what the items of one sequence share. It runs once when
+    the loop reaches the sequence (items come in sequence order: a loop
+    over the sequences that have items, around the loop over one's
+    items), and ``item_fn`` takes its result as a fifth argument."""
+    def place(buf, s, j, *held):
         q_start = rg.cu[s] + j * BQ
         n_valid = jnp.minimum(BQ, rg.q_lens[s] - j * BQ)
         q_pos0 = rg.kv_lens[s] - rg.q_lens[s] + j * BQ
-        res = item_fn(s, q_start, q_pos0, n_valid).astype(jnp.float32)
+        res = item_fn(s, q_start, q_pos0, n_valid, *held).astype(jnp.float32)
         start = (q_start,) + (0,) * len(out_shape)
         old = jax.lax.dynamic_slice(buf, start, (BQ,) + out_shape)
         keep = (jnp.arange(BQ) < n_valid).reshape(
@@ -595,8 +656,21 @@ def _chunk_loop(rg: Ragged, T: int, out_shape, item_fn):
         return jax.lax.dynamic_update_slice(
             buf, jnp.where(keep, res, old), start)
 
+    def body(w, buf):
+        s = jnp.searchsorted(rg.item_cum, w, side="right").astype(jnp.int32)
+        return place(buf, s, w - (rg.item_cum[s] - rg.items[s]))
+
+    def seq_body(c, buf):
+        s = jnp.searchsorted(with_items, c, side="right").astype(jnp.int32)
+        held = seq_fn(s)
+        return jax.lax.fori_loop(
+            0, rg.items[s], lambda j, b: place(b, s, j, held), buf)
+
     buf = jnp.zeros((T + BQ,) + out_shape, jnp.float32)
-    return jax.lax.fori_loop(0, rg.item_cum[-1], body, buf)[:T]
+    if seq_fn is None:
+        return jax.lax.fori_loop(0, rg.item_cum[-1], body, buf)[:T]
+    with_items = jnp.cumsum(rg.items > 0)
+    return jax.lax.fori_loop(0, with_items[-1], seq_body, buf)[:T]
 
 
 def _pad_rows(a, before=0, after=BQ):
@@ -834,17 +908,42 @@ def _dsa_attention(lp, x, q_resid, q_full, batch: StepBatch, latent_cache,
     return out, index_cache, index_scale, stats
 
 
-def _swa_attention(q_full, entry, batch: StepBatch, ring, slot_base, *,
-                   max_q_len: int, g: Geom):
+def _absorb(lp, q_nope, q_pe, g: Geom, dtype):
+    """w_uk folded into the queries: [N, H, width] over latent rows as
+    they are stored (zero over the row's pad lanes: the scores are as
+    without them)."""
+    q_lat = jnp.einsum("thn,hnl->thl", q_nope.astype(jnp.float32),
+                       lp["w_uk"].astype(jnp.float32)).astype(dtype)
+    q_full = jnp.concatenate([q_lat, q_pe], axis=-1)  # [N, Hq, lora+rope]
+    pad = g.width - q_full.shape[-1]
+    if pad:
+        q_full = jnp.pad(q_full, ((0, 0), (0, 0), (0, pad)))
+    return q_full
+
+
+def _expand(lp, out_lat):
+    """The latent result [N, H, lora] through w_uv: [N, H, v] float32."""
+    return jnp.einsum("thl,hlv->thv", out_lat.astype(jnp.float32),
+                      lp["w_uv"].astype(jnp.float32))
+
+
+def _swa_attention(lp, q_nope, q_pe, entry, batch: StepBatch, ring,
+                   slot_base, *, max_q_len: int, g: Geom):
     """A windowed layer: token t attends the positions (t - window, t] of
     its sequence. What lies before this step is in the sequence's ring
     (position p in row p % R of its slot), what this step brings is in
     ``entry`` [T, W]; the step's last R rows a sequence go into the ring
-    at the end. Returns (out_lat [T, H, lora] float32, ring)."""
-    T = q_full.shape[0]
+    at the end. The one-token rows attend absorbed, a chunk's tokens
+    decompressed ("Two ways through a step's tokens"); a decode-only step
+    folds and expands all its T rows, a step with a chunk its S first
+    tokens. Returns (out [T, H, v] float32, ring)."""
+    T, dtype = entry.shape[0], q_nope.dtype
     md = batch.attn
     n_slots, R, W = ring.shape
     win = g.window
+    chunks = max_q_len > 1
+    # a decode-only step is left as it was: folded first, all T rows
+    q_full = None if chunks else _absorb(lp, q_nope, q_pe, g, dtype)
     rg = _ragged(md, T, max_q_len)
     slots = batch.ssm_slots + slot_base
     cs = rg.kv_lens - rg.q_lens              # positions before this step
@@ -864,35 +963,63 @@ def _swa_attention(q_full, entry, batch: StepBatch, ring, slot_base, *,
         [(rp >= 0) & (rp > cs[:, None] - win),
          jnp.ones((rp.shape[0], 1), bool)], axis=1)
     mask &= (rg.q_lens > 0)[:, None]
-    rows = _attend(q_full[rg.first], keys, mask, scale=g.scale, lora=g.lora)
-    out = jnp.zeros((T, g.heads, g.lora), jnp.float32).at[rg.first].set(
+    q_rows = (_absorb(lp, q_nope[rg.first], q_pe[rg.first], g, dtype)
+              if chunks else q_full[rg.first])
+    rows = _attend(q_rows, keys, mask, scale=g.scale, lora=g.lora)
+    if chunks:
+        rows = _expand(lp, rows)
+    out = jnp.zeros((T,) + rows.shape[1:], jnp.float32).at[rg.first].set(
         jnp.where((rg.q_lens == 1)[:, None, None], rows, 0.0))
 
-    if max_q_len > 1:
-        back = -(-(win - 1) // BQ) * BQ      # in-step keys before the item
-        qf_p, e_p = _pad_rows(q_full), _pad_rows(entry, before=back)
-        local = jnp.arange(BQ, dtype=jnp.int32)
-        kloc = jnp.arange(back + BQ, dtype=jnp.int32) - back
+    if chunks:
+        def decompress(rows):
+            """Latent rows as stored [N, W] -> a head's keys [N, H, nope +
+            rope] (the rotary lanes are the heads' in common) and values
+            [N, H, v]."""
+            c = rows[:, :g.lora].astype(dtype)
+            k_nope = jnp.einsum("tl,hnl->thn", c, lp["w_uk"],
+                                preferred_element_type=jnp.float32)
+            k_pe = jnp.broadcast_to(
+                rows[:, None, g.lora:g.lora + g.rope],
+                k_nope.shape[:2] + (g.rope,))
+            v = jnp.einsum("tl,hlv->thv", c, lp["w_uv"],
+                           preferred_element_type=jnp.float32)
+            return (jnp.concatenate([k_nope.astype(dtype),
+                                     k_pe.astype(dtype)], axis=-1),
+                    v.astype(dtype))
 
-        def item(s, q_start, q_pos0, n_valid):
-            q = jax.lax.dynamic_slice_in_dim(qf_p, q_start, BQ)
-            step_keys = jax.lax.dynamic_slice_in_dim(e_p, q_start,
-                                                     back + BQ)
-            # in-step key at flat q_start + kloc: same sequence iff not
-            # before the sequence's first token; position q_pos0 + kloc
-            same = q_start + kloc >= rg.cu[s]
-            dist = local[:, None] - kloc[None, :]
+        # an item's keys of the step: the ``back`` rows before its first
+        # query and its own, fewer where the step begins sooner; rows
+        # behind the step so that a slice of that many always lies inside
+        back = -(-(win - 1) // BQ) * BQ
+        q_p = _pad_rows(jnp.concatenate([q_nope, q_pe], axis=-1))
+        k_p, v_p = decompress(_pad_rows(entry,
+                                        after=max(BQ, back + BQ - T)))
+        local = jnp.arange(BQ, dtype=jnp.int32)
+        span = jnp.arange(back + BQ, dtype=jnp.int32)
+
+        def item(s, q_start, q_pos0, n_valid, ring_kv):
+            cut = lambda a, at, n: jax.lax.dynamic_slice_in_dim(a, at, n)
+            k0 = jnp.maximum(q_start - back, 0)
+            # in-step key at flat k0 + span: same sequence iff not before
+            # the sequence's first token; ``dist`` positions before the
+            # query
+            same = k0 + span >= rg.cu[s]
+            dist = (q_start + local)[:, None] - (k0 + span)[None, :]
             m_step = same[None, :] & (dist >= 0) & (dist < win)
             rp_s = ring_pos(cs[s])                           # [R]
             q_pos = q_pos0 + local
             m_ring = (rp_s >= 0)[None, :] & (
                 rp_s[None, :] > q_pos[:, None] - win)
-            keys = jnp.concatenate([ring[slots[s]], step_keys], axis=0)
-            return _attend(q, keys, jnp.concatenate([m_ring, m_step], 1),
-                           scale=g.scale, lora=g.lora)
+            return _attend_heads(
+                cut(q_p, q_start, BQ),
+                (ring_kv + (m_ring,),
+                 (cut(k_p, k0, back + BQ), cut(v_p, k0, back + BQ), m_step)),
+                scale=g.scale)
 
-        chunks = _chunk_loop(rg, T, (g.heads, g.lora), item)
-        out = jnp.where(rg.chunked[:, None, None], chunks, out)
+        chunked = _chunk_loop(rg, T, (g.heads, g.v), item,
+                              lambda s: decompress(ring[slots[s]]))
+        out = jnp.where(rg.chunked[:, None, None], chunked, out)
 
     # the step's rows into the ring: of each sequence the last R (an
     # earlier one would land on a later one's row), padding rows into the
@@ -903,7 +1030,7 @@ def _swa_attention(q_full, entry, batch: StepBatch, ring, slot_base, *,
                     slot_base * R + jnp.arange(T, dtype=jnp.int32) % R)
     ring = ring.reshape(n_slots * R, W).at[dst].set(
         entry.astype(ring.dtype)).reshape(ring.shape)
-    return out, ring
+    return (out if chunks else _expand(lp, out)), ring
 
 
 def _mla_attention(lp, x, batch: StepBatch, latent_cache, cfg: ModelConfig,
@@ -950,37 +1077,32 @@ def _mla_attention(lp, x, batch: StepBatch, latent_cache, cfg: ModelConfig,
         latent_cache = flat.at[batch.slot_mapping].set(
             entry.astype(flat.dtype)).reshape(latent_cache.shape)
 
-    # Absorb q_nope through W_UK → latent space; MQA over the latent cache.
-    q_lat = jnp.einsum("thn,hnl->thl", q_nope.astype(jnp.float32),
-                       lp["w_uk"].astype(jnp.float32)).astype(x.dtype)
-    q_full = jnp.concatenate([q_lat, q_pe], axis=-1)  # [T, Hq, lora+rope]
-    if pad:
-        # zero q over the pad lanes — scores are unchanged
-        q_full = jnp.pad(q_full, ((0, 0), (0, 0), (0, pad)))
-
     stats = None
     if kind == SWA:
-        out_lat, ring = _swa_attention(q_full, entry, batch, ring,
-                                       slot_base, max_q_len=max_q_len, g=g)
-    elif cfg.use_dsa:
-        # DSA: the indexer's choice of positions, then attention over the
-        # chosen latent rows only (reference deepseek_v32.py).
-        out_lat, index_cache, index_scale, stats = _dsa_attention(
-            lp, x, qa, q_full, batch, latent_cache, index_cache,
-            index_scale, cfg, cos_sin, max_q_len=max_q_len, g=g,
-            attn_impl=attn_impl)
+        out, ring = _swa_attention(lp, q_nope, q_pe, entry, batch, ring,
+                                   slot_base, max_q_len=max_q_len, g=g)
     else:
-        # MQA over the latent cache; values are the latent prefix of the
-        # keys (v_cache=None → the Pallas kernels read v from the k block
-        # in VMEM, one DMA stream; the xla path slices lazily inside its
-        # gather).
-        kc = latent_cache[:, :, None, :]              # [P, page, 1, width]
-        out_lat = paged_attention(q_full, kc, None, batch.attn,
-                                  scale=g.scale, max_q_len=max_q_len,
-                                  impl=attn_impl,
-                                  v_dim=lora)         # [T, Hq, lora]
-    out = jnp.einsum("thl,hlv->thv", out_lat.astype(jnp.float32),
-                     lp["w_uv"].astype(jnp.float32))
+        # Absorb q_nope through W_UK → latent space; MQA over the latent
+        # cache.
+        q_full = _absorb(lp, q_nope, q_pe, g, x.dtype)
+        if cfg.use_dsa:
+            # DSA: the indexer's choice of positions, then attention over
+            # the chosen latent rows only (reference deepseek_v32.py).
+            out_lat, index_cache, index_scale, stats = _dsa_attention(
+                lp, x, qa, q_full, batch, latent_cache, index_cache,
+                index_scale, cfg, cos_sin, max_q_len=max_q_len, g=g,
+                attn_impl=attn_impl)
+        else:
+            # MQA over the latent cache; values are the latent prefix of
+            # the keys (v_cache=None → the Pallas kernels read v from the
+            # k block in VMEM, one DMA stream; the xla path slices lazily
+            # inside its gather).
+            kc = latent_cache[:, :, None, :]          # [P, page, 1, width]
+            out_lat = paged_attention(q_full, kc, None, batch.attn,
+                                      scale=g.scale, max_q_len=max_q_len,
+                                      impl=attn_impl,
+                                      v_dim=lora)     # [T, Hq, lora]
+        out = _expand(lp, out_lat)
     if g.gate == "headwise":
         gate = jax.nn.sigmoid((x @ lp["attn_gate"]).astype(jnp.float32))
         out = out * gate[:, :, None]

@@ -1732,6 +1732,9 @@ class ModelRunner:
             from gllm_tpu.models.deepseek import count_rows_attended
             count_rows_attended(self.model_cfg, host.attn.cu_q_lens,
                                 self.dsa_rows_path)
+        if self.model_cfg.use_swa:
+            from gllm_tpu.models.deepseek import count_swa_tokens
+            count_swa_tokens(self.model_cfg, host.attn.cu_q_lens)
         new_sig = self._note_dispatch(
             "step", host, tuple(flags.values()), flags["all_greedy"])
         build.stop()

@@ -336,3 +336,109 @@ def test_min_row_and_page_buckets_are_floors_of_every_step(argv, mixed,
     builder = BatchBuilder(config, 16)
     assert builder.shape_signature(batch([1] * 7 + [40])) == mixed
     assert builder.shape_signature(batch([1] * 7)) == decode
+
+
+# ---- a windowed layer's chunk: decompressed keys against the absorbed form --
+
+def _swa_step(lp, g, rows, ring, seqs, T, S, max_q_len):
+    """One step of ``_swa_attention`` over ``seqs`` ((slot, positions
+    before the step, tokens), ...), padded to T tokens and S sequences;
+    ``rows``: slot -> (q_nope, q_pe, entry) of every position. Returns
+    (out [tokens, H, v], ring)."""
+    from gllm_tpu.batching import StepBatch
+    from gllm_tpu.ops.attention import AttentionMetadata
+    n = sum(q for _, _, q in seqs)
+    take = lambda i: np.concatenate(
+        [rows[s][i][c:c + q] for s, c, q in seqs]
+        + [np.zeros((T - n,) + rows[seqs[0][0]][i].shape[1:], np.float32)])
+    pad = lambda a, to: np.asarray(list(a) + [0] * (to - len(a)), np.int32)
+    q_lens = [q for _, _, q in seqs]
+    batch = StepBatch(
+        token_ids=None, slot_mapping=None, logits_indices=None, sampling=None,
+        positions=pad([p for _, c, q in seqs for p in range(c, c + q)], T),
+        ssm_slots=pad([s for s, _, _ in seqs], S),
+        attn=AttentionMetadata(
+            np.concatenate([[0], np.cumsum(pad(q_lens, S))]).astype(np.int32),
+            pad([c + q for _, c, q in seqs], S), None, np.int32(len(seqs))))
+    out, ring = _SWA_JIT(lp, take(0), take(1), take(2), batch, ring, 0,
+                         max_q_len=max_q_len, g=g)
+    return np.asarray(out)[:n], ring
+
+
+_SWA_JIT = jax.jit(deepseek._swa_attention,
+                   static_argnames=("max_q_len", "g"))
+
+
+@pytest.mark.parametrize("seqs", [
+    pytest.param([(0, 40)], id="first_chunk_empty_ring"),
+    pytest.param([(300, 300)], id="later_chunk_full_ring"),
+    pytest.param([(7, 5)], id="shorter_than_one_item"),
+    pytest.param([(30, 150), (0, 131)], id="two_chunked_sequences"),
+    pytest.param([(50, 1), (9, 140), (3, 1), (0, 1)],
+                 id="chunk_beside_decoding_rows"),
+    pytest.param([(21, 13)], id="chunk_of_exactly_window_tokens"),
+])
+def test_a_chunk_attends_decompressed_what_its_tokens_attend_absorbed(seqs):
+    """A step that carries a chunk (decompressed keys for the chunk's
+    tokens, one loop item of 128 queries after the other) against the same
+    tokens one decode-only step each (the absorbed form), float32 at the
+    rehearsal's widths: the results and the rings they leave."""
+    cfg = from_hf_config(TINY)
+    g = deepseek.geom(cfg, deepseek.SWA)
+    assert (g.heads, g.lora, g.nope, g.rope, g.v, g.window) == (
+        2, 48, 24, 8, 16, 13)
+    R = cfg.swa_ring_len(8)
+    rng = np.random.default_rng(len(seqs) * 1000 + seqs[0][1])
+    draw = lambda *shape: rng.standard_normal(shape).astype(np.float32)
+    lp = {"w_uk": draw(g.heads, g.nope, g.lora) * g.lora ** -0.5,
+          "w_uv": draw(g.heads, g.lora, g.v) * g.lora ** -0.5}
+    seqs = [(slot, c, q) for slot, (c, q) in enumerate(seqs, start=1)]
+    rows = {}
+    for slot, c, q in seqs:
+        entry = np.zeros((c + q, g.width), np.float32)
+        entry[:, :g.lora + g.rope] = draw(c + q, g.lora + g.rope)
+        rows[slot] = (draw(c + q, g.heads, g.nope),
+                      draw(c + q, g.heads, g.rope), entry)
+    n_slots = len(seqs) + 1
+
+    # every position a decode-only step of its own, from an empty ring
+    ring = jnp.zeros((n_slots, R, g.width), jnp.float32)
+    want = []
+    for p in range(max(c + q for _, c, q in seqs)):
+        live = [(s, p, 1) for s, c, q in seqs if p < c + q]
+        out, new = _swa_step(lp, g, rows, ring, live, len(seqs), len(seqs),
+                             max_q_len=1)
+        want.append({s: out[i] for i, (s, _, _) in enumerate(live)})
+        ring = new
+    after = np.asarray(ring)
+
+    # the rings as each sequence's context left them, then the step
+    ring = np.zeros((n_slots, R, g.width), np.float32)
+    for slot, c, _ in seqs:
+        for p in range(max(0, c - R), c):
+            ring[slot, p % R] = rows[slot][2][p]
+    n = sum(q for _, _, q in seqs)
+    got, ring = _swa_step(lp, g, rows, jnp.asarray(ring), seqs,
+                          -(-(n + 3) // 8) * 8, len(seqs) + 2,
+                          max_q_len=max(q for _, _, q in seqs))
+    at = 0
+    for slot, c, q in seqs:
+        np.testing.assert_allclose(
+            got[at:at + q], np.stack([want[p][slot] for p in range(c, c + q)]),
+            rtol=1e-5, atol=1e-5, err_msg=f"slot {slot}")
+        at += q
+    np.testing.assert_array_equal(np.asarray(ring)[1:], after[1:])
+
+
+def test_tokens_are_counted_by_the_form_that_attended_them():
+    """A prompt of 40 tokens in chunks of 32 and 8 (each more than one
+    token: decompressed), then the sampled tokens fed back one a step
+    (absorbed; the last is never fed), times three windowed layers."""
+    got = lambda: {f: deepseek._M_SWA_TOKENS.get(form=f)
+                   for f in ("decompressed", "absorbed")}
+    llm, before = engine(), got()
+    llm.generate(prompt_token_ids=[list(range(2, 42))],
+                 sampling_params=[SamplingParams(
+                     temperature=0.0, max_tokens=5, ignore_eos=True)])
+    assert {f: n - before[f] for f, n in got().items()} == {
+        "decompressed": 3 * 40, "absorbed": 3 * 4}

@@ -879,8 +879,16 @@ def test_dots3_mixed_step_compiles_for_v5e(topo, on_tpu, monkeypatch):
 def test_dots3_windowed_attention_compiles_for_v5e_at_the_chunk(topo,
                                                                 on_tpu):
     """The windowed layers' attention alone at the cell's geometry: 2112
-    tokens of 64 heads over rows of 1152 as stored, 64 sequences, rings of
-    544 rows; a work item attends [ring | 640 rows of the step]."""
+    tokens of 64 heads, 64 sequences, rings of 544 rows of 1152 as stored;
+    a work item attends [ring | 640 rows of the step] DECOMPRESSED, 256
+    lanes a key and 128 a value, and hands on [128, 64, 128]. What the
+    change from the absorbed form is for (PR 50): no [tokens, 64, latent]
+    tensor in the program, temporaries under 0.6 GiB where they were
+    1.435, and under a third of the FLOPs (XLA's count for the described
+    chip; it counts a loop's body once, so a 2048-token chunk's sixteen
+    items are added here: the absorbed form as the parent ran it, w_uk
+    folded into all T queries, sixteen items over [1184, 1152], w_uv out
+    of all T results, its one-token rows left out)."""
     from gllm_tpu.batching import StepBatch
     from gllm_tpu.models import deepseek as ds
     from gllm_tpu.ops.attention import AttentionMetadata
@@ -888,27 +896,63 @@ def test_dots3_windowed_attention_compiles_for_v5e_at_the_chunk(topo,
     g = ds.geom(cfg, ds.SWA)
     assert (g.heads, g.width, g.lora, g.window) == (64, 1152, 1024, 513)
     one = jax.sharding.SingleDeviceSharding(topo.devices[0])
-    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
-    T, S = 2112, 64
+    bf = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                             sharding=one)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
+    T, S, items = 2112, 64, 16
+    lp = {"w_uk": bf(64, 192, 1024), "w_uv": bf(64, 1024, 128)}
 
-    def attend(q, entry, pos, cu, kv_lens, slots, ring):
+    def attend(lp, q_nope, q_pe, entry, pos, cu, kv_lens, slots, ring):
         batch = StepBatch(
             token_ids=None, positions=pos, slot_mapping=None,
             logits_indices=None, sampling=None, ssm_slots=slots,
             attn=AttentionMetadata(cu, kv_lens, None, jnp.int32(S)))
-        return ds._swa_attention(q, entry, batch, ring, 0, max_q_len=T, g=g)
+        return ds._swa_attention(lp, q_nope, q_pe, entry, batch, ring, 0,
+                                 max_q_len=T, g=g)
+
+    def flops(compiled):
+        cost = compiled.cost_analysis()
+        return (cost[0] if isinstance(cost, list) else cost)["flops"]
+
+    def flops_of(fn, *args):
+        return flops(jax.jit(fn).lower(*args).compile())
 
     t0 = time.monotonic()
     compiled = jax.jit(attend).lower(
-        sds((T, 64, 1152), jnp.bfloat16), sds((T, 1152), jnp.bfloat16),
-        sds((T,), jnp.int32), sds((S + 1,), jnp.int32), sds((S,), jnp.int32),
-        sds((S,), jnp.int32),
-        sds((3 * 65, 544, 1152), jnp.bfloat16)).compile()
+        lp, bf(T, 64, 192), bf(T, 64, 64), bf(T, 1152), i32(T), i32(S + 1),
+        i32(S), i32(S), bf(3 * 65, 544, 1152)).compile()
     mem = compiled.memory_analysis()
     print(f"\n[compile] dots3 windowed attention: "
           f"{time.monotonic() - t0:.1f}s, "
           f"{mem.temp_size_in_bytes / GiB:.3f} GiB temp")
-    assert mem.temp_size_in_bytes < 1.5 * GiB
+    assert mem.temp_size_in_bytes < 0.6 * GiB
+    assert not re.search(r"\[(2112|2240),64,(1024|1088|1152)\]",
+                         compiled.as_text())
+
+    mask = jax.ShapeDtypeStruct((128, 1184), jnp.bool_, sharding=one)
+    part = lambda n: (bf(n, 64, 256), bf(n, 64, 128),
+                      jax.ShapeDtypeStruct((128, n), jnp.bool_, sharding=one))
+    item = flops_of(
+        lambda q, parts: ds._attend_heads(q, parts, scale=g.scale),
+        bf(128, 64, 256), (part(544), part(640)))
+    whole = flops(compiled)
+    decompressed = whole + (items - 1) * item
+    absorbed = (
+        flops_of(lambda lp, q_nope, q_pe: ds._absorb(lp, q_nope, q_pe, g,
+                                                     q_nope.dtype),
+                 lp, bf(T, 64, 192), bf(T, 64, 64))
+        + items * flops_of(lambda q, keys, m: ds._attend(
+            q, keys, m, scale=g.scale, lora=g.lora),
+            bf(128, 64, 1152), bf(1184, 1152), mask)
+        + flops_of(ds._expand, lp, jax.ShapeDtypeStruct(
+            (T, 64, 1024), jnp.float32, sharding=one)))
+    print(f"[compile] dots3 windowed attention, GFLOP of a 2048-token "
+          f"chunk beside 63 rows: absorbed {absorbed / 1e9:.1f}, "
+          f"decompressed {decompressed / 1e9:.1f} ({item / 1e9:.1f} an "
+          f"item, {whole / 1e9:.1f} the program with its loops' bodies "
+          f"once)")
+    assert decompressed < absorbed / 3
+    assert item < 8e9
 
 
 # ---- dense latent attention at a.x-k1's widths ------------------------------
