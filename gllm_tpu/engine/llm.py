@@ -296,6 +296,13 @@ class LLM:
             refuse_for_rings(config)
         elif model_cfg.paged_windows:
             refuse_for_paged_windows(config)
+        if model_cfg.use_short_conv and config.parallel.world_size > 1:
+            raise ValueError(
+                "a model with gated short-convolution layers (layer_types: "
+                "conv) is served by one chip: its window pool is not "
+                "partitioned, its packed KV layout is one replica's and "
+                "the pp runner cuts no stages for it; not supported with "
+                "it: tp / pp / dp / sp > 1")
         if model_cfg.use_mamba and config.parallel.world_size > 1:
             raise ValueError(
                 "a model with Mamba-2 layers (layer_types: mamba or "

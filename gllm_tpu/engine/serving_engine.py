@@ -169,6 +169,41 @@ class StreamChunk:
     first_token: Optional[FirstToken] = None
 
 
+class _Emitter:
+    """The one thread that writes the middle tokens of every attached
+    stream (``RequestHandle.attach``). A step's chunks reach it as ONE
+    list, so a step wakes one thread where it woke a handler thread a
+    stream: at 128 streams the handlers' turns at the interpreter
+    (two a chunk, and a woken thread's way to them) took longer than
+    the step (my chip runs, PR 51: ``front.emit_lag_p95_ms`` 45 beside
+    a decode step of 18). Started with the first list posted."""
+
+    def __init__(self):
+        self._q: "queue.SimpleQueue[list]" = queue.SimpleQueue()
+        self._lock = threading.Lock()
+        self._thread: Optional[threading.Thread] = None
+
+    def post(self, batch: list) -> None:
+        if self._thread is None:
+            with self._lock:
+                if self._thread is None:
+                    self._thread = threading.Thread(
+                        target=self._run, name="gllm-emitter", daemon=True)
+                    self._thread.start()
+        self._q.put(batch)
+
+    def _run(self) -> None:
+        while True:
+            for handle, chunk in self._q.get():
+                try:
+                    handle.emit(chunk)
+                except Exception:   # noqa: BLE001 — the others' streams go on
+                    logger.exception("emitter: stream %s", handle.seq_id)
+
+
+EMITTER = _Emitter()
+
+
 class RequestHandle:
     # liveness poll interval for the bounded get below
     POLL_S = 0.5
@@ -176,7 +211,18 @@ class RequestHandle:
     def __init__(self, seq_id: int, prompt_len: int, engine=None):
         self.seq_id = seq_id
         self.prompt_len = prompt_len
-        self.chunks: "queue.Queue[StreamChunk]" = queue.Queue()
+        self.chunks: "queue.SimpleQueue[StreamChunk]" = queue.SimpleQueue()
+        # ``attach``: once a sink is attached every chunk goes by the
+        # emitter thread, in the order it was put. ``_sink`` writes a
+        # middle token onto the stream's socket and is dropped for good
+        # the first time it hands a chunk back; whatever it does not
+        # write goes on to ``chunks``, where the handler thread waits.
+        # ``unsent``: the tail of an event the socket did not take
+        # whole, for the handler thread to send before anything else.
+        self._lock = threading.Lock()
+        self._routed = False
+        self._sink = None
+        self.unsent = b""
         # when set, __iter__ polls engine liveness instead of blocking
         # forever on a queue a dead engine thread will never feed
         self._engine = engine
@@ -185,6 +231,48 @@ class RequestHandle:
         # been streamed — a replayed continuation could then re-emit or
         # contradict already-delivered structured output
         self.replay_safe = True
+
+    def attach(self, sink) -> bool:
+        """The handler thread offers ``sink(chunk) -> bool`` (True: the
+        chunk's event is on the socket) and goes to wait on ``chunks``.
+        Refused where a chunk is already waiting there: that stream
+        stays the handler thread's, as every stream was."""
+        with self._lock:
+            if not self.chunks.empty():
+                return False
+            self._sink, self._routed = sink, True
+            return True
+
+    def put(self, chunk: "StreamChunk", batch: Optional[list] = None
+            ) -> None:
+        """Every chunk of the stream comes through here. ``batch``: the
+        list the caller will post to the emitter itself (one list a
+        step)."""
+        if not self._routed:
+            with self._lock:
+                if not self._routed:
+                    self.chunks.put(chunk)
+                    return
+        if batch is not None:
+            batch.append((self, chunk))
+        else:
+            EMITTER.post([(self, chunk)])
+
+    def emit(self, chunk: "StreamChunk") -> None:
+        """On the emitter thread: a middle token to the sink, anything
+        else (the last chunk, an error, whatever the sink hands back or
+        fails on) to the handler thread, which deals with it as it
+        always has."""
+        sink = self._sink
+        if (sink is not None and chunk.finish_reason is None
+                and chunk.token_id is not None):
+            try:
+                if sink(chunk):
+                    return
+            except Exception:       # noqa: BLE001 — the handler's to see
+                pass
+            self._sink = None
+        self.chunks.put(chunk)
 
     def __iter__(self):
         while True:
@@ -218,10 +306,12 @@ EMIT_LAG_EVERY = 8
 
 
 def deliver_output(llm: LLM, out, handle: RequestHandle,
-                   emitted: dict, now: float = 0.0) -> None:
+                   emitted: dict, now: float = 0.0,
+                   batch: Optional[list] = None) -> None:
     """Turn one SeqOutput into a StreamChunk on the request's queue
     (shared by the single-host and multi-host serving engines). ``now``:
-    the step's time.monotonic(), for the emit-lag stamp."""
+    the step's time.monotonic(), for the emit-lag stamp. ``batch``: see
+    ``RequestHandle.put``."""
     text = ""
     final_text = None
     if llm.tokenizer is not None:
@@ -244,7 +334,7 @@ def deliver_output(llm: LLM, out, handle: RequestHandle,
             first = FirstToken(
                 out.seq, now or time.monotonic(),
                 llm.spans if getattr(llm, "tracing", False) else None)
-        handle.chunks.put(StreamChunk(
+        handle.put(StreamChunk(
             token_id=out.new_token_id,
             text=text,
             finish_reason=out.finish_reason,
@@ -256,7 +346,7 @@ def deliver_output(llm: LLM, out, handle: RequestHandle,
             final_text=final_text,
             t_deliver=(now if out.seq.num_output_tokens
                        % EMIT_LAG_EVERY == 1 else 0.0),
-            first_token=first))
+            first_token=first), batch)
     if out.finish_reason is not None:
         emitted.pop(out.seq.seq_id, None)
 
@@ -715,7 +805,7 @@ class ServingEngine:
             h = entry.handle
             if h is not None:
                 _M_ABORTED.inc()
-                h.chunks.put(StreamChunk(None, "", "abort",
+                h.put(StreamChunk(None, "", "abort",
                                          error="engine shutdown"))
         # stop serving peers, drain pending disk writes; host-tier
         # pages are NOT force-demoted here (an operator who wants the
@@ -754,10 +844,11 @@ class ServingEngine:
 
         A collected step's outputs are held as ``pending`` and handed
         to the handler threads only once the next step is on the device
-        (``LLM.step``'s ``after_dispatch`` seam): 32 woken handlers then
-        send their chunks while this thread is blocked in ``wait`` with
-        the interpreter released, and not while it builds the next step
-        with the device idle. Wherever the loop will not launch — nothing
+        (``LLM.step``'s ``after_dispatch`` seam): the emitter thread
+        (and a handler thread for each stream that is not attached to
+        it) then sends their chunks while this thread is blocked in
+        ``wait`` with the interpreter released, and not while it builds
+        the next step with the device idle. Wherever the loop will not launch — nothing
         left to run, a step that dispatched nothing, a failed step, a
         deadline about to close a stream, the loop's exit — the pending
         outputs are flushed at once; a superseded generation drops them
@@ -913,11 +1004,12 @@ class ServingEngine:
         """One step's outputs to their handles and the journal (the
         ``deliver`` phase)."""
         now = time.monotonic()
+        batch: list = []
         for out in outputs:
             handle = self._handles.get(out.seq.seq_id)
             if handle is None:
                 continue
-            deliver_output(llm, out, handle, self._emitted, now)
+            deliver_output(llm, out, handle, self._emitted, now, batch)
             if self._journal is not None:
                 if out.new_token_id is not None:
                     # DELIVERED = committed: replay continues from
@@ -933,6 +1025,8 @@ class ServingEngine:
                     self._deadlines.pop(out.seq.seq_id, None)
                     _M_ACTIVE.set(len(self._handles))
                 self._emitted.pop(out.seq.seq_id, None)
+        if batch:
+            EMITTER.post(batch)
 
     # ---- fault isolation ---------------------------------------------------
 
@@ -1062,7 +1156,7 @@ class ServingEngine:
             if h is None:
                 continue
             _M_ABORTED.inc()
-            h.chunks.put(StreamChunk(
+            h.put(StreamChunk(
                 None, "", "error",
                 error=f"engine crash-looped during recovery: {why}"))
         self._close_open_handles("error", why)
@@ -1123,7 +1217,7 @@ class ServingEngine:
                 continue
             _M_REPLAYED.inc(outcome="unsafe")
             _M_ABORTED.inc()
-            handle.chunks.put(StreamChunk(
+            handle.put(StreamChunk(
                 None, "", "error",
                 error=("engine is rebuilding after a fault and this "
                        f"request is not replay-safe ({why}); retry "
@@ -1169,14 +1263,14 @@ class ServingEngine:
                 dropped += 1
                 _M_REPLAYED.inc(outcome="aborted")
                 _M_ABORTED.inc()
-                h.chunks.put(StreamChunk(None, "", "abort"))
+                h.put(StreamChunk(None, "", "abort"))
                 continue
             if entry.deadline is not None and now >= entry.deadline:
                 dropped += 1
                 _M_REPLAYED.inc(outcome="expired")
                 _M_DEADLINE.inc()
                 _M_ABORTED.inc()
-                h.chunks.put(StreamChunk(None, "", "deadline"))
+                h.put(StreamChunk(None, "", "deadline"))
                 continue
             sp = copy.deepcopy(entry.sampling)
             with self._lock:
@@ -1259,7 +1353,7 @@ class ServingEngine:
             self._journal.pop(seq_id)
         if handle is not None:
             _M_ABORTED.inc()
-            handle.chunks.put(StreamChunk(None, "", reason or "error",
+            handle.put(StreamChunk(None, "", reason or "error",
                                           error=detail))
 
     def _close_open_handles(self, reason: str,
@@ -1284,7 +1378,7 @@ class ServingEngine:
                 self.llm.spans.finish(h.seq_id, reason or "error",
                                       now)
         for h in handles:
-            h.chunks.put(StreamChunk(None, "", reason, error=detail))
+            h.put(StreamChunk(None, "", reason, error=detail))
 
     # ---- watchdog ----------------------------------------------------------
 

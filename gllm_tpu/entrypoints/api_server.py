@@ -39,12 +39,13 @@ logger = logging.getLogger(__name__)
 
 # How long a committed token waits for the socket: deliver_output's stamp
 # on the chunk (engine thread; a stream's first token and every eighth
-# after it) to this thread's flush of the SSE event that carries it. The
-# handler threads share one interpreter with the engine thread; this is
-# their share of the gap between tokens.
+# after it) to the flush of the SSE event that carries it (``_sse`` on the
+# stream's handler thread, ``_sink`` on the emitter thread). Those threads
+# share one interpreter with the engine thread; this is their share of
+# the gap between tokens.
 _M_EMIT_LAG = obs_metrics.histogram(
     "gllm_http_emit_lag_seconds",
-    "deliver_output's stamp of a token to the handler thread's flush of "
+    "deliver_output's stamp of a token to the flush of "
     "the SSE chunk that carries it (a stream's first token and every "
     "eighth after it)",
     buckets=(5e-5, 1e-4, 2e-4, 5e-4, 1e-3, 2e-3, 5e-3, 1e-2, 2e-2, 5e-2,
@@ -245,13 +246,46 @@ class Handler(BaseHTTPRequestHandler):
         thread writes its ``first_token`` event."""
         self.wfile.write(b"data: " + json.dumps(obj).encode() + b"\n\n")
         self.wfile.flush()
-        if chunk is not None and (chunk.t_deliver
-                                  or chunk.first_token is not None):
+        if chunk is not None:
+            self._sent(chunk)
+
+    @staticmethod
+    def _sent(chunk) -> None:
+        """``chunk``'s event is on the socket: its stamps, where it
+        carries any."""
+        if chunk.t_deliver or chunk.first_token is not None:
             now = time.monotonic()
             if chunk.t_deliver:
                 _M_EMIT_LAG.observe(now - chunk.t_deliver)
             if chunk.first_token is not None:
                 chunk.first_token.record(now)
+
+    def _sink(self, handle, make_chunk):
+        """What ``RequestHandle.attach`` is offered for a plain stream:
+        a middle token's event written from the emitter thread, so that
+        a step's tokens cost one thread's turns at the interpreter and
+        not a turn of every handler thread. The socket is never waited
+        for there: what it does not take at once is this thread's to
+        send (``handle.unsent``), and so is everything after it. With a
+        fault point armed the chunk goes the handler thread's way, past
+        the points in ``_stream``."""
+        conn = self.connection
+
+        def sink(chunk) -> bool:
+            if faults.FAULTS.active:
+                return False
+            data = (b"data: " + json.dumps(make_chunk(
+                chunk.text or "", None)).encode() + b"\n\n")
+            try:
+                n = conn.send(data, socket.MSG_DONTWAIT)
+            except BlockingIOError:
+                n = 0
+            if n < len(data):
+                handle.unsent = data[n:]
+                raise BlockingIOError
+            self._sent(chunk)
+            return True
+        return sink
 
     # ---- routes -----------------------------------------------------------
 
@@ -903,7 +937,15 @@ class Handler(BaseHTTPRequestHandler):
                 push_to=None, prompt_ids=None):
         pushed_pages = None
         try:
+            if not router and getattr(self, "connection", None) is not None:
+                handle.attach(self._sink(handle, make_chunk))
             for chunk in handle:
+                if handle.unsent:
+                    self.wfile.write(handle.unsent)
+                    handle.unsent = b""
+                    # this chunk's own event, which the sink had begun
+                    self._sent(chunk)
+                    continue
                 # chaos points (docs/robustness.md#fleet): replica_kill
                 # hard-closes the connection mid-stream — from a front
                 # router's side this is the serving process dying;

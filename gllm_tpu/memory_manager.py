@@ -135,7 +135,8 @@ class MemoryManager:
         self.ssm_working_slots = ssm_working_slots
         self.ssm_snapshot_slots = ssm_snapshot_slots
         # tokens in a chunk of the recurrent layers' chunked rule
-        # (ModelConfig.ssm_chunk): the scheduler's cap on multi-token rows
+        # (ModelConfig.ssm_chunk; 0 where the slot state has none): the
+        # scheduler's cap on multi-token rows
         self.ssm_chunk = ssm_chunk
         # the gauge the working slots are counted in (the engine names
         # _M_SWA_SLOTS where the slots are window rings)

@@ -105,6 +105,10 @@ class ModelConfig:
     # scores). Only the last two have a group limit, whatever ``n_group``
     # says (skt/A.X-K1 states n_group 8 beside topk_method "none")
     topk_method: str = "greedy"
+    # what ``norm_topk_prob`` adds to the chosen scores' sum before it
+    # divides by it: a constant of the published model (DeepSeek's 1e-20;
+    # lfm2_moe's 1e-6)
+    route_norm_eps: float = 1e-20
 
     @property
     def route_groups(self) -> int:
@@ -328,12 +332,32 @@ class ModelConfig:
                 or "parallel_hybrid" in self.layer_types)
 
     @property
+    def use_short_conv(self) -> bool:
+        """Gated short-convolution layers (lfm2_moe's "conv" layers,
+        ops/short_conv.py): a layer's whole state is its convolution's
+        window, ``linear_conv_kernel_dim - 1`` rows of the hidden size."""
+        return "conv" in self.layer_types
+
+    @property
     def use_hybrid(self) -> bool:
-        """A layer of this model holds per-sequence RECURRENT state in
-        the slot pool (``ssm_slot_shapes`` says what a slot of it stores):
-        Gated-DeltaNet or Mamba-2 layers. The one test every fence of the
+        """A layer of this model holds per-sequence state in the slot
+        pool (``ssm_slot_shapes`` says what a slot of it stores):
+        Gated-DeltaNet or Mamba-2 layers' RECURRENT state, or a gated
+        short convolution's window alone. The one test every fence of the
         runner, the pp runner and the engine reads."""
-        return "linear_attention" in self.layer_types or self.use_mamba
+        return ("linear_attention" in self.layer_types or self.use_mamba
+                or self.use_short_conv)
+
+    @property
+    def ssm_chunked_rule(self) -> bool:
+        """Do this model's slot-pool layers run rows of more than one
+        token through a chunked rule in the packed layout of whole chunks
+        (ops/gdn.gdn_chunk_slots)? Gated-DeltaNet and Mamba-2 do; a short
+        convolution reads a token's predecessors over the flat token axis
+        and has no such layout. The scheduler's cap on multi-token rows,
+        ``BatchBuilder.shape_signature``'s choice of bucket and the
+        runner's allowance for the rule's temporaries read this."""
+        return self.use_hybrid and not self.use_short_conv
 
     @property
     def mamba_d_inner(self) -> int:
@@ -343,13 +367,17 @@ class ModelConfig:
     def ssm_slot_shapes(self) -> Tuple[Tuple[int, ...], Tuple[int, ...]]:
         """What one slot of one recurrent layer stores, float32: (the
         convolution's window [taps - 1, channels], the recurrent state
-        [heads or groups of heads, rows, lanes]). A Mamba-2 state is a
+        [heads or groups of heads, rows, lanes], or () where a layer keeps
+        none: a gated short convolution). A Mamba-2 state is a
         head's own [head_dim, state]; the Gated-DeltaNet states lie g
         heads abreast, [Nv / g, Dk, g x Dv] (``ops/gdn.pack_state``: g =
         2 and 384 lanes at Olmo-Hybrid's 192, g = 1 at Qwen3-Next's
         128), so that the lanes are whole 128-lane tiles and the TPU
         stores the elements alone."""
         taps = self.linear_conv_kernel_dim - 1
+        if self.use_short_conv:
+            # the window is the whole state: no second shape
+            return ((taps, self.hidden_size), ())
         if self.use_mamba:
             return ((taps, self.gdn_conv_dim),
                     (self.mamba_num_heads, self.mamba_head_dim,
@@ -363,8 +391,11 @@ class ModelConfig:
     @property
     def ssm_chunk(self) -> int:
         """Tokens in a chunk of the recurrent layers' chunked rule (the
-        packed layout of a mixed step: ops/gdn.gdn_chunk_slots)."""
+        packed layout of a mixed step: ops/gdn.gdn_chunk_slots); 0 where
+        they have no chunked rule (``ssm_chunked_rule``)."""
         from gllm_tpu.ops.gdn import GDN_CHUNK
+        if self.use_short_conv:
+            return 0
         return self.mamba_chunk_size if self.use_mamba else GDN_CHUNK
 
     @property
@@ -404,9 +435,10 @@ class ModelConfig:
 
     @property
     def num_linear_layers(self) -> int:
-        """Layers of this stage that hold recurrent state."""
+        """Layers of this stage that hold a slot of the pool."""
         return sum(1 for t in self.stage_layer_types
-                   if t in ("linear_attention", "mamba", "parallel_hybrid"))
+                   if t in ("linear_attention", "mamba", "parallel_hybrid",
+                            "conv"))
 
     @property
     def num_moe_layers(self) -> int:
@@ -414,7 +446,9 @@ class ModelConfig:
 
     @property
     def gdn_conv_dim(self) -> int:
-        """Channels of a recurrent layer's short convolution."""
+        """Channels of a slot-pool layer's short convolution."""
+        if self.use_short_conv:
+            return self.hidden_size
         if self.use_mamba:
             return (self.mamba_d_inner
                     + 2 * self.mamba_n_groups * self.ssm_state_size)
@@ -466,7 +500,8 @@ _ARCH_OF_MODEL_TYPE = {"olmo_hybrid": "OlmoHybridForCausalLM",
                        "axk1": "AXK1ForCausalLM",
                        "nemotron_h": "NemotronHForCausalLM",
                        "cohere2_moe": "Cohere2MoeForCausalLM",
-                       "falcon_h1": "FalconH1ForCausalLM"}
+                       "falcon_h1": "FalconH1ForCausalLM",
+                       "lfm2_moe": "Lfm2MoeForCausalLM"}
 
 # hybrid_override_pattern's letters (NemotronH)
 _NEMOTRON_KINDS = {"M": "mamba", "E": "moe", "*": "full_attention"}
@@ -564,6 +599,52 @@ def _falcon_h1(hf: Dict[str, Any]):
         attn_output_gate=False, norm_zero_centered=False)
     # the published base is the integer 100000000000, more than 32 bits
     return {**hf, "rope_theta": float(hf.get("rope_theta", 1e4))}, extra
+
+
+def _lfm2_moe(hf: Dict[str, Any]):
+    """config.json of LiquidAI/LFM2-24B-A2B (model_type lfm2_moe) -> (the
+    keys ``from_hf_config`` reads, the extra fields). A layer is an
+    operator and a feed-forward, each behind its RMSNorm: the operator a
+    gated short convolution ("conv": ``conv_L_cache`` taps, no bias, no
+    activation) or GQA with a per-head q / k norm and rotary embedding
+    over the whole head; the feed-forward a SwiGLU of ``intermediate_size``
+    in the first ``num_dense_layers`` layers and, behind them,
+    ``num_experts`` routed experts of ``moe_intermediate_size`` under a
+    sigmoid router whose choice is corrected by ``expert_bias``
+    (``use_expert_bias``), no shared expert. The row gives no ``head_dim``
+    (hidden / heads) and no key for the tied head (the family's
+    default)."""
+    unserved = [("conv_bias", False), ("norm_topk_prob", True),
+                ("hidden_act", "silu"), ("attention_bias", False)]
+    bad = [f"{k}={hf[k]!r}" for k, want in unserved
+           if k in hf and hf[k] != want]
+    types = tuple(hf.get("layer_types") or ())
+    if len(types) != hf["num_hidden_layers"] or set(types) - {
+            "conv", "full_attention"}:
+        bad.append(f"layer_types={types!r} for "
+                   f"{hf['num_hidden_layers']} layers")
+    if bad:
+        raise ValueError(
+            "lfm2_moe: models/lfm2_moe.py serves the published block ("
+            + ", ".join(f"{k}={w!r}" for k, w in unserved)
+            + ', layers "conv" | "full_attention"), not ' + ", ".join(bad))
+    rope = hf.get("rope_parameters") or {}
+    extra = dict(layer_types=types,
+                 linear_conv_kernel_dim=hf.get("conv_L_cache", 3),
+                 route_norm_eps=1e-6, attn_output_gate=False,
+                 norm_zero_centered=False)
+    hf = {**hf,
+          "rms_norm_eps": hf.get("norm_eps", 1e-5),
+          "rope_theta": float(rope.get("rope_theta",
+                                       hf.get("rope_theta", 1e6))),
+          "rope_scaling": None,
+          "first_k_dense_replace": hf.get("num_dense_layers", 0),
+          "tie_word_embeddings": hf.get(
+              "tie_word_embeddings", hf.get("tie_embedding", True)),
+          "scoring_func": "sigmoid",
+          "topk_method": ("noaux_tc" if hf.get("use_expert_bias", True)
+                          else "none")}
+    return hf, extra
 
 
 def from_hf_config(hf: Dict[str, Any]) -> ModelConfig:
@@ -759,22 +840,27 @@ def from_hf_config(hf: Dict[str, Any]) -> ModelConfig:
         hf, extra = _cohere2_moe(hf)
     if arch == "FalconH1ForCausalLM":
         hf, extra = _falcon_h1(hf)
+    if arch == "Lfm2MoeForCausalLM":
+        hf, extra = _lfm2_moe(hf)
     share = hf.get("ep_share")
     if share:
         # this repo's own key, read for every family that holds a share of
         # its experts: {"chips", "rank", <count>} where <count> is the
         # config's own key for the number of routed experts
-        # (``n_routed_experts``; ``num_experts`` for cohere2_moe) says
+        # (``n_routed_experts``; ``num_experts`` for cohere2_moe and
+        # lfm2_moe) says
         # that this key of the config counts the experts HELD here, one
         # of ``chips`` equal shares of the published count
-        from gllm_tpu.models.registry import (_COHERE2_MOE_ARCHS, _MLA_ARCHS,
+        from gllm_tpu.models.registry import (_COHERE2_MOE_ARCHS,
+                                              _LFM2_MOE_ARCHS, _MLA_ARCHS,
                                               _NEMOTRON_H_ARCHS)
-        if arch not in _MLA_ARCHS + _NEMOTRON_H_ARCHS + _COHERE2_MOE_ARCHS:
+        own_count = _COHERE2_MOE_ARCHS + _LFM2_MOE_ARCHS
+        if arch not in _MLA_ARCHS + _NEMOTRON_H_ARCHS + own_count:
             raise ValueError(f"ep_share: {arch} holds no share of its "
                              "experts (the families of models/deepseek.py,"
-                             " nemotron_h.py and cohere2_moe.py do)")
-        count = ("num_experts" if arch in _COHERE2_MOE_ARCHS
-                 else "n_routed_experts")
+                             " nemotron_h.py, cohere2_moe.py and "
+                             "lfm2_moe.py do)")
+        count = "num_experts" if arch in own_count else "n_routed_experts"
         held = hf[count]
         if share[count] != held * share["chips"]:
             raise ValueError(
@@ -789,6 +875,7 @@ def from_hf_config(hf: Dict[str, Any]) -> ModelConfig:
     qk_norm = arch in ("Qwen3ForCausalLM", "Qwen3MoeForCausalLM",
                        "Qwen3NextForCausalLM", "Qwen3_5ForCausalLM",
                        "Qwen3_5MoeForCausalLM", "OlmoHybridForCausalLM",
+                       "Lfm2MoeForCausalLM",
                        "Qwen3VLForConditionalGeneration",
                        "Qwen3VLMoeForConditionalGeneration")
     is_glm4 = arch in ("Glm4ForCausalLM",)

@@ -331,7 +331,8 @@ def deepseek_route(router_logits: jnp.ndarray, e_bias: Optional[jnp.ndarray],
     _, ids = jax.lax.top_k(choice, K)
     weights = jnp.take_along_axis(scores, ids, axis=-1)
     if cfg.norm_topk_prob:
-        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
+                             + cfg.route_norm_eps)
     weights = weights * cfg.routed_scaling_factor
     return weights, ids.astype(jnp.int32)
 

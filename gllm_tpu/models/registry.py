@@ -182,6 +182,20 @@ def get_model_def(cfg: ModelConfig) -> ModelDef:
             param_specs=falcon_h1.no_mesh_specs,
             kv_specs=falcon_h1.no_mesh_specs,
         )
+    if cfg.architecture in _LFM2_MOE_ARCHS:
+        from gllm_tpu.models import lfm2_moe
+        return ModelDef(
+            family="lfm2_moe",
+            init_params=lfm2_moe.init_params,
+            forward=lfm2_moe.forward,
+            compute_logits=lfm2_moe.compute_logits,
+            make_rope_table=lfm2_moe.make_rope_table,
+            load_params=lfm2_moe.load_params,
+            init_kv_cache=lfm2_moe.init_kv_cache,
+            param_specs=lfm2_moe.no_mesh_specs,
+            kv_specs=lfm2_moe.no_mesh_specs,
+            startup_line=lfm2_moe.startup_line,
+        )
     raise NotImplementedError(
         f"architecture {cfg.architecture!r} not supported yet; "
         f"dense: {_DENSE_ARCHS}, moe: {_MOE_ARCHS}, mla: {_MLA_ARCHS}, "
@@ -258,6 +272,15 @@ _FALCON_H1_ARCHS = (
 )
 
 
+_LFM2_MOE_ARCHS = (
+    # LiquidAI/LFM2-24B-A2B (model_type lfm2_moe): a gated short
+    # convolution (its window the only state, in the slot pool) or GQA with
+    # heads of 64 in lane-packed pairs, then a SwiGLU or sigmoid-routed
+    # experts, in every layer (models/lfm2_moe.py)
+    "Lfm2MoeForCausalLM",
+)
+
+
 def supported_architectures() -> Dict[str, str]:
     out = {a: "dense" for a in _DENSE_ARCHS}
     out.update({a: "moe" for a in _MOE_ARCHS})
@@ -269,4 +292,5 @@ def supported_architectures() -> Dict[str, str]:
     out.update({a: "nemotron_h" for a in _NEMOTRON_H_ARCHS})
     out.update({a: "cohere2_moe" for a in _COHERE2_MOE_ARCHS})
     out.update({a: "falcon_h1" for a in _FALCON_H1_ARCHS})
+    out.update({a: "lfm2_moe" for a in _LFM2_MOE_ARCHS})
     return out

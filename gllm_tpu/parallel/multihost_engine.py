@@ -728,11 +728,11 @@ class MultihostServingEngine:
                 _, sid, reason = evt
                 h = self._handles.pop(sid, None)
                 if h is not None:
-                    h.chunks.put(StreamChunk(None, "", reason or "error"))
+                    h.put(StreamChunk(None, "", reason or "error"))
                 return
             if evt[0] == "fail":
                 for h in list(self._handles.values()):
-                    h.chunks.put(StreamChunk(None, "", "error"))
+                    h.put(StreamChunk(None, "", "error"))
                 self._handles.clear()
                 self._emitted.clear()
                 return
@@ -797,7 +797,7 @@ class MultihostServingEngine:
         self._emitted.pop(seq_id, None)
         if h is not None:
             from gllm_tpu.engine.serving_engine import StreamChunk
-            h.chunks.put(StreamChunk(None, "", "abort"))
+            h.put(StreamChunk(None, "", "abort"))
 
     def shutdown(self) -> None:
         self.engine.shutdown()

@@ -65,9 +65,21 @@ _M_MAMBA_CHUNK_TOKENS = obs.counter(
     "gllm_mamba_chunk_tokens_total",
     "real tokens in those layouts (the new tokens of the rows that "
     "prefilled)")
+# a gated short convolution (ops/short_conv.py) has one path for every
+# row and no packed layout: its rows by the kind of row, and the tokens of
+# the rows that held more than one
+_M_SCONV_ROWS = obs.counter(
+    "gllm_sconv_rows_total",
+    "rows of a short-convolution model's steps through the operator, by "
+    "kind: decode (one new token) or chunk (more)", ("kind",))
+_M_SCONV_CHUNK_TOKENS = obs.counter(
+    "gllm_sconv_chunk_tokens_total",
+    "new tokens of the rows that went through the operator with more "
+    "than one (prompt chunks)")
 _SSM_COUNTERS = {
     "gdn": (_M_GDN_ROWS, _M_GDN_CHUNK_SLOTS, _M_GDN_CHUNK_TOKENS),
     "mamba": (_M_MAMBA_ROWS, _M_MAMBA_CHUNK_SLOTS, _M_MAMBA_CHUNK_TOKENS),
+    "sconv": (_M_SCONV_ROWS, None, _M_SCONV_CHUNK_TOKENS),
 }
 
 
@@ -87,7 +99,9 @@ class BatchBuilder:
         self.use_mm = use_mm
         self.use_ssm = use_ssm
         # the recurrent layers' chunk (ModelConfig.ssm_chunk) sizes a
-        # mixed step's packed layout; their kind names the counters
+        # mixed step's packed layout (0: the slot state has no chunked
+        # rule and no layout, ModelConfig.ssm_chunked_rule); their kind
+        # names the counters
         self.ssm_chunk = ssm_chunk
         self._m_rows, self._m_slots, self._m_tokens = _SSM_COUNTERS[ssm_kind]
         # a per-sequence slot rides the batch (``ssm_slots``): recurrent
@@ -125,7 +139,7 @@ class BatchBuilder:
         else:
             t = bucket_size(sum(rows), self.min_token_bucket,
                             self.max_tokens)
-            if self.use_ssm:
+            if self.use_ssm and self.ssm_chunk:
                 # the rows that prefill run the chunked rule in a packed
                 # layout sized by the token bucket (models/hybrid.py): take
                 # the first bucket whose layout holds them (many short
@@ -469,7 +483,14 @@ class BatchBuilder:
             ssm_slots[:K] = np.fromiter(
                 (getattr(it.seq, "ssm_slot", None) or 0 for it in items),
                 np.int32, count=K)
-        if self.use_ssm:
+        if self.use_ssm and not self.ssm_chunk:
+            # no chunked rule, no layout: rows by kind, and their tokens
+            n_chunk = int((ns > 1).sum())
+            self._m_rows.inc(K - n_chunk, kind="decode")
+            if n_chunk:
+                self._m_rows.inc(n_chunk, kind="chunk")
+                self._m_tokens.inc(int(ns[ns > 1].sum()))
+        elif self.use_ssm:
             n_chunk = int((ns > 1).sum())
             self._m_rows.inc(K - n_chunk, path="recurrent")
             if n_chunk:

@@ -221,7 +221,8 @@ def _ssm_update(conv, rec, idx, snap_src, snap_dst, zero_slots, rest_src,
         return jax.lax.dynamic_update_slice(pool, slot, at(dst[i]))
 
     def move(i, pools):
-        return tuple(moved(pool, i) for pool in pools)
+        # a model without a recurrent stack hands None for it
+        return jax.tree.map(lambda pool: moved(pool, i), pools)
 
     return jax.lax.fori_loop(0, jnp.sum(dst != 0), move, (conv, rec))
 
@@ -325,6 +326,11 @@ def pick_kv_pack(cfg: ModelConfig, tp_sharded: bool) -> int:
     the pack factor (2/4 adjacent kv heads per 128-lane cache row) for
     head_dim < 128 models. Packing is a single-replica layout: tp/dp
     shard the unpacked specs, so sharded meshes need native alignment.
+    models/hybrid.py and the Mamba-2 decoders build their paged cache
+    themselves (``kv_cache_heads``: padded KV heads, no ``kv_pack``), so
+    they are never packed; a slot-pool model whose decoder serves its
+    attention through ``dense._attention`` over a cache built with
+    ``kv_pack`` (models/lfm2_moe.py) packs as a dense model does.
 
     On the CPU backend the kernels run in interpret mode, which has no
     Mosaic lane constraints (same escape as ops/gdn.py) — any layout is
@@ -338,7 +344,7 @@ def pick_kv_pack(cfg: ModelConfig, tp_sharded: bool) -> int:
             return 1 if cfg.kv_lora_rank % 128 == 0 else 0
         if cfg.head_dim % 128 == 0:
             return 1
-        if tp_sharded or cfg.use_hybrid:
+        if tp_sharded or (cfg.use_hybrid and not cfg.use_short_conv):
             return 0
         for p in (2, 4):
             if cfg.head_dim * p % 128 == 0 and cfg.num_kv_heads % p == 0:
@@ -412,10 +418,30 @@ def resolve_attn_impl(impl: str, cfg: ModelConfig, tp: int, pack: int,
     if not pack:
         why = ("no 128-lane-aligned KV layout: head_dim (x pack 2/4) % 128 "
                "== 0, or kv_lora_rank % 128 == 0 for MLA, and no lane "
-               "packing under tp or for hybrid models")
+               "packing under tp or for the decoders that build their own "
+               "paged cache (models/hybrid.py, the Mamba-2 models)")
     elif tp_sharded and not pallas_tp_ok(cfg, tp):
         why = f"head counts do not divide over tp={tp}"
-    if cfg.use_hybrid:
+    if cfg.use_short_conv:
+        # which kernel serves which kind of step of the attention layers;
+        # the operator has no recurrent kernel
+        from gllm_tpu.ops.attention import DECODE_ROWS_NAME
+        kinds = cfg.stage_layer_types
+        logger.info(
+            "[startup] short-convolution model (%d conv + %d attention "
+            "layers): attention (%d query heads over %d KV heads of %d) "
+            "-> %s; the gated short convolution (%d taps, a window of %d "
+            "rows its whole state) -> xla (ops/short_conv.py: one gather "
+            "over the flat token axis, no recurrent kernel, no chunked "
+            "rule)", kinds.count("conv"), kinds.count("full_attention"),
+            cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
+            ("pallas over kv_pack %d (%d KV heads abreast in a 128-lane "
+             "cache row): paged_decode_attention (decode steps), "
+             "ragged_paged_attention (a mixed step's chunks) and %s (its "
+             "decoding rows)" % (pack, pack, DECODE_ROWS_NAME))
+            if why is None else f"xla ({why})",
+            cfg.linear_conv_kernel_dim, cfg.linear_conv_kernel_dim - 1)
+    elif cfg.use_hybrid:
         # the two kinds of layer choose apart: the recurrent layers' head
         # dims (96 / 192 in Olmo-Hybrid, 64 / 128 in NemotronH) say nothing
         # about the full-attention layers' kernels
@@ -649,6 +675,8 @@ class ModelRunner:
                                     mm_embed_dim=model_cfg.mm_embed_dim,
                                     ssm_chunk=model_cfg.ssm_chunk,
                                     ssm_kind=("mamba" if model_cfg.use_mamba
+                                              else "sconv"
+                                              if model_cfg.use_short_conv
                                               else "gdn"))
         if model_cfg.use_mm:
             from gllm_tpu.utils import LRUBytesCache
@@ -742,11 +770,10 @@ class ModelRunner:
             self.ssm_working_slots = self.ssm_snapshot_slots = 0
         self.num_pages = (config.cache.num_pages
                           or self.determine_num_pages())
+        kw = {"kv_pack": self.kv_pack} if self.kv_pack > 1 else {}
         if model_cfg.use_seq_slots:
-            kw = {"num_slots": (1 + self.ssm_working_slots
-                                + self.ssm_snapshot_slots)}
-        else:
-            kw = {"kv_pack": self.kv_pack} if self.kv_pack > 1 else {}
+            kw["num_slots"] = (1 + self.ssm_working_slots
+                               + self.ssm_snapshot_slots)
 
         def make_kv():
             kv = self.model_def.init_kv_cache(
@@ -830,6 +857,21 @@ class ModelRunner:
                     experts = "pallas gmm (ops/pallas/grouped_matmul.py)"
                 logger.info("[startup] held experts: grouped products -> "
                             "%s", experts)
+        if model_cfg.use_short_conv:
+            # the window pool, in one line beside the model's own
+            # (``ModelDef.startup_line``)
+            slots = 1 + self.ssm_working_slots + self.ssm_snapshot_slots
+            Lc = model_cfg.num_linear_layers
+            logger.info(
+                "[startup] window pool: %d slots x %d conv layers x %d "
+                "bytes as the TPU stores them = %d bytes (a layer's slot: "
+                "%s float32, its whole state; no recurrent stack); KV "
+                "rows: %d KV heads of %d lanes, %d abreast in a cache row "
+                "(kv_pack %d)", slots, Lc,
+                self._ssm_pool_bytes() // (Lc * slots),
+                self._ssm_pool_bytes(), model_cfg.ssm_slot_shapes[0],
+                model_cfg.num_kv_heads, model_cfg.head_dim, self.kv_pack,
+                self.kv_pack)
         if model_cfg.dense_mla:
             # dense latent attention: what the chip holds, in one line
             # beside the line that says which kernel serves which kind of
@@ -1068,10 +1110,16 @@ class ModelRunner:
         if not cfg.use_hybrid:
             return 0
         slots = 1 + self.ssm_working_slots + self.ssm_snapshot_slots
-        (taps, channels), (heads, rows, lanes) = cfg.ssm_slot_shapes
+        (taps, channels), state = cfg.ssm_slot_shapes
 
         def up(n, m):
             return -(-n // m) * m
+        if not state:
+            # the window is all there is (a gated short convolution): the
+            # compiler tiles the pool's [2, channels] face (2, 128), its
+            # elements' bytes
+            return cfg.num_linear_layers * slots * taps * channels * 4
+        heads, rows, lanes = state
         rec = slots * heads * up(rows, 8) * up(lanes, 128)
         conv = up(slots, 8) * channels * taps
         return cfg.num_linear_layers * (rec + conv) * 4
@@ -1089,7 +1137,7 @@ class ModelRunner:
         1.04 GiB in all by the TPU compiler's count (the 1024-token
         bucket's 0.61: tests/test_tpu_compile.py)."""
         cfg = self.model_cfg
-        if not cfg.use_hybrid:
+        if not cfg.ssm_chunked_rule:
             return 0
         from gllm_tpu.ops.gdn import gdn_chunk_slots
         n, c = gdn_chunk_slots(self.builder.max_tokens,

@@ -253,13 +253,15 @@ class Scheduler:
         self._seq_bucket_cap = min(config.max_num_seqs,
                                    self.sched_cfg.max_decode_seqs
                                    + self.sched_cfg.max_prefill_tokens)
-        # Hybrid models: rows with more than one new token (prefill
+        # Hybrid models whose slot state has a chunked rule
+        # (ModelConfig.ssm_chunked_rule; ``ssm_chunk`` 0 says it has
+        # none): rows with more than one new token (prefill
         # chunks, decode rows with drafts) run the GDN layers' chunked
         # rule in a packed layout of whole chunks; a step holds as many
         # as the largest token bucket's layout takes
         # (ops/gdn.gdn_chunk_rows_cap, BatchBuilder.shape_signature)
         self._chunk_rows_cap: Optional[int] = None
-        if self.mm.use_ssm:
+        if self.mm.use_ssm and self.mm.ssm_chunk:
             from gllm_tpu.ops.gdn import gdn_chunk_rows_cap
             spec_rows = config.spec_k if config.spec_decode else 0
             self._chunk_rows_cap = gdn_chunk_rows_cap(

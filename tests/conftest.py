@@ -138,6 +138,32 @@ _XFAIL = {
         "here). tests/perfbench/test_kernels_cohere2_moe.py holds what it "
         "held: PR 41's ten are its cell's alone, and every list that named "
         "all cells names all six",
+    # ISSUE 51 appends its cell to the slot gauge's list
+    # (``kv.ssm_slots_peak_pct``: four cells now), and the accepted tests
+    # of PR 44 and PR 48 count every metric that names four or more cells
+    # as one that names ALL cells.
+    "test_kernels_cohere2_moe.py::"
+    "test_every_new_metric_is_this_cells_alone_and_has_its_reader":
+        "the accepted test takes every metric that names four or more "
+        "cells for one that names all of them; ISSUE 51 appends "
+        "lfm2-24b-a2b.reason to the slot gauge's list, which then names "
+        "four (the slot-pool cells) and not all; a benchmark PR has to "
+        "pin the lists by name "
+        "(tests/perfbench/test_kernels_cohere2_moe.py may not be edited "
+        "here). tests/perfbench/test_kernels_lfm2_moe.py holds what it "
+        "held: the cell's ten metrics are its alone, and every list that "
+        "named all cells names all eight",
+    "test_kernels_falcon_h1.py::"
+    "test_every_new_metric_is_this_cells_alone_and_has_its_reader":
+        "the accepted test takes every metric that names four or more "
+        "cells for one that names all of them and pins the slot gauge's "
+        "list to three cells; ISSUE 51 appends lfm2-24b-a2b.reason to "
+        "that list; a benchmark PR has to pin the lists by name "
+        "(tests/perfbench/test_kernels_falcon_h1.py may not be edited "
+        "here). tests/perfbench/test_kernels_lfm2_moe.py holds what it "
+        "held: PR 48's eight are its cell's alone, every list that named "
+        "all cells names all eight, and the gauge's list is the four "
+        "slot-pool cells",
 }
 
 
@@ -157,7 +183,7 @@ _LONGEST_FIRST = (
     "test_pallas_decode_attention.py", "test_hybrid_qwen3next.py",
     "test_hybrid_olmo.py", "test_prepared_launch.py",
     "perfbench/test_reference_nemotron_h.py", "test_falcon_h1.py",
-    "test_spec_fused.py",
+    "test_lfm2_moe.py", "test_spec_fused.py",
     "perfbench/test_reference_olmo_hybrid.py", "test_pipeline_parallel.py",
     "test_spec_decode.py", "test_moe_models.py",
     "test_pallas_ragged_attention.py",

@@ -443,7 +443,8 @@ def test_the_manifest_names_the_counters_reader():
                       "dots3-note-prev.longdoc", "a.x-k1.docqa",
                       "nemotron-3-nano-30b-a3b.reason",
                       "command-a-plus-05-2026.docqa",
-                      "falcon-h1-34b-instruct.reason"]}]
+                      "falcon-h1-34b-instruct.reason",
+                      "lfm2-24b-a2b.reason"]}]
     path = os.path.join(root, "perfbench", "layer_metrics",
                         "runner.h2d_arrays_per_step.py")
     spec = importlib.util.spec_from_file_location("h2d_reader", path)

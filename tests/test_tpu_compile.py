@@ -279,8 +279,11 @@ def _kernel_args(topo, *, S, T, Hq=32, Hkv=8, D=64, pack=2, P=8192,
     # falcon-h1-34b-instruct: 64 rows, five query heads a KV head, the
     # pages of 6 layers
     dict(S=64, T=64, Hq=20, Hkv=4, D=128, pack=1, P=6 * 8320),
+    # lfm2-24b-a2b: 128 rows, heads of 64 packed in pairs, the pages of
+    # the 10 attention layers
+    dict(S=128, T=128, P=10 * 16640),
 ], ids=["smoke_packed", "dense_cell_hkv8", "hybrid_cell_hkv32",
-        "mla_cell_hkv1", "par_cell_20x4"])
+        "mla_cell_hkv1", "par_cell_20x4", "sconv_cell_packed"])
 def test_decode_kernel_compiles_for_v5e(topo, on_tpu, geometry):
     """Mosaic accepts the decode kernel's block update at every geometry
     served on the chip, with the block and group the table gives (under
@@ -369,6 +372,16 @@ def test_ragged_kernel_under_several_kv_heads_compiles_for_v5e(
 @pytest.mark.slow
 def test_ragged_kernel_compiles_for_v5e(topo, on_tpu):
     assert has_kernel(_ragged(topo, S=8, T=2048))
+
+
+def test_ragged_kernel_compiles_for_v5e_at_the_packed_cells_pool(topo,
+                                                                 on_tpu):
+    """The ragged kernel over heads of 64 packed in pairs at
+    lfm2-24b-a2b's rows and pool: 128 rows, a 512-token mixed step, the
+    pages of the 10 attention layers (the decode kernel's case at this
+    geometry is ``sconv_cell_packed`` above)."""
+    compiled = _ragged(topo, S=128, T=512, P=10 * 16640)
+    assert attention_calls(compiled) == ["ragged_paged_attention"]
 
 
 @pytest.mark.slow
@@ -1316,6 +1329,96 @@ def test_falcon_h1_probe_chunk_with_prompt_logprobs_fits_the_chip(
                  ["mamba2_chunk_scan", "mamba2_recurrent_step"], 1.2 * GiB)
 
 
+# ---- a gated short convolution beside packed GQA at LFM2-24B-A2B's widths ---
+
+LFM2 = "lfm2-24b-a2b"
+
+
+def _lfm2_step(topo, monkeypatch, make_batch, calls, grouped, temp_bound):
+    """Compile one step of the short-convolution cell and hold it to: the
+    attention kernels over the packed cache as Pallas calls and the held
+    experts' products in the form ``grouped`` names (the Pallas ``gmm`` in
+    a mixed step; in a decode-only step of 128 rows every held expert
+    times every row, XLA's batched products over the stack in place), the
+    weights, the KV pool and the window pool as the
+    configuration derives them (``_ssm_pool_bytes`` within 1 % of the
+    compiler's count), no copy of a whole stack or of the window pool, and
+    no operation of its own whose result is one layer of a projection's
+    stack (docs/stacked_layers.md)."""
+    from gllm_tpu.models.config import from_hf_config
+    cfg = from_hf_config(_perfbench_hf(LFM2))
+    runner = make_runner(cfg, topo, num_pages=16640, monkeypatch=monkeypatch,
+                         max_num_seqs=128, max_model_len=4096,
+                         attention_impl="auto")
+    assert runner.attn_impl == "pallas" and runner.kv_pack == 2
+    assert runner.kv.k.shape == (10, 16640, 16, 4, 128)
+    assert runner.kv.conv.shape == (30, 129, 2, 2048) and runner.kv.rec is None
+    c = compile_of(runner.step_async, _with_slots(make_batch(runner)))
+    text = c.compiled.as_text()
+    assert attention_calls(c.compiled) == calls
+    assert _pallas_calls(c.compiled) == grouped
+    if not grouped:
+        assert re.search(r"= bf16\[8,128,1536\]\S* fusion\(", text)
+    mem = c.compiled.memory_analysis()
+    print(f"\n[compile] lfm2 {calls[0]}: {c.seconds:.1f}s, "
+          f"{mem.argument_size_in_bytes / GiB:.2f} GiB of arguments, "
+          f"{mem.temp_size_in_bytes / GiB:.3f} GiB temp, "
+          f"{mem.generated_code_size_in_bytes / 1e6:.1f} MB of code")
+    assert mem.temp_size_in_bytes < temp_bound, mem.temp_size_in_bytes
+    # no whole stack (30 conv, 10 attention, 38 expert, 2 dense layers)
+    # and no window pool is copied; the taps' [30, 3, 2048] (368 KB,
+    # retiled once a step) is the one stack under a megabyte
+    stacks = [tuple(int(d) for d in dims.split(","))
+              for dims in re.findall(
+                  r"= bf16\[((?:30|10|38|2),[\d,]+)\]\S* copy\(", text)]
+    assert all(2 * np.prod(s) < 1e6 for s in stacks), stacks
+    assert not re.findall(r"= f32\[(30,129|3870),2,2048\]\S* copy\(", text)
+    comps = list(_computations(text))
+    one_layer = re.compile(
+        r"= bf16\[(1,|8,|1,8,)?(2048|11776|1536),(6144|2048|512|11776|1536)"
+        r"\]\S* (fusion|copy)\(")
+    moved = [ln.strip()[:140] for _, lines, fused in comps if not fused
+             for ln in lines if one_layer.search(ln)]
+    assert not moved, moved
+    derived = _perfbench_hf(LFM2)["derived"]
+    weights = _weight_bytes(runner)
+    assert weights == derived["weight_bytes"]
+    kv_args = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                  for x in (runner.kv.k, runner.kv.v))
+    assert kv_args == derived["kv_pool_bytes"] \
+        == runner.num_pages * runner._kv_bytes_per_page()
+    # what is left of the arguments: the window pool, the rotary table
+    # and the step's batch (a quarter of a megabyte)
+    rope = int(np.prod(runner.cos_sin.shape)) * runner.cos_sin.dtype.itemsize
+    state = mem.argument_size_in_bytes - weights - kv_args - rope
+    assert abs(state / runner._ssm_pool_bytes() - 1) < 0.01, (
+        state, runner._ssm_pool_bytes())
+    assert runner._ssm_pool_bytes() == derived["window_pool_bytes"]
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.6 * GiB
+
+
+@pytest.mark.slow
+def test_lfm2_decode_step_compiles_for_v5e(topo, on_tpu, monkeypatch):
+    """The cell's decode step: 128 rows at 129 pages in the 256-page
+    bucket, 7.29 GB of weights, 5.45 GB of KV and 0.07 GB of windows as
+    arguments. Counted from shapes by the compiler; nothing runs."""
+    _lfm2_step(topo, monkeypatch, lambda r: decode_batch(r, 128, 129),
+               ["paged_decode_attention"], [], 0.5 * GiB)
+
+
+@pytest.mark.slow
+def test_lfm2_largest_mixed_step_compiles_for_v5e(topo, on_tpu,
+                                                  monkeypatch):
+    """The cell's largest mixed step: a 2048-token chunk beside 127
+    decoding rows (the 2176-token program of the cell's --maxd 128; under
+    this runner's default --maxd 256 the same batch takes the 2304-token
+    bucket): both attention kernels, the operator's one gather over the
+    flat token axis, no packed layout."""
+    _lfm2_step(topo, monkeypatch,
+               lambda r: prefill_batch(r, 2048, ndecode=127, npages=129),
+               MIXED_STEP_CALLS, ["gmm"], 1.6 * GiB)
+
+
 # ---- windowed GQA in pages at command-a-plus-05-2026's widths ---------------
 
 COHERE = "command-a-plus-05-2026"
@@ -1448,9 +1551,10 @@ def test_cohere2_mixed_step_compiles_for_v5e(topo, on_tpu, monkeypatch,
 
 @pytest.mark.parametrize("config,slots,dp", [
     (FALCON, 65, 1), ("olmo-hybrid-7b", 33, 1),
-    ("nemotron-3-nano-30b-a3b", 65, 1), (FALCON, 33, 2)],
+    ("nemotron-3-nano-30b-a3b", 65, 1), (FALCON, 33, 2),
+    ("lfm2-24b-a2b", 129, 1)],
     ids=["falcon_256_lanes", "hybrid_384_lanes", "state_space_128_lanes",
-         "falcon_dp_stacked"])
+         "falcon_dp_stacked", "window_alone"])
 def test_slot_maintenance_moves_slots_not_the_pool(topo, on_tpu, config,
                                                    slots, dp):
     """`runner._ssm_apply` at a cell's pools as `ssm_slot_shapes` lays
@@ -1468,8 +1572,12 @@ def test_slot_maintenance_moves_slots_not_the_pool(topo, on_tpu, config,
     sds = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(
         shape, dt, sharding=one)
     lead = (cfg.num_linear_layers, slots)
-    conv, rec = (sds((dp,) * (dp > 1) + lead + s)
-                 for s in cfg.ssm_slot_shapes)
+    window, state = cfg.ssm_slot_shapes
+    conv = sds((dp,) * (dp > 1) + lead + window)
+    # a window-only slot (lfm2_moe) hands no recurrent stack over: the
+    # window pool is then the pool the bounds are held to
+    rec = sds((dp,) * (dp > 1) + lead + state) if state else None
+    largest = conv if rec is None else rec
     lists = [sds((4,), jnp.int32)] * 5
     t0 = time.monotonic()
     if dp > 1:
@@ -1480,9 +1588,9 @@ def test_slot_maintenance_moves_slots_not_the_pool(topo, on_tpu, config,
     cost = compiled.cost_analysis()
     cost = cost[0] if isinstance(cost, (list, tuple)) else cost
     temp = compiled.memory_analysis().temp_size_in_bytes
-    pool = 4 * int(np.prod(rec.shape))
+    pool = 4 * int(np.prod(largest.shape))
     slot = pool // (dp * slots)
-    print(f"\n[compile] slot maintenance, rec {rec.shape}: "
+    print(f"\n[compile] slot maintenance, pool {largest.shape}: "
           f"{time.monotonic() - t0:.1f}s, bytes accessed "
           f"{cost['bytes accessed'] / 1e9:.3f} GB of a pool of "
           f"{pool / 1e9:.2f}, temporaries {temp / 1e6:.2f} MB of a slot's "
